@@ -166,7 +166,6 @@ def build_local_sgd_step(
     shards and the sync reduction moves shard-sized payloads only
     (reference local_sgd/HSDP composition).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     cfg = config or LocalSGDConfig()
@@ -175,8 +174,8 @@ def build_local_sgd_step(
     bspec = batch_spec if batch_spec is not None else rep
 
     @partial(
-        shard_map, mesh=mesh,
-        in_specs=(rep, bspec), out_specs=rep, check_rep=False,
+        jax.shard_map, mesh=mesh,
+        in_specs=(rep, bspec), out_specs=rep, check_vma=False,
     )
     def inner_fn(replica_params, batch):
         params = jax.tree.map(lambda x: x[0], replica_params)

@@ -473,20 +473,28 @@ def model_flops_per_token(cfg, seq_len: Optional[int] = None) -> float:
     return 6.0 * n + 12 * cfg.num_layers * seq * cfg.hidden_size
 
 
-def mfu_denominator_flops(device_kind: str) -> Optional[float]:
-    """Peak bf16 FLOP/s for known TPU generations (for MFU accounting).
-    Returns None for unknown hardware — an MFU against a guessed peak
-    would be silently wrong."""
-    kind = device_kind.lower()
-    table = {
-        "v6": 918e12,
-        "v5p": 459e12,
-        "v5": 197e12,   # v5e / v5 lite
-        "v4": 275e12,
-        "v3": 123e12,
-        "v2": 45e12,
-    }
-    for key, val in table.items():
-        if key in kind:
-            return val
-    return None
+#: Peak dense bf16 FLOP/s of one chip, keyed by the EXACT
+#: ``jax.Device.device_kind`` string (vendor-published peaks; v5e:
+#: Google Cloud "TPU v5e" documentation).  A device that is not here is
+#: an error, not a default — add its kind and its published peak.
+PEAK_BF16_FLOPS = {
+    "TPU v5 lite": 197e12,   # v5e
+    "TPU v6 lite": 918e12,   # v6e (Trillium)
+    "TPU v5": 459e12,        # v5p
+    "TPU v4": 275e12,
+    "TPU v3": 123e12,
+    "TPU v2": 45e12,
+}
+
+
+def mfu_denominator_flops(device_kind: str) -> float:
+    """Peak bf16 FLOP/s for MFU accounting.  Raises for a device the
+    table does not know: an MFU against a guessed peak would be
+    silently wrong, and one that silently disappears hides the device."""
+    try:
+        return PEAK_BF16_FLOPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published bf16 peak on record for device_kind "
+            f"{device_kind!r}: add it to mesh.PEAK_BF16_FLOPS (known: "
+            f"{sorted(PEAK_BF16_FLOPS)})") from None

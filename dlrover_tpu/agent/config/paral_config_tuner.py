@@ -22,7 +22,7 @@ from dlrover_tpu.common.log import default_logger as logger
 
 
 def paral_config_path() -> str:
-    return os.getenv(ConfigPath.ENV_PARAL_CONFIG, ConfigPath.PARAL_CONFIG)
+    return ConfigPath.paral_config()
 
 
 def write_paral_config(config: comm.ParallelConfig,
@@ -75,6 +75,12 @@ class ParalConfigTuner:
         if self._thread is not None:
             self._thread.join(timeout=2)
             self._thread = None
+            # the file is this job's (its name carries the job id):
+            # nobody else would ever overwrite or remove it
+            try:
+                os.remove(self._path)
+            except OSError:
+                pass
 
     def check_once(self) -> Optional[comm.ParallelConfig]:
         """Fetch the config; write the file when a version advanced."""

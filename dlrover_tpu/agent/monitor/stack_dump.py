@@ -27,6 +27,7 @@ import signal
 import time
 from typing import Dict, Iterable, Optional
 
+from dlrover_tpu.common.constants import job_uid, runtime_dir
 from dlrover_tpu.common.log import default_logger as logger
 
 ENV_DUMP_DIR = "DLROVER_STACK_DUMP_DIR"
@@ -35,8 +36,7 @@ _registered_file = None  # keep the dump file object alive (faulthandler
 
 
 def default_dump_dir() -> str:
-    job = os.environ.get("DLROVER_JOB_UID", "local")
-    return f"/tmp/dlrover_tpu/stacks/{job}"
+    return runtime_dir("stacks", job_uid())
 
 
 def dump_path(pid: int, dump_dir: Optional[str] = None) -> str:

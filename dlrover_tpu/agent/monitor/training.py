@@ -21,7 +21,7 @@ from dlrover_tpu.common.log import default_logger as logger
 
 
 def metrics_path() -> str:
-    return os.getenv(ConfigPath.ENV_RUNTIME_METRICS, ConfigPath.RUNTIME_METRICS)
+    return ConfigPath.runtime_metrics()
 
 
 def write_runtime_metrics(
@@ -98,6 +98,12 @@ class TrainingMonitor:
         if self._thread is not None:
             self._thread.join(timeout=2)
             self._thread = None
+            # the file is this job's (its name carries the job id):
+            # nobody else would ever overwrite or remove it
+            try:
+                os.remove(self._path)
+            except OSError:
+                pass
 
     def check_once(self) -> Optional[int]:
         data = read_runtime_metrics(self._path)

@@ -29,6 +29,8 @@ fleet-level history.
 from __future__ import annotations
 
 import argparse
+import os
+import tempfile
 import threading
 from typing import Any, Dict, Optional
 
@@ -246,7 +248,8 @@ class BrainClient:
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--port", type=int, default=23500)
-    p.add_argument("--db", default="/tmp/dlrover_tpu_brain.db")
+    p.add_argument("--db", default=os.path.join(
+        tempfile.gettempdir(), "dlrover_tpu_brain.db"))
     args = p.parse_args(argv)
     service = BrainService(JobHistoryStore(args.db), port=args.port)
     service.start()

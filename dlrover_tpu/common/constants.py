@@ -6,6 +6,9 @@ TPU pod-slice deployments: workers are per-host processes driving all local
 TPU chips via one JAX process, not per-GPU processes.
 """
 
+import os
+import tempfile
+
 
 class NodeType:
     MASTER = "master"
@@ -281,11 +284,37 @@ class NodeEnv:
     TELEMETRY_ENDPOINT = "DLROVER_TELEMETRY_ENDPOINT"
 
 
+def runtime_dir(*parts: str) -> str:
+    """Where one host's processes keep the small files they share (IPC
+    sockets, stack dumps, the runtime-metrics and paral-config files):
+    under the temporary directory the environment names (``TMPDIR``),
+    never a fixed ``/tmp`` path, so two checkouts that are given
+    directories of their own cannot meet in one file."""
+    return os.path.join(tempfile.gettempdir(), "dlrover_tpu", *parts)
+
+
+def job_uid() -> str:
+    return os.getenv(NodeEnv.JOB_UID, "local")
+
+
 class ConfigPath:
+    """The agent <-> trainer hot-reload files.  The defaults carry the
+    job's id: two jobs on one host share neither file."""
+
     ENV_PARAL_CONFIG = NodeEnv.PARAL_CONFIG_PATH
-    PARAL_CONFIG = "/tmp/dlrover_tpu/auto_paral_config.json"
     ENV_RUNTIME_METRICS = NodeEnv.RUNTIME_METRICS_PATH
-    RUNTIME_METRICS = "/tmp/dlrover_tpu/runtime_metrics.json"
+
+    @staticmethod
+    def paral_config() -> str:
+        return os.getenv(
+            ConfigPath.ENV_PARAL_CONFIG,
+            runtime_dir(f"auto_paral_config_{job_uid()}.json"))
+
+    @staticmethod
+    def runtime_metrics() -> str:
+        return os.getenv(
+            ConfigPath.ENV_RUNTIME_METRICS,
+            runtime_dir(f"runtime_metrics_{job_uid()}.json"))
 
 
 class RendezvousName:

@@ -21,17 +21,17 @@ from typing import Any, Dict, Optional
 
 import msgpack
 
+from dlrover_tpu.common.constants import job_uid, runtime_dir
 from dlrover_tpu.common.log import default_logger as logger
 
-SOCKET_TMP_DIR = "/tmp/dlrover_tpu/sockets/"
 
 _LEN = struct.Struct("!I")
 
 
 def _socket_path(name: str) -> str:
-    os.makedirs(SOCKET_TMP_DIR, exist_ok=True)
-    job = os.getenv("DLROVER_JOB_UID", "local")
-    return os.path.join(SOCKET_TMP_DIR, f"{job}_{name}.sock")
+    sockets = runtime_dir("sockets")
+    os.makedirs(sockets, exist_ok=True)
+    return os.path.join(sockets, f"{job_uid()}_{name}.sock")
 
 
 def _send_msg(conn: socket.socket, obj: Any) -> None:
@@ -474,12 +474,12 @@ def prefault_readonly(mm, length: int = 0) -> str:
 
 def clear_sockets() -> None:
     """Remove this job's socket files (used by tests and agent shutdown)."""
-    if not os.path.exists(SOCKET_TMP_DIR):
+    sockets = runtime_dir("sockets")
+    if not os.path.exists(sockets):
         return
-    job = os.getenv("DLROVER_JOB_UID", "local")
-    for f in os.listdir(SOCKET_TMP_DIR):
-        if f.startswith(f"{job}_"):
+    for f in os.listdir(sockets):
+        if f.startswith(f"{job_uid()}_"):
             try:
-                os.unlink(os.path.join(SOCKET_TMP_DIR, f))
+                os.unlink(os.path.join(sockets, f))
             except OSError:
                 pass

@@ -10,7 +10,7 @@ import threading
 import time
 from typing import Dict, Optional
 
-from dlrover_tpu.common.constants import RendezvousName
+from dlrover_tpu.common.constants import NodeStatus, RendezvousName
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.rpc import bind_server_port, build_server
 from dlrover_tpu.master.elastic_training.elastic_ps import ElasticPsService
@@ -26,6 +26,10 @@ from dlrover_tpu.master.monitor.speed_monitor import SpeedMonitor
 from dlrover_tpu.master.servicer import MasterServicer
 from dlrover_tpu.master.shard.task_manager import TaskManager
 from dlrover_tpu.master.stats.job_collector import JobMetricCollector
+
+
+# how long a finished job's master waits for an agent's final report
+AGENT_WRAPUP_SECONDS = 120.0
 
 
 class LocalJobMaster:
@@ -77,13 +81,35 @@ class LocalJobMaster:
         self._server.start()
         logger.info("Local master serving on port %s", self._port)
 
+    def agents_ended(self) -> bool:
+        """Every agent that reported its node RUNNING has since reported
+        how it ended (vacuously true for plain clients, which report no
+        node status at all)."""
+        return all(
+            status in (NodeStatus.SUCCEEDED, NodeStatus.FAILED)
+            for status in self.servicer.node_status.values())
+
     def run(self) -> int:
-        """Block until the job finishes (all datasets completed) or stop."""
+        """Block until the job finishes or stop.  The job has finished
+        when all datasets are completed AND the agents have reported how
+        they ended: after its last shard a worker still wraps up (at a
+        real size the final blocking checkpoint save alone takes tens of
+        seconds), and a master that has left by then costs its agent a
+        whole RPC retry deadline on every remaining call.  An agent that
+        never reports (it was killed) holds the master for
+        ``AGENT_WRAPUP_SECONDS`` at most."""
+        done_at = None
         try:
             while not self._stopped.is_set():
-                if self.task_manager.finished():
-                    logger.info("All dataset tasks completed; master exits")
-                    break
+                if not self.task_manager.finished():
+                    done_at = None
+                else:
+                    done_at = done_at or time.time()
+                    if self.agents_ended() or (
+                            time.time() - done_at > AGENT_WRAPUP_SECONDS):
+                        logger.info(
+                            "All dataset tasks completed; master exits")
+                        break
                 time.sleep(2)
         except KeyboardInterrupt:
             pass

@@ -7,7 +7,7 @@ service with two unary RPCs — ``get`` (queries) and ``report``
 
 import json
 import time
-from typing import Optional
+from typing import Dict, Optional
 
 from dlrover_tpu.common import comm
 from dlrover_tpu.common.constants import (
@@ -56,6 +56,8 @@ class MasterServicer:
         self._diagnosis_manager = diagnosis_manager
         self._start_training_time = 0.0
         self._start_autoscale = False
+        # last status each agent reported for its node (node_id -> status)
+        self.node_status: Dict[int, str] = {}
 
     # ------------------------------------------------------------- get
     def get(self, request_bytes: bytes, context=None) -> bytes:
@@ -402,6 +404,7 @@ class MasterServicer:
                 )
             return None
         if isinstance(message, comm.NodeStatusReport):
+            self.node_status[message.node_id] = message.status
             if self._job_manager is not None:
                 self._job_manager.update_node_reported_status(
                     req.node_type or NodeType.WORKER,

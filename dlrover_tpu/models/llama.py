@@ -116,6 +116,20 @@ class LlamaConfig:
         return cls(**kw)
 
     @classmethod
+    def from_preset(
+        cls, name: str, num_layers: int = 0, **kw
+    ) -> "LlamaConfig":
+        """A named preset, optionally cut in DEPTH only (``num_layers``
+        > 0) — how the entry points name a model: widths are the
+        preset's own, depth is what the chip at hand holds."""
+        if name not in PRESETS:
+            raise ValueError(
+                f"unknown model preset {name!r}: use one of {PRESETS}")
+        if num_layers:
+            kw["num_layers"] = int(num_layers)
+        return getattr(cls, name)(**kw)
+
+    @classmethod
     def tiny(cls, **kw) -> "LlamaConfig":
         base = dict(
             vocab_size=256,
@@ -130,6 +144,10 @@ class LlamaConfig:
         )
         base.update(kw)
         return cls(**base)
+
+
+#: presets the entry points (examples/, the serving worker) can name
+PRESETS = ("tiny", "llama2_7b")
 
 
 def resolve_remat_policy(name: str):

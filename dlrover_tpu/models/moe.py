@@ -166,13 +166,9 @@ class MoEMLP(nn.Module):
         wg = w_gate.astype(self.dtype)
         wu = w_up.astype(self.dtype)
         wd = w_down.astype(self.dtype)
-        from dlrover_tpu.ops.fp8 import _supports_fp8
-
-        if self.fp8 and _supports_fp8():
+        if self.fp8:
             from dlrover_tpu.ops.fp8 import fake_quant_fp8, grad_quant_fp8
         else:
-            # degrade like fp8_dot_general does on jax builds without
-            # fp8 dtypes instead of crashing (advisor r2)
             fake_quant_fp8 = grad_quant_fp8 = lambda x: x  # noqa: E731
         # grouped GEMM over the expert dim (reference grouped_gemm_moe.py)
         xq = fake_quant_fp8(expert_in)

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 
 from dlrover_tpu.common.log import default_logger as logger
 
-_warned_fallback = False
 _warned_cp = False
 
 
@@ -188,38 +187,80 @@ def dot_product_attention(
         # kernel wins (and avoids O(s^2) memory) beyond that.  The gate must
         # match the kernel's block-divisibility requirement — there is no
         # exception fallback once dispatched.
-        try:
-            from dlrover_tpu.ops.pallas.flash_attention import (
-                DEFAULT_BLOCK_K,
-                DEFAULT_BLOCK_Q,
-            )
+        from dlrover_tpu.ops.pallas.flash_attention import (
+            DEFAULT_BLOCK_K,
+            DEFAULT_BLOCK_Q,
+        )
 
-            use_pallas = (
-                jax.default_backend() not in ("cpu", "gpu")
-                and q.shape[1] >= 2048
-                and q.shape[1] % DEFAULT_BLOCK_Q == 0
-                and k.shape[1] % DEFAULT_BLOCK_K == 0
-            )
-        except ImportError:
-            use_pallas = False
+        use_pallas = (
+            jax.default_backend() not in ("cpu", "gpu")
+            and q.shape[1] >= 2048
+            and q.shape[1] % DEFAULT_BLOCK_Q == 0
+            and k.shape[1] % DEFAULT_BLOCK_K == 0
+        )
     if use_pallas:
-        try:
-            from dlrover_tpu.ops.pallas.flash_attention import flash_attention
-        except ImportError:
-            global _warned_fallback
-            if not _warned_fallback:
-                _warned_fallback = True
-                logger.warning(
-                    "Pallas flash-attention kernel unavailable; using the "
-                    "O(s^2)-memory XLA attention path"
-                )
-        else:
-            return flash_attention(
-                q, k, v, causal=causal, segment_ids=segment_ids, scale=scale
-            )
+        return _sharded_flash_attention(
+            q, k, v, causal=causal, segment_ids=segment_ids, scale=scale
+        )
     return _xla_attention(
         q, k, v, causal=causal, segment_ids=segment_ids, scale=scale
     )
+
+
+def _sharded_flash_attention(q, k, v, *, causal, segment_ids, scale):
+    """The Pallas flash kernel on global arrays of a plain (dp/fsdp/tp)
+    mesh.  A Mosaic kernel cannot be partitioned by GSPMD, so under an
+    ambient mesh the call is wrapped in ``shard_map`` with the specs the
+    logical rules give the activations — batch over dp/fsdp, heads over
+    tp, the sequence whole on every device — and each device runs the
+    kernel on its own shard; nothing is gathered.  Without a mesh, inside
+    an enclosing shard_map (the Ulysses and ring paths), or when every
+    axis the operands use has size 1, the kernel is called directly."""
+    from jax.sharding import PartitionSpec
+
+    from dlrover_tpu.accel.parallel.mesh import ambient_mesh, axes_size
+    from dlrover_tpu.ops.pallas.flash_attention import flash_attention
+
+    def direct(q, k, v, seg):
+        return flash_attention(
+            q, k, v, causal=causal, segment_ids=seg, scale=scale
+        )
+
+    mesh = None if _under_named_axes() else ambient_mesh()
+    if mesh is None:
+        return direct(q, k, v, segment_ids)
+    q_spec, kv_spec, _ = _attention_specs(mesh)
+    batch_axes, head_axes, kv_head_axes = q_spec[0], q_spec[2], kv_spec[2]
+    n_batch = axes_size(mesh, batch_axes)
+    n_heads = axes_size(mesh, head_axes)
+    if n_batch * n_heads == 1:
+        return direct(q, k, v, segment_ids)
+    if head_axes != kv_head_axes:
+        raise ValueError(
+            "flash attention under a mesh needs q heads and kv heads "
+            f"sharded alike (rules give {head_axes!r} and "
+            f"{kv_head_axes!r}): a device must hold whole GQA groups"
+        )
+    if q.shape[0] % n_batch or q.shape[2] % n_heads or k.shape[2] % n_heads:
+        raise ValueError(
+            f"flash attention under mesh {dict(mesh.shape)} needs batch "
+            f"{q.shape[0]} divisible by {n_batch} ({batch_axes!r}) and "
+            f"head counts {q.shape[2]}/{k.shape[2]} divisible by "
+            f"{n_heads} ({head_axes!r}): the Pallas kernel runs per "
+            "device on whole shards"
+        )
+    qkv_spec = PartitionSpec(batch_axes, None, head_axes, None)
+    args, specs = (q, k, v), (qkv_spec,) * 3
+    if segment_ids is not None:
+        args += (segment_ids,)
+        specs += (PartitionSpec(batch_axes, None),)
+    return jax.shard_map(
+        lambda q, k, v, seg=None: direct(q, k, v, seg),
+        mesh=mesh,
+        in_specs=specs,
+        out_specs=qkv_spec,
+        check_vma=False,
+    )(*args)
 
 
 # ---------------------------------------------------------------------------

@@ -39,10 +39,6 @@ E4M3_MAX = 448.0
 E5M2_MAX = 57344.0
 
 
-def _supports_fp8() -> bool:
-    return hasattr(jnp, "float8_e4m3fn") and hasattr(jnp, "float8_e5m2")
-
-
 def quantize_dequantize(x: jax.Array, fp8_dtype: Any, max_val: float) -> jax.Array:
     """Round-trip x through fp8 with per-tensor current scaling."""
     xf = x.astype(jnp.float32)
@@ -102,11 +98,6 @@ def fp8_dot_general(
     fp8-quantized gradients.  Inject into flax layers:
     ``nn.DenseGeneral(..., dot_general=fp8_dot_general)``.
     """
-    if not _supports_fp8():  # very old jax: degrade to the plain dot
-        return jax.lax.dot_general(
-            lhs, rhs, dimension_numbers, precision=precision,
-            preferred_element_type=preferred_element_type,
-        )
     return grad_quant_fp8(jax.lax.dot_general(
         fake_quant_fp8(lhs),
         fake_quant_fp8(rhs),

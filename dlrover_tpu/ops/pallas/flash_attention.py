@@ -483,9 +483,9 @@ def flash_attention(
 ) -> jax.Array:
     """Flash attention on [batch, seq, heads, dim] inputs (GQA allowed).
 
-    Falls back to raising ValueError for shapes the kernels cannot tile;
-    the caller (ops.attention.dot_product_attention) catches import errors
-    only, so keep inputs block-aligned (seq divisible by 128).
+    Raises ValueError for shapes the kernels cannot tile; the caller
+    (ops.attention.dot_product_attention) has no fallback once it has
+    dispatched here, so keep inputs block-aligned (seq divisible by 128).
     """
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape

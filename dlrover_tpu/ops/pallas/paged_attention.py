@@ -38,12 +38,20 @@ leverage:
    into a 2-slot VMEM scratch, starting group ``g+1``'s DMAs before
    computing group ``g`` — the double-buffer pattern, with the page
    list coming from the scalar-prefetched block table.
-2. **Dequantization folded inside.**  Quantized pools ship their
-   block-shaped scale pools; codes are dequantized in VMEM right after
-   the copy lands (int4 codes unpack split-half: byte ``j`` holds code
-   ``j`` low-nibble and ``j + D/2`` high-nibble, so unpack is a
-   concatenate, not an interleave).  HBM traffic is code-width; the
-   XLA gather path cannot avoid materializing the dequantized rows.
+2. **Dequantization folded inside.**  Quantized codes are widened in
+   VMEM right after the copy lands (int4 codes unpack split-half: byte
+   ``j`` holds code ``j`` low-nibble and ``j + D/2`` high-nibble, so
+   unpack is a concatenate, not an interleave).  HBM traffic for the
+   codes is code-width; the XLA gather path cannot avoid materializing
+   the dequantized rows.  The per-(token, head) SCALES do not stream in
+   place: their pool's minor dimension is the KV-head count, which the
+   TPU pads to 128 lanes in HBM and refuses as a DMA slice, so the
+   wrapper gathers this batch's scales (2/D of the code bytes) into a
+   lane-dense ``[B, groups, KV, rows]`` view, and the kernel applies
+   them to the ``[G, rows]`` score and probability tiles — a scale is
+   constant along the head dim, so it factors out of both dots.
+   **Packed int4 pools do not compile on a TPU** (``INT4_REFUSAL``):
+   that variant runs in interpret mode only, as the parity harness.
 3. Online softmax (flash-style m/l/acc carry in VMEM scratch) over
    ``[KV*G, pages*bs]`` score tiles per group; GQA queries regroup to
    ``[KV, G, D]`` and each kv head's scores come from one dot against
@@ -82,6 +90,17 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
+#: Why the packed-int4 variant is refused on a TPU (compiled for a
+#: described v5e, PR 21).  The pool's minor dimension is D//2 = 64, and
+#: Mosaic accepts a DMA slice only of a 128-aligned minor dimension.
+#: Until the pool is re-laid lane-dense, int4 pools run the gather.
+INT4_REFUSAL = (
+    "the packed int4 pool does not compile on TPU v5e — MosaicError: "
+    "'Slice shape along dimension 3 must be aligned to tiling (128), "
+    "but is 64' (the packed minor dimension D//2); use "
+    "attention_impl='xla' (the gather) or kv_dtype='int8'"
+)
+
 
 def _unpack4_f32(x: jax.Array) -> jax.Array:
     """Packed int4 ``[..., Dc] -> f32 codes [..., 2*Dc]`` (split-half
@@ -101,12 +120,12 @@ def _decode_kernel(
     quant: bool, packed: bool,
 ):
     if quant:
-        (q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
-         kb, vb, ksb, vsb, m_scr, l_scr, acc_scr, sem, ssem) = args
+        (q_ref, k_hbm, v_hbm, ks_ref, vs_ref, o_ref,
+         kb, vb, m_scr, l_scr, acc_scr, sem) = args
     else:
         (q_ref, k_hbm, v_hbm, o_ref,
          kb, vb, m_scr, l_scr, acc_scr, sem) = args
-        ks_hbm = vs_hbm = ksb = vsb = ssem = None
+        ks_ref = vs_ref = None
 
     b = pl.program_id(0)
     bs, p_n = block_size, pages
@@ -123,13 +142,6 @@ def _decode_kernel(
                 k_hbm.at[page], kb.at[slot, j], sem.at[slot, j, 0]))
             copies.append(pltpu.make_async_copy(
                 v_hbm.at[page], vb.at[slot, j], sem.at[slot, j, 1]))
-            if quant:
-                copies.append(pltpu.make_async_copy(
-                    ks_hbm.at[page], ksb.at[slot, j],
-                    ssem.at[slot, j, 0]))
-                copies.append(pltpu.make_async_copy(
-                    vs_hbm.at[page], vsb.at[slot, j],
-                    ssem.at[slot, j, 1]))
         return copies
 
     def start_group(g, slot):
@@ -146,14 +158,15 @@ def _decode_kernel(
     start_group(0, 0)                 # warm-up: first group in flight
     qf = q_ref[0].astype(jnp.float32)            # [KV, G, D]
 
-    def _dequant(raw, scale):
+    def _codes(raw):
         # raw [P, bs, KV, Dc] -> f32 [P, bs, KV, D]; the whole point:
         # this runs on VMEM-resident codes AFTER the copy, so HBM only
-        # ever saw code-width bytes
-        if not quant:
-            return raw.astype(jnp.float32)
-        codes = _unpack4_f32(raw) if packed else raw.astype(jnp.float32)
-        return codes * scale.astype(jnp.float32)[..., None]
+        # ever saw code-width bytes.  The per-(token, head) scales are
+        # NOT applied here: a scale is constant along the head dim, so
+        # it factors out of both dots and multiplies the [G, rows]
+        # score / probability tiles instead (rows on lanes — see the
+        # wrapper's scale layout)
+        return _unpack4_f32(raw) if packed else raw.astype(jnp.float32)
 
     def body(g, _):
         slot = jax.lax.rem(g, 2)
@@ -163,18 +176,26 @@ def _decode_kernel(
             start_group(g + 1, jax.lax.rem(g + 1, 2))
 
         wait_group(g, slot)
-        kf = _dequant(kb[slot], ksb[slot] if quant else None)
-        vf = _dequant(vb[slot], vsb[slot] if quant else None)
-        kf = kf.reshape(rows, kv_heads, head_dim)
-        vf = vf.reshape(rows, kv_heads, head_dim)
+        kf = _codes(kb[slot]).reshape(rows, kv_heads, head_dim)
+        vf = _codes(vb[slot]).reshape(rows, kv_heads, head_dim)
+        if quant:
+            ks = ks_ref[0, g]         # [KV, rows] f32, this group's
+            vs = vs_ref[0, g]
+
+        def k_scaled(x, kvi):         # [G, rows] * [1, rows]
+            return x * ks[kvi:kvi + 1, :] if quant else x
+
+        def v_scaled(x, kvi):
+            return x * vs[kvi:kvi + 1, :] if quant else x
+
         # per-kv-head scores: [KV*G, rows] via KV dots (static loop) —
         # at rows = pages*bs the dot's N dim is 128+ and fills the MXU
         scores = jnp.concatenate(
             [
-                jax.lax.dot_general(
+                k_scaled(jax.lax.dot_general(
                     qf[kvi], kf[:, kvi], (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
-                )
+                ), kvi)
                 for kvi in range(kv_heads)
             ],
             axis=0,
@@ -195,7 +216,8 @@ def _decode_kernel(
         pv = jnp.concatenate(
             [
                 jax.lax.dot_general(
-                    p[kvi * group:(kvi + 1) * group], vf[:, kvi],
+                    v_scaled(p[kvi * group:(kvi + 1) * group], kvi),
+                    vf[:, kvi],
                     (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 )
@@ -233,6 +255,8 @@ def paged_decode_attention(
     packed = quant and dc != d
     if packed:
         assert dc * 2 == d, (q.shape, k_pool.shape)
+        if not interpret:
+            raise NotImplementedError(INT4_REFUSAL)
     else:
         assert dc == d, (q.shape, k_pool.shape)
     assert h % kv == 0, (h, kv)
@@ -256,7 +280,7 @@ def paged_decode_attention(
         num_groups=num_groups, kv_heads=kv, group=g, head_dim=d,
         quant=quant, packed=packed,
     )
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [pl.BlockSpec((1, kv, g, d), q_map), any_spec, any_spec]
     operands = [qg, k_pool, v_pool]
     scratch = [
@@ -264,20 +288,27 @@ def paged_decode_attention(
         pltpu.VMEM((2, p_n, bs, kv, dc), v_pool.dtype),
     ]
     if quant:
-        in_specs += [any_spec, any_spec]
-        operands += [k_scale, v_scale]
-        scratch += [
-            pltpu.VMEM((2, p_n, bs, kv), k_scale.dtype),
-            pltpu.VMEM((2, p_n, bs, kv), v_scale.dtype),
-        ]
+        # The scale pools' minor dimension is the KV-head count, which
+        # no TPU tiling accepts as a DMA slice (and which HBM pads to
+        # 128 lanes), so the kernel never touches them in place.  The
+        # wrapper gathers this batch's scales — 2/D of the code bytes —
+        # into a lane-dense [B, groups, KV, rows] f32 view whose rows
+        # line up with the score tile's key axis; the codes, which are
+        # the traffic that matters, still stream in place.
+        def rows_on_lanes(scale_pool):
+            s = jnp.take(scale_pool, table, axis=0)   # [B, MBp, bs, KV]
+            s = s.reshape(b, num_groups, p_n * bs, kv)
+            return s.transpose(0, 1, 3, 2).astype(jnp.float32)
+
+        s_spec = pl.BlockSpec((1, num_groups, kv, p_n * bs), q_map)
+        in_specs += [s_spec, s_spec]
+        operands += [rows_on_lanes(k_scale), rows_on_lanes(v_scale)]
     scratch += [
         pltpu.VMEM((kv * g,), jnp.float32),
         pltpu.VMEM((kv * g,), jnp.float32),
         pltpu.VMEM((kv * g, d), jnp.float32),
         pltpu.SemaphoreType.DMA((2, p_n, 2)),
     ]
-    if quant:
-        scratch.append(pltpu.SemaphoreType.DMA((2, p_n, 2)))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -342,6 +373,60 @@ def gather_reference(
         preferred_element_type=jnp.float32,
     )
     return out.reshape(b, h, d)
+
+
+def kernel_parity(
+    *, slots: int, max_blocks: int, block_size: int, num_heads: int,
+    num_kv_heads: int, head_dim: int, dtype, kv_dtype: Optional[str],
+    interpret: bool, seed: int = 0,
+) -> Dict[str, object]:
+    """The fused kernel against :func:`gather_reference` at an engine's
+    pool geometry (page size, heads, dtypes, its table shape; no more
+    blocks than the table can name, so the check costs megabytes beside
+    a pool that fills the device), filled from ``seed``.  Runs wherever
+    the caller runs: on a chip it is the COMPILED kernel, which the
+    interpret-mode tests cannot vouch for — a serving worker reports it
+    before it announces.  The reference's dots run at full f32
+    precision so the comparison measures the kernel, not the backend's
+    default matmul truncation."""
+    from dlrover_tpu.models.quantize import (
+        quantize_kv_int4,
+        quantize_kv_int8,
+    )
+
+    if kv_dtype == "int4" and not interpret:
+        return {"kv_dtype": "int4", "refused": INT4_REFUSAL}
+    b, mb = slots, max_blocks
+    nb = b * mb + 1
+    kq, kk, kv_ = jax.random.split(jax.random.PRNGKey(seed), 3)
+    shape = (nb, block_size, num_kv_heads, head_dim)
+    q = (0.3 * jax.random.normal(kq, (b, num_heads, head_dim))).astype(dtype)
+    k = (0.3 * jax.random.normal(kk, shape)).astype(dtype)
+    v = (0.3 * jax.random.normal(kv_, shape)).astype(dtype)
+    scales = {}
+    if kv_dtype in ("int8", "int4"):
+        quant = quantize_kv_int4 if kv_dtype == "int4" else quantize_kv_int8
+        k, scales["k_scale"] = quant(k)
+        v, scales["v_scale"] = quant(v)
+    table = jax.random.randint(
+        jax.random.fold_in(kq, 1), (b, mb), 1, nb, jnp.int32)
+    # one key, odd mid lengths, ..., every column live
+    top = mb * block_size
+    lengths = jnp.clip(
+        jnp.linspace(1, top, b).astype(jnp.int32) | 1, 1, top)
+    out = paged_decode_attention(
+        q, k, v, table, lengths, interpret=interpret, **scales)
+    with jax.default_matmul_precision("highest"):
+        ref = gather_reference(
+            q, k, v, table, lengths, scales.get("k_scale"),
+            scales.get("v_scale"))
+    return {
+        "kv_dtype": kv_dtype or "bf16",
+        "max_abs_err": float(jnp.max(jnp.abs(out - ref))),
+        "finite": bool(jnp.isfinite(out).all()),
+        "pool_shape": list(k.shape), "table_shape": [b, mb],
+        "interpret": interpret,
+    }
 
 
 # ------------------------------------------------- measured auto-pick

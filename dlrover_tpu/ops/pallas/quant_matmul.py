@@ -237,6 +237,18 @@ def int8_dot_general(
     n = rhs.shape[1]
     if k % 128 or n % 128 or k < 256:
         return plain(lhs, rhs)
+    from dlrover_tpu.accel.parallel.mesh import ambient_mesh
+
+    mesh = ambient_mesh()
+    if mesh is not None and mesh.size > 1:
+        # a Mosaic kernel cannot be partitioned by GSPMD, and no
+        # shard_map exists for this one: refuse at trace time rather
+        # than let the compiler do it in its own words
+        raise NotImplementedError(
+            f"int8_dot_general (LlamaConfig.w8a8) under mesh "
+            f"{dict(mesh.shape)}: the Pallas int8 matmul runs on one "
+            "device only; use w8a8 without a mesh, or bf16 under one"
+        )
     lead = lhs.shape[:-1]
     m = 1
     for d in lead:
@@ -252,13 +264,14 @@ def int8_dot_general(
         # 384/640/... and crash at trace time)
         return 256 if dim % 256 == 0 else 128
 
-    interpret = jax.default_backend() == "cpu"
+    from dlrover_tpu.ops.pallas import interpret_off_chip
+
     out = int8_matmul(
         a2, rhs,
         block_m=block(a2.shape[0]),
         block_n=block(n),
         block_k=block(k),
-        interpret=interpret,
+        interpret=interpret_off_chip(),
     )
     if pad:
         out = out[:m]

@@ -401,7 +401,10 @@ class InferenceEngine:
         # this engine's real pool geometry and picks the faster
         # (resolve_attention_impl is the pure, tested decision)
         self.attention_impl_requested = str(attention_impl)
-        self._kernel_interpret = jax.default_backend() in ("cpu", "gpu")
+        from dlrover_tpu.ops.pallas import interpret_off_chip
+
+        self._kernel_interpret = interpret_off_chip()
+        self.attention_impl_why = ""
         self.attention_impl, self.attention_impl_us = \
             self._resolve_attention()
         self._build_programs()
@@ -423,13 +426,30 @@ class InferenceEngine:
                     "attention_impl='pallas' reads paged block pools "
                     "in place; pass paged=True")
             return "xla", None
+        refused = ""
+        if self.kv_dtype == "int4" and not self._kernel_interpret:
+            from dlrover_tpu.ops.pallas.paged_attention import (
+                INT4_REFUSAL,
+            )
+
+            refused = INT4_REFUSAL
+        if req == "pallas" and refused:
+            raise ValueError(
+                f"attention_impl='pallas' with kv_dtype='int4': {refused}")
         if req in ("xla", "pallas"):
+            self.attention_impl_why = "requested"
             return req, None
         if self._kernel_interpret:
             # no TPU: the interpret-mode kernel is a parity harness,
             # not a perf candidate — auto must not "measure" it
+            self.attention_impl_why = (
+                "auto off-chip: the interpret-mode kernel is not timed")
+            return "xla", None
+        if refused:
+            self.attention_impl_why = f"auto: kernel not tried — {refused}"
             return "xla", None
         timings = self._measure_attention()
+        self.attention_impl_why = "auto: measured, faster impl kept"
         # stored in MICROseconds to match the attribute name (the
         # measurement itself is perf_counter seconds)
         return resolve_attention_impl("auto", timings), {
@@ -613,6 +633,55 @@ class InferenceEngine:
                 return out, n_commit, cache, rng
 
             self._spec_fn = spec_fn
+
+    def warmup(self) -> int:
+        """Compile, by running each once on an IDLE engine, every
+        program this engine can dispatch: the decode chunk, the
+        speculative verify, and at every admission group size the
+        chunked-prefill program and the bucketed prefill of each bucket
+        ``_admit`` sends there (with chunked prefill on, buckets above
+        ``prefill_chunk`` never reach it).  A serving worker calls this
+        BEFORE it announces its address, so no request ever waits on a
+        compile behind a liveness window sized for steady-state steps.
+        Nothing live is touched: an idle paged engine's table rows are
+        all zero, which routes every write to the trash block (the
+        dense layout's junk lands in rows the next admission
+        overwrites or masks), no slot is active, and the sampling key
+        is left as it was.  Returns the number of programs run."""
+        assert not self._queue and all(
+            r is None for r in self._slot_req), "warmup needs an idle engine"
+        rng, b = self._rng, self.max_slots
+
+        def zeros(*shape):
+            return jnp.zeros(shape, jnp.int32)
+
+        _, _, _, self._cache, _ = self._chunk_fn(
+            self.params, self._cache, zeros(b), zeros(b),
+            jnp.zeros(b, bool), rng)
+        ran = 1
+        if self._spec_fn is not None:
+            _, _, self._cache, _ = self._spec_fn(
+                self.params, self._cache, zeros(b, self.speculative_k),
+                zeros(b), zeros(b), rng)
+            ran += 1
+        chunked = self._prefill_chunk_fn is not None
+        buckets = [n for n in self.buckets
+                   if not chunked or n <= self.prefill_chunk]
+        for g in range(1, b + 1):
+            slots = jnp.arange(g, dtype=jnp.int32)
+            if chunked:
+                self._cache, _, _ = self._prefill_chunk_fn(
+                    self.params, self._cache,
+                    zeros(g, self.prefill_chunk), zeros(g), slots,
+                    zeros(g), rng)
+                ran += 1
+            for bucket in buckets:
+                self._cache, _, _ = self._insert_fn(
+                    self.params, self._cache, zeros(g, bucket),
+                    jnp.ones(g, jnp.int32), slots, zeros(g), rng)
+                ran += 1
+        jax.block_until_ready(self._cache)
+        return ran
 
     # ------------------------------------------------------- requests
     def add_request(self, prompt_ids, max_new_tokens: int) -> int:

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import argparse
 import itertools
+import json
 import os
 import signal
 import socket
@@ -570,26 +571,80 @@ class WorkerServer:
 def _build_llama_engine(args) -> object:
     """Real-engine path (lazy imports: jax must not gate ``--engine
     fake``).  Weights are randomly initialized — the checkpoint-loading
-    rung is recorded in ROADMAP, not faked here."""
+    rung is recorded in ROADMAP, not faked here.
+
+    The engine compiles what it will serve (``InferenceEngine.warmup``)
+    BEFORE this returns, so the worker announces its address only once
+    no request can wait on a compile: the fabric's liveness windows are
+    sized for steady-state steps, and a full-width program compiles for
+    longer than any of them.  ``--report-file`` receives what the build
+    saw (device, depth, pool size, the attention pick and its evidence,
+    kernel-vs-gather parity on this backend)."""
+    t0 = time.time()
+    from dlrover_tpu.utils.compile_cache import ensure_compile_cache
+
+    cache_dir = ensure_compile_cache()
     import jax
     import jax.numpy as jnp
 
     from dlrover_tpu.models.llama import LlamaConfig, LlamaModel
+    from dlrover_tpu.ops.pallas import interpret_off_chip
+    from dlrover_tpu.ops.pallas.paged_attention import kernel_parity
     from dlrover_tpu.serving.engine import InferenceEngine
     from dlrover_tpu.serving.router.replica import InferenceEngineAdapter
 
-    cfg = LlamaConfig.tiny(max_seq_len=args.max_len, dtype=jnp.float32)
+    # one dtype for compute AND parameters: initializing in float32 and
+    # re-laying to bf16 would double the footprint during the build
+    dtype = jnp.dtype(args.dtype)
+    cfg = LlamaConfig.from_preset(
+        args.model, args.layers, max_seq_len=args.max_len,
+        dtype=dtype, param_dtype=dtype)
     model = LlamaModel(cfg)
-    variables = model.init(
+    variables = jax.jit(model.init)(
         jax.random.PRNGKey(args.seed), jnp.zeros((1, 8), jnp.int32))
-    return InferenceEngineAdapter(InferenceEngine(
+    engine = InferenceEngine(
         cfg, variables, max_slots=args.slots, chunk=4, paged=True,
         block_size=args.block_size, seed=args.seed,
+        cache_blocks=args.blocks,
         kv_dtype=args.kv_dtype if args.kv_dtype != "bf16" else None,
         prefill_chunk=args.prefill_chunk,
         speculative_k=args.speculative_k,
         attention_impl=args.attention_impl,
-    ))
+    )
+    del variables
+    t_built = time.time()
+    programs = engine.warmup()
+    if args.report_file:
+        dev = jax.devices()[0]
+        report = {
+            "platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": jax.device_count(),
+            "jax": jax.__version__, "compile_cache_dir": cache_dir,
+            "model": args.model, "layers": cfg.num_layers,
+            "dtype": args.dtype, "params": cfg.num_params,
+            "slots": args.slots, "max_len": args.max_len,
+            "block_size": args.block_size,
+            "kv_dtype": args.kv_dtype,
+            "kv_blocks": engine._blockmgr.num_blocks,
+            "attention_impl_requested": args.attention_impl,
+            "attention_impl": engine.attention_impl,
+            "attention_impl_why": engine.attention_impl_why,
+            "attention_impl_us": engine.attention_impl_us,
+            "warmup_programs": programs,
+            "build_seconds": t_built - t0,
+            "warmup_seconds": time.time() - t_built,
+            "kernel_parity": kernel_parity(
+                slots=engine.max_slots, max_blocks=engine._max_blocks,
+                block_size=engine.block_size, num_heads=cfg.num_heads,
+                num_kv_heads=cfg.num_kv_heads, head_dim=cfg.head_dim_,
+                dtype=cfg.dtype, kv_dtype=engine.kv_dtype,
+                interpret=interpret_off_chip(), seed=args.seed),
+        }
+        report["peak_bytes_in_use"] = (dev.memory_stats() or {}).get(
+            "peak_bytes_in_use")
+        with open(args.report_file, "w") as f:
+            json.dump(report, f)
+    return InferenceEngineAdapter(engine)
 
 
 def main(argv: Optional[List[str]] = None) -> int:
@@ -605,7 +660,24 @@ def main(argv: Optional[List[str]] = None) -> int:
     p.add_argument("--slots", type=int, default=4)
     p.add_argument("--tokens-per-step", type=int, default=4)
     p.add_argument("--block-size", type=int, default=4)
-    p.add_argument("--blocks", type=int, default=10_000)
+    p.add_argument("--blocks", type=int, default=None,
+                   help="KV pool size in native-dtype blocks (fake "
+                        "engine: 10000 when unset; llama engine: "
+                        "every slot at full length when unset)")
+    p.add_argument("--model", default="tiny",
+                   help="llama engine: LlamaConfig preset "
+                        "(models.llama.PRESETS)")
+    p.add_argument("--layers", type=int, default=0,
+                   help="llama engine: cut the preset's depth to what "
+                        "the device holds beside its KV pool (0 = the "
+                        "preset's own); widths are never cut")
+    p.add_argument("--dtype", choices=("float32", "bfloat16"),
+                   default="float32",
+                   help="llama engine: compute and parameter dtype")
+    p.add_argument("--report-file", default="",
+                   help="llama engine: write what the build saw "
+                        "(device, depth, pool, attention pick, kernel "
+                        "parity) to this JSON file before announcing")
     p.add_argument("--max-len", type=int, default=4096)
     p.add_argument("--kv-dtype", choices=("bf16", "int8", "int4"),
                    default="bf16",
@@ -670,10 +742,20 @@ def main(argv: Optional[List[str]] = None) -> int:
     args = p.parse_args(argv)
 
     if args.engine == "llama":
-        engine = _build_llama_engine(args)
+        try:
+            engine = _build_llama_engine(args)
+        except Exception:
+            # the supervisor discards a worker's stderr: leave the
+            # reason where whoever asked for a report will look
+            if args.report_file:
+                import traceback
+
+                with open(args.report_file, "w") as f:
+                    json.dump({"error": traceback.format_exc()}, f)
+            raise
     else:
         engine = FakeEngine(
-            slots=args.slots, blocks=args.blocks,
+            slots=args.slots, blocks=args.blocks or 10_000,
             block_size=args.block_size,
             tokens_per_step=args.tokens_per_step,
             max_len=args.max_len, step_delay=args.step_delay,

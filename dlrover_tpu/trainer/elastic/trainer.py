@@ -34,13 +34,18 @@ from dlrover_tpu.accel.accelerate import (
     AccelerateResult,
     accelerate,
 )
-from dlrover_tpu.accel.parallel.mesh import MeshSpec, num_data_shards
+from dlrover_tpu.accel.parallel.mesh import (
+    MeshSpec,
+    logical_rules_context,
+    num_data_shards,
+)
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.trainer.flash_checkpoint import (
     Checkpointer,
     SaverMode,
     StorageType,
 )
+from dlrover_tpu.utils.compile_cache import ensure_compile_cache
 
 # accelerate() results keyed by (mesh dims, accum, batch shape, seq, model
 # id) — a restarted process starts cold, but within one process an
@@ -117,8 +122,6 @@ class ElasticTrainer:
         save_storage_interval: int = 50,
         saver_mode: SaverMode = SaverMode.AUTO,
         metrics_every: int = 1,
-        compile_cache_dir: Optional[str] = None,
-        compile_cache_min_secs: Optional[float] = None,
         xprof_every_n_steps: int = 0,
         metrics_port: Optional[int] = None,
     ):
@@ -168,39 +171,17 @@ class ElasticTrainer:
                 self.metrics_exporter.add_text_source(
                     self.auto_profiler.prometheus_text)
             self.metrics_exporter.start()
-        self._compile_cache_dir = (
-            compile_cache_dir
-            if compile_cache_dir is not None
-            else os.environ.get("DLROVER_COMPILE_CACHE_DIR")
-        )
-        self._compile_cache_min_secs = compile_cache_min_secs
         self._steps_since_report = 0
         self._host_step = 0
 
     # -- world / strategy -------------------------------------------------
     def prepare(self, devices: Optional[Sequence[Any]] = None) -> None:
         """Build mesh + jitted steps for the current world size."""
-        if self._compile_cache_dir:
-            # Persistent (disk) compilation cache: the in-process
-            # _COMPILE_CACHE dies with the worker, but elastic restarts
-            # respawn the process — the disk cache is what turns the
-            # post-restart recompile into a cache hit (VERDICT's
-            # compile-cache-keyed-by-mesh at the granularity that
-            # actually matters for goodput).
-            try:
-                jax.config.update(
-                    "jax_compilation_cache_dir", self._compile_cache_dir
-                )
-                if self._compile_cache_min_secs is not None:
-                    # only override the persistence threshold when the
-                    # user asked — jax's default (and any value they set
-                    # themselves) stands otherwise
-                    jax.config.update(
-                        "jax_persistent_cache_min_compile_time_secs",
-                        self._compile_cache_min_secs,
-                    )
-            except Exception as e:  # old jax without the knobs
-                logger.warning("compile cache unavailable: %s", e)
+        # Persistent (disk) compilation cache: the in-process
+        # _COMPILE_CACHE dies with the worker, but elastic restarts
+        # respawn the process — the disk cache is what turns the
+        # post-restart recompile into a cache hit.
+        ensure_compile_cache()
         if devices is None:
             devices = jax.devices()
         if self._mesh_spec_fn is not None:
@@ -415,6 +396,29 @@ class ElasticTrainer:
         if isinstance(batch, dict):
             return {k: reshape(v) for k, v in batch.items()}
         return {"input_ids": reshape(batch)}
+
+    def compiled_step_text(self, batch: Any) -> str:
+        """The train step's optimized HLO, as compiled for this mesh at
+        this batch's shape (a compile-cache hit once the step has run):
+        how a caller shows what the step really contains — a Pallas
+        kernel is a ``tpu_custom_call``, FSDP is all-gather /
+        reduce-scatter."""
+        assert self.state is not None, "call restore_or_init() first"
+        res = self.result
+        with logical_rules_context(res.config.logical_rules), res.mesh:
+            return res.jit_train_step.lower(
+                self.state, self._shape_batch(batch)).compile().as_text()
+
+    def param_bytes_per_device(self) -> Dict[int, int]:
+        """Parameter bytes each local device really holds (its
+        addressable shards): whether a sharded run is spread."""
+        assert self.state is not None, "call restore_or_init() first"
+        held: Dict[int, int] = {}
+        for leaf in jax.tree_util.tree_leaves(self.state.params):
+            for shard in leaf.addressable_shards:
+                held[shard.device.id] = (
+                    held.get(shard.device.id, 0) + shard.data.nbytes)
+        return dict(sorted(held.items()))
 
     def train_step(self, batch: Any) -> Dict[str, jax.Array]:
         assert self.state is not None, "call restore_or_init() first"

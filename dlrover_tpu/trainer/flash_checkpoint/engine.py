@@ -444,12 +444,13 @@ class CheckpointEngine:
         import jax
 
         def snap(leaf):
-            if isinstance(leaf, jax.Array):
-                try:
-                    return leaf.copy()  # async device copy, same sharding
-                except Exception:
-                    return leaf  # deleted/donated already: writer will log
-            return leaf
+            if isinstance(leaf, jax.Array) and not leaf.is_deleted():
+                # async device copy, same sharding.  It is a SECOND copy
+                # of the leaf in HBM until the writer has staged it: if
+                # that does not fit, the allocation error propagates —
+                # a save never silently takes another path
+                return leaf.copy()
+            return leaf  # host leaf, or donated already: writer will log
 
         return jax.tree_util.tree_map(snap, state)
 
@@ -690,7 +691,14 @@ class CheckpointEngine:
             step, saved = loaded
             if target is None:
                 return step, saved
-            return step, _restore_into(target, saved, shardings)
+            restored = _restore_into(target, saved, shardings)
+            if zero_copy_ok and not host_views:
+                # the device_put above read straight out of the shm
+                # segment and returns before the transfer has finished;
+                # a later save may rewrite or resize that segment, so
+                # the transfer must have landed before we hand back
+                jax.block_until_ready(restored)
+            return step, restored
         return self.load_from_storage(target, shardings)
 
     def _load_partial_from_memory(

@@ -45,6 +45,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, List, Optional, Tuple
 
+import ml_dtypes  # noqa: F401  registers bfloat16/fp8 dtype NAMES with
+# numpy: the agent's saver reads a worker's shm state by dtype name and
+# never imports jax, so without this a bf16 state cannot be persisted
 import numpy as np
 
 from dlrover_tpu.common.log import default_logger as logger
@@ -172,10 +175,7 @@ class SharedMemoryHandler:  # dlint: disable=DL011 worker restore and agent pers
 
         for leaf in jax.tree_util.tree_leaves(state):
             if isinstance(leaf, jax.Array):
-                try:
-                    leaf.copy_to_host_async()
-                except Exception:
-                    break  # backend without async staging: plain path
+                leaf.copy_to_host_async()
         committed = self._meta.get() or {}
         generation = int(committed.get("generation", 0)) + 1
         buf = generation % self.NUM_BUFFERS

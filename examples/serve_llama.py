@@ -23,6 +23,7 @@ Run::
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import numpy as np
@@ -31,7 +32,12 @@ import numpy as np
 def main() -> None:
     p = argparse.ArgumentParser()
     p.add_argument("--hf", default="",
-                   help="HF checkpoint path (empty = random tiny model)")
+                   help="HF checkpoint path (empty = random weights)")
+    p.add_argument("--model", default="tiny",
+                   help="LlamaConfig preset for random weights "
+                        "(models.llama.PRESETS)")
+    p.add_argument("--layers", type=int, default=0,
+                   help="cut the preset's depth (0 = the preset's own)")
     p.add_argument("--requests", type=int, default=8)
     p.add_argument("--prompt-len", type=int, default=32)
     p.add_argument("--max-new", type=int, default=32)
@@ -57,9 +63,15 @@ def main() -> None:
 
         from dlrover_tpu.models.llama import LlamaConfig, LlamaModel
 
-        cfg = LlamaConfig.tiny(max_seq_len=256, scan_layers=False)
+        cfg = LlamaConfig.from_preset(
+            args.model, args.layers, scan_layers=False,
+            max_seq_len=max(256, args.prompt_len + args.max_new))
+        if args.model != "tiny":
+            # initialize in the compute dtype: float32 parameters would
+            # double the footprint until the engine re-lays them
+            cfg = dataclasses.replace(cfg, param_dtype=cfg.dtype)
         model = LlamaModel(cfg)
-        variables = model.init(
+        variables = jax.jit(model.init)(
             jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)
         )
 

@@ -84,7 +84,7 @@ def assert_steps_consistent(rows, max_redos: int):
     return sorted(set(steps))
 
 
-def test_kill_one_node_resumes_trajectory(tmp_path):
+def test_kill_one_node_resumes_trajectory(tmp_path, record_path):
     work = str(tmp_path)
     from dlrover_tpu.common.rpc import find_free_port
 
@@ -167,7 +167,7 @@ def test_kill_one_node_resumes_trajectory(tmp_path):
         finally:
             client.close()
 
-        with open(os.path.join(REPO, "ELASTIC_SPMD_E2E.json"), "w") as f:
+        with open(record_path("ELASTIC_SPMD_E2E.json"), "w") as f:
             json.dump(
                 {
                     "steps": rows,

@@ -121,43 +121,44 @@ def test_scale_up_resumes_with_identical_trajectory(tmp_path):
     np.testing.assert_allclose(b_losses, ref_losses[3:], rtol=2e-4, atol=2e-4)
 
 
-def test_persistent_compile_cache_dir(tmp_path):
-    """prepare() wires the JAX persistent compilation cache so elastic
-    restarts (fresh processes) reuse compiled executables from disk."""
+def test_persistent_compile_cache_dir(tmp_path, monkeypatch):
+    """prepare() applies the one compile-cache rule
+    (utils/compile_cache), so elastic restarts (fresh processes) reuse
+    compiled executables from disk: with JAX_COMPILATION_CACHE_DIR set
+    nothing is set in code; unset, the fixed in-checkout directory is."""
     import jax
 
-    from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer
     from dlrover_tpu.models.llama import LlamaConfig, LlamaModel
+    from dlrover_tpu.trainer.elastic.trainer import ElasticTrainer
+    from dlrover_tpu.utils import compile_cache
 
-    cache = tmp_path / "xla_cache"
     trainer = ElasticTrainer(
         LlamaModel(LlamaConfig.tiny(max_seq_len=32)),
         global_batch_size=8,
         micro_batch_per_shard=1,
         seq_len=32,
-        compile_cache_dir=str(cache),
-        compile_cache_min_secs=0.0,  # persist even sub-second compiles
     )
     prev_dir = jax.config.jax_compilation_cache_dir
-    prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
     try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path / "placed"))
         trainer.prepare()
-        assert jax.config.jax_compilation_cache_dir == str(cache)
-        trainer.restore_or_init(jax.random.PRNGKey(0))
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv(compile_cache.ENV_VAR)
+        trainer.prepare()
+        assert jax.config.jax_compilation_cache_dir \
+            == compile_cache.DEFAULT_DIR
+        assert compile_cache.DEFAULT_DIR.endswith(".jax_cache")
+        assert str(tmp_path) not in compile_cache.DEFAULT_DIR
         import numpy as np
         import jax.numpy as jnp
 
+        trainer.restore_or_init(jax.random.PRNGKey(0))
         shape = (trainer.plan.micro_batch_global, 32)
         if trainer.plan.grad_accum_steps > 1:
             shape = (trainer.plan.grad_accum_steps,) + shape
-        ids = jnp.zeros(shape, jnp.int32)
-        metrics = trainer.train_step(ids)
+        metrics = trainer.train_step(jnp.zeros(shape, jnp.int32))
         assert np.isfinite(float(metrics["loss"]))
-        # the executable landed in the on-disk cache
-        assert cache.exists() and any(cache.iterdir())
     finally:
         # restore global jax config for the rest of the suite
         jax.config.update("jax_compilation_cache_dir", prev_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", prev_min
-        )

@@ -325,6 +325,44 @@ class TestServicerEndToEnd:
         action = master_client.report_heart_beat()
         assert action == ""
 
+    def test_finished_job_waits_for_the_agents_final_report(
+            self, master_client, local_master, monkeypatch):
+        """After the last dataset task the master stays until every
+        agent that reported RUNNING has reported how it ended (a worker
+        still wraps up; a master gone by then costs its agent a whole
+        retry deadline per call) — plain clients, which report no node
+        status, do not hold it, and a killed agent holds it for
+        AGENT_WRAPUP_SECONDS at most."""
+        import threading
+
+        from dlrover_tpu.common.constants import NodeStatus
+        from dlrover_tpu.master import local_master as lm
+
+        master, _ = local_master
+        assert master.agents_ended()          # nobody reported anything
+        master_client.report_node_status(0, NodeStatus.RUNNING)
+        assert not master.agents_ended()
+        master_client.report_dataset_shard_params(
+            batch_size=4, num_epochs=1, dataset_size=4, shuffle=False,
+            num_minibatches_per_shard=1, dataset_name="d")
+        master_client.report_task_result(
+            "d", master_client.get_task("d").task_id)
+        assert master.task_manager.finished()
+        t = threading.Thread(target=master.run, daemon=True)
+        t.start()
+        t.join(3.0)
+        assert t.is_alive(), "the master left a running agent behind"
+        master_client.report_node_status(0, NodeStatus.SUCCEEDED)
+        t.join(5.0)
+        assert not t.is_alive()
+        # an agent that never reports its end: bounded by the wrap-up
+        master_client.report_node_status(0, NodeStatus.RUNNING)
+        monkeypatch.setattr(lm, "AGENT_WRAPUP_SECONDS", 0.5)
+        t = threading.Thread(target=master.run, daemon=True)
+        t.start()
+        t.join(8.0)
+        assert not t.is_alive()
+
     def test_barrier_via_rpc(self, master_client):
         assert not master_client.barrier("ckpt")
         assert master_client.barrier("ckpt", notify=True)

@@ -116,7 +116,7 @@ def _reference_losses():
     return losses
 
 
-def test_slice_loss_shrinks_then_regrows(tmp_path):
+def test_slice_loss_shrinks_then_regrows(tmp_path, record_path):
     work = str(tmp_path)
     from dlrover_tpu.common.rpc import find_free_port
 
@@ -198,7 +198,7 @@ def test_slice_loss_shrinks_then_regrows(tmp_path):
         finally:
             client.close()
 
-        with open(os.path.join(REPO, "MULTISLICE_E2E.json"), "w") as f:
+        with open(record_path("MULTISLICE_E2E.json"), "w") as f:
             json.dump(
                 {
                     "steps": rows,

@@ -444,7 +444,6 @@ def test_profile_call_times_collectives():
     the per-collective timing xpu_timer provides."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from dlrover_tpu.utils.xprof_metrics import profile_call
@@ -453,8 +452,8 @@ def test_profile_call_times_collectives():
 
     @jax.jit
     def step(x):
-        f = shard_map(lambda v: jax.lax.psum(v @ v, "dp"), mesh,
-                      in_specs=P("dp"), out_specs=P())
+        f = jax.shard_map(lambda v: jax.lax.psum(v @ v, "dp"), mesh=mesh,
+                          in_specs=P("dp"), out_specs=P())
         return f(x).sum()
 
     x = jnp.ones((8 * 32, 32))

@@ -47,7 +47,9 @@ from dlrover_tpu.models.quantize import (
     unpack_int4,
 )
 from dlrover_tpu.ops.pallas.paged_attention import (
+    INT4_REFUSAL,
     gather_reference,
+    kernel_parity,
     measure_paged_attention,
     paged_decode_attention,
     resolve_attention_impl,
@@ -527,12 +529,81 @@ def test_worker_flags_reach_the_engine(monkeypatch):
     args = argparse.Namespace(
         max_len=256, seed=0, slots=2, block_size=8,
         kv_dtype="int4", prefill_chunk=32, speculative_k=0,
-        attention_impl="pallas")
+        attention_impl="pallas", model="tiny", layers=0,
+        dtype="float32", blocks=None, report_file="")
     with pytest.raises(RuntimeError, match="stop after capture"):
         worker_mod._build_llama_engine(args)
     assert captured["kv_dtype"] == "int4"
     assert captured["attention_impl"] == "pallas"
     assert captured["prefill_chunk"] == 32
+
+
+# -- what a worker does before it announces ---------------------------------
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8", "int4"])
+def test_kernel_parity_self_check_within_the_interpret_bound(kv_dtype):
+    """The self-check a serving worker reports before it announces
+    (``kernel_parity``: seeded pools at an engine's geometry, odd
+    lengths, a random table) holds the interpreted kernel to the bound
+    the hand-built cases above use."""
+    got = kernel_parity(
+        slots=3, max_blocks=5, block_size=8, num_heads=8,
+        num_kv_heads=2, head_dim=32, dtype=jnp.float32,
+        kv_dtype=kv_dtype, interpret=True, seed=1)
+    assert got["finite"] and got["max_abs_err"] <= 3e-5, got
+    assert got["table_shape"] == [3, 5] and got["pool_shape"][0] == 16
+    assert got["kv_dtype"] == (kv_dtype or "bf16")
+
+
+def test_kernel_parity_names_the_int4_refusal_off_interpret():
+    """Compiled (not interpreted), the packed int4 pool is refused by
+    the chip's compiler: the self-check says so instead of trying."""
+    got = kernel_parity(
+        slots=2, max_blocks=2, block_size=8, num_heads=4,
+        num_kv_heads=2, head_dim=32, dtype=jnp.float32,
+        kv_dtype="int4", interpret=False)
+    assert got == {"kv_dtype": "int4", "refused": INT4_REFUSAL}
+
+
+@pytest.mark.parametrize("kw,programs", [
+    # decode chunk + every bucket (32, 64, 96) at both group sizes
+    (dict(), 1 + 2 * 3),
+    (dict(paged=True, block_size=8), 1 + 2 * 3),
+    # chunked prefill on: only the buckets <= prefill_chunk reach the
+    # bucketed program, plus the chunk program at both group sizes
+    (dict(paged=True, block_size=8, kv_dtype="int8", prefill_chunk=32),
+     1 + 2 * (1 + 1)),
+    (dict(paged=True, block_size=8, speculative_k=4), 2 + 2 * 3),
+    (dict(speculative_k=4, prefill_chunk=32), 2 + 2 * (1 + 1)),
+])
+def test_warmup_compiles_every_dispatch_and_changes_no_output(
+        setup, kw, programs):
+    """``warmup()`` runs every program the engine can dispatch on the
+    engine that then serves: it must leave the cache, the table and
+    the sampling key as an untouched engine's, so the same requests
+    give the same tokens — and the requests that follow compile
+    nothing new."""
+    cfg, _ = setup
+    prompts = [_prompts(cfg, 1, n, seed=n)[0] for n in (48, 7, 20, 70)]
+
+    def run(warm):
+        eng = _engine(setup, **kw)
+        if warm:
+            assert eng.warmup() == programs
+            sizes = [f._cache_size() for f in (
+                eng._chunk_fn, eng._insert_fn, eng._prefill_chunk_fn,
+                eng._spec_fn) if f is not None]
+        rids = [eng.add_request(p, 6) for p in prompts]
+        res = eng.run()
+        if warm:
+            assert sizes == [f._cache_size() for f in (
+                eng._chunk_fn, eng._insert_fn, eng._prefill_chunk_fn,
+                eng._spec_fn) if f is not None], "a request compiled"
+        return [res[r] for r in rids]
+
+    for a, b in zip(run(False), run(True)):
+        np.testing.assert_array_equal(a, b)
 
 
 # -- nightly int4 drift study + TPU microbench ------------------------------

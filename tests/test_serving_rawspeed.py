@@ -241,46 +241,56 @@ def test_kv_int8_roundtrip_bound():
 def test_kv_int8_logit_drift_bounded_vs_native(setup):
     """Seeded small model, identical prompts admitted into a native
     and an int8 paged engine: the next-token logits off the quantized
-    cache must stay within a small fraction of the native logit range,
-    and greedy generations must mostly agree (the 0.9 bar the int8
-    weight path also meets)."""
+    cache must stay within a small fraction of the native logit range
+    — right after the prefill AND along a 12-token continuation
+    teacher-forced into both engines, so the quantized K/V that decode
+    itself appends is held to the same bound.
+
+    The bound is on logits, not on greedy tokens: a random-weight
+    model's top-2 margins are smaller than any rounding, so its argmax
+    flips on noise the bound allows (token agreement on a model with
+    real margins is the bench's fitted-chain gate,
+    serving_bench._fit_chain_model)."""
     from dlrover_tpu.serving.model import verify_step
 
     cfg, variables = setup
     prompts = _prompts(cfg, 2, 24, seed=11)
+    steps = 12
 
     def admitted(kv_dtype):
         eng = _engine(setup, paged=True, block_size=8,
                       kv_dtype=kv_dtype)
         for p in prompts:
-            eng.add_request(p, 8)
+            eng.add_request(p, steps + 4)
         eng._admit()
         if eng._table_dirty:
             eng._push_table()
+        return eng
+
+    ref_eng, quant_eng = admitted(None), admitted("int8")
+    np.testing.assert_array_equal(
+        ref_eng._positions, quant_eng._positions)
+    # the SAME tokens into both: the native engine's committed token,
+    # then a seeded continuation
+    forced = np.random.RandomState(5).randint(
+        0, cfg.vocab_size, (len(prompts), steps)).astype(np.int32)
+    forced[:, 0] = ref_eng._tokens
+
+    def logits_along(eng):
         logits, _ = verify_step(
-            eng.params, cfg, eng._cache,
-            jnp.asarray(eng._tokens[:, None]),
-            jnp.asarray(eng._positions),
+            eng.params, cfg, eng._cache, jnp.asarray(forced),
+            jnp.asarray(ref_eng._positions),
         )
-        return np.asarray(logits[:, 0, :]), eng
+        return np.asarray(logits)               # [B, steps, V]
 
-    ref, _ = admitted(None)
-    quant, _ = admitted("int8")
+    ref, quant = logits_along(ref_eng), logits_along(quant_eng)
+    assert ref.shape == (len(prompts), steps, cfg.vocab_size)
     spread = float(ref.max() - ref.min())
-    drift = float(np.max(np.abs(quant - ref)))
-    assert drift <= 0.05 * spread, (drift, spread)
-
-    def gen(kv_dtype):
-        eng = _engine(setup, paged=True, block_size=8,
-                      kv_dtype=kv_dtype)
-        rids = [eng.add_request(p, 12) for p in prompts]
-        res = eng.run()
-        return [res[r] for r in rids]
-
-    agree = np.mean([
-        np.mean(a == b) for a, b in zip(gen(None), gen("int8"))
-    ])
-    assert agree >= 0.9, agree
+    drift = np.max(np.abs(quant - ref), axis=(0, 2))   # per position
+    assert drift[0] <= 0.05 * spread, (drift[0], spread)
+    assert drift.max() <= 0.05 * spread, (drift, spread)
+    # and the quantized cache is really in play: not bit-identical
+    assert drift.max() > 0.0
 
 
 def test_kv_int8_budget_multiplier_feeds_pool_and_ledger(setup):

@@ -1,0 +1,160 @@
+"""The main paths' Pallas kernels, compiled for a TPU v5e that is
+DESCRIBED, not attached (the ``on-chip-measurement`` guide, section 2).
+
+Interpret-mode parity cannot see what the chip's compiler refuses — a
+slice off the tiling, a kernel GSPMD cannot partition — and PR 21 found
+both in this tree.  These compiles guard every later PR at no chip time.
+Nothing runs, so nothing here says anything about results or speed.
+
+Rules of this file: the topology is described inside a module-scoped
+fixture (never at import or collection — only one process may load the
+TPU library, and every xdist worker imports every test file), the
+compiles run in the test's own process, everything stays in this ONE
+file, and JAX's persistent compilation cache is off around them (an
+entry compiled for a described chip cannot be read back without one).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from dlrover_tpu.ops.attention import dot_product_attention
+from dlrover_tpu.ops.pallas.flash_attention import flash_attention
+from dlrover_tpu.ops.pallas.paged_attention import (
+    INT4_REFUSAL,
+    paged_decode_attention,
+)
+from dlrover_tpu.ops.pallas.quant_matmul import int8_matmul
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _attn_loss(fn):
+    def loss(q, k, v, seg):
+        return fn(q, k, v, seg).astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("segments", [False, True])
+def test_flash_fwd_bwd_7b_width_one_chip(one_chip, segments):
+    """llama2_7b attention (32 MHA heads of 128) at seq 4096, forward
+    and backward, with and without segment ids."""
+    x = jax.ShapeDtypeStruct((1, 4096, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=one_chip) \
+        if segments else None
+    grad = _attn_loss(lambda q, k, v, s: flash_attention(
+        q, k, v, causal=True, segment_ids=s))
+    text = jax.jit(grad).lower(x, x, x, seg).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_flash_under_fsdp_mesh_is_shard_mapped(topo):
+    """The default four-chip mesh (fsdp=4): the dispatch must wrap the
+    kernel in shard_map over the batch — a bare Mosaic call on GSPMD
+    arrays is refused ('cannot be automatically partitioned') — and
+    must not gather q/k/v to do it.  ``use_pallas=True`` is passed
+    because ``jax.default_backend()`` is the CPU here."""
+    from dlrover_tpu.accel.parallel.mesh import MeshSpec
+
+    mesh = MeshSpec.for_device_count(4).build_mesh(topo.devices)
+    assert dict(mesh.shape)["fsdp"] == 4
+    sharding = NamedSharding(mesh, P(("dp", "fsdp"), None, "tp", None))
+    x = jax.ShapeDtypeStruct((4, 4096, 32, 128), jnp.bfloat16,
+                             sharding=sharding)
+    grad = _attn_loss(lambda q, k, v, s: dot_product_attention(
+        q, k, v, causal=True, use_pallas=True))
+    with mesh:
+        compiled = jax.jit(grad).lower(x, x, x, None).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-gather" not in text
+    # each device works on its own quarter of the batch
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    assert per_device == 3 * (4096 * 32 * 128 * 2)
+
+
+# (q heads, kv heads): the bench geometry (h2048, GQA 16:4) and the
+# smoke's (llama2_7b, 32 MHA heads); head_dim 128, 16-row pages
+_GEOMETRIES = {"bench16x4": (16, 4), "llama2_7b": (32, 32)}
+
+
+def _paged_args(one_chip, heads, kv_heads, kv_dtype, d=128, bs=16,
+                nb=512, slots=8, mb=32):
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    code = {"bf16": (d, jnp.bfloat16), "int8": (d, jnp.int8),
+            "int4": (d // 2, jnp.int8)}[kv_dtype]
+    pool = s((nb, bs, kv_heads, code[0]), code[1])
+    scale = None if kv_dtype == "bf16" else \
+        s((nb, bs, kv_heads), jnp.bfloat16)
+    return (s((slots, heads, d), jnp.bfloat16), pool, pool,
+            s((slots, mb), jnp.int32), s((slots,), jnp.int32),
+            scale, scale)
+
+
+def _paged(q, k, v, table, lengths, ks, vs):
+    return paged_decode_attention(q, k, v, table, lengths,
+                                  k_scale=ks, v_scale=vs)
+
+
+@pytest.mark.parametrize("geometry", sorted(_GEOMETRIES))
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_paged_decode_kernel_compiles(one_chip, geometry, kv_dtype):
+    """bf16 and int8 pools stream in place.  int8 was refused before
+    PR 21 ('Slice shape along dimension 2 must be aligned to tiling
+    (128), but is 4': the per-token scale slice)."""
+    args = _paged_args(one_chip, *_GEOMETRIES[geometry], kv_dtype)
+    text = jax.jit(_paged).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_paged_decode_int4_is_refused_loudly(one_chip):
+    """Packed int4 pools do not compile on a TPU (minor dimension 64);
+    until the pool is re-laid the kernel refuses in the repo's own
+    words, at trace time — it never runs the gather under its name."""
+    args = _paged_args(one_chip, *_GEOMETRIES["bench16x4"], "int4")
+    with pytest.raises(NotImplementedError) as err:
+        jax.jit(_paged).lower(*args)
+    assert str(err.value) == INT4_REFUSAL
+
+
+@pytest.mark.parametrize("rows", [8, 4096])
+def test_int8_matmul_compiles(one_chip, rows):
+    """The W8A8 projection at llama2_7b's MLP width, at a decode row
+    count (padded to one 128-row block) and a prefill one."""
+    m = max(rows, 128)
+    a = jax.ShapeDtypeStruct((m, 4096), jnp.bfloat16, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((4096, 11008), jnp.bfloat16,
+                             sharding=one_chip)
+    fn = jax.jit(lambda a, b: int8_matmul(
+        a, b, block_m=128, block_n=256, block_k=512))
+    assert "tpu_custom_call" in fn.lower(a, b).compile().as_text()
