@@ -477,10 +477,13 @@ def accelerate(
             # when mask density varies across microbatches.
             def micro_step(carry, mb):
                 loss_acc, grad_acc, w_acc = carry
-                (loss, aux), grads = grad_fn(state.params, mb)
-                w = aux["weight"]
-                grads = jax.tree_util.tree_map(lambda g: g * w, grads)
-                return (loss_acc + loss * w, _tree_add(grad_acc, grads), w_acc + w), None
+                with jax.named_scope("loss_and_grad"):
+                    (loss, aux), grads = grad_fn(state.params, mb)
+                with jax.named_scope("grad_accum"):
+                    w = aux["weight"]
+                    grads = jax.tree_util.tree_map(lambda g: g * w, grads)
+                    return (loss_acc + loss * w, _tree_add(grad_acc, grads),
+                            w_acc + w), None
 
             zero_grads = jax.tree_util.tree_map(
                 lambda x: jnp.zeros(x.shape, jnp.float32), state.params
@@ -489,12 +492,16 @@ def accelerate(
             (loss_sum, grads, w_sum), _ = jax.lax.scan(
                 micro_step, (zero, zero_grads, zero), batch
             )
-            inv = 1.0 / w_sum
-            loss = loss_sum * inv
-            grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
+            with jax.named_scope("grad_accum"):
+                inv = 1.0 / w_sum
+                loss = loss_sum * inv
+                grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
         else:
-            (loss, _), grads = grad_fn(state.params, batch)
-        new_state = state.apply_gradients(grads=grads)
+            with jax.named_scope("loss_and_grad"):
+                (loss, _), grads = grad_fn(state.params, batch)
+        # clipping is the first link of the optimizer chain (see above)
+        with jax.named_scope("optimizer"):
+            new_state = state.apply_gradients(grads=grads)
         metrics = {
             "loss": loss,
             "grad_norm": optax.global_norm(grads),

@@ -320,6 +320,9 @@ def paged_decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, d), jnp.float32),
         interpret=interpret,
+        # the name of the kernel's instruction in a device trace
+        # (``paged_decode_attention.<n>``), whatever wraps the call
+        name="paged_decode_attention",
     )(table.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
     return out.reshape(b, h, d)
 

@@ -39,6 +39,7 @@ from dlrover_tpu.models.llama import LlamaConfig
 from dlrover_tpu.rl.generation import select_token
 from dlrover_tpu.serving.model import decode_step, prefill
 from dlrover_tpu.serving.params import serving_params_from_llama
+from dlrover_tpu.utils.profiler import span, spanned
 
 # dlint DL012 contract: a lifetime allocation is owned by the admitting
 # path until it is bound to a slot (whose release funnel is
@@ -508,10 +509,11 @@ class InferenceEngine:
                 pos = jnp.where(active, pos + 1, pos)
                 return (toks, pos, cache, key), nxt
 
-            (tokens, positions, cache, rng), out = jax.lax.scan(
-                step, (tokens, positions, cache, rng), None,
-                length=n_steps,
-            )
+            with jax.named_scope("decode_chunk"):
+                (tokens, positions, cache, rng), out = jax.lax.scan(
+                    step, (tokens, positions, cache, rng), None,
+                    length=n_steps,
+                )
             return out.T, tokens, positions, cache, rng
 
         paged = self.paged
@@ -532,7 +534,8 @@ class InferenceEngine:
             serves every skip value; the dense layout has no sharing
             and ignores it."""
             lp = tokens.shape[1]
-            logits, ks, vs = prefill(params, cfg, tokens, real_len)
+            with jax.named_scope("prefill"):
+                logits, ks, vs = prefill(params, cfg, tokens, real_len)
             if paged and kv_quant:
                 from dlrover_tpu.serving.paged import (
                     scatter_tokens_q,
@@ -605,9 +608,10 @@ class InferenceEngine:
                 at [G, prefill_chunk]).  ``last_idx`` picks the single
                 position whose logits feed sampling; the host uses the
                 sampled token only for the FINAL chunk."""
-                logits, cache = verify_step(
-                    params, cfg, cache, tokens, start,
-                    slots=slots, logits_index=last_idx)
+                with jax.named_scope("prefill_chunk"):
+                    logits, cache = verify_step(
+                        params, cfg, cache, tokens, start,
+                        slots=slots, logits_index=last_idx)
                 rng, sub = jax.random.split(rng)
                 first = select_token(
                     logits[:, 0, :], sub, temperature, top_k, top_p)
@@ -623,8 +627,9 @@ class InferenceEngine:
             @functools.partial(jax.jit, donate_argnums=(1,))
             def spec_fn(params, cache, tokens, positions, draft_len,
                         rng):
-                logits, cache = verify_step(
-                    params, cfg, cache, tokens, positions)
+                with jax.named_scope("verify"):
+                    logits, cache = verify_step(
+                        params, cfg, cache, tokens, positions)
                 rng, sub = jax.random.split(rng)
                 out, n_commit = rejection_commit(
                     logits, tokens[:, 1:], draft_len, sub,
@@ -710,6 +715,7 @@ class InferenceEngine:
         self._queue.append(Request(rid, prompt, int(max_new_tokens)))
         return rid
 
+    @spanned("dlrover.engine.admit")
     def _admit(self) -> None:
         """Admit waiting requests into free slots.  Consecutive queue
         entries whose prompts land in the SAME length bucket prefill as
@@ -775,12 +781,14 @@ class InferenceEngine:
                      if self.paged
                      else np.zeros(len(group), np.int32))
             t0 = time.perf_counter()
-            self._cache, firsts, self._rng = self._insert_fn(
-                self.params, self._cache, jnp.asarray(padded),
-                jnp.asarray(lens), jnp.asarray(slots, jnp.int32),
-                jnp.asarray(skips), self._rng,
-            )
-            firsts = np.asarray(firsts)
+            with span("dlrover.engine.prefill", bucket=bucket,
+                      n=len(group)):
+                self._cache, firsts, self._rng = self._insert_fn(
+                    self.params, self._cache, jnp.asarray(padded),
+                    jnp.asarray(lens), jnp.asarray(slots, jnp.int32),
+                    jnp.asarray(skips), self._rng,
+                )
+                firsts = np.asarray(firsts)
             self.stats.prefill_seconds += time.perf_counter() - t0
             self.stats.prefill_calls += 1
             self.stats.prefill_admissions += len(group)
@@ -943,20 +951,23 @@ class InferenceEngine:
         if self.paged and self._table_dirty:
             self._push_table()
         t0 = time.perf_counter()
-        self._cache, firsts, self._rng = self._prefill_chunk_fn(
-            self.params, self._cache, jnp.asarray(chunk),
-            jnp.asarray(starts),
-            jnp.asarray(slots, jnp.int32),
-            jnp.asarray(last_idx),
-            self._rng,
-        )
+        with span("dlrover.engine.prefill_chunk", n=g):
+            self._cache, firsts, self._rng = self._prefill_chunk_fn(
+                self.params, self._cache, jnp.asarray(chunk),
+                jnp.asarray(starts),
+                jnp.asarray(slots, jnp.int32),
+                jnp.asarray(last_idx),
+                self._rng,
+            )
+            # the sync belongs to the dispatch it waits for, as in
+            # _admit and the decode chunk
+            firsts = np.asarray(firsts)
         dt = time.perf_counter() - t0
         self.stats.prefill_seconds += dt
         self.stats.prefill_chunk_seconds += dt
         self.stats.prefill_calls += 1
         self.stats.prefill_chunks += 1
         self.stats.prefill_chunk_slots += g
-        firsts = np.asarray(firsts)
         for i, s in enumerate(slots):
             req = self._slot_req[s]
             end = int(ends[i])
@@ -1035,6 +1046,7 @@ class InferenceEngine:
                 return True
         return True
 
+    @spanned("dlrover.engine.push_table")
     def _push_table(self) -> None:
         table = jnp.asarray(self._table_np)
         if self.mesh is not None:
@@ -1086,6 +1098,7 @@ class InferenceEngine:
         return bool(self._queue) or any(
             r is not None for r in self._slot_req)
 
+    @spanned("dlrover.engine.step")
     def step(self) -> List[Request]:
         """Admit waiting requests, advance at most ONE bounded prefill
         chunk, run one decode chunk (or speculative verify), return
@@ -1108,40 +1121,48 @@ class InferenceEngine:
             if self.paged and self._table_dirty:
                 self._push_table()
             t0 = time.perf_counter()
-            out, tokens, positions, self._cache, self._rng = \
-                self._chunk_fn(
-                    self.params, self._cache,
-                    jnp.asarray(self._tokens), jnp.asarray(self._positions),
-                    jnp.asarray(active), self._rng,
-                )
-            out = np.asarray(out)                       # [B, chunk]
-            # copies: jax->numpy views are read-only, but _admit mutates
-            self._tokens = np.array(tokens)
-            self._positions = np.array(positions)
+            with span("dlrover.engine.decode_chunk"):
+                out, tokens, positions, self._cache, self._rng = \
+                    self._chunk_fn(
+                        self.params, self._cache,
+                        jnp.asarray(self._tokens),
+                        jnp.asarray(self._positions),
+                        jnp.asarray(active), self._rng,
+                    )
+                out = np.asarray(out)                       # [B, chunk]
+                # copies: jax->numpy views are read-only, but _admit
+                # mutates
+                self._tokens = np.array(tokens)
+                self._positions = np.array(positions)
             self.stats.decode_seconds += time.perf_counter() - t0
             self.stats.decode_forwards += self.chunk
-            for s in range(self.max_slots):
-                req = self._slot_req[s]
-                if req is None or self._prefilling[s]:
-                    continue
-                take = min(self.chunk, int(self._remaining[s]))
-                toks = out[s, :take].tolist()
-                if self.eos_token is not None and self.eos_token in toks:
-                    toks = toks[: toks.index(self.eos_token) + 1]
-                req.output.extend(toks)
-                if self._spec_fn is not None and toks:
-                    # keep the draft-lookup context fresh so a later
-                    # switch back to speculation sees these tokens
-                    n = int(self._ctx_len[s])
-                    end = min(n + len(toks), self._ctx_buf.shape[1])
-                    self._ctx_buf[s, n:end] = toks[: end - n]
-                    self._ctx_len[s] = end
-                self._remaining[s] -= len(toks)
-                self.stats.generated_tokens += len(toks)
-                self._finish_if_done(s, toks[-1] if toks else -1)
+            self._deliver_chunk(out)
             if self._spec_fn is not None:
                 self._after_chunk_round()
         return self._finished[before:]
+
+    @spanned("dlrover.engine.deliver")
+    def _deliver_chunk(self, out: np.ndarray) -> None:
+        """Hand a decode chunk's tokens ([B, chunk]) to their requests."""
+        for s in range(self.max_slots):
+            req = self._slot_req[s]
+            if req is None or self._prefilling[s]:
+                continue
+            take = min(self.chunk, int(self._remaining[s]))
+            toks = out[s, :take].tolist()
+            if self.eos_token is not None and self.eos_token in toks:
+                toks = toks[: toks.index(self.eos_token) + 1]
+            req.output.extend(toks)
+            if self._spec_fn is not None and toks:
+                # keep the draft-lookup context fresh so a later
+                # switch back to speculation sees these tokens
+                n = int(self._ctx_len[s])
+                end = min(n + len(toks), self._ctx_buf.shape[1])
+                self._ctx_buf[s, n:end] = toks[: end - n]
+                self._ctx_len[s] = end
+            self._remaining[s] -= len(toks)
+            self.stats.generated_tokens += len(toks)
+            self._finish_if_done(s, toks[-1] if toks else -1)
 
     def _after_chunk_round(self) -> None:
         """Speculation governor, chunk-decode side: count down a
@@ -1195,43 +1216,45 @@ class InferenceEngine:
         if self.paged and self._table_dirty:
             self._push_table()
         t0 = time.perf_counter()
-        out, n_commit, self._cache, self._rng = self._spec_fn(
-            self.params, self._cache, jnp.asarray(tokens),
-            jnp.asarray(self._positions), jnp.asarray(draft_lens),
-            self._rng,
-        )
-        out = np.asarray(out)
-        n_commit = np.asarray(n_commit)
+        with span("dlrover.engine.verify"):
+            out, n_commit, self._cache, self._rng = self._spec_fn(
+                self.params, self._cache, jnp.asarray(tokens),
+                jnp.asarray(self._positions), jnp.asarray(draft_lens),
+                self._rng,
+            )
+            out = np.asarray(out)
+            n_commit = np.asarray(n_commit)
         self.stats.decode_seconds += time.perf_counter() - t0
         self.stats.spec_calls += 1
         self.stats.decode_forwards += 1
         round_proposed = 0
         round_accepted = 0
-        for s in range(self.max_slots):
-            req = self._slot_req[s]
-            if req is None or self._prefilling[s]:
-                continue
-            accepted = int(n_commit[s]) - 1
-            round_proposed += int(draft_lens[s])
-            round_accepted += accepted
-            self.stats.spec_proposed += int(draft_lens[s])
-            self.stats.spec_accepted += accepted
-            toks = out[s, : accepted + 1].tolist()
-            take = min(len(toks), int(self._remaining[s]))
-            toks = toks[:take]
-            if self.eos_token is not None and self.eos_token in toks:
-                toks = toks[: toks.index(self.eos_token) + 1]
-            if not toks:
-                continue
-            req.output.extend(toks)
-            n = int(self._ctx_len[s])
-            self._ctx_buf[s, n:n + len(toks)] = toks
-            self._ctx_len[s] = n + len(toks)
-            self._remaining[s] -= len(toks)
-            self.stats.generated_tokens += len(toks)
-            self._tokens[s] = toks[-1]
-            self._positions[s] += len(toks)
-            self._finish_if_done(s, toks[-1])
+        with span("dlrover.engine.deliver"):
+            for s in range(self.max_slots):
+                req = self._slot_req[s]
+                if req is None or self._prefilling[s]:
+                    continue
+                accepted = int(n_commit[s]) - 1
+                round_proposed += int(draft_lens[s])
+                round_accepted += accepted
+                self.stats.spec_proposed += int(draft_lens[s])
+                self.stats.spec_accepted += accepted
+                toks = out[s, : accepted + 1].tolist()
+                take = min(len(toks), int(self._remaining[s]))
+                toks = toks[:take]
+                if self.eos_token is not None and self.eos_token in toks:
+                    toks = toks[: toks.index(self.eos_token) + 1]
+                if not toks:
+                    continue
+                req.output.extend(toks)
+                n = int(self._ctx_len[s])
+                self._ctx_buf[s, n:n + len(toks)] = toks
+                self._ctx_len[s] = n + len(toks)
+                self._remaining[s] -= len(toks)
+                self.stats.generated_tokens += len(toks)
+                self._tokens[s] = toks[-1]
+                self._positions[s] += len(toks)
+                self._finish_if_done(s, toks[-1])
         # governor: measured low acceptance -> back off to chunk decode
         # (a missing draft costs one wasted verify's worth of drafts
         # every round; backing off makes the miss genuinely free)

@@ -151,6 +151,10 @@ class ServingRequest:
     # the next router step — queued requests are dropped, in-flight
     # ones are aborted and a CANCEL is sent to the owning replica
     cancel_requested: bool = False
+    # when the first token of the current attempt was seen: the TOKEN
+    # frame's receive time for a remote replica; for an in-process one
+    # the ``now`` its router step BEGAN with, so up to one engine step
+    # EARLY (``last_delivery_at`` is the clock read at the hand-over)
     first_token_at: Optional[float] = None
     ttft_recorded: bool = False            # metrics bookkeeping
     finished_at: Optional[float] = None
@@ -160,6 +164,12 @@ class ServingRequest:
     # the signal the hedging sweep compares against its adaptive delay
     dispatched_at: Optional[float] = None
     last_token_at: Optional[float] = None
+    # deliveries of the current attempt: how many times tokens were
+    # handed to this request, and ``time.monotonic()`` READ AT the
+    # newest hand-over (never a caller's ``now``) — first-token time
+    # and token gaps as a client sees them come from these two
+    deliveries: int = 0
+    last_delivery_at: Optional[float] = None
     # hedging stream gate: None = the single attempt streams normally;
     # a (replica_name, engine_rid) pair = ONLY that attempt's tokens
     # reach the client stream (the hedge attempt races silently and
@@ -222,6 +232,13 @@ class ServingRequest:
     def total_len(self) -> int:
         return int(self.prompt.size) + int(self.max_new_tokens)
 
+    @property
+    def admitted_at(self) -> Optional[float]:
+        """When a replica took the current attempt (its ``dispatched_at``,
+        ``time.monotonic()`` as its engine accepted it); None while
+        queued.  ``admitted_at - enqueued_at`` is the wait in the queue."""
+        return self.dispatched_at
+
     # ------------------------------------------------------- streaming
     def push_tokens(self, tokens: List[int], now: float) -> None:
         """Tokens newly emitted for this request.  The FIRST push of an
@@ -235,9 +252,14 @@ class ServingRequest:
             if self.trace is not None:
                 self.trace.first_token(now)
         self.last_token_at = now
+        self._delivered()
         self.output.extend(tokens)
         self._streamed += len(tokens)
         self._events.put(("tokens", list(tokens)))
+
+    def _delivered(self) -> None:
+        self.deliveries += 1
+        self.last_delivery_at = time.monotonic()
 
     def finish(self, output: List[int], now: float) -> None:
         if self.state in SERVING_REQUEST_TERMINAL_STATES:
@@ -251,6 +273,7 @@ class ServingRequest:
         if len(output) > self._streamed:
             # engines without incremental emission (or a final flush
             # race) still complete the stream before it closes
+            self._delivered()
             self._events.put(("tokens", output[self._streamed:]))
         if self.first_token_at is None:
             self.first_token_at = now
@@ -322,6 +345,8 @@ class ServingRequest:
         # dispatch starts unhedged with a fresh progress clock
         self.dispatched_at = None
         self.last_token_at = None
+        self.deliveries = 0
+        self.last_delivery_at = None
         self.stream_owner = None
         self._events.put(("restart", None))
 

@@ -75,6 +75,7 @@ from dlrover_tpu.serving.router.replica import (
 )
 from dlrover_tpu.serving.router.scheduler import ContinuousBatchScheduler
 from dlrover_tpu.serving.tenancy.registry import TENANT_CLASSES
+from dlrover_tpu.utils.profiler import PhaseSpans, span
 
 
 def _tid(req: ServingRequest) -> Optional[str]:
@@ -348,10 +349,11 @@ class ServingRouter:
         tenant: Optional[str] = None,
     ) -> ServingRequest:
         try:
-            req = self.gateway.submit(
-                prompt_ids, max_new_tokens, priority=priority,
-                timeout=timeout, now=now, tenant=tenant,
-            )
+            with span("dlrover.router.submit"):
+                req = self.gateway.submit(
+                    prompt_ids, max_new_tokens, priority=priority,
+                    timeout=timeout, now=now, tenant=tenant,
+                )
         except Exception:
             self.metrics.rejected = self.gateway.rejected
             raise
@@ -361,6 +363,19 @@ class ServingRouter:
     # ----------------------------------------------------------- pump
     def step(self, now: Optional[float] = None) -> List[ServingRequest]:
         """One router round; returns the requests completed by it."""
+        # in the profiler's trace: the round, and under it one span per
+        # phase (``dlrover.router.phase.<name>``, the names of the
+        # step-phase histogram); each replica's pump is a span of its
+        # own under the pump phase
+        phases = PhaseSpans("dlrover.router.phase.")
+        with span("dlrover.router.step"):
+            try:
+                return self._step_round(now, phases)
+            finally:
+                phases.close()
+
+    def _step_round(self, now: Optional[float],
+                    phases: PhaseSpans) -> List[ServingRequest]:
         now = time.monotonic() if now is None else now
         perf = time.perf_counter
         phase = self.metrics.observe_step_phase
@@ -368,7 +383,12 @@ class ServingRouter:
         # profiler so its samples landing on this thread mid-step know
         # which phase they hit (noop call per phase when unattached)
         prof = self.profiler
-        mark = prof.set_phase if prof is not None else _noop_phase
+        set_phase = prof.set_phase if prof is not None else _noop_phase
+
+        def mark(name: Optional[str]) -> None:
+            set_phase(name)
+            phases.enter(name)
+
         # live tenant-spec reload, OUTSIDE the step lock (file I/O):
         # requested by SIGHUP or an admin endpoint, applied here so the
         # new contracts are in force for this round's admissions
@@ -598,7 +618,8 @@ class ServingRouter:
             completed: List[ServingRequest] = []
             for handle in self.manager.pumpable():
                 try:
-                    done = handle.pump(now)
+                    with span("dlrover.router.pump", replica=handle.name):
+                        done = handle.pump(now)
                 except ReplicaDeadError:
                     self._reap(now, dumps=dumps)
                     continue

@@ -46,6 +46,7 @@ from dlrover_tpu.trainer.flash_checkpoint import (
     StorageType,
 )
 from dlrover_tpu.utils.compile_cache import ensure_compile_cache
+from dlrover_tpu.utils.profiler import span, step_span
 
 # accelerate() results keyed by (mesh dims, accum, batch shape, seq, model
 # id) — a restarted process starts cold, but within one process an
@@ -423,17 +424,26 @@ class ElasticTrainer:
     def train_step(self, batch: Any) -> Dict[str, jax.Array]:
         assert self.state is not None, "call restore_or_init() first"
         t0 = time.time()
-        shaped = self._shape_batch(batch)
-        if self.auto_profiler is not None:
-            self.state, metrics = self.auto_profiler.around_step(
-                lambda: self.result.train_step(self.state, shaped)
-            )
-        else:
-            self.state, metrics = self.result.train_step(
-                self.state, shaped
-            )
-        self._host_step += 1
-        self._report_runtime_metrics(time.time() - t0)
+        # named by the step this call completes: the number maybe_save
+        # and the checkpoint's spans carry for the same step
+        with step_span("dlrover.trainer.step", self._host_step + 1):
+            with span("dlrover.trainer.shape_batch"):
+                shaped = self._shape_batch(batch)
+
+            def dispatch():
+                # the call of the jitted step until it RETURNS: the
+                # enqueue, and whatever the runtime makes the caller
+                # wait for (a donated buffer in use, a full queue)
+                with span("dlrover.trainer.dispatch"):
+                    return self.result.train_step(self.state, shaped)
+
+            if self.auto_profiler is not None:
+                self.state, metrics = self.auto_profiler.around_step(
+                    dispatch)
+            else:
+                self.state, metrics = dispatch()
+            self._host_step += 1
+            self._report_runtime_metrics(time.time() - t0)
         return metrics
 
     def _report_runtime_metrics(self, elapsed: float) -> None:
@@ -494,10 +504,20 @@ class ElasticTrainer:
         except ValueError:
             pass  # not the main thread: rely on the pipeline barrier
 
+    @property
+    def checkpoint_engine(self):
+        """The flash-checkpoint engine (``ckpt_metrics()``, ``flush()``),
+        or None without a ``checkpoint_dir``.  Read-only: saves go
+        through :meth:`maybe_save` / :meth:`save`."""
+        return self._ckpt.engine if self._ckpt is not None else None
+
     def maybe_save(self, block: bool = False) -> bool:
         """Flash-checkpoint cadence: shm every ``save_memory_interval``
         steps, async disk persist every ``save_storage_interval``.
-        Returns True when a checkpoint was actually written.
+        Returns what the engine answered for a save that was due — False
+        when it could not take it (the previous commit still in flight
+        past its barrier, or a ``block=True`` commit that did not land) —
+        and False when none was due.
 
         ``block=True`` waits for the shm COMMIT (not just the staging
         hand-off) — required when the caller acknowledges consumed work
@@ -507,15 +527,19 @@ class ElasticTrainer:
         if self._ckpt is None:
             return False
         step = self.step
-        if self._save_storage_interval and step % self._save_storage_interval == 0:
-            self._ckpt.save_checkpoint(step, self.state, StorageType.DISK,
-                                       block=block)
-            return True
-        if self._save_memory_interval and step % self._save_memory_interval == 0:
-            self._ckpt.save_checkpoint(step, self.state, StorageType.MEMORY,
-                                       block=block)
-            return True
-        return False
+        tier = None
+        if self._save_storage_interval \
+                and step % self._save_storage_interval == 0:
+            tier = StorageType.DISK
+        elif self._save_memory_interval \
+                and step % self._save_memory_interval == 0:
+            tier = StorageType.MEMORY
+        with span("dlrover.trainer.maybe_save", due=int(tier is not None),
+                  tier=tier.name if tier is not None else "none"):
+            if tier is None:
+                return False
+            return self._ckpt.save_checkpoint(step, self.state, tier,
+                                              block=block)
 
     def save(self, storage_type: StorageType = StorageType.DISK) -> bool:
         if self._ckpt is None:

@@ -51,6 +51,7 @@ from dlrover_tpu.trainer.flash_checkpoint.shm_handler import (
     SharedMemoryHandler,
     leaf_paths,
 )
+from dlrover_tpu.utils.profiler import name_os_thread, span
 
 
 class SaverMode(str, Enum):
@@ -291,9 +292,11 @@ class CheckpointEngine:
         self.saves_staged = 0
         self.saves_committed = 0
         self.saves_collapsed = 0
+        self.saves_skipped = 0
         self.save_errors = 0
         self.inloop_pause_s_total = 0.0
         self.commit_s_total = 0.0
+        self.lock_wait_s_total = 0.0
         self.last_commit_s = 0.0
         self._save_error_streak = 0
         self._stage_skip_streak = 0
@@ -363,7 +366,22 @@ class CheckpointEngine:
             ok = self._save_to_memory_sync(step, state, _notify_storage)
             self.inloop_pause_s_total += time.perf_counter() - t0
             return ok
-        staged = self._snapshot_state(state)
+        with span("dlrover.ckpt.stage", step=step):
+            staged_ok = self._stage(step, state, _notify_storage)
+        self.inloop_pause_s_total += time.perf_counter() - t0
+        if not staged_ok:
+            return False
+        if block:
+            return self.flush(timeout=self._save_timeout) \
+                and self._latest_memory_step >= step
+        return True
+
+    def _stage(self, step: int, state: Any, notify_storage: bool) -> bool:
+        """The in-loop part of an async save: device snapshot, pipeline
+        barrier, hand-off to the writer.  False = skipped at the
+        barrier."""
+        with span("dlrover.ckpt.snapshot"):
+            staged = self._snapshot_state(state)
         # pipeline barrier: the previous save must commit before a new
         # one stages (at-most-one-behind crash-loss contract).  The wait
         # is BOUNDED SHORT: a normal in-flight copy finishes in well
@@ -372,7 +390,10 @@ class CheckpointEngine:
         # SKIP this save (the old "training never blocks on storage"
         # contract) instead of stalling the training loop for up to the
         # 600s save timeout.
-        if not self.flush(timeout=self.STAGE_BARRIER_S):
+        with span("dlrover.ckpt.barrier"):
+            idle = self.flush(timeout=self.STAGE_BARRIER_S)
+        if not idle:
+            self.saves_skipped += 1
             self._stage_skip_streak += 1
             if self._stage_skip_streak == 1:
                 logger.warning(
@@ -384,7 +405,6 @@ class CheckpointEngine:
             else:
                 logger.debug("step %s memory save skipped (streak %s)",
                              step, self._stage_skip_streak)
-            self.inloop_pause_s_total += time.perf_counter() - t0
             return False
         if self._stage_skip_streak:
             logger.info(
@@ -392,19 +412,15 @@ class CheckpointEngine:
                 step, self._stage_skip_streak,
             )
             self._stage_skip_streak = 0
-        with self._save_cv:
+        with span("dlrover.ckpt.handoff"), self._save_cv:
             self._ensure_writer()
             if self._pending is not None:  # raced another saver thread
                 _, _, prev_notify = self._pending
-                _notify_storage = _notify_storage or prev_notify
+                notify_storage = notify_storage or prev_notify
                 self.saves_collapsed += 1
-            self._pending = (step, staged, _notify_storage)
+            self._pending = (step, staged, notify_storage)
             self.saves_staged += 1
             self._save_cv.notify_all()
-        self.inloop_pause_s_total += time.perf_counter() - t0
-        if block:
-            return self.flush(timeout=self._save_timeout) \
-                and self._latest_memory_step >= step
         return True
 
     def _save_to_memory_sync(
@@ -466,18 +482,23 @@ class CheckpointEngine:
         self._writer_thread.start()
 
     def _writer_loop(self) -> None:
+        name_os_thread(threading.current_thread().name)
         while True:
             with self._save_cv:
                 while self._pending is None and not self._writer_stop:
                     self._save_cv.wait(timeout=1.0)
                 if self._writer_stop and self._pending is None:
                     return
-                step, state, notify = self._pending
+                # rebinding ``state`` lets go of the previous save's
+                # device snapshot, kept until now
+                with span("dlrover.ckpt.pickup"):
+                    step, state, notify = self._pending
                 self._pending = None
                 self._writer_busy = True
             try:
                 t0 = time.perf_counter()
-                self._commit_staged_save(step, state, notify)
+                with span("dlrover.ckpt.commit", step=step):
+                    self._commit_staged_save(step, state, notify)
                 self.last_commit_s = time.perf_counter() - t0
                 self.commit_s_total += self.last_commit_s
             except Exception as e:
@@ -507,8 +528,12 @@ class CheckpointEngine:
         # blocking here is fine — this is the writer thread, not the
         # training loop; the agent saver releases the lock when its
         # persist pass finishes
-        if not self._shm_lock.acquire(owner=owner,
-                                      timeout=self._save_timeout):
+        t0 = time.perf_counter()
+        with span("dlrover.ckpt.lock_wait"):
+            locked = self._shm_lock.acquire(owner=owner,
+                                            timeout=self._save_timeout)
+        self.lock_wait_s_total += time.perf_counter() - t0
+        if not locked:
             raise TimeoutError(
                 f"shm lock busy for {self._save_timeout}s (saver persist "
                 "wedged?); save skipped"
@@ -572,10 +597,21 @@ class CheckpointEngine:
             "dlrover_ckpt_saves_staged_total": float(self.saves_staged),
             "dlrover_ckpt_saves_committed_total": float(self.saves_committed),
             "dlrover_ckpt_saves_collapsed_total": float(self.saves_collapsed),
+            "dlrover_ckpt_saves_skipped_total": float(self.saves_skipped),
             "dlrover_ckpt_save_errors_total": float(self.save_errors),
             "dlrover_ckpt_inloop_pause_seconds_total": float(
                 self.inloop_pause_s_total),
             "dlrover_ckpt_commit_seconds_total": float(self.commit_s_total),
+            "dlrover_ckpt_lock_wait_seconds_total": float(
+                self.lock_wait_s_total),
+            # the writer's two copies, timed where they happen
+            # (shm_handler._write_generation)
+            "dlrover_ckpt_d2h_seconds_total": float(
+                self._shm_handler.d2h_s_total),
+            "dlrover_ckpt_shm_copy_seconds_total": float(
+                self._shm_handler.shm_copy_s_total),
+            "dlrover_ckpt_bytes_committed_total": float(
+                self._shm_handler.bytes_written_total),
             "dlrover_ckpt_committed_step": float(self._latest_memory_step),
         }
 

@@ -43,6 +43,7 @@ serving whichever bytes the buffer happens to hold.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import ml_dtypes  # noqa: F401  registers bfloat16/fp8 dtype NAMES with
@@ -52,6 +53,7 @@ import numpy as np
 
 from dlrover_tpu.common.log import default_logger as logger
 from dlrover_tpu.common.multi_process import SharedDict, SharedMemory
+from dlrover_tpu.utils.profiler import span
 
 _SHM_PREFIX = "dlrover_tpu_ckpt"
 
@@ -152,6 +154,12 @@ class SharedMemoryHandler:  # dlint: disable=DL011 worker restore and agent pers
         self._shm_names = {0: base, 1: f"{base}_g1"}
         self._meta = SharedDict(f"ckpt_meta_{local_rank}", create=create)
         self._shm: Dict[int, Optional[SharedMemory]] = {0: None, 1: None}
+        # where a generation's write time goes (the engine's
+        # ckpt_metrics() reads these): bringing the bytes to the host,
+        # and copying them into the segment
+        self.d2h_s_total = 0.0
+        self.shm_copy_s_total = 0.0
+        self.bytes_written_total = 0
 
     # -- write side (training process) ----------------------------------
     def save_state_dict(self, state: Any, step: int) -> None:
@@ -159,7 +167,9 @@ class SharedMemoryHandler:  # dlint: disable=DL011 worker restore and agent pers
         buffer first, then ONE atomic meta publish.  A writer death at
         any instant before the publish leaves the previous generation
         committed and readable."""
-        self._publish(self._write_generation(state, step))
+        record = self._write_generation(state, step)
+        with span("dlrover.ckpt.publish"):
+            self._publish(record)
 
     def _write_generation(self, state: Any, step: int) -> Dict[str, Any]:
         """Stage the payload of the NEXT generation into the inactive
@@ -173,41 +183,53 @@ class SharedMemoryHandler:  # dlint: disable=DL011 worker restore and agent pers
         # (reference engine.py: the async-copy half of its save pause).
         import jax
 
-        for leaf in jax.tree_util.tree_leaves(state):
-            if isinstance(leaf, jax.Array):
-                leaf.copy_to_host_async()
-        committed = self._meta.get() or {}
-        generation = int(committed.get("generation", 0)) + 1
-        buf = generation % self.NUM_BUFFERS
-        buffer_generations = dict(committed.get("buffer_generations") or {})
-        # commit marker, phase 1: record the attempt (a restore ignores
-        # ``inflight``; a postmortem reads inflight > generation as
-        # "a save died mid-copy")
-        self._meta.set({"inflight": generation})
-        pairs = leaf_paths(state)
-        metas: Dict[str, Dict] = {}
-        buffers: List[Tuple[int, np.ndarray]] = []
-        offset = 0
-        for path, leaf in pairs:
-            gshape, dtype, shard_metas, arrays = _local_shards(leaf)
-            for m, arr in zip(shard_metas, arrays):
-                arr = np.ascontiguousarray(arr)
-                m["offset"] = offset
-                m["nbytes"] = arr.nbytes
-                buffers.append((offset, arr))
-                offset += arr.nbytes
-            metas[path] = {
-                "global_shape": list(gshape),
-                "dtype": dtype,
-                "shards": shard_metas,
-            }
+        t_d2h = time.perf_counter()
+        with span("dlrover.ckpt.d2h_dispatch"):
+            for leaf in jax.tree_util.tree_leaves(state):
+                if isinstance(leaf, jax.Array):
+                    leaf.copy_to_host_async()
+        # from here the thread waits for the bytes: the commit marker's
+        # first phase and the shard walk run beside the copies in flight
+        with span("dlrover.ckpt.d2h_wait"):
+            committed = self._meta.get() or {}
+            generation = int(committed.get("generation", 0)) + 1
+            buf = generation % self.NUM_BUFFERS
+            buffer_generations = dict(
+                committed.get("buffer_generations") or {})
+            # commit marker, phase 1: record the attempt (a restore
+            # ignores ``inflight``; a postmortem reads inflight >
+            # generation as "a save died mid-copy")
+            self._meta.set({"inflight": generation})
+            pairs = leaf_paths(state)
+            metas: Dict[str, Dict] = {}
+            buffers: List[Tuple[int, np.ndarray]] = []
+            offset = 0
+            for path, leaf in pairs:
+                gshape, dtype, shard_metas, arrays = _local_shards(leaf)
+                for m, arr in zip(shard_metas, arrays):
+                    arr = np.ascontiguousarray(arr)
+                    m["offset"] = offset
+                    m["nbytes"] = arr.nbytes
+                    buffers.append((offset, arr))
+                    offset += arr.nbytes
+                metas[path] = {
+                    "global_shape": list(gshape),
+                    "dtype": dtype,
+                    "shards": shard_metas,
+                }
         total = offset
-        self._ensure_shm(total, buf)
+        self.d2h_s_total += time.perf_counter() - t_d2h
+        with span("dlrover.ckpt.shm_alloc"):
+            self._ensure_shm(total, buf)
         mv = self._shm[buf].buf
-        for off, arr in buffers:
-            # single host copy straight into shm (no tobytes() staging)
-            dst = np.ndarray(arr.shape, arr.dtype, buffer=mv, offset=off)
-            np.copyto(dst, arr)
+        t_copy = time.perf_counter()
+        with span("dlrover.ckpt.shm_copy", bytes=total):
+            for off, arr in buffers:
+                # single host copy straight into shm (no tobytes() staging)
+                dst = np.ndarray(arr.shape, arr.dtype, buffer=mv, offset=off)
+                np.copyto(dst, arr)
+        self.shm_copy_s_total += time.perf_counter() - t_copy
+        self.bytes_written_total += total
         buffer_generations[str(buf)] = generation
         return {
             "step": int(step),

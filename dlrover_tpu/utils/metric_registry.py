@@ -432,6 +432,27 @@ METRIC_HELP: Dict[str, str] = {
         "cumulative writer-thread time copying + publishing "
         "generations (overlapped with training, not a pause)"
     ),
+    "dlrover_ckpt_lock_wait_seconds_total": (
+        "cumulative writer-thread time waiting for the shm lock (the "
+        "agent's saver holds it while it persists); part of commit"
+    ),
+    "dlrover_ckpt_d2h_seconds_total": (
+        "cumulative writer-thread time bringing a generation's bytes "
+        "from the device to the host (copy dispatch + the wait for "
+        "them); part of commit"
+    ),
+    "dlrover_ckpt_shm_copy_seconds_total": (
+        "cumulative writer-thread time copying host bytes into the "
+        "shm segment; part of commit"
+    ),
+    "dlrover_ckpt_bytes_committed_total": (
+        "bytes written into shm generations by this process"
+    ),
+    "dlrover_ckpt_saves_skipped_total": (
+        "memory saves refused at staging because the previous commit "
+        "was still in flight past STAGE_BARRIER_S (training never "
+        "blocks on storage; the save is lost, not late)"
+    ),
     "dlrover_ckpt_committed_step": (
         "training step of the last fully-committed shm generation"
     ),

@@ -14,6 +14,9 @@ equivalents are:
 - :func:`trace` — ``jax.profiler`` trace capture (the XProf/``xplane``
   trace is the TPU analogue of the CUDA-event kernel timeline; view with
   TensorBoard);
+- :func:`span` — the program's own host spans (trainer step, checkpoint
+  stage and commit, engine step, router step) written into that same
+  trace, on the device's clock, whoever opened the profiler;
 - :class:`MetricsExporter` — a Prometheus text endpoint per process
   (``/metrics``), like xpu_timer's per-rank ``:38888+rank`` exporter.
 
@@ -24,6 +27,7 @@ profiler plugin, so the framework only adds the serving layer.
 from __future__ import annotations
 
 import contextlib
+import functools
 import http.server
 import json
 import random
@@ -286,14 +290,87 @@ class Histogram:
 @contextlib.contextmanager
 def trace(log_dir: str, host_tracer_level: int = 2):
     """Capture an XLA/XProf trace for the enclosed region (TensorBoard-
-    viewable) — the TPU analogue of xpu_timer's kernel timeline."""
+    viewable) — the TPU analogue of xpu_timer's kernel timeline.  The
+    program's :func:`span`s land in it; Python-level function tracing
+    is off (it slows the host it is meant to time)."""
     import jax
 
-    jax.profiler.start_trace(log_dir, host_tracer_level=host_tracer_level)
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = host_tracer_level
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=options)
     try:
         yield
     finally:
         jax.profiler.stop_trace()
+
+
+def span(name: str, **attrs):
+    """A host span in the profiler's OWN trace: a
+    ``jax.profiler.TraceAnnotation``, so it lands in the ``.xplane.pb``
+    of whichever session is open (:func:`trace`, the trainer's
+    ``AutoProfiler``, a benchmark's) on the clock of the device's
+    ``XLA Ops`` line, with ``attrs`` as the event's stats.  With no
+    session open it records nothing and costs about a microsecond, so
+    the program's spans (``dlrover.<layer>.<what>``) are unconditional."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name, **attrs)
+
+
+def spanned(name: str):
+    """Decorator: the whole call of a function as one :func:`span`."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with span(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
+
+
+def step_span(name: str, step: int):
+    """:func:`span` for one training step: a ``StepTraceAnnotation``,
+    which xprof's step views group device time by (stat ``step_num``)."""
+    from jax.profiler import StepTraceAnnotation
+
+    return StepTraceAnnotation(name, step_num=step)
+
+
+class PhaseSpans:
+    """Consecutive :func:`span`s of one function's phases, one open at a
+    time: ``enter(phase)`` closes the open one and opens
+    ``<prefix><phase>``; ``enter(None)`` / ``close()`` close the last.
+    For a long function that marks its phases at boundaries instead of
+    nesting them in ``with`` blocks (the router's step)."""
+
+    def __init__(self, prefix: str):
+        self._prefix = prefix
+        self._open = None
+
+    def enter(self, phase: Optional[str]) -> None:
+        self.close()
+        if phase is not None:
+            self._open = span(self._prefix + phase)
+            self._open.__enter__()
+
+    def close(self) -> None:
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+
+def name_os_thread(name: str) -> None:
+    """Give the calling thread ``name`` at the OS too (Linux
+    ``PR_SET_NAME``, 15 bytes): the profiler labels a thread's line in
+    the trace by its OS name, and Python sets none (every line reads
+    ``python``).  Call it first thing on the thread."""
+    import ctypes
+
+    try:
+        ctypes.CDLL(None).prctl(15, name.encode()[:15], 0, 0, 0)
+    except (OSError, AttributeError):
+        pass  # no prctl on this platform: the line keeps its default
 
 
 def escape_label_value(value: str) -> str:
@@ -415,7 +492,7 @@ class MetricsExporter:
 
     def add_text_source(self, fn) -> None:
         """``fn() -> str`` of ready-made Prometheus text appended at
-        scrape time (e.g. NativeTracer.export_prometheus)."""
+        scrape time (e.g. ``RouterMetrics.render_histograms``)."""
         self._text_sources.append(fn)
 
     def attach_tracer(self, tracer) -> None:

@@ -49,12 +49,12 @@ Fleet-scale additions (the observability plane):
   or any non-``ok`` terminal status (expiry, cancellation, poisoning)
   forces retention, so every incident keeps its full span tree even
   at 1% sampling;
-- **Chrome export** — :meth:`Tracer.export_chrome_trace` emits the
-  same trace-event JSON schema as the native tracer
-  (``NativeTracer.export_chrome_trace``), pid mapped to
-  router/replica and tid to the trace, so request spans and native
-  hot-section timers concatenate into one perfetto view
-  (:func:`~dlrover_tpu.utils.native_timer.merge_chrome_traces`).
+- **Chrome export** — :meth:`Tracer.export_chrome_trace` emits
+  Chrome trace-event JSON, pid mapped to router/replica and tid to
+  the trace: request trees across processes in one perfetto view.
+  (Where a process spends its time — step loop, checkpoint writer,
+  engine dispatches — is in the profiler's own trace, beside the
+  device's operations: ``utils/profiler.span``.)
 """
 
 from __future__ import annotations
@@ -571,12 +571,9 @@ class Tracer:
     # ---------------------------------------------- chrome-trace export
     def export_chrome_trace(self, trace_id: Optional[str] = None,
                             path: Optional[str] = None) -> str:
-        """Chrome trace-event JSON — the SAME schema the native tracer
-        emits (``NativeTracer.export_chrome_trace``: complete events
-        with ``name``/``ph``/``ts``/``dur``/``pid``/``tid``, µs
-        timestamps on the monotonic clock), so a request's spans, the
-        router's step loop and native hot-section timers concatenate
-        into one perfetto view (merge_chrome_traces).  ``pid`` maps to
+        """Chrome trace-event JSON: complete events with
+        ``name``/``ph``/``ts``/``dur``/``pid``/``tid``, µs timestamps
+        on the monotonic clock, loadable in perfetto.  ``pid`` maps to
         the process the span ran in (router vs each replica — worker
         spans are already clock-translated to router time at graft),
         ``tid`` to the trace, so concurrent requests land on separate

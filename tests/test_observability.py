@@ -336,67 +336,6 @@ def test_window_gauge_empty_window_rates_and_stats_are_zero():
     assert g.mean(now=100.0) == 0.0
 
 
-# -- native tracer (xpu_timer counterpart) ----------------------------------
-
-def _native_timer_or_skip():
-    from dlrover_tpu.utils import native_timer
-
-    reason = native_timer.check_toolchain()
-    if reason is not None:  # pragma: no cover
-        pytest.skip(f"native toolchain unavailable: {reason}")
-    return native_timer
-
-
-def test_native_tracer_spans_stats_and_exports(tmp_path):
-    nt = _native_timer_or_skip()
-    tracer = nt.NativeTracer(ring_capacity=256)
-    for _ in range(50):
-        with tracer.span("train_step"):
-            pass
-    t0 = tracer.now_ns()
-    tracer.record("ckpt_save", t0, t0 + 5_000_000)  # 5ms span
-    s = tracer.stats("train_step")
-    assert s["count"] == 50
-    assert s["p99_s"] >= s["p50_s"] >= 0
-    assert tracer.stats("ckpt_save")["max_s"] == pytest.approx(
-        0.005, rel=0.01)
-
-    prom = tracer.export_prometheus()
-    assert 'xputimer_span_count{name="train_step"} 50' in prom
-    path = str(tmp_path / "trace.json")
-    trace = json.loads(tracer.export_chrome_trace(path))
-    assert len(trace["traceEvents"]) == 51
-    assert json.load(open(path))["traceEvents"]
-
-
-def test_native_tracer_ring_wraps():
-    nt = _native_timer_or_skip()
-    tracer = nt.NativeTracer(ring_capacity=16)
-    for _ in range(40):
-        with tracer.span("s"):
-            pass
-    trace = json.loads(tracer.export_chrome_trace())
-    assert len(trace["traceEvents"]) == 16  # ring keeps the newest spans
-    assert tracer.stats("s")["count"] == 40  # aggregates keep everything
-
-
-def test_exporter_serves_native_tracer_text():
-    nt = _native_timer_or_skip()
-    tracer = nt.NativeTracer(ring_capacity=64)
-    with tracer.span("rpc"):
-        pass
-    exporter = MetricsExporter()
-    exporter.add_text_source(tracer.export_prometheus)
-    exporter.start()
-    try:
-        body = urllib.request.urlopen(
-            f"http://127.0.0.1:{exporter.port}/metrics", timeout=5
-        ).read().decode()
-        assert 'xputimer_span_count{name="rpc"} 1' in body
-    finally:
-        exporter.stop()
-
-
 # -- topology sorter --------------------------------------------------------
 
 def test_slice_topology_sorter_keeps_rank0_group_first():

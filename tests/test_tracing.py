@@ -562,39 +562,6 @@ def test_chrome_export_schema_and_pid_mapping():
     assert any(e["args"]["name"] == "router" for e in meta)
 
 
-def test_chrome_export_concatenates_with_native_tracer():
-    """The unified-view acceptance: a span-tracer export and a
-    NativeTracer export merge into ONE valid trace-event JSON."""
-    from dlrover_tpu.utils.native_timer import (
-        NativeTracer,
-        check_toolchain,
-        merge_chrome_traces,
-    )
-
-    if check_toolchain() is not None:
-        pytest.skip("native toolchain unavailable")
-    router = _local_router()
-    router.submit(_prompt(1), 8)
-    router.run_until_idle()
-    native = NativeTracer(ring_capacity=64)
-    with native.span("router.step"):
-        pass
-    merged = json.loads(merge_chrome_traces(
-        router.tracer.export_chrome_trace(),
-        native.export_chrome_trace(),
-    ))
-    events = merged["traceEvents"]
-    _assert_trace_events_schema(events)
-    names = {e["name"] for e in events}
-    assert "router.step" in names and "request" in names
-    # the two exports keep distinct pids (native pins pid 0, the span
-    # tracer starts at 1) so perfetto shows them as separate processes
-    native_pids = {e["pid"] for e in events
-                   if e["name"] == "router.step"}
-    span_pids = {e["pid"] for e in events if e["name"] == "request"}
-    assert native_pids.isdisjoint(span_pids)
-
-
 def test_traces_chrome_endpoint_serves_and_404s():
     router = _local_router()
     req = router.submit(_prompt(1), 8)
