@@ -67,14 +67,24 @@ Layout contract (matches serving/paged.py):
   k_scale  [NB, bs, KV]     per-(token, head) scales (quantized pools)
   v_scale  [NB, bs, KV]
   table    [B, MB] int32    per-slot block lists (0 = trash block)
-  lengths  [B]    int32     visible keys per slot (= position + 1)
+  lengths  [B]    int32     visible keys per slot (= position + 1;
+                            0 = the slot's output is not wanted)
 Returns [B, H, D] fp32.
 
-Pages past the slot's length still stream (static grid) but their
-scores are masked to -inf; with MB sized from the engine's max_len
-this is the same worst-case the dense layout always pays.  The table
-is padded to a multiple of ``pages_per_block`` with trash-block zeros
-— padded pages read harmless junk that the length mask discards.
+Only a slot's LIVE pages stream: each program's group loop runs
+``min(ceil(length / rows), groups)`` times (``rows`` = one group's
+``pages_per_block * bs`` key rows), a trip count read from ``lengths``
+at run time, so the kernel's traffic follows the context a slot holds
+and not the width its table was sized for.  Length 0 reads nothing
+and returns zeros — the engine passes it for a slot that is idle or
+parked while its prompt prefills, whose logits nobody reads.  The LAST
+live group streams whole: its rows past the length are masked to -inf,
+and its pages past the allocation are table zeros, the trash block,
+whose junk the mask discards (the table is padded with them to a
+multiple of ``pages_per_block``).  A length past the table's capacity
+reads the whole table and no further.  For every length >= 1 the
+output is bit-identical to running every group: a wholly dead group
+only ever multiplied the carry by ``alpha = 1`` and added ``p = 0``.
 """
 
 from __future__ import annotations
@@ -85,6 +95,7 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -152,10 +163,19 @@ def _decode_kernel(
         for c in _group_copies(g, slot):
             c.wait()
 
+    # the slot's LIVE groups: the loop below ends at the slot's length,
+    # not at the table's width, so a wholly dead group is never copied
+    # (a skipped group contributed alpha = 1, p = 0: nothing changes)
+    n_live = jnp.minimum(pl.cdiv(lengths_ref[b], rows), num_groups)
+
     m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
-    start_group(0, 0)                 # warm-up: first group in flight
+
+    @pl.when(n_live > 0)              # a DMA that starts is waited:
+    def _():                          # length 0 starts none
+        start_group(0, 0)             # warm-up: first group in flight
+
     qf = q_ref[0].astype(jnp.float32)            # [KV, G, D]
 
     def _codes(raw):
@@ -171,7 +191,7 @@ def _decode_kernel(
     def body(g, _):
         slot = jax.lax.rem(g, 2)
 
-        @pl.when(g + 1 < num_groups)
+        @pl.when(g + 1 < n_live)
         def _():                      # overlap: next group's DMA first
             start_group(g + 1, jax.lax.rem(g + 1, 2))
 
@@ -229,10 +249,30 @@ def _decode_kernel(
         m_scr[...] = m_new
         return 0
 
-    jax.lax.fori_loop(0, num_groups, body, 0)
+    jax.lax.fori_loop(0, n_live, body, 0)
     denom = jnp.maximum(l_scr[...], 1e-30)
     o_ref[0] = (acc_scr[...] / denom[:, None]).reshape(
         kv_heads, group, head_dim).astype(o_ref.dtype)
+
+
+def _page_groups(table_width: int, pages_per_block: int):
+    """``(pages per group, groups)`` a table of ``table_width`` pages is
+    cut into: the wrapper's padding and the kernel's trip count both
+    come from here."""
+    p_n = max(1, min(int(pages_per_block), table_width))
+    return p_n, -(-table_width // p_n)
+
+
+def streamed_rows(lengths, block_size: int, table_width: int,
+                  pages_per_block: int = 8) -> int:
+    """Key rows the kernel copies (K and V each) for slots of these
+    ``lengths``: whole groups up to each length, none for length 0,
+    never more than the table — the kernel's trip count as host
+    arithmetic, for a caller that books what it streams."""
+    p_n, num_groups = _page_groups(table_width, pages_per_block)
+    rows = p_n * block_size
+    groups = np.clip(-(-np.asarray(lengths) // rows), 0, num_groups)
+    return int(groups.sum()) * rows
 
 
 @functools.partial(
@@ -264,12 +304,11 @@ def paged_decode_attention(
     mb = table.shape[1]
     # pad the table to a multiple of the page-group size with zeros —
     # the trash block, whose junk the length mask discards
-    p_n = max(1, min(int(pages_per_block), mb))
-    pad = (-mb) % p_n
+    p_n, num_groups = _page_groups(mb, pages_per_block)
+    pad = num_groups * p_n - mb
     if pad:
         table = jnp.concatenate(
             [table, jnp.zeros((b, pad), table.dtype)], axis=1)
-    num_groups = (mb + pad) // p_n
     qg = q.reshape(b, kv, g, d)
 
     def q_map(bi, table_ref, lengths_ref):
@@ -441,7 +480,10 @@ def measure_paged_attention(
     """Best-of-``trials`` wall seconds for each impl on THESE operands
     — the one-shot measurement ``attention_impl="auto"`` runs at
     engine build (and the bench's crossover probe).  Both sides
-    compile first; the measured runs sync via block_until_ready."""
+    compile first; the measured runs sync via block_until_ready.
+    The kernel's time follows ``lengths`` (it streams live pages
+    only), the gather's the table's width: the engine hands in full
+    lengths, the kernel's worst case."""
     impls = {
         "xla": lambda: gather_reference(
             q, k_pool, v_pool, table, lengths, k_scale, v_scale),

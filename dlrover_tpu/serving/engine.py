@@ -91,6 +91,13 @@ class EngineStats:
     spec_accepted: int = 0        # draft tokens accepted
     spec_calls: int = 0           # verify dispatches (model forwards)
     decode_forwards: int = 0      # ALL decode-path model forwards
+    kv_rows_live: int = 0         # key rows the fused paged kernel's
+    #                               decoding slots could see, summed
+    #                               over slots and decode forwards
+    kv_rows_streamed: int = 0     # key rows that kernel copied for
+    #                               them: whole page groups up to each
+    #                               slot's length.  Both stay 0 where
+    #                               the gather path decodes
 
     @property
     def decode_tokens_per_sec(self) -> float:
@@ -112,6 +119,15 @@ class EngineStats:
         /metrics; ``tokens_per_forward`` is the derived win)."""
         return self.spec_accepted / self.spec_proposed \
             if self.spec_proposed else 0.0
+
+    @property
+    def kv_stream_ratio(self) -> float:
+        """Key rows the paged kernel copied per row a slot could see:
+        1.0 is a stream with no dead row, and running every group of
+        the table for every slot reads ``slots x table rows / live``
+        (0.0 before the kernel has decoded anything)."""
+        return self.kv_rows_streamed / self.kv_rows_live \
+            if self.kv_rows_live else 0.0
 
 
 def _bucket(n: int, buckets: Tuple[int, ...]) -> int:
@@ -460,7 +476,12 @@ class InferenceEngine:
         """One-shot timing of both paged attention impls on THIS
         engine's pools at worst-case context (every table column
         live): the evidence behind the auto-pick, kept on the engine
-        (``attention_impl_us``) so the bench can print it."""
+        (``attention_impl_us``) so the bench can print it.  The
+        kernel's time follows the lengths it is handed, the gather's
+        does not: at FULL lengths this is the kernel's slowest case,
+        so a pick of the kernel holds at every shorter context, and a
+        pick of the gather is a worst-case decision, not a typical
+        one."""
         from dlrover_tpu.ops.pallas.paged_attention import (
             measure_paged_attention,
         )
@@ -502,7 +523,8 @@ class InferenceEngine:
                 logits, cache = decode_step(
                     params, cfg, cache, toks, pos,
                     attention_impl=impl,
-                    kernel_interpret=kernel_interpret)
+                    kernel_interpret=kernel_interpret,
+                    active=active)
                 key, sub = jax.random.split(key)
                 nxt = select_token(logits, sub, temperature, top_k, top_p)
                 toks = jnp.where(active, nxt.astype(toks.dtype), toks)
@@ -1120,8 +1142,10 @@ class InferenceEngine:
         if active.any():
             if self.paged and self._table_dirty:
                 self._push_table()
+            live, streamed = self._book_kv_rows(active)
             t0 = time.perf_counter()
-            with span("dlrover.engine.decode_chunk"):
+            with span("dlrover.engine.decode_chunk",
+                      kv_rows_live=live, kv_rows_streamed=streamed):
                 out, tokens, positions, self._cache, self._rng = \
                     self._chunk_fn(
                         self.params, self._cache,
@@ -1140,6 +1164,30 @@ class InferenceEngine:
             if self._spec_fn is not None:
                 self._after_chunk_round()
         return self._finished[before:]
+
+    def _book_kv_rows(self, active: np.ndarray,
+                      chunks: int = 1) -> Tuple[int, int]:
+        """Add to ``stats.kv_rows_live`` / ``kv_rows_streamed`` what
+        the fused paged kernel reads in the next ``chunks`` decode
+        chunks dispatched from ``_positions`` for the slots ``active``
+        (the lengths it will be handed: ``position + 1``, one more
+        each forward; every other slot gets length 0 and reads
+        nothing), and return the two sums.  Host integer arithmetic
+        on a [slots, forwards] array; (0, 0) where the gather path
+        decodes."""
+        if self.attention_impl != "pallas":
+            return 0, 0
+        from dlrover_tpu.ops.pallas.paged_attention import streamed_rows
+
+        lengths = self._positions[active][:, None] \
+            + np.arange(1, chunks * self.chunk + 1)[None, :]
+        live = int(np.minimum(
+            lengths, self._max_blocks * self.block_size).sum())
+        streamed = streamed_rows(
+            lengths, self.block_size, self._max_blocks)
+        self.stats.kv_rows_live += live
+        self.stats.kv_rows_streamed += streamed
+        return live, streamed
 
     @spanned("dlrover.engine.deliver")
     def _deliver_chunk(self, out: np.ndarray) -> None:
@@ -1296,6 +1344,7 @@ class InferenceEngine:
             int(self._remaining[s]) for s in range(self.max_slots)
             if self._slot_req[s] is not None)
         n_chunks = max(1, -(-min_remaining // self.chunk))
+        self._book_kv_rows(active, n_chunks)
         t0 = time.perf_counter()
         outs = []
         tokens = jnp.asarray(self._tokens)

@@ -161,6 +161,7 @@ def decode_step(
     positions: jax.Array,          # [B] write position per slot
     attention_impl: str = "xla",
     kernel_interpret: bool = False,
+    active: Optional[jax.Array] = None,   # [B] bool
 ) -> Tuple[jax.Array, Dict[str, Any]]:
     """One decode step for all slots; returns (logits [B, V], cache).
 
@@ -172,12 +173,20 @@ def decode_step(
     fewer, larger kernels over unsliced weights is the win (module
     docstring).  ``attention_impl="pallas"`` routes the paged-cache
     attention read through the fused kernel (the K=1 single-query
-    path — exactly this function's case).
+    path — exactly this function's case), which streams only the pages
+    a slot's length makes live: ``positions + 1`` keys, the last group
+    of pages whole.  ``active`` (the engine's mask: the slot holds a
+    request and is not prefilling) hands the kernel length 0 for every
+    other slot, so an idle slot or one parked past its allocation reads
+    no page and attends to nothing (zeros) — its logits are junk either
+    way and the caller must not read them.  With no mask every slot
+    reads ``positions + 1`` keys.  The gather path ignores the mask.
     """
     logits, cache = verify_step(params, cfg, cache, tokens[:, None],
                                 positions,
                                 attention_impl=attention_impl,
-                                kernel_interpret=kernel_interpret)
+                                kernel_interpret=kernel_interpret,
+                                active=active)
     return logits[:, 0, :], cache
 
 
@@ -191,6 +200,7 @@ def verify_step(
     logits_index: Optional[jax.Array] = None,
     attention_impl: str = "xla",
     kernel_interpret: bool = False,
+    active: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, Any]]:
     """Speculative VERIFY: process K tokens per slot in one dispatch and
     return next-token logits at every position ([B, K, V], cache).
@@ -232,7 +242,8 @@ def verify_step(
     dense (bf16-width) view is never materialized.  Every other shape
     (speculative verify, chunk prefill, slot subsets) keeps the gather
     path; ``kernel_interpret`` runs the kernel in Pallas interpret
-    mode (the CPU parity harness).
+    mode (the CPU parity harness).  ``active`` is :func:`decode_step`'s
+    mask and reaches nothing but that kernel.
     """
     dtype = cfg.dtype
     d = cfg.head_dim_
@@ -277,6 +288,8 @@ def verify_step(
         )
 
         lengths = positions.astype(jnp.int32) + 1
+        if active is not None:
+            lengths = jnp.where(active, lengths, 0)
 
     new_k, new_v = [], []
     new_ks, new_vs = [], []

@@ -132,6 +132,8 @@ class RouterMetrics:
         self.kv4_blocks = 0.0
         self.prefill_chunk_seconds = 0.0
         self.paged_kernel_step_seconds = 0.0
+        self.kv_rows_live = 0.0
+        self.kv_rows_streamed = 0.0
         # prefix-cache fleet aggregates (engine-side COW ledger summed
         # over reporting replicas, same sweep as the raw-speed keys)
         self.prefix_hits = 0.0
@@ -289,6 +291,10 @@ class RouterMetrics:
             d.get("prefill_chunk_seconds", 0.0) for d in dicts)
         self.paged_kernel_step_seconds = sum(
             d.get("paged_kernel_step_seconds", 0.0) for d in dicts)
+        self.kv_rows_live = sum(
+            d.get("kv_rows_live", 0.0) for d in dicts)
+        self.kv_rows_streamed = sum(
+            d.get("kv_rows_streamed", 0.0) for d in dicts)
         for attr, key in (
             ("prefix_hits", "prefix_hits"),
             ("prefix_misses", "prefix_misses"),
@@ -391,6 +397,9 @@ class RouterMetrics:
             "serving_prefill_chunk_seconds": self.prefill_chunk_seconds,
             "serving_paged_kernel_step_seconds":
                 self.paged_kernel_step_seconds,
+            "serving_paged_kv_stream_ratio": (
+                self.kv_rows_streamed / self.kv_rows_live
+                if self.kv_rows_live else 0.0),
             "serving_sched_capacity_evals_total":
                 self.sched_capacity_evals,
             "serving_sched_rounds_skipped_total":

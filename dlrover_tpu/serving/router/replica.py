@@ -171,6 +171,10 @@ class InferenceEngineAdapter:
                 1.0 if impl == "pallas" else 0.0)
             out["paged_kernel_step_seconds"] = (
                 st.decode_seconds if impl == "pallas" else 0.0)
+            # what that kernel's decode forwards copied against what
+            # their slots could see (both 0 on the gather path)
+            out["kv_rows_live"] = float(st.kv_rows_live)
+            out["kv_rows_streamed"] = float(st.kv_rows_streamed)
             # prefix-cache ledger (all-float, so the dict still rides
             # STATS frames as-is); dense engines have no sharing
             prefix = getattr(eng, "prefix_stats", None)

@@ -181,6 +181,13 @@ METRIC_HELP: Dict[str, str] = {
         "zero with a nonzero pallas impl count says the kernel fleet "
         "is idle, not broken"
     ),
+    "serving_paged_kv_stream_ratio": (
+        "key rows the fused paged kernel copied per key row its "
+        "decoding slots could see, over the fleet's decode forwards "
+        "so far — the kernel streams whole page groups up to a slot's "
+        "length, so short contexts read near 1-2 and a fleet at full "
+        "context 1.0; 0 = no replica decodes with the kernel"
+    ),
     "serving_kv_int4_blocks": (
         "KV cache blocks held in packed-int4 pools across the fleet "
         "(a subset of serving_kv_quant_blocks) — int4's ~3.7x budget "

@@ -33,6 +33,8 @@ long-context mix, per-step logit-drift histogram asserted within
 bound.  The TPU kernel microbench stub skips cleanly off-TPU.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -53,6 +55,7 @@ from dlrover_tpu.ops.pallas.paged_attention import (
     measure_paged_attention,
     paged_decode_attention,
     resolve_attention_impl,
+    streamed_rows,
 )
 from dlrover_tpu.serving.engine import InferenceEngine
 from dlrover_tpu.serving.paged import kv_budget_multiplier
@@ -160,6 +163,150 @@ def test_kernel_parity_mha_and_block_boundary():
     ref = gather_reference(q, kf, vf, table, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=3e-5)
+
+
+# -- the group loop ends at the slot's length --------------------------------
+
+_PAGES, _BS = 2, 8                       # a group = 16 key rows
+_ROWS = _PAGES * _BS
+_RAGGED = {
+    "zero": 0, "one": 1, "rows-1": _ROWS - 1, "rows": _ROWS,
+    "rows+1": _ROWS + 1, "mid": 2 * _ROWS + 7, "capacity": 3 * _ROWS,
+    "parked": 3 * _ROWS + 1,
+}
+_QUANTIZERS = {"bf16": None, "int8": quantize_kv_int8,
+               "int4": quantize_kv_int4}
+
+
+def _poison_dead(pools, table, lengths, groups, trash: bool):
+    """NaN in every block of every WHOLLY dead group of every slot (and
+    in the trash block): what a kernel that stops at the slot's length
+    never copies.  Scales are poisoned with their codes."""
+    dead = [0] if trash else []
+    for b, n in enumerate(lengths):
+        live = min(-(-int(n) // _ROWS), groups)
+        dead += list(np.asarray(table)[b, live * _PAGES:])
+    out = []
+    for x in pools:
+        if x is None:
+            out.append(None)
+        elif jnp.issubdtype(x.dtype, jnp.floating):
+            out.append(x.at[np.array(dead)].set(jnp.nan))
+        else:                            # integer codes have no NaN:
+            out.append(x.at[np.array(dead)].set(127))   # their scale has
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _ragged_run(pool: str, mb: int):
+    """One batch holding every length of ``_RAGGED``: the kernel on the
+    clean pools, the kernel on the poisoned pools, the gather on the
+    clean pools (cached: each case below reads one slot of it)."""
+    lengths = np.array(list(_RAGGED.values()), np.int32)
+    q, kf, vf, table, _ = _pool_setup(B=len(lengths), bs=_BS, MB=mb,
+                                      seed=5)
+    k, v, ks, vs = kf, vf, None, None
+    if _QUANTIZERS[pool] is not None:
+        k, ks = _QUANTIZERS[pool](kf)
+        v, vs = _QUANTIZERS[pool](vf)
+    groups = -(-mb // _PAGES)
+    # a padded table's last group names the trash block: a slot at
+    # capacity streams it (masked), so only an unpadded table can have
+    # it poisoned
+    pk, pv, pks, pvs = _poison_dead(
+        (k, v, ks, vs), table, lengths, groups, trash=mb % _PAGES == 0)
+
+    def kernel(k, v, ks, vs):
+        return np.asarray(paged_decode_attention(
+            q, k, v, table, jnp.asarray(lengths), k_scale=ks, v_scale=vs,
+            pages_per_block=_PAGES, interpret=True))
+
+    ref = np.asarray(gather_reference(
+        q, k, v, table, jnp.asarray(lengths), ks, vs))
+    return kernel(k, v, ks, vs), kernel(pk, pv, pks, pvs), ref
+
+
+@pytest.mark.parametrize("mb", [6, 7], ids=["even", "padded"])
+@pytest.mark.parametrize("length", sorted(_RAGGED))
+@pytest.mark.parametrize("pool", sorted(_QUANTIZERS))
+def test_kernel_reads_only_live_groups(pool, length, mb):
+    """Each slot's group loop runs to ITS length: the output matches
+    the gather for every length >= 1 (a parked slot, one past capacity,
+    reads the whole table and no further), is zeros for length 0, and
+    does not change by one bit when every wholly dead group holds NaN —
+    a kernel that streamed them would turn a NaN in a dead V row into a
+    NaN output through ``0 x NaN``."""
+    clean, poisoned, ref = _ragged_run(pool, mb)
+    slot = list(_RAGGED).index(length)
+    assert np.isfinite(poisoned[slot]).all()
+    np.testing.assert_array_equal(poisoned[slot], clean[slot])
+    if _RAGGED[length] == 0:
+        np.testing.assert_array_equal(clean[slot], 0.0)
+    else:
+        np.testing.assert_allclose(clean[slot], ref[slot], atol=3e-5)
+
+
+def test_streamed_rows_is_the_kernels_trip_count():
+    """The host arithmetic the engine books with: whole groups up to
+    each length, none for 0, never past the (padded) table."""
+    lengths = list(_RAGGED.values())
+    for mb in (6, 7):
+        groups = -(-mb // _PAGES)
+        want = sum(min(-(-n // _ROWS), groups) * _ROWS for n in lengths)
+        assert streamed_rows(lengths, _BS, mb, _PAGES) == want
+    assert streamed_rows([0, 0], _BS, 6, _PAGES) == 0
+    # the wrapper's default group: 8 pages, or the whole of a narrower
+    # table
+    assert streamed_rows([1, 129], 16, 145) == 128 + 256
+    assert streamed_rows([1, 200], 16, 5) == 80 + 80
+
+
+def test_engine_parked_and_idle_slots_read_nothing(setup):
+    """Through the real engine, chunked prefill on: while the long
+    prompt prefills its slot is PARKED past its allocation, the third
+    slot is idle all along and the short request's slot is idle (at a
+    stale position) once it finishes.  The kernel engine is handed
+    length 0 for each of them and reproduces the gather engine's greedy
+    outputs exactly; what it streamed is within 2x of what its decoding
+    slots could see, where every group of every slot on every forward
+    (the table's width) is many times that."""
+    cfg, _ = setup
+    short, long_ = _prompts(cfg, 1, 40)[0], _prompts(cfg, 1, 80, seed=4)[0]
+
+    def run(impl):
+        eng = _engine(setup, max_slots=3, paged=True, block_size=8,
+                      prefill_chunk=16, attention_impl=impl)
+        rids = [eng.add_request(short, 12), eng.add_request(long_, 6)]
+        parked_beside_decode = 0
+        while eng.has_work:
+            eng.step()
+            parked_beside_decode += int(
+                eng._prefilling.any() and eng.stats.decode_forwards > 0)
+        assert parked_beside_decode >= 1
+        res = {r.rid: np.asarray(r.output) for r in eng._finished}
+        return eng, [res[r] for r in rids]
+
+    xla, base = run("xla")
+    kern, outs = run("pallas")
+    for a, b in zip(base, outs):
+        np.testing.assert_array_equal(a, b)
+    assert xla.stats.kv_rows_live == xla.stats.kv_rows_streamed == 0
+    st = kern.stats
+    # 18 generated tokens less the two that prefill gives: every live
+    # row is a key some decoding slot attended to
+    assert st.kv_rows_live >= sum(range(41, 52)) + sum(range(81, 86))
+    assert 1.0 <= st.kv_stream_ratio < 2.0
+    every_group = st.decode_forwards * kern.max_slots * streamed_rows(
+        [kern._cache_len], kern.block_size, kern._max_blocks)
+    assert every_group / st.kv_rows_live > 4.0
+    from dlrover_tpu.serving.router.metrics import RouterMetrics
+    from dlrover_tpu.serving.router.replica import InferenceEngineAdapter
+
+    m = RouterMetrics()
+    m.observe_engine_metrics([InferenceEngineAdapter(kern).engine_metrics(),
+                              InferenceEngineAdapter(xla).engine_metrics()])
+    assert m.metrics()["serving_paged_kv_stream_ratio"] \
+        == pytest.approx(st.kv_stream_ratio)
 
 
 # -- auto-pick contract -----------------------------------------------------

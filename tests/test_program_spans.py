@@ -177,7 +177,7 @@ def serve_run(tmp_path_factory):
         scheduler=ContinuousBatchScheduler(block_size=16))
     chunked = InferenceEngine(cfg, variables, max_slots=2, chunk=4,
                               paged=True, block_size=16, prefill_chunk=16,
-                              temperature=0.0)
+                              temperature=0.0, attention_impl="pallas")
     speculating = InferenceEngine(cfg, variables, max_slots=2, chunk=4,
                                   paged=True, block_size=16,
                                   speculative_k=3, temperature=0.0)
@@ -244,6 +244,15 @@ def test_span_attributes_are_the_events_stats(train_run, serve_run):
         == {"chunked", "speculating"}
     assert all(a["bucket"] >= 8 and a["n"] == 1 for _, _, _, a in
                ps.named(served, "dlrover.engine.prefill"))
+    # the paged kernel's rows, booked before each chunk's dispatch (the
+    # speculating engine decodes through the gather: it books none)
+    chunks = [a for _, _, _, a in
+              ps.named(served, "dlrover.engine.decode_chunk")]
+    booked = serve_run["stats"][0]
+    assert sum(a["kv_rows_live"] for a in chunks) \
+        == booked.kv_rows_live > 0
+    assert sum(a["kv_rows_streamed"] for a in chunks) \
+        == booked.kv_rows_streamed >= booked.kv_rows_live
 
 
 def test_counters_are_registered_monotone_and_inside_the_commit(train_run):

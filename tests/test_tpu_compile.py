@@ -101,9 +101,15 @@ def test_flash_under_fsdp_mesh_is_shard_mapped(topo):
     assert per_device == 3 * (4096 * 32 * 128 * 2)
 
 
-# (q heads, kv heads): the bench geometry (h2048, GQA 16:4) and the
-# smoke's (llama2_7b, 32 MHA heads); head_dim 128, 16-row pages
-_GEOMETRIES = {"bench16x4": (16, 4), "llama2_7b": (32, 32)}
+# the bench geometry (h2048, GQA 16:4), the smoke's (llama2_7b, 32 MHA
+# heads) and the ``serve-batch-closed`` cell's own (mistral-7b-serve:
+# GQA 32:8, 8 slots of 2305 rows = 145 pages, which the kernel pads to
+# 19 groups of 8, in a pool of 1161 blocks); head_dim 128, 16-row pages
+_GEOMETRIES = {
+    "bench16x4": dict(heads=16, kv_heads=4),
+    "llama2_7b": dict(heads=32, kv_heads=32),
+    "mistral7b": dict(heads=32, kv_heads=8, mb=145, nb=1161),
+}
 
 
 def _paged_args(one_chip, heads, kv_heads, kv_dtype, d=128, bs=16,
@@ -129,10 +135,12 @@ def _paged(q, k, v, table, lengths, ks, vs):
 @pytest.mark.parametrize("geometry", sorted(_GEOMETRIES))
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 def test_paged_decode_kernel_compiles(one_chip, geometry, kv_dtype):
-    """bf16 and int8 pools stream in place.  int8 was refused before
+    """bf16 and int8 pools stream in place, the group loop's trip
+    count read from ``lengths`` at run time.  int8 was refused before
     PR 21 ('Slice shape along dimension 2 must be aligned to tiling
     (128), but is 4': the per-token scale slice)."""
-    args = _paged_args(one_chip, *_GEOMETRIES[geometry], kv_dtype)
+    args = _paged_args(one_chip, kv_dtype=kv_dtype,
+                       **_GEOMETRIES[geometry])
     text = jax.jit(_paged).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
 
@@ -141,7 +149,8 @@ def test_paged_decode_int4_is_refused_loudly(one_chip):
     """Packed int4 pools do not compile on a TPU (minor dimension 64);
     until the pool is re-laid the kernel refuses in the repo's own
     words, at trace time — it never runs the gather under its name."""
-    args = _paged_args(one_chip, *_GEOMETRIES["bench16x4"], "int4")
+    args = _paged_args(one_chip, kv_dtype="int4",
+                       **_GEOMETRIES["bench16x4"])
     with pytest.raises(NotImplementedError) as err:
         jax.jit(_paged).lower(*args)
     assert str(err.value) == INT4_REFUSAL
