@@ -115,13 +115,15 @@ def default_loss_fn(
     route the decoder stack through the GPipe schedule).
     """
 
-    def _aux_losses(var_updates) -> jax.Array:
-        """Sum of sown per-layer MoE losses (load-balance + z-loss), zero
-        when the model has none."""
-        leaves = jax.tree_util.tree_leaves(var_updates.get("moe_losses", {}))
-        if not leaves:
-            return jnp.zeros((), jnp.float32)
-        return sum(jnp.sum(leaf) for leaf in leaves)
+    def _with_moe(loss, weight, var_updates):
+        """The task loss plus the MoE layers' sown losses (a mean over
+        layers; zero when the model has none), and the routing statistics
+        for the step's metrics in ``aux["moe"]``."""
+        from dlrover_tpu.models import moe
+
+        sown = var_updates.get("moe_losses", {})
+        return loss + moe.aux_loss(sown), {
+            "weight": weight, "moe": moe.routing_stats(sown)}
 
     if forward_fn is None:
 
@@ -170,7 +172,7 @@ def default_loss_fn(
             hidden, kernel, labels, mask, chunk_size=loss_chunk_size,
             logit_scale=getattr(model.config, "logit_scale", 1.0),
         )
-        return loss + _aux_losses(var_updates), {"weight": weight}
+        return _with_moe(loss, weight, var_updates)
 
     def loss_fn(params, batch):
         logits, var_updates = forward_fn(params, batch, return_hidden=False)
@@ -185,7 +187,7 @@ def default_loss_fn(
         loss, weight = masked_language_model_loss(
             logits, labels, mask, return_weight=True
         )
-        return loss + _aux_losses(var_updates), {"weight": weight}
+        return _with_moe(loss, weight, var_updates)
 
     return chunked_loss_fn if loss_chunk_size else loss_fn
 
@@ -483,22 +485,27 @@ def accelerate(
                     w = aux["weight"]
                     grads = jax.tree_util.tree_map(lambda g: g * w, grads)
                     return (loss_acc + loss * w, _tree_add(grad_acc, grads),
-                            w_acc + w), None
+                            w_acc + w), aux.get("moe", {})
 
             zero_grads = jax.tree_util.tree_map(
                 lambda x: jnp.zeros(x.shape, jnp.float32), state.params
             )
             zero = jnp.zeros((), jnp.float32)
-            (loss_sum, grads, w_sum), _ = jax.lax.scan(
+            (loss_sum, grads, w_sum), moe_stats = jax.lax.scan(
                 micro_step, (zero, zero_grads, zero), batch
             )
+            # one value a microbatch: the worst load, the mean loss
+            worst = {"moe_load_max": jnp.max, "moe_load_min": jnp.min}
+            moe_stats = {k: worst.get(k, jnp.mean)(v)
+                         for k, v in moe_stats.items()}
             with jax.named_scope("grad_accum"):
                 inv = 1.0 / w_sum
                 loss = loss_sum * inv
                 grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
         else:
             with jax.named_scope("loss_and_grad"):
-                (loss, _), grads = grad_fn(state.params, batch)
+                (loss, aux), grads = grad_fn(state.params, batch)
+            moe_stats = aux.get("moe", {})
         # clipping is the first link of the optimizer chain (see above)
         with jax.named_scope("optimizer"):
             new_state = state.apply_gradients(grads=grads)
@@ -506,6 +513,8 @@ def accelerate(
             "loss": loss,
             "grad_norm": optax.global_norm(grads),
             "step": new_state.step,
+            # routing statistics of a MoE model (models/moe.py), else none
+            **moe_stats,
         }
         return new_state, metrics
 

@@ -201,6 +201,7 @@ def make_pipelined_forward(
     params live at ``params['layers']['layer']``.
     """
     from dlrover_tpu.accel.parallel.mesh import with_logical_constraint
+    from dlrover_tpu.models import moe
     from dlrover_tpu.models.llama import DecoderLayer, RMSNorm
 
     cfg = model.config
@@ -224,18 +225,16 @@ def make_pipelined_forward(
         def one_layer(carry, layer_params):
             h, aux = carry
             if cfg.num_experts:
-                # MoE layers sow load-balance/z losses; collect them into
-                # the pipeline's scalar side channel (pp x ep composition:
+                # MoE layers sow load-balance/z losses; collect their sum
+                # (the loss takes the mean over layers, below) into the
+                # pipeline's scalar side channel (pp x ep composition:
                 # experts stay ep-sharded inside the stage — GSPMD manages
                 # ep while shard_map only manualizes pp)
                 h, vu = layer_mod.apply(
                     {"params": layer_params}, h, positions, segment_ids,
                     mutable=["moe_losses"],
                 )
-                aux = aux + sum(
-                    jnp.sum(leaf.astype(jnp.float32))
-                    for leaf in jax.tree_util.tree_leaves(vu["moe_losses"])
-                )
+                aux = aux + moe.aux_loss(vu["moe_losses"])
             else:
                 h = layer_mod.apply(
                     {"params": layer_params}, h, positions, segment_ids
@@ -275,7 +274,9 @@ def make_pipelined_forward(
             num_microbatches=m_count,
             remat=remat,
         )
-        var_updates = {"moe_losses": {"pipeline": aux}} if cfg.num_experts \
+        # routing statistics do not cross the pipeline's scalar channel
+        var_updates = {"moe_losses": {"pipeline": {
+            "aux_loss": aux / cfg.num_layers}}} if cfg.num_experts \
             else {}
 
         x = norm_mod.apply({"params": params["final_norm"]}, x)

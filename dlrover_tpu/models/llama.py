@@ -60,9 +60,16 @@ class LlamaConfig:
     # atorch/atorch/modules/moe/moe_layer.py)
     num_experts: int = 0
     moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
+    # the top-k router weights renormalised to sum to one (Mixtral) or
+    # left as the softmax gave them (OLMoE's ``norm_topk_prob: false``)
+    moe_norm_topk_prob: bool = True
+    # added to the task loss as a MEAN over the MoE layers
     moe_aux_loss_coef: float = 0.01
     moe_z_loss_coef: float = 1e-3
+    # RMSNorm with a learned scale over the WHOLE projected query and the
+    # whole projected key, before the split into heads and before RoPE
+    # (OLMoE, OLMo-2)
+    qk_norm: bool = False
     # q/k/v projection biases (Qwen2-family checkpoints; o_proj stays
     # bias-free in every supported architecture)
     attention_bias: bool = False
@@ -108,12 +115,40 @@ class LlamaConfig:
         if self.num_experts:
             mlp = mlp * self.num_experts + h * self.num_experts  # + router
         per_layer = attn + mlp + 2 * h
+        if self.qk_norm:
+            per_layer += d * (self.num_heads + self.num_kv_heads)
         emb = v * h * (1 if self.tie_embeddings else 2)
         return self.num_layers * per_layer + emb + h
 
     @classmethod
     def llama2_7b(cls, **kw) -> "LlamaConfig":
         return cls(**kw)
+
+    @classmethod
+    def olmoe_1b_7b(cls, **kw) -> "LlamaConfig":
+        """allenai/OLMoE-1B-7B-0125-Instruct as its config.json has it:
+        MHA with QK-norm, 64 experts of width 1024, 8 a token, weights
+        not renormalised; the two loss coefficients are OlmoeConfig's
+        default and the OLMoE report's."""
+        base = dict(
+            vocab_size=50304,
+            hidden_size=2048,
+            intermediate_size=1024,
+            num_layers=16,
+            num_heads=16,
+            num_kv_heads=16,
+            max_seq_len=4096,
+            rope_theta=10000.0,
+            rms_norm_eps=1e-5,
+            num_experts=64,
+            moe_top_k=8,
+            moe_norm_topk_prob=False,
+            moe_aux_loss_coef=0.01,
+            moe_z_loss_coef=1e-3,
+            qk_norm=True,
+        )
+        base.update(kw)
+        return cls(**base)
 
     @classmethod
     def from_preset(
@@ -147,7 +182,7 @@ class LlamaConfig:
 
 
 #: presets the entry points (examples/, the serving worker) can name
-PRESETS = ("tiny", "llama2_7b")
+PRESETS = ("tiny", "llama2_7b", "olmoe_1b_7b")
 
 
 def resolve_remat_policy(name: str):
@@ -284,6 +319,13 @@ class Attention(nn.Module):
         q = q_proj(x)
         k = k_proj(x)
         v = v_proj(x)
+        if cfg.qk_norm:
+            def whole(name, y):
+                flat = y.reshape(*y.shape[:-2], y.shape[-2] * y.shape[-1])
+                return RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
+                               name=name)(flat).reshape(y.shape)
+
+            q, k = whole("q_norm", q), whole("k_norm", k)
         q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"))
         k = with_logical_constraint(k, ("batch", "seq", "kv_heads", "head_dim"))
         v = with_logical_constraint(v, ("batch", "seq", "kv_heads", "head_dim"))
@@ -396,7 +438,7 @@ class DecoderLayer(nn.Module):
                 intermediate_size=cfg.intermediate_size,
                 num_experts=cfg.num_experts,
                 top_k=cfg.moe_top_k,
-                capacity_factor=cfg.moe_capacity_factor,
+                norm_topk_prob=cfg.moe_norm_topk_prob,
                 aux_loss_coef=cfg.moe_aux_loss_coef,
                 z_loss_coef=cfg.moe_z_loss_coef,
                 dtype=cfg.dtype,

@@ -1,108 +1,207 @@
-"""Mixture-of-Experts layer with expert parallelism, TPU-native.
+"""Mixture-of-Experts layer: dropless sorted dispatch over a grouped matmul.
 
 Parity targets in the reference:
-- ``MOELayer`` with all-to-all token dispatch
-  (atorch/atorch/modules/moe/moe_layer.py:87 ``_AllToAll``)
-- top-k / switch gating (atorch/atorch/modules/moe/topk_gating.py,
-  switch_gating.py)
+- ``MOELayer`` token dispatch (atorch/atorch/modules/moe/moe_layer.py:87)
+- top-k gating (atorch/atorch/modules/moe/topk_gating.py)
 - grouped-GEMM experts (atorch/atorch/modules/moe/grouped_gemm_moe.py)
 
-TPU-native design: experts live on the ``ep`` mesh axis as a leading
-``expert`` dimension of the FFN params; dispatch/combine are einsums over a
-dense ``[batch, seq, expert, capacity]`` mask.  With tokens sharded over
-``dp/fsdp`` and experts over ``ep``, GSPMD lowers the dispatch einsum to
-exactly the all-to-all the reference issues by hand, and the per-expert
-matmuls are a single batched (grouped) GEMM on the MXU — no ragged loops,
-no host control flow, fully jittable.
+TPU-native design.  The router picks ``top_k`` experts per token by
+``jax.lax.top_k`` over a float32 softmax.  The ``T x top_k`` (token,
+expert) picks are sorted by expert, so that each expert's rows are one
+contiguous group of ``[T * top_k, hidden]``; the three expert matmuls
+are grouped matmuls over those groups (:func:`grouped_matmul`); the
+result goes back to token order and is summed with the router's
+weights.  No pick is ever dropped and no buffer has a capacity: a
+group is as long as its expert's picks.  Both moves between token
+order and expert order are row gathers in forward AND backward
+(:func:`_to_expert_order`, :func:`_to_token_order`: each is the other's
+transpose), so the layer holds no scatter.
 
-Aux losses (load-balance + router z-loss) are sown into the
-``"moe_losses"`` flax collection; :func:`dlrover_tpu.accel.accelerate.
-default_loss_fn` adds them to the task loss.
+Experts carry the ``expert`` logical axis (``ep`` in the mesh rules), so
+their stored parameters and optimizer state shard over ``ep``.  The
+layer's own work is not partitioned yet: the picks are sorted over ALL
+tokens and a Pallas call has no partitioning rule, so on a mesh the
+router's probabilities and the grouped matmul's operands are replicated
+first (:func:`_replicated`) and every device computes the whole layer.
+The result is the same; the all-to-all by hand is ROADMAP B1(c).
+
+What the layer sows into the ``"moe_losses"`` collection, once a layer:
+``aux_loss`` (the coefficients times the two terms below: what
+:func:`aux_loss` averages over layers into the task loss),
+``balance_loss`` (``e * sum_e f_e P_e``, ``f_e`` the share of ALL
+``T x top_k`` picks that chose e, ``P_e`` the mean router probability),
+``z_loss`` (``mean_t logsumexp(logits_t)^2``) and ``expert_counts``
+(the group sizes).  :func:`routing_stats` reduces them for the step's
+metrics.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import functools
+import math
+from typing import Dict
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
-from dlrover_tpu.accel.parallel.mesh import with_logical_constraint
+from dlrover_tpu.accel.parallel.mesh import (ambient_mesh,
+                                              with_logical_constraint)
 
 
-def top_k_gating(
-    router_logits: jax.Array,
-    k: int,
-    capacity: int,
-    *,
-    dtype=jnp.float32,
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Top-k token->expert assignment with per-(batch-row, expert) capacity.
+# (rows, contraction, columns) of one tile of the grouped matmul.  Chosen on
+# the v5e at OLMoE's shapes, 32768 rows in 64 uneven groups against
+# [64, 2048, 1024] and [64, 1024, 2048], forward + backward of the three
+# matmuls (my chip runs, PR 26): (256, 1024, 1024) 15.5 ms, (512, 1024,
+# 1024) 16.8, (128, 1024, 1024) 17.4, (256, 1024, 512) and (256, 512, 1024)
+# 17.7, (256, 2048, 512) 19.0, (512, 512, 512) 20.9, (64, 1024, 1024) 24.4,
+# the kernel's default (128, 128, 128) 149 ms; ``jax.lax.ragged_dot``
+# 23.5 ms.  A side of 2048 beside one of 1024, and (1024, 1024, 1024), do
+# not fit the chip's fast memory.  The time does not move with the skew.
+GMM_TILING = (256, 1024, 1024)
 
-    router_logits: [b, s, e].  Returns (dispatch_mask [b, s, e, c],
-    combine_weights [b, s, e, c], load_balance_loss, router_z_loss).
 
-    Semantics follow the reference's TopKGate (reference:
-    atorch/atorch/modules/moe/topk_gating.py; switch gating is k=1):
-    highest-prob expert first, tokens beyond an expert's capacity dropped,
-    combine weights renormalized over the selected experts.
-    """
-    b, s, e = router_logits.shape
-    logits_f32 = router_logits.astype(jnp.float32)
-    probs = jax.nn.softmax(logits_f32, axis=-1)
+def _interpret() -> bool:
+    """Off the TPU the kernel runs in Pallas's interpreter: the same
+    program, slowly (the CPU tests)."""
+    return jax.default_backend() != "tpu"
 
-    # iterative top-k: one-hot argmax, mask, repeat (static k unrolled —
-    # jit-friendly, no sort of the full expert dim)
-    remaining = probs
-    selections = []  # [b, s, e] one-hots, best first
-    for _ in range(k):
-        idx = jnp.argmax(remaining, axis=-1)
-        onehot = jax.nn.one_hot(idx, e, dtype=jnp.float32)
-        selections.append(onehot)
-        remaining = remaining * (1.0 - onehot)
 
-    # position of each token in its expert's buffer: cumsum over the
-    # sequence, priority to higher-k selections first (reference dispatches
-    # top-1 choices before top-2 overflow)
-    dispatch = jnp.zeros((b, s, e, capacity), jnp.float32)
-    combine = jnp.zeros((b, s, e, capacity), jnp.float32)
-    fill = jnp.zeros((b, e), jnp.float32)  # tokens already in each buffer
-    for onehot in selections:
-        pos = jnp.cumsum(onehot, axis=1) - 1.0 + fill[:, None, :]
-        within = (pos < capacity) & (onehot > 0)
-        pos_clipped = jnp.clip(pos, 0, capacity - 1).astype(jnp.int32)
-        slot = jax.nn.one_hot(pos_clipped, capacity, dtype=jnp.float32)
-        mask = within.astype(jnp.float32)[..., None] * slot
-        dispatch = dispatch + mask
-        gate = jnp.sum(probs * onehot, axis=-1)  # [b, s]
-        combine = combine + mask * gate[..., None, None]
-        fill = fill + jnp.sum(onehot * within.astype(jnp.float32), axis=1)
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array,
+                   group_sizes: jax.Array) -> jax.Array:
+    """``lhs[rows of group g] @ rhs[g]`` for contiguous row groups: the
+    ``megablox`` kernel JAX ships, forward and backward (its ``tgmm`` makes
+    the weights' gradient), float32 accumulation.
 
-    # renormalize combine weights over the experts that accepted the token
-    denom = jnp.sum(combine, axis=(2, 3), keepdims=True)
-    combine = combine / jnp.maximum(denom, 1e-9)
+    lhs [n, k]; rhs [groups, k, m]; group_sizes [groups] int32 summing to
+    n.  Returns [n, m] in ``lhs.dtype``.
 
-    # load-balance loss (Switch Transformer form): e * sum_i f_i * p_i
-    me = jnp.mean(probs, axis=(0, 1))  # mean router prob per expert
-    ce = jnp.mean(selections[0], axis=(0, 1))  # fraction routed (top-1)
-    lb_loss = e * jnp.sum(me * ce)
-    z_loss = jnp.mean(jnp.square(jax.nn.logsumexp(logits_f32, axis=-1)))
-    return dispatch.astype(dtype), combine.astype(dtype), lb_loss, z_loss
+    On a mesh the operands are replicated first, and so is the result (whose
+    constraint does the same for the cotangent on the way back): a Pallas
+    call is not partitioned, and left to itself GSPMD shards the
+    interpreter's loops on the CPU meshes of the tests, with collectives
+    inside them that the CPU runtime can deadlock on."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    n, k = lhs.shape
+    tm, tk, tn = GMM_TILING
+    # a tile's rows must divide n; its other sides may overhang
+    tiling = (math.gcd(n, tm), min(tk, k), min(tn, rhs.shape[2]))
+    out = gmm(_replicated(lhs), _replicated(rhs), group_sizes, lhs.dtype,
+              tiling, interpret=_interpret())
+    return _replicated(out)
+
+
+def _replicated(x: jax.Array) -> jax.Array:
+    """``x`` constrained to no sharding on the mesh of the context (by a
+    bare ``PartitionSpec``: inside the pipeline's ``shard_map`` that is the
+    mesh with its manual axis, which a ``NamedSharding`` of the physical
+    mesh does not match); ``x`` itself where there is no mesh."""
+    if ambient_mesh() is None:
+        return x
+    return jax.lax.with_sharding_constraint(x, PartitionSpec())
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _to_expert_order(x, order, inverse, top_k):
+    """Rows of ``x`` [T, m] repeated ``top_k`` times and put in expert
+    order: out[j] = x[order[j] // top_k]."""
+    return x[order // top_k]
+
+
+def _to_expert_order_fwd(x, order, inverse, top_k):
+    return x[order // top_k], inverse
+
+
+def _to_expert_order_bwd(top_k, inverse, g):
+    # the transpose of a row gather is a scatter-add; with the inverse
+    # permutation at hand it is a gather and a sum over each token's picks
+    t = g.shape[0] // top_k
+    picks = g[inverse].reshape(t, top_k, g.shape[-1])
+    return picks.astype(jnp.float32).sum(axis=1).astype(g.dtype), None, None
+
+
+_to_expert_order.defvjp(_to_expert_order_fwd, _to_expert_order_bwd)
+
+
+@jax.custom_vjp
+def _to_token_order(y, order, inverse):
+    """Rows of ``y`` [T * top_k, m] from expert order back to pick order:
+    out[i] = y[inverse[i]] (pick i = token i // top_k, choice i % top_k)."""
+    return y[inverse]
+
+
+def _to_token_order_fwd(y, order, inverse):
+    return y[inverse], order
+
+
+def _to_token_order_bwd(order, g):
+    return g[order], None, None
+
+
+_to_token_order.defvjp(_to_token_order_fwd, _to_token_order_bwd)
+
+
+def _leaves_named(tree, name: str):
+    """The leaves of a ``moe_losses`` tree sown under ``name``, each
+    flattened: one entry a layer under ``nn.scan``'s stacking or not."""
+    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
+    return [leaf for path, leaf in flat
+            if any(getattr(p, "key", None) == name for p in path)]
+
+
+def _layer_mean(moe_losses, name: str):
+    """Mean over layers of the scalar sown under ``name``; None for a
+    model that sowed none."""
+    leaves = _leaves_named(moe_losses, name)
+    if not leaves:
+        return None
+    return jnp.mean(jnp.concatenate(
+        [jnp.ravel(leaf).astype(jnp.float32) for leaf in leaves]))
+
+
+def aux_loss(moe_losses) -> jax.Array:
+    """MEAN over layers of the sown ``aux_loss`` (zero for a dense model):
+    a depth cut does not rescale the term."""
+    mean = _layer_mean(moe_losses, "aux_loss")
+    return jnp.zeros((), jnp.float32) if mean is None else mean
+
+
+def routing_stats(moe_losses) -> Dict[str, jax.Array]:
+    """The step's routing metrics from the sown collection ({} for a dense
+    model): ``moe_load_max`` / ``moe_load_min`` are the worst layer's
+    largest / smallest group over the mean group (1.0 = even), the two
+    losses are means over layers, without their coefficients."""
+    counts = _leaves_named(moe_losses, "expert_counts")
+    if not counts:
+        return {}
+    counts = jnp.concatenate(
+        [c.reshape(-1, c.shape[-1]).astype(jnp.float32) for c in counts])
+    load = counts / jnp.mean(counts, axis=-1, keepdims=True)
+    return {
+        "moe_load_max": jnp.max(load),
+        "moe_load_min": jnp.min(load),
+        "moe_balance_loss": _layer_mean(moe_losses, "balance_loss"),
+        "moe_z_loss": _layer_mean(moe_losses, "z_loss"),
+    }
 
 
 class MoEMLP(nn.Module):
-    """Expert-parallel SwiGLU FFN (drop-in for the dense MLP).
+    """Sparse SwiGLU FFN (drop-in for the dense MLP): every token's
+    ``top_k`` experts, none dropped.
 
-    num_experts must be divisible by the mesh's ``ep`` size; params carry
-    the ``expert`` logical axis so the rules table shards them over ``ep``.
+    Expert params carry the ``expert`` logical axis, so the rules table
+    shards them over ``ep`` (``num_experts`` divisible by its size).
     """
 
     hidden_size: int
     intermediate_size: int
     num_experts: int
     top_k: int = 2
-    capacity_factor: float = 1.25
+    # renormalise the top-k weights to sum to one (Mixtral) or keep the
+    # softmax's own values (OLMoE, ``norm_topk_prob: false``)
+    norm_topk_prob: bool = True
     aux_loss_coef: float = 0.01
     z_loss_coef: float = 1e-3
     dtype: type = jnp.bfloat16
@@ -114,7 +213,8 @@ class MoEMLP(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         b, s, m = x.shape
-        e, h = self.num_experts, self.intermediate_size
+        e, h, k = self.num_experts, self.intermediate_size, self.top_k
+        t = b * s
         init = nn.initializers.lecun_normal()
 
         router = nn.DenseGeneral(
@@ -142,44 +242,53 @@ class MoEMLP(nn.Module):
             "w_down", (e, h, m), ("expert", "mlp", "embed")
         )
 
-        capacity = max(1, int(self.capacity_factor * self.top_k * s / e))
-        logits = router(x)  # [b, s, e] f32
-        dispatch, combine, lb_loss, z_loss = top_k_gating(
-            logits, self.top_k, capacity, dtype=self.dtype
-        )
-        self.sow(
-            "moe_losses",
-            "aux_loss",
-            self.aux_loss_coef * lb_loss + self.z_loss_coef * z_loss,
-            reduce_fn=lambda a, b: a + b,
-            init_fn=lambda: jnp.zeros((), jnp.float32),
-        )
+        with jax.named_scope("moe_route"):
+            logits = router(x).reshape(t, e)  # f32
+            # replicated: the picks are sorted over ALL tokens below, and
+            # XLA's partitioner aborts on a batch-sharded top-k inside the
+            # pipeline's partly manual shard_map
+            probs = _replicated(jax.nn.softmax(logits, axis=-1))
+            top_p, top_e = jax.lax.top_k(probs, k)  # [t, k]
+            if self.norm_topk_prob:
+                top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            picks = top_e.reshape(t * k)
+            counts = jnp.sum(jax.nn.one_hot(picks, e, dtype=jnp.int32), axis=0)
+            share = counts.astype(jnp.float32) / (t * k)
+            balance = e * jnp.sum(share * jnp.mean(probs, axis=0))
+            z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+        for name, value in (
+            ("aux_loss", self.aux_loss_coef * balance + self.z_loss_coef * z),
+            ("balance_loss", balance),
+            ("z_loss", z),
+            ("expert_counts", counts),
+        ):
+            # one layer, one value: under nn.scan the collection stacks
+            self.sow("moe_losses", name, value,
+                     reduce_fn=lambda _, new: new, init_fn=lambda: None)
 
-        xd = x.astype(self.dtype)
-        # dispatch: [b,s,e,c] x [b,s,m] -> [b,e,c,m] — GSPMD inserts the
-        # token->expert all-to-all here when tokens are dp-sharded and
-        # experts ep-sharded (reference moe_layer.py:87 _AllToAll)
-        expert_in = jnp.einsum("bsec,bsm->becm", dispatch, xd)
-        expert_in = with_logical_constraint(
-            expert_in, ("batch", "expert", None, "act_embed")
-        )
-        wg = w_gate.astype(self.dtype)
-        wu = w_up.astype(self.dtype)
-        wd = w_down.astype(self.dtype)
+        with jax.named_scope("moe_dispatch"):
+            order = jnp.argsort(picks, stable=True)  # expert-major
+            inverse = jnp.argsort(order)
+            xs = _to_expert_order(
+                x.reshape(t, m).astype(self.dtype), order, inverse, k)
+
         if self.fp8:
             from dlrover_tpu.ops.fp8 import fake_quant_fp8, grad_quant_fp8
         else:
             fake_quant_fp8 = grad_quant_fp8 = lambda x: x  # noqa: E731
-        # grouped GEMM over the expert dim (reference grouped_gemm_moe.py)
-        xq = fake_quant_fp8(expert_in)
-        gate = grad_quant_fp8(jnp.einsum("becm,emh->bech", xq,
-                                         fake_quant_fp8(wg)))
-        up = grad_quant_fp8(jnp.einsum("becm,emh->bech", xq,
-                                       fake_quant_fp8(wu)))
-        act = nn.silu(gate) * up
-        act = with_logical_constraint(act, ("batch", "expert", None, "mlp"))
-        out = grad_quant_fp8(jnp.einsum("bech,ehm->becm", fake_quant_fp8(act),
-                                        fake_quant_fp8(wd)))
-        # combine: expert->token all-to-all back
-        y = jnp.einsum("bsec,becm->bsm", combine, out)
+        with jax.named_scope("moe_experts"):
+            wg = fake_quant_fp8(w_gate.astype(self.dtype))
+            wu = fake_quant_fp8(w_up.astype(self.dtype))
+            wd = fake_quant_fp8(w_down.astype(self.dtype))
+            xq = fake_quant_fp8(xs)
+            gate = grad_quant_fp8(grouped_matmul(xq, wg, counts))
+            up = grad_quant_fp8(grouped_matmul(xq, wu, counts))
+            act = nn.silu(gate) * up
+            out = grad_quant_fp8(
+                grouped_matmul(fake_quant_fp8(act), wd, counts))
+
+        with jax.named_scope("moe_combine"):
+            out = _to_token_order(out, order, inverse).reshape(t, k, m)
+            y = jnp.sum(out.astype(jnp.float32) * top_p[..., None], axis=1)
+        y = y.astype(self.dtype).reshape(b, s, m)
         return with_logical_constraint(y, ("batch", "seq", "act_embed"))

@@ -125,6 +125,13 @@ def serving_params_from_llama(
     the Pallas kernel layout at load time."""
     import flax.linen as nn
 
+    if cfg.num_experts or cfg.qk_norm:
+        raise ValueError(
+            "the serving engine's model is the dense decoder without "
+            f"QK-norm: num_experts={cfg.num_experts}, qk_norm={cfg.qk_norm} "
+            "would be served as a different model (ROADMAP B1, serving "
+            "half: serving/model.py::_mlp and the attention block are "
+            "dense-only)")
     if dtype is None:
         dtype = cfg.dtype
     variables = nn.meta.unbox(variables)

@@ -219,10 +219,11 @@ class Trainer:
                         # mid-window, so logging_steps would over-count)
                         "steps_per_sec": sps,
                     }
-                    if "grad_norm" in metrics:
-                        logs["grad_norm"] = float(
-                            jax.device_get(metrics["grad_norm"])
-                        )
+                    # grad_norm, and a MoE model's routing statistics
+                    for name in metrics:
+                        if name == "grad_norm" or name.startswith("moe_"):
+                            logs[name] = float(
+                                jax.device_get(metrics[name]))
                     if self._schedule is not None:
                         logs["learning_rate"] = float(self._schedule(step))
                     plan = self.elastic.plan

@@ -173,6 +173,8 @@ def main() -> int:
         if saved:
             save_seconds.append((step, time.time() - t0))
         print(f"[train] step {step} loss {loss:.6f}"
+              + "".join(f" {k} {float(v):.4f}" for k, v in metrics.items()
+                        if k.startswith("moe_"))  # a MoE preset's routing
               + (f" save {save_seconds[-1][1]:.3f}s" if saved else ""),
               flush=True)
         if sharding is not None:

@@ -109,7 +109,12 @@ def test_kill_one_node_resumes_trajectory(tmp_path, record_path):
                 JAX_PLATFORMS="cpu",
             )
             agents.append(subprocess.Popen(
-                _agent_cmd(rank, f"127.0.0.1:{port}", work),
+                # a second a step: the steps between KILL_AFTER_STEP and
+                # the last must outlast the 1 s poll below even when the
+                # suite's other workers starve this process (without it
+                # all 10 steps can pass between two polls, and the kill
+                # comes after the run)
+                _agent_cmd(rank, f"127.0.0.1:{port}", work, step_sleep=1.0),
                 env=env, cwd=REPO,
                 stdout=open(os.path.join(work, f"agent{rank}.log"), "w"),
                 stderr=subprocess.STDOUT,

@@ -167,3 +167,30 @@ def test_int8_matmul_compiles(one_chip, rows):
     fn = jax.jit(lambda a, b: int8_matmul(
         a, b, block_m=128, block_n=256, block_k=512))
     assert "tpu_custom_call" in fn.lower(a, b).compile().as_text()
+
+
+def test_grouped_expert_matmul_compiles_at_olmoe_widths(one_chip,
+                                                       monkeypatch):
+    """OLMoE's three expert matmuls (32 768 rows in 64 groups against
+    [64, 2048, 1024] and back), forward and backward, as the kernel and
+    not its interpreter: the tile of ``GMM_TILING`` must fit the chip's
+    fast memory (a larger one was refused on the chip, PR 26)."""
+    from dlrover_tpu.models import moe
+
+    # the backend here is the CPU: steer the layer onto the chip's path
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, wg, wu, wd, sizes):
+        act = jax.nn.silu(moe.grouped_matmul(x, wg, sizes)) \
+            * moe.grouped_matmul(x, wu, sizes)
+        return moe.grouped_matmul(act, wd, sizes).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        s((32768, 2048)), s((64, 2048, 1024)), s((64, 2048, 1024)),
+        s((64, 1024, 2048)), s((64,), jnp.int32)).compile().as_text()
+    # 2 forward calls the gradient needs, 3 for the rows' gradient, 3 for
+    # the weights' (the last forward matmul's output is not needed)
+    assert text.count("tpu_custom_call") >= 8
