@@ -340,9 +340,14 @@ class CheckpointEngine:
         device-side copy, so a caller that DONATES its state into the
         next jitted step cannot invalidate the bytes mid-copy) plus the
         writer hand-off — a pointer swap, not the memcpy.  The writer
-        thread performs the host copy into the shm handler's inactive
-        buffer and publishes the generation atomically; a crash before
-        the publish restores the previous generation (never torn).
+        thread brings the snapshot to the host ONCE, a bounded number of
+        bytes in flight at a time (``shm_handler.D2H_BUDGET_BYTES``: the
+        whole state dispatched at once stands the device for seconds),
+        each piece copied into the shm handler's inactive buffer beside
+        the next piece's transfer; it publishes the generation
+        atomically and lets go of the snapshot as the commit ends.  A
+        crash before the publish restores the previous generation
+        (never torn).
 
         The pipeline is depth 1: staging save N first waits out any
         still-copying save N-1 (steady state: already done — a full
@@ -489,8 +494,6 @@ class CheckpointEngine:
                     self._save_cv.wait(timeout=1.0)
                 if self._writer_stop and self._pending is None:
                     return
-                # rebinding ``state`` lets go of the previous save's
-                # device snapshot, kept until now
                 with span("dlrover.ckpt.pickup"):
                     step, state, notify = self._pending
                 self._pending = None
@@ -519,6 +522,12 @@ class CheckpointEngine:
                         step, e,
                     )
             finally:
+                # let go of the device snapshot (and of the host bytes
+                # cached on its shards) now, not at the next pick-up: it
+                # is a second copy of the state in HBM.  A reference
+                # dropped, never ``.delete()``: a sync save hands the
+                # CALLER's arrays down the same path
+                state = None
                 with self._save_cv:
                     self._writer_busy = False
                     self._save_cv.notify_all()
@@ -605,11 +614,13 @@ class CheckpointEngine:
             "dlrover_ckpt_lock_wait_seconds_total": float(
                 self.lock_wait_s_total),
             # the writer's two copies, timed where they happen
-            # (shm_handler._write_generation)
+            # (shm_handler._stream), summed over a generation's pieces
             "dlrover_ckpt_d2h_seconds_total": float(
                 self._shm_handler.d2h_s_total),
             "dlrover_ckpt_shm_copy_seconds_total": float(
                 self._shm_handler.shm_copy_s_total),
+            "dlrover_ckpt_d2h_bytes_total": float(
+                self._shm_handler.d2h_bytes_total),
             "dlrover_ckpt_bytes_committed_total": float(
                 self._shm_handler.bytes_written_total),
             "dlrover_ckpt_committed_step": float(self._latest_memory_step),

@@ -84,48 +84,75 @@ def _key_name(k) -> str:
     return str(k)
 
 
-def _local_shards(leaf) -> Tuple[Tuple[int, ...], str, List[Dict], List[np.ndarray]]:
-    """(global_shape, dtype, shard_metas, shard_arrays) for one leaf.
+# Bytes of host copies started and not yet consumed, per device, that one
+# commit keeps in flight.  Each transfer ends in a host-side relayout
+# (``XlaDelinearize``, ~0.7 GB/s on one runtime thread), and the device
+# loses time in step with how many of them run at once.  Read on a TPU
+# v5e with a 4.2 GB state of 38 leaves, the largest 256 MiB, saved
+# beside 145 ms steps (PERF.md section 6, PR 27: ms lost a save / s a
+# commit, medians): nothing ahead 278 / 7.77; 384 MiB (small pieces
+# ahead, never two of the largest) 213 / 6.95; 512 MiB (two of the
+# largest) 319 / 4.95; 1 GiB 776 / 3.72; the whole state 2 225 / 4.17.
+# Kept: the largest whose loss stays within 10 % of nothing ahead.
+D2H_BUDGET_BYTES = 384 * 2**20
 
-    Each shard meta: {"index": [[start, stop], ...] per dim, "shape": [...]}.
-    Deduplicates replicated shards (one copy per distinct index).
+
+@dataclasses.dataclass
+class _Piece:
+    """One (leaf, distinct shard) of a generation: the object whose bytes
+    are read, and their place in the segment."""
+
+    data: Any  # a single-device ``jax.Array`` (``shard.data``) or ndarray
+    device: Any  # None for a host leaf: nothing to bring over
+    offset: int
+    nbytes: int
+
+
+def _distinct_shards(leaf) -> Tuple[Tuple[int, ...], np.dtype, List[Tuple[List, Any, Any]]]:
+    """(global_shape, dtype, [(index, data, device)]) for one leaf, from
+    shapes alone: no byte leaves the device here.
+
+    ``index`` is [[start, stop], ...] per dim; replicated shards are
+    de-duplicated (one per distinct index).  ``data`` is the very object
+    the pass later reads: a host copy started on the LEAF would land on
+    another object (``shard.data is leaf`` is False even on one device)
+    and the read would transfer the bytes a second time.
     """
     import jax
 
     if isinstance(leaf, jax.Array) and hasattr(leaf, "addressable_shards"):
         global_shape = tuple(leaf.shape)
-        dtype = np.dtype(leaf.dtype).name
         seen = set()
-        metas, arrays = [], []
+        shards = []
         for shard in leaf.addressable_shards:
-            idx = shard.index
             key = tuple(
                 (s.start or 0, s.stop if s.stop is not None else dim)
-                for s, dim in zip(idx, global_shape)
+                for s, dim in zip(shard.index, global_shape)
             )
             if key in seen:
                 continue
             seen.add(key)
-            data = np.asarray(shard.data)
-            metas.append(
-                {
-                    "index": [[a, b] for a, b in key],
-                    "shape": list(data.shape),
-                }
-            )
-            arrays.append(data)
-        if not metas:  # 0-dim / fully local fallback
-            data = np.asarray(leaf)
-            metas = [{"index": [], "shape": list(data.shape)}]
-            arrays = [data]
-        return global_shape, dtype, metas, arrays
+            shards.append(([[a, b] for a, b in key], shard.data, shard.device))
+        if not shards:  # fully local fallback
+            shards = [([], leaf, None)]
+        return global_shape, np.dtype(leaf.dtype), shards
     data = np.asarray(leaf)
     return (
         tuple(data.shape),
-        np.dtype(data.dtype).name,
-        [{"index": [[0, d] for d in data.shape], "shape": list(data.shape)}],
-        [data],
+        data.dtype,
+        [([[0, d] for d in data.shape], data, None)],
     )
+
+
+def _start_host_copy(data) -> None:
+    """Begin the device -> host transfer of one piece (returns at once)."""
+    data.copy_to_host_async()
+
+
+def _host_bytes(data) -> np.ndarray:
+    """The piece on the host: waits for the copy started on ``data``, or
+    makes it now if none was (a host leaf is itself)."""
+    return np.asarray(data)
 
 
 @dataclasses.dataclass
@@ -160,6 +187,10 @@ class SharedMemoryHandler:  # dlint: disable=DL011 worker restore and agent pers
         self.d2h_s_total = 0.0
         self.shm_copy_s_total = 0.0
         self.bytes_written_total = 0
+        # bytes of every array a host copy was started on, or that was
+        # read with none started: 1.00 x bytes_written_total when each
+        # piece crosses once
+        self.d2h_bytes_total = 0
 
     # -- write side (training process) ----------------------------------
     def save_state_dict(self, state: Any, step: int) -> None:
@@ -176,59 +207,31 @@ class SharedMemoryHandler:  # dlint: disable=DL011 worker restore and agent pers
         buffer WITHOUT publishing; returns the publish record.  Split
         from :meth:`_publish` so the commit-marker protocol is directly
         testable (a staged-but-unpublished generation must be invisible
-        to every reader)."""
-        # Stage ALL leaves' D2H DMA first, then consume: the copies
-        # overlap across shards and the save pause approaches
-        # max(total D2H, shm memcpy) instead of their serial sum
-        # (reference engine.py: the async-copy half of its save pause).
-        import jax
+        to every reader).
 
-        t_d2h = time.perf_counter()
-        with span("dlrover.ckpt.d2h_dispatch"):
-            for leaf in jax.tree_util.tree_leaves(state):
-                if isinstance(leaf, jax.Array):
-                    leaf.copy_to_host_async()
-        # from here the thread waits for the bytes: the commit marker's
-        # first phase and the shard walk run beside the copies in flight
-        with span("dlrover.ckpt.d2h_wait"):
+        ONE streaming pass over the (leaf, distinct shard) pieces: the
+        generation is laid out from shapes and dtypes alone and its
+        segment made ready before a byte moves; then each piece's host
+        copy is started on the object that is read, at most
+        ``D2H_BUDGET_BYTES`` a device started and not yet consumed, and
+        the pieces are consumed in order: wait for one, copy it into its
+        place in the segment, let it go, start the next.  The shm copy
+        of piece k runs beside the transfer of piece k+1; the state
+        crosses to the host once and is never whole on the host side of
+        this pass."""
+        with span("dlrover.ckpt.shm_alloc"):
             committed = self._meta.get() or {}
             generation = int(committed.get("generation", 0)) + 1
             buf = generation % self.NUM_BUFFERS
             buffer_generations = dict(
                 committed.get("buffer_generations") or {})
+            metas, pieces, total = self._lay_out(state)
             # commit marker, phase 1: record the attempt (a restore
             # ignores ``inflight``; a postmortem reads inflight >
             # generation as "a save died mid-copy")
             self._meta.set({"inflight": generation})
-            pairs = leaf_paths(state)
-            metas: Dict[str, Dict] = {}
-            buffers: List[Tuple[int, np.ndarray]] = []
-            offset = 0
-            for path, leaf in pairs:
-                gshape, dtype, shard_metas, arrays = _local_shards(leaf)
-                for m, arr in zip(shard_metas, arrays):
-                    arr = np.ascontiguousarray(arr)
-                    m["offset"] = offset
-                    m["nbytes"] = arr.nbytes
-                    buffers.append((offset, arr))
-                    offset += arr.nbytes
-                metas[path] = {
-                    "global_shape": list(gshape),
-                    "dtype": dtype,
-                    "shards": shard_metas,
-                }
-        total = offset
-        self.d2h_s_total += time.perf_counter() - t_d2h
-        with span("dlrover.ckpt.shm_alloc"):
             self._ensure_shm(total, buf)
-        mv = self._shm[buf].buf
-        t_copy = time.perf_counter()
-        with span("dlrover.ckpt.shm_copy", bytes=total):
-            for off, arr in buffers:
-                # single host copy straight into shm (no tobytes() staging)
-                dst = np.ndarray(arr.shape, arr.dtype, buffer=mv, offset=off)
-                np.copyto(dst, arr)
-        self.shm_copy_s_total += time.perf_counter() - t_copy
+        self._stream(pieces, self._shm[buf].buf)
         self.bytes_written_total += total
         buffer_generations[str(buf)] = generation
         return {
@@ -240,6 +243,72 @@ class SharedMemoryHandler:  # dlint: disable=DL011 worker restore and agent pers
             "buffer": buf,
             "buffer_generations": buffer_generations,
         }
+
+    @staticmethod
+    def _lay_out(state: Any) -> Tuple[Dict[str, Dict], List[_Piece], int]:
+        """(leaf metas, pieces in segment order, total bytes)."""
+        metas: Dict[str, Dict] = {}
+        pieces: List[_Piece] = []
+        offset = 0
+        for path, leaf in leaf_paths(state):
+            gshape, dtype, shards = _distinct_shards(leaf)
+            shard_metas = []
+            for index, data, device in shards:
+                shape = tuple(data.shape)
+                nbytes = int(np.prod(shape, dtype=np.int64)) * dtype.itemsize
+                shard_metas.append({"index": index, "shape": list(shape),
+                                    "offset": offset, "nbytes": nbytes})
+                pieces.append(_Piece(data, device, offset, nbytes))
+                offset += nbytes
+            metas[path] = {
+                "global_shape": list(gshape),
+                "dtype": dtype.name,
+                "shards": shard_metas,
+            }
+        return metas, pieces, offset
+
+    def _stream(self, pieces: List[_Piece], mv: memoryview) -> None:
+        """Bring ``pieces`` to the host and into ``mv``, in order, with a
+        bounded look-ahead.  Consumes the list: a piece is let go of as
+        soon as its bytes are in the segment."""
+        in_flight: Dict[Any, int] = {}
+        ahead = 0  # pieces[:ahead] have had their host copy considered
+        for k in range(len(pieces)):
+            # copies start in order; the piece about to be read always
+            # may (a piece larger than the budget goes alone), one
+            # beyond it only inside its device's budget
+            while ahead < len(pieces):
+                nxt = pieces[ahead]
+                if nxt.device is not None:
+                    held = in_flight.get(nxt.device, 0)
+                    if ahead > k and held + nxt.nbytes > D2H_BUDGET_BYTES:
+                        break
+                    t0 = time.perf_counter()
+                    with span("dlrover.ckpt.d2h_dispatch", bytes=nxt.nbytes):
+                        _start_host_copy(nxt.data)
+                    self.d2h_s_total += time.perf_counter() - t0
+                    self.d2h_bytes_total += nxt.nbytes
+                    in_flight[nxt.device] = held + nxt.nbytes
+                ahead += 1
+            piece, pieces[k] = pieces[k], None
+            t0 = time.perf_counter()
+            with span("dlrover.ckpt.d2h_wait", bytes=piece.nbytes,
+                      in_flight=sum(in_flight.values())):
+                host = _host_bytes(piece.data)
+            t1 = time.perf_counter()
+            with span("dlrover.ckpt.shm_copy", bytes=piece.nbytes):
+                # single host copy straight into shm (no staging)
+                dst = np.ndarray(host.shape, host.dtype, buffer=mv,
+                                 offset=piece.offset)
+                np.copyto(dst, host)
+            del host, dst
+            self.d2h_s_total += t1 - t0
+            self.shm_copy_s_total += time.perf_counter() - t1
+            if piece.device is None:
+                # a host leaf: read where it lies, no copy was started
+                self.d2h_bytes_total += piece.nbytes
+            else:
+                in_flight[piece.device] -= piece.nbytes
 
     def _publish(self, record: Dict[str, Any]) -> None:
         """Commit marker, phase 2: one atomic meta update flips the

@@ -455,6 +455,12 @@ METRIC_HELP: Dict[str, str] = {
     "dlrover_ckpt_bytes_committed_total": (
         "bytes written into shm generations by this process"
     ),
+    "dlrover_ckpt_d2h_bytes_total": (
+        "bytes of every array a device-to-host copy was started on "
+        "(or that was read with none started) while writing "
+        "generations; over bytes_committed it reads 1.00 when each "
+        "piece of the state crosses to the host once"
+    ),
     "dlrover_ckpt_saves_skipped_total": (
         "memory saves refused at staging because the previous commit "
         "was still in flight past STAGE_BARRIER_S (training never "

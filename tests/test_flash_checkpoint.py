@@ -471,3 +471,310 @@ def test_commit_quarantines_stage_gutted_during_rename(tmp_path):
         assert not os.path.exists(tracker) or "9" not in open(tracker).read()
     finally:
         saver.stop()
+
+
+# -- the commit's streaming pass (ISSUE 27): one transfer a piece, a
+# -- bounded number of bytes in flight, nothing kept once consumed -------
+
+LEAF = 1024  # float32 elements of a stream-test leaf: 4 KiB
+
+
+def _stream_state(kind):
+    """(state, {path: full numpy value}) for one family of leaves."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    full = {f"w{i}": (np.arange(LEAF, dtype=np.float32) + 1000.0 * i
+                      ).reshape(8, LEAF // 8) for i in range(5)}
+    if kind == "host_numpy":
+        return dict(full), full
+    if kind == "single_device":
+        return {k: jnp.asarray(v) for k, v in full.items()}, full
+    if kind == "zero_dim":
+        full = dict(full, step=np.array(7, np.int32),
+                    scale=np.array(0.5, np.float32))
+        state = {k: jnp.asarray(v) for k, v in full.items()}
+        state["scale"] = full["scale"]  # a 0-dim host leaf beside a device one
+        return state, full
+    mesh = Mesh(np.array(jax.devices()[:4]), ("x",))
+    spec = {"sharded": P("x", None), "replicated_on_mesh": P()}[kind]
+    return {k: jax.device_put(v, NamedSharding(mesh, spec))
+            for k, v in full.items()}, full
+
+
+STREAM_KINDS = ("single_device", "sharded", "replicated_on_mesh",
+                "host_numpy", "zero_dim")
+# less than one leaf's piece on any of the meshes, and several leaves
+STREAM_BUDGETS = {"under_one_piece": 64, "several_leaves": 3 * LEAF * 4 + 64}
+
+
+class _Spy:
+    """Wraps the handler module's two touch points with the device: which
+    objects a host copy was started on, which were read, and the bytes
+    started and not yet read when each copy starts."""
+
+    def __init__(self, monkeypatch, shm_handler, budget):
+        self.budget = budget
+        self.started, self.read, self.over = [], [], []
+        self.max_open = 0  # most copies of one device started and not read
+        self._keep = []  # ids stay unique while the objects live
+        self._open = {}
+        real_start = shm_handler._start_host_copy
+        real_read = shm_handler._host_bytes
+
+        def start(data):
+            self._keep.append(data)
+            dev = next(iter(data.devices()))
+            before = sum(n for d, n in self._open.values() if d == dev)
+            self._open[id(data)] = (dev, data.nbytes)
+            self.started.append(id(data))
+            if before and before + data.nbytes > budget:
+                self.over.append((before, data.nbytes))
+            self.max_open = max(self.max_open, sum(
+                1 for d, _ in self._open.values() if d == dev))
+            return real_start(data)
+
+        def read(data):
+            self._keep.append(data)
+            self.read.append(id(data))
+            self._open.pop(id(data), None)
+            return real_read(data)
+
+        monkeypatch.setattr(shm_handler, "D2H_BUDGET_BYTES", budget)
+        monkeypatch.setattr(shm_handler, "_start_host_copy", start)
+        monkeypatch.setattr(shm_handler, "_host_bytes", read)
+
+
+@pytest.fixture()
+def stream_handler():
+    from dlrover_tpu.trainer.flash_checkpoint.shm_handler import (
+        SharedMemoryHandler,
+    )
+
+    handler = SharedMemoryHandler(local_rank=0, create=True)
+    yield handler
+    handler.close(unlink=True)
+
+
+def _stream_cases():
+    return [pytest.param(kind, budget, id=f"{kind}-{name}")
+            for kind in STREAM_KINDS
+            for name, budget in STREAM_BUDGETS.items()]
+
+
+@pytest.mark.parametrize("kind,budget", _stream_cases())
+def test_streamed_generation_restores_every_byte(
+        stream_handler, monkeypatch, kind, budget):
+    from dlrover_tpu.trainer.flash_checkpoint import shm_handler
+
+    monkeypatch.setattr(shm_handler, "D2H_BUDGET_BYTES", budget)
+    state, full = _stream_state(kind)
+    stream_handler.save_state_dict(state, step=11)
+    step, leaves, arrays = stream_handler.load_arrays()
+    assert step == 11 and set(leaves) == set(full)
+    for path, want in full.items():
+        meta = leaves[path]
+        assert meta["global_shape"] == list(want.shape)
+        assert meta["dtype"] == want.dtype.name
+        covered = np.zeros(want.shape, bool)
+        for i, shard in enumerate(meta["shards"]):
+            region = tuple(slice(a, b) for a, b in shard["index"])
+            np.testing.assert_array_equal(arrays[(path, i)], want[region])
+            covered[region] = True
+        assert covered.all(), f"{path}: shards leave a hole"
+    del arrays  # shm views must die before the segment closes
+
+
+@pytest.mark.parametrize("kind,budget", _stream_cases())
+def test_each_piece_crosses_to_the_host_once(
+        stream_handler, monkeypatch, kind, budget):
+    """A host copy starts exactly once on each distinct shard object and
+    that object is the one read; nothing is read that was not laid out."""
+    from dlrover_tpu.trainer.flash_checkpoint import shm_handler
+
+    spy = _Spy(monkeypatch, shm_handler, budget)
+    state, full = _stream_state(kind)
+    rec = stream_handler._write_generation(state, step=1)
+    pieces = sum(len(m["shards"]) for m in rec["leaves"].values())
+    assert len(spy.read) == len(set(spy.read)) == pieces
+    assert len(spy.started) == len(set(spy.started))
+    assert set(spy.started) <= set(spy.read)
+    host = {"host_numpy": pieces, "zero_dim": 1}.get(kind, 0)
+    assert len(spy.started) == pieces - host  # a host leaf needs no copy
+    assert stream_handler.d2h_bytes_total == rec["total_bytes"] \
+        == stream_handler.bytes_written_total
+    # and again: the counters stay one to one over generations
+    stream_handler._write_generation(state, step=2)
+    assert stream_handler.d2h_bytes_total \
+        == stream_handler.bytes_written_total == 2 * rec["total_bytes"]
+
+
+@pytest.mark.parametrize("kind,budget", _stream_cases())
+def test_bytes_in_flight_stay_inside_the_budget(
+        stream_handler, monkeypatch, kind, budget):
+    """Per device: a copy starts beside others only inside the budget; a
+    piece larger than the budget goes alone."""
+    from dlrover_tpu.trainer.flash_checkpoint import shm_handler
+
+    spy = _Spy(monkeypatch, shm_handler, budget)
+    state, _ = _stream_state(kind)
+    stream_handler.save_state_dict(state, step=1)
+    assert spy.over == []
+    if kind == "host_numpy":
+        assert spy.max_open == 0  # nothing to bring over
+    elif budget < LEAF:
+        assert spy.max_open == 1  # no look-ahead: one piece at a time
+    else:
+        assert spy.max_open > 1   # the look-ahead the budget allows is used
+
+
+def _engine(tmp_path, **kw):
+    from dlrover_tpu.trainer.flash_checkpoint.engine import CheckpointEngine
+
+    return CheckpointEngine(str(tmp_path / "ckpt"),
+                            saver_mode=SaverMode.LOCAL, **kw)
+
+
+def _watch_snapshots(engine):
+    """Weak references to the leaves of every device snapshot the engine
+    takes from now on."""
+    import weakref
+
+    import jax
+
+    refs = []
+    real = engine._snapshot_state
+
+    def snapshot(state):
+        staged = real(state)
+        refs.extend(weakref.ref(leaf)
+                    for leaf in jax.tree_util.tree_leaves(staged))
+        return staged
+
+    engine._snapshot_state = snapshot
+    return refs
+
+
+@pytest.mark.parametrize("kind", ["single_device", "sharded"])
+def test_snapshot_is_let_go_when_its_commit_ends(tmp_path, kind):
+    """After ``flush()`` neither the engine nor the handler holds the
+    device snapshot (a second copy of the state in HBM): not until the
+    next save's pick-up, and not after a failed commit either."""
+    import gc
+
+    engine = _engine(tmp_path)
+    snapshots = _watch_snapshots(engine)
+    state, full = _stream_state(kind)
+    try:
+        assert engine.save_to_memory(1, state)
+        assert engine.flush(timeout=60)
+        gc.collect()
+        assert len(snapshots) == len(full)
+        assert all(ref() is None for ref in snapshots)
+        assert engine.ckpt_metrics()["dlrover_ckpt_committed_step"] == 1
+        # the caller's own arrays were never touched
+        for path, want in full.items():
+            assert not state[path].is_deleted()
+            np.testing.assert_array_equal(np.asarray(state[path]), want)
+    finally:
+        engine.close()
+
+
+def test_sync_save_leaves_the_callers_arrays_alive(tmp_path, monkeypatch):
+    """``DLROVER_CKPT_SYNC_SAVE=1`` hands the CALLER's arrays to the same
+    pass: they come back undeleted and readable, and nothing of the
+    engine keeps them once the caller lets go."""
+    import gc
+    import weakref
+
+    monkeypatch.setenv("DLROVER_CKPT_SYNC_SAVE", "1")
+    engine = _engine(tmp_path)
+    state, full = _stream_state("single_device")
+    try:
+        assert engine.save_to_memory(4, state)
+        m = engine.ckpt_metrics()
+        assert m["dlrover_ckpt_committed_step"] == 4
+        assert m["dlrover_ckpt_d2h_bytes_total"] \
+            == m["dlrover_ckpt_bytes_committed_total"] > 0
+        for path, want in full.items():
+            assert not state[path].is_deleted()
+            np.testing.assert_array_equal(np.asarray(state[path]), want)
+        refs = [weakref.ref(leaf) for leaf in state.values()]
+        del state
+        gc.collect()
+        assert all(ref() is None for ref in refs)
+    finally:
+        engine.close()
+
+
+@pytest.mark.parametrize("dies_at", [1, 3])
+def test_writer_death_between_pieces_keeps_previous_generation(
+        stream_handler, monkeypatch, dies_at):
+    """A writer that dies with some pieces of generation 2 in the segment
+    and others not leaves generation 1 committed, byte for byte."""
+    from dlrover_tpu.trainer.flash_checkpoint import shm_handler
+
+    first, full = _stream_state("single_device")
+    stream_handler.save_state_dict(first, step=1)
+    real_read = shm_handler._host_bytes
+    reads = []
+
+    def read(data):
+        reads.append(data)
+        if len(reads) > dies_at:
+            raise RuntimeError("writer killed between pieces")
+        return real_read(data)
+
+    monkeypatch.setattr(shm_handler, "D2H_BUDGET_BYTES", LEAF * 4 * 2)
+    monkeypatch.setattr(shm_handler, "_host_bytes", read)
+    second = {k: v + 1.0 for k, v in first.items()}
+    with pytest.raises(RuntimeError, match="between pieces"):
+        stream_handler.save_state_dict(second, step=2)
+    assert len(reads) == dies_at + 1
+    meta = stream_handler.get_meta()
+    assert meta.valid and meta.step == 1 and meta.generation == 1
+    # the attempt is on record for a postmortem, and nothing else moved
+    assert stream_handler._meta.get()["inflight"] == 2
+    step, leaves, arrays = stream_handler.load_arrays()
+    assert step == 1
+    for path, want in full.items():
+        np.testing.assert_array_equal(arrays[(path, 0)], want)
+    del arrays
+    # the next save takes the same generation number and commits
+    monkeypatch.setattr(shm_handler, "_host_bytes", real_read)
+    stream_handler.save_state_dict(second, step=3)
+    meta = stream_handler.get_meta()
+    assert meta.valid and meta.step == 3 and meta.generation == 2
+    step, leaves, arrays = stream_handler.load_arrays()
+    for path, want in full.items():
+        np.testing.assert_array_equal(arrays[(path, 0)], want + 1.0)
+    del arrays
+
+
+def test_failed_async_commit_lets_the_snapshot_go(tmp_path, monkeypatch):
+    """The engine's side of a death between pieces: the error is counted,
+    the committed step stands, and the snapshot is not kept."""
+    import gc
+
+    from dlrover_tpu.trainer.flash_checkpoint import shm_handler
+
+    engine = _engine(tmp_path)
+    state, _ = _stream_state("single_device")
+    try:
+        assert engine.save_to_memory(1, state, block=True)
+        snapshots = _watch_snapshots(engine)
+
+        def dies(data):
+            raise RuntimeError("writer killed between pieces")
+
+        monkeypatch.setattr(shm_handler, "_host_bytes", dies)
+        assert engine.save_to_memory(2, state)
+        assert engine.flush(timeout=60)
+        gc.collect()
+        m = engine.ckpt_metrics()
+        assert m["dlrover_ckpt_save_errors_total"] == 1
+        assert m["dlrover_ckpt_committed_step"] == 1
+        assert snapshots and all(ref() is None for ref in snapshots)
+    finally:
+        engine.close()

@@ -77,6 +77,7 @@ NEW_COUNTERS = ("dlrover_ckpt_d2h_seconds_total",
                 "dlrover_ckpt_shm_copy_seconds_total",
                 "dlrover_ckpt_lock_wait_seconds_total",
                 "dlrover_ckpt_bytes_committed_total",
+                "dlrover_ckpt_d2h_bytes_total",
                 "dlrover_ckpt_saves_skipped_total")
 
 
@@ -159,8 +160,10 @@ def _train_run(tmp):
         assert engine.flush(timeout=60.0)
     after = engine.ckpt_metrics()
     trainer.close()
+    leaves = len(jax.tree_util.tree_leaves(trainer.state))
     return {"parsed": _parse(tmp / "trace"), "before": before,
-            "after": after, "saved": saved, "trainer_step": trainer.step}
+            "after": after, "saved": saved, "trainer_step": trainer.step,
+            "leaves": leaves}
 
 
 @pytest.fixture(scope="module")
@@ -303,6 +306,38 @@ def test_commit_children_tile_the_commit(job, tmp_path):
             == pytest.approx(commit - children, abs=1e-6)
     finally:
         engine.close()
+
+
+def test_commit_has_one_d2h_wait_a_piece_with_its_bytes(train_run):
+    """The streaming pass: under each commit one ``d2h_wait`` and one
+    ``shm_copy`` a piece (here a leaf: one device), one ``d2h_dispatch`` a
+    device piece, each with the piece's ``bytes``; the waits' bytes add up
+    to what the counters say crossed and was committed."""
+    parsed = train_run["parsed"]
+    before, after = train_run["before"], train_run["after"]
+    commits = ps.named(parsed, "dlrover.ckpt.commit")
+    waits = ps.named(parsed, "dlrover.ckpt.d2h_wait")
+    copies = ps.named(parsed, "dlrover.ckpt.shm_copy")
+    dispatches = ps.named(parsed, "dlrover.ckpt.d2h_dispatch")
+    pieces = train_run["leaves"]
+    assert len(commits) == 2
+    assert len(waits) == len(copies) == len(dispatches) == 2 * pieces
+    for line, start, dur, _ in waits + copies + dispatches:
+        assert any(cl == line and s <= start and s + d >= start + dur
+                   for cl, s, d, _ in commits)
+    crossed = after["dlrover_ckpt_d2h_bytes_total"] \
+        - before["dlrover_ckpt_d2h_bytes_total"]
+    assert sum(a["bytes"] for _, _, _, a in waits) == crossed \
+        == sum(a["bytes"] for _, _, _, a in dispatches) \
+        == after["dlrover_ckpt_bytes_committed_total"] \
+        - before["dlrover_ckpt_bytes_committed_total"]
+    # the bytes in flight as a wait opens: its own piece at the least,
+    # never more than the budget beside a piece that fits it
+    from dlrover_tpu.trainer.flash_checkpoint import shm_handler
+
+    for _, _, _, a in waits:
+        assert a["bytes"] <= a["in_flight"] <= max(
+            a["bytes"], shm_handler.D2H_BUDGET_BYTES)
 
 
 def test_a_save_skipped_at_the_barrier_is_counted(job, tmp_path):
