@@ -465,7 +465,8 @@ def num_data_shards(spec: MeshSpec) -> int:
 def model_flops_per_token(cfg, seq_len: Optional[int] = None) -> float:
     """Training model-FLOPs per token: ``6*N`` for the matmuls plus the
     attention quadratic term (``12 * L * s * h`` fwd+bwd).  The single
-    source of the MFU numerator used by bench.py and the probes —
+    source of an MFU numerator (its last caller left the tree with
+    the pre-chip benchmark, ROADMAP D7) —
     recompute from rematerialization is deliberately NOT counted (it
     shows up as lost MFU, keeping the accounting honest)."""
     n = cfg.num_params

@@ -14,9 +14,8 @@ batch that sequences enter and leave independently —
 - ``int8=True`` serves pre-quantized int8 weights through XLA's native
   int8 MXU dot (weights stream from HBM at half the bf16 bytes — decode
   is bandwidth-bound, so this is the serving speedup; measured against
-  the hand-tiled Pallas alternative in
-  benchmarks/probes/int8_decode_probe*, the native dot wins at every
-  serving shape).
+  the hand-tiled Pallas alternative, the native dot wins at every
+  serving shape: the numbers are in ``serving/model._mm``).
 
 Static shapes everywhere: prompts right-pad to power-of-two buckets,
 the decode batch is fixed at ``max_slots``, EOS only masks. One compile
@@ -199,8 +198,8 @@ class InferenceEngine:
         the continuous batch the placement ledger can admit at fixed
         HBM.  ``kv_dtype="int4"`` packs two codes per byte (split-half
         nibbles, even head_dim required) for ``kv_budget_x`` ~3.7x —
-        coarser rounding, bounded by the drift tests and the bench's
-        ``kv4_ok`` greedy-agreement gate.
+        coarser rounding, bounded by the drift tests of
+        ``tests/test_paged_kernel.py``.
 
         ``attention_impl`` selects the paged decode attention read:
         ``"xla"`` = fused gather (materializes the dequantized dense
@@ -476,7 +475,7 @@ class InferenceEngine:
         """One-shot timing of both paged attention impls on THIS
         engine's pools at worst-case context (every table column
         live): the evidence behind the auto-pick, kept on the engine
-        (``attention_impl_us``) so the bench can print it.  The
+        (``attention_impl_us``) so a worker's report can print it.  The
         kernel's time follows the lengths it is handed, the gather's
         does not: at FULL lengths this is the kernel's slowest case,
         so a pick of the kernel holds at every shorter context, and a

@@ -41,21 +41,20 @@ from dlrover_tpu.ops.attention import dot_product_attention
 from dlrover_tpu.rl.generation import select_token
 
 
-def _mm(x: jax.Array, w: Any, dtype, wide: bool = False) -> jax.Array:
+def _mm(x: jax.Array, w: Any, dtype) -> jax.Array:
     """x @ w for fp or pre-quantized ({"q","scale"}) weights.
 
     Every int8 matmul — decode AND prefill — runs XLA's NATIVE int8
     dot: per-row activation scales, int8xint8 -> int32 on the MXU,
     per-column weight scales applied on the OUTPUT (column scales
     commute with the contraction, so this matches dequantize-first
-    numerics).  Measured on v5e (benchmarks/probes/int8_decode_probe*):
-    at decode shapes (M=8, h2048) the native dot streams weights at
-    331 GB/s vs the Pallas kernel's 259 and bf16's wins grow with N
-    (square 1.25x, qkv-fused 1.51x, lm head 1.83x) — XLA's own
-    pipeline beats the hand-tiled kernel at every serving shape, so
-    the Pallas path is gone (it remains in ops/ for the training-side
-    frozen-layer use).  ``wide`` is kept for call-site documentation
-    only.
+    numerics).  Measured on v5e by the int8 decode probes of commit
+    3d8b828 (since deleted; this comment is the record): at decode
+    shapes (M=8, h2048) the native dot streams weights at 331 GB/s vs
+    the Pallas kernel's 259 and bf16's wins grow with N (square 1.25x,
+    qkv-fused 1.51x, lm head 1.83x) — XLA's own pipeline beats the
+    hand-tiled kernel at every serving shape, so the Pallas path is
+    gone (it remains in ops/ for the training-side frozen-layer use).
     """
     if isinstance(w, dict):
         amax = jnp.maximum(
@@ -117,20 +116,20 @@ def _qkv_split(cfg: LlamaConfig, qkv: jax.Array):
     )
 
 
-def _attn_proj(lp, h, cfg: LlamaConfig, dtype, wide: bool = False):
+def _attn_proj(lp, h, cfg: LlamaConfig, dtype):
     """q/k/v projections for either param layout: fused ``wqkv``
     (single-chip decode: fewer, larger launches) or unfused
     ``wq/wk/wv`` (tensor-parallel serving: per-matrix column sharding
     keeps head semantics — params.py shard_serving_state)."""
     d = cfg.head_dim_
     if "wqkv" in lp:
-        qkv = _mm(h, lp["wqkv"], dtype, wide)
+        qkv = _mm(h, lp["wqkv"], dtype)
         if "bqkv" in lp:  # Qwen2-family qkv biases
             qkv = qkv + lp["bqkv"].astype(dtype)
         return _qkv_split(cfg, qkv)
 
     def one(wn: str, bn: str, heads: int):
-        y = _mm(h, lp[wn], dtype, wide)
+        y = _mm(h, lp[wn], dtype)
         if bn in lp:
             y = y + lp[bn].astype(dtype)
         return _split_heads(y, heads, d)
@@ -142,15 +141,15 @@ def _attn_proj(lp, h, cfg: LlamaConfig, dtype, wide: bool = False):
     )
 
 
-def _mlp(lp, h, cfg: LlamaConfig, dtype, wide: bool = False):
+def _mlp(lp, h, cfg: LlamaConfig, dtype):
     f = cfg.intermediate_size
     if "wgu" in lp:
-        gu = _mm(h, lp["wgu"], dtype, wide)
+        gu = _mm(h, lp["wgu"], dtype)
         act = jax.nn.silu(gu[..., :f]) * gu[..., f:]
     else:
-        act = jax.nn.silu(_mm(h, lp["wgate"], dtype, wide)) * _mm(
-            h, lp["wup"], dtype, wide)
-    return _mm(act, lp["down"], dtype, wide)
+        act = jax.nn.silu(_mm(h, lp["wgate"], dtype)) * _mm(
+            h, lp["wup"], dtype)
+    return _mm(act, lp["down"], dtype)
 
 
 def decode_step(
@@ -452,15 +451,15 @@ def prefill(
     for i in range(cfg.num_layers):
         lp = _layer_weights(params["layers"], i)
         h = _rmsnorm(x, lp["input_norm"], cfg.rms_norm_eps).astype(dtype)
-        q, k, v = _attn_proj(lp, h, cfg, dtype, wide=True)
+        q, k, v = _attn_proj(lp, h, cfg, dtype)
         q = apply_rope(q, angles)
         k = apply_rope(k, angles)
         o = dot_product_attention(q, k, v, causal=True,
                                   sp_ulysses=False).astype(dtype)
         o = o.reshape(o.shape[0], lp_len, cfg.num_heads * d)
-        x = x + _mm(o, lp["wo"], dtype, wide=True)
+        x = x + _mm(o, lp["wo"], dtype)
         h = _rmsnorm(x, lp["post_norm"], cfg.rms_norm_eps).astype(dtype)
-        x = x + _mlp(lp, h, cfg, dtype, wide=True)
+        x = x + _mlp(lp, h, cfg, dtype)
         ks.append(k)
         vs.append(v)
     x = _rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)

@@ -22,9 +22,8 @@ Layers (one module each):
 - :mod:`slo`       — per-priority objectives + multi-window error-
   budget burn rates; the SLO-pressure autoscale signal;
 - :mod:`loadgen`   — seeded replayable open-loop traffic generator +
-  the 10k-QPS gateway rig (bench.py --config gateway) and the FULL-
-  pipeline router rig (admission -> placement -> streamed tokens ->
-  DONE; bench.py --config router);
+  the gateway rig and the FULL-pipeline router rig (admission ->
+  placement -> streamed tokens -> DONE), both driven by tier-1 tests;
 - :mod:`metrics`   — Prometheus gauges/counters for all of the above;
 - :mod:`router`    — the orchestrating pump, behind the step-engine
   seam (``step_engine="event" | "sweep"``);

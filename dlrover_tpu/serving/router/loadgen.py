@@ -13,8 +13,8 @@ and drives it at the in-process serving stack:
   (Pareto) or fixed prompt lengths, and a per-priority mix.  Same
   config + seed -> byte-identical schedule, so a perf regression
   re-runs the EXACT offered load that exposed it;
-- :func:`run_gateway_rig` — the bench harness (``bench.py --config
-  gateway``): replays a schedule against a router wall-clock
+- :func:`run_gateway_rig` — the gateway harness (``tests/test_otlp.py``
+  drives it): replays a schedule against a router wall-clock
   open-loop, measuring what the GATEWAY itself costs — per-request
   admission latency (the ``submit()`` call: validation, brown-out
   check, queue insert, trace creation), admission→placement wait,
@@ -23,8 +23,9 @@ and drives it at the in-process serving stack:
   is wired.  The queue bound and the brown-out ladder are expected
   to bite at rate: shed requests ARE the measurement, not a failure.
 
-- :func:`run_router_rig` — the FULL-pipeline twin (``bench.py
-  --config router``): the same open-loop schedule driven through the
+- :func:`run_router_rig` — the FULL-pipeline twin
+  (``tests/test_step_engine.py::test_router_rig_*``, ``test_tenancy``,
+  ``test_prefix_cache``): the same open-loop schedule driven through the
   WHOLE serving path — admission, placement, submit, streamed tokens,
   DONE — against a fleet of in-process engines, measuring sustained
   **end-to-end** QPS, e2e latency percentiles from the completed

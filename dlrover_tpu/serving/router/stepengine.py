@@ -4,10 +4,12 @@ The second candidate behind the step-engine seam (the first is the
 consolidated single-threaded event loop, ``ServingRouter(
 step_engine="event")`` — see router.py).  The question the ROADMAP
 poses — "single-threaded event loop or sharded routers behind a
-consistent front — pick per measurement, not per taste" — is answered
-by benchmarking BOTH on the full-pipeline open-loop rig
-(``bench.py --config router``); PERF.md "Router raw speed" records the
-A/B and the shipped default is the measured winner.
+consistent front — pick per measurement, not per taste" — was answered
+by running BOTH on the full-pipeline open-loop rig
+(``loadgen.run_router_rig``) over ``FakeEngine`` replicas on a CPU:
+CHANGES.md PR 15 records the A/B (a tie at rate, the event loop ahead
+on a deep blocked queue), and the event loop is the shipped default.
+No cell of ``BENCHMARK.json`` runs the sharded front (ROADMAP D5).
 
 Design:
 

@@ -25,18 +25,6 @@ jax.config.update("jax_platforms", "cpu")
 import pytest  # noqa: E402
 
 
-@pytest.fixture()
-def record_path(tmp_path):
-    """Where an e2e test writes its JSON record: the file of record at
-    the repo root only when ``DLROVER_REFRESH_RECORDS=1`` asks for a
-    refresh, else under ``tmp_path`` — a plain (loaded, six-worker) run
-    must not rewrite a committed record with what the load made of it."""
-    refresh = os.environ.get("DLROVER_REFRESH_RECORDS") == "1"
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__))) \
-        if refresh else str(tmp_path)
-    return lambda name: os.path.join(root, name)
-
-
 @pytest.fixture(autouse=True, scope="session")
 def _reap_worker_subprocesses():
     """Session-end sweep of serving-worker subprocesses: a test that

@@ -476,8 +476,8 @@ def test_chunked_loss_under_tensor_parallel_vocab():
 def test_offload_remat_policies_resolve():
     """Selective activation offloading policies (reference
     selective_offloading_checkpoint.py:252) resolve to callables; the
-    execution path needs a real TPU (XLA host memory spaces), covered
-    by benchmarks/offload_probe.py."""
+    execution path needs a real TPU (XLA host memory spaces) and has
+    no test here."""
     from dlrover_tpu.models.llama import resolve_remat_policy
 
     assert callable(resolve_remat_policy("offload_dots"))

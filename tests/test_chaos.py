@@ -22,6 +22,8 @@ import uuid
 import numpy as np
 import pytest
 
+from test_elastic_spmd_e2e import wait_until_listening
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -73,7 +75,7 @@ def test_master_restart_mid_run(tmp_path):
     )
     agent = None
     try:
-        time.sleep(2)
+        wait_until_listening(port, master)
         agent = subprocess.Popen(
             [sys.executable, "-m", "dlrover_tpu.agent.launcher",
              "--nnodes=1", "--node_rank=0",

@@ -124,15 +124,22 @@ def test_producer_error_raises_not_clean_end():
 def test_excluded_coworker_rejoins_after_refresh():
     """A restarted coworker at a previously-excluded address serves again
     once the refresh re-announces it."""
-    svc = CoworkerDataService(_batches(2))
-    addr = f"127.0.0.1:{svc.port}"
-    # not started yet: first contacts fail and exclude the address
+    from dlrover_tpu.common.rpc import find_free_port
+
+    port = find_free_port()
+    addr = f"127.0.0.1:{port}"
+    # nothing listens yet: first contacts are refused at once and
+    # exclude the address.  (A service bound but not started would HOLD
+    # those calls to their deadline, and the one in flight when it
+    # starts can take a batch whose reply comes too late: the batch is
+    # lost, and the test counted one of two.)
     it = RemoteBatchIterator(
-        [addr], rpc_timeout_s=0.5, max_failures=1,
+        [addr], rpc_timeout_s=5.0, max_failures=1,
         refresh_fn=lambda: [addr], refresh_interval_s=0.2,
     )
     import time as _t
     _t.sleep(1.0)  # let it fail + exclude
+    svc = CoworkerDataService(_batches(2), port=port)
     svc.start()    # "restart" the coworker
     got = sorted(int(b["i"][0]) for b in it)
     assert got == [0, 1]

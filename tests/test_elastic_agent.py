@@ -100,6 +100,10 @@ def test_two_node_rendezvous_and_env(master2, tmp_path):
             entrypoint=[sys.executable, "-c", script],
             monitor_interval=0.3,
             env={"OUT_PATH": str(out)},
+            # two agents in one process share a job uid: with the saver
+            # factory on, both bind ONE queue socket (unlink, then bind),
+            # and the loser of that race dies with EADDRINUSE
+            flash_ckpt=False,
         )
         agent = ElasticAgent(client, rank, spec)
         results[rank] = agent.run()
@@ -129,6 +133,7 @@ def test_two_node_network_check(master2):
             entrypoint=[sys.executable, "-c", "print('ok')"],
             monitor_interval=0.3,
             network_check=True,
+            flash_ckpt=False,   # as above
         )
         agent = ElasticAgent(client, rank, spec)
         results[rank] = agent.run()
@@ -165,6 +170,7 @@ def test_membership_change_triggers_restart(master2, tmp_path):
             entrypoint=[sys.executable, "-c", script],
             monitor_interval=0.3,
             env={"OUT_DIR": str(tmp_path)},
+            flash_ckpt=False,   # as above
         )
         agent = ElasticAgent(client, rank, spec)
         results[rank] = agent.run()

@@ -319,9 +319,15 @@ def test_drain_retirement_shuts_down_remote_worker(threaded_workers):
     req = router.submit(_prompt(1), 8)
     router.step()
     router.begin_drain("rw")
-    _drive(router, timeout=10.0)
+    # retirement is the step AFTER the one that takes the DONE frame:
+    # has_work goes false one step too early to wait on
+    deadline = time.monotonic() + 10.0
+    while "rw" in router.replica_names:
+        assert time.monotonic() < deadline, \
+            "drained replica never retired"
+        router.step()
+        time.sleep(0.002)
     assert req.state == ServingRequestState.DONE
-    assert "rw" not in router.replica_names
     # GOODBYE reached the worker: its serve loop shut itself down
     deadline = time.monotonic() + 5.0
     while not w.server.stop_event.is_set() \

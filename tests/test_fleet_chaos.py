@@ -107,10 +107,14 @@ class _FleetStubSupervisor(WorkerSupervisor):
     becomes a FakeEngine replica, so the borrowed host really takes
     traffic through the router's pump.  ``fail_next`` makes the next N
     spawns die mid-boot (the worker SIGKILLed before its announce —
-    exactly what the supervisor's announce timeout surfaces)."""
+    exactly what the supervisor's announce timeout surfaces).  ``clock``
+    is the harness's synthetic clock: the join happens at ITS time, so
+    the replica's first heartbeat and the router's steps share a clock
+    whatever the machine's uptime is."""
 
-    def __init__(self, **kw):
+    def __init__(self, clock, **kw):
         super().__init__(**kw)
+        self.clock = clock
         self._pid = 5000
         self.fail_next = 0
         self.boot_failures = 0
@@ -130,7 +134,8 @@ class _FleetStubSupervisor(WorkerSupervisor):
             self.workers[name] = record
         if join and self.router is not None:
             self.router.join_replica(
-                name, FakeEngine(slots=2, tokens_per_step=2))
+                name, FakeEngine(slots=2, tokens_per_step=2),
+                now=self.clock())
         self.spawn_counts[name] = self.spawn_counts.get(name, 0) + 1
         return record
 
@@ -159,7 +164,7 @@ class _Fleet:
                 f"serving-replica-{i}",
                 FakeEngine(slots=2, tokens_per_step=2), now=self.t)
         self.sup = _FleetStubSupervisor(
-            router=self.router, respawn=False,
+            lambda: self.t, router=self.router, respawn=False,
             recorder=self.router.recorder)
         self.hosts = {f"host-{r}": r for r in range(n_hosts)}
         self.ckpt = Checkpointer(

@@ -19,15 +19,20 @@ Reference counterpart: node-loss-at-scale rendezvous
 slice topology grouping (net_topology.py:62).
 """
 
-import json
 import os
 import signal
 import subprocess
 import sys
-import time
 
 import numpy as np
-import pytest
+
+from test_elastic_spmd_e2e import (
+    _read_metrics,
+    assert_steps_consistent,
+    one_world_at_a_time,
+    wait_for_rows,
+    wait_until_listening,
+)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOTAL_STEPS = 16
@@ -50,16 +55,6 @@ def _agent_cmd(node_rank, master_addr, work):
         "--metrics-file", os.path.join(work, "metrics"),
         "--step-sleep", "4.0",
     ]
-
-
-def _read_metrics(path):
-    rows = []
-    if os.path.exists(path):
-        with open(path) as f:
-            for line in f:
-                s, loss, world = line.split()
-                rows.append((int(s), float(loss), int(world)))
-    return rows
 
 
 def _start_agent(rank, port, work, agents, tag=""):
@@ -116,7 +111,8 @@ def _reference_losses():
     return losses
 
 
-def test_slice_loss_shrinks_then_regrows(tmp_path, record_path):
+@one_world_at_a_time
+def test_slice_loss_shrinks_then_regrows(tmp_path):
     work = str(tmp_path)
     from dlrover_tpu.common.rpc import find_free_port
 
@@ -129,41 +125,28 @@ def test_slice_loss_shrinks_then_regrows(tmp_path, record_path):
     )
     agents = {}
     try:
-        time.sleep(2)
+        wait_until_listening(port, master)
         for rank in range(4):
             _start_agent(rank, port, work, agents)
 
         # phase 1: the 4-host / 2-slice world must train past the kill
         # step (worker_num == 4 in the metrics)
         m0 = os.path.join(work, "metrics.r0")
-        deadline = time.time() + 600
-        while time.time() < deadline:
-            rows = _read_metrics(m0)
-            if any(s >= KILL_AFTER_STEP and w == 4 for s, _, w in rows):
-                break
-            if agents[0].poll() is not None:
-                pytest.fail("agent0 exited before the 2-slice world ran")
-            time.sleep(1)
-        else:
-            pytest.fail("2-slice world never trained to the kill step")
+        wait_for_rows(
+            m0, agents[0],
+            lambda rows: any(s >= KILL_AFTER_STEP and w == 4
+                             for s, _, w in rows),
+            600, f"the 2-slice world at step {KILL_AFTER_STEP}")
 
         # kill ONE host of slice 1 (rank 3): the whole slice must leave
         os.killpg(os.getpgid(agents[3].pid), signal.SIGKILL)
         agents[3].wait(30)
 
         # phase 2: slice 0 re-forms ALONE (worker_num == 2) and trains
-        deadline = time.time() + 600
-        shrink_seen = False
-        while time.time() < deadline:
-            rows = _read_metrics(m0)
-            if any(w == 2 for _, _, w in rows):
-                shrink_seen = True
-                break
-            if agents[0].poll() is not None:
-                break
-            time.sleep(1)
-        assert shrink_seen, (
-            f"slice 0 never trained alone: {_read_metrics(m0)}")
+        wait_for_rows(
+            m0, agents[0],
+            lambda rows: any(w == 2 for _, _, w in rows),
+            600, "slice 0 training alone")
 
         # phase 3: a replacement host for slice 1 joins -> regrow to 4
         _start_agent(3, port, work, agents, tag="b")
@@ -172,8 +155,6 @@ def test_slice_loss_shrinks_then_regrows(tmp_path, record_path):
 
         rows = _read_metrics(m0)
         worlds = {s: w for s, _, w in rows}
-        from test_elastic_spmd_e2e import assert_steps_consistent
-
         steps = assert_steps_consistent(rows, max_redos=4)  # kill+regrow x async commit
         assert steps[-1] == TOTAL_STEPS
         assert 4 in worlds.values() and 2 in worlds.values(), worlds
@@ -187,32 +168,6 @@ def test_slice_loss_shrinks_then_regrows(tmp_path, record_path):
         for s, loss, _ in rows:
             assert np.isclose(loss, ref[s - 1], rtol=1e-3, atol=1e-3), (
                 s, loss, ref[s - 1])
-
-        from dlrover_tpu.agent.master_client import MasterClient
-
-        client = MasterClient(f"127.0.0.1:{port}", node_id=9,
-                              node_type="worker")
-        try:
-            goodput = client.query_job_detail().get(
-                "metrics", {}).get("goodput", {})
-        finally:
-            client.close()
-
-        with open(record_path("MULTISLICE_E2E.json"), "w") as f:
-            json.dump(
-                {
-                    "steps": rows,
-                    "slice_unit": SLICE_UNIT,
-                    "killed_rank": 3,
-                    "killed_after_step": KILL_AFTER_STEP,
-                    "shrink_step": shrink_step,
-                    "regrow_steps": sorted(regrown),
-                    "world_phases": [4, 2, 4],
-                    "reference_match_rtol": 1e-3,
-                    "goodput": goodput,
-                },
-                f, indent=1,
-            )
     finally:
         for p in agents.values():
             if p.poll() is None:

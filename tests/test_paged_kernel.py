@@ -13,9 +13,9 @@ What's covered, and why each gate exists:
   build-time measurement feeds), and engine validation/resolution
   edges;
 - **int4 pools**: pack/unpack identity, quantization round-trip bound,
-  logit drift bounded vs the native twin (greedy agreement is gated in
-  bench on a FITTED model — random-init margins are smaller than the
-  honest 4-bit error floor, see serving_bench._fit_chain_model);
+  logit drift bounded vs the native twin (greedy agreement is NOT
+  asserted: random-init margins are smaller than the honest 4-bit
+  error floor, and it would take a fitted model to hold it);
 - **KV-budget single source**: ``paged.kv_budget_multiplier`` is THE
   formula — the engine's pool scaling, ``InferenceEngine.kv_budget_x``
   and the router-side adapter ledger are pinned to it for int8 AND
@@ -412,8 +412,7 @@ def test_int4_logit_drift_bounded_vs_native(setup):
     """Same-cache next-token logits, int4 pool vs native: drift stays
     a bounded fraction of the native logit spread.  GREEDY agreement
     is deliberately NOT asserted here — random-init margins sit below
-    the honest 4-bit error floor, so it is gated in bench on the
-    fitted chain model instead (kv4_ok)."""
+    the honest 4-bit error floor."""
     from dlrover_tpu.serving.model import verify_step
 
     cfg, _ = setup

@@ -318,8 +318,8 @@ def test_cancel_inflight_on_expiry_local_engine_reclaims_slot():
         scheduler=ContinuousBatchScheduler(block_size=4),
         cancel_inflight_on_expiry=True,
     )
-    router.join_replica("local", eng)
     t0 = 100.0
+    router.join_replica("local", eng, now=t0)
     hog = router.submit(_prompt(0), 1000, timeout=5.0, now=t0)
     waiter = router.submit(_prompt(1), 4, timeout=None, now=t0)
     router.step(now=t0 + 1.0)   # hog placed, decoding

@@ -248,9 +248,8 @@ def test_kv_int8_logit_drift_bounded_vs_native(setup):
 
     The bound is on logits, not on greedy tokens: a random-weight
     model's top-2 margins are smaller than any rounding, so its argmax
-    flips on noise the bound allows (token agreement on a model with
-    real margins is the bench's fitted-chain gate,
-    serving_bench._fit_chain_model)."""
+    flips on noise the bound allows (token agreement would need a
+    model with real margins)."""
     from dlrover_tpu.serving.model import verify_step
 
     cfg, variables = setup

@@ -195,7 +195,7 @@ class _PlanScaler:
         self.plans.append(plan)
 
 
-def _router_with_slow_engine(slo):
+def _router_with_slow_engine(slo, t0):
     router = ServingRouter(
         scheduler=ContinuousBatchScheduler(block_size=4),
         metrics=RouterMetrics(window_seconds=5.0),
@@ -204,7 +204,7 @@ def _router_with_slow_engine(slo):
     # plenty of slots: the queue never builds, but generation takes
     # long enough (driven by the synthetic clock below) to blow TTFT
     router.join_replica("r0", FakeEngine(slots=64, tokens_per_step=1,
-                                         blocks=100000))
+                                         blocks=100000), now=t0)
     return router
 
 
@@ -226,7 +226,7 @@ def _drive_slow_requests(router, auto, t0, rounds=30):
 
 def test_burn_rate_drives_scale_up_where_queue_depth_would_not():
     slo = _engine(fast=10.0, slow=40.0, target=0.9)
-    router = _router_with_slow_engine(slo)
+    router = _router_with_slow_engine(slo, t0=1000.0)
     scaler = _PlanScaler()
     auto = ServingAutoScaler(
         router, scaler,
@@ -252,7 +252,7 @@ def test_burn_rate_drives_scale_up_where_queue_depth_would_not():
     # CONTROL: identical drive with the SLO signal disabled — queue
     # depth alone never scales (proving the burn was the cause)
     slo2 = _engine(fast=10.0, slow=40.0, target=0.9)
-    router2 = _router_with_slow_engine(slo2)
+    router2 = _router_with_slow_engine(slo2, t0=1000.0)
     scaler2 = _PlanScaler()
     auto2 = ServingAutoScaler(
         router2, scaler2,
@@ -278,9 +278,9 @@ def test_router_feeds_poisoning_as_violation():
         metrics=RouterMetrics(window_seconds=5.0),
         slo=slo,
     )
-    router.join_replica("r0", FakeEngine(slots=4, tokens_per_step=1,
-                                         blocks=100000))
     t = 700.0
+    router.join_replica("r0", FakeEngine(slots=4, tokens_per_step=1,
+                                         blocks=100000), now=t)
     req = router.submit(np.full(8, 1, np.int32), 8,
                         priority=PRIORITY_NORMAL, now=t)
     router.step(now=t)           # placed on r0
