@@ -14,11 +14,29 @@ group size (``ih // reps``), so each query-head block reads its kv head's
 block directly from HBM.
 
 Forward (per batch x head x q-block, kv-blocks innermost grid dim):
-    m, l, acc scratch carried across kv blocks; causal blocks fully above
-    the diagonal are skipped with @pl.when.  LSE is written for backward.
+    m, l, acc scratch carried across kv blocks.  LSE is written for backward.
 Backward: FlashAttention-2 style — a precomputed delta = rowsum(do * o),
     one kernel accumulating dq over kv blocks, one accumulating (dk, dv)
-    over q blocks.
+    over q blocks (on K Q^T, the scores transposed).
+
+Which blocks are masked.  A grid step fetches one DMA block of scores
+([block_q, block_k], 1024 x 1024 by default) and walks it in compute
+tiles (``_for_live_tiles``).  Under a causal mask and no segment ids a
+block is one of three kinds, told apart from the program ids: above the
+diagonal it is skipped (and asks for no new K/V); wholly below it every
+tile runs with NO mask (no iota, compare or select); crossed by it, only
+the ``_SUB_TILE``-wide tiles ON the diagonal are masked, the tiles below
+run unmasked and the tiles above do not run.  That needs the diagonal to
+cross a block corner to corner (block_q == block_k, seq_offset a multiple
+of it), which the training shapes give; any other crossed block is masked
+whole.  With segment ids every tile of every block that runs keeps the
+full mask and the guard for rows it masks fully.  ``score_tiles`` counts
+all this from the shapes.
+
+Numerics: MXU operands in the inputs' dtype (``q * scale``, ``p`` and
+``ds`` rounded to it once, explicitly: with f32 operands Mosaic's default
+precision rounds to bf16 inside the matmul anyway), f32 accumulators,
+scores, softmax statistics, ``lse`` and ``delta``.
 """
 
 from __future__ import annotations
@@ -31,19 +49,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Measured on v5e (470M-class Llama, bf16, head_dim 128): 1024x1024
-# blocks are best in the FULL training step (0.70 MFU at seq 4096).
-# Note: an isolated fwd+bwd kernel microbenchmark prefers 512-wide q
-# tiles by ~16%, but the full model with remat regresses to 0.69 MFU
-# with them — tune against the end-to-end step, not the kernel alone.
-# 2048-wide blocks exceed the 16MB scoped-VMEM limit; _fwd/_bwd clamp
-# blocks to the sequence length.
-# 1024x1024: the r3 end-to-end sweep measured 2048x2048 ~0.8% faster on
-# the fwd-dominant probe, but its BACKWARD kernel exceeds the 16M scoped
-# VMEM limit in full bench compiles (22.5M stack) — 1024 is the largest
-# robust block.
-# Overridable for end-to-end sweeps (and per-deployment tuning) without
-# code edits; the values above remain the measured defaults.
+# The DMA block a grid step fetches, overridable for end-to-end sweeps (and
+# per-deployment tuning) without code edits.  2048-wide blocks exceed the
+# 16MB scoped-VMEM limit in the backward; _fwd/_bwd clamp blocks to the
+# sequence length.  The compute tile inside the block is _SUB_TILE below.
+# Measured on a TPU v5e (PR 29, device time of one call from a profiler
+# trace, bf16, seq 4096, head_dim 128, causal; before -> after this tiling):
+#   32 q / 8 kv heads (Mistral-7B): fwd 2.249 -> 1.223 ms, dq 1.623 -> 1.353,
+#     dkv 2.028 -> 1.612; a layer and step under remat (2 fwd + dq + dkv)
+#     8.149 -> 5.411 ms, 58 % of the bf16 peak on what causal attention needs
+#   16 / 16 heads (OLMoE): 1.126 -> 0.609, 0.809 -> 0.676, 1.047 -> 0.840;
+#     4.107 -> 2.735 ms
+# One tile width everywhere, 128 / 512 / 1024: the Mistral layer 6.84 / 5.54 /
+# 6.20 ms; 256 on the diagonal and 512 below it 5.41 (kept); 512 and 1024
+# 5.62.  What did NOT
+# help: splitting a tile's rows, emitting the next tile's Q K^T before this
+# tile's softmax (the scheduler already orders them), 1-D m / l (their
+# layout changes cost the forward 1.2 ms a call at 512-wide tiles).
 import os as _os
 
 def _block_from_env(var: str, default: int) -> int:
@@ -76,23 +98,165 @@ def _block_from_env(var: str, default: int) -> int:
 DEFAULT_BLOCK_Q = _block_from_env("DLROVER_FLASH_BLOCK_Q", 1024)
 DEFAULT_BLOCK_K = _block_from_env("DLROVER_FLASH_BLOCK_K", 1024)
 _NEG_INF = -1e30
+_LANES = 128
+# Compute tiles inside the DMA block (see the module docstring): the
+# diagonal is followed in steps of _SUB_TILE, and a block with no mask is
+# walked in tiles twice as wide.
+_SUB_TILE = 256
+
+_NT = (((1,), (1,)), ((), ()))  # A @ B^T
+_NN = (((1,), (0,)), ((), ()))  # A @ B
 
 
-def _block_mask(
-    q_pos: jax.Array,
-    k_pos: jax.Array,
-    causal: bool,
-    q_seg: Optional[jax.Array],
-    k_seg: Optional[jax.Array],
-) -> Optional[jax.Array]:
-    """[BQ, BK] boolean mask (True = attend) or None when unmasked."""
+def _sub_tile(block: int) -> int:
+    """The compute tile along an axis whose DMA block is ``block`` wide."""
+    return _SUB_TILE if block % _SUB_TILE == 0 else block
+
+
+def _wide_tile(block: int) -> int:
+    """The tile of a walk that follows no diagonal."""
+    tile = _sub_tile(block)
+    return 2 * tile if block % (2 * tile) == 0 else tile
+
+
+def _trimmed(block_q: int, block_k: int, causal: bool, seq_offset: int) -> bool:
+    """Whether the only blocks the diagonal crosses are those with
+    ``q0 == k0``: then a crossed block's live tiles are known when the
+    kernel is traced, and it computes those alone.  Otherwise a crossed
+    block is masked whole."""
+    return causal and block_q == block_k and seq_offset % block_q == 0
+
+
+def score_tiles(
+    sq: int, skv: int, block_q: int, block_k: int, causal: bool,
+    seq_offset: int,
+) -> Tuple[float, float, float]:
+    """``(computed, unmasked, needed)`` of one head's score matrix, in
+    units of one compute tile (``_sub_tile(block_k)`` squared): what the
+    kernels run through the MXU, how much of that pays for no mask, and
+    what the attention needs.  The kernels walk a block exactly as this
+    counts it (calls without segment ids; with them every tile of a
+    block that runs is masked)."""
+    block_q, block_k = min(block_q, sq), min(block_k, skv)
+    t = _sub_tile(block_k)
+    unit = float(t * t)
+    if not causal:
+        whole = sq * skv / unit
+        return whole, whole, whole
+    # query row i sees the keys up to its own position, i + seq_offset
+    needed = sum(min(max(i + seq_offset + 1, 0), skv) for i in range(sq)) / unit
+    trimmed = _trimmed(block_q, block_k, causal, seq_offset)
+    n = block_k // t
+    computed = unmasked = 0.0
+    for iq in range(sq // block_q):
+        q0 = iq * block_q + seq_offset
+        for ik in range(skv // block_k):
+            k0 = ik * block_k
+            block = block_q * block_k / unit
+            if q0 + block_q - 1 < k0:
+                continue  # above the diagonal
+            if q0 >= k0 + block_k - 1:
+                computed, unmasked = computed + block, unmasked + block
+            elif trimmed:  # n tiles on the diagonal, n (n - 1) / 2 below
+                computed += n * (n + 1) / 2
+                unmasked += n * (n - 1) / 2
+            else:
+                computed += block
+    return computed, unmasked, needed
+
+
+def _lanes(x: jax.Array, n: int) -> jax.Array:
+    """A per-row statistic kept on all 128 lanes, widened to ``n`` columns
+    (no lane broadcast: the same vregs again)."""
+    reps, rem = divmod(n, _LANES)
+    if rem:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return x if reps == 1 else jnp.tile(x, (1, reps))
+
+
+def _fold(p: jax.Array) -> jax.Array:
+    """The row sums of ``p`` left in 128 per-lane parts (a row's parts add
+    up to its sum): whole-vreg adds, no reduction across lanes."""
+    rows, n = p.shape
+    if n % _LANES:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+        return jnp.where(lane == 0, jnp.sum(p, axis=1, keepdims=True), 0.0)
+    return functools.reduce(
+        jnp.add, [p[:, i:i + _LANES] for i in range(0, n, _LANES)]
+    )
+
+
+def _tile_mask(
+    shape, q_start, k_start, causal: bool,
+    q_seg: Optional[jax.Array], k_seg: Optional[jax.Array], q_axis: int,
+) -> jax.Array:
+    """Boolean mask (True = attend) of a score tile whose axis ``q_axis``
+    runs over queries from ``q_start`` and the other over keys from
+    ``k_start``."""
     mask = None
     if causal:
-        mask = q_pos[:, None] >= k_pos[None, :]
+        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+        mask = q_pos >= k_pos
     if q_seg is not None:
-        seg = q_seg[:, None] == k_seg[None, :]
+        if q_axis == 0:
+            seg = q_seg[:, None] == k_seg[None, :]
+        else:
+            seg = k_seg[:, None] == q_seg[None, :]
         mask = seg if mask is None else jnp.logical_and(mask, seg)
     return mask
+
+
+def _live_k_block(iq, ik, block_q, block_k, causal, seq_offset):
+    """The k block the step (iq, ik) asks for: its own, or above the
+    diagonal, where nothing runs, the row's last live one again, so that
+    the pipeline fetches nothing for a step that computes nothing."""
+    if not causal:
+        return ik
+    return jnp.minimum(ik, (iq * block_q + seq_offset + block_q - 1) // block_k)
+
+
+def _for_live_tiles(
+    tile_fn, q0, k0, *, block_q: int, block_k: int, walk: str,
+    causal: bool, seq_offset: int, have_segs: bool,
+) -> None:
+    """Run ``tile_fn((r0, r1), (c0, c1), masked)`` for every compute tile
+    of the block at (``q0``, ``k0``) that holds a live score.  ``c0:c1``
+    is the tile's extent along the ``walk`` axis ("k" or "q") and
+    ``r0:r1`` along the other.  Three classes of block: above the
+    diagonal, nothing runs; wholly below it (or not causal), every tile
+    runs unmasked; crossed by it, the tiles on the diagonal run masked,
+    those below it unmasked and those above it not at all (or, when the
+    crossing is not known at trace time, every tile masked).  With
+    segment ids every tile of a block that runs is masked."""
+    width, other = (block_k, block_q) if walk == "k" else (block_q, block_k)
+    tile, wide = _sub_tile(width), _wide_tile(width)
+
+    def whole(masked):
+        for c in range(0, width, wide):
+            tile_fn((0, other), (c, c + wide), masked)
+
+    if not causal:
+        whole(have_segs)
+        return
+    run = q0 + block_q - 1 >= k0
+    if have_segs:
+        pl.when(run)(lambda: whole(True))
+        return
+    below = q0 >= k0 + block_k - 1
+    pl.when(below)(lambda: whole(False))
+
+    @pl.when(jnp.logical_and(run, jnp.logical_not(below)))
+    def _crossed():
+        if not _trimmed(block_q, block_k, causal, seq_offset):
+            whole(True)
+            return
+        for c in range(0, width, tile):  # here q0 == k0
+            tile_fn((c, c + tile), (c, c + tile), True)
+            if walk == "k" and c + tile < other:  # the query rows after it
+                tile_fn((c + tile, other), (c, c + tile), False)
+            if walk == "q" and c > 0:  # the key rows before it
+                tile_fn((0, c), (c, c + tile), False)
 
 
 # ---------------------------------------------------------------------------
@@ -103,63 +267,70 @@ def _block_mask(
 def _fwd_kernel(
     q_ref, k_ref, v_ref, qseg_ref, kseg_ref,
     o_ref, lse_ref,
-    m_scr, l_scr, acc_scr,
+    q_scr, m_scr, l_scr, acc_scr,
     *, causal: bool, scale: float, block_q: int, block_k: int,
     seq_offset: int, have_segs: bool,
 ):
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
+    d = acc_scr.shape[-1]
 
     @pl.when(ik == 0)
     def _init():
+        # q * scale is rounded for the MXU once a q block
+        q_scr[:] = (q_ref[0, 0].astype(jnp.float32) * scale).astype(q_scr.dtype)
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # Global positions of this block's rows/cols.  seq_offset shifts query
-    # positions (queries are the tail of the kv sequence when sq < skv).
-    q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q,), 0) + seq_offset
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k,), 0)
+    # First global position of this block's rows/cols.  seq_offset shifts
+    # query positions (queries are the tail of the kv sequence when sq < skv).
+    q0 = iq * block_q + seq_offset
+    k0 = ik * block_k
 
-    # Causal: skip blocks entirely above the diagonal.
-    run = True
-    if causal:
-        run = (iq * block_q + seq_offset) + block_q - 1 >= ik * block_k
-
-    @pl.when(run)
-    def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)
+    def _tile(rows, cols, masked):
+        (r0, r1), (c0, c1) = rows, cols
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            q_scr[r0:r1], k_ref[0, 0, c0:c1], _NT,
+            preferred_element_type=jnp.float32,
         )
-        q_seg = qseg_ref[0, 0] if have_segs else None
-        k_seg = kseg_ref[0, 0] if have_segs else None
-        mask = _block_mask(q_pos, k_pos, causal, q_seg, k_seg)
-        if mask is not None:
+        if masked:
+            mask = _tile_mask(
+                s.shape, q0 + r0, k0 + c0, causal,
+                qseg_ref[0, 0, r0:r1] if have_segs else None,
+                kseg_ref[0, 0, c0:c1] if have_segs else None, 0,
+            )
             s = jnp.where(mask, s, _NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        if mask is not None:
-            # For a fully-masked row m_new stays at -inf and exp(s - m_new)
-            # would be 1 at masked entries; force them to 0.
+        # m and l live on all 128 lanes of their rows ([rows, 128]): a
+        # 1-D statistic would change layout at every use
+        m_prev = m_scr[r0:r1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, c1 - c0))
+        if masked and have_segs:
+            # Only segment ids can mask a row fully: m_new then stays at
+            # -inf and exp(s - m_new) would be 1 at masked entries.
             p = jnp.where(mask, p, 0.0)
-        l_scr[:] = l_scr[:] * corr + jnp.sum(p, axis=1)
-        m_scr[:] = m_new
-        v = v_ref[0, 0].astype(jnp.float32)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[r0:r1] = l_scr[r0:r1] * corr + _fold(p)
+        m_scr[r0:r1] = m_new
+        v = v_ref[0, 0, c0:c1]
         pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32
         )
-        acc_scr[:] = acc_scr[:] * corr[:, None] + pv
+        acc_scr[r0:r1] = acc_scr[r0:r1] * _lanes(corr, d) + pv
+
+    _for_live_tiles(
+        _tile, q0, k0, block_q=block_q, block_k=block_k, walk="k",
+        causal=causal, seq_offset=seq_offset, have_segs=have_segs,
+    )
 
     @pl.when(ik == nk - 1)
     def _final():
-        l = l_scr[:]
+        l = jnp.sum(l_scr[:], axis=1, keepdims=True)  # the lanes' parts
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:] / l_safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0, 0] = m_scr[:] + jnp.log(l_safe)
+        o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        # every lane of m holds the row's value: the max lays it out 1-D
+        lse_ref[0, 0, 0] = jnp.max(m_scr[:], axis=1) + jnp.log(l_safe[:, 0])
 
 
 def _fwd(
@@ -188,27 +359,33 @@ def _fwd(
         jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32),
     ]
+    def kb(iq, ik):
+        return _live_k_block(iq, ik, block_q, block_k, causal, seq_offset)
+
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             pl.BlockSpec(
-                (1, 1, block_k, d), lambda ib, ih, iq, ik: (ib, ih // reps, ik, 0)
+                (1, 1, block_k, d),
+                lambda ib, ih, iq, ik: (ib, ih // reps, kb(iq, ik), 0),
             ),
             pl.BlockSpec(
-                (1, 1, block_k, d), lambda ib, ih, iq, ik: (ib, ih // reps, ik, 0)
+                (1, 1, block_k, d),
+                lambda ib, ih, iq, ik: (ib, ih // reps, kb(iq, ik), 0),
             ),
             pl.BlockSpec((1, 1, block_q), lambda ib, ih, iq, ik: (ib, 0, iq)),
-            pl.BlockSpec((1, 1, block_k), lambda ib, ih, iq, ik: (ib, 0, ik)),
+            pl.BlockSpec((1, 1, block_k), lambda ib, ih, iq, ik: (ib, 0, kb(iq, ik))),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             pl.BlockSpec((1, 1, 1, block_q), lambda ib, ih, iq, ik: (ib, ih, 0, iq)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, d), q.dtype),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         out_shape=out_shape,
@@ -225,7 +402,7 @@ def _fwd(
 def _dq_kernel(
     q_ref, k_ref, v_ref, qseg_ref, kseg_ref, do_ref, lse_ref, delta_ref,
     dq_ref,
-    dq_scr,
+    q_scr, dq_scr,
     *, causal, scale, block_q, block_k, seq_offset, have_segs,
 ):
     iq, ik = pl.program_id(2), pl.program_id(3)
@@ -233,40 +410,42 @@ def _dq_kernel(
 
     @pl.when(ik == 0)
     def _init():
+        # q * scale is rounded for the MXU once a q block
+        q_scr[:] = (q_ref[0, 0].astype(jnp.float32) * scale).astype(q_scr.dtype)
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q,), 0) + seq_offset
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k,), 0)
-    run = True
-    if causal:
-        run = (iq * block_q + seq_offset) + block_q - 1 >= ik * block_k
+    q0 = iq * block_q + seq_offset
+    k0 = ik * block_k
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0, 0]
-        delta = delta_ref[0, 0, 0]
+    def _tile(rows, cols, masked):
+        (r0, r1), (c0, c1) = rows, cols
+        k = k_ref[0, 0, c0:c1]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            q_scr[r0:r1], k, _NT, preferred_element_type=jnp.float32
         )
-        q_seg = qseg_ref[0, 0] if have_segs else None
-        k_seg = kseg_ref[0, 0] if have_segs else None
-        mask = _block_mask(q_pos, k_pos, causal, q_seg, k_seg)
-        if mask is not None:
+        if masked:
+            mask = _tile_mask(
+                s.shape, q0 + r0, k0 + c0, causal,
+                qseg_ref[0, 0, r0:r1] if have_segs else None,
+                kseg_ref[0, 0, c0:c1] if have_segs else None, 0,
+            )
             s = jnp.where(mask, s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        if mask is not None:
+        p = jnp.exp(s - lse_ref[0, 0, 0, r0:r1][:, None])
+        if masked and have_segs:
             p = jnp.where(mask, p, 0.0)  # fully-masked rows have lse=-inf
-        dov = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        dp = jax.lax.dot_general(
+            do_ref[0, 0, r0:r1], v_ref[0, 0, c0:c1], _NT,
+            preferred_element_type=jnp.float32,
         )
-        ds = p * (dov - delta[:, None])
-        dq_scr[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        ds = p * (dp - delta_ref[0, 0, 0, r0:r1][:, None])
+        dq_scr[r0:r1] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32
         )
+
+    _for_live_tiles(
+        _tile, q0, k0, block_q=block_q, block_k=block_k, walk="k",
+        causal=causal, seq_offset=seq_offset, have_segs=have_segs,
+    )
 
     @pl.when(ik == nk - 1)
     def _final():
@@ -282,6 +461,9 @@ def _dkv_kernel(
     # Grid is (batch, kv_head, kv_block, q_block * reps): the innermost dim
     # folds the q-blocks of every query head sharing this kv head, so dk/dv
     # accumulate in scratch across the whole GQA group (no HBM revisits).
+    # The scores are computed TRANSPOSED (K Q^T: keys down the rows, queries
+    # across the lanes), so that p^T and ds^T feed the MXU as they come and
+    # lse / delta are rows, as they are stored.
     ik, j = pl.program_id(2), pl.program_id(3)
     nj = pl.num_programs(3)
     iq = j // reps
@@ -291,43 +473,43 @@ def _dkv_kernel(
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q,), 0) + seq_offset
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k,), 0)
-    run = True
-    if causal:
-        run = (iq * block_q + seq_offset) + block_q - 1 >= ik * block_k
+    q0 = iq * block_q + seq_offset
+    k0 = ik * block_k
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0, 0]
-        delta = delta_ref[0, 0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    def _tile(rows, cols, masked):
+        (r0, r1), (c0, c1) = rows, cols  # keys, queries
+        q = q_ref[0, 0, c0:c1]
+        q = (q.astype(jnp.float32) * scale).astype(q.dtype)
+        do = do_ref[0, 0, c0:c1]
+        st = jax.lax.dot_general(
+            k_ref[0, 0, r0:r1], q, _NT, preferred_element_type=jnp.float32
         )
-        q_seg = qseg_ref[0, 0] if have_segs else None
-        k_seg = kseg_ref[0, 0] if have_segs else None
-        mask = _block_mask(q_pos, k_pos, causal, q_seg, k_seg)
-        if mask is not None:
-            s = jnp.where(mask, s, _NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        if mask is not None:
-            p = jnp.where(mask, p, 0.0)  # fully-masked rows have lse=-inf
-        # dv += p^T @ do
-        dv_scr[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        if masked:
+            mask = _tile_mask(
+                st.shape, q0 + c0, k0 + r0, causal,
+                qseg_ref[0, 0, c0:c1] if have_segs else None,
+                kseg_ref[0, 0, r0:r1] if have_segs else None, 1,
+            )
+            st = jnp.where(mask, st, _NEG_INF)
+        pt = jnp.exp(st - lse_ref[0, 0, :, c0:c1])
+        if masked and have_segs:
+            pt = jnp.where(mask, pt, 0.0)  # fully-masked rows have lse=-inf
+        dv_scr[r0:r1] += jax.lax.dot_general(
+            pt.astype(do.dtype), do, _NN, preferred_element_type=jnp.float32
         )
-        dov = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        dpt = jax.lax.dot_general(
+            v_ref[0, 0, r0:r1], do, _NT, preferred_element_type=jnp.float32
         )
-        ds = p * (dov - delta[:, None])
+        dst = pt * (dpt - delta_ref[0, 0, :, c0:c1])
         # dk += ds^T @ q  (q already carries `scale`)
-        dk_scr[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        dk_scr[r0:r1] += jax.lax.dot_general(
+            dst.astype(q.dtype), q, _NN, preferred_element_type=jnp.float32
         )
+
+    _for_live_tiles(
+        _tile, q0, k0, block_q=block_q, block_k=block_k, walk="q",
+        causal=causal, seq_offset=seq_offset, have_segs=have_segs,
+    )
 
     @pl.when(j == nj - 1)
     def _final():
@@ -367,29 +549,40 @@ def _bwd(
         else (lambda ib, ih, i, j: (ib, ih, j, 0)),
     )
 
+    def kb(iq, ik):
+        return _live_k_block(iq, ik, block_q, block_k, causal, seq_offset)
+
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **common),
         grid=(b, h, nq, nk),
         in_specs=[
             qkv_spec(block_q, "outer"),       # q
             pl.BlockSpec(
-                (1, 1, block_k, d), lambda ib, ih, i, j: (ib, ih // reps, j, 0)
+                (1, 1, block_k, d),
+                lambda ib, ih, i, j: (ib, ih // reps, kb(i, j), 0),
             ),                                 # k
             pl.BlockSpec(
-                (1, 1, block_k, d), lambda ib, ih, i, j: (ib, ih // reps, j, 0)
+                (1, 1, block_k, d),
+                lambda ib, ih, i, j: (ib, ih // reps, kb(i, j), 0),
             ),                                 # v
             pl.BlockSpec((1, 1, block_q), lambda ib, ih, i, j: (ib, 0, i)),
-            pl.BlockSpec((1, 1, block_k), lambda ib, ih, i, j: (ib, 0, j)),
+            pl.BlockSpec((1, 1, block_k), lambda ib, ih, i, j: (ib, 0, kb(i, j))),
             qkv_spec(block_q, "outer"),       # do
             pl.BlockSpec((1, 1, 1, block_q), lambda ib, ih, i, j: (ib, ih, 0, i)),
             pl.BlockSpec((1, 1, 1, block_q), lambda ib, ih, i, j: (ib, ih, 0, i)),
         ],
         out_specs=qkv_spec(block_q, "outer"),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, d), q.dtype),
+            pltpu.VMEM((block_q, d), jnp.float32),
+        ],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
     )(q, k, v, q_seg, k_seg, do, lse, delta)
 
+    # No such clamp on the dkv grid: there the dead steps come FIRST in a
+    # k block's walk, and asking them for the first live q block measured
+    # no gain (PR 29: 1.650 ms a call with and without).
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **common, reps=reps),
         grid=(b, hkv, nk, nq * reps),

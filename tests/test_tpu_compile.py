@@ -76,6 +76,21 @@ def test_flash_fwd_bwd_7b_width_one_chip(one_chip, segments):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("heads,kv_heads", [(32, 8), (16, 16)])
+def test_flash_fwd_bwd_cells_heads_one_chip(one_chip, heads, kv_heads):
+    """The training cells' attention (Mistral-7B: 32 query / 8 KV heads;
+    OLMoE: 16 / 16) at seq 4096: the tiled kernels' slices of the DMA
+    block (256- and 512-wide, rows from a tile's edge) are on the tiling."""
+    q = jax.ShapeDtypeStruct((1, 4096, heads, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4096, kv_heads, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    grad = _attn_loss(lambda q, k, v, s: flash_attention(
+        q, k, v, causal=True, segment_ids=s))
+    text = jax.jit(grad).lower(q, kv, kv, None).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3  # forward, dq, dkv
+
+
 def test_flash_under_fsdp_mesh_is_shard_mapped(topo):
     """The default four-chip mesh (fsdp=4): the dispatch must wrap the
     kernel in shard_map over the batch — a bare Mosaic call on GSPMD
