@@ -494,8 +494,10 @@ def accelerate(
             (loss_sum, grads, w_sum), moe_stats = jax.lax.scan(
                 micro_step, (zero, zero_grads, zero), batch
             )
-            # one value a microbatch: the worst load, the mean loss
-            worst = {"moe_load_max": jnp.max, "moe_load_min": jnp.min}
+            # one value a microbatch: the worst load, the mean loss, the
+            # step's picks on held experts
+            worst = {"moe_load_max": jnp.max, "moe_load_min": jnp.min,
+                     "moe_picks_held": jnp.sum}
             moe_stats = {k: worst.get(k, jnp.mean)(v)
                          for k, v in moe_stats.items()}
             with jax.named_scope("grad_accum"):

@@ -207,6 +207,10 @@ def make_pipelined_forward(
     cfg = model.config
     if not cfg.scan_layers:
         raise ValueError("pipeline parallelism requires scan_layers=True")
+    if getattr(cfg, "layers", None) is not None:
+        raise ValueError(
+            "pipeline parallelism stacks ONE kind of layer per stage; this "
+            "model describes its layers one by one (LlamaConfig.layers)")
     num_stages = mesh.shape["pp"]
     if cfg.num_layers % num_stages:
         raise ValueError(

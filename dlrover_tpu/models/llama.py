@@ -37,6 +37,51 @@ Dtype = Any
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    """The rotary embedding of one kind of layer.  ``rotary_fraction`` is
+    the share of a head that rotates (its FIRST dimensions; the rest pass
+    through); ``yarn_factor`` > 0 scales the frequencies as YaRN does and
+    multiplies cos and sin by ``attention_factor``."""
+
+    theta: float = 10000.0
+    rotary_fraction: float = 1.0
+    yarn_factor: float = 0.0
+    yarn_original_max_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """One decoder layer of a model whose layers are not all alike: what
+    the model, ``num_params``, the presets and the benchmark read."""
+
+    num_heads: int = 32
+    # keys a query sees, itself counted; 0: every key behind it (full)
+    window: int = 0
+    rope: RopeSpec = RopeSpec()
+    mlp: str = "dense"            # "dense" | "sparse"
+
+
+def layer_pattern(specs: Tuple[LayerSpec, ...]) -> Tuple[int, int]:
+    """``(leading, period)``: the fewest leading layers behind which the
+    rest is one run of layers repeated at least twice, and the shortest
+    such run.  Layers all alike are ``(0, 1)``; where nothing repeats
+    every layer leads and the period is 0."""
+    n = len(specs)
+    if all(s == specs[0] for s in specs):
+        return 0, 1
+    for lead in range(n):
+        rest = specs[lead:]
+        for period in range(1, len(rest) // 2 + 1):
+            if len(rest) % period == 0 and all(
+                    s == rest[i % period] for i, s in enumerate(rest)):
+                return lead, period
+    return n, 0
+
+
+@dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 32000
     hidden_size: int = 4096
@@ -66,6 +111,27 @@ class LlamaConfig:
     # added to the task loss as a MEAN over the MoE layers
     moe_aux_loss_coef: float = 0.01
     moe_z_loss_coef: float = 1e-3
+    # an expert's width where it is not the dense layers' (None = theirs)
+    moe_intermediate_size: Optional[int] = None
+    # the router's scores: "softmax" over all experts, or "sigmoid" of
+    # each logit (then norm_topk_prob divides the picked scores by their sum)
+    moe_score_fn: str = "softmax"
+    # the routed experts' summed output is multiplied by this
+    moe_routed_scale: float = 1.0
+    # one shared expert of this width on every token (0 = none)
+    moe_shared_width: int = 0
+    # (first, count): the routed experts THIS device holds of every sparse
+    # layer, one share of an expert-parallel group; the router keeps all
+    # ``num_experts`` outputs and a pick on an absent expert adds nothing
+    moe_experts_held: Optional[Tuple[int, int]] = None
+    # initialise each expert as a matrix of its own (MoEMLP.per_expert_init)
+    moe_per_expert_init: bool = False
+    # a sigmoid gate on the attention output, one a query head, from the
+    # layer's normed input
+    attn_head_gate: bool = False
+    # layers that are not all alike, one LayerSpec each (None = every
+    # layer is the one the fields above describe)
+    layers: Optional[Tuple[LayerSpec, ...]] = None
     # RMSNorm with a learned scale over the WHOLE projected query and the
     # whole projected key, before the split into heads and before RoPE
     # (OLMoE, OLMo-2)
@@ -105,20 +171,53 @@ class LlamaConfig:
     def head_dim_(self) -> int:
         return self.head_dim or self.hidden_size // self.num_heads
 
+    def __post_init__(self):
+        if self.layers is not None and len(self.layers) != self.num_layers:
+            raise ValueError(
+                f"{len(self.layers)} layer descriptions for num_layers="
+                f"{self.num_layers}")
+
+    @property
+    def layer_specs(self) -> Tuple[LayerSpec, ...]:
+        """Every layer's description; of a uniform model, the one kind."""
+        if self.layers is not None:
+            return self.layers
+        return (LayerSpec(
+            num_heads=self.num_heads, rope=RopeSpec(theta=self.rope_theta),
+            mlp="sparse" if self.num_experts else "dense"),
+        ) * self.num_layers
+
+    @property
+    def rope_kinds(self) -> Tuple[RopeSpec, ...]:
+        """The distinct rotary embeddings, in order of first use."""
+        return tuple(dict.fromkeys(s.rope for s in self.layer_specs))
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    def layer_params(self, spec: LayerSpec) -> int:
+        """Parameters of one layer as this device holds it."""
+        h, d = self.hidden_size, self.head_dim_
+        n = h * d * (spec.num_heads * 2 + self.num_kv_heads * 2) + 2 * h
+        if self.attn_head_gate:
+            n += h * spec.num_heads
+        if self.qk_norm:
+            n += d * (spec.num_heads + self.num_kv_heads)
+        if spec.mlp == "sparse":
+            held = (self.moe_experts_held or (0, self.num_experts))[1]
+            n += 3 * h * self.expert_width * held + h * self.num_experts
+            n += 3 * h * self.moe_shared_width
+        else:
+            n += 3 * h * self.intermediate_size
+        return n
+
     @property
     def num_params(self) -> int:
         """Approximate parameter count (for MFU accounting)."""
         h, v = self.hidden_size, self.vocab_size
-        d = self.head_dim_
-        attn = h * d * (self.num_heads * 2 + self.num_kv_heads * 2)
-        mlp = 3 * h * self.intermediate_size
-        if self.num_experts:
-            mlp = mlp * self.num_experts + h * self.num_experts  # + router
-        per_layer = attn + mlp + 2 * h
-        if self.qk_norm:
-            per_layer += d * (self.num_heads + self.num_kv_heads)
         emb = v * h * (1 if self.tie_embeddings else 2)
-        return self.num_layers * per_layer + emb + h
+        return sum(map(self.layer_params, self.layer_specs)) + emb + h
 
     @classmethod
     def llama2_7b(cls, **kw) -> "LlamaConfig":
@@ -146,6 +245,56 @@ class LlamaConfig:
             moe_aux_loss_coef=0.01,
             moe_z_loss_coef=1e-3,
             qk_norm=True,
+        )
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
+    def laguna_xs2(cls, **kw) -> "LlamaConfig":
+        """poolside/Laguna-XS.2 as its config.json has it: every fourth
+        layer full attention with 48 query heads (half the head rotated,
+        theta 5e5, YaRN x64 over 4096), the others a window of 512 with
+        64 heads (plain RoPE, theta 1e4), 8 KV heads throughout, a head
+        gate; layer 0 a dense SwiGLU of 8192, the rest 256 sigmoid-routed
+        experts of 512, 8 a token, scaled by 2.5, beside one shared
+        expert of 512.  ``num_layers`` cuts the pattern's depth;
+        ``moe_experts_held`` and ``vocab_size`` give one chip its share."""
+        num_layers = int(kw.pop("num_layers", 40))
+        full = RopeSpec(
+            theta=500000.0, rotary_fraction=0.5, yarn_factor=64.0,
+            yarn_original_max_len=4096, yarn_beta_fast=64.0,
+            yarn_beta_slow=1.0, attention_factor=1.4158883083359672)
+        window = RopeSpec(theta=10000.0)
+        layers = tuple(
+            LayerSpec(
+                num_heads=64 if i % 4 else 48,
+                window=512 if i % 4 else 0,
+                rope=window if i % 4 else full,
+                mlp="sparse" if i else "dense")
+            for i in range(num_layers))
+        base = dict(
+            vocab_size=100352,
+            hidden_size=2048,
+            intermediate_size=8192,
+            num_layers=num_layers,
+            num_heads=48,
+            num_kv_heads=8,
+            head_dim=128,
+            max_seq_len=8192,
+            rope_theta=500000.0,
+            rms_norm_eps=1e-6,
+            num_experts=256,
+            moe_top_k=8,
+            moe_norm_topk_prob=True,
+            moe_aux_loss_coef=0.0,
+            moe_z_loss_coef=0.0,
+            moe_intermediate_size=512,
+            moe_score_fn="sigmoid",
+            moe_routed_scale=2.5,
+            moe_shared_width=512,
+            moe_per_expert_init=True,
+            attn_head_gate=True,
+            layers=layers,
         )
         base.update(kw)
         return cls(**base)
@@ -182,7 +331,7 @@ class LlamaConfig:
 
 
 #: presets the entry points (examples/, the serving worker) can name
-PRESETS = ("tiny", "llama2_7b", "olmoe_1b_7b")
+PRESETS = ("tiny", "llama2_7b", "olmoe_1b_7b", "laguna_xs2")
 
 
 def resolve_remat_policy(name: str):
@@ -244,6 +393,60 @@ def rope_frequencies(head_dim: int, max_len: int, theta: float) -> jax.Array:
     return jnp.outer(pos, inv)
 
 
+def rope_inverse_frequencies(spec: RopeSpec, head_dim: int) -> jax.Array:
+    """[rotary/2] inverse frequencies of one kind of layer: plain
+    ``theta^(-2i/rotary)`` over the rotating part of the head, or YaRN's
+    blend as ``transformers`` computes it: the pairs that turn more than
+    ``beta_fast`` times over the original length keep theirs, those that
+    turn less than ``beta_slow`` times are divided by the factor, a
+    linear ramp between."""
+    import math
+
+    rotary = int(head_dim * spec.rotary_fraction)
+    exponent = jnp.arange(0, rotary, 2, dtype=jnp.float32) / rotary
+    inv = 1.0 / (spec.theta ** exponent)
+    if not spec.yarn_factor:
+        return inv
+
+    def correction_dim(turns):
+        return (rotary * math.log(spec.yarn_original_max_len
+                                  / (turns * 2 * math.pi))
+                / (2 * math.log(spec.theta)))
+
+    low = max(math.floor(correction_dim(spec.yarn_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(spec.yarn_beta_slow)), rotary - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip(
+        (jnp.arange(rotary // 2, dtype=jnp.float32) - low) / (high - low),
+        0.0, 1.0)
+    return inv / spec.yarn_factor * ramp + inv * (1.0 - ramp)
+
+
+def rope_table(spec: RopeSpec, head_dim: int, positions: jax.Array):
+    """``(cos, sin)`` [..., rotary/2] of one kind of layer at
+    ``positions``, times its ``attention_factor``."""
+    angles = positions.astype(jnp.float32)[..., None] * \
+        rope_inverse_frequencies(spec, head_dim)
+    return (jnp.cos(angles) * spec.attention_factor,
+            jnp.sin(angles) * spec.attention_factor)
+
+
+def apply_rope_table(x: jax.Array, table) -> jax.Array:
+    """x: [b, s, h, d]; ``table`` from :func:`rope_table` ([s, r/2] or
+    [b, s, r/2]): the first r dimensions of a head rotate (in halves, as
+    :func:`apply_rope`), the rest pass through."""
+    cos, sin = (t[..., None, :] for t in table)
+    if cos.ndim == 3:
+        cos, sin = cos[None], sin[None]
+    rotary = 2 * cos.shape[-1]
+    xf = x.astype(jnp.float32)
+    x1, x2 = jnp.split(xf[..., :rotary], 2, axis=-1)
+    out = jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, xf[..., rotary:]], axis=-1)
+    return out.astype(x.dtype)
+
+
 def apply_rope(x: jax.Array, angles: jax.Array) -> jax.Array:
     """x: [b, s, h, d]; angles: [s, d//2] (shared positions) or
     [b, s, d//2] (per-example positions, e.g. packed sequences)."""
@@ -259,6 +462,8 @@ def apply_rope(x: jax.Array, angles: jax.Array) -> jax.Array:
 
 class Attention(nn.Module):
     config: LlamaConfig
+    # this layer's description where the model's layers are not all alike
+    spec: Optional[LayerSpec] = None
 
     @nn.compact
     def __call__(
@@ -268,12 +473,14 @@ class Attention(nn.Module):
         segment_ids: Optional[jax.Array] = None,
         decode: bool = False,
         cache_len: Optional[int] = None,
+        rope=None,
     ) -> jax.Array:
-        cfg = self.config
+        cfg, spec = self.config, self.spec
         d = cfg.head_dim_
+        num_heads = cfg.num_heads if spec is None else spec.num_heads
         init = nn.initializers.lecun_normal()
         q_proj = nn.DenseGeneral(
-            (cfg.num_heads, d),
+            (num_heads, d),
             axis=-1,
             use_bias=cfg.attention_bias,
             dtype=cfg.dtype,
@@ -330,9 +537,13 @@ class Attention(nn.Module):
         k = with_logical_constraint(k, ("batch", "seq", "kv_heads", "head_dim"))
         v = with_logical_constraint(v, ("batch", "seq", "kv_heads", "head_dim"))
 
-        angles = rope_frequencies(d, cfg.max_seq_len, cfg.rope_theta)[positions]
-        q = checkpoint_name(apply_rope(q, angles), "qkv_proj")
-        k = checkpoint_name(apply_rope(k, angles), "qkv_proj")
+        if spec is None:
+            angles = rope_frequencies(d, cfg.max_seq_len, cfg.rope_theta)[positions]
+            q = checkpoint_name(apply_rope(q, angles), "qkv_proj")
+            k = checkpoint_name(apply_rope(k, angles), "qkv_proj")
+        else:  # the kind's table, made once a step by the model
+            q = checkpoint_name(apply_rope_table(q, rope), "qkv_proj")
+            k = checkpoint_name(apply_rope_table(k, rope), "qkv_proj")
         v = checkpoint_name(v, "qkv_proj")
 
         if decode:
@@ -377,7 +588,25 @@ class Attention(nn.Module):
             ).astype(x.dtype)
             return o_proj(out)
 
-        out = dot_product_attention(q, k, v, causal=True, segment_ids=segment_ids)
+        if spec is None:
+            out = dot_product_attention(
+                q, k, v, causal=True, segment_ids=segment_ids)
+        else:
+            with jax.named_scope(
+                    "attn_window" if spec.window else "attn_full"):
+                out = dot_product_attention(
+                    q, k, v, causal=True, segment_ids=segment_ids,
+                    window=spec.window or None)
+        if cfg.attn_head_gate:
+            gate = nn.DenseGeneral(
+                num_heads, use_bias=False, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, dot_general=cfg.dot_general,
+                kernel_init=nn.with_logical_partitioning(
+                    init, ("embed", "heads")),
+                name="g_proj",
+            )(x)
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))[..., None]).astype(out.dtype)
         out = checkpoint_name(out, "attn_out")
         out = with_logical_constraint(out, ("batch", "seq", "heads", "head_dim"))
         return o_proj(out)
@@ -413,6 +642,7 @@ class MLP(nn.Module):
 
 class DecoderLayer(nn.Module):
     config: LlamaConfig
+    spec: Optional[LayerSpec] = None
 
     @nn.compact
     def __call__(
@@ -422,25 +652,31 @@ class DecoderLayer(nn.Module):
         segment_ids: Optional[jax.Array] = None,
         decode: bool = False,
         cache_len: Optional[int] = None,
+        rope=None,
     ) -> jax.Array:
-        cfg = self.config
+        cfg, spec = self.config, self.spec
         h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="input_norm")(x)
-        x = x + Attention(cfg, name="attn")(h, positions, segment_ids,
-                                            decode=decode,
-                                            cache_len=cache_len)
+        x = x + Attention(cfg, spec, name="attn")(
+            h, positions, segment_ids, decode=decode, cache_len=cache_len,
+            rope=rope)
         x = with_logical_constraint(x, ("batch", "seq", "act_embed"))
         h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="post_norm")(x)
-        if cfg.num_experts:
+        if cfg.num_experts and (spec is None or spec.mlp == "sparse"):
             from dlrover_tpu.models.moe import MoEMLP
 
             mlp = MoEMLP(
                 hidden_size=cfg.hidden_size,
-                intermediate_size=cfg.intermediate_size,
+                intermediate_size=cfg.expert_width,
                 num_experts=cfg.num_experts,
                 top_k=cfg.moe_top_k,
                 norm_topk_prob=cfg.moe_norm_topk_prob,
                 aux_loss_coef=cfg.moe_aux_loss_coef,
                 z_loss_coef=cfg.moe_z_loss_coef,
+                score_fn=cfg.moe_score_fn,
+                routed_scale=cfg.moe_routed_scale,
+                shared_width=cfg.moe_shared_width,
+                experts_held=cfg.moe_experts_held,
+                per_expert_init=cfg.moe_per_expert_init,
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
                 fp8=cfg.fp8,
@@ -462,6 +698,41 @@ class _ScanLayer(nn.Module):
         x, positions, segment_ids = carry
         x = DecoderLayer(self.config, name="layer")(x, positions, segment_ids)
         return (x, positions, segment_ids), None
+
+
+def _layer_class(cfg: LlamaConfig, in_scan: bool):
+    """``DecoderLayer``, under ``nn.remat`` where the config asks.  A
+    layer OUTSIDE a scan keeps ``prevent_cse``: without it XLA merges the
+    recomputed forward with the first one and nothing is rematerialised
+    (read off a v5e capture, PR 31: three flash calls for the leading
+    layer where the scanned ones have four)."""
+    if not cfg.remat:
+        return DecoderLayer
+    # decode and cache_len (positions 4 and 5, self counted) stay Python
+    # values under the trace
+    return nn.remat(DecoderLayer, policy=resolve_remat_policy(cfg.remat_policy),
+                    prevent_cse=not in_scan, static_argnums=(4, 5))
+
+
+class _ScanPeriod(nn.Module):
+    """One period of a model whose layers are not all alike, as
+    ``nn.scan``'s body: the period's layers in order, each under its OWN
+    remat, so that the backward's live set stays one layer's."""
+
+    config: LlamaConfig
+    specs: Tuple[LayerSpec, ...]
+
+    @nn.compact
+    def __call__(self, carry, _):
+        x, positions, segment_ids, ropes = carry
+        cfg = self.config
+        layer_cls = _layer_class(cfg, in_scan=True)
+        kinds = cfg.rope_kinds
+        for j, spec in enumerate(self.specs):
+            x = layer_cls(cfg, spec, name=f"layer_{j}")(
+                x, positions, segment_ids, False, None,
+                ropes[kinds.index(spec.rope)])
+        return (x, positions, segment_ids, ropes), None
 
 
 class LlamaModel(nn.Module):
@@ -503,7 +774,9 @@ class LlamaModel(nn.Module):
                 "scan_layers=False for generation configs (training keeps "
                 "scan_layers=True — the cache never exists under training)"
             )
-        if cfg.scan_layers:
+        if cfg.layers is not None:
+            x = self._mixed_layers(x, positions, segment_ids, decode)
+        elif cfg.scan_layers:
             block = _ScanLayer
             if cfg.remat:
                 policy = resolve_remat_policy(cfg.remat_policy)
@@ -558,3 +831,35 @@ class LlamaModel(nn.Module):
         if cfg.logit_scale != 1.0:
             logits = logits * cfg.logit_scale
         return with_logical_constraint(logits, ("batch", "seq", "vocab"))
+
+    def _mixed_layers(self, x, positions, segment_ids, decode):
+        """The layers of a model that has more than one kind: the leading
+        layers that do not repeat run unrolled (``layer_<i>``), the rest
+        is ``nn.scan`` over PERIODS (``periods/layer_<j>``).  Each kind's
+        rotary table is made here, once a step."""
+        cfg = self.config
+        if decode:
+            raise NotImplementedError(
+                "KV-cache decode knows one kind of layer (ROADMAP A3, A4)")
+        specs, kinds = cfg.layer_specs, cfg.rope_kinds
+        ropes = tuple(rope_table(kind, cfg.head_dim_, positions)
+                      for kind in kinds)
+        lead, period = (layer_pattern(specs) if cfg.scan_layers
+                        else (len(specs), 0))
+        layer_cls = _layer_class(cfg, in_scan=False)
+        for i in range(lead):
+            x = layer_cls(cfg, specs[i], name=f"layer_{i}")(
+                x, positions, segment_ids, False, None,
+                ropes[kinds.index(specs[i].rope)])
+        if period:
+            scan = nn.scan(
+                _ScanPeriod,
+                variable_axes={"params": 0, "moe_losses": 0},
+                split_rngs={"params": True},
+                length=(len(specs) - lead) // period,
+                metadata_params={nn.PARTITION_NAME: "layers"},
+            )
+            (x, _, _, _), _ = scan(
+                cfg, specs[lead:lead + period], name="periods")(
+                    (x, positions, segment_ids, ropes), None)
+        return x

@@ -25,21 +25,36 @@ router's probabilities and the grouped matmul's operands are replicated
 first (:func:`_replicated`) and every device computes the whole layer.
 The result is the same; the all-to-all by hand is ROADMAP B1(c).
 
+Variants (all off by default, and then the traced layer is as it was):
+``score_fn="sigmoid"`` scores each expert by the sigmoid of its logit;
+``routed_scale`` multiplies the routed sum; ``shared_width`` adds one
+dense SwiGLU expert on every token (scope ``moe_shared``);
+``experts_held=(first, count)`` makes the layer ONE SHARE of an
+expert-parallel group: the router keeps all ``num_experts`` outputs and
+picks ``top_k`` of them, the layer holds ``count`` experts' weights,
+the picks on held experts are sorted by expert AHEAD of all others, the
+grouped matmuls get the held groups' sizes and multiply nothing behind
+them, and a pick on an absent expert adds nothing, forward or backward.
+Nothing stands in for the absent devices.  The sorted buffer keeps its
+static bound of ``T x top_k`` rows though ``count / num_experts`` of
+them are live on average: the exchange of a deployment would fill it.
+
 What the layer sows into the ``"moe_losses"`` collection, once a layer:
 ``aux_loss`` (the coefficients times the two terms below: what
 :func:`aux_loss` averages over layers into the task loss),
 ``balance_loss`` (``e * sum_e f_e P_e``, ``f_e`` the share of ALL
 ``T x top_k`` picks that chose e, ``P_e`` the mean router probability),
-``z_loss`` (``mean_t logsumexp(logits_t)^2``) and ``expert_counts``
-(the group sizes).  :func:`routing_stats` reduces them for the step's
-metrics.
+``z_loss`` (``mean_t logsumexp(logits_t)^2``), ``expert_counts``
+(the picks of each of ALL ``num_experts``) and, of a share,
+``held_counts`` (its groups' sizes).  :func:`routing_stats` reduces them
+for the step's metrics.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Dict
+from typing import Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -172,18 +187,35 @@ def routing_stats(moe_losses) -> Dict[str, jax.Array]:
     """The step's routing metrics from the sown collection ({} for a dense
     model): ``moe_load_max`` / ``moe_load_min`` are the worst layer's
     largest / smallest group over the mean group (1.0 = even), the two
-    losses are means over layers, without their coefficients."""
-    counts = _leaves_named(moe_losses, "expert_counts")
-    if not counts:
+    losses are means over layers, without their coefficients.  Of a model
+    whose layers hold a share of their experts (``experts_held``), the
+    load is over the HELD groups, ``moe_picks_held`` is the picks on them
+    summed over layers and ``moe_held_share`` that over all picks."""
+    def stacked(name):
+        return jnp.concatenate(
+            [c.reshape(-1, c.shape[-1]).astype(jnp.float32)
+             for c in _leaves_named(moe_losses, name)])
+
+    if not _leaves_named(moe_losses, "expert_counts"):
         return {}
-    counts = jnp.concatenate(
-        [c.reshape(-1, c.shape[-1]).astype(jnp.float32) for c in counts])
-    load = counts / jnp.mean(counts, axis=-1, keepdims=True)
+    counts = stacked("expert_counts")
+    mean = jnp.mean(counts, axis=-1, keepdims=True)
+    held = {}
+    if _leaves_named(moe_losses, "held_counts"):
+        # one share of an expert-parallel group: the load is over the
+        # groups it HOLDS (a layer's router may send it nothing at all),
+        # and its picks are counted beside all picks
+        picks, counts = jnp.sum(counts), stacked("held_counts")
+        mean = jnp.maximum(jnp.mean(counts, axis=-1, keepdims=True), 1.0)
+        held = {"moe_picks_held": jnp.sum(counts),
+                "moe_held_share": jnp.sum(counts) / picks}
+    load = counts / mean
     return {
         "moe_load_max": jnp.max(load),
         "moe_load_min": jnp.min(load),
         "moe_balance_loss": _layer_mean(moe_losses, "balance_loss"),
         "moe_z_loss": _layer_mean(moe_losses, "z_loss"),
+        **held,
     }
 
 
@@ -209,6 +241,19 @@ class MoEMLP(nn.Module):
     # fp8 expert GEMMs (the model's FLOPs majority); the router stays
     # f32 — routing decisions are the standard fp8-recipe exclusion
     fp8: bool = False
+    # "softmax" over all experts, or the "sigmoid" of each logit
+    score_fn: str = "softmax"
+    routed_scale: float = 1.0
+    # one shared expert of this width on every token (0 = none)
+    shared_width: int = 0
+    # (first, count) of the experts this device holds (None = all)
+    experts_held: Optional[Tuple[int, int]] = None
+    # LeCun fan-in of ONE expert's matrix.  Off, the fan-in is the whole
+    # stack's (the expert axis counts as receptive field), which makes an
+    # expert's output (experts^-1/2)^3 of a dense layer's at
+    # initialisation: 1/512 at 64 experts, too little for a comparison
+    # with a reference to see the routed sum at all
+    per_expert_init: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -226,20 +271,26 @@ class MoEMLP(nn.Module):
             name="router",
         )
 
+        expert_init = (nn.initializers.lecun_normal(batch_axis=(0,))
+                       if self.per_expert_init else init)
+
         def expert_param(name, shape, axes):
             return self.param(
                 name,
-                nn.with_logical_partitioning(init, axes),
+                nn.with_logical_partitioning(expert_init, axes),
                 shape,
                 self.param_dtype,
             )
 
+        if self.score_fn not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router score_fn {self.score_fn!r}")
+        first, held = self.experts_held or (0, e)
         w_gate = expert_param(
-            "w_gate", (e, m, h), ("expert", "embed", "mlp")
+            "w_gate", (held, m, h), ("expert", "embed", "mlp")
         )
-        w_up = expert_param("w_up", (e, m, h), ("expert", "embed", "mlp"))
+        w_up = expert_param("w_up", (held, m, h), ("expert", "embed", "mlp"))
         w_down = expert_param(
-            "w_down", (e, h, m), ("expert", "mlp", "embed")
+            "w_down", (held, h, m), ("expert", "mlp", "embed")
         )
 
         with jax.named_scope("moe_route"):
@@ -247,10 +298,17 @@ class MoEMLP(nn.Module):
             # replicated: the picks are sorted over ALL tokens below, and
             # XLA's partitioner aborts on a batch-sharded top-k inside the
             # pipeline's partly manual shard_map
-            probs = _replicated(jax.nn.softmax(logits, axis=-1))
-            top_p, top_e = jax.lax.top_k(probs, k)  # [t, k]
+            if self.score_fn == "softmax":
+                probs = _replicated(jax.nn.softmax(logits, axis=-1))
+                top_p, top_e = jax.lax.top_k(probs, k)  # [t, k]
+            else:
+                scores = _replicated(jax.nn.sigmoid(logits))
+                top_p, top_e = jax.lax.top_k(scores, k)
+                probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
             if self.norm_topk_prob:
                 top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+            if self.routed_scale != 1.0:
+                top_p = top_p * self.routed_scale
             picks = top_e.reshape(t * k)
             counts = jnp.sum(jax.nn.one_hot(picks, e, dtype=jnp.int32), axis=0)
             share = counts.astype(jnp.float32) / (t * k)
@@ -266,11 +324,28 @@ class MoEMLP(nn.Module):
             self.sow("moe_losses", name, value,
                      reduce_fn=lambda _, new: new, init_fn=lambda: None)
 
+        if self.experts_held is not None:
+            # this share's picks ahead of all others, by held expert; the
+            # groups are the held experts', and end where the others start
+            local = picks - first
+            is_held = jnp.logical_and(local >= 0, local < held)
+            picks = jnp.where(is_held, local, held)
+            counts = counts[first:first + held]
+            self.sow("moe_losses", "held_counts", counts,
+                     reduce_fn=lambda _, new: new, init_fn=lambda: None)
+
         with jax.named_scope("moe_dispatch"):
             order = jnp.argsort(picks, stable=True)  # expert-major
             inverse = jnp.argsort(order)
             xs = _to_expert_order(
                 x.reshape(t, m).astype(self.dtype), order, inverse, k)
+            if self.experts_held is not None:
+                # rows behind the held groups are no expert's: the grouped
+                # matmuls leave them unwritten, forward (``out``) and
+                # backward (the rows' gradient, which this select's
+                # transpose zeroes)
+                live = (jnp.arange(t * k) < jnp.sum(counts))[:, None]
+                xs = jnp.where(live, xs, jnp.zeros((), xs.dtype))
 
         if self.fp8:
             from dlrover_tpu.ops.fp8 import fake_quant_fp8, grad_quant_fp8
@@ -284,11 +359,43 @@ class MoEMLP(nn.Module):
             gate = grad_quant_fp8(grouped_matmul(xq, wg, counts))
             up = grad_quant_fp8(grouped_matmul(xq, wu, counts))
             act = nn.silu(gate) * up
+            if self.fp8 and self.experts_held is not None:
+                # fp8 scales by the largest entry: not one of rows that
+                # no matmul wrote
+                act = jnp.where(live, act, jnp.zeros((), act.dtype))
             out = grad_quant_fp8(
                 grouped_matmul(fake_quant_fp8(act), wd, counts))
 
         with jax.named_scope("moe_combine"):
+            if self.experts_held is not None:
+                out = jnp.where(live, out, jnp.zeros((), out.dtype))
             out = _to_token_order(out, order, inverse).reshape(t, k, m)
             y = jnp.sum(out.astype(jnp.float32) * top_p[..., None], axis=1)
+        if self.shared_width:
+            with jax.named_scope("moe_shared"):
+                y = y + self._shared_expert(x).reshape(t, m)
         y = y.astype(self.dtype).reshape(b, s, m)
         return with_logical_constraint(y, ("batch", "seq", "act_embed"))
+
+    def _shared_expert(self, x: jax.Array) -> jax.Array:
+        """The dense SwiGLU expert every token visits: matmuls in
+        ``self.dtype``, the result in float32 for the routed sum."""
+        init = nn.initializers.lecun_normal()
+        if self.fp8:
+            from dlrover_tpu.ops.fp8 import fp8_dot_general as dot_general
+        else:
+            dot_general = jax.lax.dot_general
+
+        def dense(features, axes, name):
+            return nn.DenseGeneral(
+                features, use_bias=False, dtype=self.dtype,
+                param_dtype=self.param_dtype, dot_general=dot_general,
+                kernel_init=nn.with_logical_partitioning(init, axes),
+                name=name)
+
+        gate = dense(self.shared_width, ("embed", "mlp"), "shared_gate")(x)
+        up = dense(self.shared_width, ("embed", "mlp"), "shared_up")(x)
+        act = with_logical_constraint(nn.silu(gate) * up,
+                                      ("batch", "seq", "mlp"))
+        return dense(self.hidden_size, ("mlp", "embed"), "shared_down")(
+            act).astype(jnp.float32)

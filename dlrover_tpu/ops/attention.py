@@ -32,6 +32,7 @@ def _xla_attention(
     causal: bool,
     segment_ids: Optional[jax.Array],
     scale: Optional[float],
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Reference softmax attention in pure XLA ops.
 
@@ -56,6 +57,8 @@ def _xla_attention(
         q_pos = jnp.arange(sq)[:, None] + (skv - sq)
         kv_pos = jnp.arange(skv)[None, :]
         mask = q_pos >= kv_pos
+        if window is not None:  # the key itself counted: i - w < j <= i
+            mask = jnp.logical_and(mask, q_pos - kv_pos < window)
     if segment_ids is not None:
         seg = segment_ids[:, :, None] == segment_ids[:, None, :]
         seg = seg[:, None, None, :, :]
@@ -99,6 +102,7 @@ def dot_product_attention(
     scale: Optional[float] = None,
     use_pallas: Optional[bool] = None,
     sp_ulysses: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Multi-head attention with GQA; dispatches to the Pallas TPU kernel
     when running on TPU (and shapes are kernel-friendly), else pure XLA.
@@ -111,9 +115,14 @@ def dot_product_attention(
     attends over the full sequence with a head slice.  ``sp_ulysses=False``
     forces plain GSPMD semantics.
 
+    ``window=w`` (causal only) lets query ``i`` see the keys
+    ``i - w < j <= i``; the ring and Ulysses paths refuse it.
+
     q: [batch, q_seq, q_heads, head_dim]
     k, v: [batch, kv_seq, kv_heads, head_dim]
     """
+    if window is not None and not causal:
+        raise ValueError("a window needs causal attention")
     if use_pallas is None:
         import os
 
@@ -141,6 +150,7 @@ def dot_product_attention(
                     segment_ids=segment_ids,
                     scale=scale,
                     use_pallas=use_pallas,
+                    window=window,
                 )
             global _warned_cp
             if not _warned_cp:
@@ -163,6 +173,7 @@ def dot_product_attention(
                     segment_ids=segment_ids,
                     scale=scale,
                     use_pallas=use_pallas,
+                    window=window,
                 )
             if sp_ulysses:
                 raise ValueError(
@@ -200,14 +211,17 @@ def dot_product_attention(
         )
     if use_pallas:
         return _sharded_flash_attention(
-            q, k, v, causal=causal, segment_ids=segment_ids, scale=scale
+            q, k, v, causal=causal, segment_ids=segment_ids, scale=scale,
+            window=window,
         )
     return _xla_attention(
-        q, k, v, causal=causal, segment_ids=segment_ids, scale=scale
+        q, k, v, causal=causal, segment_ids=segment_ids, scale=scale,
+        window=window,
     )
 
 
-def _sharded_flash_attention(q, k, v, *, causal, segment_ids, scale):
+def _sharded_flash_attention(q, k, v, *, causal, segment_ids, scale,
+                             window=None):
     """The Pallas flash kernel on global arrays of a plain (dp/fsdp/tp)
     mesh.  A Mosaic kernel cannot be partitioned by GSPMD, so under an
     ambient mesh the call is wrapped in ``shard_map`` with the specs the
@@ -223,7 +237,8 @@ def _sharded_flash_attention(q, k, v, *, causal, segment_ids, scale):
 
     def direct(q, k, v, seg):
         return flash_attention(
-            q, k, v, causal=causal, segment_ids=seg, scale=scale
+            q, k, v, causal=causal, segment_ids=seg, scale=scale,
+            window=window,
         )
 
     mesh = None if _under_named_axes() else ambient_mesh()
@@ -355,6 +370,7 @@ def ulysses_attention(
     scale: Optional[float] = None,
     use_pallas: Optional[bool] = None,
     rules=None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Sequence-parallel attention via explicit seq<->heads all-to-all.
 
@@ -369,6 +385,10 @@ def ulysses_attention(
     Arguments are *global* arrays; returns the global [b, sq, hq, d] output
     partitioned like the input.
     """
+    if window is not None:
+        raise NotImplementedError(
+            f"Ulysses attention has no window (window={window}): a layer "
+            "with one must not run under an sp axis")
     q_spec, kv_spec, seg_spec = _attention_specs(mesh, rules)
 
     def inner(q, k, v, seg):

@@ -33,6 +33,19 @@ whole.  With segment ids every tile of every block that runs keeps the
 full mask and the guard for rows it masks fully.  ``score_tiles`` counts
 all this from the shapes.
 
+A window.  ``window=w`` lets query ``i`` see the keys ``i - w < j <= i``
+(its own counted; causal only).  A block is then skipped above the
+diagonal OR wholly below the window, runs unmasked wholly inside both,
+and is masked where it crosses either boundary: with corner-to-corner
+blocks the crossed ones are known when the kernel is traced (the offset
+``q0 - k0`` takes a few multiples of the block), and each runs only its
+live tiles, masked only where a boundary passes through them.  The grid
+is as wide as a window reaches (two k blocks a q block at block 1024 and
+``w`` 512), not as the sequence: a grid step walks back from the row's
+last live block (``_walk_block``).  Only calls WITH a window are named
+(``flash_window_fwd`` / ``_dq`` / ``_dkv``); ``w`` at or over the key
+length is the kernel without one.
+
 Numerics: MXU operands in the inputs' dtype (``q * scale``, ``p`` and
 ``ds`` rounded to it once, explicitly: with f32 operands Mosaic's default
 precision rounds to bf16 inside the matmul anyway), f32 accumulators,
@@ -127,9 +140,44 @@ def _trimmed(block_q: int, block_k: int, causal: bool, seq_offset: int) -> bool:
     return causal and block_q == block_k and seq_offset % block_q == 0
 
 
+def _static_tiles(d: int, block_q: int, block_k: int, walk: str,
+                  window: int):
+    """The live compute tiles of a block whose first query stands ``d``
+    positions after its first key, under a window: ``[(rows, cols,
+    masked)]`` as ``_for_live_tiles`` hands them to a kernel.  Along the
+    ``walk`` axis one tile at a time; along the other, neighbouring tiles
+    of one class are run as one."""
+    width, other = (block_k, block_q) if walk == "k" else (block_q, block_k)
+    tw, to = _sub_tile(width), _sub_tile(other)
+    out = []
+    for c in range(0, width, tw):
+        runs = []  # [r0, r1, masked] along the other axis
+        for r in range(0, other, to):
+            # i - j over the tile: queries r.., keys c.. (walk "k") or the
+            # other way round
+            q, k, nq, nk = (r, c, to, tw) if walk == "k" else (c, r, tw, to)
+            lo, hi = d + q - (k + nk - 1), d + q + nq - 1 - k
+            if hi < 0 or lo >= window:
+                continue
+            masked = lo < 0 or hi >= window
+            if runs and runs[-1][1] == r and runs[-1][2] == masked:
+                runs[-1][1] = r + to
+            else:
+                runs.append([r, r + to, masked])
+        out += [((r0, r1), (c, c + tw), masked) for r0, r1, masked in runs]
+    return out
+
+
+def _crossed_offsets(block: int, window: int):
+    """The offsets ``q0 - k0`` (multiples of the block) at which a block
+    is crossed by the diagonal or by the window's far edge."""
+    return [d for d in range(0, window + block - 1, block)
+            if not (d >= block - 1 and d + block - 1 < window)]
+
+
 def score_tiles(
     sq: int, skv: int, block_q: int, block_k: int, causal: bool,
-    seq_offset: int,
+    seq_offset: int, window: Optional[int] = None,
 ) -> Tuple[float, float, float]:
     """``(computed, unmasked, needed)`` of one head's score matrix, in
     units of one compute tile (``_sub_tile(block_k)`` squared): what the
@@ -143,6 +191,9 @@ def score_tiles(
     if not causal:
         whole = sq * skv / unit
         return whole, whole, whole
+    if window is not None and window < skv:
+        return _window_score_tiles(sq, skv, block_q, block_k, seq_offset,
+                                   window, unit)
     # query row i sees the keys up to its own position, i + seq_offset
     needed = sum(min(max(i + seq_offset + 1, 0), skv) for i in range(sq)) / unit
     trimmed = _trimmed(block_q, block_k, causal, seq_offset)
@@ -160,6 +211,32 @@ def score_tiles(
             elif trimmed:  # n tiles on the diagonal, n (n - 1) / 2 below
                 computed += n * (n + 1) / 2
                 unmasked += n * (n - 1) / 2
+            else:
+                computed += block
+    return computed, unmasked, needed
+
+
+def _window_score_tiles(sq, skv, block_q, block_k, seq_offset, window, unit):
+    """``score_tiles`` under a window, block by block as the kernels class
+    them."""
+    needed = sum(min(max(i + seq_offset + 1, 0), skv, window)
+                 for i in range(sq)) / unit
+    trimmed = _trimmed(block_q, block_k, True, seq_offset)
+    computed = unmasked = 0.0
+    for iq in range(sq // block_q):
+        for ik in range(skv // block_k):
+            d = iq * block_q + seq_offset - ik * block_k
+            if d + block_q - 1 < 0 or d - (block_k - 1) >= window:
+                continue  # above the diagonal, or wholly below the window
+            block = block_q * block_k / unit
+            if d >= block_k - 1 and d + block_q - 1 < window:
+                computed, unmasked = computed + block, unmasked + block
+            elif trimmed:
+                for (r0, r1), (c0, c1), masked in _static_tiles(
+                        d, block_q, block_k, "k", window):
+                    area = (r1 - r0) * (c1 - c0) / unit
+                    computed += area
+                    unmasked += 0.0 if masked else area
             else:
                 computed += block
     return computed, unmasked, needed
@@ -189,6 +266,7 @@ def _fold(p: jax.Array) -> jax.Array:
 def _tile_mask(
     shape, q_start, k_start, causal: bool,
     q_seg: Optional[jax.Array], k_seg: Optional[jax.Array], q_axis: int,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Boolean mask (True = attend) of a score tile whose axis ``q_axis``
     runs over queries from ``q_start`` and the other over keys from
@@ -198,6 +276,8 @@ def _tile_mask(
         q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
         mask = q_pos >= k_pos
+        if window is not None:
+            mask = jnp.logical_and(mask, q_pos - k_pos < window)
     if q_seg is not None:
         if q_axis == 0:
             seg = q_seg[:, None] == k_seg[None, :]
@@ -216,9 +296,47 @@ def _live_k_block(iq, ik, block_q, block_k, causal, seq_offset):
     return jnp.minimum(ik, (iq * block_q + seq_offset + block_q - 1) // block_k)
 
 
+def _window_steps(block_walker: int, block_walked: int, n: int,
+                  seq_offset: int, window: int) -> int:
+    """Grid steps along the walked axis under a window: the blocks of
+    ``block_walked`` that the window of one ``block_walker`` block can
+    reach, of the ``n`` there are."""
+    if block_walker == block_walked and seq_offset % block_walker == 0:
+        return min(n, (window + block_walker - 2) // block_walker + 1)
+    return min(n, (block_walker + window - 2) // block_walked + 2)
+
+
+def _walk_k_block(iq, step, *, block_q, block_k, nk, steps, seq_offset,
+                  window):
+    """Under a window, the k block of grid step ``step`` of q block
+    ``iq`` (below zero where the row has fewer live blocks than steps:
+    nothing runs there) and the block to fetch for it (the nearest live
+    one, so that a dead step fetches nothing new).  The steps END at the
+    row's last live block."""
+    q0 = iq * block_q + seq_offset
+    last = jnp.minimum((q0 + block_q - 1) // block_k, nk - 1)
+    first = jnp.maximum((q0 - window + 1) // block_k, 0)
+    true = last - (steps - 1) + step
+    return true, jnp.clip(true, first, last)
+
+
+def _walk_q_block(ik, step, *, block_q, block_k, nq, seq_offset, window):
+    """Under a window, the q block of step ``step`` of k block ``ik``
+    in the dkv kernel's walk (past the last where fewer are live) and the
+    block to fetch.  The steps START at the first q block that sees the
+    k block."""
+    k0 = ik * block_k
+    first = jnp.maximum((k0 - seq_offset) // block_q, 0)
+    last = jnp.clip((k0 + block_k - 1 + window - 1 - seq_offset) // block_q,
+                    0, nq - 1)
+    true = first + step
+    return true, jnp.clip(true, first, last)
+
+
 def _for_live_tiles(
     tile_fn, q0, k0, *, block_q: int, block_k: int, walk: str,
     causal: bool, seq_offset: int, have_segs: bool,
+    window: Optional[int] = None, in_range=True,
 ) -> None:
     """Run ``tile_fn((r0, r1), (c0, c1), masked)`` for every compute tile
     of the block at (``q0``, ``k0``) that holds a live score.  ``c0:c1``
@@ -238,6 +356,28 @@ def _for_live_tiles(
 
     if not causal:
         whole(have_segs)
+        return
+    if window is not None:
+        d = q0 - k0
+        run = jnp.logical_and(
+            jnp.logical_and(d + block_q - 1 >= 0, d - (block_k - 1) < window),
+            in_range)
+        if have_segs:
+            pl.when(run)(lambda: whole(True))
+            return
+        inside = jnp.logical_and(d >= block_k - 1, d + block_q - 1 < window)
+        pl.when(jnp.logical_and(run, inside))(lambda: whole(False))
+        crossed = jnp.logical_and(run, jnp.logical_not(inside))
+        if not _trimmed(block_q, block_k, causal, seq_offset):
+            pl.when(crossed)(lambda: whole(True))
+            return
+        for dd in _crossed_offsets(block_q, window):
+            def _at(dd=dd):
+                for rows, cols, masked in _static_tiles(
+                        dd, block_q, block_k, walk, window):
+                    tile_fn(rows, cols, masked)
+
+            pl.when(jnp.logical_and(crossed, d == dd))(_at)
         return
     run = q0 + block_q - 1 >= k0
     if have_segs:
@@ -259,6 +399,36 @@ def _for_live_tiles(
                 tile_fn((0, c), (c, c + tile), False)
 
 
+def _named(window, name):
+    """``pallas_call``'s name: only a call with a window has one, the
+    others keep the instruction names the benchmark's readers know
+    (``attn.<n>``, ``shard_map.<n>``)."""
+    return None if window is None else name
+
+
+def _k_walk(nk, block_q, block_k, causal, seq_offset, window):
+    """``(grid steps along k, index map (iq, step) -> k block to fetch)``
+    of the forward and dq kernels."""
+    if window is None:
+        return nk, lambda iq, ik: _live_k_block(
+            iq, ik, block_q, block_k, causal, seq_offset)
+    steps = _window_steps(block_q, block_k, nk, seq_offset, window)
+    return steps, lambda iq, ik: _walk_k_block(
+        iq, ik, block_q=block_q, block_k=block_k, nk=nk, steps=steps,
+        seq_offset=seq_offset, window=window)[1]
+
+
+def _k_start(iq, ik, block_q, block_k, nk_all, steps, seq_offset, window):
+    """First key position of grid step (iq, ik) in the forward and dq
+    kernels, and whether that step has a block at all."""
+    if window is None:
+        return ik * block_k, True
+    true, _ = _walk_k_block(
+        iq, ik, block_q=block_q, block_k=block_k, nk=nk_all, steps=steps,
+        seq_offset=seq_offset, window=window)
+    return true * block_k, true >= 0
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -269,7 +439,8 @@ def _fwd_kernel(
     o_ref, lse_ref,
     q_scr, m_scr, l_scr, acc_scr,
     *, causal: bool, scale: float, block_q: int, block_k: int,
-    seq_offset: int, have_segs: bool,
+    seq_offset: int, have_segs: bool, window: Optional[int] = None,
+    nk_all: int = 0,
 ):
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
@@ -286,7 +457,8 @@ def _fwd_kernel(
     # First global position of this block's rows/cols.  seq_offset shifts
     # query positions (queries are the tail of the kv sequence when sq < skv).
     q0 = iq * block_q + seq_offset
-    k0 = ik * block_k
+    k0, in_range = _k_start(iq, ik, block_q, block_k, nk_all, nk,
+                            seq_offset, window)
 
     def _tile(rows, cols, masked):
         (r0, r1), (c0, c1) = rows, cols
@@ -298,7 +470,7 @@ def _fwd_kernel(
             mask = _tile_mask(
                 s.shape, q0 + r0, k0 + c0, causal,
                 qseg_ref[0, 0, r0:r1] if have_segs else None,
-                kseg_ref[0, 0, c0:c1] if have_segs else None, 0,
+                kseg_ref[0, 0, c0:c1] if have_segs else None, 0, window,
             )
             s = jnp.where(mask, s, _NEG_INF)
         # m and l live on all 128 lanes of their rows ([rows, 128]): a
@@ -322,6 +494,7 @@ def _fwd_kernel(
     _for_live_tiles(
         _tile, q0, k0, block_q=block_q, block_k=block_k, walk="k",
         causal=causal, seq_offset=seq_offset, have_segs=have_segs,
+        window=window, in_range=in_range,
     )
 
     @pl.when(ik == nk - 1)
@@ -334,7 +507,8 @@ def _fwd_kernel(
 
 
 def _fwd(
-    q, k, v, q_seg, k_seg, *, causal, scale, block_q, block_k, interpret
+    q, k, v, q_seg, k_seg, *, causal, scale, block_q, block_k, interpret,
+    window=None,
 ) -> Tuple[jax.Array, jax.Array]:
     b, h, sq, d = q.shape
     hkv, skv = k.shape[1], k.shape[2]
@@ -352,18 +526,18 @@ def _fwd(
     kernel = functools.partial(
         _fwd_kernel,
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        seq_offset=seq_offset, have_segs=have_segs,
+        seq_offset=seq_offset, have_segs=have_segs, window=window, nk_all=nk,
     )
-    grid = (b, h, nq, nk)
+    steps, kb = _k_walk(nk, block_q, block_k, causal, seq_offset, window)
+    grid = (b, h, nq, steps)
     out_shape = [
         jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32),
     ]
-    def kb(iq, ik):
-        return _live_k_block(iq, ik, block_q, block_k, causal, seq_offset)
 
     o, lse = pl.pallas_call(
         kernel,
+        name=_named(window, "flash_window_fwd"),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
@@ -404,6 +578,7 @@ def _dq_kernel(
     dq_ref,
     q_scr, dq_scr,
     *, causal, scale, block_q, block_k, seq_offset, have_segs,
+    window=None, nk_all=0,
 ):
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
@@ -415,7 +590,8 @@ def _dq_kernel(
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
     q0 = iq * block_q + seq_offset
-    k0 = ik * block_k
+    k0, in_range = _k_start(iq, ik, block_q, block_k, nk_all, nk,
+                            seq_offset, window)
 
     def _tile(rows, cols, masked):
         (r0, r1), (c0, c1) = rows, cols
@@ -427,7 +603,7 @@ def _dq_kernel(
             mask = _tile_mask(
                 s.shape, q0 + r0, k0 + c0, causal,
                 qseg_ref[0, 0, r0:r1] if have_segs else None,
-                kseg_ref[0, 0, c0:c1] if have_segs else None, 0,
+                kseg_ref[0, 0, c0:c1] if have_segs else None, 0, window,
             )
             s = jnp.where(mask, s, _NEG_INF)
         p = jnp.exp(s - lse_ref[0, 0, 0, r0:r1][:, None])
@@ -445,6 +621,7 @@ def _dq_kernel(
     _for_live_tiles(
         _tile, q0, k0, block_q=block_q, block_k=block_k, walk="k",
         causal=causal, seq_offset=seq_offset, have_segs=have_segs,
+        window=window, in_range=in_range,
     )
 
     @pl.when(ik == nk - 1)
@@ -457,6 +634,7 @@ def _dkv_kernel(
     dk_ref, dv_ref,
     dk_scr, dv_scr,
     *, causal, scale, block_q, block_k, seq_offset, have_segs, reps,
+    window=None, nq_all=0,
 ):
     # Grid is (batch, kv_head, kv_block, q_block * reps): the innermost dim
     # folds the q-blocks of every query head sharing this kv head, so dk/dv
@@ -466,7 +644,12 @@ def _dkv_kernel(
     # lse / delta are rows, as they are stored.
     ik, j = pl.program_id(2), pl.program_id(3)
     nj = pl.num_programs(3)
-    iq = j // reps
+    iq, in_range = j // reps, True
+    if window is not None:  # the walk starts at the first q block that sees
+        iq, _ = _walk_q_block(
+            ik, iq, block_q=block_q, block_k=block_k, nq=nq_all,
+            seq_offset=seq_offset, window=window)
+        in_range = iq <= nq_all - 1
 
     @pl.when(j == 0)
     def _init():
@@ -488,7 +671,7 @@ def _dkv_kernel(
             mask = _tile_mask(
                 st.shape, q0 + c0, k0 + r0, causal,
                 qseg_ref[0, 0, c0:c1] if have_segs else None,
-                kseg_ref[0, 0, r0:r1] if have_segs else None, 1,
+                kseg_ref[0, 0, r0:r1] if have_segs else None, 1, window,
             )
             st = jnp.where(mask, st, _NEG_INF)
         pt = jnp.exp(st - lse_ref[0, 0, :, c0:c1])
@@ -509,6 +692,7 @@ def _dkv_kernel(
     _for_live_tiles(
         _tile, q0, k0, block_q=block_q, block_k=block_k, walk="q",
         causal=causal, seq_offset=seq_offset, have_segs=have_segs,
+        window=window, in_range=in_range,
     )
 
     @pl.when(j == nj - 1)
@@ -518,7 +702,7 @@ def _dkv_kernel(
 
 
 def _bwd(
-    res, g, *, causal, scale, block_q, block_k, interpret
+    res, g, *, causal, scale, block_q, block_k, interpret, window=None
 ):
     q, k, v, q_seg, k_seg, o, lse = res
     do = g
@@ -549,12 +733,12 @@ def _bwd(
         else (lambda ib, ih, i, j: (ib, ih, j, 0)),
     )
 
-    def kb(iq, ik):
-        return _live_k_block(iq, ik, block_q, block_k, causal, seq_offset)
+    steps, kb = _k_walk(nk, block_q, block_k, causal, seq_offset, window)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **common),
-        grid=(b, h, nq, nk),
+        functools.partial(_dq_kernel, **common, window=window, nk_all=nk),
+        name=_named(window, "flash_window_dq"),
+        grid=(b, h, nq, steps),
         in_specs=[
             qkv_spec(block_q, "outer"),       # q
             pl.BlockSpec(
@@ -583,29 +767,46 @@ def _bwd(
     # No such clamp on the dkv grid: there the dead steps come FIRST in a
     # k block's walk, and asking them for the first live q block measured
     # no gain (PR 29: 1.650 ms a call with and without).
+    if window is None:
+        q_steps = nq
+
+        def qb(i, j):
+            return j // reps
+    else:
+        # under a window the walk over q blocks is as long as a window
+        # reaches, from the first q block that sees the k block
+        q_steps = _window_steps(block_k, block_q, nq, seq_offset, window)
+
+        def qb(i, j):
+            return _walk_q_block(
+                i, j // reps, block_q=block_q, block_k=block_k, nq=nq,
+                seq_offset=seq_offset, window=window)[1]
+
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **common, reps=reps),
-        grid=(b, hkv, nk, nq * reps),
+        functools.partial(_dkv_kernel, **common, reps=reps, window=window,
+                          nq_all=nq),
+        name=_named(window, "flash_window_dkv"),
+        grid=(b, hkv, nk, q_steps * reps),
         in_specs=[
             pl.BlockSpec(
                 (1, 1, block_q, d),
-                lambda ib, ih, i, j: (ib, ih * reps + j % reps, j // reps, 0),
+                lambda ib, ih, i, j: (ib, ih * reps + j % reps, qb(i, j), 0),
             ),                                 # q
             qkv_spec(block_k, "outer"),       # k
             qkv_spec(block_k, "outer"),       # v
-            pl.BlockSpec((1, 1, block_q), lambda ib, ih, i, j: (ib, 0, j // reps)),
+            pl.BlockSpec((1, 1, block_q), lambda ib, ih, i, j: (ib, 0, qb(i, j))),
             pl.BlockSpec((1, 1, block_k), lambda ib, ih, i, j: (ib, 0, i)),
             pl.BlockSpec(
                 (1, 1, block_q, d),
-                lambda ib, ih, i, j: (ib, ih * reps + j % reps, j // reps, 0),
+                lambda ib, ih, i, j: (ib, ih * reps + j % reps, qb(i, j), 0),
             ),                                 # do
             pl.BlockSpec(
                 (1, 1, 1, block_q),
-                lambda ib, ih, i, j: (ib, ih * reps + j % reps, 0, j // reps),
+                lambda ib, ih, i, j: (ib, ih * reps + j % reps, 0, qb(i, j)),
             ),
             pl.BlockSpec(
                 (1, 1, 1, block_q),
-                lambda ib, ih, i, j: (ib, ih * reps + j % reps, 0, j // reps),
+                lambda ib, ih, i, j: (ib, ih * reps + j % reps, 0, qb(i, j)),
             ),
         ],
         out_specs=[
@@ -631,30 +832,32 @@ def _bwd(
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9)
+    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10)
 )
-def _flash_bhsd(q, k, v, q_seg, k_seg, causal, scale, block_q, block_k, interpret):
+def _flash_bhsd(q, k, v, q_seg, k_seg, causal, scale, block_q, block_k,
+                interpret, window=None):
     o, _ = _fwd(
         q, k, v, q_seg, k_seg,
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        interpret=interpret,
+        interpret=interpret, window=window,
     )
     return o
 
 
-def _flash_fwd_rule(q, k, v, q_seg, k_seg, causal, scale, block_q, block_k, interpret):
+def _flash_fwd_rule(q, k, v, q_seg, k_seg, causal, scale, block_q, block_k,
+                    interpret, window):
     o, lse = _fwd(
         q, k, v, q_seg, k_seg,
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        interpret=interpret,
+        interpret=interpret, window=window,
     )
     return o, (q, k, v, q_seg, k_seg, o, lse)
 
 
-def _flash_bwd_rule(causal, scale, block_q, block_k, interpret, res, g):
+def _flash_bwd_rule(causal, scale, block_q, block_k, interpret, window, res, g):
     dq, dk, dv = _bwd(
         res, g, causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        interpret=interpret,
+        interpret=interpret, window=window,
     )
     return dq, dk, dv, None, None
 
@@ -673,8 +876,12 @@ def flash_attention(
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Flash attention on [batch, seq, heads, dim] inputs (GQA allowed).
+
+    ``window=w`` (causal only): query ``i`` sees keys ``i - w < j <= i``;
+    ``w`` at or over the key length is the call without a window.
 
     Raises ValueError for shapes the kernels cannot tile; the caller
     (ops.attention.dot_product_attention) has no fallback once it has
@@ -686,6 +893,12 @@ def flash_attention(
         scale = d ** -0.5
     if hq % hkv:
         raise ValueError(f"q heads {hq} not a multiple of kv heads {hkv}")
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(
+                f"a window ({window}) needs causal attention and a width of "
+                "at least 1")
+        window = None if window >= skv else int(window)
     bq = min(block_q, sq)
     bk = min(block_k, skv)
     if sq % bq or skv % bk:
@@ -702,6 +915,7 @@ def flash_attention(
         k_seg = segs[:, None, :]
         q_seg = (segs if segs.shape[1] == sq else segs[:, -sq:])[:, None, :]
     out = _flash_bhsd(
-        qt, kt, vt, q_seg, k_seg, causal, float(scale), bq, bk, interpret
+        qt, kt, vt, q_seg, k_seg, causal, float(scale), bq, bk, interpret,
+        window,
     )
     return out.transpose(0, 2, 1, 3)

@@ -409,6 +409,7 @@ def ring_attention(
     rules=None,
     interpret: bool = False,
     zigzag: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Context-parallel attention on *global* [b, s, h, d] arrays.
 
@@ -425,6 +426,10 @@ def ring_attention(
         seq_to_heads_all_to_all,
     )
 
+    if window is not None:
+        raise NotImplementedError(
+            f"ring attention has no window (window={window}): a layer "
+            "with one must not run under a cp axis")
     cp = mesh.shape.get("cp", 1)
     sp = mesh.shape.get("sp", 1)
     if scale is None:

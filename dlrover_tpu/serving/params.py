@@ -132,6 +132,15 @@ def serving_params_from_llama(
             "would be served as a different model (ROADMAP B1, serving "
             "half: serving/model.py::_mlp and the attention block are "
             "dense-only)")
+    if cfg.layers is not None or cfg.attn_head_gate:
+        raise ValueError(
+            "the serving engine's model has ONE kind of layer: full causal "
+            "attention with one head count and plain RoPE over the whole "
+            f"head, no head gate (attn_head_gate={cfg.attn_head_gate}); "
+            f"this model describes {len(set(cfg.layer_specs))} kinds of "
+            "layer.  Missing: a window in the paged kernel and the cache "
+            "manager (ROADMAP A4), per-layer head counts, partial rotary "
+            "and YaRN in serving/model.py (A3)")
     if dtype is None:
         dtype = cfg.dtype
     variables = nn.meta.unbox(variables)

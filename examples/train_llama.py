@@ -17,8 +17,10 @@ reference's examples/pytorch/mnist + llama2 examples):
 - global-step reports feeding the master's SpeedMonitor.
 
 ``--model`` names a :class:`LlamaConfig` preset (``tiny`` by default, the
-CPU tests' size; ``llama2_7b`` for the real widths) and ``--layers`` cuts
-its depth to what the device at hand holds — widths are never cut.
+CPU tests' size; ``llama2_7b``, ``olmoe_1b_7b`` or ``laguna_xs2`` for real
+widths) and ``--layers`` cuts its depth to what the device at hand holds —
+widths are never cut (``laguna_xs2 --layers 9`` keeps its pattern: the
+dense layer and two periods of window, window, window, full).
 
 Every boot prints one ``[train] boot {json}`` line after its first step
 (platform, device kind, seconds to first step: a restarted worker's is

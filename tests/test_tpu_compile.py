@@ -91,6 +91,24 @@ def test_flash_fwd_bwd_cells_heads_one_chip(one_chip, heads, kv_heads):
     assert text.count("tpu_custom_call") >= 3  # forward, dq, dkv
 
 
+@pytest.mark.parametrize("heads", [64, 48])
+def test_window_flash_fwd_bwd_hybrid_cell_one_chip(one_chip, heads):
+    """The ``train-hybrid-8k`` cell's window layers (Laguna-XS.2: 64 query
+    heads over 8 KV heads; 48 / 8 is its full layers' count, here with the
+    window too) at seq 8192, 2 sequences, w 512: the three named kernels,
+    whose k-grid walks back from the row's last live block."""
+    q = jax.ShapeDtypeStruct((2, 8192, heads, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8192, 8, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    grad = _attn_loss(lambda q, k, v, s: flash_attention(
+        q, k, v, causal=True, window=512))
+    text = jax.jit(grad).lower(q, kv, kv, None).compile().as_text()
+    for name in ("flash_window_fwd", "flash_window_dq", "flash_window_dkv"):
+        assert name in text
+    assert text.count("tpu_custom_call") >= 3
+
+
 def test_flash_under_fsdp_mesh_is_shard_mapped(topo):
     """The default four-chip mesh (fsdp=4): the dispatch must wrap the
     kernel in shard_map over the batch — a bare Mosaic call on GSPMD
@@ -208,4 +226,29 @@ def test_grouped_expert_matmul_compiles_at_olmoe_widths(one_chip,
         s((64, 1024, 2048)), s((64,), jnp.int32)).compile().as_text()
     # 2 forward calls the gradient needs, 3 for the rows' gradient, 3 for
     # the weights' (the last forward matmul's output is not needed)
+    assert text.count("tpu_custom_call") >= 8
+
+
+def test_grouped_expert_matmul_compiles_at_the_hybrid_cells_share(
+        one_chip, monkeypatch):
+    """``train-hybrid-8k``'s expert matmuls: the sorted buffer's static
+    bound of 16 384 x 8 rows, of which the 32 HELD experts' groups are the
+    head, against [32, 2048, 512] and back, forward and backward, with the
+    tile ``grouped_matmul`` will use (256 rows; the other sides clamp to
+    the matrices')."""
+    from dlrover_tpu.models import moe
+
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, wg, wu, wd, sizes):
+        act = jax.nn.silu(moe.grouped_matmul(x, wg, sizes)) \
+            * moe.grouped_matmul(x, wu, sizes)
+        return moe.grouped_matmul(act, wd, sizes).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        s((16384 * 8, 2048)), s((32, 2048, 512)), s((32, 2048, 512)),
+        s((32, 512, 2048)), s((32,), jnp.int32)).compile().as_text()
     assert text.count("tpu_custom_call") >= 8
