@@ -30,7 +30,8 @@ import jax.numpy as jnp
 
 from jax.ad_checkpoint import checkpoint_name
 
-from dlrover_tpu.accel.parallel.mesh import with_logical_constraint
+from dlrover_tpu.accel.parallel.mesh import (ambient_mesh,
+                                              with_logical_constraint)
 from dlrover_tpu.ops.attention import dot_product_attention
 
 Dtype = Any
@@ -653,7 +654,10 @@ class DecoderLayer(nn.Module):
         decode: bool = False,
         cache_len: Optional[int] = None,
         rope=None,
+        stacked=None,
     ) -> jax.Array:
+        """``stacked``: a sparse layer's expert weights as the scan over
+        layers stacks them, and the layer's index (``MoEMLP``)."""
         cfg, spec = self.config, self.spec
         h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="input_norm")(x)
         x = x + Attention(cfg, spec, name="attn")(
@@ -682,9 +686,9 @@ class DecoderLayer(nn.Module):
                 fp8=cfg.fp8,
                 name="mlp",
             )
+            x = x + mlp(h, stacked)
         else:
-            mlp = MLP(cfg, name="mlp")
-        x = x + mlp(h)
+            x = x + MLP(cfg, name="mlp")(h)
         return with_logical_constraint(x, ("batch", "seq", "act_embed"))
 
 
@@ -694,9 +698,11 @@ class _ScanLayer(nn.Module):
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, carry, _):
+    def __call__(self, carry, index, stacks):
         x, positions, segment_ids = carry
-        x = DecoderLayer(self.config, name="layer")(x, positions, segment_ids)
+        x = DecoderLayer(self.config, name="layer")(
+            x, positions, segment_ids,
+            stacked=(stacks["layer"], index) if stacks else None)
         return (x, positions, segment_ids), None
 
 
@@ -723,15 +729,17 @@ class _ScanPeriod(nn.Module):
     specs: Tuple[LayerSpec, ...]
 
     @nn.compact
-    def __call__(self, carry, _):
+    def __call__(self, carry, index, stacks):
         x, positions, segment_ids, ropes = carry
         cfg = self.config
         layer_cls = _layer_class(cfg, in_scan=True)
         kinds = cfg.rope_kinds
         for j, spec in enumerate(self.specs):
-            x = layer_cls(cfg, spec, name=f"layer_{j}")(
+            name = f"layer_{j}"
+            x = layer_cls(cfg, spec, name=name)(
                 x, positions, segment_ids, False, None,
-                ropes[kinds.index(spec.rope)])
+                ropes[kinds.index(spec.rope)],
+                (stacks[name], index) if name in (stacks or ()) else None)
         return (x, positions, segment_ids, ropes), None
 
 
@@ -787,10 +795,13 @@ class LlamaModel(nn.Module):
                 block,
                 variable_axes={"params": 0, "moe_losses": 0},
                 split_rngs={"params": True},
+                in_axes=(0, nn.broadcast),
                 length=cfg.num_layers,
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )
-            (x, _, _), _ = scan(cfg, name="layers")((x, positions, segment_ids), None)
+            (x, _, _), _ = scan(cfg, name="layers")(
+                (x, positions, segment_ids),
+                *self._stacked_experts("layers", cfg.num_layers))
         elif decode:
             # no remat in decode (nothing to rematerialize — inference);
             # keeping the bool OUT of nn.remat also matters: remat would
@@ -856,10 +867,43 @@ class LlamaModel(nn.Module):
                 _ScanPeriod,
                 variable_axes={"params": 0, "moe_losses": 0},
                 split_rngs={"params": True},
+                in_axes=(0, nn.broadcast),
                 length=(len(specs) - lead) // period,
                 metadata_params={nn.PARTITION_NAME: "layers"},
             )
             (x, _, _, _), _ = scan(
                 cfg, specs[lead:lead + period], name="periods")(
-                    (x, positions, segment_ids, ropes), None)
+                    (x, positions, segment_ids, ropes),
+                    *self._stacked_experts(
+                        "periods", (len(specs) - lead) // period))
         return x
+
+    def _stacked_experts(self, name: str, length: int):
+        """What the scan ``name`` over ``length`` layers (or periods) gets
+        beside its carry: each step's index and, to every step alike, the
+        expert weights of its sparse layers as the parameter tree stacks
+        them (``moe.stacked_expert_weights``), so that the grouped matmuls
+        read a layer's tiles in the stack (``moe.grouped_matmul``) where
+        XLA would copy the layer out for them.  The gradient goes through
+        the scan's own slices, as ever.
+
+        ``(None, None)``, and the scan is traced as it always was, where
+        there is no stack the kernels could read: a model without experts;
+        ``init``; weights quantised for the matmuls or kept in another
+        dtype than theirs; a mesh of several devices, where the kernel's
+        operands are replicated first and a stack would be gathered
+        whole."""
+        cfg = self.config
+        mesh = ambient_mesh()
+        if (not cfg.num_experts or cfg.fp8
+                or not self.has_variable("params", name)
+                or (mesh is not None and mesh.size > 1)):
+            return None, None
+        from dlrover_tpu.models.moe import stacked_expert_weights
+
+        stacks = stacked_expert_weights(
+            {layer: tree["mlp"] for layer, tree in nn.unbox(
+                self.get_variable("params", name)).items()}, cfg.dtype)
+        if stacks is None:
+            return None, None
+        return jnp.arange(length), stacks

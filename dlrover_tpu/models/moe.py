@@ -77,35 +77,123 @@ from dlrover_tpu.accel.parallel.mesh import (ambient_mesh,
 GMM_TILING = (256, 1024, 1024)
 
 
+# the expert weights of a ``MoEMLP``, as its parameters name them
+_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
 def _interpret() -> bool:
     """Off the TPU the kernel runs in Pallas's interpreter: the same
     program, slowly (the CPU tests)."""
     return jax.default_backend() != "tpu"
 
 
-def grouped_matmul(lhs: jax.Array, rhs: jax.Array,
-                   group_sizes: jax.Array) -> jax.Array:
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+                   stacked: Optional[Tuple[jax.Array, jax.Array]] = None
+                   ) -> jax.Array:
     """``lhs[rows of group g] @ rhs[g]`` for contiguous row groups: the
-    ``megablox`` kernel JAX ships, forward and backward (its ``tgmm`` makes
+    ``megablox`` kernels JAX ships, forward and backward (``tgmm`` makes
     the weights' gradient), float32 accumulation.
 
     lhs [n, k]; rhs [groups, k, m]; group_sizes [groups] int32 summing to
-    n.  Returns [n, m] in ``lhs.dtype``.
+    n (less where rows behind the last group are no group's).  Returns
+    [n, m] in ``lhs.dtype``.
 
-    On a mesh the operands are replicated first, and so is the result (whose
-    constraint does the same for the cotangent on the way back): a Pallas
-    call is not partitioned, and left to itself GSPMD shards the
-    interpreter's loops on the CPU meshes of the tests, with collectives
-    inside them that the CPU runtime can deadlock on."""
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    ``stacked=(stack, layer)`` says where ``rhs`` lies: it is row ``layer``
+    of ``stack`` [layers, groups, k, m], the weights of all layers as a scan
+    over layers holds them.  The kernel then reads its tiles THERE, the
+    layer added to the group its index map selects, and ``rhs`` is never
+    made: a Pallas call, unlike XLA's own dots, cannot take a slice of the
+    stack as its operand, so XLA copies one out before every call (0.8 ms
+    for OLMoE's 268 MB, six times a layer and step).  ``rhs`` still stands
+    for the layer's weights in the derivative: its gradient, one layer's,
+    is what the scan stacks, and ``stack`` gets none (pass it under
+    ``stop_gradient``).  Only on one device (``LlamaModel`` decides).
 
+    On a mesh of several the operands are replicated first, and so is the
+    result (whose constraint does the same for the cotangent on the way
+    back): a Pallas call is not partitioned, and left to itself GSPMD
+    shards the interpreter's loops on the CPU meshes of the tests, with
+    collectives inside them that the CPU runtime can deadlock on."""
     n, k = lhs.shape
     tm, tk, tn = GMM_TILING
     # a tile's rows must divide n; its other sides may overhang
     tiling = (math.gcd(n, tm), min(tk, k), min(tn, rhs.shape[2]))
-    out = gmm(_replicated(lhs), _replicated(rhs), group_sizes, lhs.dtype,
-              tiling, interpret=_interpret())
-    return _replicated(out)
+    if stacked is None:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        out = gmm(_replicated(lhs), _replicated(rhs), group_sizes, lhs.dtype,
+                  tiling, interpret=_interpret())
+        return _replicated(out)
+    stack, layer = stacked
+    # All layers' groups in one row, and the layer as a NEGATIVE first group:
+    # ``group_offset`` is ``megablox``'s parameter for a shard of the groups,
+    # used here for what its documentation does not name.  Three things in
+    # ``gmm`` (jax 0.9.0) make that read this layer's tiles and only those;
+    # a JAX that moves one of them would read another layer's weights, and
+    # tests/test_moe.py's bit-exact comparisons with the sliced call are
+    # what says so (run them on every JAX upgrade):
+    #  - the right-hand side's index map is ``group_ids[tile] -
+    #    group_offset``, so group g is read at row ``layer * groups + g``;
+    #  - ``make_group_metadata`` keeps group g where ``start_group <= g <=
+    #    start_group + rhs.shape[0] - 1``: the first is not above 0 and the
+    #    last not below ``groups - 1``, so every group is kept, and no tile
+    #    is rolled to the front (no group's number is below ``start_group``);
+    #  - ``rhs.shape[0]`` (layers x groups) is not below the number of
+    #    groups, so ``_zero_uninitialized_memory`` does not run.
+    return _gmm_in_stack(lhs, rhs, group_sizes,
+                         stack.reshape(-1, *stack.shape[2:]),
+                         -(layer.astype(jnp.int32) * group_sizes.shape[0]),
+                         tiling)
+
+
+def stacked_expert_weights(scanned, dtype):
+    """For the scan over layers that owns ``scanned`` (``{layer's name:
+    the parameters of its MLP}``, every leaf stacked over the scan's
+    steps): what :class:`MoEMLP` takes as ``stacked[0]`` in each sparse
+    layer of a step, ``{layer's name: {"w_gate": [steps, experts, hidden,
+    width], ...}}`` under ``stop_gradient``.  None where no kernel could
+    read a stack: no layer is sparse, or the weights are kept in another
+    dtype than the matmuls' ``dtype`` (a layer's cast is a copy of its
+    own)."""
+    stacks = {layer: {name: mlp[name] for name in _EXPERT_WEIGHTS}
+              for layer, mlp in scanned.items() if _EXPERT_WEIGHTS[0] in mlp}
+    leaves = jax.tree_util.tree_leaves(stacks)
+    if not leaves or any(x.dtype != dtype for x in leaves):
+        return None
+    return jax.lax.stop_gradient(stacks)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _gmm_in_stack(lhs, rhs, group_sizes, rows, first, tiling):
+    """The grouped matmul of ``lhs`` with a layer's weights ``rhs``, whose
+    VALUES are read from ``rows`` [layers x groups, k, m], the layer's
+    starting ``-first`` groups in.  ``megablox``'s own derivative would
+    make a gradient as large as ``rows``; this one makes the layer's, and
+    gives it to ``rhs``."""
+    return _gmm_in_stack_fwd(lhs, rhs, group_sizes, rows, first, tiling)[0]
+
+
+def _gmm_in_stack_fwd(lhs, rhs, group_sizes, rows, first, tiling):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    out = gmm(lhs, rows, group_sizes, lhs.dtype, tiling, first,
+              interpret=_interpret())
+    return out, (lhs, group_sizes, rows, first)
+
+
+def _gmm_in_stack_bwd(tiling, residuals, g):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    lhs, group_sizes, rows, first = residuals
+    d_lhs = gmm(g, rows, group_sizes, lhs.dtype, tiling, first,
+                transpose_rhs=True, interpret=_interpret())
+    d_rhs = tgmm(lhs.swapaxes(0, 1), g, group_sizes, rows.dtype, tiling,
+                 num_actual_groups=group_sizes.shape[0],
+                 interpret=_interpret())
+    return d_lhs, d_rhs, None, None, None
+
+
+_gmm_in_stack.defvjp(_gmm_in_stack_fwd, _gmm_in_stack_bwd)
 
 
 def _replicated(x: jax.Array) -> jax.Array:
@@ -256,7 +344,12 @@ class MoEMLP(nn.Module):
     per_expert_init: bool = False
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array, stacked=None) -> jax.Array:
+        """``stacked``, inside a scan over layers: the three expert
+        weights as the scan stacks them (``{"w_gate": [layers, experts,
+        hidden, width], ...}``, in ``self.dtype``, under ``stop_gradient``)
+        and this layer's index, for :func:`grouped_matmul` to read them in
+        place."""
         b, s, m = x.shape
         e, h, k = self.num_experts, self.intermediate_size, self.top_k
         t = b * s
@@ -351,20 +444,26 @@ class MoEMLP(nn.Module):
             from dlrover_tpu.ops.fp8 import fake_quant_fp8, grad_quant_fp8
         else:
             fake_quant_fp8 = grad_quant_fp8 = lambda x: x  # noqa: E731
+
+        def lies_in(name):
+            return stacked and (stacked[0][name], stacked[1])
+
         with jax.named_scope("moe_experts"):
             wg = fake_quant_fp8(w_gate.astype(self.dtype))
             wu = fake_quant_fp8(w_up.astype(self.dtype))
             wd = fake_quant_fp8(w_down.astype(self.dtype))
             xq = fake_quant_fp8(xs)
-            gate = grad_quant_fp8(grouped_matmul(xq, wg, counts))
-            up = grad_quant_fp8(grouped_matmul(xq, wu, counts))
+            gate = grad_quant_fp8(
+                grouped_matmul(xq, wg, counts, lies_in("w_gate")))
+            up = grad_quant_fp8(
+                grouped_matmul(xq, wu, counts, lies_in("w_up")))
             act = nn.silu(gate) * up
             if self.fp8 and self.experts_held is not None:
                 # fp8 scales by the largest entry: not one of rows that
                 # no matmul wrote
                 act = jnp.where(live, act, jnp.zeros((), act.dtype))
-            out = grad_quant_fp8(
-                grouped_matmul(fake_quant_fp8(act), wd, counts))
+            out = grad_quant_fp8(grouped_matmul(
+                fake_quant_fp8(act), wd, counts, lies_in("w_down")))
 
         with jax.named_scope("moe_combine"):
             if self.experts_held is not None:

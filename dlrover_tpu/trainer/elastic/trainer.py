@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 import time
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
@@ -88,6 +89,44 @@ def plan_global_batch(
         data_shards=shards,
         grad_accum_steps=global_batch_size // micro_global,
     )
+
+
+def expert_weight_copies(step_text: str) -> int:
+    """How many instructions of a compiled step's text
+    (:meth:`ElasticTrainer.compiled_step_text`) make an operand
+    [groups, k, n] of a grouped expert matmul (``gmm.<n>``) by a
+    ``dynamic-slice``, alone or in a fusion: copies of a layer's expert
+    weights out of the scan's stack, which a Pallas call cannot slice as
+    its operand (``models/moe.py grouped_matmul`` reads the stack in
+    place: 0).  The text holds a loop's body once, so this counts by body
+    and not by step: 6 for each sparse layer of a body where every call
+    gets its copy (3 forward, 3 in the backward's recomputed forward)."""
+    made_by, slicing, calls, computation = {}, set(), [], None
+    for line in step_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            computation = head.group(1)
+            continue
+        m = re.match(
+            r"\s+(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\((.*)", line)
+        if not m:
+            continue
+        name, dims, opcode, rest = m.groups()
+        if opcode == "dynamic-slice":
+            slicing.add(computation)
+        if opcode == "custom-call" and re.match(r"gmm(\.\d+)?$", name):
+            calls.append(rest.split(")", 1)[0])
+        called = re.search(r"calls=%([^\s,]+)", rest)
+        made_by[name] = (opcode, called and called.group(1),
+                         dims.count(",") + 1)
+    copies = set()
+    for operands in calls:
+        for operand in re.findall(r"%([^\s,)]+)", operands):
+            opcode, called, rank = made_by.get(operand, (None, None, 0))
+            if rank == 3 and (opcode == "dynamic-slice" or (
+                    opcode == "fusion" and called in slicing)):
+                copies.add(operand)
+    return len(copies)
 
 
 class ElasticTrainer:

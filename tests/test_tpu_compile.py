@@ -252,3 +252,106 @@ def test_grouped_expert_matmul_compiles_at_the_hybrid_cells_share(
         s((16384 * 8, 2048)), s((32, 2048, 512)), s((32, 2048, 512)),
         s((32, 512, 2048)), s((32,), jnp.int32)).compile().as_text()
     assert text.count("tpu_custom_call") >= 8
+
+
+def _olmoe_cell():
+    from dlrover_tpu.models.llama import LlamaConfig
+
+    return LlamaConfig.olmoe_1b_7b(
+        num_layers=3, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+        remat=True), 1, 4096
+
+
+def _hybrid_cell():
+    from dlrover_tpu.models.llama import LlamaConfig
+
+    return LlamaConfig.laguna_xs2(
+        num_layers=9, moe_experts_held=(0, 32), vocab_size=12544,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, remat=True), 2, 8192
+
+
+@pytest.mark.parametrize("cell,stack,sparse_layers_a_loop", [
+    (_olmoe_cell, (3, 64, 2048, 1024), 1),
+    (_hybrid_cell, (2, 32, 2048, 512), 4),
+], ids=["train-moe-dropless", "train-hybrid-8k"])
+def test_sparse_cells_step_reads_expert_weights_in_the_stack(
+        topo, monkeypatch, cell, stack, sparse_layers_a_loop):
+    """The whole train step of the two sparse cells (``ElasticTrainer``'s
+    own jitted step, bf16 state, remat, the scan over layers / periods) as
+    the chip's compiler makes it: no copy of a layer's expert weights out
+    of the stack ahead of a grouped matmul (6 a sparse layer before
+    PR 33, 0.8 ms each at OLMoE's sizes), and the kernels still named
+    ``gmm.<n>`` / ``tgmm.<n>``, 12 a sparse layer (3 forward, 3
+    recomputed, 3 + 3 backward): what the benchmark's readers find them
+    by.  The text holds a loop's body once."""
+    import re
+
+    import flax.linen as nn
+
+    from dlrover_tpu.accel.parallel.mesh import logical_rules_context
+    from dlrover_tpu.models.llama import LlamaModel
+    from dlrover_tpu.trainer.elastic.trainer import (
+        ElasticTrainer, expert_weight_copies)
+
+    # the backend here is the CPU: steer attention and the experts onto
+    # the chip's kernels
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, rows, seq = cell()
+    trainer = ElasticTrainer(
+        LlamaModel(cfg), global_batch_size=rows, micro_batch_per_shard=rows,
+        seq_len=seq, checkpoint_dir=None, save_memory_interval=0,
+        save_storage_interval=0)
+    trainer.prepare(devices=[topo.devices[0]])
+    res = trainer.result
+    state = nn.unbox(res.abstract_state)
+    scanned = state.params["layers"]["layer"] if cfg.layers is None \
+        else state.params["periods"]["layer_0"]
+    assert scanned["mlp"]["w_gate"].shape == stack
+    batch = {"input_ids": jax.ShapeDtypeStruct((rows, seq), jnp.int32)}
+    with logical_rules_context(res.config.logical_rules), res.mesh:
+        text = res.jit_train_step.lower(state, batch).compile().as_text()
+    assert expert_weight_copies(text) == 0
+    calls = re.findall(r"^\s+%(t?gmm)(?:\.\d+)? = ", text, re.M)
+    assert calls.count("gmm") == 9 * sparse_layers_a_loop
+    assert calls.count("tgmm") == 3 * sparse_layers_a_loop
+    # all layers' groups in one row: the operand the kernels index into
+    assert f"bf16[{stack[0] * stack[1]},{stack[2]},{stack[3]}]" in text
+
+
+def test_expert_weight_copies_counts_a_sliced_operand():
+    """The counter on a step text made by hand: one fusion that slices
+    feeds two ``gmm`` calls (one copy), a plain slice a third, and a
+    sliced operand of another rank or of another kernel is not a copy of
+    expert weights."""
+    from dlrover_tpu.trainer.elastic.trainer import expert_weight_copies
+
+    text = """
+%fused_computation.1 (p0: bf16[3,4,8,8], p1: s32[]) -> bf16[4,8,8] {
+  %p0 = bf16[3,4,8,8]{3,2,1,0} parameter(0)
+  %p1 = s32[] parameter(1)
+  %dynamic-slice.1 = bf16[1,4,8,8]{3,2,1,0} dynamic-slice(%p0, %p1), dynamic_slice_sizes={1,4,8,8}
+  ROOT %bitcast.1 = bf16[4,8,8]{2,1,0} bitcast(%dynamic-slice.1)
+}
+
+%fused_computation.2 (p0: bf16[16,8]) -> bf16[16,8] {
+  %p0.1 = bf16[16,8]{1,0} parameter(0)
+  ROOT %negate.1 = bf16[16,8]{1,0} negate(%p0.1)
+}
+
+ENTRY %main (a: bf16[3,4,8,8], i: s32[], x: bf16[16,8]) -> bf16[16,8] {
+  %a = bf16[3,4,8,8]{3,2,1,0} parameter(0)
+  %i = s32[] parameter(1)
+  %x = bf16[16,8]{1,0} parameter(2)
+  %dynamic-slice_bitcast_fusion.1 = bf16[4,8,8]{2,1,0:T(8,128)(2,1)} fusion(%a, %i), kind=kLoop, calls=%fused_computation.1, metadata={}
+  %fusion.2 = bf16[16,8]{1,0} fusion(%x), kind=kLoop, calls=%fused_computation.2
+  %dynamic-slice.2 = bf16[4,8,8]{2,1,0} dynamic-slice(%a, %i), dynamic_slice_sizes={4,8,8}
+  %dynamic-slice.3 = bf16[16,8]{1,0} dynamic-slice(%x, %i), dynamic_slice_sizes={16,8}
+  %gmm.1 = bf16[16,8]{1,0} custom-call(%i, %fusion.2, %dynamic-slice_bitcast_fusion.1), custom_call_target="tpu_custom_call"
+  %gmm.2 = bf16[16,8]{1,0} custom-call(%i, %gmm.1, %dynamic-slice_bitcast_fusion.1), custom_call_target="tpu_custom_call"
+  %gmm = bf16[16,8]{1,0} custom-call(%i, %dynamic-slice.3, %dynamic-slice.2), custom_call_target="tpu_custom_call"
+  %attn.4 = bf16[16,8]{1,0} custom-call(%i, %gmm, %dynamic-slice.2), custom_call_target="tpu_custom_call"
+  ROOT %tgmm.1 = bf16[4,8,8]{2,1,0} custom-call(%i, %gmm.2, %attn.4), custom_call_target="tpu_custom_call"
+}
+"""
+    assert expert_weight_copies(text) == 2
+    assert expert_weight_copies(text.replace("gmm", "other")) == 0
