@@ -127,6 +127,30 @@ class LlamaConfig:
     moe_experts_held: Optional[Tuple[int, int]] = None
     # initialise each expert as a matrix of its own (MoEMLP.per_expert_init)
     moe_per_expert_init: bool = False
+    # a learned bias added to the scores for the CHOICE of the top_k only
+    # (``noaux_tc``); the weights stay the scores' own
+    moe_select_bias: bool = False
+    # of a model whose layers are otherwise alike: this many leading
+    # layers keep the dense MLP, the rest are sparse
+    moe_first_dense: int = 0
+    # latent attention (MLA; ``kv_lora_rank`` 0 = the grouped-query block):
+    # the query through a normed bottleneck of ``q_lora_rank``; one normed
+    # latent row of ``kv_lora_rank`` and one rotated key row of
+    # ``qk_rope_head_dim`` a token, shared by all heads; a head's key is
+    # ``qk_nope_head_dim`` of the latent's up-projection beside that row,
+    # its value ``v_head_dim`` of it; the rotary dimensions rotate in
+    # adjacent pairs.  Served only (serving/latent.py)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # a learned selection of keys (``index_topk`` 0 = none): an indexer of
+    # ``index_n_heads`` heads of ``index_head_dim`` scores every key
+    # behind a query, and the query attends to the ``index_topk`` largest
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # a sigmoid gate on the attention output, one a query head, from the
     # layer's normed input
     attn_head_gate: bool = False
@@ -170,6 +194,8 @@ class LlamaConfig:
 
     @property
     def head_dim_(self) -> int:
+        if self.kv_lora_rank:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.head_dim or self.hidden_size // self.num_heads
 
     def __post_init__(self):
@@ -183,10 +209,15 @@ class LlamaConfig:
         """Every layer's description; of a uniform model, the one kind."""
         if self.layers is not None:
             return self.layers
-        return (LayerSpec(
-            num_heads=self.num_heads, rope=RopeSpec(theta=self.rope_theta),
-            mlp="sparse" if self.num_experts else "dense"),
-        ) * self.num_layers
+        def one(mlp):
+            return LayerSpec(num_heads=self.num_heads,
+                             rope=RopeSpec(theta=self.rope_theta), mlp=mlp)
+
+        if not self.num_experts:
+            return (one("dense"),) * self.num_layers
+        lead = min(self.moe_first_dense, self.num_layers)
+        return (one("dense"),) * lead + (one("sparse"),) * (
+            self.num_layers - lead)
 
     @property
     def rope_kinds(self) -> Tuple[RopeSpec, ...]:
@@ -200,7 +231,18 @@ class LlamaConfig:
     def layer_params(self, spec: LayerSpec) -> int:
         """Parameters of one layer as this device holds it."""
         h, d = self.hidden_size, self.head_dim_
-        n = h * d * (spec.num_heads * 2 + self.num_kv_heads * 2) + 2 * h
+        if self.kv_lora_rank:
+            heads, q, c = spec.num_heads, self.q_lora_rank, self.kv_lora_rank
+            n = (h * q + q + q * heads * d
+                 + h * (c + self.qk_rope_head_dim) + c
+                 + c * heads * (self.qk_nope_head_dim + self.v_head_dim)
+                 + heads * self.v_head_dim * h + 2 * h)
+            if self.index_topk:
+                i = self.index_head_dim
+                n += (q * self.index_n_heads * i + h * i + 2 * i
+                      + h * self.index_n_heads)
+        else:
+            n = h * d * (spec.num_heads * 2 + self.num_kv_heads * 2) + 2 * h
         if self.attn_head_gate:
             n += h * spec.num_heads
         if self.qk_norm:
@@ -209,6 +251,8 @@ class LlamaConfig:
             held = (self.moe_experts_held or (0, self.num_experts))[1]
             n += 3 * h * self.expert_width * held + h * self.num_experts
             n += 3 * h * self.moe_shared_width
+            if self.moe_select_bias:
+                n += self.num_experts
         else:
             n += 3 * h * self.intermediate_size
         return n
@@ -301,6 +345,54 @@ class LlamaConfig:
         return cls(**base)
 
     @classmethod
+    def glm5(cls, **kw) -> "LlamaConfig":
+        """zai-org/GLM-5 (``glm_moe_dsa``) as its config.json has it: 78
+        layers of latent attention (64 heads of 192 + 64 / 256 over a
+        latent of 512, the query through 2048) with an indexer (32 heads
+        of 128) that picks the 2048 keys a query attends to; 3 leading
+        dense layers of 12288, then 256 sigmoid-routed experts of 2048, 8
+        a token chosen by score + bias, weights over their sum x 2.5,
+        beside one shared expert.  Served, not trained.  ``num_layers``,
+        ``moe_first_dense``, ``moe_experts_held`` and ``vocab_size`` give
+        one chip its share; the next-token-prediction module is no part
+        of the forward pass."""
+        base = dict(
+            vocab_size=154880,
+            hidden_size=6144,
+            intermediate_size=12288,
+            num_layers=78,
+            num_heads=64,
+            num_kv_heads=64,
+            max_seq_len=202752,
+            rope_theta=1000000.0,
+            rms_norm_eps=1e-5,
+            q_lora_rank=2048,
+            kv_lora_rank=512,
+            qk_nope_head_dim=192,
+            qk_rope_head_dim=64,
+            v_head_dim=256,
+            index_n_heads=32,
+            index_head_dim=128,
+            index_topk=2048,
+            num_experts=256,
+            moe_top_k=8,
+            moe_norm_topk_prob=True,
+            moe_intermediate_size=2048,
+            moe_score_fn="sigmoid",
+            moe_routed_scale=2.5,
+            moe_shared_width=2048,
+            moe_select_bias=True,
+            moe_first_dense=3,
+            moe_per_expert_init=True,
+            moe_aux_loss_coef=0.0,
+            moe_z_loss_coef=0.0,
+            scan_layers=False,
+            remat=False,
+        )
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
     def from_preset(
         cls, name: str, num_layers: int = 0, **kw
     ) -> "LlamaConfig":
@@ -332,7 +424,7 @@ class LlamaConfig:
 
 
 #: presets the entry points (examples/, the serving worker) can name
-PRESETS = ("tiny", "llama2_7b", "olmoe_1b_7b", "laguna_xs2")
+PRESETS = ("tiny", "llama2_7b", "olmoe_1b_7b", "laguna_xs2", "glm5")
 
 
 def resolve_remat_policy(name: str):
@@ -681,6 +773,7 @@ class DecoderLayer(nn.Module):
                 shared_width=cfg.moe_shared_width,
                 experts_held=cfg.moe_experts_held,
                 per_expert_init=cfg.moe_per_expert_init,
+                select_bias=cfg.moe_select_bias,
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
                 fp8=cfg.fp8,
@@ -761,6 +854,14 @@ class LlamaModel(nn.Module):
         :func:`dlrover_tpu.ops.losses.fused_lm_head_loss` so the full
         logits are never materialized."""
         cfg = self.config
+        if cfg.kv_lora_rank or cfg.moe_first_dense:
+            raise NotImplementedError(
+                "LlamaModel trains the grouped-query block: latent "
+                f"attention (kv_lora_rank={cfg.kv_lora_rank}), its indexer "
+                "and leading dense layers by count (moe_first_dense="
+                f"{cfg.moe_first_dense}) are served only "
+                "(serving/latent.py); a training layer for them is "
+                "ROADMAP Reach A5")
         if positions is None:
             positions = jnp.arange(input_ids.shape[1])
         embed = nn.Embed(

@@ -307,6 +307,38 @@ def routing_stats(moe_losses) -> Dict[str, jax.Array]:
     }
 
 
+def route(logits: jax.Array, top_k: int, score_fn: str,
+          norm_topk_prob: bool, routed_scale: float,
+          select_bias: Optional[jax.Array] = None):
+    """The router's arithmetic, for the training layer and the served one
+    (serving/latent.py): ``logits`` [t, experts] float32 -> ``(weights
+    [t, top_k], experts [t, top_k], probs [t, experts])``.  ``softmax``
+    scores over all experts, or the ``sigmoid`` of each logit (``probs``
+    then the scores over their sum); the ``top_k`` largest scores, with
+    ``select_bias`` [experts] the largest of ``score + bias`` while the
+    weights stay the scores' own (``noaux_tc``); ``norm_topk_prob``
+    divides the weights by their sum, ``routed_scale`` multiplies them."""
+    # replicated: the picks are sorted over ALL tokens by the caller, and
+    # XLA's partitioner aborts on a batch-sharded top-k inside the
+    # pipeline's partly manual shard_map
+    if score_fn == "softmax":
+        scores = probs = _replicated(jax.nn.softmax(logits, axis=-1))
+    else:
+        scores = _replicated(jax.nn.sigmoid(logits))
+    if select_bias is None:
+        top_p, top_e = jax.lax.top_k(scores, top_k)  # [t, k]
+    else:
+        _, top_e = jax.lax.top_k(scores + select_bias, top_k)
+        top_p = jnp.take_along_axis(scores, top_e, axis=-1)
+    if score_fn != "softmax":
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    if norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if routed_scale != 1.0:
+        top_p = top_p * routed_scale
+    return top_p, top_e, probs
+
+
 class MoEMLP(nn.Module):
     """Sparse SwiGLU FFN (drop-in for the dense MLP): every token's
     ``top_k`` experts, none dropped.
@@ -336,6 +368,8 @@ class MoEMLP(nn.Module):
     shared_width: int = 0
     # (first, count) of the experts this device holds (None = all)
     experts_held: Optional[Tuple[int, int]] = None
+    # a bias on the scores for the CHOICE of the top_k only (route)
+    select_bias: bool = False
     # LeCun fan-in of ONE expert's matrix.  Off, the fan-in is the whole
     # stack's (the expert axis counts as receptive field), which makes an
     # expert's output (experts^-1/2)^3 of a dense layer's at
@@ -388,20 +422,16 @@ class MoEMLP(nn.Module):
 
         with jax.named_scope("moe_route"):
             logits = router(x).reshape(t, e)  # f32
-            # replicated: the picks are sorted over ALL tokens below, and
-            # XLA's partitioner aborts on a batch-sharded top-k inside the
-            # pipeline's partly manual shard_map
-            if self.score_fn == "softmax":
-                probs = _replicated(jax.nn.softmax(logits, axis=-1))
-                top_p, top_e = jax.lax.top_k(probs, k)  # [t, k]
-            else:
-                scores = _replicated(jax.nn.sigmoid(logits))
-                top_p, top_e = jax.lax.top_k(scores, k)
-                probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
-            if self.norm_topk_prob:
-                top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-            if self.routed_scale != 1.0:
-                top_p = top_p * self.routed_scale
+            bias = None
+            if self.select_bias:
+                # ``noaux_tc``: moved by the load, never by a gradient
+                bias = jax.lax.stop_gradient(self.param(
+                    "select_bias", nn.with_logical_partitioning(
+                        nn.initializers.zeros_init(), ("expert",)),
+                    (e,), jnp.float32))
+            top_p, top_e, probs = route(
+                logits, k, self.score_fn, self.norm_topk_prob,
+                self.routed_scale, bias)
             picks = top_e.reshape(t * k)
             counts = jnp.sum(jax.nn.one_hot(picks, e, dtype=jnp.int32), axis=0)
             share = counts.astype(jnp.float32) / (t * k)

@@ -97,6 +97,19 @@ class EngineStats:
     #                               them: whole page groups up to each
     #                               slot's length.  Both stay 0 where
     #                               the gather path decodes
+    # a model with a learned selection of keys (LlamaConfig.index_topk),
+    # summed over queries (decode forwards and prefill chunks alike) and
+    # over nothing else: one layer's rows, as every layer reads the same
+    dsa_rows_live: int = 0        # key rows the queries could see
+    index_rows_scanned: int = 0   # index keys scored for them: whole
+    #                               page groups / key blocks up to each
+    attn_rows_selected: int = 0   # rows they attended to:
+    #                               min(live, index_topk) each
+    # sparse experts served as one share (LlamaConfig.moe_experts_held):
+    # the router's picks over ALL experts, and those on the experts held
+    # here (a device reduction carried in the cache beside the pools)
+    moe_picks: int = 0
+    moe_picks_held: int = 0
 
     @property
     def decode_tokens_per_sec(self) -> float:
@@ -118,6 +131,23 @@ class EngineStats:
         /metrics; ``tokens_per_forward`` is the derived win)."""
         return self.spec_accepted / self.spec_proposed \
             if self.spec_proposed else 0.0
+
+    @property
+    def dsa_selected_ratio(self) -> float:
+        """Rows attended per row live under a learned selection: 1.0
+        while contexts are no longer than ``index_topk`` (the selection
+        is the identity), ``index_topk / context`` beyond (0.0 for a
+        model without one)."""
+        return self.attn_rows_selected / self.dsa_rows_live \
+            if self.dsa_rows_live else 0.0
+
+    @property
+    def moe_held_share(self) -> float:
+        """Picks on the experts this replica holds over all picks:
+        ``held / num_experts`` when the router spreads evenly (0.0 for
+        a dense model)."""
+        return self.moe_picks_held / self.moe_picks \
+            if self.moe_picks else 0.0
 
     @property
     def kv_stream_ratio(self) -> float:
@@ -201,6 +231,18 @@ class InferenceEngine:
         coarser rounding, bounded by the drift tests of
         ``tests/test_paged_kernel.py``.
 
+        ``cache_blocks`` counts blocks of ``block_size`` ROWS, a row
+        being what one token keeps in one layer: K and V of every KV head
+        (``2 x kv_heads x head_dim`` values) or, of a latent-attention
+        model, its normed latent row, its rotated key row and its index
+        key (512 + 64 + 128 values for GLM-5: 1 408 bytes in bf16, no
+        head axis, in two pools under the sequence's ONE block table;
+        the latent pool pads its 576 to 640, whole 128-lane tiles, so
+        a row costs 1 536 bytes: ``serving/latent.py``).
+        Such a model needs ``paged=True``, keeps its rows in the model's
+        dtype (``kv_dtype`` None, ``kv_budget_x`` 1) and runs on one
+        device with unquantized weights.
+
         ``attention_impl`` selects the paged decode attention read:
         ``"xla"`` = fused gather (materializes the dequantized dense
         view), ``"pallas"`` = the fused paged kernel (streams blocks
@@ -211,7 +253,11 @@ class InferenceEngine:
         ``"xla"`` (the interpret-mode kernel is a correctness tool);
         an explicit ``"pallas"`` is honored anywhere (interpret mode
         off-TPU).  The resolved choice is ``self.attention_impl``,
-        the measurement (when taken) ``self.attention_impl_us``.
+        the measurement (when taken) ``self.attention_impl_us``.  Of a
+        latent-attention model it selects the indexer's scan of a slot's
+        index keys: ``"pallas"`` = ``paged_index_scores`` (live pages
+        only), ``"xla"`` = a gather of the whole table (the off-chip
+        harness); ``"auto"`` = the kernel on a TPU, unmeasured.
 
         ``prefix_sharing=False`` (paged pools only; ignored dense)
         disables copy-on-write prefix-block sharing: every admission
@@ -303,6 +349,16 @@ class InferenceEngine:
                 f"kv_dtype={kv_dtype!r} not supported: use None/'bf16' "
                 "(native), 'int8' or 'int4'")
         self.kv_budget_x = 1.0
+        # latent attention (serving/latent.py): rows without a head axis
+        # in a latent pool and an index-key pool
+        self._latent = bool(cfg.kv_lora_rank)
+        if self._latent and not (
+                self.paged and self.kv_dtype is None and mesh is None
+                and not int8):
+            raise ValueError(
+                "a latent-attention model is served from paged pools in "
+                "the model's dtype on one device: pass paged=True, no "
+                "kv_dtype, no mesh, int8=False")
         if self.paged:
             # block-pool cache (serving/paged.py): per-sequence memory
             # scales with ACTUAL lengths, concurrency is bounded by the
@@ -317,8 +373,9 @@ class InferenceEngine:
             self._max_blocks = -(-cache_len // self.block_size)
             # THE budget function — the same source the regression
             # test pins the router ledger to (serving/paged.py)
-            self.kv_budget_x = kv_budget_multiplier(
-                cfg.dtype, cfg.head_dim_, self.kv_dtype)
+            if not self._latent:
+                self.kv_budget_x = kv_budget_multiplier(
+                    cfg.dtype, cfg.head_dim_, self.kv_dtype)
             # +1: block 0 is the trash sink (never allocated), so the
             # default must still let every slot hold a full-length
             # sequence.  An EXPLICIT cache_blocks is an HBM budget
@@ -345,7 +402,30 @@ class InferenceEngine:
                         else cfg.head_dim_)
             kvd = (n_blocks, self.block_size,
                    cfg.num_kv_heads, code_dim)
-            if self.kv_dtype in ("int8", "int4"):
+            if self._latent:
+                from dlrover_tpu.serving.latent import latent_row_width
+
+                shape = (n_blocks, self.block_size)
+                self._cache = {
+                    "latent_pool": [
+                        jnp.zeros(shape + (latent_row_width(cfg),),
+                                  cfg.dtype)
+                        for _ in range(cfg.num_layers)],
+                    "index_pool": [
+                        jnp.zeros(shape + (cfg.index_head_dim
+                                           if cfg.index_topk else 0,),
+                                  cfg.dtype)
+                        for _ in range(cfg.num_layers)],
+                    "table": jnp.asarray(self._table_np),
+                    # the slot whose forward the programs hand back
+                    # (``watch``); -1: none
+                    "watch_slot": jnp.asarray(-1, jnp.int32),
+                }
+                if cfg.num_experts:
+                    # [picks, picks on held experts], wrapping: the host
+                    # adds differences (_book_moe_picks)
+                    self._cache["moe_picks"] = jnp.zeros(2, jnp.uint32)
+            elif self.kv_dtype in ("int8", "int4"):
                 from dlrover_tpu.models.quantize import KV_SCALE_DTYPE
 
                 self._cache = {
@@ -412,6 +492,19 @@ class InferenceEngine:
         self._finished: List[Request] = []
         self._next_rid = 0
         self.stats = EngineStats()
+        self._moe_picks_seen = np.zeros(2, np.uint32)
+        self._watch = None                 # ``watch``'s predicate
+        self._watch_slot = -1
+        self.witness_log: List[Dict[str, Any]] = []
+        # slots one prefill-chunk dispatch advances: all that prefill,
+        # or of a latent model one (its attention walks a row's live key
+        # blocks one row after another anyway, and one group size is one
+        # program to compile, not max_slots)
+        self._prefill_group = 1 if self._latent else self.max_slots
+        # names of the two per-layer pool lists a bucketed prefill's
+        # results are scattered into
+        self._pool_names = (("latent_pool", "index_pool") if self._latent
+                            else ("k_pool", "v_pool"))
         # paged decode attention: gather (xla) vs fused kernel
         # (pallas), resolved ONCE at build — "auto" measures both on
         # this engine's real pool geometry and picks the faster
@@ -455,6 +548,11 @@ class InferenceEngine:
         if req in ("xla", "pallas"):
             self.attention_impl_why = "requested"
             return req, None
+        if self._latent and not self._kernel_interpret:
+            self.attention_impl_why = (
+                "auto: the index kernel reads live pages only, the gather "
+                "the whole table; not measured")
+            return "pallas", None
         if self._kernel_interpret:
             # no TPU: the interpret-mode kernel is a parity harness,
             # not a perf candidate — auto must not "measure" it
@@ -524,20 +622,26 @@ class InferenceEngine:
                     attention_impl=impl,
                     kernel_interpret=kernel_interpret,
                     active=active)
+                cache = dict(cache)
+                seen = cache.pop("witness", None)
                 key, sub = jax.random.split(key)
                 nxt = select_token(logits, sub, temperature, top_k, top_p)
                 toks = jnp.where(active, nxt.astype(toks.dtype), toks)
                 pos = jnp.where(active, pos + 1, pos)
-                return (toks, pos, cache, key), nxt
+                return (toks, pos, cache, key), (nxt, seen)
 
             with jax.named_scope("decode_chunk"):
-                (tokens, positions, cache, rng), out = jax.lax.scan(
-                    step, (tokens, positions, cache, rng), None,
-                    length=n_steps,
-                )
-            return out.T, tokens, positions, cache, rng
+                (tokens, positions, cache, rng), (out, witness) = \
+                    jax.lax.scan(
+                        step, (tokens, positions, cache, rng), None,
+                        length=n_steps,
+                    )
+            # ``witness``: the watched slot's forwards, one a step (None
+            # for a model that keeps none: ``watch``)
+            return out.T, tokens, positions, cache, rng, witness
 
         paged = self.paged
+        pool_names = self._pool_names
         kv_quant = self.kv_dtype in ("int8", "int4")
         kv_packed4 = self.kv_dtype == "int4"
 
@@ -585,19 +689,12 @@ class InferenceEngine:
 
                 rows = jnp.take(cache["table"], slots, axis=0)  # [G, MB]
                 zero = jnp.zeros(slots.shape, jnp.int32)
-                new_cache = dict(
-                    cache,
-                    k_pool=[
-                        scatter_tokens(p, rows, k.astype(p.dtype),
+                new_cache = dict(cache, **{
+                    name: [
+                        scatter_tokens(p, rows, x.astype(p.dtype),
                                        zero, skip)
-                        for p, k in zip(cache["k_pool"], ks)
-                    ],
-                    v_pool=[
-                        scatter_tokens(p, rows, v.astype(p.dtype),
-                                       zero, skip)
-                        for p, v in zip(cache["v_pool"], vs)
-                    ],
-                )
+                        for p, x in zip(cache[name], new)
+                    ] for name, new in zip(pool_names, (ks, vs))})
             else:
                 new_cache = {
                     "k": [
@@ -633,10 +730,12 @@ class InferenceEngine:
                     logits, cache = verify_step(
                         params, cfg, cache, tokens, start,
                         slots=slots, logits_index=last_idx)
+                cache = dict(cache)
+                witness = cache.pop("witness", None)
                 rng, sub = jax.random.split(rng)
                 first = select_token(
                     logits[:, 0, :], sub, temperature, top_k, top_p)
-                return cache, first, rng
+                return cache, first, rng, witness
 
             self._prefill_chunk_fn = prefill_chunk_fn
 
@@ -651,6 +750,8 @@ class InferenceEngine:
                 with jax.named_scope("verify"):
                     logits, cache = verify_step(
                         params, cfg, cache, tokens, positions)
+                cache = dict(cache)
+                cache.pop("witness", None)
                 rng, sub = jax.random.split(rng)
                 out, n_commit = rejection_commit(
                     logits, tokens[:, 1:], draft_len, sub,
@@ -681,7 +782,7 @@ class InferenceEngine:
         def zeros(*shape):
             return jnp.zeros(shape, jnp.int32)
 
-        _, _, _, self._cache, _ = self._chunk_fn(
+        _, _, _, self._cache, _, _ = self._chunk_fn(
             self.params, self._cache, zeros(b), zeros(b),
             jnp.zeros(b, bool), rng)
         ran = 1
@@ -695,8 +796,8 @@ class InferenceEngine:
                    if not chunked or n <= self.prefill_chunk]
         for g in range(1, b + 1):
             slots = jnp.arange(g, dtype=jnp.int32)
-            if chunked:
-                self._cache, _, _ = self._prefill_chunk_fn(
+            if chunked and g <= self._prefill_group:
+                self._cache, _, _, _ = self._prefill_chunk_fn(
                     self.params, self._cache,
                     zeros(g, self.prefill_chunk), zeros(g), slots,
                     zeros(g), rng)
@@ -915,6 +1016,7 @@ class InferenceEngine:
             self._table_dirty = True
         self._queue.popleft()
         self._slot_req[s] = req
+        self._watch_if_wanted(s, req)
         self._prefilling[s] = True
         self._prefill_pos[s] = start
         self._tokens[s] = 0
@@ -931,7 +1033,7 @@ class InferenceEngine:
         si = jnp.asarray(src, jnp.int32)
         di = jnp.asarray(dst, jnp.int32)
         cache = dict(self._cache)
-        for key in ("k_pool", "v_pool", "k_scale", "v_scale"):
+        for key in self._pool_names + ("k_scale", "v_scale"):
             pools = cache.get(key)
             if pools is not None:
                 cache[key] = [p.at[di].set(p[si]) for p in pools]
@@ -947,7 +1049,20 @@ class InferenceEngine:
         bounds every decoding slot's inter-token gap stays ONE chunk
         dispatch (jit caches one program per live group size, bounded
         by max_slots).  When a cursor reaches its prompt end, sample
-        that row's first token and hand the slot to decode."""
+        that row's first token and hand the slot to decode.
+
+        A latent-attention model's step sends the same work as ONE
+        DISPATCH A PREFILLING SLOT (``_prefill_group`` 1), one after
+        another with one sync behind the last: its attention walks a
+        row's live key blocks row by row anyway, so a dispatch of g rows
+        would take g times one row's (compute-bound at 512 queries
+        against tens of thousands of keys), and one group size is one
+        program to compile where 1 .. max_slots are max_slots.  So what
+        bounds a decoding slot's gap there is not one dispatch but
+        ``prefilling slots x one chunk``: up to max_slots chunks a step
+        (5-13 of ~105 ms before each decode chunk in the benchmark's
+        cell, PERF.md section 5).  Nothing caps the prefilling slots a
+        step; a cap would trade throughput for that gap."""
         slots = [s for s in range(self.max_slots) if self._prefilling[s]]
         if not slots:
             return
@@ -972,17 +1087,29 @@ class InferenceEngine:
         if self.paged and self._table_dirty:
             self._push_table()
         t0 = time.perf_counter()
-        with span("dlrover.engine.prefill_chunk", n=g):
-            self._cache, firsts, self._rng = self._prefill_chunk_fn(
-                self.params, self._cache, jnp.asarray(chunk),
-                jnp.asarray(starts),
-                jnp.asarray(slots, jnp.int32),
-                jnp.asarray(last_idx),
-                self._rng,
-            )
+        with span("dlrover.engine.prefill_chunk", n=g,
+                  **self._book_selection(starts, ends)):
+            # one dispatch for all rows, or one a row where the model
+            # asks for that (``_prefill_group``); the cache threads
+            # through them and the host syncs once, behind the last
+            firsts = []
+            for i in range(0, g, self._prefill_group):
+                rows = slice(i, i + self._prefill_group)
+                self._cache, first, self._rng, seen = \
+                    self._prefill_chunk_fn(
+                        self.params, self._cache, jnp.asarray(chunk[rows]),
+                        jnp.asarray(starts[rows]),
+                        jnp.asarray(slots[rows], jnp.int32),
+                        jnp.asarray(last_idx[rows]),
+                        self._rng,
+                    )
+                if self._watch_slot in slots[rows]:
+                    self._witnessed("run", self._prefill_pos, seen)
+                firsts.append(first)
             # the sync belongs to the dispatch it waits for, as in
             # _admit and the decode chunk
-            firsts = np.asarray(firsts)
+            firsts = np.concatenate([np.asarray(f) for f in firsts])
+            self._book_moe_picks()
         dt = time.perf_counter() - t0
         self.stats.prefill_seconds += dt
         self.stats.prefill_chunk_seconds += dt
@@ -1035,6 +1162,8 @@ class InferenceEngine:
         self._slot_req[s] = None
         self._prefilling[s] = False
         self._prefill_pos[s] = 0
+        if s == self._watch_slot:
+            self._watch_slot = -1
         if self.paged and self._slot_blocks[s] is not None:
             # blocks return to the pool (shared prefix blocks just
             # decref; fully-released ones linger in the prefix LRU).
@@ -1122,10 +1251,12 @@ class InferenceEngine:
     @spanned("dlrover.engine.step")
     def step(self) -> List[Request]:
         """Admit waiting requests, advance at most ONE bounded prefill
-        chunk, run one decode chunk (or speculative verify), return
-        requests finished during this step.  The ordering IS the stall
-        bound: a max-length prompt costs every other slot one chunk
-        dispatch per decode round, never a whole prefill."""
+        chunk a prefilling slot, run one decode chunk (or speculative
+        verify), return requests finished during this step.  The
+        ordering IS the stall bound: a max-length prompt costs every
+        other slot one chunk per decode round, never a whole prefill
+        (one dispatch for all prefilling slots, or one a slot for a
+        latent-attention model: ``_advance_prefill``)."""
         before = len(self._finished)
         self._admit()
         if self.prefill_chunk:
@@ -1142,21 +1273,27 @@ class InferenceEngine:
             if self.paged and self._table_dirty:
                 self._push_table()
             live, streamed = self._book_kv_rows(active)
+            lengths = self._positions[active][:, None] + np.arange(
+                1, self.chunk + 1)[None, :]
+            rows = {"kv_rows_live": live, "kv_rows_streamed": streamed,
+                    **self._book_selection(lengths - 1, lengths)}
             t0 = time.perf_counter()
-            with span("dlrover.engine.decode_chunk",
-                      kv_rows_live=live, kv_rows_streamed=streamed):
-                out, tokens, positions, self._cache, self._rng = \
+            with span("dlrover.engine.decode_chunk", **rows):
+                out, tokens, positions, self._cache, self._rng, seen = \
                     self._chunk_fn(
                         self.params, self._cache,
                         jnp.asarray(self._tokens),
                         jnp.asarray(self._positions),
                         jnp.asarray(active), self._rng,
                     )
+                if self._watch_slot >= 0 and active[self._watch_slot]:
+                    self._witnessed("decode", self._positions, seen)
                 out = np.asarray(out)                       # [B, chunk]
                 # copies: jax->numpy views are read-only, but _admit
                 # mutates
                 self._tokens = np.array(tokens)
                 self._positions = np.array(positions)
+                self._book_moe_picks()
             self.stats.decode_seconds += time.perf_counter() - t0
             self.stats.decode_forwards += self.chunk
             self._deliver_chunk(out)
@@ -1174,7 +1311,7 @@ class InferenceEngine:
         nothing), and return the two sums.  Host integer arithmetic
         on a [slots, forwards] array; (0, 0) where the gather path
         decodes."""
-        if self.attention_impl != "pallas":
+        if self.attention_impl != "pallas" or self._latent:
             return 0, 0
         from dlrover_tpu.ops.pallas.paged_attention import streamed_rows
 
@@ -1187,6 +1324,89 @@ class InferenceEngine:
         self.stats.kv_rows_live += live
         self.stats.kv_rows_streamed += streamed
         return live, streamed
+
+    def _book_selection(self, starts, ends) -> Dict[str, int]:
+        """Book what a learned selection of keys reads for runs of
+        queries at positions ``starts[i] .. ends[i] - 1`` (a decode
+        forward is a run of one): rows live (a query at position p sees
+        p + 1), rows attended (``min(p + 1, index_topk)``) and index keys
+        scored (the decode kernel's page groups up to each length; a
+        run's key blocks up to its last query, once a query).  One
+        layer's rows.  Returns the three for the dispatch's span; {} for
+        a model without a selection, whose spans carry what they did."""
+        cfg = self.cfg
+        if not (self._latent and cfg.index_topk):
+            return {}
+        from dlrover_tpu.ops.pallas.paged_index import scanned_rows
+        from dlrover_tpu.serving.latent import KEY_BLOCK_PAGES
+
+        starts, ends = np.asarray(starts), np.asarray(ends)
+        n = (ends - starts).astype(np.int64)
+        live = n * (starts + ends + 1) // 2          # sum of p + 1
+        topk = cfg.index_topk
+        full = np.clip(ends - np.maximum(starts, topk - 1), 0, None)
+        low = n - full                                # queries below topk
+        selected = full * topk + low * (2 * starts + low + 1) // 2
+        if starts.ndim == 2:                          # decode forwards
+            scanned = scanned_rows(ends, self.block_size, self._max_blocks)
+        else:
+            kb = KEY_BLOCK_PAGES * self.block_size
+            scanned = int((n * (-(-ends // kb) * kb)).sum())
+        book = {"kv_rows_live": int(live.sum()),
+                "index_rows_scanned": int(scanned),
+                "attn_rows_selected": int(selected.sum())}
+        self.stats.dsa_rows_live += book["kv_rows_live"]
+        self.stats.index_rows_scanned += book["index_rows_scanned"]
+        self.stats.attn_rows_selected += book["attn_rows_selected"]
+        return book
+
+    def watch(self, wanted) -> None:
+        """Keep what the engine's own programs do for ONE request at a
+        time: the first admitted to chunked prefill for which
+        ``wanted(request)`` holds is watched until it ends, then the
+        next.  Every prefill-chunk and decode-chunk dispatch that
+        advances it appends to ``witness_log`` a dict of ``request``,
+        ``kind`` ("run": a prompt chunk, "decode": a chunk of forwards,
+        one query each), ``start`` (the position of its first query) and
+        ``seen``, that program's own ``witness`` output for the slot
+        (``serving/latent.py verify_step``; device arrays, a decode
+        chunk's with a leading axis of forwards): the rows each query
+        attended to and the first sparse MLP's input and output, which a
+        selection of keys and a share of the experts otherwise leave to
+        show only in the logits.  ``None`` stops.  A latent-attention
+        model's engine only: no other program keeps a witness."""
+        if not self._latent:
+            raise ValueError("only a latent-attention model's programs "
+                             "keep a witness (serving/latent.py)")
+        self._watch = wanted
+
+    def _watch_if_wanted(self, s: int, req: Request) -> None:
+        if self._watch is None or self._watch_slot >= 0 \
+                or not self._watch(req):
+            return
+        self._watch_slot = s
+        self._cache = dict(self._cache,
+                           watch_slot=jnp.asarray(s, jnp.int32))
+
+    def _witnessed(self, kind: str, positions, seen) -> None:
+        s = self._watch_slot
+        self.witness_log.append({
+            "request": self._slot_req[s], "kind": kind,
+            "start": int(positions[s]), "seen": seen})
+
+    def _book_moe_picks(self) -> None:
+        """Add to ``stats.moe_picks`` / ``moe_picks_held`` what the
+        programs dispatched since the last call counted on the device
+        (two wrapping uint32 carried in the cache; read behind a sync
+        the caller has already paid)."""
+        counted = self._cache.get("moe_picks")
+        if counted is None:
+            return
+        now = np.asarray(counted, np.uint32)
+        delta = (now - self._moe_picks_seen).astype(np.uint32)
+        self._moe_picks_seen = now
+        self.stats.moe_picks += int(delta[0])
+        self.stats.moe_picks_held += int(delta[1])
 
     @spanned("dlrover.engine.deliver")
     def _deliver_chunk(self, out: np.ndarray) -> None:
@@ -1350,7 +1570,7 @@ class InferenceEngine:
         positions = jnp.asarray(self._positions)
         active_j = jnp.asarray(active)
         for _ in range(n_chunks):
-            out, tokens, positions, self._cache, self._rng = \
+            out, tokens, positions, self._cache, self._rng, _ = \
                 self._chunk_fn(
                     self.params, self._cache, tokens, positions,
                     active_j, self._rng,

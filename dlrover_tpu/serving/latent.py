@@ -1,0 +1,494 @@
+"""The served forward of a latent-attention model with a learned selection
+of keys and sparse experts (``LlamaConfig.kv_lora_rank``; the preset is
+``LlamaConfig.glm5``): the blocks ``serving/model.py``'s ``verify_step``
+and ``prefill`` run in place of the grouped-query ones.
+
+One layer, on its normed input ``h`` (positions ``t``, ``s``):
+
+- *latent attention*: ``c_q = RMSNorm(W_qa h)``, ``q = W_qb c_q`` in heads
+  of ``[nope | rope]``; ``[c | k_r] = W_kva h``, ``c_kv = RMSNorm(c)``,
+  ``k_r`` rotated (one row for all heads, adjacent pairs), ``q_rope``
+  rotated alike.  The cache keeps ``[c_kv | k_r]`` a token a layer and
+  nothing a head.  Attention runs ABSORBED: ``q~_h = W_kvb,h[K]^T q_nope_h``
+  scores the latent row itself, ``score_h[t, s] = (q~_h[t] . c_kv[s] +
+  q_rope_h[t] . k_r[s]) / sqrt(nope + rope)``, and the value projection
+  ``W_kvb,h[V]`` is applied to the attended latent, once a query.
+- *the indexer*: ``q_i = W_iq c_q`` in ``index_n_heads`` heads, ``k_i =
+  LayerNorm(W_ik h)`` one row a token (cached beside the latent row), the
+  first ``rope`` dimensions of both rotated, ``w = W_iw h / sqrt(heads x
+  size)`` in float32; ``I[t, s] = sum_h w[t, h] relu(q_i[t, h] . k_i[s])``
+  for ``s <= t``, and query ``t`` attends to the ``index_topk`` largest
+  only (to every key behind it while they are no more than that).
+- *the MLP*: a dense SwiGLU in the leading layers, then the router of
+  ``models/moe.py`` (:func:`~dlrover_tpu.models.moe.route`) over ALL
+  experts, the grouped matmuls over the experts this device holds
+  (``moe_experts_held``), a pick on an absent expert adding nothing, and
+  the shared expert on every token.
+
+Two attention paths, both reading the pools through the block table and
+neither making a dense copy of a slot's table:
+
+- decode (one query a slot, every slot): the index scores of a slot's
+  LIVE pages from the ``paged_index_scores`` kernel, ``jax.lax.top_k``,
+  a gather of the chosen latent rows, two einsums.
+- a run of queries (a prefill chunk, a speculative verify, a bucketed
+  prefill): row by row of the group (``lax.map``), over the row's live
+  key blocks only (a loop whose trip count is read from the positions):
+  the index scores of every block, the ``index_topk``-th largest of each
+  query's scores (:func:`_kth_largest`), then causal attention over the
+  blocks again under the mask ``I >= that``, with a running softmax.  A
+  prefill therefore computes scores against every live row, chosen or
+  not (a selection kernel for prefill is ROADMAP Reach A12).
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, Optional
+
+import jax
+import jax.numpy as jnp
+
+from dlrover_tpu.models.llama import LlamaConfig
+from dlrover_tpu.models.moe import grouped_matmul, route
+from dlrover_tpu.serving.model import _lm_head, _mm, _rmsnorm
+from dlrover_tpu.serving.paged import scatter_tokens
+
+#: pages of the pools one key block of the query-run path holds
+KEY_BLOCK_PAGES = 8
+_NEG_INF = -jnp.inf
+_LANES = 128
+
+
+def latent_row_width(cfg: LlamaConfig) -> int:
+    """Values of one row of the latent pool: ``[c_kv | k_r]`` and zeros up
+    to whole 128-lane tiles (GLM-5: 576 -> 640).  With a minor dimension
+    that is no multiple of 128 the TPU keeps a ``[blocks, 128, 576]``
+    array with the BLOCK's rows minor, and every program that gathers
+    rows copies the pool into row-major order and back (compiled for a
+    described v5e, PR 34: two copies of every layer's pool a dispatch).
+    The absorbed query carries zeros there too, so no score moves."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // _LANES) * _LANES
+
+
+def _layernorm(x, scale, bias, eps=1e-6):
+    xf = x.astype(jnp.float32)
+    mean = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
+    return ((xf - mean) * jax.lax.rsqrt(var + eps)
+            * scale.astype(jnp.float32) + bias.astype(jnp.float32))
+
+
+def rope_pairs(x: jax.Array, positions: jax.Array, theta: float,
+               rotary: int) -> jax.Array:
+    """The first ``rotary`` dimensions of ``x`` rotated in ADJACENT pairs
+    ``(x_2i, x_2i+1)`` by ``positions * theta^(-2i / rotary)``; the rest
+    pass.  ``positions`` has ``x``'s leading dimensions (fewer broadcast
+    over the rest, heads).  Float32 out."""
+    inv = 1.0 / (theta ** (
+        jnp.arange(0, rotary, 2, dtype=jnp.float32) / rotary))
+    ang = positions.astype(jnp.float32)[..., None] * inv
+    ang = ang.reshape(positions.shape
+                      + (1,) * (x.ndim - 1 - positions.ndim)
+                      + (rotary // 2,))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    pairs = xf[..., :rotary].reshape(*x.shape[:-1], rotary // 2, 2)
+    x0, x1 = pairs[..., 0], pairs[..., 1]
+    rot = jnp.stack([x0 * cos - x1 * sin, x1 * cos + x0 * sin],
+                    axis=-1).reshape(*x.shape[:-1], rotary)
+    return jnp.concatenate([rot, xf[..., rotary:]], axis=-1)
+
+
+def _projections(lp, h, cfg: LlamaConfig, pos, dtype):
+    """``h`` [B, K, E], ``pos`` [B, K] -> the absorbed queries ``qq``
+    [B, K, H, W], the cache rows ``row`` [B, K, W] (``W`` =
+    :func:`latent_row_width`: C + R and zeros), and the
+    indexer's ``q_i`` [B, K, Hi, Di], ``k_i`` [B, K, Di], ``w`` [B, K, Hi]
+    (float32)."""
+    b, k = h.shape[:2]
+    c, r = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    nope, heads = cfg.qk_nope_head_dim, cfg.num_heads
+    with jax.named_scope("mla_proj"):
+        c_q = _rmsnorm(_mm(h, lp["wq_a"], dtype), lp["q_a_norm"],
+                       cfg.rms_norm_eps).astype(dtype)
+        q = _mm(c_q, lp["wq_b"], dtype).reshape(b, k, heads, nope + r)
+        q_rope = rope_pairs(q[..., nope:], pos, cfg.rope_theta, r)
+        ckv = _mm(h, lp["wkv_a"], dtype)
+        c_kv = _rmsnorm(ckv[..., :c], lp["kv_a_norm"], cfg.rms_norm_eps)
+        k_r = rope_pairs(ckv[..., c:], pos, cfg.rope_theta, r)
+        pad = latent_row_width(cfg) - c - r
+        row = jnp.concatenate(
+            [c_kv, k_r, jnp.zeros((b, k, pad), jnp.float32)],
+            axis=-1).astype(dtype)
+        q_abs = jnp.einsum("bkhn,hnc->bkhc", q[..., :nope],
+                           lp["wkv_b_k"].astype(dtype),
+                           preferred_element_type=jnp.float32)
+        qq = jnp.concatenate(
+            [q_abs, q_rope, jnp.zeros((b, k, heads, pad), jnp.float32)],
+            axis=-1).astype(dtype)
+    q_i = k_i = w = None
+    if cfg.index_topk:
+        hi, di = cfg.index_n_heads, cfg.index_head_dim
+        with jax.named_scope("dsa_index"):
+            q_i = rope_pairs(
+                _mm(c_q, lp["iwq"], dtype).reshape(b, k, hi, di),
+                pos, cfg.rope_theta, r).astype(dtype)
+            k_i = rope_pairs(
+                _layernorm(_mm(h, lp["iwk"], dtype), lp["ik_norm_scale"],
+                           lp["ik_norm_bias"]),
+                pos, cfg.rope_theta, r).astype(dtype)
+            w = jnp.dot(h.astype(dtype), lp["iw"].astype(dtype),
+                        preferred_element_type=jnp.float32
+                        ) * float((hi * di) ** -0.5)
+    return qq, row, q_i, k_i, w
+
+
+def _softmax_scale(cfg: LlamaConfig) -> float:
+    return float(cfg.head_dim_ ** -0.5)
+
+
+def _orderable(x: jax.Array) -> jax.Array:
+    """float32 -> uint32 whose unsigned order is the floats' order."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    keys = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    return jax.lax.bitcast_convert_type(keys, jnp.uint32) \
+        ^ jnp.uint32(0x80000000)
+
+
+def _kth_largest(keys: jax.Array, k: int) -> jax.Array:
+    """Of each row of ``keys`` [R, W] (:func:`_orderable`), the largest
+    value ``t`` with at least ``k`` entries ``>= t``: the ``k``-th largest
+    entry, exactly, by 32 counting passes, bit by bit from the top (a
+    row of fewer than ``k`` entries gives 0: everything passes).  No sort:
+    a prefill chunk asks this of 512 rows of a whole context at once."""
+    def bit(i, t):
+        cand = t | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        enough = jnp.sum(keys >= cand[:, None], axis=-1) >= k
+        return jnp.where(enough, cand, t)
+
+    return jax.lax.fori_loop(
+        0, 32, bit, jnp.zeros(keys.shape[:1], jnp.uint32))
+
+
+def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
+                cfg: LlamaConfig, pages: int):
+    """A run of queries of ONE sequence against its cached rows, this
+    run's own among them: ``qq`` [K, H, C + R], ``q_i`` [K, Hi, Di], ``w``
+    [K, Hi], ``q_pos`` [K] ascending, ``table_row`` [MB] (a multiple of
+    ``pages``).  Returns the attended latent [K, H, C] float32 and the
+    selection [K, MB x bs] bool.  Work follows ``q_pos[-1]``, not MB."""
+    klen, heads, _ = qq.shape
+    c = cfg.kv_lora_rank
+    bs = latent_pool.shape[1]
+    kb = pages * bs                               # keys a block
+    n_blocks = table_row.shape[0] // pages
+    width = n_blocks * kb
+    n_live = jnp.minimum((q_pos[-1] + kb) // kb, n_blocks)
+    scale = _softmax_scale(cfg)
+
+    def block(pool, j):
+        ids = jax.lax.dynamic_slice_in_dim(table_row, j * pages, pages)
+        return jnp.take(pool, ids, axis=0).reshape(kb, pool.shape[-1])
+
+    def causal(j):
+        key_pos = j * kb + jnp.arange(kb)
+        return key_pos[None, :] <= q_pos[:, None]            # [K, kb]
+
+    if cfg.index_topk and width > cfg.index_topk:
+        from dlrover_tpu.ops.pallas.paged_index import index_scores
+
+        with jax.named_scope("dsa_index"):
+            def score_block(j, keys):
+                s = index_scores(q_i, w, block(index_pool, j))
+                s = _orderable(jnp.where(causal(j), s, _NEG_INF))
+                return jax.lax.dynamic_update_slice_in_dim(
+                    keys, s, j * kb, axis=1)
+
+            dead = _orderable(jnp.full((), _NEG_INF, jnp.float32))
+            keys = jax.lax.fori_loop(
+                0, n_live, score_block,
+                jnp.full((klen, width), dead, jnp.uint32))
+        with jax.named_scope("dsa_select"):
+            chosen = (keys >= _kth_largest(keys, cfg.index_topk)[:, None]
+                      ) & (keys > dead)
+    else:
+        # no more keys than a query may choose: plain causal attention
+        chosen = jnp.arange(width)[None, :] <= q_pos[:, None]
+
+    with jax.named_scope("mla_attn"):
+        def attend_block(j, carry):
+            m, l, acc = carry
+            lat = block(latent_pool, j)                       # [kb, C+R]
+            s = jnp.einsum("khc,sc->khs", qq, lat.astype(qq.dtype),
+                           preferred_element_type=jnp.float32) * scale
+            keep = jax.lax.dynamic_slice_in_dim(chosen, j * kb, kb, axis=1)
+            s = jnp.where(keep[:, None, :], s, _NEG_INF)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+            alpha = jnp.exp(jnp.where(jnp.isfinite(m), m, safe) - safe)
+            p = jnp.exp(s - safe[..., None])
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "khs,sc->khc", p.astype(qq.dtype),
+                lat[:, :c].astype(qq.dtype),
+                preferred_element_type=jnp.float32)
+            return m_new, alpha * l + p.sum(axis=-1), acc
+
+        m, l, acc = jax.lax.fori_loop(
+            0, n_live, attend_block,
+            (jnp.full((klen, heads), _NEG_INF, jnp.float32),
+             jnp.zeros((klen, heads), jnp.float32),
+             jnp.zeros((klen, heads, c), jnp.float32)))
+        return acc / jnp.maximum(l, 1e-30)[..., None], chosen
+
+
+def _attend_decode(qq, q_i, w, latent_pool, index_pool, table, lengths,
+                   cfg: LlamaConfig, impl: str, interpret: bool):
+    """One query a slot: ``qq`` [B, H, C + R], ``q_i`` [B, Hi, Di], ``w``
+    [B, Hi], ``lengths`` [B] the keys each slot sees (0: its output is
+    not wanted).  Returns the attended latent [B, H, C] float32 and the
+    positions attended to, [B, S] int32 (-1: none)."""
+    from dlrover_tpu.ops.pallas import paged_index
+
+    b, mb = table.shape
+    bs, c = latent_pool.shape[1], cfg.kv_lora_rank
+    if cfg.index_topk and mb * bs > cfg.index_topk:
+        with jax.named_scope("dsa_index"):
+            if impl == "pallas":
+                scores = paged_index.paged_index_scores(
+                    q_i, w, index_pool, table, lengths, interpret=interpret)
+            else:
+                scores = paged_index.gather_index_scores(
+                    q_i, w, index_pool, table, lengths)
+        with jax.named_scope("dsa_select"):
+            top, pos = jax.lax.top_k(scores, cfg.index_topk)  # [B, topk]
+            valid = top > _NEG_INF
+    else:
+        pos = jnp.broadcast_to(jnp.arange(mb * bs), (b, mb * bs))
+        valid = pos < lengths[:, None]
+    with jax.named_scope("dsa_select"):
+        page = jnp.take_along_axis(
+            table, jnp.minimum(pos // bs, mb - 1), axis=1)
+        flat = jnp.where(valid, page * bs + pos % bs, 0)
+        rows = jnp.take(latent_pool.reshape(-1, latent_pool.shape[-1]),
+                        flat, axis=0)                         # [B, S, C+R]
+    with jax.named_scope("mla_attn"):
+        s = jnp.einsum("bhc,bsc->bhs", qq, rows.astype(qq.dtype),
+                       preferred_element_type=jnp.float32
+                       ) * _softmax_scale(cfg)
+        s = jnp.where(valid[:, None, :], s, _NEG_INF)
+        m = s.max(axis=-1, keepdims=True)
+        p = jnp.exp(s - jnp.where(jnp.isfinite(m), m, 0.0))
+        p = p / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
+        o = jnp.einsum("bhs,bsc->bhc", p.astype(qq.dtype),
+                       rows[..., :c].astype(qq.dtype),
+                       preferred_element_type=jnp.float32)
+    return o, jnp.where(valid, pos, -1).astype(jnp.int32)
+
+
+def _attn_out(lp, o_lat, cfg: LlamaConfig, dtype):
+    """Attended latents [B, K, H, C] -> the block's output [B, K, E]."""
+    with jax.named_scope("mla_attn"):
+        o = jnp.einsum("bkhc,hcv->bkhv", o_lat.astype(dtype),
+                       lp["wkv_b_v"].astype(dtype),
+                       preferred_element_type=jnp.float32).astype(dtype)
+        return _mm(o.reshape(*o.shape[:2], -1), lp["wo"], dtype)
+
+
+def _swiglu(h, wgu, down, dtype):
+    gu = _mm(h, wgu, dtype)
+    f = gu.shape[-1] // 2
+    return _mm(jax.nn.silu(gu[..., :f]) * gu[..., f:], down, dtype)
+
+
+def sparse_mlp(lp, h, cfg: LlamaConfig, dtype, counted):
+    """The sparse MLP of one layer on ``h`` [B, K, E]: ``(y, [picks,
+    picks on held experts])``, the two counted over the rows ``counted``
+    [B, K] marks (a parked slot's junk row routes too).  The picks are
+    sorted by expert with this device's ahead of all others, as
+    ``models/moe.py MoEMLP`` sorts them; the grouped matmuls get the held
+    groups' sizes and multiply nothing behind them."""
+    b, klen, e = h.shape
+    t, k = b * klen, cfg.moe_top_k
+    first, held = cfg.moe_experts_held or (0, cfg.num_experts)
+    x = h.reshape(t, e)
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(x.astype(jnp.float32), lp["router"],
+                         precision=jax.lax.Precision.HIGHEST)
+        top_p, top_e, _ = route(
+            logits, k, cfg.moe_score_fn, cfg.moe_norm_topk_prob,
+            cfg.moe_routed_scale, lp.get("select_bias"))
+        local = top_e.reshape(t * k) - first
+        is_held = jnp.logical_and(local >= 0, local < held)
+        group = jnp.where(is_held, local, held)
+        sizes = jnp.sum(jax.nn.one_hot(group, held, dtype=jnp.int32), axis=0)
+        rows = jnp.repeat(counted.reshape(t), k)
+        picks = jnp.stack([jnp.sum(rows), jnp.sum(rows & is_held)]
+                          ).astype(jnp.uint32)
+    with jax.named_scope("moe_experts"):
+        order = jnp.argsort(group, stable=True)
+        xs = x.astype(dtype)[order // k]
+        gate = grouped_matmul(xs, lp["w_gate"].astype(dtype), sizes)
+        up = grouped_matmul(xs, lp["w_up"].astype(dtype), sizes)
+        out = grouped_matmul(jax.nn.silu(gate) * up,
+                             lp["w_down"].astype(dtype), sizes)
+        # rows behind the held groups are no expert's: never written
+        live = (jnp.arange(t * k) < jnp.sum(sizes))[:, None]
+        out = jnp.where(live, out, jnp.zeros((), out.dtype))
+        out = out[jnp.argsort(order)].reshape(t, k, e)
+        y = jnp.sum(out.astype(jnp.float32) * top_p[..., None], axis=1)
+    if "shared_wgu" in lp:
+        with jax.named_scope("moe_shared"):
+            y = y + _swiglu(x, lp["shared_wgu"], lp["shared_down"],
+                            dtype).astype(jnp.float32)
+    return y.astype(dtype).reshape(b, klen, e), picks
+
+
+def _mlp(lp, h, cfg: LlamaConfig, dtype, counted):
+    if "router" in lp:
+        return sparse_mlp(lp, h, cfg, dtype, counted)
+    return _swiglu(h, lp["wgu"], lp["down"], dtype), None
+
+
+def _pad_table(table: jax.Array, pages: int) -> jax.Array:
+    """The table padded with the trash block to whole key blocks."""
+    pad = -table.shape[1] % pages
+    if not pad:
+        return table
+    return jnp.concatenate(
+        [table, jnp.zeros((table.shape[0], pad), table.dtype)], axis=1)
+
+
+def verify_step(
+    params: Dict[str, Any],
+    cfg: LlamaConfig,
+    cache: Dict[str, Any],   # {"latent_pool", "index_pool": per-layer
+    tokens: jax.Array,       #   lists; "table"; "moe_picks"}
+    positions: jax.Array,
+    slots: Optional[jax.Array] = None,
+    logits_index: Optional[jax.Array] = None,
+    attention_impl: str = "xla",
+    kernel_interpret: bool = False,
+    active: Optional[jax.Array] = None,
+):
+    """``serving/model.py verify_step`` for a latent model, argument for
+    argument; one query a slot over every slot (decode) takes the decode
+    path, every other shape the query-run path (module docstring).
+
+    A cache that carries ``watch_slot`` (an int32 scalar: the engine's
+    ``watch``) comes back with ``witness``, what THIS forward did for that
+    slot's row: the keys each query attended to, a layer (decode: ``rows``
+    [layers, S] int32 positions, -1 none; a run: ``chosen_bits`` [layers,
+    K, table rows / 8] uint8, ``jnp.packbits`` of the mask), and the first
+    sparse MLP's normed input and output (``sparse_in``, ``sparse_out``
+    [K, E]).  A slot that is not among the rows leaves junk there."""
+    dtype = cfg.dtype
+    b, klen = tokens.shape
+    x = jnp.take(params["embed"], tokens, axis=0)            # [B, K, E]
+    pos_k = positions[:, None] + jnp.arange(klen)[None, :]   # [B, K]
+    table = cache["table"]
+    if slots is not None:
+        table = jnp.take(table, slots, axis=0)
+    decode = klen == 1 and slots is None and logits_index is None
+    if decode:
+        lengths = positions.astype(jnp.int32) + 1
+        counted = jnp.ones((b, 1), bool)
+        if active is not None:
+            lengths = jnp.where(active, lengths, 0)
+            counted = active[:, None]
+    else:
+        run_table = _pad_table(table, KEY_BLOCK_PAGES)
+        counted = jnp.ones((b, klen), bool) if logits_index is None else (
+            jnp.arange(klen)[None, :] <= logits_index[:, None])
+    picks = cache.get("moe_picks")
+    watch = cache.get("watch_slot")
+    if watch is not None:
+        watch = jnp.clip(watch, 0, b - 1) if slots is None \
+            else jnp.argmax(slots == watch)
+    latent_pools, index_pools, selections, seen = [], [], [], {}
+    for i, lp in enumerate(params["layers"]):
+        h = _rmsnorm(x, lp["input_norm"], cfg.rms_norm_eps).astype(dtype)
+        qq, row, q_i, k_i, w = _projections(lp, h, cfg, pos_k, dtype)
+        lat = cache["latent_pool"][i]
+        lat = scatter_tokens(lat, table, row.astype(lat.dtype), positions)
+        idx = cache["index_pool"][i]
+        if cfg.index_topk:
+            idx = scatter_tokens(idx, table, k_i.astype(idx.dtype),
+                                 positions)
+        if decode:
+            o_lat, chosen = _attend_decode(
+                qq[:, 0], None if q_i is None else q_i[:, 0],
+                None if w is None else w[:, 0], lat, idx, table, lengths,
+                cfg, attention_impl, kernel_interpret)
+            o_lat = o_lat[:, None]
+        else:
+            o_lat, chosen = jax.lax.map(
+                lambda a: _attend_run(a[0], a[1], a[2], a[3], lat, idx,
+                                      a[4], cfg, KEY_BLOCK_PAGES),
+                (qq, q_i, w, pos_k, run_table))
+        if watch is not None:
+            selections.append(jnp.take(chosen, watch, axis=0))
+        x = x + _attn_out(lp, o_lat, cfg, dtype)
+        h = _rmsnorm(x, lp["post_norm"], cfg.rms_norm_eps).astype(dtype)
+        y, n = _mlp(lp, h, cfg, dtype, counted)
+        if n is not None and picks is not None:
+            picks = picks + n
+        if n is not None and watch is not None and not seen:
+            seen.update(sparse_in=jnp.take(h, watch, axis=0),
+                        sparse_out=jnp.take(y, watch, axis=0))
+        x = x + y
+        latent_pools.append(lat)
+        index_pools.append(idx)
+
+    x = _rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
+    if logits_index is not None:
+        x = jnp.take_along_axis(
+            x, logits_index.astype(jnp.int32)[:, None, None], axis=1)
+    logits = _lm_head(params, x.astype(dtype), cfg)
+    out_cache = dict(cache, latent_pool=latent_pools,
+                     index_pool=index_pools)
+    if picks is not None:
+        out_cache["moe_picks"] = picks
+    if watch is not None:
+        chosen = jnp.stack(selections)
+        out_cache["witness"] = dict(seen, **(
+            {"rows": chosen} if decode
+            else {"chosen_bits": jnp.packbits(chosen, axis=-1)}))
+    return logits, out_cache
+
+
+def prefill(params: Dict[str, Any], cfg: LlamaConfig, tokens: jax.Array,
+            real_len: jax.Array):
+    """``serving/model.py prefill`` for a latent model: a causal pass over
+    a group of right-padded prompts with no cache behind them; returns
+    (last logits [G, V], per-layer cache rows [G, Lp, C + R], per-layer
+    index keys [G, Lp, Di]) for the engine to scatter.  A prompt is one
+    key block here, so scores are [Lp, heads, Lp]: buckets the size of a
+    prefill chunk, which is all an engine with ``prefill_chunk`` sends.
+    The experts' picks of this path are not counted."""
+    dtype = cfg.dtype
+    g, lp_len = tokens.shape
+    x = jnp.take(params["embed"], tokens, axis=0)
+    pos = jnp.broadcast_to(jnp.arange(lp_len), (g, lp_len))
+    one_page = jnp.zeros((g, 1), jnp.int32)
+    counted = jnp.ones((g, lp_len), bool)
+    rows, keys = [], []
+    for lp in params["layers"]:
+        h = _rmsnorm(x, lp["input_norm"], cfg.rms_norm_eps).astype(dtype)
+        qq, row, q_i, k_i, w = _projections(lp, h, cfg, pos, dtype)
+        if k_i is None:
+            k_i = jnp.zeros((g, lp_len, 0), dtype)
+        # each prompt's own rows as a pool of one page
+        o_lat, _ = jax.lax.map(
+            lambda a: _attend_run(a[0], a[1], a[2], a[3], a[4][None],
+                                  a[5][None], a[6], cfg, 1),
+            (qq, q_i, w, pos, row, k_i, one_page))
+        x = x + _attn_out(lp, o_lat, cfg, dtype)
+        h = _rmsnorm(x, lp["post_norm"], cfg.rms_norm_eps).astype(dtype)
+        x = x + _mlp(lp, h, cfg, dtype, counted)[0]
+        rows.append(row)
+        keys.append(k_i)
+    x = _rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
+    last = jnp.take_along_axis(
+        x, (jnp.atleast_1d(real_len).astype(jnp.int32) - 1)[:, None, None],
+        axis=1)
+    return _lm_head(params, last.astype(dtype), cfg)[:, 0, :], rows, keys

@@ -243,7 +243,17 @@ def verify_step(
     path; ``kernel_interpret`` runs the kernel in Pallas interpret
     mode (the CPU parity harness).  ``active`` is :func:`decode_step`'s
     mask and reaches nothing but that kernel.
+
+    A latent-attention model (``cfg.kv_lora_rank``) has its own blocks
+    and cache rows: ``serving/latent.py verify_step``, same arguments.
     """
+    if cfg.kv_lora_rank:
+        from dlrover_tpu.serving import latent
+
+        return latent.verify_step(
+            params, cfg, cache, tokens, positions, slots=slots,
+            logits_index=logits_index, attention_impl=attention_impl,
+            kernel_interpret=kernel_interpret, active=active)
     dtype = cfg.dtype
     d = cfg.head_dim_
     n_rep = cfg.num_heads // cfg.num_kv_heads
@@ -439,7 +449,12 @@ def prefill(
     group of G prompts costs one dispatch instead of G — the admission
     path batches same-bucket arrivals through here.  Pad garbage beyond
     ``real_len`` is harmless: decode overwrites/masks it (module
-    docstring)."""
+    docstring).  Of a latent-attention model (``cfg.kv_lora_rank``) the
+    two lists are its cache rows and index keys (``serving/latent.py``)."""
+    if cfg.kv_lora_rank:
+        from dlrover_tpu.serving import latent
+
+        return latent.prefill(params, cfg, tokens, real_len)
     dtype = cfg.dtype
     d = cfg.head_dim_
     lp_len = tokens.shape[1]

@@ -21,6 +21,11 @@ attention, prefix reuse), rebuilt TPU-style:
   first (the gather is XLA-fused with the attention reads); a fused
   Pallas paged-attention kernel is the optimization seam.
 
+A pool's rows need no head axis: a latent-attention model keeps a
+latent pool ``[num_blocks, block_size, W]`` and an index-key pool beside
+it under the same table (serving/latent.py); ``scatter_tokens`` and the
+manager know blocks and offsets only.
+
 Shared (refcount > 1) prefix blocks are READ-ONLY — the copy-on-write
 contract (serving/prefixcache):
 

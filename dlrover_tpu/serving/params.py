@@ -125,22 +125,33 @@ def serving_params_from_llama(
     the Pallas kernel layout at load time."""
     import flax.linen as nn
 
-    if cfg.num_experts or cfg.qk_norm:
+    if cfg.qk_norm:
         raise ValueError(
-            "the serving engine's model is the dense decoder without "
-            f"QK-norm: num_experts={cfg.num_experts}, qk_norm={cfg.qk_norm} "
-            "would be served as a different model (ROADMAP B1, serving "
-            "half: serving/model.py::_mlp and the attention block are "
-            "dense-only)")
+            "the serving engine's attention blocks have no QK-norm "
+            f"(qk_norm={cfg.qk_norm}): the model would be served as a "
+            "different one.  Missing: the norm over the projected query "
+            "and key in serving/model.py::_attn_proj (ROADMAP Reach A3)")
     if cfg.layers is not None or cfg.attn_head_gate:
         raise ValueError(
-            "the serving engine's model has ONE kind of layer: full causal "
-            "attention with one head count and plain RoPE over the whole "
-            f"head, no head gate (attn_head_gate={cfg.attn_head_gate}); "
-            f"this model describes {len(set(cfg.layer_specs))} kinds of "
-            "layer.  Missing: a window in the paged kernel and the cache "
-            "manager (ROADMAP A4), per-layer head counts, partial rotary "
-            "and YaRN in serving/model.py (A3)")
+            "the serving engine's model has ONE kind of layer: full "
+            "causal attention with one head count and plain RoPE, no head "
+            f"gate (attn_head_gate={cfg.attn_head_gate}); this model "
+            f"describes {len(set(cfg.layer_specs))} kinds of layer.  "
+            "Missing: a window in the paged kernels and the cache manager "
+            "(ROADMAP A4), per-layer head counts, partial rotary and YaRN "
+            "in serving/model.py (A3)")
+    if cfg.num_experts and not cfg.kv_lora_rank:
+        raise ValueError(
+            f"sparse experts (num_experts={cfg.num_experts}) are served "
+            "behind latent attention only (serving/latent.py sparse_mlp); "
+            "the grouped-query block's MLP in serving/model.py::_mlp is "
+            "dense (ROADMAP B1, serving half)")
+    if cfg.kv_lora_rank:
+        if int8 or not fuse:
+            raise ValueError(
+                "a latent-attention model is served in its own dtype on "
+                "one device: no int8 weights, no tensor-parallel mesh")
+        return _latent_params(variables, cfg, dtype or cfg.dtype)
     if dtype is None:
         dtype = cfg.dtype
     variables = nn.meta.unbox(variables)
@@ -185,6 +196,86 @@ def serving_params_from_llama(
             jnp.asarray(params["lm_head"]["kernel"], dtype), int8
         )
     return out
+
+
+def _latent_params(variables: Any, cfg: LlamaConfig, dtype
+                   ) -> Dict[str, Any]:
+    """The serving tree of a latent-attention model (serving/latent.py)
+    from a ``layer_{i}`` tree named as ``perfbench/reference_glm5.py`` and
+    the tests make it: ``attn`` (``q_a_proj``, ``q_a_norm``, ``q_b_proj``
+    [Q, H, nope + rope], ``kv_a_proj`` [E, C + rope], ``kv_a_norm``,
+    ``kv_b_proj`` [C, H, nope + V], ``o_proj`` [H, V, E]), ``indexer``
+    (``wq_b`` [Q, Hi, Di], ``wk``, ``k_norm`` scale and bias,
+    ``weights_proj``), and ``mlp`` as ``LlamaModel`` names a dense one or
+    ``MoEMLP`` a sparse one (``select_bias`` beside the router).  The
+    latent's up-projection is split into the key part, laid out for the
+    absorbed query [H, nope, C], and the value part [H, C, V]; the router
+    and its bias stay float32."""
+    import flax.linen as nn
+
+    variables = nn.meta.unbox(variables)
+    params = variables["params"] if "params" in variables else variables
+    nope = cfg.qk_nope_head_dim
+
+    def mat(w):
+        return jnp.asarray(w, dtype)
+
+    def flat_out(w):      # [in, heads, d] -> [in, heads * d]
+        return mat(w).reshape(w.shape[0], -1)
+
+    def layer(p):
+        a = p["attn"]
+        kv_b = mat(a["kv_b_proj"]["kernel"])             # [C, H, nope+V]
+        out = {
+            "input_norm": p["input_norm"]["scale"],
+            "post_norm": p["post_norm"]["scale"],
+            "wq_a": mat(a["q_a_proj"]["kernel"]),
+            "q_a_norm": a["q_a_norm"]["scale"],
+            "wq_b": flat_out(a["q_b_proj"]["kernel"]),
+            "wkv_a": mat(a["kv_a_proj"]["kernel"]),
+            "kv_a_norm": a["kv_a_norm"]["scale"],
+            "wkv_b_k": kv_b[..., :nope].transpose(1, 2, 0),
+            "wkv_b_v": kv_b[..., nope:].transpose(1, 0, 2),
+            "wo": mat(a["o_proj"]["kernel"]).reshape(
+                -1, cfg.hidden_size),
+        }
+        if cfg.index_topk:
+            ix = p["indexer"]
+            out.update(
+                iwq=flat_out(ix["wq_b"]["kernel"]),
+                iwk=mat(ix["wk"]["kernel"]),
+                ik_norm_scale=ix["k_norm"]["scale"],
+                ik_norm_bias=ix["k_norm"]["bias"],
+                iw=mat(ix["weights_proj"]["kernel"]))
+        m = p["mlp"]
+        if "router" in m:
+            out.update(
+                router=jnp.asarray(m["router"]["kernel"], jnp.float32),
+                w_gate=mat(m["w_gate"]), w_up=mat(m["w_up"]),
+                w_down=mat(m["w_down"]))
+            if "select_bias" in m:
+                out["select_bias"] = jnp.asarray(
+                    m["select_bias"], jnp.float32)
+            if "shared_gate" in m:
+                out["shared_wgu"] = jnp.concatenate(
+                    [mat(m["shared_gate"]["kernel"]),
+                     mat(m["shared_up"]["kernel"])], axis=-1)
+                out["shared_down"] = mat(m["shared_down"]["kernel"])
+        else:
+            out["wgu"] = jnp.concatenate(
+                [mat(m["gate_proj"]["kernel"]),
+                 mat(m["up_proj"]["kernel"])], axis=-1)
+            out["down"] = mat(m["down_proj"]["kernel"])
+        return out
+
+    return {
+        "embed": mat(params["embed_tokens"]["embedding"]),
+        "layers": [layer(params[f"layer_{i}"])
+                   for i in range(cfg.num_layers)],
+        "final_norm": params["final_norm"]["scale"],
+        "lm_head": None if cfg.tie_embeddings
+        else mat(params["lm_head"]["kernel"]),
+    }
 
 
 def serving_params_nbytes(sp: Dict[str, Any]) -> int:

@@ -134,6 +134,10 @@ class RouterMetrics:
         self.paged_kernel_step_seconds = 0.0
         self.kv_rows_live = 0.0
         self.kv_rows_streamed = 0.0
+        self.dsa_rows_live = 0.0
+        self.attn_rows_selected = 0.0
+        self.moe_picks = 0.0
+        self.moe_picks_held = 0.0
         # prefix-cache fleet aggregates (engine-side COW ledger summed
         # over reporting replicas, same sweep as the raw-speed keys)
         self.prefix_hits = 0.0
@@ -295,6 +299,9 @@ class RouterMetrics:
             d.get("kv_rows_live", 0.0) for d in dicts)
         self.kv_rows_streamed = sum(
             d.get("kv_rows_streamed", 0.0) for d in dicts)
+        for name in ("dsa_rows_live", "attn_rows_selected", "moe_picks",
+                     "moe_picks_held"):
+            setattr(self, name, sum(d.get(name, 0.0) for d in dicts))
         for attr, key in (
             ("prefix_hits", "prefix_hits"),
             ("prefix_misses", "prefix_misses"),
@@ -400,6 +407,12 @@ class RouterMetrics:
             "serving_paged_kv_stream_ratio": (
                 self.kv_rows_streamed / self.kv_rows_live
                 if self.kv_rows_live else 0.0),
+            "serving_dsa_selected_ratio": (
+                self.attn_rows_selected / self.dsa_rows_live
+                if self.dsa_rows_live else 0.0),
+            "serving_moe_held_share": (
+                self.moe_picks_held / self.moe_picks
+                if self.moe_picks else 0.0),
             "serving_sched_capacity_evals_total":
                 self.sched_capacity_evals,
             "serving_sched_rounds_skipped_total":

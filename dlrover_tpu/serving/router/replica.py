@@ -175,6 +175,12 @@ class InferenceEngineAdapter:
             # their slots could see (both 0 on the gather path)
             out["kv_rows_live"] = float(st.kv_rows_live)
             out["kv_rows_streamed"] = float(st.kv_rows_streamed)
+            # a learned selection of keys and a share of the experts:
+            # the sums, so that a fleet's ratio weighs by work (all 0
+            # for a model with neither)
+            for name in ("dsa_rows_live", "attn_rows_selected",
+                         "moe_picks", "moe_picks_held"):
+                out[name] = float(getattr(st, name, 0))
             # prefix-cache ledger (all-float, so the dict still rides
             # STATS frames as-is); dense engines have no sharing
             prefix = getattr(eng, "prefix_stats", None)

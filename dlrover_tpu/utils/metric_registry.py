@@ -188,6 +188,20 @@ METRIC_HELP: Dict[str, str] = {
         "length, so short contexts read near 1-2 and a fleet at full "
         "context 1.0; 0 = no replica decodes with the kernel"
     ),
+    "serving_dsa_selected_ratio": (
+        "key rows attended per key row live on replicas whose model "
+        "picks its keys with a learned indexer (LlamaConfig.index_topk), "
+        "over decode forwards and prefill chunks so far: 1.0 while "
+        "contexts fit the selection, index_topk / context beyond; 0 = "
+        "no replica serves such a model"
+    ),
+    "serving_moe_held_share": (
+        "router picks that fell on the experts a replica holds over all "
+        "its picks, fleet-wide (a replica that is one share of an "
+        "expert-parallel group, LlamaConfig.moe_experts_held): held / "
+        "num_experts when routing is even; 0 = no replica serves "
+        "sparse experts"
+    ),
     "serving_kv_int4_blocks": (
         "KV cache blocks held in packed-int4 pools across the fleet "
         "(a subset of serving_kv_quant_blocks) — int4's ~3.7x budget "

@@ -293,10 +293,31 @@ def test_zipf_batches_replay():
     assert 0.06 < (ids == top_a).mean() < 0.12
 
 
-def test_serving_refuses_the_sparse_model():
+@pytest.mark.parametrize("lacks, match", [
+    ("qk_norm", "QK-norm"),
+    ("window", "ONE kind of layer"),
+    ("head_gate", "no head gate"),
+    ("experts_behind_gqa", "B1"),
+])
+def test_serving_refuses_a_model_by_what_it_lacks(lacks, match):
+    """Sparse experts are served (behind latent attention:
+    tests/test_sparse_serving.py); what the serving blocks still lack is
+    refused by name: QK-norm, a window, a head gate, and experts behind
+    the grouped-query block."""
+    from dlrover_tpu.models.llama import LayerSpec
     from dlrover_tpu.serving.params import serving_params_from_llama
 
     cfg = _tiny(scan_layers=False, remat=False)
     _, params, _ = _seeded(cfg)
-    with pytest.raises(ValueError, match="B1"):
+    if lacks == "window":
+        cfg = dataclasses.replace(
+            cfg, qk_norm=False, num_experts=0, layers=tuple(
+                LayerSpec(num_heads=cfg.num_heads, window=8 * (i % 2))
+                for i in range(cfg.num_layers)))
+    elif lacks == "head_gate":
+        cfg = dataclasses.replace(cfg, qk_norm=False, num_experts=0,
+                                  attn_head_gate=True)
+    elif lacks == "experts_behind_gqa":
+        cfg = dataclasses.replace(cfg, qk_norm=False)
+    with pytest.raises(ValueError, match=match):
         serving_params_from_llama({"params": params}, cfg)

@@ -1,0 +1,358 @@
+"""A latent-attention model with a learned selection of keys and sparse
+experts through the paged latent cache and the engine, tiny, float32, on
+the CPU: against the plain reference, against itself in other chunkings,
+with a shared prefix; the index-score kernel in interpret mode; what the
+engine books and exports; and that the dense model traces what it did."""
+
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dlrover_tpu.models.llama import LlamaConfig
+from dlrover_tpu.ops.pallas import paged_index
+from dlrover_tpu.serving import latent
+from dlrover_tpu.serving import model as serving_model
+from dlrover_tpu.serving.engine import InferenceEngine
+from dlrover_tpu.serving.params import serving_params_from_llama
+from perfbench import controls_glm5
+from perfbench.drivers import serve_sparse
+from perfbench.weights import SeededParams
+from perfbench.weights_glm5 import SeededGlm5Params
+from tests.test_glm5_reference import (config_of, fresh_cache,
+                                       reference_logits, tiny)
+
+SLOT = jnp.zeros(1, jnp.int32)
+
+
+def _served(cfg, seed=7):
+    params = SeededGlm5Params(cfg, seed)
+    return params, serving_params_from_llama({"params": params}, cfg)
+
+
+def _run(sp, cfg, cache, seq, start, **kw):
+    # jitted (one program a shape and set of options): eagerly the layer
+    # loop dispatches op by op
+    step = jax.jit(lambda p, c, t, at: latent.verify_step(
+        p, cfg, c, t, at, **kw))
+    key = (cfg, len(seq), tuple(sorted(kw)))
+    step = _PROGRAMS.setdefault(key, step)
+    return step(sp, cache, jnp.asarray(seq[None]),
+                jnp.asarray([start], jnp.int32))
+
+
+_PROGRAMS = {}
+
+
+@pytest.mark.parametrize("topk", [8, 4096], ids=["selected", "dense"])
+def test_prefill_and_decode_through_the_cache_are_the_reference(topk):
+    """Chunks of 16, then token by token: every position's logits are the
+    reference's full forward, with the selection smaller than the context
+    and with a context under it (plain latent attention)."""
+    cfg = tiny(index_topk=topk)
+    params, sp = _served(cfg)
+    seq = np.random.RandomState(0).randint(0, 128, 45).astype(np.int32)
+    want, picked = reference_logits(cfg, params, seq, selection_of=(0, 45))
+    # ``watch_slot``: every forward hands back what it did for that slot
+    cache = dict(fresh_cache(cfg), watch_slot=jnp.asarray(0, jnp.int32))
+    got = []
+    for s in range(0, 32, 16):
+        logits, cache = _run(sp, cfg, cache, seq[s:s + 16], s, slots=SLOT)
+        seen = cache.pop("witness")
+        chosen = np.unpackbits(np.asarray(seen["chosen_bits"]),
+                               axis=-1).astype(bool)
+        assert seen["sparse_out"].shape == (16, cfg.hidden_size)
+        got.append(logits[0])
+        for layer in range(cfg.num_layers):
+            assert (chosen[layer, :, :45]
+                    == np.asarray(picked[layer][1][s:s + 16])).all()
+    for p in range(32, 45):
+        logits, cache = _run(sp, cfg, cache, seq[p:p + 1], p)
+        seen = cache.pop("witness")
+        assert seen["sparse_in"].shape == (1, cfg.hidden_size)
+        for layer in range(cfg.num_layers):
+            rows = np.asarray(seen["rows"][layer])
+            assert sorted(rows[rows >= 0]) == np.flatnonzero(
+                np.asarray(picked[layer][1][p])).tolist()
+        got.append(logits[0])
+    np.testing.assert_allclose(jnp.concatenate(got), want, atol=1e-4)
+
+
+def test_a_chunk_at_an_offset_is_one_prefill():
+    cfg = tiny()
+    _, sp = _served(cfg)
+    seq = np.random.RandomState(3).randint(0, 128, 48).astype(np.int32)
+    whole, _ = _run(sp, cfg, fresh_cache(cfg), seq, 0, slots=SLOT)
+    cache, parts = fresh_cache(cfg), []
+    for s in (0, 16, 32):
+        logits, cache = _run(sp, cfg, cache, seq[s:s + 16], s, slots=SLOT)
+        parts.append(logits[0])
+    np.testing.assert_allclose(jnp.concatenate(parts), whole[0], atol=2e-5)
+    # the bucketed prefill (no cache behind it) gives the same last logits
+    last, rows, keys = serving_model.prefill(
+        sp, cfg, jnp.asarray(seq[None]), jnp.asarray([48]))
+    np.testing.assert_allclose(last[0], whole[0, -1], atol=2e-5)
+    assert rows[0].shape == (1, 48, latent.latent_row_width(cfg))
+    assert keys[0].shape == (1, 48, cfg.index_head_dim)
+
+
+def _engine(cfg, params, **kw):
+    args = dict(max_slots=2, chunk=4, temperature=0.0, max_len=96,
+                prefill_buckets=(32, 48, 64, 96), paged=True, block_size=8,
+                prefill_chunk=16, attention_impl="pallas")
+    args.update(kw)
+    return InferenceEngine(cfg, {"params": params}, **args)
+
+
+def test_a_request_on_a_cached_document_answers_as_a_cold_one():
+    """The document's blocks are mapped, the tail's chunks start behind
+    them, and tokens and books are those of an engine that never saw it."""
+    cfg = tiny()
+    params = SeededGlm5Params(cfg, 9)
+    rng = np.random.RandomState(5)
+    doc = rng.randint(0, 128, 48).astype(np.int32)
+    tails = [rng.randint(0, 128, n).astype(np.int32) for n in (9, 21)]
+    warm = _engine(cfg, params)
+    warm.add_request(doc, 1)
+    warm.run()
+    chunks_before = warm.stats.prefill_chunks
+    rids = [warm.add_request(np.concatenate([doc, t]), 6) for t in tails]
+    hot = warm.run()
+    # 48 tokens shared: one chunk for the 9-token tail, two for the 21
+    assert warm.stats.prefill_chunks - chunks_before <= 3
+    assert warm.prefix_stats()["prefix_shared_tokens"] == 2 * 48
+    for tail, rid in zip(tails, rids):
+        cold = _engine(cfg, params, prefix_sharing=False)
+        crid = cold.add_request(np.concatenate([doc, tail]), 6)
+        assert cold.run()[crid].tolist() == hot[rid].tolist()
+    st = warm.stats
+    assert 0 < st.dsa_selected_ratio < 1 and st.attn_rows_selected > 0
+    assert st.index_rows_scanned >= st.dsa_rows_live > st.attn_rows_selected
+    assert 0 < st.moe_picks_held < st.moe_picks
+    assert st.moe_picks % (cfg.moe_top_k * 2) == 0   # 2 sparse layers
+    assert warm._blockmgr.check_books()
+
+
+def test_the_engine_refuses_a_latent_model_without_pools():
+    cfg = tiny()
+    with pytest.raises(ValueError, match="paged=True"):
+        InferenceEngine(cfg, {"params": SeededGlm5Params(cfg, 1)},
+                        max_len=96)
+
+
+def test_the_books_are_inert_for_a_dense_model():
+    cfg = LlamaConfig.tiny(dtype=jnp.float32)
+    eng = InferenceEngine(
+        cfg, {"params": SeededParams(cfg, 2)}, max_slots=2, chunk=4,
+        temperature=0.0, max_len=64, paged=True, block_size=8,
+        attention_impl="xla")
+    assert eng._book_selection(np.zeros(2), np.ones(2)) == {}
+    eng.add_request(np.arange(10, dtype=np.int32), 5)
+    eng.run()
+    st = eng.stats
+    assert (st.dsa_rows_live, st.index_rows_scanned, st.attn_rows_selected,
+            st.moe_picks, st.moe_picks_held) == (0, 0, 0, 0, 0)
+    assert st.dsa_selected_ratio == 0.0 and st.moe_held_share == 0.0
+    assert "moe_picks" not in eng._cache and eng._prefill_group == 2
+    assert "watch_slot" not in eng._cache and eng.witness_log == []
+    with pytest.raises(ValueError, match="witness"):
+        eng.watch(lambda req: True)
+
+
+def test_the_two_gauges_reach_the_scrape():
+    from dlrover_tpu.serving.router.metrics import RouterMetrics
+    from dlrover_tpu.utils.metric_registry import METRIC_HELP
+
+    m = RouterMetrics()
+    m.observe_engine_metrics([
+        {"dsa_rows_live": 100.0, "attn_rows_selected": 8.0,
+         "moe_picks": 64.0, "moe_picks_held": 4.0},
+        {"dsa_rows_live": 300.0, "attn_rows_selected": 24.0,
+         "moe_picks": 64.0, "moe_picks_held": 4.0}, {}])
+    out = m.metrics()
+    assert out["serving_dsa_selected_ratio"] == pytest.approx(0.08)
+    assert out["serving_moe_held_share"] == pytest.approx(0.0625)
+    assert RouterMetrics().metrics()["serving_dsa_selected_ratio"] == 0.0
+    for name in ("serving_dsa_selected_ratio", "serving_moe_held_share"):
+        assert name in METRIC_HELP
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_index_kernel_matches_jnp_on_ragged_lengths(dtype):
+    """Interpret mode against the gather, lengths 0, inside a page, on a
+    group's edge and at the table's end; pages no slot lists and the rows
+    behind every length are NaN, so a read of a dead row would show."""
+    rng = np.random.RandomState(0)
+    b, hi, di, nb, bs, mb = 5, 4, 16, 40, 8, 7
+    lengths = np.asarray([0, 3, 32, 41, 56], np.int32)
+    table = np.zeros((b, mb), np.int32)
+    pool = np.full((nb, bs, di), np.nan, np.float32)
+    free = list(range(1, nb))
+    for s, n in enumerate(lengths):
+        for j in range(-(-int(n) // bs)):
+            page = free.pop()
+            table[s, j] = page
+            live = min(bs, int(n) - j * bs)
+            pool[page, :live] = rng.randn(live, di)
+    q = jnp.asarray(rng.randn(b, hi, di), dtype)
+    w = jnp.asarray(rng.randn(b, hi), jnp.float32)
+    pool, table, lengths = (jnp.asarray(pool, dtype), jnp.asarray(table),
+                            jnp.asarray(lengths))
+    got = paged_index.paged_index_scores(q, w, pool, table, lengths,
+                                         interpret=True)
+    want = paged_index.gather_index_scores(q, w, pool, table, lengths)
+    assert got.shape == want.shape == (b, paged_index.padded_rows(bs, mb))
+    live = np.arange(got.shape[1])[None, :] < np.asarray(lengths)[:, None]
+    assert np.isneginf(np.asarray(got)[~live]).all()
+    assert not np.isnan(np.asarray(got)).any()
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               rtol=1e-5, atol=1e-5)
+    assert paged_index.scanned_rows(np.asarray(lengths), bs, mb) \
+        == (0 + 32 + 32 + 64 + 64)
+
+
+def test_the_kth_largest_is_the_sorts():
+    rng = np.random.RandomState(1)
+    x = rng.randn(6, 50).astype(np.float32)
+    x[0, :45] = -np.inf                   # fewer live entries than k
+    x[1, 10:20] = x[1, 3]                 # ties
+    keys = latent._orderable(jnp.asarray(x))
+    kth = latent._kth_largest(keys, 8)
+    chosen = np.asarray(keys >= kth[:, None])
+    want = np.sort(x, axis=-1)[:, -8]
+    assert (chosen == (x >= want[:, None])).all()
+    assert chosen[0].all() and chosen[2:].sum(-1).tolist() == [8] * 4
+
+
+def _watched(**engine):
+    """Questions on one cached document through an engine that is
+    watched, as ``perfbench/drivers/serve_sparse.py`` watches its window:
+    what the engine's own programs handed back, packed for the
+    reference."""
+    cfg = tiny()
+    params = SeededGlm5Params(cfg, 9)
+    rng = np.random.RandomState(11)
+    doc = rng.randint(0, 128, 48).astype(np.int32)
+    eng = _engine(cfg, params, **engine)
+    eng.add_request(doc, 1)
+    eng.run()
+    eng.watch(lambda req: req.prompt.size > doc.size)
+    for n in (13, 16, 9):
+        eng.add_request(np.concatenate(
+            [doc, rng.randint(0, 128, n).astype(np.int32)]), 14)
+    eng.run()
+    seen = serve_sparse.Witnessed(doc, eng.witness_log, cfg.num_layers, 1)
+    return cfg, params, seen
+
+
+def _verdicts(checked):
+    return [checked[k] for k in controls_glm5.VERDICTS]
+
+
+_WATCHED = {}
+
+
+@pytest.mark.parametrize("fault", [None] + sorted(controls_glm5.FAULTS))
+def test_the_timed_programs_witness_holds_and_a_planted_fault_shows(fault):
+    """The comparison the benchmark's cell makes of the engine's OWN
+    prefill-chunk and decode-chunk programs (selected rows, first sparse
+    MLP, emitted tokens) holds against the reference, and fails, by the
+    driver's limits, against a reference with any one fault planted."""
+    if not _WATCHED:
+        _WATCHED["it"] = _watched()
+    cfg, params, seen = _WATCHED["it"]
+    assert len(seen.requests) == 2                   # one at a time
+    assert seen.queries["run"].size == 13 + 9
+    # (a request's last token is fed to no forward that counts)
+    assert seen.queries["decode"].size == 2 * 13
+    with (controls_glm5.FAULTS[fault]() if fault
+          else controls_glm5._patched()):
+        got = serve_sparse.reference_check(cfg, params, config_of(cfg), seen)
+    if fault is None:
+        assert _verdicts(got) == [True] * 3
+        assert got["checked_positions"] == 2 * 14
+        assert got["worst_logit_deficit"] < 1e-4
+        for kind in ("run", "decode"):
+            assert all(s["overlap"] == 1.0 and s["unseen"] == 0
+                       for s in got[f"selection_{kind}"])
+            assert got[f"sparse_{kind}"]["mlp_rel"] < 1e-5
+    else:
+        assert not all(_verdicts(got)), got
+
+
+@pytest.mark.parametrize("program", ["decode", "run"])
+def test_a_fault_in_one_timed_program_shows_there(program, monkeypatch):
+    """The fault planted in the PROGRAM: the indexer's head weights lose
+    their sign in the decode program's scan alone, or in the prefill
+    chunk's alone; the witness of that program fails the selection, and
+    the prefill chunk's, which ran before any decode, is untouched by the
+    decode program's."""
+    if program == "decode":
+        inner = paged_index.gather_index_scores
+        monkeypatch.setattr(
+            paged_index, "gather_index_scores",
+            lambda q, w, *a: inner(q, jnp.abs(w), *a))
+    else:
+        inner = paged_index.index_scores
+        monkeypatch.setattr(
+            paged_index, "index_scores",
+            lambda q, w, keys: inner(q, jnp.abs(w), keys))
+    cfg, params, seen = _watched(attention_impl="xla")
+    got = serve_sparse.reference_check(cfg, params, config_of(cfg), seen)
+    assert not got["selection_matches_reference"]
+    assert not serve_sparse.selection_holds(got[f"selection_{program}"])
+    if program == "decode":
+        assert all(s["overlap"] == 1.0 for s in got["selection_run"])
+
+
+# the dense decoder's serving programs, as the parent of PR 34 traced them
+# (bf16 tiny preset, paged pools): (lines, sha256 of the jaxpr's text)
+_DENSE = {
+    "decode_step": (1669, "b20c0b127f8b214e76bfa62980fbf4337e0867d3dd36102b"
+                          "47be7a48c9030341"),
+    "prefill": (619, "cd182c54b1a09da445322202802dcb7738bcba847ee412cb0066"
+                     "3b52aa569325"),
+    "prefill_chunk": (935, "0e0d6374ccc52a0fc590c0cf18c138096343708d5a04d1"
+                           "5ab6963e31d32eb594"),
+}
+
+
+@pytest.mark.parametrize("program", sorted(_DENSE))
+def test_dense_model_traces_what_it_did(program, tmp_path):
+    """``decode_step``, ``prefill`` and the chunked prefill of a dense
+    model are, to the letter, the programs the tree before the latent
+    blocks traced (``serve-batch-closed`` compiles the same).  A change
+    that means to move them, or a JAX that prints them otherwise,
+    re-pins: the text is left in a file to diff."""
+    cfg = LlamaConfig.tiny(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    sp = jax.eval_shape(lambda: serving_params_from_llama(
+        {"params": SeededParams(cfg, 3)}, cfg))
+    b, nb, bs, mb = 2, 9, 8, 4
+    S = jax.ShapeDtypeStruct
+    pool = [S((nb, bs, cfg.num_kv_heads, cfg.head_dim_), jnp.bfloat16)
+            ] * cfg.num_layers
+    cache = {"k_pool": pool, "v_pool": pool, "table": S((b, mb), jnp.int32)}
+    ints = lambda *shape: S(shape, jnp.int32)  # noqa: E731
+    if program == "decode_step":
+        jaxpr = jax.make_jaxpr(
+            lambda p, c, t, pos, act: serving_model.decode_step(
+                p, cfg, c, t, pos, attention_impl="pallas",
+                kernel_interpret=True, active=act))(
+            sp, cache, ints(b), ints(b), S((b,), jnp.bool_))
+    elif program == "prefill":
+        jaxpr = jax.make_jaxpr(
+            lambda p, t, n: serving_model.prefill(p, cfg, t, n))(
+            sp, ints(b, 16), ints(b))
+    else:
+        jaxpr = jax.make_jaxpr(
+            lambda p, c, t, pos, sl, li: serving_model.verify_step(
+                p, cfg, c, t, pos, slots=sl, logits_index=li))(
+            sp, cache, ints(1, 8), ints(1), ints(1), ints(1))
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+    (tmp_path / f"{program}.txt").write_text(text)
+    got = (len(text.splitlines()), hashlib.sha256(text.encode()).hexdigest())
+    assert got == _DENSE[program], \
+        f"jax {jax.__version__}; the trace: {tmp_path / program}.txt"

@@ -178,6 +178,21 @@ def test_paged_decode_kernel_compiles(one_chip, geometry, kv_dtype):
     assert "tpu_custom_call" in text
 
 
+def test_index_score_kernel_compiles_at_glm5_widths(one_chip):
+    """``paged_index_scores`` at the served cell's geometry: 32 slots, 32
+    index heads of 128, pages of 128 rows, a table of 259."""
+    from dlrover_tpu.ops.pallas.paged_index import paged_index_scores
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(paged_index_scores).lower(
+        s((32, 32, 128), jnp.bfloat16), s((32, 32), jnp.float32),
+        s((2700, 128, 128), jnp.bfloat16), s((32, 259), jnp.int32),
+        s((32,), jnp.int32)).compile().as_text()
+    assert "paged_index_scores" in text and "tpu_custom_call" in text
+
+
 def test_paged_decode_int4_is_refused_loudly(one_chip):
     """Packed int4 pools do not compile on a TPU (minor dimension 64);
     until the pool is re-laid the kernel refuses in the repo's own
