@@ -11,6 +11,11 @@ batch that sequences enter and leave independently —
   batching, the Orca/vLLM scheduling model);
 - decode runs in chunks of ``chunk`` tokens per host sync (multi-step
   scheduling) — sampling stays on-device inside a ``lax.scan``;
+- a step dispatches ALL its programs (the prefills of what it admits,
+  one prompt chunk of what still prefills, the decode chunk) before it
+  waits for any: each slot's last token passes from program to program
+  on the device, and the host reads every result once, in dispatch
+  order, behind the last dispatch (``InferenceEngine.step``);
 - ``int8=True`` serves pre-quantized int8 weights through XLA's native
   int8 MXU dot (weights stream from HBM at half the bf16 bytes — decode
   is bandwidth-bound, so this is the serving speedup; measured against
@@ -24,11 +29,12 @@ per (bucket) + one for the decode chunk.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +75,11 @@ class Request:
 @dataclasses.dataclass
 class EngineStats:
     generated_tokens: int = 0
+    # the three ``*_seconds`` add, for each program, the host clock from
+    # the later of (its dispatch's start, the previous program's result
+    # reaching the host) to its own result reaching the host: dispatch +
+    # wait where it was the only program in flight, the program's time on
+    # the device where it queued behind another (``_read_results``)
     decode_seconds: float = 0.0
     prefill_seconds: float = 0.0
     prefill_calls: int = 0        # dispatches; < admissions when batched
@@ -83,8 +94,13 @@ class EngineStats:
     #                               several long prompts prefill
     #                               together (the TTFT-deserialization
     #                               win is slots/chunks)
-    prefill_chunk_seconds: float = 0.0  # wall seconds in chunk
-    #                               dispatches (the stall-bound budget)
+    prefill_chunk_seconds: float = 0.0  # the chunk dispatches' part of
+    #                               prefill_seconds (the stall-bound
+    #                               budget)
+    dispatches: int = 0           # programs sent to the device
+    chained_dispatches: int = 0   # ... while an earlier one of the same
+    #                               step was still unread: its host
+    #                               preparation cost the device no gap
     finished_requests: int = 0
     spec_proposed: int = 0        # draft tokens sent to verification
     spec_accepted: int = 0        # draft tokens accepted
@@ -150,6 +166,15 @@ class EngineStats:
             if self.moe_picks else 0.0
 
     @property
+    def chained_dispatch_share(self) -> float:
+        """Programs dispatched behind an unread one over all programs
+        dispatched: how often a step's queue on the device hid the
+        host's preparation of the next program (0.0 where every step is
+        one program)."""
+        return self.chained_dispatches / self.dispatches \
+            if self.dispatches else 0.0
+
+    @property
     def kv_stream_ratio(self) -> float:
         """Key rows the paged kernel copied per row a slot could see:
         1.0 is a stream with no dead row, and running every group of
@@ -157,6 +182,24 @@ class EngineStats:
         (0.0 before the kernel has decoded anything)."""
         return self.kv_rows_streamed / self.kv_rows_live \
             if self.kv_rows_live else 0.0
+
+
+@dataclasses.dataclass
+class _Unread:
+    """Programs dispatched and not yet read (one bucketed prefill, one
+    step's prompt chunks, one decode chunk): what ``_read_results``
+    needs to time them and hand their tokens on."""
+    name: str                     # span ``dlrover.engine.<name>``
+    attrs: Dict[str, Any]         # ... and its attributes
+    started: float                # host clock at the dispatch's start
+    outputs: Any                  # device arrays the host has to read
+    deliver: Callable[[Any], None]  # the bookkeeping that needs them
+
+
+# the ``EngineStats`` clocks a program's time adds to, by ``_Unread.name``
+_CLOCKS = {"prefill": ("prefill_seconds",),
+           "prefill_chunk": ("prefill_seconds", "prefill_chunk_seconds"),
+           "decode_chunk": ("decode_seconds",)}
 
 
 def _bucket(n: int, buckets: Tuple[int, ...]) -> int:
@@ -487,6 +530,16 @@ class InferenceEngine:
         self._ctx_len = np.zeros(self.max_slots, np.int32)
         self._positions = np.zeros(self.max_slots, np.int32)
         self._tokens = np.zeros(self.max_slots, np.int32)
+        # ``_tokens`` as the step's programs hand it on to each other on
+        # the device: a CACHE of the host's copy, which is whole again
+        # after every ``_read_results``.  None = not valid (whatever
+        # writes ``_tokens`` outside that chain drops it): the next
+        # dispatch uploads the host's
+        self._last_dev: Optional[jax.Array] = None
+        # what the current step has dispatched and not read, in dispatch
+        # order, and how many programs that is
+        self._unread: List[_Unread] = []
+        self._in_flight = 0
         self._remaining = np.zeros(self.max_slots, np.int32)
         self._queue: deque[Request] = deque()
         self._finished: List[Request] = []
@@ -646,10 +699,15 @@ class InferenceEngine:
         kv_packed4 = self.kv_dtype == "int4"
 
         @functools.partial(jax.jit, donate_argnums=(1,))
-        def insert_fn(params, cache, tokens, real_len, slots, skip, rng):
+        def insert_fn(params, cache, tokens, real_len, slots, skip, rng,
+                      last):
             """Prefill a GROUP of same-bucket prompts ([G, Lp]) and
             scatter their K/V into cache slots ``slots`` [G] in one
             dispatch (jit caches one program per (G, bucket) pair).
+            ``last`` [max_slots] is every slot's last token, the vector
+            the decode chunk carries: it comes back with the sampled
+            first tokens at ``slots``, so the chunk that follows needs no
+            host in between.
             ``skip`` [G] is the per-row shared-prefix length: those
             leading positions live in SHARED (read-only) prefix blocks
             already holding the first writer's K/V, so their writes
@@ -708,7 +766,8 @@ class InferenceEngine:
                 }
             rng, sub = jax.random.split(rng)
             first = select_token(logits, sub, temperature, top_k, top_p)
-            return new_cache, first, rng
+            return new_cache, first, rng, last.at[slots].set(
+                first.astype(last.dtype))
 
         self._chunk_fn = chunk_fn
         self._insert_fn = insert_fn
@@ -719,13 +778,16 @@ class InferenceEngine:
 
             @functools.partial(jax.jit, donate_argnums=(1,))
             def prefill_chunk_fn(params, cache, tokens, start, slots,
-                                 last_idx, rng):
+                                 last_idx, rng, last, final):
                 """ONE bounded prompt chunk for slot subset ``slots``:
                 a draft-free verify run attending to what previous
                 chunks cached (one compile — the chunk shape is fixed
                 at [G, prefill_chunk]).  ``last_idx`` picks the single
-                position whose logits feed sampling; the host uses the
-                sampled token only for the FINAL chunk."""
+                position whose logits feed sampling; the sampled token
+                counts only for a row on its FINAL chunk (``final`` [G],
+                a host fact): ``last`` (``insert_fn``) comes back with
+                it at that row's slot, and with the parked slot's 0 at
+                the others."""
                 with jax.named_scope("prefill_chunk"):
                     logits, cache = verify_step(
                         params, cfg, cache, tokens, start,
@@ -735,7 +797,9 @@ class InferenceEngine:
                 rng, sub = jax.random.split(rng)
                 first = select_token(
                     logits[:, 0, :], sub, temperature, top_k, top_p)
-                return cache, first, rng, witness
+                last = last.at[slots].set(
+                    jnp.where(final, first.astype(last.dtype), 0))
+                return cache, first, rng, witness, last
 
             self._prefill_chunk_fn = prefill_chunk_fn
 
@@ -797,15 +861,16 @@ class InferenceEngine:
         for g in range(1, b + 1):
             slots = jnp.arange(g, dtype=jnp.int32)
             if chunked and g <= self._prefill_group:
-                self._cache, _, _, _ = self._prefill_chunk_fn(
+                self._cache, _, _, _, _ = self._prefill_chunk_fn(
                     self.params, self._cache,
                     zeros(g, self.prefill_chunk), zeros(g), slots,
-                    zeros(g), rng)
+                    zeros(g), rng, zeros(b), jnp.zeros(g, bool))
                 ran += 1
             for bucket in buckets:
-                self._cache, _, _ = self._insert_fn(
+                self._cache, _, _, _ = self._insert_fn(
                     self.params, self._cache, zeros(g, bucket),
-                    jnp.ones(g, jnp.int32), slots, zeros(g), rng)
+                    jnp.ones(g, jnp.int32), slots, zeros(g), rng,
+                    zeros(b))
                 ran += 1
         jax.block_until_ready(self._cache)
         return ran
@@ -837,14 +902,28 @@ class InferenceEngine:
         self._queue.append(Request(rid, prompt, int(max_new_tokens)))
         return rid
 
-    @spanned("dlrover.engine.admit")
     def _admit(self) -> None:
+        """Admit waiting requests and read their first tokens: for a
+        caller outside ``step``, which dispatches more before it reads."""
+        self._dispatch_admissions()
+        self._read_results()
+
+    @spanned("dlrover.engine.admit")
+    def _dispatch_admissions(self) -> None:
         """Admit waiting requests into free slots.  Consecutive queue
         entries whose prompts land in the SAME length bucket prefill as
         one batched dispatch — at G admissions per dispatch this cuts
         the prefill launch count up to G-fold (the vLLM-style batched
         prefill; on this rig dispatch latency dominates prefill, so the
-        cut is a direct wall-clock win)."""
+        cut is a direct wall-clock win).
+
+        Nothing here waits for the device.  What the host decides needs
+        no sampled token: slot, blocks, position and budget are booked
+        at dispatch, and the first tokens stay on the device (in the
+        ``last`` vector for the programs behind, in an ``_Unread`` for
+        ``_read_results``).  A slot whose first token ends its request
+        is therefore held until the step's reads, not refilled within
+        this call."""
         while self._queue:
             free = [
                 s for s in range(self.max_slots)
@@ -902,30 +981,42 @@ class InferenceEngine:
             skips = (np.asarray([a[1] for a in allocs], np.int32)
                      if self.paged
                      else np.zeros(len(group), np.int32))
-            t0 = time.perf_counter()
-            with span("dlrover.engine.prefill", bucket=bucket,
-                      n=len(group)):
-                self._cache, firsts, self._rng = self._insert_fn(
-                    self.params, self._cache, jnp.asarray(padded),
-                    jnp.asarray(lens), jnp.asarray(slots, jnp.int32),
-                    jnp.asarray(skips), self._rng,
-                )
-                firsts = np.asarray(firsts)
-            self.stats.prefill_seconds += time.perf_counter() - t0
+            started = time.perf_counter()
+            with self._dispatching("prefill"):
+                self._cache, firsts, self._rng, self._last_dev = \
+                    self._launch(
+                        self._insert_fn,
+                        self.params, self._cache, jnp.asarray(padded),
+                        jnp.asarray(lens), jnp.asarray(slots, jnp.int32),
+                        jnp.asarray(skips), self._rng, self._last_tokens(),
+                    )
             self.stats.prefill_calls += 1
             self.stats.prefill_admissions += len(group)
-            for g, (s, req) in enumerate(zip(slots, group)):
-                first = int(firsts[g])
+            for s, req in zip(slots, group):
                 self._slot_req[s] = req
-                req.output.append(first)
-                p = req.prompt.size
-                self._ctx_buf[s, :p] = req.prompt
-                self._ctx_buf[s, p] = first
-                self._ctx_len[s] = p + 1
-                self._tokens[s] = first
-                self._positions[s] = p
+                self._positions[s] = req.prompt.size
                 self._remaining[s] = req.max_new_tokens - 1
-                self._finish_if_done(s, first)
+            self._unread.append(_Unread(
+                "prefill", {"bucket": bucket, "n": len(group)}, started,
+                [firsts], functools.partial(
+                    self._deliver_firsts,
+                    [(s, g) for g, s in enumerate(slots)])))
+
+    def _deliver_firsts(self, rows: List[Tuple[int, int]],
+                        firsts: List[np.ndarray]) -> None:
+        """Hand prefill programs' first tokens (one array a program) to
+        their requests: ``rows`` pairs a slot with its index in them."""
+        firsts = np.concatenate(firsts)
+        for s, g in rows:
+            req = self._slot_req[s]
+            first = int(firsts[g])
+            req.output.append(first)
+            p = req.prompt.size
+            self._ctx_buf[s, :p] = req.prompt
+            self._ctx_buf[s, p] = first
+            self._ctx_len[s] = p + 1
+            self._tokens[s] = first
+            self._finish_if_done(s, first)
 
     def _alloc_lifetime(self, req: Request, bucket: int):
         """ONE capacity formula for every admission path (batched AND
@@ -1051,9 +1142,15 @@ class InferenceEngine:
         by max_slots).  When a cursor reaches its prompt end, sample
         that row's first token and hand the slot to decode.
 
+        Nothing here waits for the device: what a chunk's end means
+        for its slot (cursor, published blocks, and on a prompt's last
+        chunk the hand-over to decode at a position the host knows) is
+        booked at dispatch, and the first tokens are read with the rest
+        of the step (``_read_results``).
+
         A latent-attention model's step sends the same work as ONE
-        DISPATCH A PREFILLING SLOT (``_prefill_group`` 1), one after
-        another with one sync behind the last: its attention walks a
+        DISPATCH A PREFILLING SLOT (``_prefill_group`` 1), one behind
+        another: its attention walks a
         row's live key blocks row by row anyway, so a dispatch of g rows
         would take g times one row's (compute-bound at 512 queries
         against tens of thousands of keys), and one group size is one
@@ -1072,6 +1169,7 @@ class InferenceEngine:
         starts = np.zeros(g, np.int32)
         last_idx = np.zeros(g, np.int32)
         ends = np.zeros(g, np.int32)
+        final = np.zeros(g, bool)       # rows on their prompt's last chunk
         for i, s in enumerate(slots):
             req = self._slot_req[s]
             assert req is not None
@@ -1080,68 +1178,66 @@ class InferenceEngine:
             chunk[i, : end - start] = req.prompt[start:end]
             starts[i] = start
             ends[i] = end
+            final[i] = end == req.prompt.size
             # index (within the chunk) of the prompt's final token:
             # only meaningful on a row's final chunk; clamped junk
             # otherwise (that row's sampled token is discarded)
             last_idx[i] = max(0, min(end, req.prompt.size) - 1 - start)
         if self.paged and self._table_dirty:
             self._push_table()
-        t0 = time.perf_counter()
-        with span("dlrover.engine.prefill_chunk", n=g,
-                  **self._book_selection(starts, ends)):
+        started = time.perf_counter()
+        attrs = {"n": g, **self._book_selection(starts, ends)}
+        with self._dispatching("prefill_chunk"):
             # one dispatch for all rows, or one a row where the model
-            # asks for that (``_prefill_group``); the cache threads
-            # through them and the host syncs once, behind the last
+            # asks for that (``_prefill_group``); the cache and the last
+            # tokens thread through them
             firsts = []
             for i in range(0, g, self._prefill_group):
                 rows = slice(i, i + self._prefill_group)
-                self._cache, first, self._rng, seen = \
-                    self._prefill_chunk_fn(
+                self._cache, first, self._rng, seen, self._last_dev = \
+                    self._launch(
+                        self._prefill_chunk_fn,
                         self.params, self._cache, jnp.asarray(chunk[rows]),
                         jnp.asarray(starts[rows]),
                         jnp.asarray(slots[rows], jnp.int32),
                         jnp.asarray(last_idx[rows]),
-                        self._rng,
+                        self._rng, self._last_tokens(),
+                        jnp.asarray(final[rows]),
                     )
                 if self._watch_slot in slots[rows]:
                     self._witnessed("run", self._prefill_pos, seen)
                 firsts.append(first)
-            # the sync belongs to the dispatch it waits for, as in
-            # _admit and the decode chunk
-            firsts = np.concatenate([np.asarray(f) for f in firsts])
-            self._book_moe_picks()
-        dt = time.perf_counter() - t0
-        self.stats.prefill_seconds += dt
-        self.stats.prefill_chunk_seconds += dt
         self.stats.prefill_calls += 1
         self.stats.prefill_chunks += 1
         self.stats.prefill_chunk_slots += g
+        ended = []
         for i, s in enumerate(slots):
             req = self._slot_req[s]
             end = int(ends[i])
             self._prefill_pos[s] = end
             if self.paged:
-                # the chunk just written completes every prompt block
+                # the chunk just dispatched completes every prompt block
                 # it crosses the end of — publish them so waiting
-                # admissions (shared_prefix_ready) can warm-start
+                # admissions (shared_prefix_ready) can warm-start: the
+                # device runs its queue in order, so the block is
+                # written before any later program reads it
                 bs = self.block_size
                 blocks = self._slot_blocks[s]
                 for j in range(int(starts[i]) // bs,
                                min(end // bs, req.prompt.size // bs)):
                     self._blockmgr.mark_filled(blocks[j])
-            if end < req.prompt.size:
+            if not final[i]:
                 continue
-            first = int(firsts[i])
             self._prefilling[s] = False
-            req.output.append(first)
-            p = req.prompt.size
-            self._ctx_buf[s, :p] = req.prompt
-            self._ctx_buf[s, p] = first
-            self._ctx_len[s] = p + 1
-            self._tokens[s] = first
-            self._positions[s] = p
+            self._positions[s] = req.prompt.size
             self._remaining[s] = req.max_new_tokens - 1
-            self._finish_if_done(s, first)
+            ended.append((s, i))
+        # read even where no row ends its prompt: the wait is the clock
+        # of this dispatch, and without it the decode chunk's would
+        # count this program's time as its own
+        self._unread.append(_Unread(
+            "prefill_chunk", attrs, started, firsts,
+            functools.partial(self._deliver_firsts, ended)))
 
     def _finish_if_done(self, s: int, last_token: int) -> bool:
         req = self._slot_req[s]
@@ -1193,12 +1289,16 @@ class InferenceEngine:
         for s, req in enumerate(self._slot_req):
             if req is not None and req.rid == rid:
                 self._release_slot(s)
+                # slot state changed outside a step's chain of programs
+                self._last_dev = None
                 return True
         return True
 
     @spanned("dlrover.engine.push_table")
     def _push_table(self) -> None:
-        table = jnp.asarray(self._table_np)
+        # a copy: the next admission binds its blocks in ``_table_np``
+        # while programs that read this table may still be in flight
+        table = jnp.asarray(self._table_np.copy())
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
@@ -1256,50 +1356,131 @@ class InferenceEngine:
         ordering IS the stall bound: a max-length prompt costs every
         other slot one chunk per decode round, never a whole prefill
         (one dispatch for all prefilling slots, or one a slot for a
-        latent-attention model: ``_advance_prefill``)."""
+        latent-attention model: ``_advance_prefill``).
+
+        The step dispatches ALL of that before it waits for any of it.
+        Which slots are free, prefill or decode, every position and
+        every block are host facts; the one thing a program needs of the
+        one before is each slot's last token, and that passes between
+        them on the device (``_last_tokens``).  So while the host
+        prepares the next program the device runs the last, and the
+        results are read once, in dispatch order, behind the last
+        dispatch (``_read_results``).  What a sampled token alone can
+        say, that it ends its request, is learnt there: a slot whose
+        FIRST token is the end-of-sequence sits in this step's decode
+        chunk as a wasted lane, as one that ends mid-chunk does."""
         before = len(self._finished)
-        self._admit()
+        self._dispatch_admissions()
         if self.prefill_chunk:
             self._advance_prefill()
-        active = np.array([
+        if self._spec_fn is not None and self._spec_state == "on":
+            # drafts come from the host's context: read first
+            self._read_results()
+            if self._decoding().any():
+                self._spec_step()
+            return self._finished[before:]
+        active = self._decoding()
+        if active.any():
+            self._dispatch_decode(active)
+        self._read_results()
+        if active.any() and self._spec_fn is not None:
+            self._after_chunk_round()
+        return self._finished[before:]
+
+    def _decoding(self) -> np.ndarray:
+        """The slots a decode dispatch advances: those holding a request
+        that is past its prefill and, as far as the host can know before
+        it has read the last token, not at its end (a budget of one
+        token is spent by the prefill's)."""
+        return np.array([
             r is not None and not self._prefilling[s]
+            and self._remaining[s] > 0
             for s, r in enumerate(self._slot_req)
         ])
-        if active.any() and self._spec_fn is not None \
-                and self._spec_state == "on":
-            self._spec_step()
-            return self._finished[before:]
-        if active.any():
-            if self.paged and self._table_dirty:
-                self._push_table()
-            live, streamed = self._book_kv_rows(active)
-            lengths = self._positions[active][:, None] + np.arange(
-                1, self.chunk + 1)[None, :]
-            rows = {"kv_rows_live": live, "kv_rows_streamed": streamed,
-                    **self._book_selection(lengths - 1, lengths)}
-            t0 = time.perf_counter()
-            with span("dlrover.engine.decode_chunk", **rows):
-                out, tokens, positions, self._cache, self._rng, seen = \
-                    self._chunk_fn(
-                        self.params, self._cache,
-                        jnp.asarray(self._tokens),
-                        jnp.asarray(self._positions),
-                        jnp.asarray(active), self._rng,
-                    )
-                if self._watch_slot >= 0 and active[self._watch_slot]:
-                    self._witnessed("decode", self._positions, seen)
-                out = np.asarray(out)                       # [B, chunk]
-                # copies: jax->numpy views are read-only, but _admit
-                # mutates
-                self._tokens = np.array(tokens)
-                self._positions = np.array(positions)
-                self._book_moe_picks()
-            self.stats.decode_seconds += time.perf_counter() - t0
-            self.stats.decode_forwards += self.chunk
-            self._deliver_chunk(out)
-            if self._spec_fn is not None:
-                self._after_chunk_round()
-        return self._finished[before:]
+
+    def _dispatch_decode(self, active: np.ndarray) -> None:
+        """One decode chunk for the slots ``active``, not waited for."""
+        if self.paged and self._table_dirty:
+            self._push_table()
+        live, streamed = self._book_kv_rows(active)
+        lengths = self._positions[active][:, None] + np.arange(
+            1, self.chunk + 1)[None, :]
+        rows = {"kv_rows_live": live, "kv_rows_streamed": streamed,
+                **self._book_selection(lengths - 1, lengths)}
+        started = time.perf_counter()
+        with self._dispatching("decode_chunk"):
+            out, self._last_dev, _, self._cache, self._rng, seen = \
+                self._launch(
+                    self._chunk_fn,
+                    self.params, self._cache, self._last_tokens(),
+                    # a copy: the host goes on writing ``_positions``
+                    # while the device may not have fetched them yet
+                    jnp.asarray(self._positions.copy()),
+                    jnp.asarray(active), self._rng,
+                )
+        if self._watch_slot >= 0 and active[self._watch_slot]:
+            self._witnessed("decode", self._positions, seen)
+        # what the program does to them: no round trip
+        self._positions[active] += self.chunk
+        self.stats.decode_forwards += self.chunk
+        self._unread.append(_Unread(
+            "decode_chunk", rows, started, out,
+            functools.partial(self._deliver_chunk, active)))
+
+    # ------------------------------------- dispatch now, read afterwards
+    def _last_tokens(self) -> jax.Array:
+        """Every slot's last token on the device: what the step's last
+        program handed on, or the host's ``_tokens`` where that is not
+        valid."""
+        if self._last_dev is None:
+            # a copy, as of ``_positions``: the reads write ``_tokens``
+            self._last_dev = jnp.asarray(self._tokens.copy())
+        return self._last_dev
+
+    def _launch(self, program, *args):
+        """Dispatch one program, counted, and as chained where an
+        earlier one of this step is still unread."""
+        self.stats.dispatches += 1
+        self.stats.chained_dispatches += self._in_flight > 0
+        self._in_flight += 1
+        return program(*args)
+
+    def _dispatching(self, name: str):
+        """The span of a dispatch with nothing in flight: the device
+        stands while the host is in it, so it is part of that program's
+        time as its wait is (``_read_results`` opens the name again
+        around the wait, with the attributes).  A chained dispatch has
+        none: the device is busy, and the time is the host's own."""
+        if self._in_flight:
+            return contextlib.nullcontext()
+        return span("dlrover.engine." + name)
+
+    def _read_results(self) -> None:
+        """Read what the step dispatched, in dispatch order, and do the
+        bookkeeping that needed it: first tokens into their requests,
+        then the decode chunk's, each followed by its finishes, so
+        requests finish (and free their slots) in the order of the
+        dispatches.  Each wait is a span under its program's name and
+        adds to that program's ``*_seconds`` (``EngineStats``)."""
+        if not self._unread:
+            return
+        unread, self._unread = self._unread, []
+        with span("dlrover.engine.reads", dispatches=self._in_flight,
+                  chained=self._in_flight - 1):
+            reached = 0.0         # the last result reached the host at
+            for sent in unread:
+                with span("dlrover.engine." + sent.name, **sent.attrs):
+                    values = jax.tree_util.tree_map(
+                        np.asarray, sent.outputs)
+                now = time.perf_counter()
+                for clock in _CLOCKS[sent.name]:
+                    setattr(self.stats, clock, getattr(self.stats, clock)
+                            + now - max(sent.started, reached))
+                reached = now
+                sent.deliver(values)
+            self._in_flight = 0
+            # behind the syncs above
+            self._book_moe_picks()
 
     def _book_kv_rows(self, active: np.ndarray,
                       chunks: int = 1) -> Tuple[int, int]:
@@ -1409,11 +1590,15 @@ class InferenceEngine:
         self.stats.moe_picks_held += int(delta[1])
 
     @spanned("dlrover.engine.deliver")
-    def _deliver_chunk(self, out: np.ndarray) -> None:
-        """Hand a decode chunk's tokens ([B, chunk]) to their requests."""
-        for s in range(self.max_slots):
+    def _deliver_chunk(self, active: np.ndarray, out: np.ndarray) -> None:
+        """Hand a decode chunk's tokens ([B, chunk]) to the requests of
+        the slots ``active`` in it.  A slot whose request the reads
+        before this one have ended (on its first token) keeps nothing of
+        its lane."""
+        self._tokens[active] = out[active, -1]
+        for s in np.flatnonzero(active):
             req = self._slot_req[s]
-            if req is None or self._prefilling[s]:
+            if req is None:
                 continue
             take = min(self.chunk, int(self._remaining[s]))
             toks = out[s, :take].tolist()
@@ -1484,13 +1669,17 @@ class InferenceEngine:
             self._push_table()
         t0 = time.perf_counter()
         with span("dlrover.engine.verify"):
-            out, n_commit, self._cache, self._rng = self._spec_fn(
+            out, n_commit, self._cache, self._rng = self._launch(
+                self._spec_fn,
                 self.params, self._cache, jnp.asarray(tokens),
                 jnp.asarray(self._positions), jnp.asarray(draft_lens),
                 self._rng,
             )
             out = np.asarray(out)
             n_commit = np.asarray(n_commit)
+        self._in_flight = 0
+        # the commits below write the host's ``_tokens`` only
+        self._last_dev = None
         self.stats.decode_seconds += time.perf_counter() - t0
         self.stats.spec_calls += 1
         self.stats.decode_forwards += 1
@@ -1571,14 +1760,17 @@ class InferenceEngine:
         active_j = jnp.asarray(active)
         for _ in range(n_chunks):
             out, tokens, positions, self._cache, self._rng, _ = \
-                self._chunk_fn(
+                self._launch(
+                    self._chunk_fn,
                     self.params, self._cache, tokens, positions,
                     active_j, self._rng,
                 )
             outs.append(out)
         out = np.concatenate([np.asarray(o) for o in outs], axis=1)
+        self._in_flight = 0
         self._tokens = np.array(tokens)
         self._positions = np.array(positions)
+        self._last_dev = None
         self.stats.decode_seconds += time.perf_counter() - t0
         for s in range(self.max_slots):
             req = self._slot_req[s]
@@ -1623,4 +1815,5 @@ class InferenceEngine:
             mask[i, p_len:p_len + n_real] = 1
         # engine state stays warm for the next batch
         self._finished.clear()
+        self._last_dev = None
         return tokens, mask

@@ -138,6 +138,8 @@ class RouterMetrics:
         self.attn_rows_selected = 0.0
         self.moe_picks = 0.0
         self.moe_picks_held = 0.0
+        self.dispatches = 0.0
+        self.chained_dispatches = 0.0
         # prefix-cache fleet aggregates (engine-side COW ledger summed
         # over reporting replicas, same sweep as the raw-speed keys)
         self.prefix_hits = 0.0
@@ -300,7 +302,7 @@ class RouterMetrics:
         self.kv_rows_streamed = sum(
             d.get("kv_rows_streamed", 0.0) for d in dicts)
         for name in ("dsa_rows_live", "attn_rows_selected", "moe_picks",
-                     "moe_picks_held"):
+                     "moe_picks_held", "dispatches", "chained_dispatches"):
             setattr(self, name, sum(d.get(name, 0.0) for d in dicts))
         for attr, key in (
             ("prefix_hits", "prefix_hits"),
@@ -407,6 +409,9 @@ class RouterMetrics:
             "serving_paged_kv_stream_ratio": (
                 self.kv_rows_streamed / self.kv_rows_live
                 if self.kv_rows_live else 0.0),
+            "serving_engine_chained_dispatch_share": (
+                self.chained_dispatches / self.dispatches
+                if self.dispatches else 0.0),
             "serving_dsa_selected_ratio": (
                 self.attn_rows_selected / self.dsa_rows_live
                 if self.dsa_rows_live else 0.0),

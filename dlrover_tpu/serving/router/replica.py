@@ -157,6 +157,11 @@ class InferenceEngineAdapter:
             "prefill_chunk_seconds": st.prefill_chunk_seconds,
             "prefill_calls": float(st.prefill_calls),
             "prefill_admissions": float(st.prefill_admissions),
+            # programs sent to the device, and those sent while an
+            # earlier one of the same step was unread (the sums: a
+            # fleet's share weighs by work)
+            "dispatches": float(st.dispatches),
+            "chained_dispatches": float(st.chained_dispatches),
         }
         if getattr(eng, "paged", False):
             # resolved paged-attention impl (0=xla gather, 1=fused

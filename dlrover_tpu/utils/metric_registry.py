@@ -188,6 +188,14 @@ METRIC_HELP: Dict[str, str] = {
         "length, so short contexts read near 1-2 and a fleet at full "
         "context 1.0; 0 = no replica decodes with the kernel"
     ),
+    "serving_engine_chained_dispatch_share": (
+        "engine programs (prefills, prompt chunks, decode chunks) "
+        "dispatched while an earlier program of the same engine step "
+        "was still unread, over all programs dispatched, fleet-wide: "
+        "the share of dispatches whose host preparation the device's "
+        "queue hid; 0 = every step is one program (or no replica "
+        "reports)"
+    ),
     "serving_dsa_selected_ratio": (
         "key rows attended per key row live on replicas whose model "
         "picks its keys with a learned indexer (LlamaConfig.index_topk), "

@@ -248,6 +248,38 @@ def _watched(**engine):
     return cfg, params, seen
 
 
+def test_a_watched_request_is_witnessed_by_a_step_that_waits_once():
+    """``step`` dispatches a latent model's prompt chunks (one a slot)
+    and its decode chunk before it reads any of them: the watched
+    request's witness is still appended with every dispatch that
+    advances it, as device arrays the step never reads, and the experts'
+    picks are booked behind the step's reads."""
+    cfg = tiny()
+    rng = np.random.RandomState(4)
+    eng = _engine(cfg, SeededGlm5Params(cfg, 9))
+    eng.watch(lambda req: req.prompt.size == 40)
+    for n in (40, 37):
+        eng.add_request(rng.randint(0, 128, n).astype(np.int32), 6)
+    picks = []
+    while eng.has_work:
+        eng.step()
+        assert not eng._unread
+        picks.append(eng.stats.moe_picks)
+    watched = eng.witness_log
+    assert {w["request"].rid for w in watched} == {0}
+    assert [(w["kind"], w["start"]) for w in watched] == [
+        ("run", 0), ("run", 16), ("run", 32), ("decode", 40),
+        ("decode", 44)]
+    assert all(isinstance(leaf, jax.Array) for w in watched
+               for leaf in jax.tree_util.tree_leaves(w["seen"]))
+    st = eng.stats
+    # three steps of two chunk programs, the second behind the first; a
+    # decode chunk behind the third's, and one alone
+    assert (st.dispatches, st.chained_dispatches) == (2 * 3 + 2, 3 + 1)
+    assert picks == sorted(picks) and picks[0] > 0
+    assert st.moe_picks == picks[-1] and 0 < st.moe_picks_held < st.moe_picks
+
+
 def _verdicts(checked):
     return [checked[k] for k in controls_glm5.VERDICTS]
 

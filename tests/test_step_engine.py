@@ -14,6 +14,7 @@ failure — must reach the SAME terminal state and output per submitted
 request under the old sweep, the event loop, and the sharded front.
 """
 
+import dataclasses
 import threading
 import time
 
@@ -830,3 +831,291 @@ def test_router_open_loop_soak_60s():
         assert rig["router_books_ok"], (arrival, rig)
         assert rig["router_cancel_attempts"] > 0
         assert rig["router_qps"] >= 1000, (arrival, rig)
+
+
+# ----------------------------------------------------------------------
+# The ENGINE's step (ISSUE 35): a step dispatches all its programs before
+# it waits for any.  Each slot's last token passes from program to
+# program on the device; the host reads once, in dispatch order, behind
+# the last dispatch.
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+from dlrover_tpu.models.llama import LlamaConfig, LlamaModel  # noqa: E402
+from dlrover_tpu.serving.engine import InferenceEngine  # noqa: E402
+from dlrover_tpu.serving.router.replica import (  # noqa: E402
+    InferenceEngineAdapter,
+)
+
+# (prompt length, max_new_tokens): across the buckets 8 / 16 / 32 / 48,
+# two longer than ``prefill_chunk`` 16, one with a budget of one token;
+# the first token of request 4 is made the end-of-sequence
+_QUEUE = ((5, 9), (12, 7), (20, 10), (7, 1), (9, 8), (30, 6), (6, 11),
+          (14, 5))
+_EOS_FIRST = 4
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    cfg = LlamaConfig.tiny(max_seq_len=64, dtype=jnp.float32)
+    variables = LlamaModel(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    rng = np.random.RandomState(7)
+    prompts = [rng.randint(1, cfg.vocab_size, n).astype(np.int32)
+               for n, _ in _QUEUE]
+    return cfg, variables, prompts
+
+
+def _engine(tiny_model, **kw):
+    cfg, variables, _ = tiny_model
+    args = dict(max_slots=3, chunk=4, temperature=0.0, max_len=48,
+                prefill_buckets=(8, 16, 32, 48))
+    args.update(kw)
+    return InferenceEngine(cfg, variables, **args)
+
+
+@pytest.fixture(scope="module")
+def solo_tokens(tiny_model):
+    """Every request of the queue alone, through ``generate`` on an idle
+    engine of one slot with no end-of-sequence: greedy decoding's tokens,
+    which no batching, layout or admission path may change."""
+    _, _, prompts = tiny_model
+    eng = _engine(tiny_model, max_slots=1)
+    solo = []
+    for prompt, (_, new) in zip(prompts, _QUEUE):
+        tokens, _ = eng.generate(prompt[None], new)
+        solo.append(tokens[0, prompt.size:].tolist())
+    return solo
+
+
+def _until_eos(tokens, eos):
+    return tokens[: tokens.index(eos) + 1] if eos in tokens else tokens
+
+
+def _drive(eng, prompts, reupload=False):
+    """The whole queue through ``step``; returns the tokens by request,
+    the order of finishes, and the finishes of each step as (where the
+    finishing token came from, slot, request)."""
+    phases = []
+    finish = eng._finish_if_done
+
+    def during(phase, fn):
+        def inner(*args):
+            phases.append(phase)
+            try:
+                return fn(*args)
+            finally:
+                phases.pop()
+        return inner
+
+    def noted_finish(s, token):
+        req = eng._slot_req[s]
+        if finish(s, token):
+            steps[-1].append((phases[-1], s, req.rid))
+            return True
+        return False
+
+    eng._finish_if_done = noted_finish
+    eng._deliver_firsts = during("first", eng._deliver_firsts)
+    eng._deliver_chunk = during("chunk", eng._deliver_chunk)
+    for prompt, (_, new) in zip(prompts, _QUEUE):
+        eng.add_request(prompt, new)
+    steps, order = [], []
+    while eng.has_work:
+        assert len(steps) < 200
+        if reupload:
+            eng._last_dev = None
+        steps.append([])
+        order += [r.rid for r in eng.step()]
+        assert not eng._unread and eng._in_flight == 0
+        # the device's vector is the host's, slot for slot
+        assert np.array_equal(np.asarray(eng._last_tokens()), eng._tokens)
+        assert order == [rid for step in steps for _, _, rid in step]
+    return ({r.rid: list(r.output) for r in eng._finished}, order, steps)
+
+
+@pytest.mark.parametrize("sampling", ["greedy", "seeded"])
+@pytest.mark.parametrize("admission", ["bucketed", "chunked"])
+@pytest.mark.parametrize("layout", ["paged", "dense"])
+def test_a_mixed_queue_yields_the_parents_tokens(
+        tiny_model, solo_tokens, layout, admission, sampling):
+    """Prompts across the buckets, long ones, a budget of one token and a
+    first token that is the end-of-sequence, more requests than slots:
+    greedy, every request's tokens are its solo run's (the parent's,
+    token for token); sampled from a seed, they are those of an engine
+    that uploads the host's last tokens before every step, which is
+    where the parent's programs took them from.  Requests finish in the
+    order the parent's code ran its finishes: first tokens in dispatch
+    order, then the decode chunk's by slot."""
+    _, _, prompts = tiny_model
+    kw = dict(paged=layout == "paged", block_size=8,
+              prefill_chunk=16 if admission == "chunked" else 0)
+    if sampling == "greedy":
+        eos = solo_tokens[_EOS_FIRST][0]
+        want = {i: _until_eos(t, eos) for i, t in enumerate(solo_tokens)}
+        got, order, steps = _drive(_engine(tiny_model, eos_token=eos, **kw),
+                                   prompts)
+        assert got == want
+    else:
+        kw.update(temperature=0.8, top_k=20, seed=5)
+        free, _, _ = _drive(_engine(tiny_model, **kw), prompts)
+        eos = free[_EOS_FIRST][0]
+        got, order, steps = _drive(_engine(tiny_model, eos_token=eos, **kw),
+                                   prompts)
+        want, want_order, _ = _drive(
+            _engine(tiny_model, eos_token=eos, **kw), prompts, reupload=True)
+        assert got == want and order == want_order
+        assert got != {i: _until_eos(t, eos)
+                       for i, t in enumerate(solo_tokens)}   # it sampled
+    assert got[_EOS_FIRST] == [eos] and len(got[3]) == 1
+    assert sorted(order) == list(range(len(_QUEUE)))
+    for step in steps:
+        firsts = [f for f in step if f[0] == "first"]
+        chunk = [f for f in step if f[0] == "chunk"]
+        assert step == firsts + chunk
+        assert [s for _, s, _ in chunk] == sorted(s for _, s, _ in chunk)
+    # a first token that ends its request waits for the step's reads
+    assert any(f[2] == _EOS_FIRST and f[0] == "first"
+               for step in steps for f in step)
+
+
+class _Read:
+    """A program's output that says when the host reads it."""
+
+    def __init__(self, array, events, name):
+        self._array, self._events, self._name = array, events, name
+
+    def __array__(self, *args, **kwargs):
+        self._events.append(("read", self._name))
+        return np.asarray(self._array, *args, **kwargs)
+
+
+def _spied(eng, events):
+    """Wrap the engine's programs: every dispatch is noted, and every
+    output the host has a use for notes its read."""
+    def spy(name, program, reads):
+        def run(*args):
+            events.append(("dispatch", name))
+            out = list(program(*args))
+            for i in reads:
+                out[i] = _Read(out[i], events, name)
+            return tuple(out)
+        return run
+
+    eng._insert_fn = spy("prefill", eng._insert_fn, (1,))
+    eng._chunk_fn = spy("decode_chunk", eng._chunk_fn, (0,))
+    if eng._prefill_chunk_fn is not None:
+        eng._prefill_chunk_fn = spy("prefill_chunk", eng._prefill_chunk_fn,
+                                    (1,))
+
+
+def test_a_step_reads_nothing_between_its_dispatches(tiny_model):
+    """Two admissions (two buckets: two prefill programs), a prompt
+    chunk and a decode chunk in one step: four dispatches, then their
+    four reads, in the order of the dispatches."""
+    _, _, prompts = tiny_model
+    eng = _engine(tiny_model, max_slots=4, paged=True, block_size=8,
+                  prefill_chunk=16)
+    eng.add_request(prompts[0], 12)
+    eng.add_request(prompts[5], 6)          # 30 tokens: two chunks
+    eng.step()
+    events = []
+    _spied(eng, events)
+    eng.add_request(prompts[6], 8)          # bucket 8
+    eng.add_request(prompts[1], 8)          # bucket 16
+    before = dataclasses.replace(eng.stats)
+    with jax.transfer_guard_device_to_host("disallow_explicit"):
+        eng._dispatch_admissions()
+        eng._advance_prefill()
+        eng._dispatch_decode(eng._decoding())
+    assert events == [("dispatch", n) for n in (
+        "prefill", "prefill", "prefill_chunk", "decode_chunk")]
+    assert all(len(r.output) == 0 for r in eng._slot_req[1:])
+    eng._read_results()
+    assert events[4:] == [("read", n) for n in (
+        "prefill", "prefill", "prefill_chunk", "decode_chunk")]
+    assert eng.stats.dispatches - before.dispatches == 4
+    assert eng.stats.chained_dispatches - before.chained_dispatches == 3
+    # a first token and, for all four slots, that step's chunk of four
+    assert [len(r.output) for r in eng._slot_req] == [1 + 4 + 4, 5, 5, 5]
+    # the whole of it again through ``step``: the same shape
+    del events[:]
+    eng.add_request(prompts[4], 3)
+    while eng.has_work:
+        eng.step()
+        kinds = [k for k, _ in events]
+        assert kinds == sorted(kinds), events   # dispatches, then reads
+        del events[:]
+
+
+def test_chained_dispatches_are_counted_and_exported(tiny_model):
+    """A scripted sequence of steps: a program is chained when an
+    earlier one of ITS step is unread, never across steps."""
+    _, _, prompts = tiny_model
+    eng = _engine(tiny_model, max_slots=4, paged=True, block_size=8)
+    st = eng.stats
+    assert (st.dispatches, st.chained_dispatches) == (0, 0)
+    assert st.chained_dispatch_share == 0.0
+    eng.add_request(prompts[0], 20)
+    eng.step()                  # a prefill, and the chunk behind it
+    assert (st.dispatches, st.chained_dispatches) == (2, 1)
+    eng.step()                  # the chunk alone
+    assert (st.dispatches, st.chained_dispatches) == (3, 1)
+    eng.add_request(prompts[6], 2)      # bucket 8
+    eng.add_request(prompts[4], 2)      # bucket 16
+    eng.add_request(prompts[1], 2)      # bucket 16: one group with it
+    eng.step()                  # two prefills and the chunk
+    assert (st.dispatches, st.chained_dispatches) == (6, 3)
+    assert st.prefill_calls == 3 and st.prefill_admissions == 4
+    assert st.chained_dispatch_share == 0.5
+    assert 0 < st.prefill_seconds and 0 < st.decode_seconds
+    metrics = RouterMetrics()
+    sent = InferenceEngineAdapter(eng).engine_metrics()
+    assert (sent["dispatches"], sent["chained_dispatches"]) == (6.0, 3.0)
+    metrics.observe_engine_metrics([sent, {"dispatches": 2.0}, {}])
+    assert metrics.metrics()["serving_engine_chained_dispatch_share"] \
+        == 3.0 / 8.0
+    metrics.observe_engine_metrics([])
+    assert metrics.metrics()["serving_engine_chained_dispatch_share"] == 0.0
+
+
+@pytest.mark.parametrize("outside", ["cancel", "spec_step", "drain_fixed"])
+def test_the_device_vector_is_uploaded_again_after(tiny_model, solo_tokens,
+                                                   outside):
+    """What writes the slots' state outside a step's chain of programs
+    drops the device's copy of the last tokens, and the next dispatch
+    uploads the host's: the tokens stay the solo runs'."""
+    _, _, prompts = tiny_model
+    kw = dict(paged=True, block_size=8)
+    if outside == "spec_step":
+        kw["speculative_k"] = 3
+    eng = _engine(tiny_model, **kw)
+    rids = [eng.add_request(prompts[i], _QUEUE[i][1]) for i in (0, 1, 2)]
+    if outside == "drain_fixed":
+        eng._drain_fixed()
+        assert eng.stats.chained_dispatches > 0
+    else:
+        eng.step()
+        assert eng._last_dev is None if outside == "spec_step" \
+            else eng._last_dev is not None
+    if outside == "cancel":
+        assert eng.cancel(rids[1])
+    assert eng._last_dev is None and not eng._unread
+    uploads = []
+    upload = eng._last_tokens
+    eng._last_tokens = lambda: (
+        uploads.append(eng._last_dev is None), upload())[1]
+    rids.append(eng.add_request(prompts[4], _QUEUE[4][1]))
+    if outside == "spec_step":
+        eng._spec_state = "backoff"     # chunk decode behind a verify
+        eng._spec_cooldown = 100
+    eng.step()
+    assert uploads[0] and not any(uploads[1:])
+    assert np.array_equal(np.asarray(eng._last_dev), eng._tokens)
+    done = eng.run()
+    for i, rid in zip((0, 1, 2, 4), rids):
+        if outside == "cancel" and i == 1:
+            assert rid not in done
+        else:
+            assert done[rid].tolist() == solo_tokens[i]
