@@ -43,6 +43,7 @@ from dlrover_tpu.ops.losses import (
     fused_lm_head_loss,
     masked_language_model_loss,
 )
+from dlrover_tpu.utils.profiler import device_scope
 
 
 class TrainState(train_state.TrainState):
@@ -168,10 +169,11 @@ def default_loss_fn(
                 )
             else:
                 mask = valid
-        loss, weight = fused_lm_head_loss(
-            hidden, kernel, labels, mask, chunk_size=loss_chunk_size,
-            logit_scale=getattr(model.config, "logit_scale", 1.0),
-        )
+        with device_scope("head"):
+            loss, weight = fused_lm_head_loss(
+                hidden, kernel, labels, mask, chunk_size=loss_chunk_size,
+                logit_scale=getattr(model.config, "logit_scale", 1.0),
+            )
         return _with_moe(loss, weight, var_updates)
 
     def loss_fn(params, batch):
@@ -184,9 +186,10 @@ def default_loss_fn(
             mask = mask[:, 1:] if mask is not None else None
         else:
             mask = batch.get("loss_mask")
-        loss, weight = masked_language_model_loss(
-            logits, labels, mask, return_weight=True
-        )
+        with device_scope("head"):
+            loss, weight = masked_language_model_loss(
+                logits, labels, mask, return_weight=True
+            )
         return _with_moe(loss, weight, var_updates)
 
     return chunked_loss_fn if loss_chunk_size else loss_fn
@@ -194,6 +197,17 @@ def default_loss_fn(
 
 def _tree_add(a, b):
     return jax.tree_util.tree_map(jnp.add, a, b)
+
+
+def _scoped(name: str, tx: optax.GradientTransformation):
+    """``tx`` with its update traced under ``device_scope(name)``: the
+    same state and arithmetic, named in the compiled step."""
+
+    def update(updates, state, params=None):
+        with device_scope(name):
+            return tx.update(updates, state, params)
+
+    return optax.GradientTransformation(tx.init, update)
 
 
 def _offload_streaming(tx, shardings_cell):
@@ -296,7 +310,8 @@ def accelerate(
     _offload_cell: Dict[str, Any] = {}
     if config.max_grad_norm is not None:
         optimizer = optax.chain(
-            optax.clip_by_global_norm(config.max_grad_norm), optimizer
+            _scoped("clip", optax.clip_by_global_norm(config.max_grad_norm)),
+            optimizer,
         )
     if config.offload_optimizer_states:
         optimizer = _offload_streaming(optimizer, _offload_cell)
@@ -479,9 +494,9 @@ def accelerate(
             # when mask density varies across microbatches.
             def micro_step(carry, mb):
                 loss_acc, grad_acc, w_acc = carry
-                with jax.named_scope("loss_and_grad"):
+                with device_scope("loss_and_grad"):
                     (loss, aux), grads = grad_fn(state.params, mb)
-                with jax.named_scope("grad_accum"):
+                with device_scope("grad_accum"):
                     w = aux["weight"]
                     grads = jax.tree_util.tree_map(lambda g: g * w, grads)
                     return (loss_acc + loss * w, _tree_add(grad_acc, grads),
@@ -500,20 +515,26 @@ def accelerate(
                      "moe_picks_held": jnp.sum}
             moe_stats = {k: worst.get(k, jnp.mean)(v)
                          for k, v in moe_stats.items()}
-            with jax.named_scope("grad_accum"):
+            with device_scope("grad_accum"):
                 inv = 1.0 / w_sum
                 loss = loss_sum * inv
                 grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
         else:
-            with jax.named_scope("loss_and_grad"):
+            with device_scope("loss_and_grad"):
                 (loss, aux), grads = grad_fn(state.params, batch)
             moe_stats = aux.get("moe", {})
         # clipping is the first link of the optimizer chain (see above)
-        with jax.named_scope("optimizer"):
+        with device_scope("optimizer"):
             new_state = state.apply_gradients(grads=grads)
+        # with clipping on this is the clip's own reduction (XLA computes
+        # the two once, and the one that stays is the clip's): a scope of
+        # its own would name nothing in the compiled step
+        with device_scope(
+                "grad_norm" if config.max_grad_norm is None else "clip"):
+            grad_norm = optax.global_norm(grads)
         metrics = {
             "loss": loss,
-            "grad_norm": optax.global_norm(grads),
+            "grad_norm": grad_norm,
             "step": new_state.step,
             # routing statistics of a MoE model (models/moe.py), else none
             **moe_stats,

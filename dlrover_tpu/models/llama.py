@@ -33,6 +33,7 @@ from jax.ad_checkpoint import checkpoint_name
 from dlrover_tpu.accel.parallel.mesh import (ambient_mesh,
                                               with_logical_constraint)
 from dlrover_tpu.ops.attention import dot_product_attention
+from dlrover_tpu.utils.profiler import device_scope
 
 Dtype = Any
 
@@ -616,28 +617,39 @@ class Attention(nn.Module):
             name="o_proj",
         )
 
-        q = q_proj(x)
-        k = k_proj(x)
-        v = v_proj(x)
-        if cfg.qk_norm:
-            def whole(name, y):
-                flat = y.reshape(*y.shape[:-2], y.shape[-2] * y.shape[-1])
-                return RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
-                               name=name)(flat).reshape(y.shape)
+        # ``attn_proj`` is everything of the block AROUND the attention
+        # call (projections, QK-norm, RoPE, the gate), never the call: an
+        # unnamed kernel takes the innermost scope's name (``attn.<n>``,
+        # ``attn_full.<n>``), which is how its time is found in a trace
+        with device_scope("attn_proj"):
+            q = q_proj(x)
+            k = k_proj(x)
+            v = v_proj(x)
+            if cfg.qk_norm:
+                def whole(name, y):
+                    flat = y.reshape(
+                        *y.shape[:-2], y.shape[-2] * y.shape[-1])
+                    return RMSNorm(cfg.rms_norm_eps, cfg.dtype,
+                                   cfg.param_dtype,
+                                   name=name)(flat).reshape(y.shape)
 
-            q, k = whole("q_norm", q), whole("k_norm", k)
-        q = with_logical_constraint(q, ("batch", "seq", "heads", "head_dim"))
-        k = with_logical_constraint(k, ("batch", "seq", "kv_heads", "head_dim"))
-        v = with_logical_constraint(v, ("batch", "seq", "kv_heads", "head_dim"))
+                q, k = whole("q_norm", q), whole("k_norm", k)
+            q = with_logical_constraint(
+                q, ("batch", "seq", "heads", "head_dim"))
+            k = with_logical_constraint(
+                k, ("batch", "seq", "kv_heads", "head_dim"))
+            v = with_logical_constraint(
+                v, ("batch", "seq", "kv_heads", "head_dim"))
 
-        if spec is None:
-            angles = rope_frequencies(d, cfg.max_seq_len, cfg.rope_theta)[positions]
-            q = checkpoint_name(apply_rope(q, angles), "qkv_proj")
-            k = checkpoint_name(apply_rope(k, angles), "qkv_proj")
-        else:  # the kind's table, made once a step by the model
-            q = checkpoint_name(apply_rope_table(q, rope), "qkv_proj")
-            k = checkpoint_name(apply_rope_table(k, rope), "qkv_proj")
-        v = checkpoint_name(v, "qkv_proj")
+            if spec is None:
+                angles = rope_frequencies(
+                    d, cfg.max_seq_len, cfg.rope_theta)[positions]
+                q = checkpoint_name(apply_rope(q, angles), "qkv_proj")
+                k = checkpoint_name(apply_rope(k, angles), "qkv_proj")
+            else:  # the kind's table, made once a step by the model
+                q = checkpoint_name(apply_rope_table(q, rope), "qkv_proj")
+                k = checkpoint_name(apply_rope_table(k, rope), "qkv_proj")
+            v = checkpoint_name(v, "qkv_proj")
 
         if decode:
             # KV-cache decode: append this call's K/V at the caller-given
@@ -679,30 +691,33 @@ class Attention(nn.Module):
             out = jnp.einsum(
                 "bhqk,bkhd->bqhd", probs, vv.astype(jnp.float32)
             ).astype(x.dtype)
-            return o_proj(out)
+            with device_scope("attn_proj"):
+                return o_proj(out)
 
         if spec is None:
             out = dot_product_attention(
                 q, k, v, causal=True, segment_ids=segment_ids)
         else:
-            with jax.named_scope(
+            with device_scope(
                     "attn_window" if spec.window else "attn_full"):
                 out = dot_product_attention(
                     q, k, v, causal=True, segment_ids=segment_ids,
                     window=spec.window or None)
-        if cfg.attn_head_gate:
-            gate = nn.DenseGeneral(
-                num_heads, use_bias=False, dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype, dot_general=cfg.dot_general,
-                kernel_init=nn.with_logical_partitioning(
-                    init, ("embed", "heads")),
-                name="g_proj",
-            )(x)
-            out = (out.astype(jnp.float32) * jax.nn.sigmoid(
-                gate.astype(jnp.float32))[..., None]).astype(out.dtype)
-        out = checkpoint_name(out, "attn_out")
-        out = with_logical_constraint(out, ("batch", "seq", "heads", "head_dim"))
-        return o_proj(out)
+        with device_scope("attn_proj"):
+            if cfg.attn_head_gate:
+                gate = nn.DenseGeneral(
+                    num_heads, use_bias=False, dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype, dot_general=cfg.dot_general,
+                    kernel_init=nn.with_logical_partitioning(
+                        init, ("embed", "heads")),
+                    name="g_proj",
+                )(x)
+                out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))[..., None]).astype(out.dtype)
+            out = checkpoint_name(out, "attn_out")
+            out = with_logical_constraint(
+                out, ("batch", "seq", "heads", "head_dim"))
+            return o_proj(out)
 
 
 class MLP(nn.Module):
@@ -721,16 +736,18 @@ class MLP(nn.Module):
         )
         # (the lm_head stays bf16 — the last projection is the standard
         # fp8-recipe exclusion: logit quantization hurts loss directly)
-        gate = dense(cfg.intermediate_size, ("embed", "mlp"), "gate_proj")(x)
-        up = dense(cfg.intermediate_size, ("embed", "mlp"), "up_proj")(x)
-        h = nn.silu(gate) * up
-        h = with_logical_constraint(h, ("batch", "seq", "mlp"))
-        # Deliberately NOT checkpoint-named: the wide [.., intermediate]
-        # tensors dominate saved-activation memory; the "names" remat
-        # policy recomputes them in backward instead of storing them.
-        return checkpoint_name(
-            dense(cfg.hidden_size, ("mlp", "embed"), "down_proj")(h), "mlp_out"
-        )
+        with device_scope("mlp"):
+            gate = dense(
+                cfg.intermediate_size, ("embed", "mlp"), "gate_proj")(x)
+            up = dense(cfg.intermediate_size, ("embed", "mlp"), "up_proj")(x)
+            h = nn.silu(gate) * up
+            h = with_logical_constraint(h, ("batch", "seq", "mlp"))
+            # Deliberately NOT checkpoint-named: the wide [.., intermediate]
+            # tensors dominate saved-activation memory; the "names" remat
+            # policy recomputes them in backward instead of storing them.
+            return checkpoint_name(
+                dense(cfg.hidden_size, ("mlp", "embed"), "down_proj")(h),
+                "mlp_out")
 
 
 class DecoderLayer(nn.Module):
@@ -751,12 +768,17 @@ class DecoderLayer(nn.Module):
         """``stacked``: a sparse layer's expert weights as the scan over
         layers stacks them, and the layer's index (``MoEMLP``)."""
         cfg, spec = self.config, self.spec
-        h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="input_norm")(x)
+        # the layer's two norms count with the projections (``attn_proj``)
+        with device_scope("attn_proj"):
+            h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
+                        name="input_norm")(x)
         x = x + Attention(cfg, spec, name="attn")(
             h, positions, segment_ids, decode=decode, cache_len=cache_len,
             rope=rope)
         x = with_logical_constraint(x, ("batch", "seq", "act_embed"))
-        h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="post_norm")(x)
+        with device_scope("attn_proj"):
+            h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
+                        name="post_norm")(x)
         if cfg.num_experts and (spec is None or spec.mlp == "sparse"):
             from dlrover_tpu.models.moe import MoEMLP
 
@@ -874,7 +896,8 @@ class LlamaModel(nn.Module):
             ),
             name="embed_tokens",
         )
-        x = embed(input_ids)
+        with device_scope("embed"):
+            x = embed(input_ids)
         x = with_logical_constraint(x, ("batch", "seq", "act_embed"))
 
         if decode and cfg.scan_layers:
@@ -921,28 +944,31 @@ class LlamaModel(nn.Module):
                 x = layer_cls(cfg, name=f"layer_{i}")(x, positions,
                                                       segment_ids)
 
-        x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="final_norm")(x)
+        with device_scope("head"):
+            x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
+                        name="final_norm")(x)
 
-        if return_hidden:
-            return x
+            if return_hidden:
+                return x
 
-        if cfg.tie_embeddings:
-            logits = embed.attend(x.astype(cfg.param_dtype))
-        else:
-            lm_head = nn.DenseGeneral(
-                cfg.vocab_size,
-                use_bias=False,
-                dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype,
-                kernel_init=nn.with_logical_partitioning(
-                    nn.initializers.lecun_normal(), ("embed", "vocab")
-                ),
-                name="lm_head",
-            )
-            logits = lm_head(x)
-        if cfg.logit_scale != 1.0:
-            logits = logits * cfg.logit_scale
-        return with_logical_constraint(logits, ("batch", "seq", "vocab"))
+            if cfg.tie_embeddings:
+                logits = embed.attend(x.astype(cfg.param_dtype))
+            else:
+                lm_head = nn.DenseGeneral(
+                    cfg.vocab_size,
+                    use_bias=False,
+                    dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype,
+                    kernel_init=nn.with_logical_partitioning(
+                        nn.initializers.lecun_normal(), ("embed", "vocab")
+                    ),
+                    name="lm_head",
+                )
+                logits = lm_head(x)
+            if cfg.logit_scale != 1.0:
+                logits = logits * cfg.logit_scale
+            return with_logical_constraint(
+                logits, ("batch", "seq", "vocab"))
 
     def _mixed_layers(self, x, positions, segment_ids, decode):
         """The layers of a model that has more than one kind: the leading
@@ -954,8 +980,9 @@ class LlamaModel(nn.Module):
             raise NotImplementedError(
                 "KV-cache decode knows one kind of layer (ROADMAP A3, A4)")
         specs, kinds = cfg.layer_specs, cfg.rope_kinds
-        ropes = tuple(rope_table(kind, cfg.head_dim_, positions)
-                      for kind in kinds)
+        with device_scope("attn_proj"):
+            ropes = tuple(rope_table(kind, cfg.head_dim_, positions)
+                          for kind in kinds)
         lead, period = (layer_pattern(specs) if cfg.scan_layers
                         else (len(specs), 0))
         layer_cls = _layer_class(cfg, in_scan=False)

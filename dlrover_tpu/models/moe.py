@@ -63,6 +63,7 @@ from jax.sharding import PartitionSpec
 
 from dlrover_tpu.accel.parallel.mesh import (ambient_mesh,
                                               with_logical_constraint)
+from dlrover_tpu.utils.profiler import device_scope
 
 
 # (rows, contraction, columns) of one tile of the grouped matmul.  Chosen on
@@ -420,7 +421,7 @@ class MoEMLP(nn.Module):
             "w_down", (held, h, m), ("expert", "mlp", "embed")
         )
 
-        with jax.named_scope("moe_route"):
+        with device_scope("moe_route"):
             logits = router(x).reshape(t, e)  # f32
             bias = None
             if self.select_bias:
@@ -450,14 +451,15 @@ class MoEMLP(nn.Module):
         if self.experts_held is not None:
             # this share's picks ahead of all others, by held expert; the
             # groups are the held experts', and end where the others start
-            local = picks - first
-            is_held = jnp.logical_and(local >= 0, local < held)
-            picks = jnp.where(is_held, local, held)
-            counts = counts[first:first + held]
+            with device_scope("moe_route"):
+                local = picks - first
+                is_held = jnp.logical_and(local >= 0, local < held)
+                picks = jnp.where(is_held, local, held)
+                counts = counts[first:first + held]
             self.sow("moe_losses", "held_counts", counts,
                      reduce_fn=lambda _, new: new, init_fn=lambda: None)
 
-        with jax.named_scope("moe_dispatch"):
+        with device_scope("moe_dispatch"):
             order = jnp.argsort(picks, stable=True)  # expert-major
             inverse = jnp.argsort(order)
             xs = _to_expert_order(
@@ -478,7 +480,7 @@ class MoEMLP(nn.Module):
         def lies_in(name):
             return stacked and (stacked[0][name], stacked[1])
 
-        with jax.named_scope("moe_experts"):
+        with device_scope("moe_experts"):
             wg = fake_quant_fp8(w_gate.astype(self.dtype))
             wu = fake_quant_fp8(w_up.astype(self.dtype))
             wd = fake_quant_fp8(w_down.astype(self.dtype))
@@ -495,16 +497,17 @@ class MoEMLP(nn.Module):
             out = grad_quant_fp8(grouped_matmul(
                 fake_quant_fp8(act), wd, counts, lies_in("w_down")))
 
-        with jax.named_scope("moe_combine"):
+        with device_scope("moe_combine"):
             if self.experts_held is not None:
                 out = jnp.where(live, out, jnp.zeros((), out.dtype))
             out = _to_token_order(out, order, inverse).reshape(t, k, m)
             y = jnp.sum(out.astype(jnp.float32) * top_p[..., None], axis=1)
         if self.shared_width:
-            with jax.named_scope("moe_shared"):
+            with device_scope("moe_shared"):
                 y = y + self._shared_expert(x).reshape(t, m)
-        y = y.astype(self.dtype).reshape(b, s, m)
-        return with_logical_constraint(y, ("batch", "seq", "act_embed"))
+        with device_scope("moe_combine"):
+            y = y.astype(self.dtype).reshape(b, s, m)
+            return with_logical_constraint(y, ("batch", "seq", "act_embed"))
 
     def _shared_expert(self, x: jax.Array) -> jax.Array:
         """The dense SwiGLU expert every token visits: matmuls in

@@ -44,7 +44,14 @@ from dlrover_tpu.models.llama import LlamaConfig
 from dlrover_tpu.rl.generation import select_token
 from dlrover_tpu.serving.model import decode_step, prefill
 from dlrover_tpu.serving.params import serving_params_from_llama
-from dlrover_tpu.utils.profiler import span, spanned
+from dlrover_tpu.utils.profiler import (
+    abstract,
+    device_scope,
+    program_texts,
+    register_program,
+    span,
+    spanned,
+)
 
 # dlint DL012 contract: a lifetime allocation is owned by the admitting
 # path until it is bound to a slot (whose release funnel is
@@ -678,12 +685,14 @@ class InferenceEngine:
                 cache = dict(cache)
                 seen = cache.pop("witness", None)
                 key, sub = jax.random.split(key)
-                nxt = select_token(logits, sub, temperature, top_k, top_p)
+                with device_scope("pick"):
+                    nxt = select_token(
+                        logits, sub, temperature, top_k, top_p)
                 toks = jnp.where(active, nxt.astype(toks.dtype), toks)
                 pos = jnp.where(active, pos + 1, pos)
                 return (toks, pos, cache, key), (nxt, seen)
 
-            with jax.named_scope("decode_chunk"):
+            with device_scope("decode_chunk"):
                 (tokens, positions, cache, rng), (out, witness) = \
                     jax.lax.scan(
                         step, (tokens, positions, cache, rng), None,
@@ -717,7 +726,7 @@ class InferenceEngine:
             serves every skip value; the dense layout has no sharing
             and ignores it."""
             lp = tokens.shape[1]
-            with jax.named_scope("prefill"):
+            with device_scope("prefill"):
                 logits, ks, vs = prefill(params, cfg, tokens, real_len)
             if paged and kv_quant:
                 from dlrover_tpu.serving.paged import (
@@ -765,7 +774,9 @@ class InferenceEngine:
                     ],
                 }
             rng, sub = jax.random.split(rng)
-            first = select_token(logits, sub, temperature, top_k, top_p)
+            with device_scope("pick"):
+                first = select_token(
+                    logits, sub, temperature, top_k, top_p)
             return new_cache, first, rng, last.at[slots].set(
                 first.astype(last.dtype))
 
@@ -788,15 +799,16 @@ class InferenceEngine:
                 a host fact): ``last`` (``insert_fn``) comes back with
                 it at that row's slot, and with the parked slot's 0 at
                 the others."""
-                with jax.named_scope("prefill_chunk"):
+                with device_scope("prefill_chunk"):
                     logits, cache = verify_step(
                         params, cfg, cache, tokens, start,
                         slots=slots, logits_index=last_idx)
                 cache = dict(cache)
                 witness = cache.pop("witness", None)
                 rng, sub = jax.random.split(rng)
-                first = select_token(
-                    logits[:, 0, :], sub, temperature, top_k, top_p)
+                with device_scope("pick"):
+                    first = select_token(
+                        logits[:, 0, :], sub, temperature, top_k, top_p)
                 last = last.at[slots].set(
                     jnp.where(final, first.astype(last.dtype), 0))
                 return cache, first, rng, witness, last
@@ -811,16 +823,17 @@ class InferenceEngine:
             @functools.partial(jax.jit, donate_argnums=(1,))
             def spec_fn(params, cache, tokens, positions, draft_len,
                         rng):
-                with jax.named_scope("verify"):
+                with device_scope("verify"):
                     logits, cache = verify_step(
                         params, cfg, cache, tokens, positions)
                 cache = dict(cache)
                 cache.pop("witness", None)
                 rng, sub = jax.random.split(rng)
-                out, n_commit = rejection_commit(
-                    logits, tokens[:, 1:], draft_len, sub,
-                    temperature=temperature, top_k=top_k, top_p=top_p,
-                )
+                with device_scope("pick"):
+                    out, n_commit = rejection_commit(
+                        logits, tokens[:, 1:], draft_len, sub,
+                        temperature=temperature, top_k=top_k, top_p=top_p,
+                    )
                 return out, n_commit, cache, rng
 
             self._spec_fn = spec_fn
@@ -838,7 +851,11 @@ class InferenceEngine:
         all zero, which routes every write to the trash block (the
         dense layout's junk lands in rows the next admission
         overwrites or masks), no slot is active, and the sampling key
-        is left as it was.  Returns the number of programs run."""
+        is left as it was.  Each program is registered as it is run
+        (``utils/profiler.register_program``: ``decode_chunk``,
+        ``verify``, ``prefill_chunk.g<G>``, ``prefill.g<G>.b<bucket>``),
+        so a trace of this engine can be read by the program's own
+        device scopes.  Returns the number of programs run."""
         assert not self._queue and all(
             r is None for r in self._slot_req), "warmup needs an idle engine"
         rng, b = self._rng, self.max_slots
@@ -846,12 +863,21 @@ class InferenceEngine:
         def zeros(*shape):
             return jnp.zeros(shape, jnp.int32)
 
-        _, _, _, self._cache, _, _ = self._chunk_fn(
+        def run(label, fn, *args):
+            # the program's text on demand (``program_scopes``), over the
+            # arguments' shapes: no weight or pool is kept for it
+            shapes = abstract(args)
+            register_program(label, lambda: program_texts(fn, *shapes))
+            return fn(*args)
+
+        _, _, _, self._cache, _, _ = run(
+            "decode_chunk", self._chunk_fn,
             self.params, self._cache, zeros(b), zeros(b),
             jnp.zeros(b, bool), rng)
         ran = 1
         if self._spec_fn is not None:
-            _, _, self._cache, _ = self._spec_fn(
+            _, _, self._cache, _ = run(
+                "verify", self._spec_fn,
                 self.params, self._cache, zeros(b, self.speculative_k),
                 zeros(b), zeros(b), rng)
             ran += 1
@@ -861,13 +887,15 @@ class InferenceEngine:
         for g in range(1, b + 1):
             slots = jnp.arange(g, dtype=jnp.int32)
             if chunked and g <= self._prefill_group:
-                self._cache, _, _, _, _ = self._prefill_chunk_fn(
+                self._cache, _, _, _, _ = run(
+                    f"prefill_chunk.g{g}", self._prefill_chunk_fn,
                     self.params, self._cache,
                     zeros(g, self.prefill_chunk), zeros(g), slots,
                     zeros(g), rng, zeros(b), jnp.zeros(g, bool))
                 ran += 1
             for bucket in buckets:
-                self._cache, _, _, _ = self._insert_fn(
+                self._cache, _, _, _ = run(
+                    f"prefill.g{g}.b{bucket}", self._insert_fn,
                     self.params, self._cache, zeros(g, bucket),
                     jnp.ones(g, jnp.int32), slots, zeros(g), rng,
                     zeros(b))

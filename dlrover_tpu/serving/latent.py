@@ -52,6 +52,7 @@ from dlrover_tpu.models.llama import LlamaConfig
 from dlrover_tpu.models.moe import grouped_matmul, route
 from dlrover_tpu.serving.model import _lm_head, _mm, _rmsnorm
 from dlrover_tpu.serving.paged import scatter_tokens
+from dlrover_tpu.utils.profiler import device_scope
 
 #: pages of the pools one key block of the query-run path holds
 KEY_BLOCK_PAGES = 8
@@ -108,7 +109,7 @@ def _projections(lp, h, cfg: LlamaConfig, pos, dtype):
     b, k = h.shape[:2]
     c, r = cfg.kv_lora_rank, cfg.qk_rope_head_dim
     nope, heads = cfg.qk_nope_head_dim, cfg.num_heads
-    with jax.named_scope("mla_proj"):
+    with device_scope("mla_proj"):
         c_q = _rmsnorm(_mm(h, lp["wq_a"], dtype), lp["q_a_norm"],
                        cfg.rms_norm_eps).astype(dtype)
         q = _mm(c_q, lp["wq_b"], dtype).reshape(b, k, heads, nope + r)
@@ -129,7 +130,7 @@ def _projections(lp, h, cfg: LlamaConfig, pos, dtype):
     q_i = k_i = w = None
     if cfg.index_topk:
         hi, di = cfg.index_n_heads, cfg.index_head_dim
-        with jax.named_scope("dsa_index"):
+        with device_scope("dsa_index"):
             q_i = rope_pairs(
                 _mm(c_q, lp["iwq"], dtype).reshape(b, k, hi, di),
                 pos, cfg.rope_theta, r).astype(dtype)
@@ -197,7 +198,7 @@ def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
     if cfg.index_topk and width > cfg.index_topk:
         from dlrover_tpu.ops.pallas.paged_index import index_scores
 
-        with jax.named_scope("dsa_index"):
+        with device_scope("dsa_index"):
             def score_block(j, keys):
                 s = index_scores(q_i, w, block(index_pool, j))
                 s = _orderable(jnp.where(causal(j), s, _NEG_INF))
@@ -208,14 +209,14 @@ def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
             keys = jax.lax.fori_loop(
                 0, n_live, score_block,
                 jnp.full((klen, width), dead, jnp.uint32))
-        with jax.named_scope("dsa_select"):
+        with device_scope("dsa_select"):
             chosen = (keys >= _kth_largest(keys, cfg.index_topk)[:, None]
                       ) & (keys > dead)
     else:
         # no more keys than a query may choose: plain causal attention
         chosen = jnp.arange(width)[None, :] <= q_pos[:, None]
 
-    with jax.named_scope("mla_attn"):
+    with device_scope("mla_attn"):
         def attend_block(j, carry):
             m, l, acc = carry
             lat = block(latent_pool, j)                       # [kb, C+R]
@@ -252,26 +253,26 @@ def _attend_decode(qq, q_i, w, latent_pool, index_pool, table, lengths,
     b, mb = table.shape
     bs, c = latent_pool.shape[1], cfg.kv_lora_rank
     if cfg.index_topk and mb * bs > cfg.index_topk:
-        with jax.named_scope("dsa_index"):
+        with device_scope("dsa_index"):
             if impl == "pallas":
                 scores = paged_index.paged_index_scores(
                     q_i, w, index_pool, table, lengths, interpret=interpret)
             else:
                 scores = paged_index.gather_index_scores(
                     q_i, w, index_pool, table, lengths)
-        with jax.named_scope("dsa_select"):
+        with device_scope("dsa_select"):
             top, pos = jax.lax.top_k(scores, cfg.index_topk)  # [B, topk]
             valid = top > _NEG_INF
     else:
         pos = jnp.broadcast_to(jnp.arange(mb * bs), (b, mb * bs))
         valid = pos < lengths[:, None]
-    with jax.named_scope("dsa_select"):
+    with device_scope("dsa_select"):
         page = jnp.take_along_axis(
             table, jnp.minimum(pos // bs, mb - 1), axis=1)
         flat = jnp.where(valid, page * bs + pos % bs, 0)
         rows = jnp.take(latent_pool.reshape(-1, latent_pool.shape[-1]),
                         flat, axis=0)                         # [B, S, C+R]
-    with jax.named_scope("mla_attn"):
+    with device_scope("mla_attn"):
         s = jnp.einsum("bhc,bsc->bhs", qq, rows.astype(qq.dtype),
                        preferred_element_type=jnp.float32
                        ) * _softmax_scale(cfg)
@@ -287,7 +288,7 @@ def _attend_decode(qq, q_i, w, latent_pool, index_pool, table, lengths,
 
 def _attn_out(lp, o_lat, cfg: LlamaConfig, dtype):
     """Attended latents [B, K, H, C] -> the block's output [B, K, E]."""
-    with jax.named_scope("mla_attn"):
+    with device_scope("mla_attn"):
         o = jnp.einsum("bkhc,hcv->bkhv", o_lat.astype(dtype),
                        lp["wkv_b_v"].astype(dtype),
                        preferred_element_type=jnp.float32).astype(dtype)
@@ -311,7 +312,7 @@ def sparse_mlp(lp, h, cfg: LlamaConfig, dtype, counted):
     t, k = b * klen, cfg.moe_top_k
     first, held = cfg.moe_experts_held or (0, cfg.num_experts)
     x = h.reshape(t, e)
-    with jax.named_scope("moe_route"):
+    with device_scope("moe_route"):
         logits = jnp.dot(x.astype(jnp.float32), lp["router"],
                          precision=jax.lax.Precision.HIGHEST)
         top_p, top_e, _ = route(
@@ -324,7 +325,7 @@ def sparse_mlp(lp, h, cfg: LlamaConfig, dtype, counted):
         rows = jnp.repeat(counted.reshape(t), k)
         picks = jnp.stack([jnp.sum(rows), jnp.sum(rows & is_held)]
                           ).astype(jnp.uint32)
-    with jax.named_scope("moe_experts"):
+    with device_scope("moe_experts"):
         order = jnp.argsort(group, stable=True)
         xs = x.astype(dtype)[order // k]
         gate = grouped_matmul(xs, lp["w_gate"].astype(dtype), sizes)
@@ -337,7 +338,7 @@ def sparse_mlp(lp, h, cfg: LlamaConfig, dtype, counted):
         out = out[jnp.argsort(order)].reshape(t, k, e)
         y = jnp.sum(out.astype(jnp.float32) * top_p[..., None], axis=1)
     if "shared_wgu" in lp:
-        with jax.named_scope("moe_shared"):
+        with device_scope("moe_shared"):
             y = y + _swiglu(x, lp["shared_wgu"], lp["shared_down"],
                             dtype).astype(jnp.float32)
     return y.astype(dtype).reshape(b, klen, e), picks
@@ -346,7 +347,8 @@ def sparse_mlp(lp, h, cfg: LlamaConfig, dtype, counted):
 def _mlp(lp, h, cfg: LlamaConfig, dtype, counted):
     if "router" in lp:
         return sparse_mlp(lp, h, cfg, dtype, counted)
-    return _swiglu(h, lp["wgu"], lp["down"], dtype), None
+    with device_scope("mlp"):
+        return _swiglu(h, lp["wgu"], lp["down"], dtype), None
 
 
 def _pad_table(table: jax.Array, pages: int) -> jax.Array:

@@ -39,6 +39,7 @@ from dlrover_tpu.models.llama import LlamaConfig, apply_rope, rope_frequencies
 from dlrover_tpu.ops.attention import dot_product_attention
 
 from dlrover_tpu.rl.generation import select_token
+from dlrover_tpu.utils.profiler import device_scope, device_scoped
 
 
 def _mm(x: jax.Array, w: Any, dtype) -> jax.Array:
@@ -87,6 +88,7 @@ def _split_heads(x: jax.Array, n_heads: int, d: int) -> jax.Array:
     return x.reshape(b, t, n_heads, d)
 
 
+@device_scoped("kv_write")
 def _write_cache(cache: jax.Array, kv: jax.Array,
                  positions: jax.Array) -> jax.Array:
     """Per-row BLOCK scatter: writes kv[b]'s full K-token run at
@@ -116,7 +118,7 @@ def _qkv_split(cfg: LlamaConfig, qkv: jax.Array):
     )
 
 
-def _attn_proj(lp, h, cfg: LlamaConfig, dtype):
+def _qkv(lp, h, cfg: LlamaConfig, dtype):
     """q/k/v projections for either param layout: fused ``wqkv``
     (single-chip decode: fewer, larger launches) or unfused
     ``wq/wk/wv`` (tensor-parallel serving: per-matrix column sharding
@@ -141,6 +143,20 @@ def _attn_proj(lp, h, cfg: LlamaConfig, dtype):
     )
 
 
+@device_scoped("attn_proj")
+def _attn_proj(lp, h, cfg: LlamaConfig, dtype, angles):
+    """The block's q, k (rotated by ``angles``) and v."""
+    q, k, v = _qkv(lp, h, cfg, dtype)
+    return apply_rope(q, angles), apply_rope(k, angles), v
+
+
+@device_scoped("attn_proj")
+def _attn_out(lp, o, x, dtype):
+    """The residual stream with the attended heads projected back in."""
+    return x + _mm(o, lp["wo"], dtype)
+
+
+@device_scoped("mlp")
 def _mlp(lp, h, cfg: LlamaConfig, dtype):
     f = cfg.intermediate_size
     if "wgu" in lp:
@@ -305,9 +321,7 @@ def verify_step(
     for i in range(cfg.num_layers):
         lp = _layer_weights(params["layers"], i)
         h = _rmsnorm(x, lp["input_norm"], cfg.rms_norm_eps).astype(dtype)
-        q, k, v = _attn_proj(lp, h, cfg, dtype)
-        q = apply_rope(q, angles)
-        k = apply_rope(k, angles)
+        q, k, v = _attn_proj(lp, h, cfg, dtype, angles)
         ck = cv = None
         if paged and quant:
             kp, ksc = scatter_q(
@@ -317,10 +331,11 @@ def verify_step(
                 cache["v_pool"][i], cache["v_scale"][i], table,
                 v, positions)
             if use_kernel:
-                o = paged_decode_attention(
-                    q[:, 0], kp, vp, table, lengths,
-                    k_scale=ksc, v_scale=vsc,
-                    interpret=kernel_interpret)[:, None]
+                with device_scope("paged_attn"):
+                    o = paged_decode_attention(
+                        q[:, 0], kp, vp, table, lengths,
+                        k_scale=ksc, v_scale=vsc,
+                        interpret=kernel_interpret)[:, None]
             else:
                 ck = gather_q(kp, ksc, table, dtype)
                 cv = gather_q(vp, vsc, table, dtype)
@@ -336,9 +351,10 @@ def verify_step(
                                 v.astype(cache["v_pool"][i].dtype),
                                 positions)
             if use_kernel:
-                o = paged_decode_attention(
-                    q[:, 0], kp, vp, table, lengths,
-                    interpret=kernel_interpret)[:, None]
+                with device_scope("paged_attn"):
+                    o = paged_decode_attention(
+                        q[:, 0], kp, vp, table, lengths,
+                        interpret=kernel_interpret)[:, None]
             else:
                 ck = gather_blocks(kp, table)
                 cv = gather_blocks(vp, table)
@@ -364,7 +380,7 @@ def verify_step(
         if not use_kernel:
             o = _attn_verify(q, ck, cv, positions, n_rep)
         o = o.astype(dtype).reshape(b, klen, cfg.num_heads * d)
-        x = x + _mm(o, lp["wo"], dtype)
+        x = _attn_out(lp, o, x, dtype)
         h = _rmsnorm(x, lp["post_norm"], cfg.rms_norm_eps).astype(dtype)
         x = x + _mlp(lp, h, cfg, dtype)
 
@@ -384,6 +400,7 @@ def verify_step(
     return logits, out_cache
 
 
+@device_scoped("paged_attn")
 def _attn_verify(
     q: jax.Array,            # [B, K, H, D]
     cache_k: jax.Array,      # [B, L, KV, D]
@@ -421,6 +438,7 @@ def _attn_verify(
     return out.reshape(b, qlen, h, d)
 
 
+@device_scoped("head")
 def _lm_head(params, x, cfg: LlamaConfig) -> jax.Array:
     # compute dtype mirrors the training module (models/llama.py lm_head:
     # bf16 matmul; tied path attends in param_dtype) so greedy decode
@@ -466,13 +484,13 @@ def prefill(
     for i in range(cfg.num_layers):
         lp = _layer_weights(params["layers"], i)
         h = _rmsnorm(x, lp["input_norm"], cfg.rms_norm_eps).astype(dtype)
-        q, k, v = _attn_proj(lp, h, cfg, dtype)
-        q = apply_rope(q, angles)
-        k = apply_rope(k, angles)
+        q, k, v = _attn_proj(lp, h, cfg, dtype, angles)
+        # no scope of its own around the call: the flash kernel has no
+        # name and takes the innermost one's (utils/profiler.device_scope)
         o = dot_product_attention(q, k, v, causal=True,
                                   sp_ulysses=False).astype(dtype)
         o = o.reshape(o.shape[0], lp_len, cfg.num_heads * d)
-        x = x + _mm(o, lp["wo"], dtype)
+        x = _attn_out(lp, o, x, dtype)
         h = _rmsnorm(x, lp["post_norm"], cfg.rms_norm_eps).astype(dtype)
         x = x + _mlp(lp, h, cfg, dtype)
         ks.append(k)

@@ -56,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from dlrover_tpu.serving.prefixcache import PrefixBlockIndex, chain_key
+from dlrover_tpu.utils.profiler import device_scoped
 
 # legacy alias: the chained digest moved to serving/prefixcache (the
 # router computes routing heads with the SAME function)
@@ -317,6 +318,7 @@ class BlockManager:
 
 
 # ---------------------------------------------------------------- device
+@device_scoped("paged_attn")
 def gather_blocks(pool: jax.Array, table: jax.Array) -> jax.Array:
     """``pool [NB, bs, KV, D] x table [B, MB] -> [B, MB*bs, KV, D]`` —
     the dense per-slot view the attention kernels consume."""
@@ -356,6 +358,7 @@ def _block_offsets(table: jax.Array, positions: jax.Array,
     return bidx, pos % bs
 
 
+@device_scoped("kv_write")
 def scatter_tokens(
     pool: jax.Array,        # [NB, bs, KV, D]
     table: jax.Array,       # [B, MB]
@@ -406,6 +409,7 @@ def kv_budget_multiplier(ref_dtype, head_dim: int,
     return ref / (code_bytes + jnp.dtype(KV_SCALE_DTYPE).itemsize)
 
 
+@device_scoped("kv_write")
 def scatter_tokens_q(
     pool: jax.Array,        # [NB, bs, KV, D] int8 codes
     scale_pool: jax.Array,  # [NB, bs, KV] per-vector scales
@@ -431,6 +435,7 @@ def scatter_tokens_q(
     )
 
 
+@device_scoped("paged_attn")
 def gather_blocks_q(
     pool: jax.Array,        # [NB, bs, KV, D] int8 codes
     scale_pool: jax.Array,  # [NB, bs, KV]
@@ -453,6 +458,7 @@ def gather_blocks_q(
     )
 
 
+@device_scoped("kv_write")
 def scatter_tokens_q4(
     pool: jax.Array,        # [NB, bs, KV, D//2] packed int4 codes
     scale_pool: jax.Array,  # [NB, bs, KV] per-vector scales
@@ -479,6 +485,7 @@ def scatter_tokens_q4(
     )
 
 
+@device_scoped("paged_attn")
 def gather_blocks_q4(
     pool: jax.Array,        # [NB, bs, KV, D//2] packed int4 codes
     scale_pool: jax.Array,  # [NB, bs, KV]

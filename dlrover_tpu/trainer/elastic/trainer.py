@@ -47,7 +47,13 @@ from dlrover_tpu.trainer.flash_checkpoint import (
     StorageType,
 )
 from dlrover_tpu.utils.compile_cache import ensure_compile_cache
-from dlrover_tpu.utils.profiler import span, step_span
+from dlrover_tpu.utils.profiler import (
+    abstract,
+    program_texts,
+    register_program,
+    span,
+    step_span,
+)
 
 # accelerate() results keyed by (mesh dims, accum, batch shape, seq, model
 # id) — a restarted process starts cold, but within one process an
@@ -129,6 +135,14 @@ def expert_weight_copies(step_text: str) -> int:
     return len(copies)
 
 
+def _step_texts(res: AccelerateResult, shapes) -> Tuple[str, str]:
+    """``utils/profiler.program_texts`` of the train step as compiled
+    for ``res``'s mesh over ``shapes`` = (state, batch), arrays or their
+    ``abstract`` shapes: a compile-cache hit once the step has run."""
+    with logical_rules_context(res.config.logical_rules), res.mesh:
+        return program_texts(res.jit_train_step, *shapes)
+
+
 class ElasticTrainer:
     """Drives fixed-global-batch training across elastic restarts.
 
@@ -186,6 +200,9 @@ class ElasticTrainer:
         if self._ckpt is not None:
             self._install_flush_on_term()
         self.result: Optional[AccelerateResult] = None
+        # how a step is dispatched: ``_first_step`` after ``prepare``,
+        # then the jitted step itself
+        self._step: Optional[Callable] = None
         self.plan: Optional[ElasticBatchPlan] = None
         self.state: Any = None
         from dlrover_tpu.utils.profiler import StepTimer
@@ -260,6 +277,7 @@ class ElasticTrainer:
                 devices=devices,
             )
             _COMPILE_CACHE[key] = self.result
+        self._step = self._first_step
         logger.info(
             "ElasticTrainer prepared: mesh=%s accum=%s micro_global=%s",
             spec.dims, self.plan.grad_accum_steps, self.plan.micro_batch_global,
@@ -444,10 +462,19 @@ class ElasticTrainer:
         kernel is a ``tpu_custom_call``, FSDP is all-gather /
         reduce-scatter."""
         assert self.state is not None, "call restore_or_init() first"
-        res = self.result
-        with logical_rules_context(res.config.logical_rules), res.mesh:
-            return res.jit_train_step.lower(
-                self.state, self._shape_batch(batch)).compile().as_text()
+        return _step_texts(
+            self.result, abstract((self.state, self._shape_batch(batch))))[1]
+
+    def _first_step(self, state: Any, shaped: Any):
+        """The first dispatch after :meth:`prepare`, which compiles the
+        step: the program is registered here, over the shapes it is
+        compiled for (``utils/profiler.program_scopes`` reads its text
+        on demand; the thunk holds shapes, never the state), and every
+        later step goes straight to the jitted step."""
+        res, shapes = self.result, abstract((state, shaped))
+        register_program("train_step", lambda: _step_texts(res, shapes))
+        self._step = res.train_step
+        return self._step(state, shaped)
 
     def param_bytes_per_device(self) -> Dict[int, int]:
         """Parameter bytes each local device really holds (its
@@ -474,7 +501,7 @@ class ElasticTrainer:
                 # enqueue, and whatever the runtime makes the caller
                 # wait for (a donated buffer in use, a full queue)
                 with span("dlrover.trainer.dispatch"):
-                    return self.result.train_step(self.state, shaped)
+                    return self._step(self.state, shaped)
 
             if self.auto_profiler is not None:
                 self.state, metrics = self.auto_profiler.around_step(

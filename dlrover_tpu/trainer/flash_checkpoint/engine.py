@@ -550,7 +550,10 @@ class CheckpointEngine:
         try:
             self._shm_handler.save_state_dict(state, step)
         finally:
-            self._shm_lock.release(owner=owner)
+            # a round trip to the lock's server: milliseconds on a loaded
+            # machine, and a part of the commit like the wait for it
+            with span("dlrover.ckpt.unlock"):
+                self._shm_lock.release(owner=owner)
         self._latest_memory_step = step
         self.saves_committed += 1
         if self._save_error_streak:

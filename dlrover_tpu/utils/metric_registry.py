@@ -706,21 +706,20 @@ METRIC_HELP: Dict[str, str] = {
         "unix time of the most recent xprof capture"
     ),
     "dlrover_xprof_device_seconds": (
-        "total device time of the last captured step"
+        "device busy time of the last captured step (self time: a loop "
+        "is not counted again with its body)"
     ),
     "dlrover_xprof_collective_seconds_total": (
-        "device time in collectives during the last captured step"
+        "device self time in collectives during the last captured step"
     ),
     "dlrover_xprof_collective_seconds": (
-        "per-collective device time of the last captured step "
+        "per-collective device self time of the last captured step "
         "(labeled op=...)"
     ),
-    "dlrover_xprof_op_seconds": (
-        "per-op device time of the last captured step (labeled op=...)"
-    ),
-    "dlrover_xprof_op_count": (
-        "per-op execution count of the last captured step "
-        "(labeled op=...)"
+    "dlrover_xprof_scope_seconds": (
+        "device self time of the last captured step by the program's own "
+        "device scope (labeled scope=...: utils/profiler.device_scope "
+        "names, (unscoped), (other programs))"
     ),
 }
 
@@ -776,11 +775,11 @@ METRIC_LABELS: Dict[str, tuple] = {
     # per-rank step skew: ranks are bounded by the training world size
     # (SpeedMonitor prunes departed workers), never per-request ids
     "dlrover_master_step_skew_seconds": ("rank",),
-    # per-op device time of the last captured step: op names come
-    # from the XLA module (bounded by the compiled program)
+    # device time of the last captured step: collective names come from
+    # the XLA module, scopes from the program's device_scope calls (both
+    # bounded by the compiled program)
     "dlrover_xprof_collective_seconds": ("op",),
-    "dlrover_xprof_op_seconds": ("op",),
-    "dlrover_xprof_op_count": ("op",),
+    "dlrover_xprof_scope_seconds": ("scope",),
 }
 
 

@@ -17,6 +17,11 @@ equivalents are:
 - :func:`span` — the program's own host spans (trainer step, checkpoint
   stage and commit, engine step, router step) written into that same
   trace, on the device's clock, whoever opened the profiler;
+- :func:`device_scope` — the device-side sibling of :func:`span`: a
+  ``jax.named_scope`` the process remembers, so that
+  :func:`program_scopes` can say which scope each instruction of a
+  compiled hot-path program (:func:`register_program`) belongs to —
+  what ``utils/xprof_metrics.scope_seconds`` joins a trace with;
 - :class:`MetricsExporter` — a Prometheus text endpoint per process
   (``/metrics``), like xpu_timer's per-rank ``:38888+rank`` exporter.
 
@@ -27,13 +32,15 @@ profiler plugin, so the framework only adds the serving layer.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
 import http.server
 import json
 import random
+import re
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Any, Callable, Dict, Iterable, List, Optional, Set, Tuple
 
 from dlrover_tpu.common.log import default_logger as logger
 
@@ -358,6 +365,252 @@ class PhaseSpans:
         if self._open is not None:
             self._open.__exit__(None, None, None)
             self._open = None
+
+
+# ---------------------------------------------------------------------
+# Device scopes: which part of the program an instruction belongs to.
+#
+# ``jax.named_scope`` puts a name on the ``op_name`` path of every
+# operation traced under it, and the compiler keeps that path as the
+# instruction's metadata.  A profiler trace names an event by its
+# instruction (``fusion.379``) and ``jax.profiler.ProfileData`` shows no
+# metadata, so the scope of an event comes from the compiled program's
+# text, which only the process that compiled it can produce.  Nothing
+# here runs on a step or a request: a scope is entered while a program
+# is TRACED, and a program is registered once, when it is first compiled.
+
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*)$")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_CALLED = re.compile(r"(?:calls|to_apply)=%?([\w.\-]+)")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_PATH_ELEMENT = re.compile(r"[^/()]+")
+_LOCATION = re.compile(r'loc\("((?:[^"\\]|\\.)*)"')
+_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
+
+
+def innermost_scope(op_name: str, declared: Iterable[str]) -> Optional[str]:
+    """The last element of an ``op_name`` path that is a declared scope.
+    ``transpose(jvp(..))``, ``jit(..)``, ``checkpoint``,
+    ``rematted_computation``, ``while/body`` and flax's module names are
+    elements to pass over: ``jit(step)/loss_and_grad/transpose(jvp(Model))
+    /layers/attn/attn_proj/q_proj/dot_general`` is ``attn_proj``."""
+    on_path = _scopes_on(op_name, declared)
+    return on_path[-1] if on_path else None
+
+
+def _scopes_on(path: str, declared: Iterable[str]) -> List[str]:
+    """The declared scopes among a path's elements, outermost first."""
+    return [e for e in _PATH_ELEMENT.findall(path) if e in declared]
+
+
+@dataclasses.dataclass(frozen=True)
+class ProgramTable:
+    """One compiled program: ``scope_of`` maps each instruction name of
+    its optimized HLO to the innermost declared scope on the
+    instruction's ``op_name`` path (None: unscoped).  ``missing`` are
+    scopes the program's trace-time code entered that the text does not
+    hold: an executable from a compile cache that ANOTHER tree filled
+    carries that tree's metadata (jax's cache key leaves it out), and
+    then no reading may be made from this table (``complete``)."""
+
+    label: str
+    module: str
+    scope_of: Dict[str, Optional[str]]
+    missing: Tuple[str, ...] = ()
+
+    @property
+    def complete(self) -> bool:
+        return not self.missing
+
+
+def parse_program(label: str, compiled_text: str, declared: Iterable[str],
+                  traced_text: str = "") -> ProgramTable:
+    """The table of one program from its optimized HLO text.
+
+    An instruction takes the scope of its OWN ``metadata={op_name=..}``
+    (a fusion's is the one the compiler gave the fusion).  One without
+    an ``op_name`` was made by the compiler, and is placed by structure,
+    never by a guess: a fusion or call takes the scope its called
+    computation's instructions agree on; an instruction that only moves
+    data (memory-space assignment's ``copy-done`` / ``slice-done`` and
+    the bitcasts behind them) takes the scope its consumers agree on,
+    being their wait.  Where they disagree it stays unscoped.
+    ``traced_text`` is the lowered module with locations
+    (``Lowered.as_text(debug_info=True)``): the scopes this tree's code
+    entered when the program was traced."""
+    declared = frozenset(declared)
+    module = _MODULE.match(compiled_text)
+    scope_of: Dict[str, Optional[str]] = {}
+    unnamed: List[str] = []                  # instructions with no op_name
+    called: Dict[str, str] = {}              # instruction -> computation
+    members: Dict[str, List[str]] = {}       # computation -> its named ones
+    users: Dict[str, List[str]] = {}         # instruction -> consumers
+    present: Set[str] = set()
+    computation = None
+    for line in compiled_text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            computation = head.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m or computation is None:
+            continue
+        name, rest = m.groups()
+        op = _OP_NAME.search(rest)
+        if op:
+            on_path = _scopes_on(op.group(1), declared)
+            scope_of[name] = on_path[-1] if on_path else None
+            present.update(on_path)
+            members.setdefault(computation, []).append(name)
+        else:
+            scope_of[name] = None
+            unnamed.append(name)
+        callee = _CALLED.search(rest)
+        if callee:
+            called[name] = callee.group(1)
+        body = rest.split(", metadata=", 1)[0]
+        for operand in _OPERAND.findall(body.split("(", 1)[-1]):
+            users.setdefault(operand, []).append(name)
+
+    def agreed(names: Iterable[str]) -> Optional[str]:
+        scopes = {scope_of.get(n) for n in names}
+        return scopes.pop() if len(scopes) == 1 else None
+
+    for name in unnamed:
+        inside = members.get(called.get(name))
+        if inside:
+            scope_of[name] = agreed(inside)
+    # consumers' scope, through chains of unnamed instructions: a few
+    # passes reach a fixed point (copy-start -> copy-done -> bitcast)
+    for _ in range(4):
+        for name in unnamed:
+            if scope_of[name] is None and name in users:
+                scope_of[name] = agreed(users[name])
+
+    # a location is an operation's name-stack path (``jit(f)/scope/mul``)
+    # or a frame of the traceback behind it, named by its FUNCTION
+    # (``loc("prefill"(..))``): only a path says a scope was entered
+    traced = set()
+    for loc in _LOCATION.findall(traced_text):
+        if "/" in loc:
+            traced.update(_scopes_on(loc, declared))
+    return ProgramTable(label=label, module=module.group(1) if module else "",
+                        scope_of=scope_of,
+                        missing=tuple(sorted(traced - present)))
+
+
+def abstract(tree: Any) -> Any:
+    """``tree`` with a ``jax.ShapeDtypeStruct`` (shape, dtype, weak type
+    and, of an array COMMITTED to its devices, its sharding) in place of
+    every array: what a program is lowered over again without its
+    buffers, to the executable it already ran with.  An uncommitted
+    array (``jnp.zeros``, a jit's result from such) gets no sharding:
+    one would pin the argument and make another program of it."""
+    import jax
+
+    def one(x):
+        sharding = x.sharding if getattr(x, "committed", False) else None
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding,
+            weak_type=bool(getattr(x, "weak_type", False)))
+
+    return jax.tree_util.tree_map(one, tree)
+
+
+def program_texts(jitted: Any, *args: Any) -> Tuple[str, str]:
+    """``(lowered text with locations, optimized HLO text)`` of a jitted
+    function over (abstract) arguments.  The compile is the one the
+    program already ran with: a hit in the process's own or in the
+    persistent compile cache."""
+    lowered = jitted.lower(*args)
+    return lowered.as_text(debug_info=True), lowered.compile().as_text()
+
+
+class ProgramRegistry:
+    """The declared device scopes of a process and its registered
+    hot-path programs.  ``register`` keeps a THUNK that yields
+    :func:`program_texts`' pair over abstract arguments: one dictionary
+    entry a compiled program until somebody asks for :meth:`tables`."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.scopes: Set[str] = set()
+        self._thunks: Dict[str, Callable[[], Tuple[str, str]]] = {}
+        self._tables: Dict[str, Optional[ProgramTable]] = {}
+
+    def register(self, label: str,
+                 thunk: Callable[[], Tuple[str, str]]) -> None:
+        with self._lock:
+            self._thunks[label] = thunk
+            self._tables.pop(label, None)
+
+    def labels(self) -> List[str]:
+        with self._lock:
+            return sorted(self._thunks)
+
+    def tables(self) -> Dict[str, ProgramTable]:
+        """label -> :class:`ProgramTable`, each thunk evaluated once.  A
+        program whose text cannot be made any more is left out (logged)."""
+        with self._lock:
+            wanted = list(self._thunks)
+            todo = {l: self._thunks[l] for l in wanted
+                    if l not in self._tables}
+        for label, thunk in todo.items():
+            try:
+                traced, compiled = thunk()
+                table = parse_program(label, compiled, self.scopes, traced)
+            except Exception:
+                logger.exception("no compiled text for program %s", label)
+                table = None
+            with self._lock:
+                self._tables.setdefault(label, table)
+        with self._lock:
+            return {l: self._tables[l] for l in wanted
+                    if self._tables.get(l) is not None}
+
+
+_PROGRAMS = ProgramRegistry()
+
+
+def device_scope(name: str):
+    """The device-side sibling of :func:`span`: ``jax.named_scope(name)``,
+    and ``name`` joins the process's declared scopes, which is what lets
+    :func:`program_scopes` tell a scope from the other elements of an
+    ``op_name`` path.  Entered while a program is traced, never on a
+    step.  An unnamed ``pallas_call`` takes the name of the innermost
+    scope around it and the benchmark finds kernels by that name: wrap
+    what is around a kernel call, never the call."""
+    import jax
+
+    _PROGRAMS.scopes.add(name)
+    return jax.named_scope(name)
+
+
+def device_scoped(name: str):
+    """Decorator: the whole call of a function under one
+    :func:`device_scope` (the device-side sibling of :func:`spanned`)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with device_scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
+
+
+def register_program(label: str,
+                     thunk: Callable[[], Tuple[str, str]]) -> None:
+    """Make a compiled hot-path program known to :func:`program_scopes`
+    (call it once, when the program is first compiled; see
+    :class:`ProgramRegistry`)."""
+    _PROGRAMS.register(label, thunk)
+
+
+def program_scopes() -> Dict[str, ProgramTable]:
+    """The tables of the process's registered programs, their texts
+    compiled on demand, once."""
+    return _PROGRAMS.tables()
 
 
 def name_os_thread(name: str) -> None:

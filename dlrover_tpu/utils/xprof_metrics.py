@@ -1,37 +1,53 @@
-"""Automatic per-kernel / per-collective timing from XLA traces.
+"""Device time by the program's own scopes, from an XLA trace.
 
 Parity target: xpu_timer (reference atorch/dev/xpu_timer/nvidia/hook.cc
 + README.md:1-40) — an LD_PRELOAD shim that times every CUDA kernel and
-NCCL collective transparently and serves the numbers as Prometheus
-gauges, no user instrumentation.  The TPU equivalent needs no
-interposer: XLA's profiler already records every executed op with
-device timestamps; what was missing (VERDICT r3 item 8) is consuming
-that timeline AUTOMATICALLY into the existing metrics endpoint.
+NCCL collective and serves the numbers as Prometheus gauges with no user
+instrumentation.  The TPU equivalent needs no interposer: XLA's profiler
+records every executed instruction with device timestamps.  What this
+module adds is the reduction an operator and the benchmark both read:
+device SELF time by the scope (``utils/profiler.device_scope``) each
+instruction belongs to, program by program.
 
-Pieces:
+Two steps, so the arithmetic is tested on plain lists:
 
-- :func:`parse_xplane_dir` — read the ``*.xplane.pb`` files a
-  ``jax.profiler`` capture writes and aggregate device-op durations by
-  op name (proto: tensorflow.tsl.profiler xplane, bundled with the
-  baked-in TF install — no TensorBoard needed);
-- :func:`op_breakdown` — classify into collectives (all-reduce /
-  all-gather / reduce-scatter / all-to-all / collective-permute /
-  send+recv) vs compute, with a top-k op table;
-- :class:`AutoProfiler` — owns the every-N-steps capture: wrap the
-  train step with :meth:`around_step`; every ``every_n`` steps ONE step
-  runs under a trace, is parsed, and the breakdown becomes Prometheus
-  gauges (``dlrover_xprof_collective_seconds{op=...}``,
-  ``dlrover_xprof_op_seconds{op=...}``) served by the existing
-  :class:`~dlrover_tpu.utils.profiler.MetricsExporter` via
-  ``add_text_source``.
+- :func:`extract` reads one ``.xplane.pb`` with
+  ``jax.profiler.ProfileData`` (nothing but JAX) into, per device, the
+  events of its ``XLA Ops`` line and the intervals of its ``XLA Modules``
+  line;
+- :func:`join` takes each event's self time, assigns it to the program
+  that was running, and looks its instruction up in that program's table
+  (``utils/profiler.program_scopes``).  :func:`scope_seconds` is the two.
 
-The engine/Trainer wire this up when ``xprof_every_n_steps`` is set —
-from the user's point of view collective timings appear on ``/metrics``
-with zero code changes, like xpu_timer's gauges.
+What a v5e capture holds (read by hand with ``perfbench/trace_reduce
+.describe`` from captures of ``serve-batch-closed`` and
+``train-moe-dropless``, jax 0.9.0; PERF.md section 6, PR 36): one plane a
+chip, ``/device:TPU:<n>``.  Its ``XLA Ops`` line has one event an executed
+HLO instruction, named by the instruction; control flow (``while``,
+``conditional``, ``call``) ENCLOSES its body's events on the same line, so
+an instruction's time is its duration less that of the events nested in
+it.  Its ``XLA Modules`` line has one event an EXECUTION of a compiled
+program, named ``<HLO module name>(<id>)`` — ``jit__train_step(1234..)``,
+``jit_chunk_fn(..)`` — spanning that execution's instructions; the id is
+the executable's, the same for every execution of one program and
+different between two programs that share a module name (the prefill
+buckets are all ``jit_insert_fn``).  The id is nowhere in a program's
+text, so programs that share a module name are told apart by the
+instruction names their executions show (see :func:`join`).  The CPU
+backend writes no device plane: its executed instructions are on the
+``tf_XLA*`` host lines with nothing saying which program ran, and the
+join then goes by instruction name over all registered programs.
+
+:class:`AutoProfiler` owns the operator's every-N-steps capture
+(``ElasticTrainer(xprof_every_n_steps=)``): one step runs under a trace
+and the reduction becomes Prometheus gauges,
+``dlrover_xprof_scope_seconds{scope=..}`` and the collective gauges of
+the xpu_timer parity, all self time.
 """
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
@@ -39,121 +55,272 @@ import shutil
 import tempfile
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 from dlrover_tpu.common.log import default_logger as logger
+from dlrover_tpu.utils.profiler import (
+    ProgramTable,
+    escape_label_value,
+    program_scopes,
+)
 
-# XLA collective op names (HLO thunks as they appear in device traces)
-_COLLECTIVE_RE = re.compile(
+DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
+OTHER = "(other programs)"
+SEVERAL = "(several programs)"
+CPU_PLANE = "/device:CPU-rehearsal:0"
+
+_HLO_NAME = re.compile(r"^%?([\w.\-]+)\s*=")
+# XLA collective instructions as they are named in a device trace
+_COLLECTIVE = re.compile(
     r"all-reduce|all-gather|reduce-scatter|all-to-all|"
     r"collective-permute|collective-broadcast|send|recv|psum|ppermute",
     re.IGNORECASE,
 )
 
-
-def _is_collective(name: str) -> bool:
-    return bool(_COLLECTIVE_RE.search(name))
+Event = List[Any]          # [name, start ns, duration ns]
 
 
-def parse_xplane_dir(log_dir: str) -> Dict[str, Dict[str, float]]:
-    """Aggregate op durations from every ``*.xplane.pb`` under
-    ``log_dir``.
-
-    Returns ``{op_name: {"total_us": float, "count": float}}`` from the
-    DEVICE planes (TPU/GPU/CPU-device) of the capture; host/Python
-    lines are skipped — the device timeline is what xpu_timer times.
-    """
-    from tensorflow.tsl.profiler.protobuf import xplane_pb2
-
-    device_ops: Dict[str, Dict[str, float]] = {}
-    host_ops: Dict[str, Dict[str, float]] = {}
-    paths = glob.glob(
-        os.path.join(log_dir, "**", "*.xplane.pb"), recursive=True)
-    for path in paths:
-        space = xplane_pb2.XSpace()
-        with open(path, "rb") as f:
-            space.ParseFromString(f.read())
-        for plane in space.planes:
-            metadata = {m.id: m.name for m in plane.event_metadata.values()}
-            if "/device:" in plane.name:
-                # real accelerator capture: the "XLA Ops" line carries
-                # one event per executed HLO (name = the HLO text);
-                # "Async XLA Ops"/"XLA Modules" duplicate them
-                for line in plane.lines:
-                    if line.name != "XLA Ops":
-                        continue
-                    _aggregate(line, metadata, device_ops)
-            elif plane.name.startswith("/host:"):
-                # CPU backend (tests): executed ops land on the XLA
-                # listener lines, whose names vary across jax versions
-                # ("tf_XLAPjRt..." on older releases, "tf_XLAEigen/..."
-                # and "tf_XLATfrtCpuClient/..." on newer ones) — match
-                # the stable "tf_XLA" stem.  Names are plain op names;
-                # skip the region/bookkeeping markers interleaved with
-                # them ("end:" pairs, ThreadpoolListener regions, the
-                # ThunkExecutor completion wait).
-                for line in plane.lines:
-                    if not line.name.startswith("tf_XLA"):
-                        continue
-                    _aggregate(line, metadata, host_ops,
-                               skip_prefixes=("end:", "Thread",
-                                              "ThunkExecutor"))
-    # device planes are authoritative; the host table only stands in
-    # when no accelerator plane exists (CPU test runs)
-    return device_ops or host_ops
+def _instruction(raw: str) -> str:
+    """``%fusion.12 = f32[..] fusion(...)`` -> ``fusion.12``."""
+    m = _HLO_NAME.match(raw)
+    return m.group(1) if m else raw.split("(")[0].strip()[:160]
 
 
-_HLO_NAME_RE = re.compile(r"^%?([\w.\-]+)\s*=")
+def newest_xplane(log_dir: str) -> str:
+    paths = sorted(glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                             recursive=True), key=os.path.getmtime)
+    if not paths:
+        raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
+    return paths[-1]
 
 
-def _aggregate(line, metadata, out, skip_prefixes=()) -> None:
-    for event in line.events:
-        raw = metadata.get(event.metadata_id, "")
-        if not raw or any(raw.startswith(p) for p in skip_prefixes):
-            continue
-        m = _HLO_NAME_RE.match(raw)
-        name = m.group(1) if m else raw.split("(")[0].strip()[:160]
-        rec = out.setdefault(name, {"total_us": 0.0, "count": 0.0})
-        rec["total_us"] += event.duration_ps / 1e6
-        rec["count"] += 1
+def extract(path: str) -> Dict[str, Dict[str, List[Event]]]:
+    """``{plane: {"ops": [Event], "modules": [Event]}}`` of one capture.
+    With no TPU plane in it (the CPU backend), the ``tf_XLA*`` host
+    lines' instructions stand in as one device with no module line."""
+    import jax
+
+    pd = jax.profiler.ProfileData.from_file(path)
+    devices: Dict[str, Dict[str, List[Event]]] = {}
+    cpu: List[Event] = []
+    for plane in pd.planes:
+        if DEVICE_PLANE.match(plane.name):
+            rec = devices.setdefault(plane.name, {"ops": [], "modules": []})
+            for line in plane.lines:
+                if line.name == OPS_LINE:
+                    rec["ops"] = [[_instruction(e.name), float(e.start_ns),
+                                   float(e.duration_ns)] for e in line.events]
+                elif line.name == MODULES_LINE:
+                    rec["modules"] = [[e.name, float(e.start_ns),
+                                       float(e.duration_ns)]
+                                      for e in line.events]
+        elif plane.name.startswith("/host:"):
+            for line in plane.lines:
+                if line.name.startswith("tf_XLA"):
+                    cpu.extend(
+                        [_instruction(e.name), float(e.start_ns),
+                         float(e.duration_ns)] for e in line.events
+                        if e.duration_ns > 0
+                        and not e.name.startswith(("end:", "Thread")))
+    if not devices and cpu:
+        devices[CPU_PLANE] = {"ops": cpu, "modules": []}
+    return devices
 
 
-def op_breakdown(
-    ops: Dict[str, Dict[str, float]], top_k: int = 10
-) -> Dict[str, Any]:
-    """Split an op table into collectives vs compute with a top-k list."""
-    collectives: Dict[str, float] = {}
-    compute_us = 0.0
-    total_us = 0.0
-    for name, rec in ops.items():
-        total_us += rec["total_us"]
-        if _is_collective(name):
-            collectives[name] = collectives.get(name, 0.0) \
-                + rec["total_us"]
+def _clip(events: List[Event], lo: float, hi: float) -> List[Event]:
+    out = []
+    for name, start, dur in events:
+        s, e = max(start, lo), min(start + dur, hi)
+        if e > s:
+            out.append([name, s, e - s])
+    return out
+
+
+def self_times(events: List[Event]) -> List[Event]:
+    """``[name, start, SELF ns]`` an event: its duration less that of the
+    events nested in it on the line (a ``while`` less its body), so the
+    sum over a line is the time the line was busy, counted once."""
+    out: List[Event] = []
+    stack: List[list] = []            # [index into out, end]
+    for name, start, dur in sorted(events, key=lambda e: (e[1], -e[2])):
+        while stack and stack[-1][1] <= start:
+            stack.pop()
+        if stack:
+            out[stack[-1][0]][2] -= dur
+        stack.append([len(out), start + dur])
+        out.append([name, start, dur])
+    for ev in out:
+        ev[2] = max(0.0, ev[2])
+    return out
+
+
+def _merged_label(tables: List[ProgramTable]) -> str:
+    """One label for programs that could not be told apart: what their
+    labels share up to a ``.`` and ``*`` (``prefill.*``), else the
+    module's name (on the CPU, where instruction names are all there is
+    to go by, programs of different modules may share one: ``SEVERAL``)."""
+    if len(tables) == 1:
+        return tables[0].label
+    parts = [t.label.split(".") for t in tables]
+    common = []
+    for column in zip(*parts):
+        if len(set(column)) != 1:
+            break
+        common.append(column[0])
+    if common:
+        return ".".join(common + ["*"])
+    modules = {t.module for t in tables}
+    return modules.pop() if len(modules) == 1 else SEVERAL
+
+
+class _Program:
+    """The registered programs one executable of the trace may be."""
+
+    def __init__(self, tables: List[ProgramTable]):
+        self.tables = tables
+        self.label = _merged_label(tables)
+        self.complete = all(t.complete for t in tables)
+
+    def scope_of(self, instruction: str) -> Optional[str]:
+        """The scope every candidate gives the instruction, else None:
+        never a guess between programs that disagree."""
+        scopes = {t.scope_of.get(instruction) for t in self.tables
+                  if instruction in t.scope_of}
+        return scopes.pop() if len(scopes) == 1 else None
+
+
+def _resolve(candidates: List[ProgramTable], seen: Iterable[str]) -> _Program:
+    """Which of the programs that share a module name an executable is:
+    those whose text holds every instruction its executions showed."""
+    seen = set(seen)
+    holding = [t for t in candidates if seen <= t.scope_of.keys()]
+    return _Program(holding or candidates)
+
+
+def join(devices: Dict[str, Dict[str, List[Event]]],
+         tables: Dict[str, ProgramTable],
+         window: Optional[Tuple[float, float]] = None) -> Dict[str, dict]:
+    """``{program label: {"executions": n, "scopes": {scope: self
+    seconds}, "unscoped": seconds, "unscoped_ops": {instruction:
+    seconds}, "complete": bool}}`` plus ``"(other programs)"`` for the
+    modules nobody registered (a checkpoint's snapshot, transfers), mean
+    over devices, within ``window`` (ns on the trace's clock).
+
+    The sum over everything equals the time the devices were busy.  A
+    program whose table is not ``complete`` (its text is another tree's:
+    ``ProgramTable``) keeps its seconds, all ``unscoped``, and says so:
+    a reader makes no reading from it.  Programs that share a module name
+    are told apart by executable (the id in the module event's name) and
+    the instructions it showed; where more than one registered program
+    fits, an instruction counts under a scope only if all that fit agree
+    on it.  With no module line (the CPU backend) the same holds over
+    ALL registered programs."""
+    by_module: Dict[str, List[ProgramTable]] = {}
+    for table in tables.values():
+        by_module.setdefault(table.module, []).append(table)
+    out: Dict[str, dict] = {}
+    n_dev = max(1, len(devices))
+
+    def record(program: Optional[_Program]) -> dict:
+        return out.setdefault(program.label if program else OTHER, {
+            "executions": 0.0, "scopes": {}, "unscoped": 0.0,
+            "unscoped_ops": {},
+            "complete": program.complete if program else True})
+
+    def add(program: Optional[_Program], name: str, self_ns: float) -> None:
+        rec, sec = record(program), self_ns / 1e9 / n_dev
+        scope = program.scope_of(name) \
+            if program and program.complete else None
+        if scope is None:
+            rec["unscoped"] += sec
+            rec["unscoped_ops"][name] = \
+                rec["unscoped_ops"].get(name, 0.0) + sec
         else:
-            compute_us += rec["total_us"]
-    top = sorted(ops.items(), key=lambda kv: -kv[1]["total_us"])[:top_k]
-    return {
-        "total_device_us": total_us,
-        "compute_us": compute_us,
-        "collective_us": sum(collectives.values()),
-        "collectives": collectives,
-        "top_ops": [
-            (name, rec["total_us"], int(rec["count"])) for name, rec in top
-        ],
-    }
+            rec["scopes"][scope] = rec["scopes"].get(scope, 0.0) + sec
+
+    for plane in sorted(devices):
+        ops, modules = devices[plane]["ops"], devices[plane]["modules"]
+        if window is not None:
+            ops, modules = _clip(ops, *window), _clip(modules, *window)
+        events = self_times(ops)
+        if not modules:
+            # no module line (the CPU backend): by instruction name over
+            # every registered program that has one of that name
+            holders: Dict[str, Optional[_Program]] = {}
+            for name, _, self_ns in events:
+                if name not in holders:
+                    having = [t for t in tables.values()
+                              if name in t.scope_of]
+                    holders[name] = _Program(having) if having else None
+                add(holders[name], name, self_ns)
+            continue
+        modules = sorted(modules, key=lambda m: m[1])
+        starts = [m[1] for m in modules]
+        placed, shown = [], {}
+        for name, start, self_ns in events:
+            i = bisect.bisect_right(starts, start) - 1
+            exe = modules[i][0] if i >= 0 and \
+                start < modules[i][1] + modules[i][2] else None
+            shown.setdefault(exe, set()).add(name)
+            placed.append((exe, name, self_ns))
+        programs: Dict[Optional[str], Optional[_Program]] = {}
+        for exe in {m[0] for m in modules} | set(shown):
+            # ``<module name>(<executable id>)``
+            candidates = by_module.get(exe.rsplit("(", 1)[0]) \
+                if exe else None
+            programs[exe] = _resolve(candidates, shown.get(exe, ())) \
+                if candidates else None
+        for exe, _, _ in modules:
+            record(programs[exe])["executions"] += 1.0 / n_dev
+        for exe, name, self_ns in placed:
+            add(programs[exe], name, self_ns)
+    return out
 
 
-def profile_call(fn: Callable[[], Any], log_dir: Optional[str] = None,
-                 top_k: int = 10) -> Tuple[Any, Optional[Dict[str, Any]]]:
+def total_seconds(programs: Dict[str, dict]) -> float:
+    """Everything :func:`join` placed: the devices' busy seconds."""
+    return sum(sum(p["scopes"].values()) + p["unscoped"]
+               for p in programs.values())
+
+
+def scope_seconds(xplane_path: str,
+                  window: Optional[Tuple[float, float]] = None,
+                  tables: Optional[Dict[str, ProgramTable]] = None
+                  ) -> Dict[str, dict]:
+    """:func:`join` of one capture with the process's registered programs
+    (``utils/profiler.program_scopes``: their texts are compiled here, on
+    demand — cache hits — unless ``tables`` hands them in)."""
+    return join(extract(xplane_path),
+                program_scopes() if tables is None else tables, window)
+
+
+def collective_seconds(devices: Dict[str, Dict[str, List[Event]]]
+                       ) -> Dict[str, float]:
+    """Self seconds by collective instruction, mean over devices (an async
+    pair's ``-start`` and ``-done`` are two instructions: the gap between
+    them is overlap, not collective time)."""
+    out: Dict[str, float] = {}
+    n_dev = max(1, len(devices))
+    for rec in devices.values():
+        for name, _, self_ns in self_times(rec["ops"]):
+            if _COLLECTIVE.search(name):
+                out[name] = out.get(name, 0.0) + self_ns / 1e9 / n_dev
+    return out
+
+
+def profile_call(fn: Callable[[], Any], log_dir: Optional[str] = None
+                 ) -> Tuple[Any, Optional[Dict[str, Any]]]:
     """Run ``fn`` under a jax.profiler trace; return ``(result,
-    breakdown)``.
+    breakdown)``: ``{"programs": join's result, "collectives":
+    collective_seconds', "device_seconds": the busy seconds}``.
 
-    Failures strictly AFTER ``fn`` executed (trace parse, proto import)
-    yield ``(result, None)`` — the caller must NOT re-run ``fn``: with
-    donated arguments (the train step donates the state) a second call
-    would reuse already-donated buffers and crash.  Only a failure to
-    start the trace propagates before ``fn`` runs.
+    Failures strictly AFTER ``fn`` executed (reading the trace) yield
+    ``(result, None)`` — the caller must NOT re-run ``fn``: with donated
+    arguments (the train step donates the state) a second call would
+    reuse already-donated buffers and crash.  Only a failure to start
+    the trace propagates before ``fn`` runs.
     """
     import jax
 
@@ -169,9 +336,13 @@ def profile_call(fn: Callable[[], Any], log_dir: Optional[str] = None,
             except Exception:
                 logger.exception("stopping xprof trace failed")
         try:
-            breakdown = op_breakdown(parse_xplane_dir(tmp), top_k=top_k)
+            devices = extract(newest_xplane(tmp))
+            programs = join(devices, program_scopes())
+            breakdown = {"programs": programs,
+                         "collectives": collective_seconds(devices),
+                         "device_seconds": total_seconds(programs)}
         except Exception:
-            logger.exception("xprof trace parse failed; step result "
+            logger.exception("xprof trace could not be read; step result "
                              "kept, breakdown skipped")
             breakdown = None
         return result, breakdown
@@ -185,19 +356,16 @@ def _sanitize(name: str) -> str:
 
 
 class AutoProfiler:
-    """Every-N-steps transparent op timing -> Prometheus text lines.
+    """Every-N-steps transparent device timing -> Prometheus text lines.
 
     ``around_step(fn)`` replaces a direct train-step call: on most steps
     it just calls through; every ``every_n``-th step it captures an XLA
     trace of that single step and refreshes the gauge set.  Register
-    :meth:`prometheus_text` with
-    ``MetricsExporter.add_text_source``.
+    :meth:`prometheus_text` with ``MetricsExporter.add_text_source``.
     """
 
-    def __init__(self, every_n: int = 100, top_k: int = 10,
-                 warmup_steps: int = 2):
+    def __init__(self, every_n: int = 100, warmup_steps: int = 2):
         self.every_n = max(1, int(every_n))
-        self.top_k = top_k
         self._warmup = warmup_steps  # never trace compile steps
         self._step = 0
         self._lock = threading.Lock()
@@ -214,7 +382,7 @@ class AutoProfiler:
         if not due:
             return fn()
         try:
-            result, breakdown = profile_call(fn, top_k=self.top_k)
+            result, breakdown = profile_call(fn)
         except Exception:
             # profile_call only raises BEFORE fn ran (trace start
             # failure) — re-running is safe then, and only then
@@ -234,8 +402,11 @@ class AutoProfiler:
             return self._breakdown
 
     def prometheus_text(self) -> str:
-        """Labeled gauges in Prometheus text format (xpu_timer's
-        metric surface, README.md:1-40)."""
+        """Labeled gauges in Prometheus text format (xpu_timer's metric
+        surface, README.md:1-40), all device SELF time of the captured
+        step: ``dlrover_xprof_scope_seconds{scope=..}`` by the program's
+        device scope (``(unscoped)``: instructions of a registered program
+        under no scope; ``(other programs)``: everything else that ran)."""
         with self._lock:
             bd = self._breakdown
             ts = self._last_profile_time
@@ -245,20 +416,22 @@ class AutoProfiler:
         lines = [
             f"dlrover_xprof_profiles_total {float(n)}",
             f"dlrover_xprof_last_capture_timestamp {ts}",
-            "dlrover_xprof_device_seconds "
-            f"{bd['total_device_us'] / 1e6}",
+            f"dlrover_xprof_device_seconds {bd['device_seconds']}",
             "dlrover_xprof_collective_seconds_total "
-            f"{bd['collective_us'] / 1e6}",
+            f"{sum(bd['collectives'].values())}",
         ]
-        for name, us in sorted(bd["collectives"].items()):
+        for name, sec in sorted(bd["collectives"].items()):
             lines.append(
                 f'dlrover_xprof_collective_seconds{{op="{_sanitize(name)}"}} '
-                f"{us / 1e6}")
-        for name, us, count in bd["top_ops"]:
+                f"{sec}")
+        by_scope: Dict[str, float] = {}
+        for label, rec in bd["programs"].items():
+            for scope, sec in rec["scopes"].items():
+                by_scope[scope] = by_scope.get(scope, 0.0) + sec
+            key = OTHER if label == OTHER else "(unscoped)"
+            by_scope[key] = by_scope.get(key, 0.0) + rec["unscoped"]
+        for scope, sec in sorted(by_scope.items()):
             lines.append(
-                f'dlrover_xprof_op_seconds{{op="{_sanitize(name)}"}} '
-                f"{us / 1e6}")
-            lines.append(
-                f'dlrover_xprof_op_count{{op="{_sanitize(name)}"}} '
-                f"{float(count)}")
+                "dlrover_xprof_scope_seconds"
+                f'{{scope="{escape_label_value(scope)}"}} {sec}')
         return "\n".join(lines) + "\n"

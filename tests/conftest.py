@@ -68,3 +68,18 @@ def master_client(local_master):
     client = MasterClient(addr, node_id=0, node_type="worker")
     yield client
     client.close()
+
+
+@pytest.fixture()
+def fresh_compiles():
+    """No persistent compile cache around a test that reads a compiled
+    program's metadata: jax's cache key leaves metadata out, so an entry
+    another tree wrote would carry that tree's scopes."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()

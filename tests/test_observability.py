@@ -363,19 +363,36 @@ def test_slice_topology_sorter_keeps_rank0_group_first():
 # ---------------------------------------------------------------------------
 
 
-def test_profile_call_captures_ops():
+def test_profile_call_reduces_by_scope(monkeypatch, fresh_compiles):
+    """A registered program's captured step comes back as self seconds
+    by its own device scopes, and the parts add up to the device time."""
     import jax
     import jax.numpy as jnp
 
-    from dlrover_tpu.utils.xprof_metrics import profile_call
+    from dlrover_tpu.utils import profiler
+    from dlrover_tpu.utils.xprof_metrics import profile_call, total_seconds
 
-    f = jax.jit(lambda a, b: (a @ b).sum())
+    monkeypatch.setattr(profiler, "_PROGRAMS", profiler.ProgramRegistry())
+
+    @jax.jit
+    def f(a, b):
+        with profiler.device_scope("mlp"):
+            return (a @ b).sum()
+
     x = jnp.ones((128, 128))
-    f(x, x).block_until_ready()  # compile outside the trace
+    # compile outside the trace, and not from the persistent cache
+    # (``fresh_compiles``): an entry another tree wrote would carry that
+    # tree's scopes
+    f(x, x).block_until_ready()
+    shapes = profiler.abstract((x, x))
+    profiler.register_program(
+        "f", lambda: profiler.program_texts(f, *shapes))
     result, bd = profile_call(lambda: f(x, x))
     assert float(result) != 0.0
-    assert bd["total_device_us"] > 0
-    assert bd["top_ops"], bd
+    assert bd["device_seconds"] > 0
+    assert bd["device_seconds"] == pytest.approx(
+        total_seconds(bd["programs"]))
+    assert bd["programs"]["f"]["scopes"]["mlp"] > 0
 
 
 def test_profile_call_times_collectives():
@@ -398,8 +415,37 @@ def test_profile_call_times_collectives():
     x = jnp.ones((8 * 32, 32))
     step(x).block_until_ready()
     _, bd = profile_call(lambda: step(x))
-    assert bd["collectives"], bd["top_ops"]
-    assert bd["collective_us"] > 0
+    assert bd["collectives"], bd["programs"]
+    assert sum(bd["collectives"].values()) > 0
+    assert sum(bd["collectives"].values()) <= bd["device_seconds"]
+
+
+def test_a_nested_while_is_not_counted_twice():
+    """The old gauges summed durations: a ``while`` counted its body
+    again.  Self time: the loop keeps what its body does not cover, and
+    the gauges' total is the time the device was busy."""
+    from dlrover_tpu.utils.profiler import ProgramTable
+    from dlrover_tpu.utils.xprof_metrics import (
+        collective_seconds, join, self_times, total_seconds)
+
+    ops = [["while.1", 0.0, 1000.0],            # the scan over layers
+           ["while.2", 100.0, 800.0],           # a loop inside its body
+           ["fusion.3", 100.0, 300.0], ["all-reduce.4", 400.0, 500.0],
+           ["fusion.5", 1000.0, 200.0]]
+    assert {n: s for n, _, s in self_times(ops)} == {
+        "while.1": 200.0, "while.2": 0.0, "fusion.3": 300.0,
+        "all-reduce.4": 500.0, "fusion.5": 200.0}
+    devices = {"/device:TPU:0": {
+        "ops": ops, "modules": [["jit__train_step(1)", 0.0, 1200.0]]}}
+    table = ProgramTable("train_step", "jit__train_step", {
+        "while.1": "loss_and_grad", "while.2": "loss_and_grad",
+        "fusion.3": "mlp", "all-reduce.4": "mlp", "fusion.5": "optimizer"})
+    programs = join(devices, {"train_step": table})
+    assert total_seconds(programs) == pytest.approx(1200e-9)   # not 2800
+    assert programs["train_step"]["scopes"] == pytest.approx(
+        {"loss_and_grad": 200e-9, "mlp": 800e-9, "optimizer": 200e-9})
+    assert collective_seconds(devices) == pytest.approx(
+        {"all-reduce.4": 500e-9})
 
 
 def test_auto_profiler_every_n_and_prometheus_text():
@@ -419,12 +465,15 @@ def test_auto_profiler_every_n_and_prometheus_text():
     text = prof.prometheus_text()
     assert "dlrover_xprof_profiles_total 1.0" in text
     assert "dlrover_xprof_device_seconds" in text
-    assert "dlrover_xprof_op_seconds{op=" in text
+    # nobody registered ``f``: its time is there, under no scope's name
+    assert 'dlrover_xprof_scope_seconds{scope="(other programs)"}' in text
+    assert "dlrover_xprof_op_seconds" not in text
 
 
 def test_elastic_trainer_xprof_endpoint():
     """Zero-instrumentation wiring: a normal train loop with
-    xprof_every_n_steps exposes op timings on /metrics."""
+    xprof_every_n_steps exposes device time by the step's own scopes on
+    /metrics."""
     import urllib.request
 
     import jax
@@ -448,7 +497,8 @@ def test_elastic_trainer_xprof_endpoint():
         url = f"http://127.0.0.1:{tr.metrics_exporter.port}/metrics"
         body = urllib.request.urlopen(url, timeout=5).read().decode()
         assert "dlrover_step_count" in body
-        assert "dlrover_xprof_op_seconds{op=" in body
+        assert "dlrover_xprof_scope_seconds{scope=" in body
+        assert "dlrover_xprof_device_seconds" in body
     finally:
         tr.close()
 

@@ -285,20 +285,24 @@ def _hybrid_cell():
         dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, remat=True), 2, 8192
 
 
-@pytest.mark.parametrize("cell,stack,sparse_layers_a_loop", [
-    (_olmoe_cell, (3, 64, 2048, 1024), 1),
-    (_hybrid_cell, (2, 32, 2048, 512), 4),
+@pytest.mark.parametrize("cell,stack,sparse_layers_a_loop,flash_calls", [
+    (_olmoe_cell, (3, 64, 2048, 1024), 1, {"attn": 4}),
+    (_hybrid_cell, (2, 32, 2048, 512), 4, {
+        "attn_full": 8, "flash_window_fwd": 6, "flash_window_dq": 3,
+        "flash_window_dkv": 3}),
 ], ids=["train-moe-dropless", "train-hybrid-8k"])
 def test_sparse_cells_step_reads_expert_weights_in_the_stack(
-        topo, monkeypatch, cell, stack, sparse_layers_a_loop):
+        topo, monkeypatch, cell, stack, sparse_layers_a_loop, flash_calls):
     """The whole train step of the two sparse cells (``ElasticTrainer``'s
     own jitted step, bf16 state, remat, the scan over layers / periods) as
     the chip's compiler makes it: no copy of a layer's expert weights out
     of the stack ahead of a grouped matmul (6 a sparse layer before
     PR 33, 0.8 ms each at OLMoE's sizes), and the kernels still named
     ``gmm.<n>`` / ``tgmm.<n>``, 12 a sparse layer (3 forward, 3
-    recomputed, 3 + 3 backward): what the benchmark's readers find them
-    by.  The text holds a loop's body once."""
+    recomputed, 3 + 3 backward), and the flash calls ``attn.<n>`` /
+    ``attn_full.<n>`` / ``flash_window_*``: what the benchmark's readers
+    find them by.  The text holds a loop's body once."""
+    import collections
     import re
 
     import flax.linen as nn
@@ -329,6 +333,14 @@ def test_sparse_cells_step_reads_expert_weights_in_the_stack(
     calls = re.findall(r"^\s+%(t?gmm)(?:\.\d+)? = ", text, re.M)
     assert calls.count("gmm") == 9 * sparse_layers_a_loop
     assert calls.count("tgmm") == 3 * sparse_layers_a_loop
+    # the flash calls too: an unnamed ``pallas_call`` takes the innermost
+    # scope's name, so ``utils/profiler.device_scope`` wraps what is AROUND
+    # a kernel and never the call (PR 36: ``attn_proj``, ``mlp``, ``head``)
+    kernels = collections.Counter(re.findall(
+        r"^\s+%([\w\-]+?)(?:\.\d+)? = .* custom-call\(.*"
+        r"custom_call_target=\"tpu_custom_call\"", text, re.M))
+    assert kernels == flash_calls | {
+        "gmm": 9 * sparse_layers_a_loop, "tgmm": 3 * sparse_layers_a_loop}
     # all layers' groups in one row: the operand the kernels index into
     assert f"bf16[{stack[0] * stack[1]},{stack[2]},{stack[3]}]" in text
 
