@@ -1,4 +1,15 @@
-"""Pallas TPU kernels."""
+"""Pallas TPU kernels.
+
+- ``flash_attention``: training's causal / segmented / windowed attention,
+  forward and backward.
+- ``paged_attention``: a dense decoder's decode attention over paged K/V
+  pools (bf16, int8; int4 refused), ending at each slot's length.
+- ``paged_index``: a latent model's index scores over a slot's live pages
+  of index keys (decode).
+- ``mla_prefill``: a latent model's masked, absorbed attention of a run
+  of queries (a prefill chunk) over its live pages of latent rows.
+- ``quant_matmul``: int8 x int8 matmul with per-row / per-column scales.
+"""
 
 
 def interpret_off_chip() -> bool:

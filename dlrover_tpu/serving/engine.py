@@ -108,6 +108,12 @@ class EngineStats:
     chained_dispatches: int = 0   # ... while an earlier one of the same
     #                               step was still unread: its host
     #                               preparation cost the device no gap
+    # a latent-attention model's prompt chunks (one layer's, as every
+    # layer walks the same): host arithmetic from each chunk's end
+    prefill_key_blocks: int = 0   # key blocks their attention walked:
+    #                               whole blocks up to each chunk's last
+    #                               query
+    prefill_key_blocks_table: int = 0  # key blocks their tables hold
     finished_requests: int = 0
     spec_proposed: int = 0        # draft tokens sent to verification
     spec_accepted: int = 0        # draft tokens accepted
@@ -180,6 +186,15 @@ class EngineStats:
         one program)."""
         return self.chained_dispatches / self.dispatches \
             if self.dispatches else 0.0
+
+    @property
+    def prefill_key_block_share(self) -> float:
+        """Key blocks the prompt chunks' attention walked over the key
+        blocks their tables hold: how far the work follows the live
+        depth and not the table's width (0.0 for a model whose chunks
+        gather a slot's whole table)."""
+        return self.prefill_key_blocks / self.prefill_key_blocks_table \
+            if self.prefill_key_blocks_table else 0.0
 
     @property
     def kv_stream_ratio(self) -> float:
@@ -802,7 +817,9 @@ class InferenceEngine:
                 with device_scope("prefill_chunk"):
                     logits, cache = verify_step(
                         params, cfg, cache, tokens, start,
-                        slots=slots, logits_index=last_idx)
+                        slots=slots, logits_index=last_idx,
+                        attention_impl=impl,
+                        kernel_interpret=kernel_interpret)
                 cache = dict(cache)
                 witness = cache.pop("witness", None)
                 rng, sub = jax.random.split(rng)
@@ -825,7 +842,9 @@ class InferenceEngine:
                         rng):
                 with device_scope("verify"):
                     logits, cache = verify_step(
-                        params, cfg, cache, tokens, positions)
+                        params, cfg, cache, tokens, positions,
+                        attention_impl=impl,
+                        kernel_interpret=kernel_interpret)
                 cache = dict(cache)
                 cache.pop("witness", None)
                 rng, sub = jax.random.split(rng)
@@ -1214,7 +1233,8 @@ class InferenceEngine:
         if self.paged and self._table_dirty:
             self._push_table()
         started = time.perf_counter()
-        attrs = {"n": g, **self._book_selection(starts, ends)}
+        attrs = {"n": g, **self._book_selection(starts, ends),
+                 **self._book_key_blocks(starts + c)}
         with self._dispatching("prefill_chunk"):
             # one dispatch for all rows, or one a row where the model
             # asks for that (``_prefill_group``); the cache and the last
@@ -1568,6 +1588,26 @@ class InferenceEngine:
         self.stats.index_rows_scanned += book["index_rows_scanned"]
         self.stats.attn_rows_selected += book["attn_rows_selected"]
         return book
+
+    def _book_key_blocks(self, ends) -> Dict[str, int]:
+        """Book the key blocks a latent-attention model's prompt chunks
+        walk whose PROGRAMS end at ``ends`` (a chunk's padding is a
+        query too), in the blocks of the path that walks them
+        (``serving/latent.py _attend_run``), beside what their tables
+        hold; returns the first for the dispatch's span ({} for any
+        other model)."""
+        if not self._latent:
+            return {}
+        from dlrover_tpu.ops.pallas.mla_prefill import key_blocks
+        from dlrover_tpu.serving.latent import KEY_BLOCK_PAGES
+
+        table = -(-self._max_blocks // KEY_BLOCK_PAGES) * KEY_BLOCK_PAGES
+        walked, held = key_blocks(
+            ends, self.block_size, table,
+            None if self.attention_impl == "pallas" else KEY_BLOCK_PAGES)
+        self.stats.prefill_key_blocks += walked
+        self.stats.prefill_key_blocks_table += held
+        return {"key_blocks": walked}
 
     def watch(self, wanted) -> None:
         """Keep what the engine's own programs do for ONE request at a

@@ -33,12 +33,18 @@ neither making a dense copy of a slot's table:
   a gather of the chosen latent rows, two einsums.
 - a run of queries (a prefill chunk, a speculative verify, a bucketed
   prefill): row by row of the group (``lax.map``), over the row's live
-  key blocks only (a loop whose trip count is read from the positions):
-  the index scores of every block, the ``index_topk``-th largest of each
-  query's scores (:func:`_kth_largest`), then causal attention over the
-  blocks again under the mask ``I >= that``, with a running softmax.  A
-  prefill therefore computes scores against every live row, chosen or
-  not (a selection kernel for prefill is ROADMAP Reach A12).
+  key blocks only (trip counts read from the positions): the index
+  scores of every block, the ``index_topk``-th largest of each query's
+  scores (:func:`_kth_largest`), then causal attention over the blocks
+  again under the mask ``I >= that``, with a running softmax.  With
+  ``attention_impl="pallas"`` (``verify_step``: the engine's prompt
+  chunks and verifies on a chip) that attention is ONE kernel,
+  ``ops/pallas/mla_prefill.py mla_prefill_attention``, whose score tiles
+  live and die in VMEM; otherwise, and in :func:`prefill`, it is the
+  ``jnp`` loop of :func:`_attend_run`, the kernel's oracle, whose tiles
+  pass through HBM.  Either way a run computes scores against every live
+  row, chosen or not: masked-dense at the MXU's pace.  Attending the
+  chosen rows alone is ROADMAP Reach A12, a row gather away.
 """
 
 from __future__ import annotations
@@ -172,12 +178,20 @@ def _kth_largest(keys: jax.Array, k: int) -> jax.Array:
 
 
 def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
-                cfg: LlamaConfig, pages: int):
+                cfg: LlamaConfig, pages: int, impl: str = "xla",
+                interpret: bool = False):
     """A run of queries of ONE sequence against its cached rows, this
     run's own among them: ``qq`` [K, H, C + R], ``q_i`` [K, Hi, Di], ``w``
     [K, Hi], ``q_pos`` [K] ascending, ``table_row`` [MB] (a multiple of
     ``pages``).  Returns the attended latent [K, H, C] float32 and the
-    selection [K, MB x bs] bool.  Work follows ``q_pos[-1]``, not MB."""
+    selection [K, MB x bs] bool.  Work follows ``q_pos[-1]``, not MB.
+
+    The selection is computed the same way whatever ``impl``; the masked
+    attention under it is the ``mla_prefill_attention`` kernel with
+    ``impl == "pallas"`` (score tiles stay in VMEM; key blocks of its
+    own size, which divides the table's) and the ``jnp`` loop
+    below otherwise (the off-chip path and the kernel's oracle: every
+    block's ``[K, H, keys]`` float32 scores pass through HBM)."""
     klen, heads, _ = qq.shape
     c = cfg.kv_lora_rank
     bs = latent_pool.shape[1]
@@ -217,6 +231,16 @@ def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
         chosen = jnp.arange(width)[None, :] <= q_pos[:, None]
 
     with device_scope("mla_attn"):
+        if impl == "pallas":
+            from dlrover_tpu.ops.pallas.mla_prefill import (
+                mla_prefill_attention,
+            )
+
+            o_lat = mla_prefill_attention(
+                qq, jnp.where(chosen, 0.0, _NEG_INF), q_pos, latent_pool,
+                table_row, c=c, scale=scale, interpret=interpret)
+            return o_lat, chosen
+
         def attend_block(j, carry):
             m, l, acc = carry
             lat = block(latent_pool, j)                       # [kb, C+R]
@@ -425,7 +449,8 @@ def verify_step(
         else:
             o_lat, chosen = jax.lax.map(
                 lambda a: _attend_run(a[0], a[1], a[2], a[3], lat, idx,
-                                      a[4], cfg, KEY_BLOCK_PAGES),
+                                      a[4], cfg, KEY_BLOCK_PAGES,
+                                      attention_impl, kernel_interpret),
                 (qq, q_i, w, pos_k, run_table))
         if watch is not None:
             selections.append(jnp.take(chosen, watch, axis=0))
@@ -466,6 +491,9 @@ def prefill(params: Dict[str, Any], cfg: LlamaConfig, tokens: jax.Array,
     index keys [G, Lp, Di]) for the engine to scatter.  A prompt is one
     key block here, so scores are [Lp, heads, Lp]: buckets the size of a
     prefill chunk, which is all an engine with ``prefill_chunk`` sends.
+    Its attention stays the ``jnp`` loop of :func:`_attend_run` whatever
+    the engine's ``attention_impl`` (one block of the prompt's own rows,
+    no pool behind it: nothing for the kernel to save).
     The experts' picks of this path are not counted."""
     dtype = cfg.dtype
     g, lp_len = tokens.shape

@@ -275,7 +275,7 @@ def registry(monkeypatch):
 # a scope that named a kernel call would show as an instruction's name
 NEW_SCOPES = ("attn_proj", "mlp", "head", "embed", "clip", "grad_norm",
               "kv_write", "paged_attn", "pick")
-KERNEL_NAMES = re.compile(r"^(attn|gmm|tgmm|paged_)")
+KERNEL_NAMES = re.compile(r"^(attn|gmm|tgmm|paged_|mla_prefill)")
 
 
 def _no_kernel_renamed(table):
@@ -378,21 +378,30 @@ def _dense_engine():
     }
 
 
-def _latent_engine():
+def _latent_engine(**kw):
     from perfbench.weights_glm5 import SeededGlm5Params
     from tests.test_glm5_reference import tiny
 
     cfg = tiny()
     seven = {"mla_proj", "dsa_index", "dsa_select", "mla_attn",
              "moe_route", "moe_experts", "moe_shared"}
-    return _engine(cfg, {"params": SeededGlm5Params(cfg, 1)}), {
+    return _engine(cfg, {"params": SeededGlm5Params(cfg, 1)}, **kw), {
         "decode_chunk": seven | {"head", "pick", "kv_write", "mlp"},
         "prefill_chunk.g1": seven | {"head", "pick", "kv_write", "mlp"},
     }
 
 
-@pytest.mark.parametrize("build", [_dense_engine, _latent_engine],
-                         ids=["dense", "latent"])
+def _latent_kernel_engine():
+    """The engine whose decode chunk takes ``paged_index_scores`` and
+    whose prompt chunks take ``mla_prefill_attention`` (interpreted
+    here: the kernels' bodies are the programs' own instructions, under
+    the scopes around the calls)."""
+    return _latent_engine(attention_impl="pallas")
+
+
+@pytest.mark.parametrize(
+    "build", [_dense_engine, _latent_engine, _latent_kernel_engine],
+    ids=["dense", "latent", "latent-kernels"])
 def test_engine_warmup_registers_every_program(fresh_compiles, registry,
                                                build):
     """(b) Each program ``warmup`` runs is registered under its label;

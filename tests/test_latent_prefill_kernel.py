@@ -1,0 +1,135 @@
+"""``mla_prefill_attention`` (a query run's masked, absorbed latent
+attention as one Pallas kernel) in interpret mode on the CPU, tiny:
+against the ``jnp`` loop of ``serving/latent.py _attend_run`` it stands
+in for, case by case, and through ``verify_step`` on a chunk behind a
+cache.  What the chip's compiler makes of it is
+``tests/test_tpu_compile.py``."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dlrover_tpu.ops.pallas import mla_prefill
+from dlrover_tpu.serving import latent
+from dlrover_tpu.serving.params import serving_params_from_llama
+from perfbench.weights_glm5 import SeededGlm5Params
+from tests.test_glm5_reference import fresh_cache, tiny
+
+BS = 8                 # rows a page
+
+# name -> the run: its first position, its queries, the table's pages,
+# the SELECTION's and the loop's pages a key block (the kernel's own
+# are ``PAGES_PER_BLOCK``, 4: blocks of 32 keys), the selection's size,
+# and positions whose index keys outscore all others (the selection is
+# then those, wherever they lie)
+_CASES = {
+    # 37 rows: neither the kernel's 32 nor the loop's 64 divides them
+    "ragged_depth": dict(start=21, klen=16, table=16, pages=8, topk=8),
+    # the table holds 4 kernel blocks, the run sees 2: the pages behind
+    # them are NaN in the pool the kernel reads
+    "dead_block_nan": dict(start=40, klen=16, table=16, pages=8, topk=8,
+                           poison=True),
+    # no more keys than a query may choose: plain causal attention
+    "no_selection": dict(start=16, klen=16, table=8, pages=4, topk=4096),
+    # every query chooses positions 32 .. 39: the kernel's blocks 0, 2
+    # and 3 hold nothing for either tile of queries (48 = 32 + 16)
+    "block_masked_out": dict(start=64, klen=48, table=16, pages=8, topk=8,
+                             boosted=range(32, 40)),
+    # the run begins and ends inside a page
+    "mid_page_start": dict(start=13, klen=10, table=8, pages=2, topk=8),
+}
+
+
+def _inputs(cfg, dtype, start, klen, table, seed=0, boosted=()):
+    rng = np.random.RandomState(seed)
+    width = latent.latent_row_width(cfg)
+    hi, di = cfg.index_n_heads, cfg.index_head_dim
+    n_pages = table + 3
+    lat = rng.randn(n_pages, BS, width).astype(np.float32)
+    lat[..., cfg.kv_lora_rank + cfg.qk_rope_head_dim:] = 0.0
+    keys = 0.1 * rng.randn(n_pages, BS, di).astype(np.float32)
+    ids = rng.permutation(n_pages - 1)[:table].astype(np.int32) + 1
+    for p in boosted:
+        keys[ids[p // BS], p % BS] = 1.0
+    qq = rng.randn(klen, cfg.num_heads, width).astype(np.float32)
+    qq[..., cfg.kv_lora_rank + cfg.qk_rope_head_dim:] = 0.0
+    q_i = np.abs(rng.randn(klen, hi, di)).astype(np.float32)
+    w = np.abs(rng.randn(klen, hi)).astype(np.float32)
+    return dict(
+        qq=jnp.asarray(qq, dtype), q_i=jnp.asarray(q_i, dtype),
+        w=jnp.asarray(w), q_pos=jnp.arange(start, start + klen),
+        latent_pool=jnp.asarray(lat, dtype),
+        index_pool=jnp.asarray(keys, dtype), table_row=jnp.asarray(ids))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_CASES))
+def test_kernel_is_the_loop(case, dtype):
+    spec = dict(_CASES[case])
+    pages, poison = spec.pop("pages"), spec.pop("poison", False)
+    cfg = tiny(index_topk=spec.pop("topk"), dtype=dtype)
+    args = _inputs(cfg, dtype, **spec)
+    want, chosen = latent._attend_run(**args, cfg=cfg, pages=pages)
+    mine = mla_prefill.block_pages(spec["table"])
+    if poison:
+        live = (spec["start"] + spec["klen"] - 1) // (mine * BS) + 1
+        assert live * mine < spec["table"]
+        dead = args["table_row"][live * mine:]
+        args["latent_pool"] = args["latent_pool"].at[dead].set(jnp.nan)
+    got, chosen_k = latent._attend_run(
+        **args, cfg=cfg, pages=pages, impl="pallas", interpret=True)
+    np.testing.assert_array_equal(chosen_k, chosen)
+    if "boosted" in spec:
+        np.testing.assert_array_equal(
+            np.flatnonzero(np.asarray(chosen).any(axis=0)),
+            list(spec["boosted"]))
+    else:
+        assert np.asarray(chosen).any(axis=1).all()
+    assert np.isfinite(np.asarray(got)).all()
+    # float32: the two differ in the order of the sums alone.  bfloat16:
+    # ``p`` is rounded against the running max of ITS block, and blocks
+    # of 32 and of 64 keys round it at different points
+    same_blocks = mine == pages
+    tol = 5e-6 if same_blocks or dtype == jnp.float32 else 1e-2
+    np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+
+
+def test_key_blocks_are_whole_blocks_to_the_last_query():
+    walked, held = mla_prefill.key_blocks(
+        [1, 512, 513, 40_000], block_size=128, table_width=264)
+    assert (walked, held) == (1 + 1 + 2 + 66, 4 * 66)
+
+
+def test_a_chunk_through_the_kernel_is_the_chunk_through_the_loop():
+    """``verify_step`` on a chunk at an offset, with a watched slot: the
+    kernel's path gives the loop's logits (to rounding), the loop's
+    selection bit for bit in every layer, and pools that hold the same
+    rows."""
+    cfg = tiny()
+    sp = serving_params_from_llama(
+        {"params": SeededGlm5Params(cfg, 7)}, cfg)
+    seq = np.random.RandomState(3).randint(0, 128, 48).astype(np.int32)
+    slot = jnp.zeros(1, jnp.int32)
+
+    def step(impl):
+        return jax.jit(lambda p, c, t, at: latent.verify_step(
+            p, cfg, c, t, at, slots=slot, attention_impl=impl,
+            kernel_interpret=True))
+
+    _, behind = step("xla")(sp, fresh_cache(cfg), jnp.asarray(seq[None, :32]),
+                            jnp.asarray([0], jnp.int32))
+    behind = dict(behind, watch_slot=jnp.int32(0))
+    out = {impl: step(impl)(sp, behind, jnp.asarray(seq[None, 32:]),
+                            jnp.asarray([32], jnp.int32))
+           for impl in ("xla", "pallas")}
+    (want, cache_x), (got, cache_p) = out["xla"], out["pallas"]
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    bits = np.asarray(cache_x["witness"]["chosen_bits"])
+    assert bits.shape[:2] == (cfg.num_layers, 16) and bits.any()
+    np.testing.assert_array_equal(cache_p["witness"]["chosen_bits"], bits)
+    for name in ("latent_pool", "index_pool"):
+        for a, b in zip(cache_p[name], cache_x[name]):
+            np.testing.assert_allclose(a, b, atol=2e-5)
+    np.testing.assert_array_equal(cache_p["moe_picks"], cache_x["moe_picks"])

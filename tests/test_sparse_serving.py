@@ -156,6 +156,8 @@ def test_the_books_are_inert_for_a_dense_model():
     assert (st.dsa_rows_live, st.index_rows_scanned, st.attn_rows_selected,
             st.moe_picks, st.moe_picks_held) == (0, 0, 0, 0, 0)
     assert st.dsa_selected_ratio == 0.0 and st.moe_held_share == 0.0
+    assert eng._book_key_blocks(np.ones(2)) == {}
+    assert (st.prefill_key_blocks, st.prefill_key_block_share) == (0, 0.0)
     assert "moe_picks" not in eng._cache and eng._prefill_group == 2
     assert "watch_slot" not in eng._cache and eng.witness_log == []
     with pytest.raises(ValueError, match="witness"):
@@ -278,6 +280,11 @@ def test_a_watched_request_is_witnessed_by_a_step_that_waits_once():
     assert (st.dispatches, st.chained_dispatches) == (2 * 3 + 2, 3 + 1)
     assert picks == sorted(picks) and picks[0] > 0
     assert st.moe_picks == picks[-1] and 0 < st.moe_picks_held < st.moe_picks
+    # a prompt's three chunk programs end at 16, 32 and 48, the last one
+    # padded: 1 + 1 + 2 of the kernel's blocks of 4 pages x 8 rows, where
+    # a table of 12 pages (16 in whole selection blocks) holds 4
+    assert (st.prefill_key_blocks, st.prefill_key_blocks_table) == (8, 24)
+    assert st.prefill_key_block_share == pytest.approx(1 / 3)
 
 
 def _verdicts(checked):
@@ -352,13 +359,18 @@ _DENSE = {
 }
 
 
-@pytest.mark.parametrize("program", sorted(_DENSE))
+@pytest.mark.parametrize("program", sorted(_DENSE) + ["prefill_chunk.pallas"])
 def test_dense_model_traces_what_it_did(program, tmp_path):
     """``decode_step``, ``prefill`` and the chunked prefill of a dense
     model are, to the letter, the programs the tree before the latent
-    blocks traced (``serve-batch-closed`` compiles the same).  A change
-    that means to move them, or a JAX that prints them otherwise,
-    re-pins: the text is left in a file to diff."""
+    blocks traced (``serve-batch-closed`` compiles the same); the chunked
+    prefill also where the engine hands it the kernels' options, as it
+    has since a latent model's run of queries takes a kernel (PR 37): a
+    dense run of queries takes none.  A change that means to move them,
+    or a JAX that prints them otherwise, re-pins: the text is left in a
+    file to diff."""
+    program, _, impl = program.partition(".")
+    kernels = dict(attention_impl=impl, kernel_interpret=True) if impl else {}
     cfg = LlamaConfig.tiny(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
     sp = jax.eval_shape(lambda: serving_params_from_llama(
         {"params": SeededParams(cfg, 3)}, cfg))
@@ -381,7 +393,7 @@ def test_dense_model_traces_what_it_did(program, tmp_path):
     else:
         jaxpr = jax.make_jaxpr(
             lambda p, c, t, pos, sl, li: serving_model.verify_step(
-                p, cfg, c, t, pos, slots=sl, logits_index=li))(
+                p, cfg, c, t, pos, slots=sl, logits_index=li, **kernels))(
             sp, cache, ints(1, 8), ints(1), ints(1), ints(1))
     text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
     (tmp_path / f"{program}.txt").write_text(text)

@@ -193,6 +193,42 @@ def test_index_score_kernel_compiles_at_glm5_widths(one_chip):
     assert "paged_index_scores" in text and "tpu_custom_call" in text
 
 
+def test_latent_prefill_kernel_compiles_under_its_scope(one_chip):
+    """A prefill chunk's query run at the served cell's geometry (512
+    queries, 64 heads, rows of 640, a table of 264 pages of 128): the
+    selection, then ``mla_prefill_attention`` as ONE custom call that the
+    program's own scopes place under ``mla_attn`` by the name the trace
+    will show, ``mla_prefill_attn``."""
+    from dlrover_tpu.models.llama import LlamaConfig
+    from dlrover_tpu.serving import latent
+    from dlrover_tpu.utils.profiler import device_scope, parse_program
+
+    cfg = LlamaConfig.glm5(num_layers=1, dtype=jnp.bfloat16)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def run(qq, q_i, w, q_pos, lat, idx, table):
+        with device_scope("prefill_chunk"):
+            return latent._attend_run(
+                qq, q_i, w, q_pos, lat, idx, table, cfg,
+                latent.KEY_BLOCK_PAGES, "pallas")
+
+    lowered = jax.jit(run).lower(
+        s((512, 64, 640), jnp.bfloat16), s((512, 32, 128), jnp.bfloat16),
+        s((512, 32), jnp.float32), s((512,), jnp.int32),
+        s((2700, 128, 640), jnp.bfloat16), s((2700, 128, 128), jnp.bfloat16),
+        s((264,), jnp.int32))
+    table = parse_program(
+        "run", lowered.compile().as_text(),
+        {"prefill_chunk", "dsa_index", "dsa_select", "mla_attn"},
+        lowered.as_text(debug_info=True))
+    assert table.complete, table.missing
+    kernels = {n: scope for n, scope in table.scope_of.items()
+               if n.startswith("mla_prefill_attn")}
+    assert kernels and set(kernels.values()) == {"mla_attn"}, kernels
+
+
 def test_paged_decode_int4_is_refused_loudly(one_chip):
     """Packed int4 pools do not compile on a TPU (minor dimension 64);
     until the pool is re-laid the kernel refuses in the repo's own
