@@ -140,7 +140,8 @@ class LlamaConfig:
     # ``qk_rope_head_dim`` a token, shared by all heads; a head's key is
     # ``qk_nope_head_dim`` of the latent's up-projection beside that row,
     # its value ``v_head_dim`` of it; the rotary dimensions rotate in
-    # adjacent pairs.  Served only (serving/latent.py)
+    # adjacent pairs.  ``q_lora_rank`` 0: no bottleneck, one ``W_q`` from
+    # the hidden state.  Served only (serving/latent.py)
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -160,8 +161,16 @@ class LlamaConfig:
     layers: Optional[Tuple[LayerSpec, ...]] = None
     # RMSNorm with a learned scale over the WHOLE projected query and the
     # whole projected key, before the split into heads and before RoPE
-    # (OLMoE, OLMo-2)
+    # (OLMoE, OLMo-2).  Of a latent-attention model: over each query
+    # head's ``qk_nope_head_dim + qk_rope_head_dim`` values, one scale for
+    # all heads, before rotation; the key's norm is the latent's RMSNorm
     qk_norm: bool = False
+    # the rotary embedding of a latent-attention model where it is not
+    # plain RoPE at ``rope_theta`` (YaRN: ``rope_inverse_frequencies``)
+    rope_scaling: Optional[RopeSpec] = None
+    # the softmax scale is ``head_dim_ ** -0.5`` times this (``deepseek_yarn``
+    # multiplies it by ``yarn_mscale(factor, mscale_all_dim) ** 2``)
+    attn_scale_mult: float = 1.0
     # q/k/v projection biases (Qwen2-family checkpoints; o_proj stays
     # bias-free in every supported architecture)
     attention_bias: bool = False
@@ -204,6 +213,13 @@ class LlamaConfig:
             raise ValueError(
                 f"{len(self.layers)} layer descriptions for num_layers="
                 f"{self.num_layers}")
+        if (self.rope_scaling or self.attn_scale_mult != 1.0) \
+                and not self.kv_lora_rank:
+            raise ValueError(
+                "rope_scaling / attn_scale_mult describe a latent-attention "
+                "model (serving/latent.py); a grouped-query model whose "
+                "layers scale their frequencies says so a layer "
+                "(layers=..., LayerSpec.rope)")
 
     @property
     def layer_specs(self) -> Tuple[LayerSpec, ...]:
@@ -211,14 +227,18 @@ class LlamaConfig:
         if self.layers is not None:
             return self.layers
         def one(mlp):
-            return LayerSpec(num_heads=self.num_heads,
-                             rope=RopeSpec(theta=self.rope_theta), mlp=mlp)
+            return LayerSpec(num_heads=self.num_heads, rope=self.rope, mlp=mlp)
 
         if not self.num_experts:
             return (one("dense"),) * self.num_layers
         lead = min(self.moe_first_dense, self.num_layers)
         return (one("dense"),) * lead + (one("sparse"),) * (
             self.num_layers - lead)
+
+    @property
+    def rope(self) -> RopeSpec:
+        """The rotary embedding of a model whose layers are all alike."""
+        return self.rope_scaling or RopeSpec(theta=self.rope_theta)
 
     @property
     def rope_kinds(self) -> Tuple[RopeSpec, ...]:
@@ -234,7 +254,7 @@ class LlamaConfig:
         h, d = self.hidden_size, self.head_dim_
         if self.kv_lora_rank:
             heads, q, c = spec.num_heads, self.q_lora_rank, self.kv_lora_rank
-            n = (h * q + q + q * heads * d
+            n = ((h * q + q + q * heads * d if q else h * heads * d)
                  + h * (c + self.qk_rope_head_dim) + c
                  + c * heads * (self.qk_nope_head_dim + self.v_head_dim)
                  + heads * self.v_head_dim * h + 2 * h)
@@ -247,7 +267,8 @@ class LlamaConfig:
         if self.attn_head_gate:
             n += h * spec.num_heads
         if self.qk_norm:
-            n += d * (spec.num_heads + self.num_kv_heads)
+            n += d if self.kv_lora_rank else d * (
+                spec.num_heads + self.num_kv_heads)
         if spec.mlp == "sparse":
             held = (self.moe_experts_held or (0, self.num_experts))[1]
             n += 3 * h * self.expert_width * held + h * self.num_experts
@@ -394,6 +415,69 @@ class LlamaConfig:
         return cls(**base)
 
     @classmethod
+    def sarvam_105b(cls, **kw) -> "LlamaConfig":
+        """sarvamai/sarvam-105b (``sarvam_mla``) as its config.json has it:
+        32 layers of latent attention, 64 heads of 128 + 64 / 128 over a
+        latent of 512, the query straight from the hidden state (no
+        bottleneck), ``deepseek_yarn`` x 40 over 4096 positions (beta 32 /
+        1, theta 1e4; ``mscale`` = ``mscale_all_dim`` = 1, so cos and sin
+        are scaled by 1 and the softmax scale by ``(0.1 ln 40 + 1)^2``);
+        one leading dense SwiGLU of 16384, then 128 sigmoid-routed experts
+        of 2048, 8 a token chosen by score + bias, weights over their sum
+        x 2.5, beside one shared expert; vocabulary 262144, untied.
+        Served, not trained.  ``num_layers``, ``moe_experts_held`` and
+        ``vocab_size`` give one chip its share.
+
+        Assumed, where the config names a mechanism and not its equation:
+        ``use_qk_norm`` is an RMSNorm with one learned scale over each
+        query head's 192 values before rotation, and the RMSNorm on the
+        latent is the key's (a norm over a head's up-projected key could
+        not be absorbed into the query, and the cache would not be the
+        published 576-wide row); the rotated 64 rotate in adjacent
+        pairs."""
+        import math
+
+        m = 0.1 * 1.0 * math.log(40.0) + 1.0     # mscale_all_dim = 1
+        base = dict(
+            vocab_size=262144,
+            hidden_size=4096,
+            intermediate_size=16384,
+            num_layers=32,
+            num_heads=64,
+            num_kv_heads=64,
+            max_seq_len=131072,
+            rope_theta=10000.0,
+            rms_norm_eps=1e-6,
+            q_lora_rank=0,
+            kv_lora_rank=512,
+            qk_nope_head_dim=128,
+            qk_rope_head_dim=64,
+            v_head_dim=128,
+            qk_norm=True,
+            rope_scaling=RopeSpec(
+                theta=10000.0, yarn_factor=40.0,
+                yarn_original_max_len=4096, yarn_beta_fast=32.0,
+                yarn_beta_slow=1.0, attention_factor=1.0),
+            attn_scale_mult=m * m,
+            num_experts=128,
+            moe_top_k=8,
+            moe_norm_topk_prob=True,
+            moe_intermediate_size=2048,
+            moe_score_fn="sigmoid",
+            moe_routed_scale=2.5,
+            moe_shared_width=2048,
+            moe_select_bias=True,
+            moe_first_dense=1,
+            moe_per_expert_init=True,
+            moe_aux_loss_coef=0.0,
+            moe_z_loss_coef=0.0,
+            scan_layers=False,
+            remat=False,
+        )
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
     def from_preset(
         cls, name: str, num_layers: int = 0, **kw
     ) -> "LlamaConfig":
@@ -425,7 +509,8 @@ class LlamaConfig:
 
 
 #: presets the entry points (examples/, the serving worker) can name
-PRESETS = ("tiny", "llama2_7b", "olmoe_1b_7b", "laguna_xs2", "glm5")
+PRESETS = ("tiny", "llama2_7b", "olmoe_1b_7b", "laguna_xs2", "glm5",
+           "sarvam_105b")
 
 
 def resolve_remat_policy(name: str):

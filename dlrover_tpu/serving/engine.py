@@ -125,7 +125,10 @@ class EngineStats:
     kv_rows_streamed: int = 0     # key rows that kernel copied for
     #                               them: whole page groups up to each
     #                               slot's length.  Both stay 0 where
-    #                               the gather path decodes
+    #                               the gather path decodes.  Of a
+    #                               latent-attention model that attends
+    #                               its whole context: one layer's
+    #                               latent rows (mla_decode_attention)
     # a model with a learned selection of keys (LlamaConfig.index_topk),
     # summed over queries (decode forwards and prefill chunks alike) and
     # over nothing else: one layer's rows, as every layer reads the same
@@ -415,7 +418,8 @@ class InferenceEngine:
                 "(native), 'int8' or 'int4'")
         self.kv_budget_x = 1.0
         # latent attention (serving/latent.py): rows without a head axis
-        # in a latent pool and an index-key pool
+        # in a latent pool and, of a model with an indexer, an index-key
+        # pool
         self._latent = bool(cfg.kv_lora_rank)
         if self._latent and not (
                 self.paged and self.kv_dtype is None and mesh is None
@@ -476,16 +480,15 @@ class InferenceEngine:
                         jnp.zeros(shape + (latent_row_width(cfg),),
                                   cfg.dtype)
                         for _ in range(cfg.num_layers)],
-                    "index_pool": [
-                        jnp.zeros(shape + (cfg.index_head_dim
-                                           if cfg.index_topk else 0,),
-                                  cfg.dtype)
-                        for _ in range(cfg.num_layers)],
                     "table": jnp.asarray(self._table_np),
                     # the slot whose forward the programs hand back
                     # (``watch``); -1: none
                     "watch_slot": jnp.asarray(-1, jnp.int32),
                 }
+                if cfg.index_topk:
+                    self._cache["index_pool"] = [
+                        jnp.zeros(shape + (cfg.index_head_dim,), cfg.dtype)
+                        for _ in range(cfg.num_layers)]
                 if cfg.num_experts:
                     # [picks, picks on held experts], wrapping: the host
                     # adds differences (_book_moe_picks)
@@ -578,8 +581,9 @@ class InferenceEngine:
         self._prefill_group = 1 if self._latent else self.max_slots
         # names of the two per-layer pool lists a bucketed prefill's
         # results are scattered into
-        self._pool_names = (("latent_pool", "index_pool") if self._latent
-                            else ("k_pool", "v_pool"))
+        self._pool_names = ("k_pool", "v_pool") if not self._latent else (
+            ("latent_pool", "index_pool") if cfg.index_topk
+            else ("latent_pool",))
         # paged decode attention: gather (xla) vs fused kernel
         # (pallas), resolved ONCE at build — "auto" measures both on
         # this engine's real pool geometry and picks the faster
@@ -625,7 +629,7 @@ class InferenceEngine:
             return req, None
         if self._latent and not self._kernel_interpret:
             self.attention_impl_why = (
-                "auto: the index kernel reads live pages only, the gather "
+                "auto: the decode kernels read live pages only, the gather "
                 "the whole table; not measured")
             return "pallas", None
         if self._kernel_interpret:
@@ -1533,16 +1537,23 @@ class InferenceEngine:
     def _book_kv_rows(self, active: np.ndarray,
                       chunks: int = 1) -> Tuple[int, int]:
         """Add to ``stats.kv_rows_live`` / ``kv_rows_streamed`` what
-        the fused paged kernel reads in the next ``chunks`` decode
+        the fused paged kernel (of a latent-attention model with no
+        selection: ``mla_decode_attention``, one layer's latent rows)
+        reads in the next ``chunks`` decode
         chunks dispatched from ``_positions`` for the slots ``active``
         (the lengths it will be handed: ``position + 1``, one more
         each forward; every other slot gets length 0 and reads
         nothing), and return the two sums.  Host integer arithmetic
         on a [slots, forwards] array; (0, 0) where the gather path
-        decodes."""
-        if self.attention_impl != "pallas" or self._latent:
+        decodes and for a learned selection (``_book_selection``)."""
+        if self.attention_impl != "pallas" or (
+                self._latent and self.cfg.index_topk):
             return 0, 0
-        from dlrover_tpu.ops.pallas.paged_attention import streamed_rows
+        if self._latent:
+            from dlrover_tpu.ops.pallas.mla_decode import streamed_rows
+        else:
+            from dlrover_tpu.ops.pallas.paged_attention import \
+                streamed_rows
 
         lengths = self._positions[active][:, None] \
             + np.arange(1, chunks * self.chunk + 1)[None, :]
@@ -1620,7 +1631,8 @@ class InferenceEngine:
         ``seen``, that program's own ``witness`` output for the slot
         (``serving/latent.py verify_step``; device arrays, a decode
         chunk's with a leading axis of forwards): the rows each query
-        attended to and the first sparse MLP's input and output, which a
+        attended to (of a model with no selection of keys: its logits)
+        and the first sparse MLP's input and output, which a
         selection of keys and a share of the experts otherwise leave to
         show only in the logits.  ``None`` stops.  A latent-attention
         model's engine only: no other program keeps a witness."""

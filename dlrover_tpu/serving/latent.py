@@ -1,20 +1,27 @@
-"""The served forward of a latent-attention model with a learned selection
-of keys and sparse experts (``LlamaConfig.kv_lora_rank``; the preset is
-``LlamaConfig.glm5``): the blocks ``serving/model.py``'s ``verify_step``
-and ``prefill`` run in place of the grouped-query ones.
+"""The served forward of a latent-attention model with sparse experts
+(``LlamaConfig.kv_lora_rank``), with a learned selection of keys (the
+preset ``LlamaConfig.glm5``) or WITHOUT one, every query attending to its
+whole context (``LlamaConfig.sarvam_105b``): the blocks
+``serving/model.py``'s ``verify_step`` and ``prefill`` run in place of the
+grouped-query ones.
 
 One layer, on its normed input ``h`` (positions ``t``, ``s``):
 
-- *latent attention*: ``c_q = RMSNorm(W_qa h)``, ``q = W_qb c_q`` in heads
-  of ``[nope | rope]``; ``[c | k_r] = W_kva h``, ``c_kv = RMSNorm(c)``,
-  ``k_r`` rotated (one row for all heads, adjacent pairs), ``q_rope``
+- *latent attention*: ``c_q = RMSNorm(W_qa h)``, ``q = W_qb c_q`` (or,
+  with ``q_lora_rank`` 0, ``q = W_q h``: no bottleneck) in heads of
+  ``[nope | rope]``, each head RMS-normed where ``qk_norm`` says so;
+  ``[c | k_r] = W_kva h``, ``c_kv = RMSNorm(c)``,
+  ``k_r`` rotated (one row for all heads, adjacent pairs, at the
+  frequencies of ``LlamaConfig.rope``: plain or YaRN's), ``q_rope``
   rotated alike.  The cache keeps ``[c_kv | k_r]`` a token a layer and
   nothing a head.  Attention runs ABSORBED: ``q~_h = W_kvb,h[K]^T q_nope_h``
   scores the latent row itself, ``score_h[t, s] = (q~_h[t] . c_kv[s] +
-  q_rope_h[t] . k_r[s]) / sqrt(nope + rope)``, and the value projection
-  ``W_kvb,h[V]`` is applied to the attended latent, once a query.
-- *the indexer*: ``q_i = W_iq c_q`` in ``index_n_heads`` heads, ``k_i =
-  LayerNorm(W_ik h)`` one row a token (cached beside the latent row), the
+  q_rope_h[t] . k_r[s]) / sqrt(nope + rope) x attn_scale_mult``, and the
+  value projection ``W_kvb,h[V]`` is applied to the attended latent, once
+  a query.
+- *the indexer* (``index_topk`` > 0): ``q_i = W_iq c_q`` in
+  ``index_n_heads`` heads, ``k_i = LayerNorm(W_ik h)`` one row a token
+  (cached beside the latent row), the
   first ``rope`` dimensions of both rotated, ``w = W_iw h / sqrt(heads x
   size)`` in float32; ``I[t, s] = sum_h w[t, h] relu(q_i[t, h] . k_i[s])``
   for ``s <= t``, and query ``t`` attends to the ``index_topk`` largest
@@ -28,9 +35,13 @@ One layer, on its normed input ``h`` (positions ``t``, ``s``):
 Two attention paths, both reading the pools through the block table and
 neither making a dense copy of a slot's table:
 
-- decode (one query a slot, every slot): the index scores of a slot's
-  LIVE pages from the ``paged_index_scores`` kernel, ``jax.lax.top_k``,
-  a gather of the chosen latent rows, two einsums.
+- decode (one query a slot, every slot), with a selection: the index
+  scores of a slot's LIVE pages from the ``paged_index_scores`` kernel,
+  ``jax.lax.top_k``, a gather of the chosen latent rows, two einsums.
+  Without one: ``ops/pallas/mla_decode.py mla_decode_attention``, one
+  kernel over each slot's live pages of the latent pool
+  (``attention_impl="pallas"``), or its ``jnp`` oracle
+  ``gather_latent_decode``, group by group of the same live pages.
 - a run of queries (a prefill chunk, a speculative verify, a bucketed
   prefill): row by row of the group (``lax.map``), over the row's live
   key blocks only (trip counts read from the positions): the index
@@ -54,7 +65,8 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from dlrover_tpu.models.llama import LlamaConfig
+from dlrover_tpu.models.llama import (LlamaConfig, RopeSpec,
+                                      rope_inverse_frequencies)
 from dlrover_tpu.models.moe import grouped_matmul, route
 from dlrover_tpu.serving.model import _lm_head, _mm, _rmsnorm
 from dlrover_tpu.serving.paged import scatter_tokens
@@ -85,19 +97,23 @@ def _layernorm(x, scale, bias, eps=1e-6):
             * scale.astype(jnp.float32) + bias.astype(jnp.float32))
 
 
-def rope_pairs(x: jax.Array, positions: jax.Array, theta: float,
+def rope_pairs(x: jax.Array, positions: jax.Array, spec: RopeSpec,
                rotary: int) -> jax.Array:
     """The first ``rotary`` dimensions of ``x`` rotated in ADJACENT pairs
-    ``(x_2i, x_2i+1)`` by ``positions * theta^(-2i / rotary)``; the rest
+    ``(x_2i, x_2i+1)`` by ``positions`` x the inverse frequencies of
+    ``spec`` over those ``rotary`` (``models/llama.py
+    rope_inverse_frequencies``, training's: plain ``theta^(-2i / rotary)``
+    or YaRN's blend), cos and sin times its ``attention_factor``; the rest
     pass.  ``positions`` has ``x``'s leading dimensions (fewer broadcast
     over the rest, heads).  Float32 out."""
-    inv = 1.0 / (theta ** (
-        jnp.arange(0, rotary, 2, dtype=jnp.float32) / rotary))
+    inv = rope_inverse_frequencies(spec, rotary)
     ang = positions.astype(jnp.float32)[..., None] * inv
     ang = ang.reshape(positions.shape
                       + (1,) * (x.ndim - 1 - positions.ndim)
                       + (rotary // 2,))
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if spec.attention_factor != 1.0:
+        cos, sin = cos * spec.attention_factor, sin * spec.attention_factor
     xf = x.astype(jnp.float32)
     pairs = xf[..., :rotary].reshape(*x.shape[:-1], rotary // 2, 2)
     x0, x1 = pairs[..., 0], pairs[..., 1]
@@ -115,14 +131,26 @@ def _projections(lp, h, cfg: LlamaConfig, pos, dtype):
     b, k = h.shape[:2]
     c, r = cfg.kv_lora_rank, cfg.qk_rope_head_dim
     nope, heads = cfg.qk_nope_head_dim, cfg.num_heads
+    rope = cfg.rope
     with device_scope("mla_proj"):
-        c_q = _rmsnorm(_mm(h, lp["wq_a"], dtype), lp["q_a_norm"],
-                       cfg.rms_norm_eps).astype(dtype)
-        q = _mm(c_q, lp["wq_b"], dtype).reshape(b, k, heads, nope + r)
-        q_rope = rope_pairs(q[..., nope:], pos, cfg.rope_theta, r)
+        if "wq_a" in lp:               # the query through its bottleneck
+            c_q = _rmsnorm(_mm(h, lp["wq_a"], dtype), lp["q_a_norm"],
+                           cfg.rms_norm_eps).astype(dtype)
+            q = _mm(c_q, lp["wq_b"], dtype).reshape(b, k, heads, nope + r)
+        else:
+            # W_q^T, [H x (nope + rope), E]: the layout the chip's
+            # compiler gives the weight itself where heads of 192 follow
+            # (with [E, H x 192] it copied every layer's 100 MB ahead of
+            # each decode chunk: compiled for a described v5e, PR 41)
+            q = jnp.einsum("bke,ne->bkn", h.astype(dtype),
+                           lp["wq_t"].astype(dtype)
+                           ).astype(dtype).reshape(b, k, heads, nope + r)
+        if "q_norm" in lp:             # a head at a time, before rotation
+            q = _rmsnorm(q, lp["q_norm"], cfg.rms_norm_eps).astype(dtype)
+        q_rope = rope_pairs(q[..., nope:], pos, rope, r)
         ckv = _mm(h, lp["wkv_a"], dtype)
         c_kv = _rmsnorm(ckv[..., :c], lp["kv_a_norm"], cfg.rms_norm_eps)
-        k_r = rope_pairs(ckv[..., c:], pos, cfg.rope_theta, r)
+        k_r = rope_pairs(ckv[..., c:], pos, rope, r)
         pad = latent_row_width(cfg) - c - r
         row = jnp.concatenate(
             [c_kv, k_r, jnp.zeros((b, k, pad), jnp.float32)],
@@ -139,11 +167,11 @@ def _projections(lp, h, cfg: LlamaConfig, pos, dtype):
         with device_scope("dsa_index"):
             q_i = rope_pairs(
                 _mm(c_q, lp["iwq"], dtype).reshape(b, k, hi, di),
-                pos, cfg.rope_theta, r).astype(dtype)
+                pos, rope, r).astype(dtype)
             k_i = rope_pairs(
                 _layernorm(_mm(h, lp["iwk"], dtype), lp["ik_norm_scale"],
                            lp["ik_norm_bias"]),
-                pos, cfg.rope_theta, r).astype(dtype)
+                pos, rope, r).astype(dtype)
             w = jnp.dot(h.astype(dtype), lp["iw"].astype(dtype),
                         preferred_element_type=jnp.float32
                         ) * float((hi * di) ** -0.5)
@@ -151,7 +179,7 @@ def _projections(lp, h, cfg: LlamaConfig, pos, dtype):
 
 
 def _softmax_scale(cfg: LlamaConfig) -> float:
-    return float(cfg.head_dim_ ** -0.5)
+    return float(cfg.head_dim_ ** -0.5 * cfg.attn_scale_mult)
 
 
 def _orderable(x: jax.Array) -> jax.Array:
@@ -271,12 +299,26 @@ def _attend_decode(qq, q_i, w, latent_pool, index_pool, table, lengths,
     """One query a slot: ``qq`` [B, H, C + R], ``q_i`` [B, Hi, Di], ``w``
     [B, Hi], ``lengths`` [B] the keys each slot sees (0: its output is
     not wanted).  Returns the attended latent [B, H, C] float32 and the
-    positions attended to, [B, S] int32 (-1: none)."""
+    positions attended to, [B, S] int32 (-1: none; None for a model with
+    no selection: a query attends to every row behind it)."""
     from dlrover_tpu.ops.pallas import paged_index
 
     b, mb = table.shape
     bs, c = latent_pool.shape[1], cfg.kv_lora_rank
-    if cfg.index_topk and mb * bs > cfg.index_topk:
+    if not cfg.index_topk:
+        from dlrover_tpu.ops.pallas import mla_decode
+
+        with device_scope("mla_attn"):
+            if impl == "pallas":
+                o = mla_decode.mla_decode_attention(
+                    qq, latent_pool, table, lengths, c=c,
+                    scale=_softmax_scale(cfg), interpret=interpret)
+            else:
+                o = mla_decode.gather_latent_decode(
+                    qq, latent_pool, table, lengths, c=c,
+                    scale=_softmax_scale(cfg))
+        return o, None
+    if mb * bs > cfg.index_topk:
         with device_scope("dsa_index"):
             if impl == "pallas":
                 scores = paged_index.paged_index_scores(
@@ -406,7 +448,11 @@ def verify_step(
     [layers, S] int32 positions, -1 none; a run: ``chosen_bits`` [layers,
     K, table rows / 8] uint8, ``jnp.packbits`` of the mask), and the first
     sparse MLP's normed input and output (``sparse_in``, ``sparse_out``
-    [K, E]).  A slot that is not among the rows leaves junk there."""
+    [K, E]).  A slot that is not among the rows leaves junk there.  A model
+    with NO selection has no rows to tell of (every query attends to every
+    row behind it) and hands back, in their place, what the selection
+    otherwise makes the only judge of: ``logits`` [V] float32, the slot's
+    own (decode: this forward's; a run: at its ``logits_index``)."""
     dtype = cfg.dtype
     b, klen = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0)            # [B, K, E]
@@ -436,8 +482,9 @@ def verify_step(
         qq, row, q_i, k_i, w = _projections(lp, h, cfg, pos_k, dtype)
         lat = cache["latent_pool"][i]
         lat = scatter_tokens(lat, table, row.astype(lat.dtype), positions)
-        idx = cache["index_pool"][i]
+        idx = None
         if cfg.index_topk:
+            idx = cache["index_pool"][i]
             idx = scatter_tokens(idx, table, k_i.astype(idx.dtype),
                                  positions)
         if decode:
@@ -452,7 +499,7 @@ def verify_step(
                                       a[4], cfg, KEY_BLOCK_PAGES,
                                       attention_impl, kernel_interpret),
                 (qq, q_i, w, pos_k, run_table))
-        if watch is not None:
+        if watch is not None and cfg.index_topk:
             selections.append(jnp.take(chosen, watch, axis=0))
         x = x + _attn_out(lp, o_lat, cfg, dtype)
         h = _rmsnorm(x, lp["post_norm"], cfg.rms_norm_eps).astype(dtype)
@@ -471,15 +518,19 @@ def verify_step(
         x = jnp.take_along_axis(
             x, logits_index.astype(jnp.int32)[:, None, None], axis=1)
     logits = _lm_head(params, x.astype(dtype), cfg)
-    out_cache = dict(cache, latent_pool=latent_pools,
-                     index_pool=index_pools)
+    out_cache = dict(cache, latent_pool=latent_pools)
+    if cfg.index_topk:
+        out_cache["index_pool"] = index_pools
     if picks is not None:
         out_cache["moe_picks"] = picks
-    if watch is not None:
+    if watch is not None and cfg.index_topk:
         chosen = jnp.stack(selections)
         out_cache["witness"] = dict(seen, **(
             {"rows": chosen} if decode
             else {"chosen_bits": jnp.packbits(chosen, axis=-1)}))
+    elif watch is not None:
+        out_cache["witness"] = dict(
+            seen, logits=jnp.take(logits[:, 0], watch, axis=0))
     return logits, out_cache
 
 
@@ -488,8 +539,9 @@ def prefill(params: Dict[str, Any], cfg: LlamaConfig, tokens: jax.Array,
     """``serving/model.py prefill`` for a latent model: a causal pass over
     a group of right-padded prompts with no cache behind them; returns
     (last logits [G, V], per-layer cache rows [G, Lp, C + R], per-layer
-    index keys [G, Lp, Di]) for the engine to scatter.  A prompt is one
-    key block here, so scores are [Lp, heads, Lp]: buckets the size of a
+    index keys [G, Lp, Di]: none of a model with no indexer) for the
+    engine to scatter.  A prompt is one key block here, so scores are
+    [Lp, heads, Lp]: buckets the size of a
     prefill chunk, which is all an engine with ``prefill_chunk`` sends.
     Its attention stays the ``jnp`` loop of :func:`_attend_run` whatever
     the engine's ``attention_impl`` (one block of the prompt's own rows,
@@ -505,18 +557,18 @@ def prefill(params: Dict[str, Any], cfg: LlamaConfig, tokens: jax.Array,
     for lp in params["layers"]:
         h = _rmsnorm(x, lp["input_norm"], cfg.rms_norm_eps).astype(dtype)
         qq, row, q_i, k_i, w = _projections(lp, h, cfg, pos, dtype)
-        if k_i is None:
-            k_i = jnp.zeros((g, lp_len, 0), dtype)
         # each prompt's own rows as a pool of one page
         o_lat, _ = jax.lax.map(
-            lambda a: _attend_run(a[0], a[1], a[2], a[3], a[4][None],
-                                  a[5][None], a[6], cfg, 1),
+            lambda a: _attend_run(
+                a[0], a[1], a[2], a[3], a[4][None],
+                None if a[5] is None else a[5][None], a[6], cfg, 1),
             (qq, q_i, w, pos, row, k_i, one_page))
         x = x + _attn_out(lp, o_lat, cfg, dtype)
         h = _rmsnorm(x, lp["post_norm"], cfg.rms_norm_eps).astype(dtype)
         x = x + _mlp(lp, h, cfg, dtype, counted)[0]
         rows.append(row)
-        keys.append(k_i)
+        if k_i is not None:
+            keys.append(k_i)
     x = _rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
     last = jnp.take_along_axis(
         x, (jnp.atleast_1d(real_len).astype(jnp.int32) - 1)[:, None, None],

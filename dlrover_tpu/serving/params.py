@@ -125,7 +125,7 @@ def serving_params_from_llama(
     the Pallas kernel layout at load time."""
     import flax.linen as nn
 
-    if cfg.qk_norm:
+    if cfg.qk_norm and not cfg.kv_lora_rank:
         raise ValueError(
             "the serving engine's attention blocks have no QK-norm "
             f"(qk_norm={cfg.qk_norm}): the model would be served as a "
@@ -201,10 +201,14 @@ def serving_params_from_llama(
 def _latent_params(variables: Any, cfg: LlamaConfig, dtype
                    ) -> Dict[str, Any]:
     """The serving tree of a latent-attention model (serving/latent.py)
-    from a ``layer_{i}`` tree named as ``perfbench/reference_glm5.py`` and
-    the tests make it: ``attn`` (``q_a_proj``, ``q_a_norm``, ``q_b_proj``
-    [Q, H, nope + rope], ``kv_a_proj`` [E, C + rope], ``kv_a_norm``,
-    ``kv_b_proj`` [C, H, nope + V], ``o_proj`` [H, V, E]), ``indexer``
+    from a ``layer_{i}`` tree named as ``perfbench/reference_glm5.py``,
+    ``perfbench/reference_sarvam.py`` and the tests make it: ``attn`` (the
+    query through a bottleneck, ``q_a_proj``, ``q_a_norm``, ``q_b_proj``
+    [Q, H, nope + rope], or with ``q_lora_rank`` 0 straight from the
+    hidden state, ``q_proj`` [E, H, nope + rope]; with ``qk_norm`` a
+    ``q_norm`` scale [nope + rope] for every head; ``kv_a_proj`` [E, C +
+    rope], ``kv_a_norm``, ``kv_b_proj`` [C, H, nope + V], ``o_proj`` [H,
+    V, E]), with ``index_topk`` an ``indexer``
     (``wq_b`` [Q, Hi, Di], ``wk``, ``k_norm`` scale and bias,
     ``weights_proj``), and ``mlp`` as ``LlamaModel`` names a dense one or
     ``MoEMLP`` a sparse one (``select_bias`` beside the router).  The
@@ -213,6 +217,11 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
     and its bias stay float32."""
     import flax.linen as nn
 
+    if cfg.index_topk and not cfg.q_lora_rank:
+        raise ValueError(
+            "the indexer's queries come from the query's bottleneck "
+            f"(index_topk={cfg.index_topk}, q_lora_rank=0): no published "
+            "model has the one without the other")
     variables = nn.meta.unbox(variables)
     params = variables["params"] if "params" in variables else variables
     nope = cfg.qk_nope_head_dim
@@ -229,9 +238,6 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
         out = {
             "input_norm": p["input_norm"]["scale"],
             "post_norm": p["post_norm"]["scale"],
-            "wq_a": mat(a["q_a_proj"]["kernel"]),
-            "q_a_norm": a["q_a_norm"]["scale"],
-            "wq_b": flat_out(a["q_b_proj"]["kernel"]),
             "wkv_a": mat(a["kv_a_proj"]["kernel"]),
             "kv_a_norm": a["kv_a_norm"]["scale"],
             "wkv_b_k": kv_b[..., :nope].transpose(1, 2, 0),
@@ -239,6 +245,14 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
             "wo": mat(a["o_proj"]["kernel"]).reshape(
                 -1, cfg.hidden_size),
         }
+        if cfg.q_lora_rank:
+            out.update(wq_a=mat(a["q_a_proj"]["kernel"]),
+                       q_a_norm=a["q_a_norm"]["scale"],
+                       wq_b=flat_out(a["q_b_proj"]["kernel"]))
+        else:
+            out["wq_t"] = flat_out(a["q_proj"]["kernel"]).T
+        if cfg.qk_norm:
+            out["q_norm"] = a["q_norm"]["scale"]
         if cfg.index_topk:
             ix = p["indexer"]
             out.update(

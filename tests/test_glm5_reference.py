@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from dlrover_tpu.models import moe
-from dlrover_tpu.models.llama import LlamaConfig, LlamaModel
+from dlrover_tpu.models.llama import LlamaConfig, LlamaModel, RopeSpec
 from dlrover_tpu.serving import latent
 from dlrover_tpu.serving.params import serving_params_from_llama
 from perfbench import reference_glm5 as ref
@@ -122,7 +122,7 @@ def test_rope_rotates_adjacent_pairs():
                        ).sum(-1)
     np.testing.assert_allclose(pairs(y), pairs(x), rtol=1e-5)
     np.testing.assert_allclose(
-        latent.rope_pairs(x, pos, 1e4, 8), y, atol=1e-6)
+        latent.rope_pairs(x, pos, RopeSpec(theta=1e4), 8), y, atol=1e-6)
     # the first pair turns by the position itself
     np.testing.assert_allclose(
         y[1, 0, 0], x[1, 0, 0] * np.cos(1.0) - x[1, 0, 1] * np.sin(1.0),

@@ -229,6 +229,88 @@ def test_latent_prefill_kernel_compiles_under_its_scope(one_chip):
     assert kernels and set(kernels.values()) == {"mla_attn"}, kernels
 
 
+def test_latent_decode_kernel_compiles_at_sarvam_widths(one_chip):
+    """``mla_decode_attention`` at the served cell's geometry: 32 slots,
+    64 heads, rows of 640, pages of 128 rows, a table of 258."""
+    from dlrover_tpu.ops.pallas.mla_decode import mla_decode_attention
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(
+        lambda q, p, t, n: mla_decode_attention(q, p, t, n, c=512,
+                                                scale=0.135)).lower(
+        s((32, 64, 640), jnp.bfloat16), s((2500, 128, 640), jnp.bfloat16),
+        s((32, 258), jnp.int32), s((32,), jnp.int32)).compile().as_text()
+    assert "mla_decode_attn" in text and "tpu_custom_call" in text
+
+
+def test_sarvam_decode_forward_streams_live_pages_only(one_chip,
+                                                       monkeypatch):
+    """One decode forward of ``sarvam-105b-serve`` at the cell's widths (32
+    slots, tables of 258 pages of 128 rows, experts 0-31 of 128, a quarter
+    of the vocabulary; the leading dense layer and one sparse layer):
+    the attention is ONE custom call a layer under ``mla_attn`` by the
+    name the trace will show, and the program's text holds no buffer of
+    ``slots x max_blocks x block_size`` rows: neither the dense gather of
+    every row the table could hold nor scores against them."""
+    import re
+
+    from dlrover_tpu.models import moe
+    from dlrover_tpu.models.llama import LlamaConfig
+    from dlrover_tpu.serving import latent
+    from dlrover_tpu.serving.model import decode_step
+    from dlrover_tpu.serving.params import serving_params_from_llama
+    from dlrover_tpu.utils.profiler import device_scope, parse_program
+    from perfbench.weights_sarvam import SeededSarvamParams
+
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    cfg = LlamaConfig.sarvam_105b(
+        num_layers=2, moe_experts_held=(0, 32), vocab_size=65536,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    slots, mb, bs, nb = 32, 258, 128, 2500
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    sp = on_chip(jax.eval_shape(lambda: serving_params_from_llama(
+        {"params": SeededSarvamParams(cfg, 3)}, cfg)))
+    S = jax.ShapeDtypeStruct
+    cache = on_chip({
+        "latent_pool": [S((nb, bs, latent.latent_row_width(cfg)),
+                          jnp.bfloat16)] * cfg.num_layers,
+        "table": S((slots, mb), jnp.int32),
+        "moe_picks": S((2,), jnp.uint32),
+        "watch_slot": S((), jnp.int32)})
+
+    def forward(p, c, t, pos, act):
+        with device_scope("decode_chunk"):
+            return decode_step(p, cfg, c, t, pos, attention_impl="pallas",
+                               active=act)
+
+    lowered = jax.jit(forward, donate_argnums=(1,)).lower(
+        sp, cache, *on_chip((S((slots,), jnp.int32), S((slots,), jnp.int32),
+                             S((slots,), jnp.bool_))))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    table = parse_program(
+        "decode", text, {"decode_chunk", "mla_attn", "mla_proj",
+                         "moe_route", "moe_experts", "moe_shared", "mlp"},
+        lowered.as_text(debug_info=True))
+    assert table.complete, table.missing
+    kernels = {n: scope for n, scope in table.scope_of.items()
+               if n.startswith("mla_decode_attn")}
+    assert len(kernels) == cfg.num_layers \
+        and set(kernels.values()) == {"mla_attn"}, kernels
+    rows = mb * bs
+    assert not re.search(rf"\[{slots},(\d+,)?{rows}[,\]]", text)
+    assert not re.search(rf"\[{slots},{rows},\d+\]", text)
+    # the logits and a layer's activations, not gigabytes of gathered rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2**20
+
+
 def test_paged_decode_int4_is_refused_loudly(one_chip):
     """Packed int4 pools do not compile on a TPU (minor dimension 64);
     until the pool is re-laid the kernel refuses in the repo's own
