@@ -16,9 +16,15 @@ consumed in VMEM, flash-attention fashion:
 - the grid is ``(K / tq,)``: a program holds ``tq`` queries x H heads as
   the ROWS of one 2-D matmul (``tq`` 32 and 64 heads: 2048 rows) and walks
   ITS live key blocks in a loop whose trip count is read from the
-  positions (scalar prefetch): ``q_pos[last of the tile] // keys a block
-  + 1``.  A block behind the run's depth, or wholly above the tile's
-  queries (causal), costs no DMA and no compute;
+  positions (scalar prefetch): ``q_pos[last REAL query of the tile] //
+  keys a block + 1``.  A block behind the run's depth, or wholly above
+  the tile's queries (causal), costs no DMA and no compute;
+- only the run's first ``n_real`` queries are anybody's (a prompt's last
+  chunk is padded to the chunk program's shape): a tile whose first
+  query stands at or behind ``n_real`` walks NO block, starts no DMA and
+  writes zeros, and a tile that straddles it walks as deep as its last
+  real query.  The one block that drops is one no real query can see,
+  so the real queries' results are the bits they were;
 - a key block is ``pages`` pages of the pool, copied by double-buffered
   manual DMA through the scalar-prefetched block table, the next block's
   pages in flight under this block's matmuls (``paged_index.py``'s
@@ -35,7 +41,11 @@ consumed in VMEM, flash-attention fashion:
 Layout:
   qq     [K, H, W]        absorbed queries (zeros behind C + R)
   bias   [K, MB * bs] f32 0 where query k attends key s, -inf elsewhere
+                          (a row at or behind ``n_real``: -inf throughout,
+                          the caller's to see to, so that a padded query
+                          of a straddling tile comes out zero too)
   q_pos  [K] int32        positions, ascending
+  n_real int32 scalar     the run's real queries (default: all K)
   pool   [NB, bs, W]      latent rows, paged
   table  [MB] int32       the sequence's pages (whole key blocks; 0 =
                           the trash block)
@@ -80,7 +90,7 @@ _NEG_INF = -jnp.inf
 
 
 def _prefill_kernel(
-    table_ref, qpos_ref,               # scalar-prefetched (SMEM)
+    table_ref, qpos_ref, nreal_ref,    # scalar-prefetched (SMEM)
     q_ref, bias_ref, pool_hbm, o_ref,
     kbuf, sem, m_scr, l_scr, acc_scr,
     *, tq: int, heads: int, pages: int, block_size: int, num_blocks: int,
@@ -94,13 +104,21 @@ def _prefill_kernel(
             pool_hbm.at[table_ref[g * pages + j]], kbuf.at[slot, j],
             sem.at[slot, j]) for j in range(pages)]
 
-    # the tile's last query sees no key behind its own position
-    n_live = jnp.minimum(qpos_ref[i * tq + tq - 1] // kb + 1, num_blocks)
+    # the tile's last REAL query sees no key behind its own position; a
+    # tile of padding walks nothing
+    first, n_real = i * tq, nreal_ref[0]
+    last = jnp.maximum(jnp.minimum(first + tq, n_real) - 1, 0)
+    n_live = jnp.where(
+        first < n_real,
+        jnp.minimum(qpos_ref[last] // kb + 1, num_blocks), 0)
     m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
-    for cp in _copies(0, 0):
-        cp.start()
+
+    @pl.when(n_live > 0)
+    def _():             # a copy started is a copy the loop waits for
+        for cp in _copies(0, 0):
+            cp.start()
 
     def body(g, _):
         slot = jax.lax.rem(g, 2)
@@ -149,8 +167,9 @@ def block_pages(table_width: int) -> int:
 def key_blocks(ends, block_size: int, table_width: int,
                pages: Optional[int] = None):
     """``(attended, held)``: the key blocks walked for runs whose last
-    query stands at ``ends - 1`` (whole blocks of ``pages`` pages, the
-    kernel's own by default, up to it, never more than the table) and the
+    REAL query stands at ``ends - 1`` (whole blocks of ``pages`` pages,
+    the kernel's own by default, up to it, never more than the table;
+    what pads a run to its program's shape walks nothing) and the
     blocks their tables hold (host arithmetic, for a caller that books
     what it attends; a later roofline reader counts FLOPs from the
     first).  The kernel's tiles of queries before a run's last stop at
@@ -162,6 +181,16 @@ def key_blocks(ends, block_size: int, table_width: int,
     return int(live.sum()), held * ends.size
 
 
+def query_tiles(n_real, klen: int):
+    """``(live, held)``: the tiles of queries that hold a real query, the
+    only ones that walk a key block, and the tiles their programs hold,
+    for runs of ``klen`` queries whose first ``n_real`` are real (host
+    arithmetic, for a caller that books what its padding costs)."""
+    tq = min(QUERIES_PER_TILE, klen)
+    n_real = np.asarray(n_real)
+    return int((-(-n_real // tq)).sum()), -(-klen // tq) * n_real.size
+
+
 @functools.partial(
     jax.jit, static_argnames=("c", "scale", "pages", "queries_per_tile",
                               "interpret"))
@@ -171,6 +200,7 @@ def mla_prefill_attention(
     q_pos: jax.Array,    # [K] int32
     pool: jax.Array,     # [NB, bs, W]
     table: jax.Array,    # [MB] int32
+    n_real: Optional[jax.Array] = None,  # int32 scalar; default: K
     *,
     c: int,
     scale: float,
@@ -192,7 +222,7 @@ def mla_prefill_attention(
         q_pos = jnp.pad(q_pos, (0, pad), mode="edge")
     rows = tq * heads
 
-    def per_tile(i, table_ref, qpos_ref):
+    def per_tile(i, table_ref, qpos_ref, nreal_ref):
         return (i, 0)
 
     out = pl.pallas_call(
@@ -200,7 +230,7 @@ def mla_prefill_attention(
             _prefill_kernel, tq=tq, heads=heads, pages=pages, block_size=bs,
             num_blocks=mb // pages, c=c, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=((klen + pad) // tq,),
             in_specs=[pl.BlockSpec((rows, w), per_tile),
                       pl.BlockSpec((tq, mb * bs), per_tile),
@@ -221,6 +251,7 @@ def mla_prefill_attention(
         # ``mla_prefill_attn.<n>``
         name="mla_prefill_attn",
     )(table.astype(jnp.int32), q_pos.astype(jnp.int32),
+      jnp.full((1,), klen if n_real is None else n_real, jnp.int32),
       qq.reshape((klen + pad) * heads, w),
       bias.astype(jnp.float32), pool)
     return out.reshape(klen + pad, heads, c)[:klen]

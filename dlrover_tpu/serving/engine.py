@@ -109,11 +109,17 @@ class EngineStats:
     #                               step was still unread: its host
     #                               preparation cost the device no gap
     # a latent-attention model's prompt chunks (one layer's, as every
-    # layer walks the same): host arithmetic from each chunk's end
+    # layer walks the same): host arithmetic from each chunk's REAL end
+    # (what pads a prompt's last chunk to the program's shape attends
+    # nothing)
     prefill_key_blocks: int = 0   # key blocks their attention walked:
     #                               whole blocks up to each chunk's last
-    #                               query
+    #                               real query
     prefill_key_blocks_table: int = 0  # key blocks their tables hold
+    prefill_query_tiles: int = 0  # tiles of queries the chunk programs
+    #                               held (the attention kernel's)
+    prefill_query_tiles_live: int = 0  # ... with a real query among
+    #                               them: the only ones that walk keys
     finished_requests: int = 0
     spec_proposed: int = 0        # draft tokens sent to verification
     spec_accepted: int = 0        # draft tokens accepted
@@ -198,6 +204,16 @@ class EngineStats:
         gather a slot's whole table)."""
         return self.prefill_key_blocks / self.prefill_key_blocks_table \
             if self.prefill_key_blocks_table else 0.0
+
+    @property
+    def prefill_live_tile_share(self) -> float:
+        """Tiles of queries with a real query among them over the tiles
+        the prompt chunks' programs held: 1.0 while every chunk is whole,
+        lower by what pads prompts' last chunks, which the attention
+        skips but the chunk's projections, index scan and MLPs still
+        compute (0.0 for a model whose chunks gather a slot's table)."""
+        return self.prefill_query_tiles_live / self.prefill_query_tiles \
+            if self.prefill_query_tiles else 0.0
 
     @property
     def kv_stream_ratio(self) -> float:
@@ -1238,7 +1254,7 @@ class InferenceEngine:
             self._push_table()
         started = time.perf_counter()
         attrs = {"n": g, **self._book_selection(starts, ends),
-                 **self._book_key_blocks(starts + c)}
+                 **self._book_key_blocks(starts, ends)}
         with self._dispatching("prefill_chunk"):
             # one dispatch for all rows, or one a row where the model
             # asks for that (``_prefill_group``); the cache and the last
@@ -1600,25 +1616,34 @@ class InferenceEngine:
         self.stats.attn_rows_selected += book["attn_rows_selected"]
         return book
 
-    def _book_key_blocks(self, ends) -> Dict[str, int]:
-        """Book the key blocks a latent-attention model's prompt chunks
-        walk whose PROGRAMS end at ``ends`` (a chunk's padding is a
-        query too), in the blocks of the path that walks them
-        (``serving/latent.py _attend_run``), beside what their tables
-        hold; returns the first for the dispatch's span ({} for any
-        other model)."""
+    def _book_key_blocks(self, starts, ends) -> Dict[str, int]:
+        """Book what the attention of a latent-attention model's prompt
+        chunks walks, whose real queries stand at ``starts[i] ..
+        ends[i] - 1`` (behind them a chunk is padding, which attends
+        nothing): key blocks up to each last real query, in the blocks
+        of the path that walks them (``serving/latent.py _attend_run``),
+        beside what their tables hold; and the attention kernel's tiles
+        of queries with a real query among them, beside the tiles the
+        chunk programs hold.  Returns what was walked for the dispatch's
+        span ({} for any other model)."""
         if not self._latent:
             return {}
-        from dlrover_tpu.ops.pallas.mla_prefill import key_blocks
+        from dlrover_tpu.ops.pallas.mla_prefill import (key_blocks,
+                                                        query_tiles)
         from dlrover_tpu.serving.latent import KEY_BLOCK_PAGES
 
         table = -(-self._max_blocks // KEY_BLOCK_PAGES) * KEY_BLOCK_PAGES
         walked, held = key_blocks(
             ends, self.block_size, table,
             None if self.attention_impl == "pallas" else KEY_BLOCK_PAGES)
+        live, tiles = query_tiles(
+            np.asarray(ends) - np.asarray(starts), self.prefill_chunk)
         self.stats.prefill_key_blocks += walked
         self.stats.prefill_key_blocks_table += held
-        return {"key_blocks": walked}
+        self.stats.prefill_query_tiles += tiles
+        self.stats.prefill_query_tiles_live += live
+        return {"key_blocks": walked, "query_tiles": tiles,
+                "query_tiles_live": live}
 
     def watch(self, wanted) -> None:
         """Keep what the engine's own programs do for ONE request at a

@@ -55,7 +55,10 @@ neither making a dense copy of a slot's table:
   ``jnp`` loop of :func:`_attend_run`, the kernel's oracle, whose tiles
   pass through HBM.  Either way a run computes scores against every live
   row, chosen or not: masked-dense at the MXU's pace.  Attending the
-  chosen rows alone is ROADMAP Reach A12, a row gather away.
+  chosen rows alone is ROADMAP Reach A12, a row gather away.  A prompt's
+  last chunk is padded to its program's shape: the queries behind
+  ``logits_index`` are nobody's, select and attend nothing, and the
+  kernel walks no key block for a tile of them.
 """
 
 from __future__ import annotations
@@ -207,12 +210,19 @@ def _kth_largest(keys: jax.Array, k: int) -> jax.Array:
 
 def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
                 cfg: LlamaConfig, pages: int, impl: str = "xla",
-                interpret: bool = False):
+                interpret: bool = False, n_real=None):
     """A run of queries of ONE sequence against its cached rows, this
     run's own among them: ``qq`` [K, H, C + R], ``q_i`` [K, Hi, Di], ``w``
     [K, Hi], ``q_pos`` [K] ascending, ``table_row`` [MB] (a multiple of
     ``pages``).  Returns the attended latent [K, H, C] float32 and the
-    selection [K, MB x bs] bool.  Work follows ``q_pos[-1]``, not MB.
+    selection [K, MB x bs] bool.  Work follows the last REAL query's
+    position, not MB: ``n_real`` (an int32 scalar; None: all K) says how
+    many of the run's queries are anybody's, the rest being what pads a
+    prompt's last chunk to its program's shape.  A padded query selects
+    and attends nothing (zeros out), no key block behind the last real
+    query is scored or walked, and a real query's selection and result
+    are what they are with ``n_real`` None, bit for bit: the one block
+    that drops is one no real query can see.
 
     The selection is computed the same way whatever ``impl``; the masked
     attention under it is the ``mla_prefill_attention`` kernel with
@@ -226,8 +236,14 @@ def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
     kb = pages * bs                               # keys a block
     n_blocks = table_row.shape[0] // pages
     width = n_blocks * kb
-    n_live = jnp.minimum((q_pos[-1] + kb) // kb, n_blocks)
+    last = q_pos[-1] if n_real is None else q_pos[n_real - 1]
+    n_live = jnp.minimum((last + kb) // kb, n_blocks)
     scale = _softmax_scale(cfg)
+
+    def real_only(chosen):
+        if n_real is None:
+            return chosen
+        return chosen & (jnp.arange(klen) < n_real)[:, None]
 
     def block(pool, j):
         ids = jax.lax.dynamic_slice_in_dim(table_row, j * pages, pages)
@@ -252,11 +268,12 @@ def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
                 0, n_live, score_block,
                 jnp.full((klen, width), dead, jnp.uint32))
         with device_scope("dsa_select"):
-            chosen = (keys >= _kth_largest(keys, cfg.index_topk)[:, None]
-                      ) & (keys > dead)
+            chosen = real_only(
+                (keys >= _kth_largest(keys, cfg.index_topk)[:, None])
+                & (keys > dead))
     else:
         # no more keys than a query may choose: plain causal attention
-        chosen = jnp.arange(width)[None, :] <= q_pos[:, None]
+        chosen = real_only(jnp.arange(width)[None, :] <= q_pos[:, None])
 
     with device_scope("mla_attn"):
         if impl == "pallas":
@@ -266,7 +283,7 @@ def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
 
             o_lat = mla_prefill_attention(
                 qq, jnp.where(chosen, 0.0, _NEG_INF), q_pos, latent_pool,
-                table_row, c=c, scale=scale, interpret=interpret)
+                table_row, n_real, c=c, scale=scale, interpret=interpret)
             return o_lat, chosen
 
         def attend_block(j, carry):
@@ -471,6 +488,9 @@ def verify_step(
         run_table = _pad_table(table, KEY_BLOCK_PAGES)
         counted = jnp.ones((b, klen), bool) if logits_index is None else (
             jnp.arange(klen)[None, :] <= logits_index[:, None])
+        # a row's real queries: behind ``logits_index`` a run is padding
+        n_real = None if logits_index is None \
+            else logits_index.astype(jnp.int32) + 1
     picks = cache.get("moe_picks")
     watch = cache.get("watch_slot")
     if watch is not None:
@@ -497,8 +517,9 @@ def verify_step(
             o_lat, chosen = jax.lax.map(
                 lambda a: _attend_run(a[0], a[1], a[2], a[3], lat, idx,
                                       a[4], cfg, KEY_BLOCK_PAGES,
-                                      attention_impl, kernel_interpret),
-                (qq, q_i, w, pos_k, run_table))
+                                      attention_impl, kernel_interpret,
+                                      a[5]),
+                (qq, q_i, w, pos_k, run_table, n_real))
         if watch is not None and cfg.index_topk:
             selections.append(jnp.take(chosen, watch, axis=0))
         x = x + _attn_out(lp, o_lat, cfg, dtype)

@@ -138,6 +138,8 @@ class RouterMetrics:
         self.attn_rows_selected = 0.0
         self.moe_picks = 0.0
         self.moe_picks_held = 0.0
+        self.prefill_query_tiles = 0.0
+        self.prefill_query_tiles_live = 0.0
         self.dispatches = 0.0
         self.chained_dispatches = 0.0
         # prefix-cache fleet aggregates (engine-side COW ledger summed
@@ -302,7 +304,9 @@ class RouterMetrics:
         self.kv_rows_streamed = sum(
             d.get("kv_rows_streamed", 0.0) for d in dicts)
         for name in ("dsa_rows_live", "attn_rows_selected", "moe_picks",
-                     "moe_picks_held", "dispatches", "chained_dispatches"):
+                     "moe_picks_held", "prefill_query_tiles",
+                     "prefill_query_tiles_live", "dispatches",
+                     "chained_dispatches"):
             setattr(self, name, sum(d.get(name, 0.0) for d in dicts))
         for attr, key in (
             ("prefix_hits", "prefix_hits"),
@@ -418,6 +422,9 @@ class RouterMetrics:
             "serving_moe_held_share": (
                 self.moe_picks_held / self.moe_picks
                 if self.moe_picks else 0.0),
+            "serving_prefill_live_tile_share": (
+                self.prefill_query_tiles_live / self.prefill_query_tiles
+                if self.prefill_query_tiles else 0.0),
             "serving_sched_capacity_evals_total":
                 self.sched_capacity_evals,
             "serving_sched_rounds_skipped_total":

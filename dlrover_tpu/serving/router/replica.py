@@ -184,7 +184,9 @@ class InferenceEngineAdapter:
             # the sums, so that a fleet's ratio weighs by work (all 0
             # for a model with neither)
             for name in ("dsa_rows_live", "attn_rows_selected",
-                         "moe_picks", "moe_picks_held"):
+                         "moe_picks", "moe_picks_held",
+                         "prefill_query_tiles",
+                         "prefill_query_tiles_live"):
                 out[name] = float(getattr(st, name, 0))
             # prefix-cache ledger (all-float, so the dict still rides
             # STATS frames as-is); dense engines have no sharing

@@ -203,6 +203,15 @@ METRIC_HELP: Dict[str, str] = {
         "contexts fit the selection, index_topk / context beyond; 0 = "
         "no replica serves such a model"
     ),
+    "serving_prefill_live_tile_share": (
+        "tiles of queries with a real query among them over the tiles "
+        "the prompt chunks' programs held, on replicas that serve a "
+        "latent-attention model, over prefill chunks so far: 1.0 while "
+        "every chunk is whole; what is missing is padding behind "
+        "prompts' last tokens, which the attention kernel skips and the "
+        "rest of a chunk still computes; 0 = no replica serves such a "
+        "model"
+    ),
     "serving_moe_held_share": (
         "router picks that fell on the experts a replica holds over all "
         "its picks, fleet-wide (a replica that is one share of an "

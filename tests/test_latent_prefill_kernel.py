@@ -96,10 +96,72 @@ def test_kernel_is_the_loop(case, dtype):
     np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
 
 
+TQ = mla_prefill.QUERIES_PER_TILE
+RUN = 3 * TQ           # a chunk program of three tiles of queries
+_ATTEND = jax.jit(latent._attend_run, static_argnames=(
+    "cfg", "pages", "impl", "interpret"))
+
+
+@pytest.mark.parametrize("selection", [True, False],
+                         ids=["selected", "causal_only"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("n_real", [1, TQ - 1, TQ, TQ + 1, RUN - 1, RUN])
+def test_a_run_stops_at_its_last_real_query(n_real, dtype, selection):
+    """A chunk program of ``RUN`` queries of which the first ``n_real``
+    are a prompt's (the rest pad its last chunk): the real queries'
+    selection and attended latents are, BIT FOR BIT, the whole run's; a
+    padded query selects nothing and comes out exactly zero; the kernel
+    is the ``jnp`` loop; and neither reads a row behind the last real
+    query's key block (nor the trash block, which the table names behind
+    it): those hold NaN in both pools."""
+    start, table, pages = 40, 24, mla_prefill.block_pages(24)
+    cfg = tiny(index_topk=8 if selection else 4096, dtype=dtype)
+    args = _inputs(cfg, dtype, start, RUN, table)
+    run = dict(cfg=cfg, pages=pages, impl="pallas", interpret=True)
+    whole, chosen_whole = _ATTEND(**args, **run)
+    again, _ = _ATTEND(**args, **run, n_real=jnp.int32(RUN))
+    np.testing.assert_array_equal(again, whole)
+
+    live = (start + n_real - 1) // (pages * BS) + 1    # key blocks walked
+    assert live * pages < table
+    ids = np.array(args["table_row"])
+    dead = np.concatenate([[0], ids[live * pages:]])
+    ids[-pages:] = 0            # the engine pads a table with the trash
+    poisoned = dict(
+        args, table_row=jnp.asarray(ids),
+        latent_pool=args["latent_pool"].at[dead].set(jnp.nan),
+        index_pool=args["index_pool"].at[dead].set(jnp.nan))
+    got, chosen = _ATTEND(**poisoned, **run, n_real=jnp.int32(n_real))
+    want, chosen_loop = _ATTEND(
+        **poisoned, **dict(run, impl="xla"), n_real=jnp.int32(n_real))
+
+    got, want = np.asarray(got), np.asarray(want)
+    np.testing.assert_array_equal(got[:n_real], np.asarray(whole)[:n_real])
+    np.testing.assert_array_equal(chosen[:n_real], chosen_whole[:n_real])
+    assert np.asarray(chosen)[:n_real].any(axis=1).all()
+    for out, picked in ((got, chosen), (want, chosen_loop)):
+        assert not np.asarray(picked)[n_real:].any()
+        assert (out[n_real:] == 0).all() and np.isfinite(out).all()
+    np.testing.assert_array_equal(chosen_loop, chosen)
+    # the same key blocks in both: they differ in the order of sums
+    # alone, of bfloat16 products where the operands are that
+    tol = 5e-6 if dtype == jnp.float32 else 2e-4
+    np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+
+
 def test_key_blocks_are_whole_blocks_to_the_last_query():
     walked, held = mla_prefill.key_blocks(
         [1, 512, 513, 40_000], block_size=128, table_width=264)
     assert (walked, held) == (1 + 1 + 2 + 66, 4 * 66)
+
+
+def test_query_tiles_are_the_tiles_with_a_real_query():
+    # runs of 512 queries: 16 tiles of 32 each; 1, 32 and 33 real queries
+    # fill 1, 1 and 2 of them, a whole chunk all 16
+    assert mla_prefill.query_tiles([1, 32, 33, 512], 512) == (20, 64)
+    # a run shorter than a tile is one tile
+    assert mla_prefill.query_tiles([3, 16], 16) == (2, 2)
 
 
 def test_a_chunk_through_the_kernel_is_the_chunk_through_the_loop():

@@ -289,6 +289,53 @@ def test_engine_serves_it_behind_a_shared_prefix():
     assert (st.kv_rows_live, st.kv_rows_streamed) == (0, 0)
 
 
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_a_padded_last_chunk_attends_nothing_and_answers_the_same(impl):
+    """A question of 6 and one of 36 tokens behind a document of 64, in
+    chunk programs of 64 queries (two tiles of the prefill kernel's): the
+    padding behind each last token attends nothing.  Greedy, the tokens
+    are those of an engine that prefills each prompt whole (the bucketed
+    program, which has no padding) and the reference's argmax; the books
+    count the tiles with a real query."""
+    cfg = tiny(max_seq_len=192)
+    params = SeededSarvamParams(cfg, 7)
+    rng = np.random.RandomState(9)
+    doc = rng.randint(0, 128, 64).astype(np.int32)
+    prompts = [np.concatenate([doc, rng.randint(0, 128, n).astype(np.int32)])
+               for n in (6, 36)]
+    sizes = dict(max_len=192, prefill_buckets=(96, 128), max_slots=2,
+                 attention_impl=impl)
+    engine = _engine(cfg, params, prefill_chunk=64, **sizes)
+    engine.add_request(doc, 1)
+    _drain(engine, 1)
+    assert engine.stats.prefill_live_tile_share == 1.0   # a whole chunk
+    for prompt in prompts:
+        engine.add_request(prompt, 6)
+    got = _drain(engine, 2)
+    whole = _engine(cfg, params, prefill_chunk=0, prefix_sharing=False,
+                    **sizes)
+    for prompt in prompts:
+        whole.add_request(prompt, 6)
+    want = _drain(whole, 2)
+    assert whole.stats.prefill_chunks == 0
+    for req, cold in zip(got, want):
+        assert req.output == cold.output and len(req.output) == 6
+        seq = np.concatenate([req.prompt, np.asarray(req.output, np.int32)])
+        logits = np.asarray(reference_logits(cfg, params, seq))
+        at = req.prompt.size - 1 + np.arange(6)
+        assert (logits[at].max(-1)
+                - logits[at, np.asarray(req.output)]).max() < 1e-3
+    st = engine.stats
+    assert engine.prefix_stats()["prefix_shared_tokens"] == 2 * 64
+    # the document's chunk and the two tails': 2 + 1 + 2 of 3 x 2 tiles
+    assert (st.prefill_query_tiles, st.prefill_query_tiles_live) == (6, 5)
+    assert st.prefill_live_tile_share == pytest.approx(5 / 6)
+    # real ends 64, 70 and 100 in key blocks of 32 rows (the kernel's
+    # 4 pages) or 64 (the loop's 8)
+    assert st.prefill_key_blocks == (
+        2 + 3 + 4 if impl == "pallas" else 1 + 2 + 2)
+
+
 def test_engine_books_the_rows_the_kernel_streams(monkeypatch):
     """``kv_rows_streamed`` is the kernel's live page groups x their rows,
     and the decode chunk's span carries both counters."""
@@ -365,12 +412,16 @@ def serve_latent_watched():
 # GLM-5's served programs, as the parent of PR 41 traced them (the tiny
 # preset of tests/test_glm5_reference.py in bf16, paged pools, the
 # kernels' options as the engine hands them): (lines, sha256 of the
-# jaxpr's text)
+# jaxpr's text).  ``prefill_chunk`` is PR 42's: its attention is told
+# how many of the chunk's queries are the prompt's (``logits_index +
+# 1``: the kernel's third scalar, the selection's mask of padded rows,
+# trip counts from the last real query's position); the other two are
+# the texts they were
 _GLM5 = {
     "decode": (5280, "9e791ac037734e6758849d901747a6915d6473d2c89adf94ea80"
                      "fd7b40c3d61c"),
-    "prefill_chunk": (6422, "899ca000160a18499bea5f9ad5a0d67d3cdb7d0ede70"
-                            "789c692ab27775b6e097"),
+    "prefill_chunk": (6531, "2191d9240025eb16efd8a5070ced717311d6444dfc91"
+                            "4fb62cd234abfc053e0b"),
     "prefill": (5010, "3c37b0a227d855b7cf0046847617ad5712bfd23acd6424ded8"
                       "17c9fc93bd7aee"),
 }

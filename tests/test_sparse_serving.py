@@ -15,6 +15,7 @@ import pytest
 from dlrover_tpu.models.llama import LlamaConfig
 from dlrover_tpu.ops.pallas import paged_index
 from dlrover_tpu.serving import latent
+from dlrover_tpu.serving import engine as engine_module
 from dlrover_tpu.serving import model as serving_model
 from dlrover_tpu.serving.engine import InferenceEngine
 from dlrover_tpu.serving.params import serving_params_from_llama
@@ -156,30 +157,41 @@ def test_the_books_are_inert_for_a_dense_model():
     assert (st.dsa_rows_live, st.index_rows_scanned, st.attn_rows_selected,
             st.moe_picks, st.moe_picks_held) == (0, 0, 0, 0, 0)
     assert st.dsa_selected_ratio == 0.0 and st.moe_held_share == 0.0
-    assert eng._book_key_blocks(np.ones(2)) == {}
+    assert eng._book_key_blocks(np.zeros(2), np.ones(2)) == {}
     assert (st.prefill_key_blocks, st.prefill_key_block_share) == (0, 0.0)
+    assert (st.prefill_query_tiles, st.prefill_live_tile_share) == (0, 0.0)
     assert "moe_picks" not in eng._cache and eng._prefill_group == 2
     assert "watch_slot" not in eng._cache and eng.witness_log == []
     with pytest.raises(ValueError, match="witness"):
         eng.watch(lambda req: True)
 
 
-def test_the_two_gauges_reach_the_scrape():
+_GAUGES = {
+    "serving_dsa_selected_ratio": 0.08,
+    "serving_moe_held_share": 0.0625,
+    "serving_prefill_live_tile_share": 0.7,
+}
+
+
+@pytest.mark.parametrize("gauge", sorted(_GAUGES))
+def test_the_gauges_reach_the_scrape(gauge):
+    """A fleet's ratio weighs its replicas by their work: the sums of
+    what each reports, then the quotient."""
     from dlrover_tpu.serving.router.metrics import RouterMetrics
     from dlrover_tpu.utils.metric_registry import METRIC_HELP
 
     m = RouterMetrics()
     m.observe_engine_metrics([
         {"dsa_rows_live": 100.0, "attn_rows_selected": 8.0,
-         "moe_picks": 64.0, "moe_picks_held": 4.0},
+         "moe_picks": 64.0, "moe_picks_held": 4.0,
+         "prefill_query_tiles": 16.0, "prefill_query_tiles_live": 16.0},
         {"dsa_rows_live": 300.0, "attn_rows_selected": 24.0,
-         "moe_picks": 64.0, "moe_picks_held": 4.0}, {}])
-    out = m.metrics()
-    assert out["serving_dsa_selected_ratio"] == pytest.approx(0.08)
-    assert out["serving_moe_held_share"] == pytest.approx(0.0625)
-    assert RouterMetrics().metrics()["serving_dsa_selected_ratio"] == 0.0
-    for name in ("serving_dsa_selected_ratio", "serving_moe_held_share"):
-        assert name in METRIC_HELP
+         "moe_picks": 64.0, "moe_picks_held": 4.0,
+         "prefill_query_tiles": 64.0, "prefill_query_tiles_live": 40.0},
+        {}])
+    assert m.metrics()[gauge] == pytest.approx(_GAUGES[gauge])
+    assert RouterMetrics().metrics()[gauge] == 0.0
+    assert gauge in METRIC_HELP
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -280,11 +292,79 @@ def test_a_watched_request_is_witnessed_by_a_step_that_waits_once():
     assert (st.dispatches, st.chained_dispatches) == (2 * 3 + 2, 3 + 1)
     assert picks == sorted(picks) and picks[0] > 0
     assert st.moe_picks == picks[-1] and 0 < st.moe_picks_held < st.moe_picks
-    # a prompt's three chunk programs end at 16, 32 and 48, the last one
-    # padded: 1 + 1 + 2 of the kernel's blocks of 4 pages x 8 rows, where
-    # a table of 12 pages (16 in whole selection blocks) holds 4
+    # a prompt's three chunks end at 16, 32 and its last token, 40 or 37
+    # (behind it the third program is padding, which walks nothing):
+    # 1 + 1 + 2 of the kernel's blocks of 4 pages x 8 rows, where a
+    # table of 12 pages (16 in whole selection blocks) holds 4.  A chunk
+    # of 16 queries is one tile of the kernel's, and never without a
+    # real query
     assert (st.prefill_key_blocks, st.prefill_key_blocks_table) == (8, 24)
     assert st.prefill_key_block_share == pytest.approx(1 / 3)
+    assert (st.prefill_query_tiles, st.prefill_query_tiles_live) == (6, 6)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_a_padded_last_chunk_answers_as_whole_prompts_do(impl, monkeypatch):
+    """Prompts of 70 and 100 tokens in chunk programs of 64 queries (two
+    tiles of the attention kernel's): 58 and 28 rows of the last chunks
+    are padding, which attends nothing.  Greedy, the tokens are those of
+    an engine that prefills each prompt whole (the bucketed program,
+    which has no padding to skip) and the reference's argmax; the books,
+    the span and the gauge say what the padding was."""
+    from dlrover_tpu.serving.router.metrics import RouterMetrics
+    from dlrover_tpu.serving.router.replica import InferenceEngineAdapter
+
+    spans = []
+    inner = engine_module.span
+
+    def span(name, **attrs):
+        if name == "dlrover.engine.prefill_chunk" and attrs:
+            spans.append(attrs)
+        return inner(name, **attrs)
+
+    monkeypatch.setattr(engine_module, "span", span)
+    cfg = tiny(max_seq_len=192)
+    params = SeededGlm5Params(cfg, 9)
+    rng = np.random.RandomState(12)
+    prompts = [rng.randint(0, 128, n).astype(np.int32) for n in (70, 100)]
+    sizes = dict(max_len=192, prefill_buckets=(96, 128),
+                 attention_impl=impl)
+    eng = _engine(cfg, params, prefill_chunk=64, **sizes)
+    rids = [eng.add_request(p, 6) for p in prompts]
+    got = eng.run()
+    whole = _engine(cfg, params, prefill_chunk=0, **sizes)
+    wids = [whole.add_request(p, 6) for p in prompts]
+    want = whole.run()
+    assert whole.stats.prefill_chunks == 0 and eng.stats.prefill_chunks == 2
+    for prompt, rid, wid in zip(prompts, rids, wids):
+        assert got[rid].tolist() == want[wid].tolist()
+        out = np.asarray(got[rid])
+        assert out.size == 6
+        logits, _ = reference_logits(
+            cfg, params, np.concatenate([prompt, out]).astype(np.int32))
+        at = prompt.size - 1 + np.arange(6)
+        logits = np.asarray(logits)[at]
+        assert (logits.max(-1) - logits[np.arange(6), out]).max() < 1e-3
+
+    st = eng.stats
+    # four chunk programs of two tiles; the one with 6 real queries
+    # fills one of them
+    assert (st.prefill_query_tiles, st.prefill_query_tiles_live) == (8, 7)
+    assert st.prefill_live_tile_share == 7 / 8
+    # real ends 64, 70, 64 and 100 in key blocks of 32 rows (the kernel's
+    # 4 pages; the loop's 8 pages hold 64): by the programs' ends, 128
+    # twice, it was 2 + 4 + 2 + 4
+    blocks = (2 + 3 + 2 + 4) if impl == "pallas" else (1 + 2 + 1 + 2)
+    pages = -(-eng._max_blocks // 8) * 8       # whole selection blocks
+    held = 4 * pages // (4 if impl == "pallas" else 8)
+    assert (st.prefill_key_blocks, st.prefill_key_blocks_table) \
+        == (blocks, held)
+    for key, total in (("query_tiles", 8), ("query_tiles_live", 7),
+                       ("key_blocks", blocks)):
+        assert sum(a[key] for a in spans) == total
+    m = RouterMetrics()
+    m.observe_engine_metrics([InferenceEngineAdapter(eng).engine_metrics()])
+    assert m.metrics()["serving_prefill_live_tile_share"] == 7 / 8
 
 
 def _verdicts(checked):

@@ -195,7 +195,8 @@ def test_index_score_kernel_compiles_at_glm5_widths(one_chip):
 
 def test_latent_prefill_kernel_compiles_under_its_scope(one_chip):
     """A prefill chunk's query run at the served cell's geometry (512
-    queries, 64 heads, rows of 640, a table of 264 pages of 128): the
+    queries of which ``n_real`` are the prompt's, 64 heads, rows of 640, a
+    table of 264 pages of 128): the
     selection, then ``mla_prefill_attention`` as ONE custom call that the
     program's own scopes place under ``mla_attn`` by the name the trace
     will show, ``mla_prefill_attn``."""
@@ -208,17 +209,17 @@ def test_latent_prefill_kernel_compiles_under_its_scope(one_chip):
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def run(qq, q_i, w, q_pos, lat, idx, table):
+    def run(qq, q_i, w, q_pos, lat, idx, table, n_real):
         with device_scope("prefill_chunk"):
             return latent._attend_run(
                 qq, q_i, w, q_pos, lat, idx, table, cfg,
-                latent.KEY_BLOCK_PAGES, "pallas")
+                latent.KEY_BLOCK_PAGES, "pallas", n_real=n_real)
 
     lowered = jax.jit(run).lower(
         s((512, 64, 640), jnp.bfloat16), s((512, 32, 128), jnp.bfloat16),
         s((512, 32), jnp.float32), s((512,), jnp.int32),
         s((2700, 128, 640), jnp.bfloat16), s((2700, 128, 128), jnp.bfloat16),
-        s((264,), jnp.int32))
+        s((264,), jnp.int32), s((), jnp.int32))
     table = parse_program(
         "run", lowered.compile().as_text(),
         {"prefill_chunk", "dsa_index", "dsa_select", "mla_attn"},
