@@ -64,6 +64,9 @@ class LayerSpec:
     window: int = 0
     rope: RopeSpec = RopeSpec()
     mlp: str = "dense"            # "dense" | "sparse"
+    # what mixes tokens: "attn" (the model's attention block) | "kda"
+    # (linear attention with a recurrent state: LlamaConfig.kda_heads)
+    mixer: str = "attn"
 
 
 def layer_pattern(specs: Tuple[LayerSpec, ...]) -> Tuple[int, int]:
@@ -153,6 +156,16 @@ class LlamaConfig:
     index_n_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    # Kimi Delta Attention (a ``LayerSpec.mixer`` of "kda"): ``kda_heads``
+    # heads of ``kda_head_dim`` for keys and values alike, a causal
+    # depthwise convolution over the last ``kda_conv`` positions ahead of
+    # q, k and v, a log-decay a channel and an output gate through
+    # bottlenecks of ``kda_rank``; a head's state is one float32 matrix of
+    # ``kda_head_dim`` squared a sequence.  Served only (serving/linear.py)
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_rank: int = 0
     # a sigmoid gate on the attention output, one a query head, from the
     # layer's normed input
     attn_head_gate: bool = False
@@ -252,7 +265,16 @@ class LlamaConfig:
     def layer_params(self, spec: LayerSpec) -> int:
         """Parameters of one layer as this device holds it."""
         h, d = self.hidden_size, self.head_dim_
-        if self.kv_lora_rank:
+        if spec.mixer == "kda":
+            # q, k, v and their convolutions; the decay's and the gate's
+            # bottlenecks, the decay's bias and a head's rate; beta; the
+            # head norm; the output projection; the block's two norms
+            w = self.kda_heads * self.kda_head_dim
+            n = (3 * h * w + 3 * self.kda_conv * w
+                 + 2 * (h * self.kda_rank + self.kda_rank * w) + w
+                 + self.kda_heads + h * self.kda_heads + self.kda_head_dim
+                 + w * h + 2 * h)
+        elif self.kv_lora_rank:
             heads, q, c = spec.num_heads, self.q_lora_rank, self.kv_lora_rank
             n = ((h * q + q + q * heads * d if q else h * heads * d)
                  + h * (c + self.qk_rope_head_dim) + c
@@ -264,9 +286,9 @@ class LlamaConfig:
                       + h * self.index_n_heads)
         else:
             n = h * d * (spec.num_heads * 2 + self.num_kv_heads * 2) + 2 * h
-        if self.attn_head_gate:
+        if self.attn_head_gate and spec.mixer == "attn":
             n += h * spec.num_heads
-        if self.qk_norm:
+        if self.qk_norm and spec.mixer == "attn":
             n += d if self.kv_lora_rank else d * (
                 spec.num_heads + self.num_kv_heads)
         if spec.mlp == "sparse":
@@ -478,6 +500,75 @@ class LlamaConfig:
         return cls(**base)
 
     @classmethod
+    def kimi_linear_48b(cls, **kw) -> "LlamaConfig":
+        """moonshotai/Kimi-Linear-48B-A3B-Instruct (``kimi_linear``) as its
+        config.json has it: 27 layers in the pattern KDA, KDA, KDA, MLA
+        (``full_attn_layers`` 4, 8, .., 24, 27).  A KDA layer: 32 heads of
+        128 for keys and values, a causal depthwise convolution of 4 ahead
+        of q, k and v, a gated delta rule with a decay a channel
+        (``ops/pallas/kda.py``).  An MLA layer: 32 heads of 128 + 64 / 128
+        over a latent of 512 beside 64 further key values shared by all
+        heads, the query straight from the hidden state, NO positional
+        encoding (``mla_use_nope``: ``RopeSpec(rotary_fraction=0)``).
+        Layer 1's MLP a SwiGLU of 9216, then 256 sigmoid-routed experts of
+        1024, 8 a token chosen by score + bias, weights over their sum x
+        2.446, beside one shared expert; vocabulary 163840, untied.
+        Served, not trained.  ``num_layers`` cuts the pattern's depth;
+        ``moe_experts_held`` and ``vocab_size`` give one chip its share.
+
+        Assumed, where the config names a mechanism and not its equation
+        (``perfbench/configs/kimi-linear-48b-serve.json``): the
+        bottlenecks' width (the head size), the decay ``-exp(A_h) x
+        softplus(W_f2 W_f1 x + b)``, SiLU after the convolution and the
+        L2 norm of q and k behind it, a float32 state, the selection
+        bias."""
+        num_layers = int(kw.pop("num_layers", 27))
+        nope = RopeSpec(rotary_fraction=0.0)
+        layers = tuple(
+            LayerSpec(
+                num_heads=32, rope=nope,
+                mixer="attn" if (i + 1) % 4 == 0 or i == 26 else "kda",
+                mlp="sparse" if i else "dense")
+            for i in range(num_layers))
+        base = dict(
+            vocab_size=163840,
+            hidden_size=2304,
+            intermediate_size=9216,
+            num_layers=num_layers,
+            num_heads=32,
+            num_kv_heads=32,
+            max_seq_len=1048576,
+            rms_norm_eps=1e-5,
+            q_lora_rank=0,
+            kv_lora_rank=512,
+            qk_nope_head_dim=128,
+            qk_rope_head_dim=64,
+            v_head_dim=128,
+            rope_scaling=nope,
+            kda_heads=32,
+            kda_head_dim=128,
+            kda_conv=4,
+            kda_rank=128,
+            num_experts=256,
+            moe_top_k=8,
+            moe_norm_topk_prob=True,
+            moe_intermediate_size=1024,
+            moe_score_fn="sigmoid",
+            moe_routed_scale=2.446,
+            moe_shared_width=1024,
+            moe_select_bias=True,
+            moe_first_dense=1,
+            moe_per_expert_init=True,
+            moe_aux_loss_coef=0.0,
+            moe_z_loss_coef=0.0,
+            scan_layers=False,
+            remat=False,
+            layers=layers,
+        )
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
     def from_preset(
         cls, name: str, num_layers: int = 0, **kw
     ) -> "LlamaConfig":
@@ -510,7 +601,7 @@ class LlamaConfig:
 
 #: presets the entry points (examples/, the serving worker) can name
 PRESETS = ("tiny", "llama2_7b", "olmoe_1b_7b", "laguna_xs2", "glm5",
-           "sarvam_105b")
+           "sarvam_105b", "kimi_linear_48b")
 
 
 def resolve_remat_policy(name: str):
@@ -961,6 +1052,13 @@ class LlamaModel(nn.Module):
         :func:`dlrover_tpu.ops.losses.fused_lm_head_loss` so the full
         logits are never materialized."""
         cfg = self.config
+        if any(s.mixer != "attn" for s in cfg.layer_specs):
+            raise NotImplementedError(
+                "LlamaModel trains attention layers: a layer whose mixer "
+                "is linear attention (LayerSpec.mixer='kda') is served only "
+                "(serving/linear.py).  Missing: the chunk kernel's backward "
+                "(ops/pallas/kda.py) and a training layer (ROADMAP Reach "
+                "A6)")
         if cfg.kv_lora_rank or cfg.moe_first_dense:
             raise NotImplementedError(
                 "LlamaModel trains the grouped-query block: latent "

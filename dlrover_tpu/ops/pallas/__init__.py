@@ -8,6 +8,11 @@
   of index keys (decode).
 - ``mla_prefill``: a latent model's masked, absorbed attention of a run
   of queries (a prefill chunk) over its live pages of latent rows.
+- ``mla_decode``: a latent model's absorbed attention of one query a slot
+  over the slot's live pages of latent rows (decode, no selection).
+- ``kda``: linear attention with a recurrent state (a gated delta rule
+  with a decay a channel): one token for every active slot in place, and
+  a run of one slot's tokens in chunks of 64.
 - ``quant_matmul``: int8 x int8 matmul with per-row / per-column scales.
 """
 

@@ -148,6 +148,19 @@ class EngineStats:
     # here (a device reduction carried in the cache beside the pools)
     moe_picks: int = 0
     moe_picks_held: int = 0
+    # linear-attention layers (LayerSpec.mixer "kda"), whose state is one
+    # float32 matrix a head and slot: host arithmetic, summed over decode
+    # forwards and layers
+    state_bytes_live: int = 0     # read + written for the slots that
+    #                               decoded: 2 x a slot's state each
+    state_bytes_streamed: int = 0  # ... for the slots the decode kernel's
+    #                               grid walked (kda_decode_step: the
+    #                               active ones; the jnp path walks all)
+    state_resets_total: int = 0   # slots whose state a prompt's first
+    #                               chunk started from zeros
+    kda_chunk_rows_real: int = 0  # prompt tokens their chunk kernel took
+    kda_chunk_rows_padded: int = 0  # ... and the rows of the 64-token
+    #                               chunks it computed for them
 
     @property
     def decode_tokens_per_sec(self) -> float:
@@ -214,6 +227,14 @@ class EngineStats:
         compute (0.0 for a model whose chunks gather a slot's table)."""
         return self.prefill_query_tiles_live / self.prefill_query_tiles \
             if self.prefill_query_tiles else 0.0
+
+    @property
+    def state_stream_ratio(self) -> float:
+        """Recurrent-state bytes the decode forwards moved per byte of
+        the slots that decoded: 1.0 when idle slots are not walked (0.0
+        for a model with no such layer)."""
+        return self.state_bytes_streamed / self.state_bytes_live \
+            if self.state_bytes_live else 0.0
 
     @property
     def kv_stream_ratio(self) -> float:
@@ -325,7 +346,16 @@ class InferenceEngine:
         a row costs 1 536 bytes: ``serving/latent.py``).
         Such a model needs ``paged=True``, keeps its rows in the model's
         dtype (``kv_dtype`` None, ``kv_budget_x`` 1) and runs on one
-        device with unquantized weights.
+        device with unquantized weights.  Its layers of LINEAR attention
+        (``LayerSpec.mixer`` "kda") keep no rows: the pools are an
+        attention layer each, and beside them a float32 state a SLOT and
+        KDA layer (``kda_state`` [slots, H, d, d], ``kda_conv``), the same
+        size at token 1 and at token 1 000 000, zeroed inside the program
+        that takes a slot's first prompt chunk, carried from chunk to
+        chunk, held still while the slot is idle or prefilling
+        (``cache_nbytes`` counts both kinds).  Such a model takes every
+        prompt in chunks (``prefill_chunk`` > 0) and is refused prefix
+        sharing, drafts and a mesh by what each would need.
 
         ``attention_impl`` selects the paged decode attention read:
         ``"xla"`` = fused gather (materializes the dequantized dense
@@ -388,6 +418,40 @@ class InferenceEngine:
         # the unfused projection layout (fused [q|k|v] columns would
         # shard head-incorrectly).
         self.mesh = mesh
+        # linear-attention layers (serving/linear.py): a state a SLOT, not
+        # rows in blocks.  What cannot be right yet is refused by what is
+        # missing, not served wrongly
+        self._kda_layers = sum(s.mixer == "kda" for s in cfg.layer_specs)
+        if self._kda_layers:
+            if not cfg.kv_lora_rank:
+                raise ValueError(
+                    "linear-attention layers are served beside latent "
+                    "attention only (serving/latent.py's layer loop)")
+            if prefix_sharing:
+                raise ValueError(
+                    "prefix_sharing=True with linear-attention layers: a "
+                    "warm start behind a shared prefix needs the recurrent "
+                    "state at the prefix's end, and nothing keeps it.  "
+                    "Missing: a snapshot of recurrent state at a shared "
+                    "prefix's end in serving/prefixcache/ (ROADMAP Reach "
+                    "A6); pass prefix_sharing=False")
+            if self.speculative_k:
+                raise ValueError(
+                    f"speculative_k={speculative_k!r} with linear-attention "
+                    "layers: a rejected draft has already advanced the "
+                    "state.  Missing: roll-back of recurrent state under "
+                    "drafts (ROADMAP Reach A6); pass speculative_k=0")
+            if not prefill_chunk:
+                raise ValueError(
+                    "linear-attention layers take their prompts in chunks "
+                    "(the state is carried from one to the next): pass "
+                    "prefill_chunk > 0")
+            if mesh is not None:
+                raise ValueError(
+                    "a mesh with linear-attention layers: a slot's state "
+                    "is kept whole on one device.  Missing: the recurrent "
+                    "state and its kernels sharded over heads (ROADMAP "
+                    "Reach A6)")
         self.params = serving_params_from_llama(
             variables, cfg, int8=int8, fuse=mesh is None)
         # speculative slack: a verify near the end of a sequence writes
@@ -492,10 +556,12 @@ class InferenceEngine:
 
                 shape = (n_blocks, self.block_size)
                 self._cache = {
+                    # one pool an ATTENTION layer: a layer of linear
+                    # attention keeps no rows
                     "latent_pool": [
                         jnp.zeros(shape + (latent_row_width(cfg),),
                                   cfg.dtype)
-                        for _ in range(cfg.num_layers)],
+                        for _ in range(cfg.num_layers - self._kda_layers)],
                     "table": jnp.asarray(self._table_np),
                     # the slot whose forward the programs hand back
                     # (``watch``); -1: none
@@ -509,6 +575,19 @@ class InferenceEngine:
                     # [picks, picks on held experts], wrapping: the host
                     # adds differences (_book_moe_picks)
                     self._cache["moe_picks"] = jnp.zeros(2, jnp.uint32)
+                if self._kda_layers:
+                    from dlrover_tpu.serving.linear import state_shapes
+
+                    state, conv = state_shapes(cfg, self.max_slots)
+                    # indexed by SLOT, donated through every program like
+                    # the pools; zeroed inside the program that takes a
+                    # slot's first prompt chunk
+                    self._cache["kda_state"] = [
+                        jnp.zeros(state, jnp.float32)
+                        for _ in range(self._kda_layers)]
+                    self._cache["kda_conv"] = [
+                        jnp.zeros(conv, cfg.dtype)
+                        for _ in range(self._kda_layers)]
             elif self.kv_dtype in ("int8", "int4"):
                 from dlrover_tpu.models.quantize import KV_SCALE_DTYPE
 
@@ -922,7 +1001,8 @@ class InferenceEngine:
             ran += 1
         chunked = self._prefill_chunk_fn is not None
         buckets = [n for n in self.buckets
-                   if not chunked or n <= self.prefill_chunk]
+                   if (not chunked or n <= self.prefill_chunk)
+                   and not self._kda_layers]
         for g in range(1, b + 1):
             slots = jnp.arange(g, dtype=jnp.int32)
             if chunked and g <= self._prefill_group:
@@ -999,8 +1079,10 @@ class InferenceEngine:
             if not free:
                 return
             bucket = _bucket(self._queue[0].prompt.size, self.buckets)
-            if self._prefill_chunk_fn is not None \
-                    and bucket > self.prefill_chunk:
+            if self._prefill_chunk_fn is not None and (
+                    bucket > self.prefill_chunk or self._kda_layers):
+                # (a state a slot is carried chunk by chunk: a model with
+                # linear-attention layers has no bucketed prefill)
                 if not self._admit_chunked(free[0]):
                     return  # pool exhausted: keep queued, keep order
                 continue
@@ -1254,7 +1336,8 @@ class InferenceEngine:
             self._push_table()
         started = time.perf_counter()
         attrs = {"n": g, **self._book_selection(starts, ends),
-                 **self._book_key_blocks(starts, ends)}
+                 **self._book_key_blocks(starts, ends),
+                 **self._book_state_chunks(starts, ends)}
         with self._dispatching("prefill_chunk"):
             # one dispatch for all rows, or one a row where the model
             # asks for that (``_prefill_group``); the cache and the last
@@ -1474,7 +1557,8 @@ class InferenceEngine:
         lengths = self._positions[active][:, None] + np.arange(
             1, self.chunk + 1)[None, :]
         rows = {"kv_rows_live": live, "kv_rows_streamed": streamed,
-                **self._book_selection(lengths - 1, lengths)}
+                **self._book_selection(lengths - 1, lengths),
+                **self._book_state_bytes(active)}
         started = time.perf_counter()
         with self._dispatching("decode_chunk"):
             out, self._last_dev, _, self._cache, self._rng, seen = \
@@ -1645,6 +1729,58 @@ class InferenceEngine:
         return {"key_blocks": walked, "query_tiles": tiles,
                 "query_tiles_live": live}
 
+    def _book_state_bytes(self, active: np.ndarray) -> Dict[str, int]:
+        """Book what the linear-attention layers of one decode chunk move
+        of their slots' states: read + written for the slots ``active``
+        (live), and for the slots the path walks (the decode kernel's
+        grid: the active ones; the ``jnp`` step: every slot).  Returns the
+        two for the dispatch's span ({} for a model with no such layer)."""
+        if not self._kda_layers:
+            return {}
+        from dlrover_tpu.ops.pallas.kda import decode_states_walked
+
+        one = 2 * int(np.prod(self._cache["kda_state"][0].shape[1:])) * 4
+        each = one * self._kda_layers * self.chunk
+        live = decode_states_walked(active) * each
+        walked = live if self.attention_impl == "pallas" \
+            else self.max_slots * each
+        self.stats.state_bytes_live += live
+        self.stats.state_bytes_streamed += walked
+        return {"state_bytes_live": live, "state_bytes_streamed": walked}
+
+    def _book_state_chunks(self, starts, ends) -> Dict[str, int]:
+        """Book what the linear-attention layers' chunk kernel takes of
+        prompt chunks whose real tokens stand at ``starts[i] .. ends[i] -
+        1``: the real tokens, the rows of the 64-token chunks it computes
+        for them (one layer's, as every layer walks the same), and the
+        slots whose state starts from zeros.  Returns them for the
+        dispatch's span ({} for a model with no such layer)."""
+        if not self._kda_layers:
+            return {}
+        from dlrover_tpu.ops.pallas.kda import CHUNK, chunk_rows
+
+        # (the ``jnp`` recurrence walks a program's every row)
+        kernel = self.attention_impl == "pallas" \
+            and self.prefill_chunk % CHUNK == 0
+        real, padded = chunk_rows(
+            np.asarray(ends) - np.asarray(starts), self.prefill_chunk,
+            CHUNK if kernel else self.prefill_chunk)
+        resets = int(np.count_nonzero(np.asarray(starts) == 0))
+        self.stats.kda_chunk_rows_real += real
+        self.stats.kda_chunk_rows_padded += padded
+        self.stats.state_resets_total += resets
+        return {"kda_chunk_rows_real": real,
+                "kda_chunk_rows_padded": padded, "state_resets": resets}
+
+    @property
+    def cache_nbytes(self) -> int:
+        """Bytes of everything this engine keeps a sequence in: the paged
+        pools (or the dense K/V) and, a slot, the linear-attention
+        layers' states and convolution rows."""
+        return int(sum(
+            x.nbytes for key, val in self._cache.items()
+            if isinstance(val, list) for x in val))
+
     def watch(self, wanted) -> None:
         """Keep what the engine's own programs do for ONE request at a
         time: the first admitted to chunked prefill for which
@@ -1659,8 +1795,11 @@ class InferenceEngine:
         attended to (of a model with no selection of keys: its logits)
         and the first sparse MLP's input and output, which a
         selection of keys and a share of the experts otherwise leave to
-        show only in the logits.  ``None`` stops.  A latent-attention
-        model's engine only: no other program keeps a witness."""
+        show only in the logits; of a model with linear-attention layers
+        also the slot's recurrent state behind each forward, of the first
+        and the last such layer (``kda_state``).  ``None`` stops.  A
+        latent-attention model's engine only: no other program keeps a
+        witness."""
         if not self._latent:
             raise ValueError("only a latent-attention model's programs "
                              "keep a witness (serving/latent.py)")

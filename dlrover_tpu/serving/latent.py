@@ -5,6 +5,13 @@ whole context (``LlamaConfig.sarvam_105b``): the blocks
 ``serving/model.py``'s ``verify_step`` and ``prefill`` run in place of the
 grouped-query ones.
 
+A model may mix such layers with layers of LINEAR attention
+(``LayerSpec.mixer`` "kda": ``serving/linear.py``, a recurrent state a
+slot in place of rows in the pools): the layer loop of :func:`verify_step`
+dispatches on each layer's description, the pools are indexed by the
+attention layers alone, and a ``RopeSpec`` whose ``rotary_fraction`` is 0
+(``LlamaConfig.kimi_linear_48b``) rotates nothing.
+
 One layer, on its normed input ``h`` (positions ``t``, ``s``):
 
 - *latent attention*: ``c_q = RMSNorm(W_qa h)``, ``q = W_qb c_q`` (or,
@@ -108,7 +115,10 @@ def rope_pairs(x: jax.Array, positions: jax.Array, spec: RopeSpec,
     rope_inverse_frequencies``, training's: plain ``theta^(-2i / rotary)``
     or YaRN's blend), cos and sin times its ``attention_factor``; the rest
     pass.  ``positions`` has ``x``'s leading dimensions (fewer broadcast
-    over the rest, heads).  Float32 out."""
+    over the rest, heads).  A ``rotary_fraction`` of 0 says NO positional
+    encoding: ``x`` passes whole.  Float32 out."""
+    if not spec.rotary_fraction:
+        return x.astype(jnp.float32)
     inv = rope_inverse_frequencies(spec, rotary)
     ang = positions.astype(jnp.float32)[..., None] * inv
     ang = ang.reshape(positions.shape
@@ -434,6 +444,45 @@ def _mlp(lp, h, cfg: LlamaConfig, dtype, counted):
         return _swiglu(h, lp["wgu"], lp["down"], dtype), None
 
 
+def _kda_mixer(lp, h, state, conv, cfg: LlamaConfig, dtype, positions,
+               slots, n_real, active, impl: str, interpret: bool):
+    """A linear-attention layer (``serving/linear.py``) on ``h`` [B, K, E]:
+    ``(y, state, conv, decay)``, the layer's per-slot state advanced and
+    what its decay was computed from and to ([B, K, 2, H, d]:
+    ``kda_decode``).  One query a slot over every slot is a decode
+    forward (``active`` [B] or None: all); a run of queries of the slots ``slots`` is a prompt chunk, a
+    row at a time, from zeros where the run starts at position 0, to its
+    ``n_real``-th token (None: all of it)."""
+    from dlrover_tpu.serving.linear import kda_decode, kda_run
+
+    b, klen, _ = h.shape
+    if slots is None:
+        if klen != 1:
+            raise ValueError(
+                "a run of queries over every slot is a speculative verify, "
+                "and a rejected draft has already advanced a linear-"
+                "attention layer's state.  Missing: roll-back of recurrent "
+                "state under drafts (ROADMAP Reach A6)")
+        if active is None:
+            active = jnp.ones((b,), bool)
+        y, state, conv, decay = kda_decode(
+            lp, h[:, 0], state, conv, active, cfg, dtype, impl, interpret)
+        return y[:, None], state, conv, decay[:, None]
+    ys, decays = [], []
+    for r in range(b):
+        slot = slots[r]
+        y, s_new, c_new, decay = kda_run(
+            lp, h[r], jnp.take(state, slot, axis=0),
+            jnp.take(conv, slot, axis=1), positions[r] == 0,
+            jnp.asarray(klen, jnp.int32) if n_real is None else n_real[r],
+            cfg, dtype, impl, interpret)
+        state = state.at[slot].set(s_new)
+        conv = conv.at[:, slot].set(c_new)
+        ys.append(y)
+        decays.append(decay)
+    return jnp.stack(ys), state, conv, jnp.stack(decays)
+
+
 def _pad_table(table: jax.Array, pages: int) -> jax.Array:
     """The table padded with the trash block to whole key blocks."""
     pad = -table.shape[1] % pages
@@ -446,8 +495,10 @@ def _pad_table(table: jax.Array, pages: int) -> jax.Array:
 def verify_step(
     params: Dict[str, Any],
     cfg: LlamaConfig,
-    cache: Dict[str, Any],   # {"latent_pool", "index_pool": per-layer
-    tokens: jax.Array,       #   lists; "table"; "moe_picks"}
+    cache: Dict[str, Any],   # {"latent_pool", "index_pool": lists, an
+    tokens: jax.Array,       #   ATTENTION layer each; "kda_state",
+                             #   "kda_conv": a KDA layer each, by slot;
+                             #   "table"; "moe_picks"}
     positions: jax.Array,
     slots: Optional[jax.Array] = None,
     logits_index: Optional[jax.Array] = None,
@@ -469,7 +520,12 @@ def verify_step(
     with NO selection has no rows to tell of (every query attends to every
     row behind it) and hands back, in their place, what the selection
     otherwise makes the only judge of: ``logits`` [V] float32, the slot's
-    own (decode: this forward's; a run: at its ``logits_index``)."""
+    own (decode: this forward's; a run: at its ``logits_index``); and of a
+    model with linear-attention layers also ``kda_state`` [2, H, d, d],
+    the slot's recurrent state behind this forward of the first and the
+    last such layer, and ``kda_decay`` [2, H, d], the first such layer's
+    ``(f, g)`` at the slot's query (a run: its last real one): the
+    log-decay ``g`` beside the float32 sums ``f`` it is a function of."""
     dtype = cfg.dtype
     b, klen = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0)            # [B, K, E]
@@ -497,42 +553,62 @@ def verify_step(
         watch = jnp.clip(watch, 0, b - 1) if slots is None \
             else jnp.argmax(slots == watch)
     latent_pools, index_pools, selections, seen = [], [], [], {}
-    for i, lp in enumerate(params["layers"]):
+    states, convs = [], []
+    for lp, spec in zip(params["layers"], cfg.layer_specs):
         h = _rmsnorm(x, lp["input_norm"], cfg.rms_norm_eps).astype(dtype)
-        qq, row, q_i, k_i, w = _projections(lp, h, cfg, pos_k, dtype)
-        lat = cache["latent_pool"][i]
-        lat = scatter_tokens(lat, table, row.astype(lat.dtype), positions)
-        idx = None
-        if cfg.index_topk:
-            idx = cache["index_pool"][i]
-            idx = scatter_tokens(idx, table, k_i.astype(idx.dtype),
-                                 positions)
-        if decode:
-            o_lat, chosen = _attend_decode(
-                qq[:, 0], None if q_i is None else q_i[:, 0],
-                None if w is None else w[:, 0], lat, idx, table, lengths,
-                cfg, attention_impl, kernel_interpret)
-            o_lat = o_lat[:, None]
+        if spec.mixer == "kda":
+            y, state, conv, decay = _kda_mixer(
+                lp, h, cache["kda_state"][len(states)],
+                cache["kda_conv"][len(convs)], cfg, dtype, positions,
+                slots, None if decode else n_real, active if decode
+                else None, attention_impl, kernel_interpret)
+            if watch is not None and not states:
+                # the first such layer's decay at the watched row's query
+                # (a run: its last real one), beside what it came from
+                at = jnp.zeros((), jnp.int32) if logits_index is None \
+                    else jnp.take(logits_index, watch).astype(jnp.int32)
+                seen["kda_decay"] = jnp.take(
+                    jnp.take(decay, watch, axis=0), at, axis=0)
+            states.append(state)
+            convs.append(conv)
+            x = x + y
         else:
-            o_lat, chosen = jax.lax.map(
-                lambda a: _attend_run(a[0], a[1], a[2], a[3], lat, idx,
-                                      a[4], cfg, KEY_BLOCK_PAGES,
-                                      attention_impl, kernel_interpret,
-                                      a[5]),
-                (qq, q_i, w, pos_k, run_table, n_real))
-        if watch is not None and cfg.index_topk:
-            selections.append(jnp.take(chosen, watch, axis=0))
-        x = x + _attn_out(lp, o_lat, cfg, dtype)
+            i = len(latent_pools)
+            qq, row, q_i, k_i, w = _projections(lp, h, cfg, pos_k, dtype)
+            lat = cache["latent_pool"][i]
+            lat = scatter_tokens(lat, table, row.astype(lat.dtype),
+                                 positions)
+            idx = None
+            if cfg.index_topk:
+                idx = cache["index_pool"][i]
+                idx = scatter_tokens(idx, table, k_i.astype(idx.dtype),
+                                     positions)
+            if decode:
+                o_lat, chosen = _attend_decode(
+                    qq[:, 0], None if q_i is None else q_i[:, 0],
+                    None if w is None else w[:, 0], lat, idx, table,
+                    lengths, cfg, attention_impl, kernel_interpret)
+                o_lat = o_lat[:, None]
+            else:
+                o_lat, chosen = jax.lax.map(
+                    lambda a: _attend_run(a[0], a[1], a[2], a[3], lat, idx,
+                                          a[4], cfg, KEY_BLOCK_PAGES,
+                                          attention_impl, kernel_interpret,
+                                          a[5]),
+                    (qq, q_i, w, pos_k, run_table, n_real))
+            if watch is not None and cfg.index_topk:
+                selections.append(jnp.take(chosen, watch, axis=0))
+            x = x + _attn_out(lp, o_lat, cfg, dtype)
+            latent_pools.append(lat)
+            index_pools.append(idx)
         h = _rmsnorm(x, lp["post_norm"], cfg.rms_norm_eps).astype(dtype)
         y, n = _mlp(lp, h, cfg, dtype, counted)
         if n is not None and picks is not None:
             picks = picks + n
-        if n is not None and watch is not None and not seen:
+        if n is not None and watch is not None and "sparse_in" not in seen:
             seen.update(sparse_in=jnp.take(h, watch, axis=0),
                         sparse_out=jnp.take(y, watch, axis=0))
         x = x + y
-        latent_pools.append(lat)
-        index_pools.append(idx)
 
     x = _rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
     if logits_index is not None:
@@ -540,6 +616,15 @@ def verify_step(
             x, logits_index.astype(jnp.int32)[:, None, None], axis=1)
     logits = _lm_head(params, x.astype(dtype), cfg)
     out_cache = dict(cache, latent_pool=latent_pools)
+    if states:
+        out_cache.update(kda_state=states, kda_conv=convs)
+        if watch is not None:
+            # the watched slot's state behind this forward, of the first
+            # and the last layer that keeps one
+            at = watch if slots is None else jnp.take(slots, watch)
+            seen["kda_state"] = jnp.stack(
+                [jnp.take(states[0], at, axis=0),
+                 jnp.take(states[-1], at, axis=0)])
     if cfg.index_topk:
         out_cache["index_pool"] = index_pools
     if picks is not None:
@@ -569,6 +654,11 @@ def prefill(params: Dict[str, Any], cfg: LlamaConfig, tokens: jax.Array,
     no pool behind it: nothing for the kernel to save).
     The experts' picks of this path are not counted."""
     dtype = cfg.dtype
+    if any(s.mixer != "attn" for s in cfg.layer_specs):
+        raise ValueError(
+            "a bucketed prefill hands back cache rows to scatter, and a "
+            "linear-attention layer keeps a state a slot: its prompts go "
+            "through the chunked path (InferenceEngine(prefill_chunk=...))")
     g, lp_len = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0)
     pos = jnp.broadcast_to(jnp.arange(lp_len), (g, lp_len))

@@ -1,0 +1,192 @@
+"""The served Kimi Delta Attention block (``LayerSpec.mixer`` "kda"): what
+``serving/latent.py``'s layer loop runs in place of latent attention for
+a layer that keeps no rows.
+
+On its normed input ``x`` (one token a slot at decode, a run of one
+slot's tokens in a prompt chunk):
+
+- ``[q' | k' | v] = SiLU(conv(W_qkv x))``: ONE matmul, then a causal
+  depthwise convolution over the last ``kda_conv`` positions a channel
+  (the positions ahead of this call are the slot's ``conv`` state, its
+  last ``kda_conv - 1`` inputs), SiLU; ``q = q' / |q'| x d^-0.5``, ``k =
+  k' / |k'|`` a head;
+- the log-decay a channel ``g = -exp(A_h) softplus(W_f2 W_f1 x + b)``
+  and ``beta = sigmoid(W_b x)`` a head, both float32;
+- the delta rule over the slot's ``state`` (one float32 ``[d, d]`` a
+  head): ``ops/pallas/kda.py``, the kernels with ``impl == "pallas"``,
+  the ``jnp`` recurrence otherwise;
+- ``y = W_o [RMSNorm_head(o) x sigmoid(W_g2 W_g1 x)]``.
+
+The state belongs to a SLOT, not to blocks: ``state`` [slots, H, d, d]
+float32 and ``conv`` [kda_conv - 1, slots, 3 H d] (the model's dtype: the
+projection's own rounding, so a prompt chunk and a decode forward see the
+same inputs; taps ahead of slots, the order the chip's compiler keeps it
+in: with slots first it copied every layer's rows into that order and
+back around each decode chunk, compiled for a described v5e, PR 43).  A
+decode forward advances the slots ``active`` marks and leaves the others
+as they were; a prompt chunk advances ITS slot, from
+zeros where it is the prompt's first (``fresh``: no host-side write
+between steps), to the state after its last real token (``n_real``).
+
+Device scopes: ``kda_proj`` (projections, convolution, norms of q and k,
+decay and beta), ``kda_scan`` (the delta rule), ``kda_out`` (gated norm
+and ``W_o``).
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+
+from dlrover_tpu.models.llama import LlamaConfig
+from dlrover_tpu.ops.pallas import kda
+from dlrover_tpu.serving.model import _mm, _rmsnorm
+from dlrover_tpu.utils.profiler import device_scope
+
+#: added to a head's squared norm of q and k before the root
+_L2_EPS = 1e-6
+
+
+def kda_params(p: Dict[str, Any], cfg: LlamaConfig, dtype
+               ) -> Dict[str, Any]:
+    """A layer's ``kda`` subtree, named as ``perfbench/reference_kimi_
+    linear.py`` and the tests make it (``q_proj`` / ``k_proj`` /
+    ``v_proj`` [E, H, d]; ``q_conv`` / ``k_conv`` / ``v_conv`` [taps, H x
+    d]; ``f_a_proj``, ``f_b_proj``, ``dt_bias`` [H x d], ``A_log`` [H];
+    ``b_proj`` [E, H]; ``g_a_proj``, ``g_b_proj``; ``o_norm`` [d];
+    ``o_proj`` [H, d, E]), as the serving tree: q, k and v fused into one
+    matrix and one filter; the decay's constants float32."""
+    def mat(w):
+        return jnp.asarray(w, dtype)
+
+    def flat(name):
+        w = mat(p[name]["kernel"])
+        return w.reshape(w.shape[0], -1)
+
+    f32 = jnp.float32
+    return {
+        "kda_wqkv": jnp.concatenate(
+            [flat("q_proj"), flat("k_proj"), flat("v_proj")], axis=-1),
+        "kda_conv": jnp.concatenate(
+            [jnp.asarray(p[n]["kernel"], f32)
+             for n in ("q_conv", "k_conv", "v_conv")], axis=-1),
+        "kda_wf1": mat(p["f_a_proj"]["kernel"]),
+        "kda_wf2": mat(p["f_b_proj"]["kernel"]),
+        "kda_dt_bias": jnp.asarray(p["dt_bias"], f32),
+        "kda_rate": jnp.exp(jnp.asarray(p["A_log"], f32)),
+        "kda_wb": mat(p["b_proj"]["kernel"]),
+        "kda_wg1": mat(p["g_a_proj"]["kernel"]),
+        "kda_wg2": mat(p["g_b_proj"]["kernel"]),
+        "kda_o_norm": p["o_norm"]["scale"],
+        "kda_wo": mat(p["o_proj"]["kernel"]).reshape(-1, cfg.hidden_size),
+    }
+
+
+def state_shapes(cfg: LlamaConfig, slots: int):
+    """``(state, conv)`` shapes of one KDA layer for ``slots`` slots."""
+    h, d = cfg.kda_heads, cfg.kda_head_dim
+    return (slots, h, d, d), (cfg.kda_conv - 1, slots, 3 * h * d)
+
+
+def _mm32(x, w, dtype):
+    """``x @ w`` with operands in ``dtype`` and the float32 sums kept: the
+    decay, beta and the gate are functions of these, and are not rounded
+    to ``dtype`` on the way."""
+    return jnp.dot(x.astype(dtype), w.astype(dtype),
+                   preferred_element_type=jnp.float32)
+
+
+def _l2(x):
+    return x * jax.lax.rsqrt(
+        jnp.sum(x * x, axis=-1, keepdims=True) + _L2_EPS)
+
+
+def _inputs(lp, x, mixed, cfg: LlamaConfig, dtype):
+    """``x`` [T, E] (the block's normed input) and ``mixed`` [T, 3 H d]
+    float32 (the projection's outputs behind the convolution) -> ``q k v
+    g`` [T, H, d] and ``beta`` [T, H], float32, and ``f`` [T, H, d], what
+    the decay is the function of (for a witness: ``g`` against ``f`` says
+    in which precision the decay was computed, whatever ``x`` was)."""
+    t = x.shape[0]
+    h, d = cfg.kda_heads, cfg.kda_head_dim
+    qkv = jax.nn.silu(mixed).reshape(t, 3, h, d)
+    q = _l2(qkv[:, 0]) * float(d ** -0.5)
+    k = _l2(qkv[:, 1])
+    f = _mm32(_mm(x, lp["kda_wf1"], dtype), lp["kda_wf2"], dtype) \
+        + lp["kda_dt_bias"]
+    f = f.reshape(t, h, d)
+    g = -lp["kda_rate"][:, None] * jax.nn.softplus(f)
+    beta = jax.nn.sigmoid(_mm32(x, lp["kda_wb"], dtype))
+    return q, k, qkv[:, 2], g, beta, f
+
+
+def _output(lp, x, o, cfg: LlamaConfig, dtype):
+    """``o`` [T, H, d] float32 -> the block's output [T, E]."""
+    with device_scope("kda_out"):
+        gate = jax.nn.sigmoid(_mm32(
+            _mm(x, lp["kda_wg1"], dtype), lp["kda_wg2"], dtype)
+        ).reshape(o.shape)
+        y = _rmsnorm(o, lp["kda_o_norm"], cfg.rms_norm_eps) * gate
+        return _mm(y.reshape(o.shape[0], -1).astype(dtype), lp["kda_wo"],
+                   dtype)
+
+
+def kda_decode(lp, x, state, conv, active, cfg: LlamaConfig, dtype,
+               impl: str, interpret: bool):
+    """One token a slot: ``x`` [B, E], ``state`` [B, H, d, d], ``conv``
+    [taps - 1, B, 3 H d], ``active`` [B] bool.  Returns ``(y [B, E],
+    state, conv, decay)``; a slot that is not active keeps state and conv
+    as they were; ``decay`` [B, 2, H, d] is ``(f, g)`` of :func:`_inputs`."""
+    with device_scope("kda_proj"):
+        new = _mm(x, lp["kda_wqkv"], dtype)
+        window = jnp.concatenate([conv, new[None]], axis=0)
+        mixed = jnp.sum(window.astype(jnp.float32)
+                        * lp["kda_conv"][:, None, :], axis=0)
+        q, k, v, g, beta, f = _inputs(lp, x, mixed, cfg, dtype)
+        conv = jnp.where(active[None, :, None], window[1:], conv)
+    with device_scope("kda_scan"):
+        if impl == "pallas":
+            o, state = kda.kda_decode_step(state, q, k, v, g, beta, active,
+                                           interpret=interpret)
+        else:
+            o, new_state = kda.kda_step(state, q, k, v, g, beta)
+            state = jnp.where(active[:, None, None, None], new_state, state)
+    return _output(lp, x, o, cfg, dtype), state, conv, jnp.stack(
+        [f, g], axis=1)
+
+
+def kda_run(lp, x, state, conv, fresh, n_real, cfg: LlamaConfig, dtype,
+            impl: str, interpret: bool):
+    """A run of one slot's tokens: ``x`` [K, E], ``state`` [H, d, d] and
+    ``conv`` [taps - 1, 3 H d] the slot's own, ``fresh`` (bool scalar:
+    the prompt's first chunk starts from zeros), ``n_real`` (int32
+    scalar: behind it the run is padding).  Returns ``(y [K, E], state,
+    conv, decay)``, state and conv after the last real token, ``decay``
+    [K, 2, H, d] as :func:`kda_decode`'s."""
+    taps = cfg.kda_conv
+    with device_scope("kda_proj"):
+        state = jnp.where(fresh, 0.0, state)
+        conv = jnp.where(fresh, jnp.zeros((), conv.dtype), conv)
+        new = _mm(x, lp["kda_wqkv"], dtype)
+        window = jnp.concatenate([conv, new], axis=0)
+        wf = window.astype(jnp.float32)
+        mixed = sum(wf[j:j + x.shape[0]] * lp["kda_conv"][j]
+                    for j in range(taps))
+        q, k, v, g, beta, f = _inputs(lp, x, mixed, cfg, dtype)
+        decay = jnp.stack([f, g], axis=1)
+        # the last taps - 1 inputs up to the last real token
+        conv = jax.lax.dynamic_slice_in_dim(window, n_real, taps - 1)
+        kernel = impl == "pallas" and x.shape[0] % kda.CHUNK == 0
+        if kernel:
+            # every consumer of the projection's output under this scope,
+            # the kernel alone under the next
+            kb, vb, g = kda.chunk_operands(k, v, g, beta, n_real)
+    with device_scope("kda_scan"):
+        if kernel:
+            o, state = kda.kda_chunk_call(state, q, k, kb, vb, g, n_real,
+                                          interpret=interpret)
+        else:
+            o, state = kda.kda_recurrence(state, q, k, v, g, beta, n_real)
+    return _output(lp, x, o, cfg, dtype), state, conv, decay

@@ -26,6 +26,7 @@ Weight entries are either an fp array ``[K, N]`` or a
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict, Optional
 
 import jax
@@ -131,15 +132,21 @@ def serving_params_from_llama(
             f"(qk_norm={cfg.qk_norm}): the model would be served as a "
             "different one.  Missing: the norm over the projected query "
             "and key in serving/model.py::_attn_proj (ROADMAP Reach A3)")
-    if cfg.layers is not None or cfg.attn_head_gate:
+    attn = {dataclasses.replace(s, mlp="dense") for s in cfg.layer_specs
+            if s.mixer == "attn"}
+    if cfg.attn_head_gate or len(attn) > 1 or any(
+            s.window for s in attn) or (
+            cfg.layers is not None and not cfg.kv_lora_rank):
         raise ValueError(
-            "the serving engine's model has ONE kind of layer: full "
-            "causal attention with one head count and plain RoPE, no head "
-            f"gate (attn_head_gate={cfg.attn_head_gate}); this model "
-            f"describes {len(set(cfg.layer_specs))} kinds of layer.  "
-            "Missing: a window in the paged kernels and the cache manager "
-            "(ROADMAP A4), per-layer head counts, partial rotary and YaRN "
-            "in serving/model.py (A3)")
+            "the serving engine's attention layers are ONE kind of layer: "
+            "full causal attention with one head count and one rotary "
+            f"embedding, no head gate (attn_head_gate={cfg.attn_head_gate}"
+            f"); this model describes {len(attn)} kinds of attention "
+            "layer.  Layers that differ are served as latent attention "
+            "beside linear attention only (LayerSpec.mixer, "
+            "serving/latent.py).  Missing: a window in the paged kernels "
+            "and the cache manager (ROADMAP A4), per-layer head counts, "
+            "partial rotary and YaRN in serving/model.py (A3)")
     if cfg.num_experts and not cfg.kv_lora_rank:
         raise ValueError(
             f"sparse experts (num_experts={cfg.num_experts}) are served "
@@ -210,7 +217,9 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
     rope], ``kv_a_norm``, ``kv_b_proj`` [C, H, nope + V], ``o_proj`` [H,
     V, E]), with ``index_topk`` an ``indexer``
     (``wq_b`` [Q, Hi, Di], ``wk``, ``k_norm`` scale and bias,
-    ``weights_proj``), and ``mlp`` as ``LlamaModel`` names a dense one or
+    ``weights_proj``); in place of ``attn`` a layer whose
+    ``LayerSpec.mixer`` is "kda" has ``kda`` (``serving/linear.py
+    kda_params``); and ``mlp`` as ``LlamaModel`` names a dense one or
     ``MoEMLP`` a sparse one (``select_bias`` beside the router).  The
     latent's up-projection is split into the key part, laid out for the
     absorbed query [H, nope, C], and the value part [H, C, V]; the router
@@ -232,12 +241,17 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
     def flat_out(w):      # [in, heads, d] -> [in, heads * d]
         return mat(w).reshape(w.shape[0], -1)
 
-    def layer(p):
+    def mixer(p, spec):
+        if spec.mixer == "kda":
+            from dlrover_tpu.serving.linear import kda_params
+
+            return kda_params(p["kda"], cfg, dtype)
+        if spec.mixer != "attn":
+            raise ValueError(f"no served mixer {spec.mixer!r}: a layer is "
+                             "'attn' or 'kda' (LayerSpec.mixer)")
         a = p["attn"]
         kv_b = mat(a["kv_b_proj"]["kernel"])             # [C, H, nope+V]
         out = {
-            "input_norm": p["input_norm"]["scale"],
-            "post_norm": p["post_norm"]["scale"],
             "wkv_a": mat(a["kv_a_proj"]["kernel"]),
             "kv_a_norm": a["kv_a_norm"]["scale"],
             "wkv_b_k": kv_b[..., :nope].transpose(1, 2, 0),
@@ -261,6 +275,12 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
                 ik_norm_scale=ix["k_norm"]["scale"],
                 ik_norm_bias=ix["k_norm"]["bias"],
                 iw=mat(ix["weights_proj"]["kernel"]))
+        return out
+
+    def layer(p, spec):
+        out = dict(mixer(p, spec),
+                   input_norm=p["input_norm"]["scale"],
+                   post_norm=p["post_norm"]["scale"])
         m = p["mlp"]
         if "router" in m:
             out.update(
@@ -284,8 +304,8 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
 
     return {
         "embed": mat(params["embed_tokens"]["embedding"]),
-        "layers": [layer(params[f"layer_{i}"])
-                   for i in range(cfg.num_layers)],
+        "layers": [layer(params[f"layer_{i}"], spec)
+                   for i, spec in enumerate(cfg.layer_specs)],
         "final_norm": params["final_norm"]["scale"],
         "lm_head": None if cfg.tie_embeddings
         else mat(params["lm_head"]["kernel"]),

@@ -312,6 +312,83 @@ def test_sarvam_decode_forward_streams_live_pages_only(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2**20
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+def test_kimi_linear_programs_keep_their_state_in_place(one_chip,
+                                                        monkeypatch,
+                                                        program):
+    """One period of kimi-linear-48b-serve (KDA, KDA, KDA, MLA) at its
+    published widths and the cell's 128 slots: the decode forward holds
+    ``kda_decode_step`` and the prompt chunk ``kda_chunk_fwd``, each under
+    ``kda_scan``, the MLA layer its own kernel under ``mla_attn``; the
+    donated states come back aliased, and nothing in the program is a
+    copy of a layer's 268 MB of state."""
+    from dlrover_tpu.models import moe
+    from dlrover_tpu.models.llama import LlamaConfig
+    from dlrover_tpu.serving import latent, linear
+    from dlrover_tpu.serving.model import decode_step
+    from dlrover_tpu.serving.params import serving_params_from_llama
+    from dlrover_tpu.utils.profiler import device_scope, parse_program
+    from perfbench.weights_kimi_linear import SeededKimiLinearParams
+
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    cfg = LlamaConfig.kimi_linear_48b(
+        num_layers=4, moe_experts_held=(0, 32), vocab_size=20480,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    slots, mb, bs, nb = 128, 33, 128, 5000
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    sp = on_chip(jax.eval_shape(lambda: serving_params_from_llama(
+        {"params": SeededKimiLinearParams(cfg, 3)}, cfg)))
+    S = jax.ShapeDtypeStruct
+    state, conv = linear.state_shapes(cfg, slots)
+    cache = on_chip({
+        "latent_pool": [S((nb, bs, latent.latent_row_width(cfg)),
+                          jnp.bfloat16)],
+        "kda_state": [S(state, jnp.float32)] * 3,
+        "kda_conv": [S(conv, jnp.bfloat16)] * 3,
+        "table": S((slots, mb), jnp.int32),
+        "moe_picks": S((2,), jnp.uint32),
+        "watch_slot": S((), jnp.int32)})
+    if program == "decode":
+        def forward(p, c, t, pos, act):
+            with device_scope("decode_chunk"):
+                return decode_step(p, cfg, c, t, pos,
+                                   attention_impl="pallas", active=act)
+
+        args = on_chip((S((slots,), jnp.int32), S((slots,), jnp.int32),
+                        S((slots,), jnp.bool_)))
+        kernel, attn = "kda_decode_step", "mla_decode_attn"
+    else:
+        def forward(p, c, t, pos, sl, li):
+            with device_scope("prefill_chunk"):
+                return latent.verify_step(
+                    p, cfg, c, t, pos, slots=sl, logits_index=li,
+                    attention_impl="pallas")
+
+        args = on_chip((S((1, 512), jnp.int32),) + (S((1,), jnp.int32),) * 3)
+        kernel, attn = "kda_chunk_fwd", "mla_prefill_attn"
+    lowered = jax.jit(forward, donate_argnums=(1,)).lower(sp, cache, *args)
+    compiled = lowered.compile()
+    table = parse_program(
+        program, compiled.as_text(),
+        {program if program != "decode" else "decode_chunk", "kda_proj",
+         "kda_scan", "kda_out", "mla_attn", "mla_proj", "moe_route",
+         "moe_experts", "moe_shared", "mlp"},
+        lowered.as_text(debug_info=True))
+    assert table.complete, table.missing
+    scopes = {n: scope for n, scope in table.scope_of.items()
+              if n.startswith((kernel, attn))}
+    assert sorted(scopes.values()) == ["kda_scan"] * 3 + ["mla_attn"], scopes
+    memory = compiled.memory_analysis()
+    one_state = 128 * 32 * 128 * 128 * 4
+    assert memory.alias_size_in_bytes >= 3 * one_state
+    assert memory.temp_size_in_bytes < one_state
+
+
 def test_paged_decode_int4_is_refused_loudly(one_chip):
     """Packed int4 pools do not compile on a TPU (minor dimension 64);
     until the pool is re-laid the kernel refuses in the repo's own
