@@ -1,6 +1,7 @@
 """Absorbed latent attention of ONE query a slot over the slot's live pages
-of a paged latent pool (TPU Pallas): the decode step of a latent-attention
-model that attends to its whole context (no learned selection of keys).
+of a paged latent pool (TPU Pallas): the decode step of every
+latent-attention model, one that attends to its whole context and, under
+a mask of the chosen rows, one with a learned selection of keys.
 
 A latent-attention model (``serving/latent.py``) caches one row
 ``[c_kv | k_r]`` a token for all heads.  At decode a slot's absorbed query
@@ -9,9 +10,13 @@ A latent-attention model (``serving/latent.py``) caches one row
 FLOPs for its W values (sarvam-105b: 64 x 2 x (640 + 512) for 1 280
 bytes = 115 FLOPs a byte against this chip's 240), so the kernel is bound
 by the bytes of the live rows.  Gathering them into a dense
-``[slots, table rows, W]`` array first (the ``jnp`` path a selection of
-2 048 rows can afford) would write and read every row the TABLE could
-hold: at 32 slots and tables of 33 k rows 1.35 GB a layer and forward.
+``[slots, table rows, W]`` array first would write and read every row the
+TABLE could hold: at 32 slots and tables of 33 k rows 1.35 GB a layer and
+forward.  A learned selection (GLM-5: 2 048 rows of 16-33 k) does not
+change that: gathering the CHOSEN rows by position runs at a tenth of
+the stream's pace on this chip and needs the positions, a sort, first
+(until PR 44: 14.6 of a decode forward's 26 ms), so a selection arrives
+here as a mask and every live row is streamed under it.
 
 The build is ``paged_index.py``'s and ``mla_prefill.py``'s: the grid is
 ``(B,)``, a program streams ITS slot's live pages in groups of ``pages``
@@ -21,7 +26,9 @@ group loop ends at the slot's length: a slot of length 0 reads nothing
 and gives zeros, a slot reads ``ceil(length / rows) x rows`` latent rows
 and not its table's width, and pages two slots share (a cached document)
 are read through each slot's own table.  Scores ``[H, rows]`` are born,
-masked behind the length, exponentiated and consumed in VMEM; running max
+masked behind the length (and by the slot's row of ``bias``, where there
+is one: a group nobody chose leaves the running softmax as it was),
+exponentiated and consumed in VMEM; running max
 and sum in float32 on all 128 lanes of a row (``flash_attention._lanes``
 / ``_fold``), ``p`` rounded to the query's dtype once before ``p x
 rows[:, :C]``, float32 accumulator, ``acc / max(l, 1e-30)`` out.
@@ -31,12 +38,18 @@ Layout (serving/paged.py, serving/latent.py):
   pool     [NB, bs, W]    latent rows, paged
   table    [B, MB] int32  block lists (0 = the trash block)
   lengths  [B] int32      keys a slot sees (0: nothing wanted)
+  bias     [B, n] f32     optional: 0 where the slot attends row s, -inf
+                          elsewhere (``n`` up to the table's rows in
+                          whole groups; a row behind ``n`` is nobody's).
+                          None: every row behind the length, and the
+                          call has no such operand at all
 Returns [B, H, C] float32.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -57,10 +70,12 @@ _NEG_INF = -jnp.inf
 
 def _decode_kernel(
     table_ref, lengths_ref,            # scalar-prefetched (SMEM)
-    q_ref, pool_hbm, o_ref,
-    kbuf, sem, m_scr, l_scr, acc_scr,
-    *, pages: int, block_size: int, num_groups: int, c: int, scale: float,
+    q_ref, pool_hbm, *rest,            # [bias_ref,] o_ref, the scratch
+    pages: int, block_size: int, num_groups: int, c: int, scale: float,
+    masked: bool,
 ):
+    bias_ref = rest[0] if masked else None
+    o_ref, kbuf, sem, m_scr, l_scr, acc_scr = rest[masked:]
     b = pl.program_id(0)
     rows = pages * block_size
     length = lengths_ref[b]
@@ -96,6 +111,9 @@ def _decode_kernel(
         s = jax.lax.dot_general(
             q, keys, _NT,
             preferred_element_type=jnp.float32) * scale      # [H, rows]
+        if masked:                     # the slot's row, over its heads
+            s = s + bias_ref[0, :, pl.ds(pl.multiple_of(g * rows, rows),
+                                         rows)]
         key_pos = g * rows + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         # the last live group is read whole: its rows behind the length
         # hold whatever the pool held, and count for nothing
@@ -137,6 +155,15 @@ def _whole_groups(table: jax.Array, pages_per_block: int):
     return table, p_n, num_groups
 
 
+def _whole_bias(bias: jax.Array, width: int) -> jax.Array:
+    """``bias`` [B, n] as float32 [B, width]: the rows behind ``n`` (the
+    padding of the table to whole groups) are nobody's."""
+    n = bias.shape[1]
+    assert n <= width, (bias.shape, width)
+    return jnp.pad(bias.astype(jnp.float32), ((0, 0), (0, width - n)),
+                   constant_values=_NEG_INF)
+
+
 @functools.partial(
     jax.jit, static_argnames=("c", "scale", "pages_per_block", "interpret"))
 def mla_decode_attention(
@@ -144,6 +171,7 @@ def mla_decode_attention(
     pool: jax.Array,     # [NB, bs, W]
     table: jax.Array,    # [B, MB] int32
     lengths: jax.Array,  # [B] int32
+    bias: Optional[jax.Array] = None,  # [B, n] f32
     *,
     c: int,
     scale: float,
@@ -154,18 +182,24 @@ def mla_decode_attention(
     bs = pool.shape[1]
     assert pool.shape[2] == w, (qq.shape, pool.shape)
     table, p_n, num_groups = _whole_groups(table, pages_per_block)
+    width = num_groups * p_n * bs
+    # a slot's whole row of the bias rides the pipeline beside its query
+    # (33 k rows: 133 KB a slot beside ~30 MB of latent rows)
+    masks = [] if bias is None else [_whole_bias(bias, width)[:, None]]
 
     def per_slot(bi, table_ref, lengths_ref):
         return (bi, 0, 0)
 
     return pl.pallas_call(
         functools.partial(_decode_kernel, pages=p_n, block_size=bs,
-                          num_groups=num_groups, c=c, scale=scale),
+                          num_groups=num_groups, c=c, scale=scale,
+                          masked=bool(masks)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b,),
             in_specs=[pl.BlockSpec((1, heads, w), per_slot),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+                      pl.BlockSpec(memory_space=pl.ANY)]
+            + [pl.BlockSpec((1, 1, width), per_slot)] * len(masks),
             out_specs=pl.BlockSpec((1, heads, c), per_slot),
             scratch_shapes=[pltpu.VMEM((2, p_n, bs, w), pool.dtype),
                             pltpu.SemaphoreType.DMA((2, p_n)),
@@ -178,22 +212,26 @@ def mla_decode_attention(
         # the kernel's instruction in a device trace:
         # ``mla_decode_attn.<n>``
         name="mla_decode_attn",
-    )(table.astype(jnp.int32), lengths.astype(jnp.int32), qq, pool)
+    )(table.astype(jnp.int32), lengths.astype(jnp.int32), qq, pool, *masks)
 
 
-def gather_latent_decode(qq, pool, table, lengths, *, c: int, scale: float,
+def gather_latent_decode(qq, pool, table, lengths, bias=None, *, c: int,
+                         scale: float,
                          pages_per_block: int = PAGES_PER_BLOCK):
     """:func:`mla_decode_attention` in plain ``jnp``: the off-chip path and
     the parity oracle.  Group by group of the same pages, every slot's at
     once, as far as the LONGEST slot's length (the trip count is read from
-    ``lengths``, not from the table's width), a running softmax in the
-    kernel's arithmetic; each group's ``[B, H, rows]`` scores pass through
-    memory, which the kernel exists to avoid."""
+    ``lengths``, not from the table's width), under the same ``bias``,
+    a running softmax in the kernel's arithmetic; each group's ``[B, H,
+    rows]`` scores pass through memory, which the kernel exists to
+    avoid."""
     b, heads, w = qq.shape
     bs = pool.shape[1]
     table, p_n, num_groups = _whole_groups(table, pages_per_block)
     rows = p_n * bs
     lengths = lengths.astype(jnp.int32)
+    if bias is not None:
+        bias = _whole_bias(bias, num_groups * rows)
     n_live = jnp.minimum((jnp.max(lengths) + rows - 1) // rows, num_groups)
 
     def group(g, carry):
@@ -202,6 +240,9 @@ def gather_latent_decode(qq, pool, table, lengths, *, c: int, scale: float,
         keys = jnp.take(pool, ids, axis=0).reshape(b, rows, w)
         s = jnp.einsum("bhw,bsw->bhs", qq, keys.astype(qq.dtype),
                        preferred_element_type=jnp.float32) * scale
+        if bias is not None:
+            s = s + jax.lax.dynamic_slice_in_dim(
+                bias, g * rows, rows, axis=1)[:, None, :]
         key_pos = g * rows + jnp.arange(rows)
         s = jnp.where((key_pos[None, :] < lengths[:, None])[:, None, :],
                       s, _NEG_INF)

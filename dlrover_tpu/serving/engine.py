@@ -132,9 +132,10 @@ class EngineStats:
     #                               them: whole page groups up to each
     #                               slot's length.  Both stay 0 where
     #                               the gather path decodes.  Of a
-    #                               latent-attention model that attends
-    #                               its whole context: one layer's
-    #                               latent rows (mla_decode_attention)
+    #                               latent-attention model, with a
+    #                               learned selection or none: one
+    #                               layer's latent rows
+    #                               (mla_decode_attention)
     # a model with a learned selection of keys (LlamaConfig.index_topk),
     # summed over queries (decode forwards and prefill chunks alike) and
     # over nothing else: one layer's rows, as every layer reads the same
@@ -1637,17 +1638,19 @@ class InferenceEngine:
     def _book_kv_rows(self, active: np.ndarray,
                       chunks: int = 1) -> Tuple[int, int]:
         """Add to ``stats.kv_rows_live`` / ``kv_rows_streamed`` what
-        the fused paged kernel (of a latent-attention model with no
-        selection: ``mla_decode_attention``, one layer's latent rows)
+        the fused paged kernel (of a latent-attention model:
+        ``mla_decode_attention``, one layer's latent rows, which a
+        learned selection streams too, under its mask: beside
+        ``_book_selection``'s ``attn_rows_selected`` that is what the
+        forward reads against what it attends)
         reads in the next ``chunks`` decode
         chunks dispatched from ``_positions`` for the slots ``active``
         (the lengths it will be handed: ``position + 1``, one more
         each forward; every other slot gets length 0 and reads
         nothing), and return the two sums.  Host integer arithmetic
         on a [slots, forwards] array; (0, 0) where the gather path
-        decodes and for a learned selection (``_book_selection``)."""
-        if self.attention_impl != "pallas" or (
-                self._latent and self.cfg.index_topk):
+        decodes."""
+        if self.attention_impl != "pallas":
             return 0, 0
         if self._latent:
             from dlrover_tpu.ops.pallas.mla_decode import streamed_rows

@@ -42,13 +42,16 @@ One layer, on its normed input ``h`` (positions ``t``, ``s``):
 Two attention paths, both reading the pools through the block table and
 neither making a dense copy of a slot's table:
 
-- decode (one query a slot, every slot), with a selection: the index
-  scores of a slot's LIVE pages from the ``paged_index_scores`` kernel,
-  ``jax.lax.top_k``, a gather of the chosen latent rows, two einsums.
-  Without one: ``ops/pallas/mla_decode.py mla_decode_attention``, one
-  kernel over each slot's live pages of the latent pool
-  (``attention_impl="pallas"``), or its ``jnp`` oracle
-  ``gather_latent_decode``, group by group of the same live pages.
+- decode (one query a slot, every slot): ``ops/pallas/mla_decode.py
+  mla_decode_attention``, one kernel over each slot's live pages of the
+  latent pool (``attention_impl="pallas"``), or its ``jnp`` oracle
+  ``gather_latent_decode``, group by group of the same live pages.  With
+  a selection the kernel streams the same pages under a MASK of the
+  chosen rows: the index scores of a slot's LIVE pages from the
+  ``paged_index_scores`` kernel, the ``index_topk``-th largest of them
+  (:func:`_kth_largest`, as a run of queries chooses), ``scores >= that``
+  as a float32 bias.  No sort, no positions, no gather of rows: this
+  chip gathers rows at a tenth of the pace it streams them.
 - a run of queries (a prefill chunk, a speculative verify, a bucketed
   prefill): row by row of the group (``lax.map``), over the row's live
   key blocks only (trip counts read from the positions): the index
@@ -218,6 +221,15 @@ def _kth_largest(keys: jax.Array, k: int) -> jax.Array:
         0, 32, bit, jnp.zeros(keys.shape[:1], jnp.uint32))
 
 
+def _chosen(keys: jax.Array, k: int, dead: jax.Array) -> jax.Array:
+    """What a selection IS, for a run of queries and for decode: of each
+    row of ``keys`` [R, W] (:func:`_orderable` scores; ``dead``: no key
+    there) the ``k`` largest, and with the ``k``-th every key that ties
+    it, as the reference chooses (``scores >= kth``); every live key of a
+    row that has no more than ``k``."""
+    return (keys >= _kth_largest(keys, k)[:, None]) & (keys > dead)
+
+
 def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
                 cfg: LlamaConfig, pages: int, impl: str = "xla",
                 interpret: bool = False, n_real=None):
@@ -278,9 +290,7 @@ def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
                 0, n_live, score_block,
                 jnp.full((klen, width), dead, jnp.uint32))
         with device_scope("dsa_select"):
-            chosen = real_only(
-                (keys >= _kth_largest(keys, cfg.index_topk)[:, None])
-                & (keys > dead))
+            chosen = real_only(_chosen(keys, cfg.index_topk, dead))
     else:
         # no more keys than a query may choose: plain causal attention
         chosen = real_only(jnp.arange(width)[None, :] <= q_pos[:, None])
@@ -326,26 +336,14 @@ def _attend_decode(qq, q_i, w, latent_pool, index_pool, table, lengths,
     """One query a slot: ``qq`` [B, H, C + R], ``q_i`` [B, Hi, Di], ``w``
     [B, Hi], ``lengths`` [B] the keys each slot sees (0: its output is
     not wanted).  Returns the attended latent [B, H, C] float32 and the
-    positions attended to, [B, S] int32 (-1: none; None for a model with
-    no selection: a query attends to every row behind it)."""
-    from dlrover_tpu.ops.pallas import paged_index
+    selection, [B, rows] bool (None where a query attends to every row
+    behind it: a model with no selection, or a table of no more rows
+    than a query may choose)."""
+    from dlrover_tpu.ops.pallas import mla_decode, paged_index
 
-    b, mb = table.shape
-    bs, c = latent_pool.shape[1], cfg.kv_lora_rank
-    if not cfg.index_topk:
-        from dlrover_tpu.ops.pallas import mla_decode
-
-        with device_scope("mla_attn"):
-            if impl == "pallas":
-                o = mla_decode.mla_decode_attention(
-                    qq, latent_pool, table, lengths, c=c,
-                    scale=_softmax_scale(cfg), interpret=interpret)
-            else:
-                o = mla_decode.gather_latent_decode(
-                    qq, latent_pool, table, lengths, c=c,
-                    scale=_softmax_scale(cfg))
-        return o, None
-    if mb * bs > cfg.index_topk:
+    mb, bs = table.shape[1], latent_pool.shape[1]
+    chosen = bias = None
+    if cfg.index_topk and mb * bs > cfg.index_topk:
         with device_scope("dsa_index"):
             if impl == "pallas":
                 scores = paged_index.paged_index_scores(
@@ -354,29 +352,52 @@ def _attend_decode(qq, q_i, w, latent_pool, index_pool, table, lengths,
                 scores = paged_index.gather_index_scores(
                     q_i, w, index_pool, table, lengths)
         with device_scope("dsa_select"):
-            top, pos = jax.lax.top_k(scores, cfg.index_topk)  # [B, topk]
-            valid = top > _NEG_INF
-    else:
-        pos = jnp.broadcast_to(jnp.arange(mb * bs), (b, mb * bs))
-        valid = pos < lengths[:, None]
-    with device_scope("dsa_select"):
-        page = jnp.take_along_axis(
-            table, jnp.minimum(pos // bs, mb - 1), axis=1)
-        flat = jnp.where(valid, page * bs + pos % bs, 0)
-        rows = jnp.take(latent_pool.reshape(-1, latent_pool.shape[-1]),
-                        flat, axis=0)                         # [B, S, C+R]
+            chosen = _chosen(
+                _orderable(scores), cfg.index_topk,
+                _orderable(jnp.full((), _NEG_INF, jnp.float32)))
+            bias = jnp.where(chosen, 0.0, _NEG_INF)
     with device_scope("mla_attn"):
-        s = jnp.einsum("bhc,bsc->bhs", qq, rows.astype(qq.dtype),
-                       preferred_element_type=jnp.float32
-                       ) * _softmax_scale(cfg)
-        s = jnp.where(valid[:, None, :], s, _NEG_INF)
-        m = s.max(axis=-1, keepdims=True)
-        p = jnp.exp(s - jnp.where(jnp.isfinite(m), m, 0.0))
-        p = p / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
-        o = jnp.einsum("bhs,bsc->bhc", p.astype(qq.dtype),
-                       rows[..., :c].astype(qq.dtype),
-                       preferred_element_type=jnp.float32)
-    return o, jnp.where(valid, pos, -1).astype(jnp.int32)
+        if impl == "pallas":
+            o = mla_decode.mla_decode_attention(
+                qq, latent_pool, table, lengths, bias,
+                c=cfg.kv_lora_rank, scale=_softmax_scale(cfg),
+                interpret=interpret)
+        else:
+            o = mla_decode.gather_latent_decode(
+                qq, latent_pool, table, lengths, bias,
+                c=cfg.kv_lora_rank, scale=_softmax_scale(cfg))
+    return o, chosen
+
+
+def _rows_of(chosen, length, rows: int, size: int) -> jax.Array:
+    """ONE slot's selection as positions, for the witness: ``chosen``
+    [W] bool (None: every row behind ``length``, of ``rows``) -> [size]
+    int32 ascending, -1 behind the last (more than ``size`` chosen rows
+    show their first ``size``: the caller leaves room for a tie).  Without a
+    scatter, a gather or a sort: ``jnp.nonzero(size=)`` scatters (299 us
+    a row of 33 k on the chip, 1.4 ms a forward) and a search over the
+    prefix sums takes 47-51, this 5.7 (PR 44).  The ``j``-th chosen row
+    is found in two steps of counting, first its group of 128 lanes (by
+    the chosen rows ahead of each group), then its lane (by the prefix
+    counts inside that group: two small matmuls on 0 / 1)."""
+    if chosen is None:
+        chosen = jnp.arange(rows) < length
+    lanes = jnp.pad(chosen, (0, -chosen.shape[0] % _LANES)
+                    ).reshape(-1, _LANES)                     # [G, 128]
+    count = jnp.sum(lanes, axis=-1, dtype=jnp.int32)
+    ahead = jnp.cumsum(count) - count          # chosen before a group
+    j = jnp.arange(size, dtype=jnp.int32)
+    group = jnp.sum(ahead[None, :] <= j[:, None], axis=-1) - 1
+    hot = group[:, None] == jnp.arange(lanes.shape[0])[None, :]
+    rank = j - jnp.sum(jnp.where(hot, ahead[None, :], 0), axis=-1)
+    exact = dict(preferred_element_type=jnp.float32)  # sums of 0 / 1
+    bits = jnp.dot(hot.astype(jnp.bfloat16), lanes.astype(jnp.bfloat16),
+                   **exact)                    # [size, 128]: its group's
+    upto = jnp.dot(bits.astype(jnp.bfloat16), jnp.triu(
+        jnp.ones((_LANES, _LANES), jnp.bfloat16)), **exact)
+    lane = jnp.sum(upto <= rank[:, None].astype(jnp.float32), axis=-1)
+    return jnp.where(j < jnp.sum(count), group * _LANES + lane,
+                     -1).astype(jnp.int32)
 
 
 def _attn_out(lp, o_lat, cfg: LlamaConfig, dtype):
@@ -513,7 +534,9 @@ def verify_step(
     A cache that carries ``watch_slot`` (an int32 scalar: the engine's
     ``watch``) comes back with ``witness``, what THIS forward did for that
     slot's row: the keys each query attended to, a layer (decode: ``rows``
-    [layers, S] int32 positions, -1 none; a run: ``chosen_bits`` [layers,
+    [layers, S] int32 positions, ascending, -1 none: that slot's row of
+    the mask alone, :func:`_rows_of`, ``S`` being ``index_topk`` and 128
+    more for ties at the threshold; a run: ``chosen_bits`` [layers,
     K, table rows / 8] uint8, ``jnp.packbits`` of the mask), and the first
     sparse MLP's normed input and output (``sparse_in``, ``sparse_out``
     [K, E]).  A slot that is not among the rows leaves junk there.  A model
@@ -597,7 +620,20 @@ def verify_step(
                                           a[5]),
                     (qq, q_i, w, pos_k, run_table, n_real))
             if watch is not None and cfg.index_topk:
-                selections.append(jnp.take(chosen, watch, axis=0))
+                seen_row = None if chosen is None \
+                    else jnp.take(chosen, watch, axis=0)
+                if decode:
+                    # the watched slot's row alone, a layer; with room
+                    # for the rows that tie the ``index_topk``-th, which
+                    # are chosen and attended with it (a tie at the
+                    # threshold is one query in a few thousand at 33 k
+                    # float32 scores: every window has some)
+                    rows = table.shape[1] * lat.shape[1]
+                    with device_scope("dsa_select"):
+                        seen_row = _rows_of(
+                            seen_row, jnp.take(lengths, watch), rows,
+                            min(cfg.index_topk + _LANES, rows))
+                selections.append(seen_row)
             x = x + _attn_out(lp, o_lat, cfg, dtype)
             latent_pools.append(lat)
             index_pools.append(idx)

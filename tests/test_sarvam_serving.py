@@ -415,11 +415,14 @@ def serve_latent_watched():
 # jaxpr's text).  ``prefill_chunk`` is PR 42's: its attention is told
 # how many of the chunk's queries are the prompt's (``logits_index +
 # 1``: the kernel's third scalar, the selection's mask of padded rows,
-# trip counts from the last real query's position); the other two are
-# the texts they were
+# trip counts from the last real query's position); ``decode`` is PR
+# 44's: its selection is a threshold and a mask over the decode kernel
+# (``_kth_largest``, a float32 bias as ``mla_decode_attention``'s fifth
+# operand, the watched slot's mask row as positions) where it was
+# ``top_k``, a gather and two einsums; ``prefill`` is the text it was
 _GLM5 = {
-    "decode": (5280, "9e791ac037734e6758849d901747a6915d6473d2c89adf94ea80"
-                     "fd7b40c3d61c"),
+    "decode": (6290, "93faba0c1818cd30fdf58c8588c48d6665d448c3e2b4d75b96e8"
+                     "3086deeec6d9"),
     "prefill_chunk": (6531, "2191d9240025eb16efd8a5070ced717311d6444dfc91"
                             "4fb62cd234abfc053e0b"),
     "prefill": (5010, "3c37b0a227d855b7cf0046847617ad5712bfd23acd6424ded8"

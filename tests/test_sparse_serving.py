@@ -137,6 +137,52 @@ def test_a_request_on_a_cached_document_answers_as_a_cold_one():
     assert warm._blockmgr.check_books()
 
 
+def test_the_decode_span_says_what_is_read_and_what_is_attended(monkeypatch):
+    """A selection model's decode chunk streams every live row under the
+    mask: its span carries ``kv_rows_streamed`` (the kernel's whole groups
+    of pages up to each length, as a model without a selection books
+    them) beside the selection's own three, and the oracle path, which
+    streams nothing, books none."""
+    from dlrover_tpu.ops.pallas import mla_decode
+    from dlrover_tpu.utils import profiler
+
+    spans = []
+    inner = profiler.span
+
+    def span(name, **attrs):
+        if name == "dlrover.engine.decode_chunk" and attrs:
+            spans.append(attrs)
+        return inner(name, **attrs)
+
+    monkeypatch.setattr("dlrover_tpu.serving.engine.span", span)
+    cfg = tiny()
+    params = SeededGlm5Params(cfg, 9)
+    rng = np.random.RandomState(6)
+    prompts = [rng.randint(0, 128, n).astype(np.int32) for n in (19, 37)]
+    eng = _engine(cfg, params)
+    for prompt in prompts:
+        eng.add_request(prompt, 5)
+    eng.run()
+    st = eng.stats
+    # one token from the prefill, then 4 forwards at lengths n + 1 .. n + 4
+    lengths = np.array([[n + j for j in range(1, 5)] for n in (19, 37)])
+    rows = mla_decode.PAGES_PER_BLOCK * 8
+    assert st.kv_rows_live == int(lengths.sum())
+    assert st.kv_rows_streamed == int((-(-lengths // rows) * rows).sum())
+    assert sum(a["kv_rows_streamed"] for a in spans) == st.kv_rows_streamed
+    assert sum(a["kv_rows_live"] for a in spans) == st.kv_rows_live
+    assert sum(a["attn_rows_selected"] for a in spans) \
+        == cfg.index_topk * lengths.size < st.kv_rows_live
+    assert all(a["index_rows_scanned"] >= a["kv_rows_live"] for a in spans)
+    oracle = _engine(cfg, params, attention_impl="xla")
+    for prompt in prompts:
+        oracle.add_request(prompt, 5)
+    oracle.run()
+    assert (oracle.stats.kv_rows_live, oracle.stats.kv_rows_streamed) \
+        == (0, 0)
+    assert oracle.stats.attn_rows_selected == st.attn_rows_selected
+
+
 def test_the_engine_refuses_a_latent_model_without_pools():
     cfg = tiny()
     with pytest.raises(ValueError, match="paged=True"):
@@ -239,6 +285,238 @@ def test_the_kth_largest_is_the_sorts():
     want = np.sort(x, axis=-1)[:, -8]
     assert (chosen == (x >= want[:, None])).all()
     assert chosen[0].all() and chosen[2:].sum(-1).tolist() == [8] * 4
+
+
+def _parents_decode(qq, q_i, w, latent_pool, index_pool, table, lengths,
+                    cfg, impl, interpret):
+    """``latent._attend_decode`` as the tree before ISSUE 44 had it, kept
+    here as an oracle: ``top_k`` over the index scores, a gather of the
+    chosen latent rows through the table, two einsums over the copy.
+    Returns the attended latent and the positions [B, S] (-1: none)."""
+    b, mb = table.shape
+    bs, c = latent_pool.shape[1], cfg.kv_lora_rank
+    if impl == "pallas":
+        scores = paged_index.paged_index_scores(
+            q_i, w, index_pool, table, lengths, interpret=interpret)
+    else:
+        scores = paged_index.gather_index_scores(
+            q_i, w, index_pool, table, lengths)
+    top, pos = jax.lax.top_k(scores, cfg.index_topk)
+    valid = top > -jnp.inf
+    page = jnp.take_along_axis(
+        table, jnp.minimum(pos // bs, mb - 1), axis=1)
+    flat = jnp.where(valid, page * bs + pos % bs, 0)
+    rows = jnp.take(latent_pool.reshape(-1, latent_pool.shape[-1]),
+                    flat, axis=0)
+    s = jnp.einsum("bhc,bsc->bhs", qq, rows.astype(qq.dtype),
+                   preferred_element_type=jnp.float32
+                   ) * latent._softmax_scale(cfg)
+    s = jnp.where(valid[:, None, :], s, -jnp.inf)
+    m = s.max(axis=-1, keepdims=True)
+    p = jnp.exp(s - jnp.where(jnp.isfinite(m), m, 0.0))
+    p = p / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
+    o = jnp.einsum("bhs,bsc->bhc", p.astype(qq.dtype),
+                   rows[..., :c].astype(qq.dtype),
+                   preferred_element_type=jnp.float32)
+    return o, jnp.where(valid, pos, -1)
+
+
+def _decode_operands(cfg, lengths, mb=15, bs=8, nb=60, seed=2,
+                     whole_numbers=False):
+    """One decode forward's attention operands for slots of these
+    ``lengths``, each on pages of its own; dead rows of both pools are
+    loud.  ``whole_numbers``: index queries, keys and weights whose
+    scores are exact in float32 and few, so that many tie."""
+    rng = np.random.RandomState(seed)
+    b = len(lengths)
+    width = latent.latent_row_width(cfg)
+    live = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+    table = np.zeros((b, mb), np.int32)
+    free = list(range(1, nb))
+    for s, n in enumerate(lengths):
+        for j in range(-(-n // bs)):
+            table[s, j] = free.pop(0)
+    lat = np.full((nb, bs, width), 1e4, np.float32)
+    idx = np.full((nb, bs, cfg.index_head_dim), 1e4, np.float32)
+    for s, n in enumerate(lengths):
+        at = (table[s, np.arange(n) // bs], np.arange(n) % bs)
+        lat[at] = rng.randn(n, width)
+        idx[at] = rng.randint(-2, 3, (n, cfg.index_head_dim)) \
+            if whole_numbers else rng.randn(n, cfg.index_head_dim)
+    lat[..., live:] = 0.0
+    qq = rng.randn(b, cfg.num_heads, width).astype(np.float32)
+    qq[..., live:] = 0.0
+    shape = (b, cfg.index_n_heads, cfg.index_head_dim)
+    q_i = rng.randint(-2, 3, shape) if whole_numbers else rng.randn(*shape)
+    w = 2.0 ** -rng.randint(0, 3, shape[:2]) if whole_numbers \
+        else rng.rand(*shape[:2])
+    return tuple(jnp.asarray(a) for a in (
+        qq, q_i.astype(np.float32), w.astype(np.float32), lat, idx, table,
+        np.asarray(lengths, np.int32)))
+
+
+def _as_mask(pos, width):
+    mask = np.zeros((pos.shape[0], width + 1), bool)
+    np.put_along_axis(mask, np.where(pos < 0, width, pos), True, axis=-1)
+    return mask[:, :width]
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_decode_under_the_threshold_is_the_parents_top_k_and_gather(impl):
+    """No score ties the ``index_topk``-th: the threshold chooses the rows
+    ``top_k`` chose, and streaming every live row under their mask
+    attends them as the gathered copy was attended.  A slot with fewer
+    rows than it may choose attends them all, a slot of length 0 none."""
+    cfg = tiny()
+    args = _decode_operands(cfg, [45, 0, 97, 5, 120])
+    got, chosen = latent._attend_decode(*args, cfg, impl, True)
+    want, pos = _parents_decode(*args, cfg, impl, True)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    assert (np.asarray(chosen)
+            == _as_mask(np.asarray(pos), chosen.shape[1])).all()
+    assert np.asarray(chosen).sum(-1).tolist() == [8, 0, 8, 5, 8]
+    assert not np.asarray(got[1]).any()
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_a_tie_at_the_threshold_is_attended_whole_as_a_run_does(impl):
+    """Scores that tie the ``index_topk``-th are ALL chosen, as the
+    reference says (``scores >= kth``) and as the same query chooses
+    when it comes as a run of one (``_attend_run``, the one helper);
+    ``top_k`` kept the lowest positions among them."""
+    cfg = tiny()
+    lengths = [61, 110, 87]
+    args = _decode_operands(cfg, lengths, whole_numbers=True)
+    qq, q_i, w, lat, idx, table, _ = args
+    # three rows that scored lower get the index key of the row that
+    # scored ``index_topk``-th: whole numbers, so the four scores are one
+    scores = np.asarray(paged_index.gather_index_scores(
+        q_i, w, idx, table, args[6]))
+    idx, pages = np.array(idx), np.asarray(table)
+    for s, n in enumerate(lengths):
+        order = np.argsort(-scores[s, :n], kind="stable")
+        kth = order[cfg.index_topk - 1]
+        for low in order[cfg.index_topk + 4:cfg.index_topk + 7]:
+            idx[pages[s, low // 8], low % 8] = idx[pages[s, kth // 8],
+                                                   kth % 8]
+    idx = jnp.asarray(idx)
+    args = args[:4] + (idx,) + args[5:]
+    got, chosen = latent._attend_decode(*args, cfg, impl, True)
+    _, pos = _parents_decode(*args, cfg, impl, True)
+    chosen, kept = np.asarray(chosen), _as_mask(np.asarray(pos),
+                                                chosen.shape[1])
+    scores = np.asarray(paged_index.gather_index_scores(*args[1:3], idx,
+                                                        table, args[6]))
+    kth = np.sort(scores, axis=-1)[:, -cfg.index_topk]
+    assert (chosen == (scores >= kth[:, None])).all()
+    assert (chosen.sum(-1) > cfg.index_topk).all()      # ties, planted
+    assert (chosen | kept == chosen).all() \
+        and (kept.sum(-1) == cfg.index_topk).all()
+    run_table = latent._pad_table(table, latent.KEY_BLOCK_PAGES)
+    for s, n in enumerate(lengths):
+        o_run, chosen_run = latent._attend_run(
+            qq[s][None], q_i[s][None], w[s][None],
+            jnp.asarray([n - 1], jnp.int32), lat, idx, run_table[s], cfg,
+            latent.KEY_BLOCK_PAGES, impl, True)
+        width = min(chosen.shape[1], chosen_run.shape[1])
+        assert (np.asarray(chosen_run)[0, :width] == chosen[s, :width]).all()
+        assert not chosen[s, width:].any()
+        np.testing.assert_allclose(got[s], o_run[0], atol=1e-5)
+
+
+@pytest.mark.parametrize("width,chosen,size", [
+    (33 * 128, 300, 512),      # fewer than asked for: -1 behind the last
+    (33 * 128, 512, 512),
+    (33 * 128, 530, 512),      # a tie past the threshold: the first 512
+    (200, 40, 64),             # no whole number of 128 lanes
+    (24, 8, 8),
+    (256, 0, 16),              # nothing chosen
+    (256, 256, 256),           # everything
+], ids=lambda v: str(v))
+def test_a_mask_rows_positions_are_numpys(width, chosen, size):
+    """``_rows_of`` counts where ``jnp.nonzero`` scatters: the same
+    positions, ascending, -1 behind the last; and, with no mask, every
+    row behind the length."""
+    rng = np.random.RandomState(width + chosen)
+    row = np.zeros(width, bool)
+    row[rng.permutation(width)[:chosen]] = True
+    row[:2] = row[-2:] = chosen > 0           # both edges
+    got = np.asarray(jax.jit(
+        lambda r: latent._rows_of(r, None, width, size))(jnp.asarray(row)))
+    want = np.full(size, -1, np.int32)
+    at = np.flatnonzero(row)[:size]
+    want[:at.size] = at
+    assert got.dtype == np.int32 and got.tolist() == want.tolist()
+    short = np.asarray(latent._rows_of(None, jnp.asarray(5), width, size))
+    assert short.tolist() == (list(range(5)) + [-1] * size)[:size]
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_the_witness_rows_are_the_watched_slots_mask(impl, monkeypatch):
+    """A decode forward over two slots hands back, a layer, the WATCHED
+    slot's chosen positions and nothing of the other's: exactly its row
+    of that layer's mask, ascending, -1 behind the last."""
+    cfg = tiny()
+    _, sp = _served(cfg)
+    rng = np.random.RandomState(6)
+    width = latent.latent_row_width(cfg)
+    cache = {
+        "latent_pool": [jnp.zeros((33, 8, width))] * cfg.num_layers,
+        "index_pool": [jnp.zeros((33, 8, cfg.index_head_dim))]
+        * cfg.num_layers,
+        "table": jnp.asarray(np.arange(1, 33).reshape(2, 16), jnp.int32),
+        "moe_picks": jnp.zeros(2, jnp.uint32)}
+    kw = dict(attention_impl=impl, kernel_interpret=True)
+    chunk = jax.jit(lambda p, c, t, at, slot: latent.verify_step(
+        p, cfg, c, t, at, slots=slot, **kw))
+    for slot, n in ((0, 48), (1, 32)):
+        seq = rng.randint(0, 128, n).astype(np.int32)
+        for s in range(0, n, 16):
+            _, cache = chunk(sp, cache, jnp.asarray(seq[None, s:s + 16]),
+                             jnp.asarray([s], jnp.int32),
+                             jnp.asarray([slot], jnp.int32))
+    masks = []
+    real = latent._attend_decode
+
+    def spy(*a):
+        o, chosen = real(*a)
+        masks.append(chosen)
+        return o, chosen
+
+    monkeypatch.setattr(latent, "_attend_decode", spy)
+
+    @jax.jit
+    def forward(p, c, t, at):
+        del masks[:]
+        _, out = latent.verify_step(p, cfg, c, t, at, **kw)
+        return out["witness"]["rows"], jnp.stack(masks)
+
+    for watch, length in ((1, 33), (0, 49)):
+        rows, chosen = forward(
+            sp, dict(cache, watch_slot=jnp.asarray(watch, jnp.int32)),
+            jnp.asarray([[5], [9]], jnp.int32),
+            jnp.asarray([48, 32], jnp.int32))
+        rows, chosen = np.asarray(rows), np.asarray(chosen)
+        # as many as a query may choose, and room for a tie at the
+        # threshold (here: as many as the table holds)
+        assert rows.shape == (cfg.num_layers, chosen.shape[-1]) \
+            and rows.dtype == np.int32
+        for layer in range(cfg.num_layers):
+            at = np.flatnonzero(chosen[layer, watch])
+            assert at.size == cfg.index_topk and at.max() < length
+            assert rows[layer, :at.size].tolist() == at.tolist()
+            assert (rows[layer, at.size:] == -1).all()
+    # a tie at the threshold is attended whole and WITNESSED whole: slot
+    # 1's 32 cached index keys of layer 0 made one key, the forward's own
+    # the 33rd row
+    keys = cache["index_pool"][0]
+    tied = dict(cache, watch_slot=jnp.asarray(1, jnp.int32), index_pool=[
+        keys.at[17:21].set(keys[17, 0])] + cache["index_pool"][1:])
+    rows, chosen = forward(sp, tied, jnp.asarray([[5], [9]], jnp.int32),
+                           jnp.asarray([48, 32], jnp.int32))
+    at = np.flatnonzero(np.asarray(chosen)[0, 1])
+    assert at.size >= 32 > cfg.index_topk
+    assert np.asarray(rows)[0, :at.size].tolist() == at.tolist()
 
 
 def _watched(**engine):

@@ -246,6 +246,104 @@ def test_latent_decode_kernel_compiles_at_sarvam_widths(one_chip):
     assert "mla_decode_attn" in text and "tpu_custom_call" in text
 
 
+def _decode_forward(one_chip, cfg, seeded, cache: dict, slots: int):
+    """One decode forward of a latent model (``seeded``: its seeded
+    parameters' class) over ``cache``'s pools and table, lowered and
+    compiled for the described chip under the scope ``decode_chunk``:
+    ``(lowered, compiled)``."""
+    from dlrover_tpu.serving.model import decode_step
+    from dlrover_tpu.serving.params import serving_params_from_llama
+    from dlrover_tpu.utils.profiler import device_scope
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    sp = jax.eval_shape(lambda: serving_params_from_llama(
+        {"params": seeded(cfg, 3)}, cfg))
+    S = jax.ShapeDtypeStruct
+
+    def forward(p, c, t, pos, act):
+        with device_scope("decode_chunk"):
+            return decode_step(p, cfg, c, t, pos, attention_impl="pallas",
+                               active=act)
+
+    lowered = jax.jit(forward, donate_argnums=(1,)).lower(*on_chip((
+        sp, dict(cache, moe_picks=S((2,), jnp.uint32),
+                 watch_slot=S((), jnp.int32)),
+        S((slots,), jnp.int32), S((slots,), jnp.int32),
+        S((slots,), jnp.bool_))))
+    return lowered, lowered.compile()
+
+
+def _decode_kernel_operands(text: str) -> list:
+    """The operand shapes of every ``mla_decode_attn`` call in a compiled
+    program's text: table, lengths, queries, pool and, of a model with a
+    learned selection, the mask."""
+    import re
+
+    calls = [line for line in text.splitlines()
+             if re.search(r"%mla_decode_attn[.\d]* = .* custom-call\(", line)]
+    return [re.findall(r"[a-z]+\d+\[[\d,]*\]", line.split(
+        "operand_layout_constraints={")[1].split("}}")[0]) for line in calls]
+
+
+def test_glm5_decode_forward_streams_under_the_mask(one_chip, monkeypatch):
+    """One decode forward of ``glm5-serve`` at the cell's widths (32 slots,
+    tables of 258 pages of 128 rows, experts 0-15 of 256, an eighth of the
+    vocabulary; the leading dense layer and one sparse layer): the
+    selection is a threshold and a mask.  A layer is ONE
+    ``paged_index_scores`` under ``dsa_index`` and ONE ``mla_decode_attn``
+    under ``mla_attn`` whose fifth operand is the float32 mask, a row a
+    slot; the program sorts nothing but the experts' picks, and holds no
+    copy of ``slots x index_topk`` latent rows nor of every row a table
+    could hold."""
+    import re
+
+    from dlrover_tpu.models import moe
+    from dlrover_tpu.models.llama import LlamaConfig
+    from dlrover_tpu.serving import latent
+    from dlrover_tpu.utils.profiler import parse_program
+    from perfbench.weights_glm5 import SeededGlm5Params
+
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    cfg = LlamaConfig.glm5(
+        num_layers=2, moe_first_dense=1, moe_experts_held=(0, 16),
+        vocab_size=19360, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    slots, mb, bs, nb = 32, 258, 128, 2700
+    S = jax.ShapeDtypeStruct
+    lowered, compiled = _decode_forward(one_chip, cfg, SeededGlm5Params, {
+        "latent_pool": [S((nb, bs, latent.latent_row_width(cfg)),
+                          jnp.bfloat16)] * cfg.num_layers,
+        "index_pool": [S((nb, bs, cfg.index_head_dim),
+                         jnp.bfloat16)] * cfg.num_layers,
+        "table": S((slots, mb), jnp.int32)}, slots)
+    text = compiled.as_text()
+    table = parse_program(
+        "decode", text, {"decode_chunk", "mla_attn", "mla_proj", "dsa_index",
+                         "dsa_select", "moe_route", "moe_experts",
+                         "moe_shared", "mlp"},
+        lowered.as_text(debug_info=True))
+    assert table.complete, table.missing
+    kernels = sorted((n.split(".")[0], scope)
+                     for n, scope in table.scope_of.items()
+                     if n.startswith(("mla_decode_attn", "paged_index")))
+    assert kernels == [("mla_decode_attn", "mla_attn")] * 2 \
+        + [("paged_index_scores", "dsa_index")] * 2, kernels
+    # the kernel's groups of 8 pages: 264 of them, 33 792 rows of mask
+    mask = f"f32[{slots},1,{-(-mb // 8) * 8 * bs}]"
+    assert _decode_kernel_operands(text) == [
+        [f"s32[{slots},264]", f"s32[{slots}]", f"bf16[{slots},64,640]",
+         f"bf16[{nb},{bs},640]", mask]] * 2
+    sorts = {table.scope_of.get(m) for m in re.findall(
+        r"%(sort[.\d]*) = \S+ sort\(", text)}
+    assert sorts <= {"moe_route", "moe_experts"}, sorts
+    for rows in (cfg.index_topk, mb * bs):        # latent rows, copied
+        assert not re.search(rf"\[{slots},{rows},(640|512)\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2**20
+
+
 def test_sarvam_decode_forward_streams_live_pages_only(one_chip,
                                                        monkeypatch):
     """One decode forward of ``sarvam-105b-serve`` at the cell's widths (32
@@ -260,9 +358,7 @@ def test_sarvam_decode_forward_streams_live_pages_only(one_chip,
     from dlrover_tpu.models import moe
     from dlrover_tpu.models.llama import LlamaConfig
     from dlrover_tpu.serving import latent
-    from dlrover_tpu.serving.model import decode_step
-    from dlrover_tpu.serving.params import serving_params_from_llama
-    from dlrover_tpu.utils.profiler import device_scope, parse_program
+    from dlrover_tpu.utils.profiler import parse_program
     from perfbench.weights_sarvam import SeededSarvamParams
 
     monkeypatch.setattr(moe, "_interpret", lambda: False)
@@ -270,31 +366,11 @@ def test_sarvam_decode_forward_streams_live_pages_only(one_chip,
         num_layers=2, moe_experts_held=(0, 32), vocab_size=65536,
         dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
     slots, mb, bs, nb = 32, 258, 128, 2500
-
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), tree)
-
-    sp = on_chip(jax.eval_shape(lambda: serving_params_from_llama(
-        {"params": SeededSarvamParams(cfg, 3)}, cfg)))
     S = jax.ShapeDtypeStruct
-    cache = on_chip({
+    lowered, compiled = _decode_forward(one_chip, cfg, SeededSarvamParams, {
         "latent_pool": [S((nb, bs, latent.latent_row_width(cfg)),
                           jnp.bfloat16)] * cfg.num_layers,
-        "table": S((slots, mb), jnp.int32),
-        "moe_picks": S((2,), jnp.uint32),
-        "watch_slot": S((), jnp.int32)})
-
-    def forward(p, c, t, pos, act):
-        with device_scope("decode_chunk"):
-            return decode_step(p, cfg, c, t, pos, attention_impl="pallas",
-                               active=act)
-
-    lowered = jax.jit(forward, donate_argnums=(1,)).lower(
-        sp, cache, *on_chip((S((slots,), jnp.int32), S((slots,), jnp.int32),
-                             S((slots,), jnp.bool_))))
-    compiled = lowered.compile()
+        "table": S((slots, mb), jnp.int32)}, slots)
     text = compiled.as_text()
     table = parse_program(
         "decode", text, {"decode_chunk", "mla_attn", "mla_proj",
@@ -305,6 +381,10 @@ def test_sarvam_decode_forward_streams_live_pages_only(one_chip,
                if n.startswith("mla_decode_attn")}
     assert len(kernels) == cfg.num_layers \
         and set(kernels.values()) == {"mla_attn"}, kernels
+    # no selection, no mask: the call the tree before the mask compiled
+    assert _decode_kernel_operands(text) == [
+        [f"s32[{slots},264]", f"s32[{slots}]", f"bf16[{slots},64,640]",
+         f"bf16[{nb},{bs},640]"]] * cfg.num_layers
     rows = mb * bs
     assert not re.search(rf"\[{slots},(\d+,)?{rows}[,\]]", text)
     assert not re.search(rf"\[{slots},{rows},\d+\]", text)
@@ -373,6 +453,10 @@ def test_kimi_linear_programs_keep_their_state_in_place(one_chip,
         kernel, attn = "kda_chunk_fwd", "mla_prefill_attn"
     lowered = jax.jit(forward, donate_argnums=(1,)).lower(sp, cache, *args)
     compiled = lowered.compile()
+    if program == "decode":          # no selection, no mask operand
+        assert _decode_kernel_operands(compiled.as_text()) == [
+            [f"s32[{slots},40]", f"s32[{slots}]", f"bf16[{slots},32,640]",
+             f"bf16[{nb},{bs},640]"]]
     table = parse_program(
         program, compiled.as_text(),
         {program if program != "decode" else "decode_chunk", "kda_proj",
