@@ -22,12 +22,13 @@ TPU-first rather than a port:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-
+from flax import struct
 from jax.ad_checkpoint import checkpoint_name
 
 from dlrover_tpu.accel.parallel.mesh import (ambient_mesh,
@@ -693,28 +694,74 @@ def rope_inverse_frequencies(spec: RopeSpec, head_dim: int) -> jax.Array:
     return inv / spec.yarn_factor * ramp + inv * (1.0 - ramp)
 
 
-def rope_table(spec: RopeSpec, head_dim: int, positions: jax.Array):
-    """``(cos, sin)`` [..., rotary/2] of one kind of layer at
-    ``positions``, times its ``attention_factor``."""
-    angles = positions.astype(jnp.float32)[..., None] * \
-        rope_inverse_frequencies(spec, head_dim)
-    return (jnp.cos(angles) * spec.attention_factor,
-            jnp.sin(angles) * spec.attention_factor)
+@struct.dataclass
+class RopeTable:
+    """One kind of layer's rotation at a step's positions: two float32
+    rows a position over the WHOLE head of ``d`` lanes, of which the first
+    ``2 * half`` rotate: ``cos`` is ``[cos, cos, 1 ...]``, ``sin``
+    ``[-sin, sin, 0 ...]``, each ``[s, d]`` or ``[b, s, d]`` with the
+    kind's ``attention_factor`` in them (``ops/pallas/rope.py``)."""
+
+    cos: jax.Array
+    sin: jax.Array
+    half: int = struct.field(pytree_node=False)
 
 
-def apply_rope_table(x: jax.Array, table) -> jax.Array:
-    """x: [b, s, h, d]; ``table`` from :func:`rope_table` ([s, r/2] or
-    [b, s, r/2]): the first r dimensions of a head rotate (in halves, as
-    :func:`apply_rope`), the rest pass through."""
-    cos, sin = (t[..., None, :] for t in table)
-    if cos.ndim == 3:
-        cos, sin = cos[None], sin[None]
-    rotary = 2 * cos.shape[-1]
-    xf = x.astype(jnp.float32)
-    x1, x2 = jnp.split(xf[..., :rotary], 2, axis=-1)
-    out = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, xf[..., rotary:]], axis=-1)
-    return out.astype(x.dtype)
+def rope_table(spec: RopeSpec, head_dim: int,
+               positions: jax.Array) -> Optional[RopeTable]:
+    """The :class:`RopeTable` of one kind of layer at ``positions``;
+    ``None`` where nothing of a head rotates."""
+    inv = rope_inverse_frequencies(spec, head_dim)
+    half = inv.shape[0]
+    if not half:
+        return None
+    angles = positions.astype(jnp.float32)[..., None] * inv
+    cos = jnp.cos(angles) * spec.attention_factor
+    sin = jnp.sin(angles) * spec.attention_factor
+    rest = cos.shape[:-1] + (head_dim - 2 * half,)
+    return RopeTable(
+        cos=jnp.concatenate([cos, cos, jnp.ones(rest, jnp.float32)], -1),
+        sin=jnp.concatenate([-sin, sin, jnp.zeros(rest, jnp.float32)], -1),
+        half=half)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _rotate(half: int, conj: bool, x, cos, sin):
+    """``x`` [b, s, h, d] rotated by a :class:`RopeTable`'s rows (back,
+    with ``conj``): the kernel where one device holds heads of 128 on a
+    TPU, its ``jnp`` oracle elsewhere."""
+    from dlrover_tpu.ops.pallas import rope
+
+    mesh = ambient_mesh()
+    # a Mosaic kernel cannot be partitioned by GSPMD: one device's work
+    if (jax.default_backend() == "tpu" and rope.kernel_takes(x, cos)
+            and (mesh is None or mesh.size == 1)):
+        return rope.rope_rotate(x, cos, sin, half, conj)
+    return rope.rotate_reference(x, cos, sin, half, conj)
+
+
+def _rotate_fwd(half, conj, x, cos, sin):
+    return _rotate(half, conj, x, cos, sin), (cos, sin)
+
+
+def _rotate_bwd(half, conj, tables, g):
+    # the transpose of a rotation is the rotation back: the same pass.
+    # The tables come from integer positions, so nothing is owed to them.
+    return _rotate(half, not conj, g, *tables), None, None
+
+
+_rotate.defvjp(_rotate_fwd, _rotate_bwd)
+
+
+def apply_rope_table(x: jax.Array, table: Optional[RopeTable]) -> jax.Array:
+    """x: [b, s, h, d]; ``table`` from :func:`rope_table`: the first
+    ``2 * table.half`` dimensions of a head rotate (in halves, as
+    :func:`apply_rope`), the rest pass through.  One pass over ``x``
+    forward and one over its cotangent backward, nothing saved between
+    them but the table, to which no gradient goes."""
+    if table is None:
+        return x
+    return _rotate(table.half, False, x, table.cos, table.sin)
 
 
 def apply_rope(x: jax.Array, angles: jax.Array) -> jax.Array:

@@ -549,6 +549,33 @@ def test_grouped_expert_matmul_compiles_at_the_hybrid_cells_share(
     assert text.count("tpu_custom_call") >= 8
 
 
+@pytest.mark.parametrize("heads,half,dtype", [
+    (64, 64, jnp.bfloat16), (48, 32, jnp.bfloat16), (8, 64, jnp.bfloat16),
+    (8, 32, jnp.float32)],
+    ids=["window-q", "full-q", "window-k", "full-k-f32"])
+def test_rope_rotate_compiles_at_the_hybrid_cells_shapes(
+        one_chip, heads, half, dtype):
+    """``train-hybrid-8k``'s rotations (2 x 8192 tokens, heads of 128, a
+    window layer rotating whole heads and a full layer half of each),
+    forward and rotating back: one kernel call each and nothing else,
+    given and giving the flash kernels' layout (the whole step's text
+    shows the projections and the flash calls on either side of it)."""
+    from dlrover_tpu.ops.pallas.rope import rope_rotate
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    for conj in (False, True):
+        text = jax.jit(
+            lambda x, c, sn: rope_rotate(
+                x.transpose(0, 2, 1, 3), c, sn, half, conj).transpose(
+                    0, 2, 1, 3)
+        ).lower(s((2, heads, 8192, 128), dtype), s((8192, 128), jnp.float32),
+                s((8192, 128), jnp.float32)).compile().as_text()
+        assert text.count("tpu_custom_call") == 1
+        assert " transpose(" not in text and " copy(" not in text
+
+
 def _olmoe_cell():
     from dlrover_tpu.models.llama import LlamaConfig
 
@@ -569,7 +596,7 @@ def _hybrid_cell():
     (_olmoe_cell, (3, 64, 2048, 1024), 1, {"attn": 4}),
     (_hybrid_cell, (2, 32, 2048, 512), 4, {
         "attn_full": 8, "flash_window_fwd": 6, "flash_window_dq": 3,
-        "flash_window_dkv": 3}),
+        "flash_window_dkv": 3, "rope_rotate": 30}),
 ], ids=["train-moe-dropless", "train-hybrid-8k"])
 def test_sparse_cells_step_reads_expert_weights_in_the_stack(
         topo, monkeypatch, cell, stack, sparse_layers_a_loop, flash_calls):
@@ -581,7 +608,11 @@ def test_sparse_cells_step_reads_expert_weights_in_the_stack(
     ``gmm.<n>`` / ``tgmm.<n>``, 12 a sparse layer (3 forward, 3
     recomputed, 3 + 3 backward), and the flash calls ``attn.<n>`` /
     ``attn_full.<n>`` / ``flash_window_*``: what the benchmark's readers
-    find them by.  The text holds a loop's body once."""
+    find them by.  The text holds a loop's body once.  Since PR 45 a
+    layer with a ``LayerSpec`` rotates q and k through ``rope_rotate``
+    (forward, recomputed, transposed: 6 a layer, the leading layer and a
+    period of four in the text) and no rotated HALF of a head is an array
+    of its own (PERF.md section 6, PR 45)."""
     import collections
     import re
 
@@ -623,6 +654,11 @@ def test_sparse_cells_step_reads_expert_weights_in_the_stack(
         "gmm": 9 * sparse_layers_a_loop, "tgmm": 3 * sparse_layers_a_loop}
     # all layers' groups in one row: the operand the kernels index into
     assert f"bf16[{stack[0] * stack[1]},{stack[2]},{stack[3]}]" in text
+    if cfg.layers is not None:
+        half_a_head = re.compile(
+            rf"(?:bf16|f32)\[{rows},{seq},\d+,(?:{cfg.head_dim_ // 2}|"
+            rf"{cfg.head_dim_ // 4})\]")
+        assert not half_a_head.search(text)
 
 
 def test_expert_weight_copies_counts_a_sliced_operand():
