@@ -9,10 +9,13 @@ TPU hosts:
 - A "worker" is one process per host driving all local TPU chips (the JAX
   model), not one process per accelerator; ``nproc_per_node`` exists for
   CPU tests and multi-slice hosts.
-- Rendezvous yields host ranks; the agent exports the
-  ``DLROVER_COORDINATOR_ADDR`` of host 0 so workers can call
-  ``jax.distributed.initialize`` (the trainer does this — TPU collectives
-  then ride ICI/DCN via XLA; there is no NCCL process-group setup).
+- Rendezvous yields host ranks; the agent exports as
+  ``DLROVER_COORDINATOR_ADDR`` the lowest rank's address and the port
+  that host offered with its join (free there at that moment, a new one
+  every round: ``MasterClient.join_rendezvous``), both from the master's
+  comm-world reply, so workers can call ``jax.distributed.initialize``
+  (the trainer does this — TPU collectives then ride ICI/DCN via XLA;
+  there is no NCCL process-group setup).
 - Membership changes (scale-up detected via ``num_nodes_waiting``) and
   worker failures both funnel into the same restart path, capped by
   ``max_restarts`` (reference: training.py:594-728).
@@ -61,7 +64,6 @@ class WorkerSpec:
     # poll the master's mutable ParallelConfig into the trainer's
     # hot-reload file (reference: --auto_tunning + ParalConfigTuner)
     auto_tunning: bool = False
-    coordinator_port: int = 52300
     env: Optional[Dict[str, str]] = None
     # Host the flash-checkpoint saver factory so trainers can checkpoint
     # into agent-owned shared memory (reference: training.py:580).
@@ -94,6 +96,18 @@ class RendezvousResult:
     group: int
     world: Dict[int, int]  # node_rank -> nproc on that node
     node_ips: Dict[int, str]
+    node_ports: Dict[int, int]  # what each node offered with its join
+
+    @property
+    def coordinator(self) -> str:
+        """``host:port`` of this world's ``jax.distributed`` service: the
+        LOWEST rank's address and the port it offered when it joined
+        this round, so every member derives the same one and no two
+        worlds (another job on the host, this job's next round) share
+        it."""
+        rank0 = min(self.world)
+        ip = self.node_ips.get(rank0) or "127.0.0.1"
+        return f"{ip}:{self.node_ports[rank0]}"
 
 
 class OutageEdge:
@@ -183,9 +197,9 @@ class MasterRendezvousHandler:
         self._retryable(self._join, deadline)
         while True:
             try:
-                rnd, group, world, node_ips = self._client.get_comm_world(
+                rdzv = RendezvousResult(*self._client.get_comm_world(
                     self._rdzv_name, self._node_rank
-                )
+                ))
                 outage_s = outage.recover()
                 if outage_s is not None:
                     logger.info(
@@ -215,16 +229,16 @@ class MasterRendezvousHandler:
                     ) from e
                 time.sleep(1.0)
                 continue
-            if world:
-                if self._node_rank not in world:
+            if rdzv.world:
+                if self._node_rank not in rdzv.world:
                     # completed without us (e.g. we were rounded out by
                     # node_unit); re-join next round
-                    raise RendezvousOutError(rnd)
+                    raise RendezvousOutError(rdzv.round)
                 self.recorder.record(
                     "rendezvous_complete", rdzv=self._rdzv_name,
-                    round=rnd, world=sorted(world),
+                    round=rdzv.round, world=sorted(rdzv.world),
                 )
-                return RendezvousResult(rnd, group, world, node_ips)
+                return rdzv
             now = time.time()
             if now - last_join_check >= self._rejoin_check_interval:
                 last_join_check = now
@@ -308,10 +322,7 @@ class LocalWorkerGroup:
             starts[r] = prefix
             prefix += rdzv.world[r]
         total_procs = prefix
-        coordinator_ip = rdzv.node_ips.get(ranks[0], "127.0.0.1") or "127.0.0.1"
-        # round-dependent port avoids TIME_WAIT collisions across restarts
-        port = spec.coordinator_port + (rdzv.round % 16)
-        coordinator = f"{coordinator_ip}:{port}"
+        coordinator = rdzv.coordinator
 
         # Workers must be able to import the framework even when it is run
         # from a source checkout (script entrypoints don't inherit the
@@ -923,7 +934,6 @@ def run_network_check(
     rounds: int = 2,
     check_timeout: float = 300.0,
     result_timeout: float = 120.0,
-    check_port: int = 52500,
 ) -> Tuple[bool, str]:
     """Two grouped check rounds; the master intersects failures to localize
     the faulty host (reference: NodeCheckElasticAgent training.py:861-1010
@@ -948,15 +958,12 @@ def run_network_check(
         except (TimeoutError, RendezvousOutError) as e:
             return False, f"check rendezvous failed: {e}"
         group_ranks = sorted(rdzv.world)
-        coordinator_ip = rdzv.node_ips.get(group_ranks[0], "127.0.0.1") or "127.0.0.1"
         env = {
             **os.environ,
             "DLROVER_CHECK_GROUP": str(rdzv.group),
             "DLROVER_CHECK_RANK": str(group_ranks.index(node_rank)),
             "DLROVER_CHECK_WORLD": str(len(group_ranks)),
-            "DLROVER_CHECK_COORDINATOR": (
-                f"{coordinator_ip}:{check_port + rdzv.round % 8}"
-            ),
+            "DLROVER_CHECK_COORDINATOR": rdzv.coordinator,
         }
         if spec.comm_perf_test:
             env["DLROVER_COMM_PERF"] = "1"

@@ -16,7 +16,7 @@ from typing import Dict, List, Optional, Tuple
 from dlrover_tpu.common import comm
 from dlrover_tpu.common.constants import NodeEnv, RendezvousName, TaskType
 from dlrover_tpu.common.retry import RetryPolicy
-from dlrover_tpu.common.rpc import RpcStub
+from dlrover_tpu.common.rpc import RpcStub, find_free_port
 from dlrover_tpu.common.serialize import (
     deserialize_message,
     serialize_message,
@@ -178,6 +178,17 @@ class MasterClient:
         node_unit: int = 1,
         slice_id: int = 0,
     ) -> int:
+        # A port free on this host NOW, for the round's jax.distributed
+        # service should this node come out as its lowest rank: a new
+        # one every join, so no two jobs on a host and no two rounds of
+        # a job dial one service, and TIME_WAIT is moot.  A pre-pick,
+        # where every server of this package binds port 0 itself and
+        # announces: this binder is a worker process, which cannot report
+        # a port back before its peers dial it.  A port lost in between
+        # fails the workers' start, and the agent's restart joins again
+        # with a new one.
+        # dlint: disable=DL001 the binder is jax.distributed inside a worker process, which cannot announce before its peers dial
+        node_port = find_free_port()
         reply = self._get(
             comm.JoinRendezvousRequest(
                 node_id=self._node_id,
@@ -187,6 +198,7 @@ class MasterClient:
                 node_unit=node_unit,
                 slice_id=slice_id,
                 node_ip=self._host_ip,
+                node_port=node_port,
             )
         )
         return reply.round
@@ -194,7 +206,7 @@ class MasterClient:
     @retry_rpc()
     def get_comm_world(
         self, rdzv_name: str, node_rank: int
-    ) -> Tuple[int, int, Dict[int, int], Dict[int, str]]:
+    ) -> Tuple[int, int, Dict[int, int], Dict[int, str], Dict[int, int]]:
         reply = self._get(
             comm.CommWorldRequest(
                 node_id=self._node_id,
@@ -202,7 +214,8 @@ class MasterClient:
                 rdzv_name=rdzv_name,
             )
         )
-        return reply.round, reply.group, reply.world, reply.node_ips
+        return (reply.round, reply.group, reply.world, reply.node_ips,
+                reply.node_ports)
 
     @retry_rpc()
     def rendezvous_joined(

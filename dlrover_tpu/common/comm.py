@@ -108,6 +108,10 @@ class JoinRendezvousRequest:
     node_unit: int = 1
     slice_id: int = 0
     node_ip: str = ""
+    # a port free on the joining host at the moment of this join: where
+    # the round's jax.distributed service listens if this node turns out
+    # to be its lowest rank
+    node_port: int = 0
 
 
 @comm_message
@@ -134,8 +138,10 @@ class CommWorldReply:
     group: int = 0
     # node_rank -> local_world_size of every node in the comm world.
     world: Dict[int, int] = field(default_factory=dict)
-    # node_rank -> ip/hostname (for jax.distributed coordinator choice).
+    # node_rank -> ip/hostname and the port offered with the join: the
+    # lowest rank's pair is the round's jax.distributed coordinator.
     node_ips: Dict[int, str] = field(default_factory=dict)
+    node_ports: Dict[int, int] = field(default_factory=dict)
 
 
 @comm_message

@@ -17,18 +17,21 @@ from dlrover_tpu.common.constants import GRPC
 SERVICE_NAME = "dlrover_tpu.Master"
 
 
-# dlint: disable=DL001 sanctioned test-only helper; every in-package caller migrated to bind_server_port / the worker announce idiom, and DL001 blocks new ones
+# dlint: disable=DL001 sanctioned helper of the tests and of the rendezvous join; every server of the package binds through bind_server_port / the worker announce idiom, and DL001 blocks new callers
 def find_free_port(port: int = 0) -> int:
     """Pick a currently-free port — bind-then-close, i.e. RACY.
 
     Between this function returning and the caller re-binding, any
     other process can grab the port (the classic TOCTOU port race).
-    TEST-ONLY: every in-package caller has been migrated — servers bind
-    port 0 THEMSELVES and report the kernel-assigned port, either via
-    :func:`bind_server_port` (gRPC) or the serving worker's announce
-    handshake (serving/remote/worker.py, master/main.py).  dlint's
+    For tests, and for ONE in-package caller: every server of this
+    package binds port 0 ITSELF and reports the kernel-assigned port,
+    either via :func:`bind_server_port` (gRPC) or the serving worker's
+    announce handshake (serving/remote/worker.py, master/main.py).  The
+    exception is ``MasterClient.join_rendezvous``, which offers a port
+    for a round's ``jax.distributed`` service: that binder is a worker
+    process that cannot announce before its peers dial it.  dlint's
     DL001 checker (``python -m tools.dlint dlrover_tpu``) rejects any
-    new in-package call to this function."""
+    other in-package call to this function."""
     with socket.socket(socket.AF_INET, socket.SOCK_STREAM) as s:
         s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
         s.bind(("", port))

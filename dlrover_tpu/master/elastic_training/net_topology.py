@@ -18,6 +18,7 @@ class NodeTopologyMeta:
     process_num: int = 1  # local world size (TPU chips driven by this host)
     slice_id: int = 0
     node_ip: str = ""
+    node_port: int = 0  # offered with the join, fresh every round
     asw: str = ""  # access switch, used for DCN locality between slices
 
 

@@ -107,6 +107,7 @@ class RendezvousManager(metaclass=ABCMeta):
         local_world_size: int,
         node_ip: str = "",
         slice_id: int = 0,
+        node_port: int = 0,
     ) -> int:
         """Add a host to the waiting list; returns the next round id."""
         with self._lock:
@@ -124,6 +125,7 @@ class RendezvousManager(metaclass=ABCMeta):
                 node_rank=node_rank,
                 process_num=local_world_size,
                 node_ip=node_ip,
+                node_port=node_port,
                 slice_id=slice_id,
             )
             self._alive_nodes.add(node_rank)

@@ -210,6 +210,7 @@ class MasterServicer:
             message.local_world_size,
             node_ip=message.node_ip,
             slice_id=message.slice_id,
+            node_port=message.node_port,
         )
         if self._job_manager is not None:
             # network-check joins may update node liveness
@@ -227,6 +228,7 @@ class MasterServicer:
         for rank, meta in world.items():
             reply.world[rank] = meta.process_num
             reply.node_ips[rank] = meta.node_ip
+            reply.node_ports[rank] = meta.node_port
         return reply
 
     def _get_paral_config(self, node_id: int):

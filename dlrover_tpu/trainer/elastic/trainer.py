@@ -340,6 +340,10 @@ class ElasticTrainer:
         from dlrover_tpu.agent.ckpt_saver import read_latest_step
 
         eng = self._ckpt.engine
+        # as ``load`` does: the agent's saver hosts the shm meta server,
+        # and on a host that has not asked for one yet (a first start, a
+        # replacement) ``get_meta`` would dial nothing for its whole 60 s
+        eng._ensure_saver()
         try:
             meta = eng._shm_handler.get_meta()
             shm_step = meta.step if meta is not None and meta.valid else -1

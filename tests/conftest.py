@@ -6,6 +6,9 @@ virtual 8-device CPU mesh: the env vars below MUST be set before the first
 """
 
 import os
+import shutil
+import tempfile
+import uuid
 
 # Force the CPU: tests always run on the virtual 8-device CPU backend,
 # whatever accelerator the host has — they check results and counts,
@@ -17,12 +20,42 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 os.environ.setdefault("DLROVER_LOG_LEVEL", "WARNING")
+# One persistent compile cache for the run, new with it and gone with it:
+# every xdist worker of a run has the run's name from xdist
+# (``pytest_configure`` below hands xdist the controller's), and the
+# processes the tests start inherit the variable.  With it set,
+# ``utils/compile_cache.ensure_compile_cache`` sets nothing (its first
+# rule), so whether a test sees a cache no longer depends on which test
+# built a trainer before it in its worker, and never the checkout's
+# ``.jax_cache`` or a directory another run wrote.  Every program is
+# kept, not only those that took JAX's default second to compile: the
+# tests' programs are tiny and many (the three latent serving files,
+# each alone: 491 s with no cache, 338 s with the default floor, 292 s
+# with none; CHANGES.md, PR 46).
+_RUN = os.environ.get("PYTEST_XDIST_TESTRUNUID") or uuid.uuid4().hex
+os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
+    tempfile.gettempdir(), f"dlrover_tpu_tests_jax_cache_{_RUN}")
+os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
 
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
+
+
+def pytest_configure(config):
+    # the controller of an xdist run: its workers see this name as
+    # PYTEST_XDIST_TESTRUNUID, so all of them share the directory above
+    if getattr(config.option, "testrunuid", "") is None:
+        config.option.testrunuid = _RUN
+
+
+@pytest.hookimpl(trylast=True)
+def pytest_sessionfinish(session):
+    if not hasattr(session.config, "workerinput"):  # not a worker's to remove
+        shutil.rmtree(os.environ["JAX_COMPILATION_CACHE_DIR"],
+                      ignore_errors=True)
 
 
 @pytest.fixture(autouse=True, scope="session")

@@ -51,10 +51,12 @@ class TestSerialize:
 
     def test_dict_with_int_keys(self):
         reply = comm.CommWorldReply(
-            round=1, world={0: 8, 2: 8}, node_ips={0: "a", 2: "b"}
+            round=1, world={0: 8, 2: 8}, node_ips={0: "a", 2: "b"},
+            node_ports={0: 40001, 2: 40002},
         )
         out = deserialize_message(serialize_message(reply))
         assert out.world == {0: 8, 2: 8}
+        assert out.node_ports == {0: 40001, 2: 40002}
 
     def test_bytes_payload(self):
         kv = comm.KeyValuePair(key="k", value=b"\x00\x01\xff")

@@ -121,6 +121,69 @@ def test_two_node_rendezvous_and_env(master2, tmp_path):
     assert c0 == c1  # same coordinator on both hosts
 
 
+def test_two_jobs_on_one_host_get_a_coordinator_each(tmp_path):
+    """Two jobs of two nodes rendezvous on this host at the same time.
+    Within a job every agent hands its workers the SAME
+    ``DLROVER_COORDINATOR_ADDR``; across the jobs the ports DIFFER (one
+    ``jax.distributed`` service a world: workers of two jobs that dial
+    one service kill each other as "a different incarnation"); and a
+    job's second round has a port that is not its first round's."""
+    script = (
+        "import os\n"
+        "open(os.environ['OUT_PATH'], 'w').write(\n"
+        "    os.environ['DLROVER_COORDINATOR_ADDR'])\n"
+    )
+    masters = [LocalJobMaster(0, node_num=2) for _ in range(2)]
+    for m in masters:
+        m.prepare()
+    addrs = [f"127.0.0.1:{m.port}" for m in masters]
+    results = {}
+
+    def run_agent(job, rank):
+        client = _client(addrs[job], rank)
+        spec = WorkerSpec(
+            entrypoint=[sys.executable, "-c", script],
+            monitor_interval=0.3,
+            env={"OUT_PATH": str(tmp_path / f"j{job}r{rank}")},
+            flash_ckpt=False,  # as above: agents in one process
+        )
+        results[job, rank] = ElasticAgent(client, rank, spec).run()
+        client.close()
+
+    second = {}
+
+    def rejoin(rank):
+        client = _client(addrs[0], rank)
+        second[rank] = MasterRendezvousHandler(
+            client, rank, timeout=30
+        ).next_rendezvous()
+        client.close()
+
+    def run_all(target, keys):
+        threads = [threading.Thread(target=target, args=k) for k in keys]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+
+    try:
+        run_all(run_agent, [(j, r) for j in range(2) for r in range(2)])
+        assert results == {(j, r): 0 for j in range(2) for r in range(2)}
+        seen = [
+            [(tmp_path / f"j{j}r{r}").read_text() for r in range(2)]
+            for j in range(2)
+        ]
+        assert seen[0][0] == seen[0][1] and seen[1][0] == seen[1][1]
+        assert seen[0][0] != seen[1][0]  # a service a job
+        run_all(rejoin, [(0,), (1,)])
+    finally:
+        for m in masters:
+            m.stop()
+    assert second[0].round == second[1].round == 2
+    assert second[0].coordinator == second[1].coordinator
+    assert second[0].coordinator != seen[0][0]  # a fresh port a round
+
+
 def test_two_node_network_check(master2):
     """Both hosts pass the grouped check (cross-host collective over a
     jax.distributed group world on CPU) and proceed to training."""

@@ -1,7 +1,7 @@
 """Elastic SPMD training across REAL jax.distributed processes.
 
-The framework's central promise, proven end to end (VERDICT r2 #1;
-reference: dlrover/python/tests/test_elastic_training_agent.py:51-63 +
+The framework's central promise, proven end to end (reference:
+dlrover/python/tests/test_elastic_training_agent.py:51-63 +
 elastic_agent/torch/training.py:577-728):
 
 - a local master + two real `dlrover-tpu-run` agents (two simulated
@@ -17,13 +17,11 @@ elastic_agent/torch/training.py:577-728):
   match an uninterrupted single-process reference run step for step.
 """
 
-import fcntl
-import functools
+import contextlib
 import os
 import signal
 import subprocess
 import sys
-import tempfile
 import time
 
 import numpy as np
@@ -35,7 +33,7 @@ KILL_AFTER_STEP = 3
 SEQ, GB = 32, 8
 
 
-def _agent_cmd(node_rank, master_addr, work, step_sleep=0.0):
+def agent_cmd(node_rank, master_addr, work, step_sleep=0.0):
     return [
         sys.executable, "-m", "dlrover_tpu.agent.launcher",
         "--nnodes=1:2", f"--node_rank={node_rank}",
@@ -51,27 +49,6 @@ def _agent_cmd(node_rank, master_addr, work, step_sleep=0.0):
     ]
 
 
-def one_world_at_a_time(test):
-    """Tests that start a world of more than one node take turns, across
-    the processes of one test run: every agent hands its workers
-    ``127.0.0.1:(52300 + round % 16)`` for the jax coordination service
-    (``elastic_agent.LocalWorkerGroup.spawn``), so two jobs on one
-    machine dial ONE service, and each kills the other's workers ("task
-    1 unexpectedly tried to connect with a different incarnation") until
-    an agent has used up its restarts.  The wait is before the test's
-    body, so it eats into none of its deadlines."""
-
-    @functools.wraps(test)
-    def locked(*args, **kwargs):
-        path = os.path.join(tempfile.gettempdir(),
-                            "dlrover_tpu_test_coordinator_port.lock")
-        with open(path, "w") as lock:
-            fcntl.flock(lock, fcntl.LOCK_EX)
-            return test(*args, **kwargs)
-
-    return locked
-
-
 def wait_until_listening(port, master, timeout=60.0):
     """Block until the master accepts on its port: the agents dial it
     right after, and how long a master takes to get there depends on
@@ -85,22 +62,65 @@ def wait_until_listening(port, master, timeout=60.0):
         time.sleep(0.05)
 
 
+@contextlib.contextmanager
+def local_master(work, node_num):
+    """A local master process for a job of ``node_num`` nodes, logging
+    to ``work/master.log``: yields its port once it listens, and ends it
+    behind the block."""
+    from dlrover_tpu.common.rpc import find_free_port
+
+    port = find_free_port()
+    master = subprocess.Popen(
+        [sys.executable, "-m", "dlrover_tpu.master.main",
+         "--platform", "local", "--port", str(port),
+         "--node_num", str(node_num)],
+        stdout=open(os.path.join(work, "master.log"), "w"),
+        stderr=subprocess.STDOUT,
+    )
+    try:
+        wait_until_listening(port, master)
+        yield port
+    finally:
+        master.terminate()
+        try:
+            master.wait(10)
+        except subprocess.TimeoutExpired:
+            master.kill()
+
+
+@contextlib.contextmanager
+def running_agents():
+    """``{rank: Popen}`` for the block to fill with agents, each started
+    in a process group of its own (``preexec_fn=os.setsid``): whatever
+    still runs behind the block is SIGKILLed with its workers."""
+    agents = {}
+    try:
+        yield agents
+    finally:
+        for p in agents.values():
+            if p.poll() is None:
+                try:
+                    os.killpg(os.getpgid(p.pid), signal.SIGKILL)
+                except (ProcessLookupError, PermissionError):
+                    pass
+
+
 def wait_for_rows(path, agent, cond, timeout, what):
     """Block until ``cond(rows)`` holds of the metrics file (read every
     50 ms: the steps after the awaited one are all the slack there is);
     fail when the agent exits or time runs out."""
     deadline = time.time() + timeout
     while time.time() < deadline:
-        rows = _read_metrics(path)
+        rows = read_metrics(path)
         if cond(rows):
             return
         if agent.poll() is not None:
             pytest.fail(f"agent exited before {what}: {rows}")
         time.sleep(0.05)
-    pytest.fail(f"never saw {what}: {_read_metrics(path)}")
+    pytest.fail(f"never saw {what}: {read_metrics(path)}")
 
 
-def _read_metrics(path):
+def read_metrics(path):
     rows = []
     if os.path.exists(path):
         with open(path) as f:
@@ -135,21 +155,9 @@ def assert_steps_consistent(rows, max_redos: int):
     return sorted(set(steps))
 
 
-@one_world_at_a_time
 def test_kill_one_node_resumes_trajectory(tmp_path):
     work = str(tmp_path)
-    from dlrover_tpu.common.rpc import find_free_port
-
-    port = find_free_port()
-    master = subprocess.Popen(
-        [sys.executable, "-m", "dlrover_tpu.master.main",
-         "--platform", "local", "--port", str(port), "--node_num", "2"],
-        stdout=open(os.path.join(work, "master.log"), "w"),
-        stderr=subprocess.STDOUT,
-    )
-    agents = []
-    try:
-        wait_until_listening(port, master)
+    with local_master(work, 2) as port, running_agents() as agents:
         for rank in (0, 1):
             env = dict(os.environ)
             env.update(
@@ -160,19 +168,19 @@ def test_kill_one_node_resumes_trajectory(tmp_path):
                 DLROVER_MONITOR_INTERVAL="1",
                 JAX_PLATFORMS="cpu",
             )
-            agents.append(subprocess.Popen(
+            agents[rank] = subprocess.Popen(
                 # half a second a step: the seven steps between
                 # KILL_AFTER_STEP and the last must outlast the 50 ms
                 # poll below even when the suite's other workers starve
                 # this process (with no pause all 10 steps can pass
                 # between two polls, and the kill comes after the run)
-                _agent_cmd(rank, f"127.0.0.1:{port}", work, step_sleep=0.5),
+                agent_cmd(rank, f"127.0.0.1:{port}", work, step_sleep=0.5),
                 env=env, cwd=REPO,
                 stdout=open(os.path.join(work, f"agent{rank}.log"), "w"),
                 stderr=subprocess.STDOUT,
                 # own process group so we can kill agent+worker together
                 preexec_fn=os.setsid,
-            ))
+            )
 
         # wait for the 2-proc world to pass KILL_AFTER_STEP
         m0 = os.path.join(work, "metrics.r0")
@@ -190,7 +198,7 @@ def test_kill_one_node_resumes_trajectory(tmp_path):
         rc = agents[0].wait(300)
         assert rc == 0, f"agent0 exited {rc}"
 
-        rows = _read_metrics(m0)
+        rows = read_metrics(m0)
         steps = assert_steps_consistent(rows, max_redos=2)  # 1 kill x at-most-one-behind commit
         assert steps[-1] == TOTAL_STEPS
         worlds = {s: w for s, _, w in rows}
@@ -201,26 +209,14 @@ def test_kill_one_node_resumes_trajectory(tmp_path):
 
         # trajectory continuity: must match an uninterrupted reference
         # run (same fixed global batch and per-step data) step for step
-        ref = _reference_losses()
+        ref = reference_losses()
         for s, loss, _ in rows:
             assert np.isclose(loss, ref[s - 1], rtol=1e-3, atol=1e-3), (
                 s, loss, ref[s - 1]
             )
-    finally:
-        for p in agents:
-            if p.poll() is None:
-                try:
-                    os.killpg(os.getpgid(p.pid), signal.SIGKILL)
-                except (ProcessLookupError, PermissionError):
-                    pass
-        master.terminate()
-        try:
-            master.wait(10)
-        except subprocess.TimeoutExpired:
-            master.kill()
 
 
-def _reference_losses():
+def reference_losses():
     """Uninterrupted in-process run: 4 devices dp2xfsdp2, identical data."""
     import jax
     import jax.numpy as jnp
@@ -247,84 +243,3 @@ def _reference_losses():
         ).astype(np.int32)
         losses.append(float(tr.train_step(batch)["loss"]))
     return losses
-
-
-@one_world_at_a_time
-def test_scale_up_mid_run_grows_world(tmp_path):
-    """Growth half of the elasticity story with REAL processes: node 0
-    trains solo, node 1 joins mid-run, node 0's agent notices the
-    waiting member, restarts into the 2-process jax.distributed world,
-    and the run continues from shm with the same trajectory."""
-    work = str(tmp_path)
-    from dlrover_tpu.common.rpc import find_free_port
-
-    port = find_free_port()
-    master = subprocess.Popen(
-        [sys.executable, "-m", "dlrover_tpu.master.main",
-         "--platform", "local", "--port", str(port), "--node_num", "2"],
-        stdout=open(os.path.join(work, "master.log"), "w"),
-        stderr=subprocess.STDOUT,
-    )
-    agents = {}
-
-    def start_agent(rank):
-        env = dict(os.environ)
-        env.update(
-            DLROVER_FORCE_CPU="1",
-            XLA_FLAGS="--xla_force_host_platform_device_count=2",
-            DLROVER_JAX_HEARTBEAT_TIMEOUT="15",
-            DLROVER_JOB_UID=f"spmdGrow{rank}",
-            DLROVER_MONITOR_INTERVAL="1",
-            JAX_PLATFORMS="cpu",
-        )
-        agents[rank] = subprocess.Popen(
-            # slow steps: the solo phase must outlive the joiner's boot
-            _agent_cmd(rank, f"127.0.0.1:{port}", work, step_sleep=2.0),
-            env=env, cwd=REPO,
-            stdout=open(os.path.join(work, f"agent{rank}.log"), "w"),
-            stderr=subprocess.STDOUT,
-            preexec_fn=os.setsid,
-        )
-
-    try:
-        wait_until_listening(port, master)
-        start_agent(0)
-        # solo world forms after the last-call window; wait for steps
-        m0 = os.path.join(work, "metrics.r0")
-        wait_for_rows(
-            m0, agents[0],
-            lambda rows: any(s >= 2 and w == 1 for s, _, w in rows),
-            300, "the solo world at step 2")
-
-        start_agent(1)  # join mid-run
-
-        rc0 = agents[0].wait(400)
-        assert rc0 == 0, "agent0 failed after scale-up"
-        rc1 = agents[1].wait(60)
-        assert rc1 == 0, "agent1 failed"
-
-        rows = _read_metrics(m0)
-        worlds = {s: w for s, _, w in rows}
-        assert worlds[TOTAL_STEPS] == 2, (
-            f"final steps did not run on the grown world: {rows}"
-        )
-        grow_step = min(s for s, w in worlds.items() if w == 2)
-        assert grow_step > 1
-        assert_steps_consistent(rows, max_redos=2)  # 1 growth restart x async commit
-        ref = _reference_losses()
-        for s, loss, _ in rows:
-            assert np.isclose(loss, ref[s - 1], rtol=1e-3, atol=1e-3), (
-                s, loss, ref[s - 1]
-            )
-    finally:
-        for p in agents.values():
-            if p.poll() is None:
-                try:
-                    os.killpg(os.getpgid(p.pid), signal.SIGKILL)
-                except (ProcessLookupError, PermissionError):
-                    pass
-        master.terminate()
-        try:
-            master.wait(10)
-        except subprocess.TimeoutExpired:
-            master.kill()

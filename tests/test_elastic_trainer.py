@@ -162,3 +162,30 @@ def test_persistent_compile_cache_dir(tmp_path, monkeypatch):
     finally:
         # restore global jax config for the rest of the suite
         jax.config.update("jax_compilation_cache_dir", prev_dir)
+
+
+def test_restore_consensus_asks_for_the_saver_before_it_reads_shm(
+        tmp_path, monkeypatch):
+    """In a world of several processes the restore-step consensus reads
+    this host's shm meta, which the agent's saver serves: it must ask for
+    the saver first, as ``CheckpointEngine.load`` does.  On a host with
+    no saver yet (a first start, a replacement) the read otherwise dials
+    a socket nobody has bound until its 60 s are over, and every peer
+    waits for this host (ROADMAP D22)."""
+    trainer = ElasticTrainer(
+        LlamaModel(LlamaConfig.tiny(max_seq_len=32)),
+        global_batch_size=8, micro_batch_per_shard=1, seq_len=32,
+        checkpoint_dir=str(tmp_path), saver_mode=SaverMode.LOCAL,
+    )
+    eng = trainer.checkpoint_engine
+    calls = []
+    monkeypatch.setattr(jax, "process_count", lambda: 2)
+    monkeypatch.setattr(eng, "_ensure_saver",
+                        lambda: calls.append("saver"))
+    monkeypatch.setattr(eng._shm_handler, "get_meta",
+                        lambda: calls.append("meta"))
+    monkeypatch.setattr(trainer, "_gather_restore_steps",
+                        lambda shm, storage: None)
+    assert trainer._consensus_restore_decision() is None
+    assert calls == ["saver", "meta"]
+    trainer.close()

@@ -21,9 +21,7 @@ import os
 import subprocess
 import sys
 
-import pytest
-
-from test_elastic_spmd_e2e import wait_until_listening
+from test_elastic_spmd_e2e import local_master, running_agents
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -44,15 +42,7 @@ SEQ, GB = 32, 8
 def test_goodput_artifact_survives_injected_kill(tmp_path):
     work = str(tmp_path)
     from dlrover_tpu.agent.master_client import MasterClient
-    from dlrover_tpu.common.rpc import find_free_port
 
-    port = find_free_port()
-    master = subprocess.Popen(
-        [sys.executable, "-m", "dlrover_tpu.master.main",
-         "--platform", "local", "--port", str(port), "--node_num", "1"],
-        stdout=open(os.path.join(work, "master.log"), "w"),
-        stderr=subprocess.STDOUT,
-    )
     env = dict(os.environ)
     env.update(
         DLROVER_FORCE_CPU="1",
@@ -64,14 +54,10 @@ def test_goodput_artifact_survives_injected_kill(tmp_path):
         DLROVER_MONITOR_INTERVAL="0.5",
         # warm-compile scale model: the restarted worker hits the
         # persistent cache the way a production job hits a warm cache
-        JAX_COMPILATION_CACHE_DIR=os.path.join(work, "jaxcache"),
-        JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
-        JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0",
+        # (the test session's, inherited: every program kept)
     )
-    agent = None
-    try:
-        wait_until_listening(port, master)
-        agent = subprocess.Popen(
+    with local_master(work, 1) as port, running_agents() as agents:
+        agent = agents[0] = subprocess.Popen(
             [
                 sys.executable, "-m", "dlrover_tpu.agent.launcher",
                 "--nnodes=1", "--node_rank=0",
@@ -91,6 +77,7 @@ def test_goodput_artifact_survives_injected_kill(tmp_path):
             env=env, cwd=REPO,
             stdout=open(os.path.join(work, "agent.log"), "w"),
             stderr=subprocess.STDOUT,
+            preexec_fn=os.setsid,
         )
         rc = agent.wait(800)
         assert rc == 0, f"agent exited {rc}"
@@ -130,11 +117,3 @@ def test_goodput_artifact_survives_injected_kill(tmp_path):
         # ...and recovery fast enough that steady goodput clears the
         # reference's bar on a run that includes a kill + full recovery
         assert g["steady_goodput"] >= 0.90, g
-    finally:
-        if agent is not None and agent.poll() is None:
-            agent.kill()
-        master.terminate()
-        try:
-            master.wait(10)
-        except subprocess.TimeoutExpired:
-            master.kill()

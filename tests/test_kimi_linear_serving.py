@@ -7,9 +7,20 @@ reference (``perfbench/reference_kimi_linear.py``) against the engine,
 LOGITS compared; a slot's state when the slot is reused, idle or
 prefilling; the shares of a sparse layer against the uncut layer; every
 refusal by its message; what the engine books; and sarvam-105b's served
-programs, which the layer loop's dispatch must leave to the letter."""
+programs, which the layer loop's dispatch must leave to the letter.
+
+The rule of the serving test files (``tests/test_sparse_serving.py`` has
+it whole): the config and the seeded params are module-scoped fixtures
+(``cfg``, ``params_of(seed)``), what several cases compute alike is
+computed once (``unplanted``), and an engine is built once where a test
+asks the same of it again (the solo runs of
+``test_requests_admitted_at_different_steps_equal_their_solo_runs``).
+The other engines differ in their seed, their slots or their attention
+path, or are compared FRESH against a used one, so each test builds its
+own."""
 
 import dataclasses
+import functools
 import hashlib
 import json
 import os
@@ -79,6 +90,17 @@ def reference_logits(cfg, params, seq, keep=None):
     x = ref.hidden_states(seq, params.layer, params.top(), cfg.num_layers,
                           dims(cfg), keep)
     return np.asarray(ref.head_logits(x, params.top(), cfg.rms_norm_eps))
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    return tiny()
+
+
+@pytest.fixture(scope="module")
+def params_of(cfg):
+    """``params_of(seed)``: ``cfg``'s seeded params, made once a seed."""
+    return functools.cache(lambda seed: SeededKimiLinearParams(cfg, seed))
 
 
 def _engine(cfg, params, impl="xla", **kw):
@@ -177,8 +199,7 @@ def test_no_rotation_passes_the_row_whole():
     assert not jnp.allclose(turned[:, 1:], x[:, 1:])
 
 
-def test_training_refuses_the_model_by_what_it_lacks():
-    cfg = tiny()
+def test_training_refuses_the_model_by_what_it_lacks(cfg):
     with pytest.raises(NotImplementedError, match="chunk kernel's backward"):
         LlamaModel(cfg).init(jax.random.PRNGKey(0),
                              jnp.zeros((1, 8), jnp.int32))
@@ -190,14 +211,13 @@ def test_training_refuses_the_model_by_what_it_lacks():
     ("pallas", (1, 63, 64, 65, 131)),
 ])
 def test_prefill_then_decode_through_the_engine_is_the_reference(
-        impl, lengths):
+        impl, lengths, cfg, params_of):
     """Prompts of 1, chunk - 1, chunk, chunk + 1 and several chunks, each
     decoded for two chunks and a bit: the logits of the prompt's last
     chunk and of every decode forward are the reference's full forward
     (the ``jnp`` recurrence at a chunk of 8; both kernels, interpreted, at
     a chunk of 64)."""
-    cfg = tiny()
-    params = SeededKimiLinearParams(cfg, 3)
+    params = params_of(3)
     engine = _engine(cfg, params, impl)
     rng = np.random.RandomState(0)
     for n in lengths:
@@ -211,12 +231,11 @@ def test_prefill_then_decode_through_the_engine_is_the_reference(
     assert s.state_stream_ratio == (1.0 if impl == "pallas" else 3.0)
 
 
-def test_a_reused_slot_gives_what_a_fresh_engine_gives():
+def test_a_reused_slot_gives_what_a_fresh_engine_gives(cfg, params_of):
     """The second request lands in the slot the first one left (one slot),
     whose state and convolution rows are the first one's last: the
     prompt's first chunk starts from zeros inside its own program."""
-    cfg = tiny()
-    params = SeededKimiLinearParams(cfg, 5)
+    params = params_of(5)
     rng = np.random.RandomState(1)
     first = rng.randint(0, VOCAB, 21).astype(np.int32)
     second = rng.randint(0, VOCAB, 13).astype(np.int32)
@@ -231,12 +250,11 @@ def test_a_reused_slot_gives_what_a_fresh_engine_gives():
         np.testing.assert_array_equal(got[pos], want[pos])
 
 
-def test_a_poisoned_state_is_zeroed_by_the_first_chunk():
+def test_a_poisoned_state_is_zeroed_by_the_first_chunk(cfg, params_of):
     """What the benchmark does in set-up: every slot's state and
     convolution rows LOUD before any request; the answers are the
     reference's."""
-    cfg = tiny()
-    params = SeededKimiLinearParams(cfg, 5)
+    params = params_of(5)
     engine = _engine(cfg, params)
     engine.warmup()
     serve_linear._poison(engine)
@@ -245,19 +263,20 @@ def test_a_poisoned_state_is_zeroed_by_the_first_chunk():
     _against_reference(cfg, params, req, logits)
 
 
-def test_requests_admitted_at_different_steps_equal_their_solo_runs():
+def test_requests_admitted_at_different_steps_equal_their_solo_runs(
+        cfg, params_of):
     """Three requests admitted at different engine steps, so that each
     slot sits idle, prefills and decodes while the others do something
     else: every request's tokens are its solo run's.  An idle slot and a
     slot mid-prefill hold their state still through the others' decode
-    forwards."""
-    cfg = tiny()
-    params = SeededKimiLinearParams(cfg, 6)
+    forwards.  The solo runs go one behind the other through ONE other
+    engine (a reused slot starts from zeros: the test above)."""
+    params = params_of(6)
     rng = np.random.RandomState(3)
     prompts = [rng.randint(0, VOCAB, n).astype(np.int32)
                for n in (27, 9, 18)]
-    solo = [_serve_one(_engine(cfg, params), p, 11)[0].output
-            for p in prompts]
+    alone = _engine(cfg, params)
+    solo = [_serve_one(alone, p, 11)[0].output for p in prompts]
     engine = _engine(cfg, params)
     rids, done, step = [], {}, 0
     while len(done) < 3:
@@ -269,9 +288,8 @@ def test_requests_admitted_at_different_steps_equal_their_solo_runs():
     assert [done[r].output for r in rids] == solo
 
 
-def test_the_engine_counts_its_state_among_its_cache_bytes():
-    cfg = tiny()
-    engine = _engine(cfg, SeededKimiLinearParams(cfg, 1))
+def test_the_engine_counts_its_state_among_its_cache_bytes(cfg, params_of):
+    engine = _engine(cfg, params_of(1))
     state = 3 * 2 * 16 * 16 * 4 * 3          # slots x heads x d x d, 3 layers
     conv = 3 * 3 * (3 * 2 * 16) * 4 * 3
     pool = 120 * 8 * latent.latent_row_width(cfg) * 4 * 1   # ONE MLA layer
@@ -286,15 +304,14 @@ def test_the_engine_counts_its_state_among_its_cache_bytes():
     (dict(mesh=object()), "a mesh with linear-attention layers"),
     (dict(prefill_chunk=0), "prompts in chunks"),
 ])
-def test_the_engine_refuses_what_cannot_be_right_yet(kw, match):
-    cfg = tiny()
+def test_the_engine_refuses_what_cannot_be_right_yet(kw, match, cfg,
+                                                     params_of):
     with pytest.raises(ValueError, match=match):
-        _engine(cfg, SeededKimiLinearParams(cfg, 1), **kw)
+        _engine(cfg, params_of(1), **kw)
 
 
-def test_the_blocks_refuse_a_bucketed_prefill_and_a_verify():
-    cfg = tiny()
-    params = SeededKimiLinearParams(cfg, 1)
+def test_the_blocks_refuse_a_bucketed_prefill_and_a_verify(cfg, params_of):
+    params = params_of(1)
     sp = serving_params_from_llama({"params": params}, cfg)
     toks = jnp.zeros((2, 4), jnp.int32)
     with pytest.raises(ValueError, match="chunked path"):
@@ -346,9 +363,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
 
 # ------------------------------------------------- the driver's own check
-def _watched():
-    cfg = tiny()
-    params = SeededKimiLinearParams(cfg, 7)
+def _watched(cfg, params):
     # one slot: the two requests run one behind the other, both watched
     engine = _engine(cfg, params, max_slots=1)
     rng = np.random.RandomState(8)
@@ -358,14 +373,15 @@ def _watched():
         while engine.has_work:
             engine.step()
             serve_linear._to_host(engine.witness_log, 8)
-    return cfg, params, serve_linear.Witnessed(engine.witness_log, 8)
+    return serve_linear.Witnessed(engine.witness_log, 8)
 
 
-def test_the_drivers_check_passes_on_the_engine():
+def test_the_drivers_check_passes_on_the_engine(cfg, params_of):
     """``drivers/serve_linear.py``'s comparison, on the CPU: the watched
     requests' logits AND the watched slot's recurrent state of the first
     and last KDA layer behind its last forward, against the reference."""
-    cfg, params, seen = _watched()
+    params = params_of(7)
+    seen = _watched(cfg, params)
     with open(os.path.join(
             ROOT, "perfbench/traffic/reason-closed-192.json")) as f:
         limits = serve_linear.limits_of(json.load(f))
@@ -386,16 +402,24 @@ def test_the_drivers_check_passes_on_the_engine():
     assert not bad["decay_matches_reference"]
 
 
+@pytest.fixture(scope="module")
+def unplanted(cfg, params_of):
+    """The reference with no fault planted, once for all the faults:
+    (sequence, its logits, what it kept)."""
+    seq = np.random.RandomState(0).randint(0, VOCAB, 45).astype(np.int32)
+    base = {}
+    return seq, reference_logits(cfg, params_of(7), seq, base), base
+
+
 @pytest.mark.parametrize("fault", sorted(controls_kimi_linear.FAULTS))
-def test_every_planted_fault_moves_the_reference(fault):
+def test_every_planted_fault_moves_the_reference(fault, cfg, params_of,
+                                                 unplanted):
     """The controls' faults change what the reference computes, and the
     reference is itself again behind them (on the chip each has to read
     as not correct by the driver's limits: PERF.md section 6)."""
-    cfg = tiny()
-    params = SeededKimiLinearParams(cfg, 7)
-    seq = np.random.RandomState(0).randint(0, VOCAB, 45).astype(np.int32)
-    base, keep = {}, {}
-    want = reference_logits(cfg, params, seq, base)
+    params = params_of(7)
+    seq, want, base = unplanted
+    keep = {}
     with controls_kimi_linear.FAULTS[fault]():
         got = reference_logits(cfg, params, seq, keep)
     again = reference_logits(cfg, params, seq)

@@ -304,10 +304,12 @@ class TestServicerEndToEnd:
     def test_rendezvous_via_rpc(self, master_client):
         rdzv_round = master_client.join_rendezvous(0, 8)
         assert rdzv_round == 0
-        r, g, world, ips = master_client.get_comm_world(
+        r, g, world, ips, ports = master_client.get_comm_world(
             RendezvousName.ELASTIC_TRAINING, 0
         )
         assert world == {0: 8}
+        # the port this join offered comes back with the node's address
+        assert set(ports) == set(ips) == {0} and ports[0] > 0
 
     def test_kv_via_rpc(self, master_client):
         master_client.kv_store_set("key1", b"hello")
@@ -372,9 +374,10 @@ class TestServicerEndToEnd:
         master_client.join_rendezvous(
             0, 8, rdzv_name=RendezvousName.NETWORK_CHECK
         )
-        r, g, world, _ = master_client.get_comm_world(
+        r, g, world, _, ports = master_client.get_comm_world(
             RendezvousName.NETWORK_CHECK, 0
         )
+        assert ports[0] > 0  # the check's world gets its port the same way
         assert world == {0: 8}
         master_client.report_network_check_result(0, True, 0.5)
         ok, reason = master_client.network_check_success()

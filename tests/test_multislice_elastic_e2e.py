@@ -27,11 +27,11 @@ import sys
 import numpy as np
 
 from test_elastic_spmd_e2e import (
-    _read_metrics,
     assert_steps_consistent,
-    one_world_at_a_time,
+    local_master,
+    read_metrics,
+    running_agents,
     wait_for_rows,
-    wait_until_listening,
 )
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -67,10 +67,10 @@ def _start_agent(rank, port, work, agents, tag=""):
         DLROVER_MONITOR_INTERVAL="1",
         DLROVER_SLICE_ID=str(rank // SLICE_UNIT),
         JAX_PLATFORMS="cpu",
-        # shared persistent compile cache: the regrown world re-enters
-        # programs the first world already compiled — without it the
-        # replacement's cold compile outlives the remaining steps
-        JAX_COMPILATION_CACHE_DIR=os.path.join(work, "jaxcache"),
+        # (the test session's compile cache is inherited: the regrown
+        # world re-enters programs the first world already compiled;
+        # without it the replacement's cold compile outlives the
+        # remaining steps)
     )
     agents[rank] = subprocess.Popen(
         _agent_cmd(rank, f"127.0.0.1:{port}", work),
@@ -111,21 +111,9 @@ def _reference_losses():
     return losses
 
 
-@one_world_at_a_time
 def test_slice_loss_shrinks_then_regrows(tmp_path):
     work = str(tmp_path)
-    from dlrover_tpu.common.rpc import find_free_port
-
-    port = find_free_port()
-    master = subprocess.Popen(
-        [sys.executable, "-m", "dlrover_tpu.master.main",
-         "--platform", "local", "--port", str(port), "--node_num", "4"],
-        stdout=open(os.path.join(work, "master.log"), "w"),
-        stderr=subprocess.STDOUT,
-    )
-    agents = {}
-    try:
-        wait_until_listening(port, master)
+    with local_master(work, 4) as port, running_agents() as agents:
         for rank in range(4):
             _start_agent(rank, port, work, agents)
 
@@ -153,7 +141,7 @@ def test_slice_loss_shrinks_then_regrows(tmp_path):
         rc0 = agents[0].wait(900)
         assert rc0 == 0, f"agent0 exited {rc0}"
 
-        rows = _read_metrics(m0)
+        rows = read_metrics(m0)
         worlds = {s: w for s, _, w in rows}
         steps = assert_steps_consistent(rows, max_redos=4)  # kill+regrow x async commit
         assert steps[-1] == TOTAL_STEPS
@@ -168,15 +156,3 @@ def test_slice_loss_shrinks_then_regrows(tmp_path):
         for s, loss, _ in rows:
             assert np.isclose(loss, ref[s - 1], rtol=1e-3, atol=1e-3), (
                 s, loss, ref[s - 1])
-    finally:
-        for p in agents.values():
-            if p.poll() is None:
-                try:
-                    os.killpg(os.getpgid(p.pid), signal.SIGKILL)
-                except (ProcessLookupError, PermissionError):
-                    pass
-        master.terminate()
-        try:
-            master.wait(10)
-        except subprocess.TimeoutExpired:
-            master.kill()

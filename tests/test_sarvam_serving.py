@@ -4,7 +4,15 @@ bottleneck, the query-head norm, YaRN at positions past the original
 length, a leading dense layer, a shared expert, the selection bias, held
 experts.  The plain reference (``perfbench/reference_sarvam.py``) against
 the served blocks (``serving/latent.py``) and the engine; the shares of a
-sparse layer against the uncut layer; what the engine books."""
+sparse layer against the uncut layer; what the engine books.
+
+The rule of the serving test files (``tests/test_sparse_serving.py`` has
+it whole): the config and the seeded params are module-scoped fixtures
+(``cfg``, ``params``, ``sp``), and an engine is built once where two
+tests ask the same of it.  Here no two do: every engine below differs
+from the others in its slots, its attention path or its context, and
+each test reads its engine's books from their start, so each builds its
+own."""
 
 import dataclasses
 
@@ -87,9 +95,24 @@ def reference_logits(cfg, params, seq):
     return ref.head_logits(x, params.top(), cfg.rms_norm_eps)
 
 
-def _served(cfg, seed=7):
+def _served(cfg, seed):
     params = SeededSarvamParams(cfg, seed)
     return params, serving_params_from_llama({"params": params}, cfg)
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    return tiny()
+
+
+@pytest.fixture(scope="module")
+def params(cfg):
+    return SeededSarvamParams(cfg, 7)
+
+
+@pytest.fixture(scope="module")
+def sp(cfg, params):
+    return serving_params_from_llama({"params": params}, cfg)
 
 
 _PROGRAMS = {}
@@ -143,12 +166,11 @@ def test_yarn_frequencies_are_trainings_and_the_references():
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_prefill_and_decode_through_the_cache_are_the_reference(impl):
+def test_prefill_and_decode_through_the_cache_are_the_reference(
+        impl, cfg, params, sp):
     """Chunks of 16, then token by token (the decode path: the oracle,
     and the kernel interpreted): every position's logits are the
     reference's full forward, at positions past YaRN's original length."""
-    cfg = tiny()
-    params, sp = _served(cfg)
     seq = np.random.RandomState(0).randint(0, 128, 45).astype(np.int32)
     want = reference_logits(cfg, params, seq)
     cache = dict(fresh_cache(cfg), watch_slot=jnp.asarray(0, jnp.int32))
@@ -173,8 +195,7 @@ def test_prefill_and_decode_through_the_cache_are_the_reference(impl):
     assert int(cache["moe_picks"][0]) == 45 * 2 * 2
 
 
-def test_bucketed_prefill_is_the_reference():
-    cfg = tiny()
+def test_bucketed_prefill_is_the_reference(cfg):
     params, sp = _served(cfg, 3)
     seq = np.random.RandomState(1).randint(0, 128, 40).astype(np.int32)
     want = reference_logits(cfg, params, seq)
@@ -187,15 +208,21 @@ def test_bucketed_prefill_is_the_reference():
     assert len(rows) == cfg.num_layers and keys == []
 
 
+@pytest.fixture(scope="module")
+def unplanted(cfg, params):
+    """The reference with no fault planted, once for all the faults:
+    (sequence, its logits)."""
+    seq = np.random.RandomState(0).randint(0, 128, 45).astype(np.int32)
+    return seq, np.asarray(reference_logits(cfg, params, seq))
+
+
 @pytest.mark.parametrize("fault", sorted(controls_sarvam.FAULTS))
-def test_every_planted_fault_moves_the_reference(fault):
+def test_every_planted_fault_moves_the_reference(fault, cfg, params,
+                                                 unplanted):
     """The controls' faults change what the reference computes (on the
     chip each has to read as not correct by the driver's limits:
     PERF.md section 6)."""
-    cfg = tiny()
-    params = SeededSarvamParams(cfg, 7)
-    seq = np.random.RandomState(0).randint(0, 128, 45).astype(np.int32)
-    want = np.asarray(reference_logits(cfg, params, seq))
+    seq, want = unplanted
     with controls_sarvam.FAULTS[fault]():
         got = np.asarray(reference_logits(cfg, params, seq))
     again = np.asarray(reference_logits(cfg, params, seq))
@@ -258,13 +285,11 @@ def _drain(engine, reqs):
     return sorted(done, key=lambda r: r.rid)
 
 
-def test_engine_serves_it_behind_a_shared_prefix():
+def test_engine_serves_it_behind_a_shared_prefix(cfg, params):
     """Through ``InferenceEngine``: a document prefilled once, then two
     questions behind it (the chunked warm start), greedy: every emitted
     token is the reference's argmax, teacher-forced, and the cache holds
     the document once."""
-    cfg = tiny()
-    params = SeededSarvamParams(cfg, 7)
     engine = _engine(cfg, params)
     assert "index_pool" not in engine._cache
     rng = np.random.RandomState(5)
@@ -336,7 +361,7 @@ def test_a_padded_last_chunk_attends_nothing_and_answers_the_same(impl):
         2 + 3 + 4 if impl == "pallas" else 1 + 2 + 2)
 
 
-def test_engine_books_the_rows_the_kernel_streams(monkeypatch):
+def test_engine_books_the_rows_the_kernel_streams(cfg, params, monkeypatch):
     """``kv_rows_streamed`` is the kernel's live page groups x their rows,
     and the decode chunk's span carries both counters."""
     from dlrover_tpu.utils import profiler
@@ -350,8 +375,6 @@ def test_engine_books_the_rows_the_kernel_streams(monkeypatch):
         return inner(name, **attrs)
 
     monkeypatch.setattr("dlrover_tpu.serving.engine.span", span)
-    cfg = tiny()
-    params = SeededSarvamParams(cfg, 7)
     engine = _engine(cfg, params, attention_impl="pallas", max_slots=2)
     rng = np.random.RandomState(6)
     for n in (19, 37):
@@ -373,11 +396,12 @@ def test_engine_books_the_rows_the_kernel_streams(monkeypatch):
         st.kv_rows_streamed / st.kv_rows_live)
 
 
-def test_the_drivers_check_passes_on_the_engine_and_fails_on_a_fault():
+def test_the_drivers_check_passes_on_the_engine_and_fails_on_a_fault(
+        cfg, params):
     """``drivers/serve_latent.py``'s comparison, on the CPU: the engine's
     watched requests against the reference, and against the reference
     with each fault planted."""
-    cfg, params, seen = serve_latent_watched()
+    seen = serve_latent_watched(cfg, params)
     got = serve_latent.reference_check(cfg, params, config_of(cfg), seen)
     assert got["watched_requests"] == 2
     assert got["logit_rms_p90"] < 1e-4 and got["sparse_decode"][
@@ -389,9 +413,7 @@ def test_the_drivers_check_passes_on_the_engine_and_fails_on_a_fault():
     assert bad["sparse_decode"]["mlp_rel"] > 0.05
 
 
-def serve_latent_watched():
-    cfg = tiny()
-    params = SeededSarvamParams(cfg, 7)
+def serve_latent_watched(cfg, params):
     # one slot: the two requests run one behind the other, both watched
     engine = _engine(cfg, params, max_slots=1)
     rng = np.random.RandomState(8)
@@ -405,8 +427,7 @@ def serve_latent_watched():
             7)
     _drain(engine, 2)
     serve_latent._to_host(engine.witness_log, 16)
-    seen = serve_latent.Witnessed(doc, engine.witness_log, 1, 16)
-    return cfg, params, seen
+    return serve_latent.Witnessed(doc, engine.witness_log, 1, 16)
 
 
 # GLM-5's served programs, as the parent of PR 41 traced them (the tiny

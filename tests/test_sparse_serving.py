@@ -2,8 +2,18 @@
 experts through the paged latent cache and the engine, tiny, float32, on
 the CPU: against the plain reference, against itself in other chunkings,
 with a shared prefix; the index-score kernel in interpret mode; what the
-engine books and exports; and that the dense model traces what it did."""
+engine books and exports; and that the dense model traces what it did.
 
+The rule of this file and of every serving test file behind it: the
+config, the seeded params and the engines come from module-scoped
+fixtures (``cfg``, ``params``, ``engines``).  An ``InferenceEngine`` jits
+its programs as closures of the instance, so every new engine traces,
+lowers and compiles them again; a test that reads OUTPUTS or DIFFERENCES
+of counters takes the module's one engine of its keyword arguments from
+``engines``, and builds its own only where it reads the books from their
+start (or patches a function the programs trace through) and says so."""
+
+import dataclasses
 import hashlib
 import re
 
@@ -32,6 +42,22 @@ SLOT = jnp.zeros(1, jnp.int32)
 def _served(cfg, seed=7):
     params = SeededGlm5Params(cfg, seed)
     return params, serving_params_from_llama({"params": params}, cfg)
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    return tiny()
+
+
+@pytest.fixture(scope="module")
+def params(cfg):
+    return SeededGlm5Params(cfg, 9)
+
+
+@pytest.fixture(scope="module")
+def sp(cfg):
+    """``cfg``'s served parameters (seed 7), for the tests of the blocks."""
+    return _served(cfg)[1]
 
 
 def _run(sp, cfg, cache, seq, start, **kw):
@@ -82,9 +108,7 @@ def test_prefill_and_decode_through_the_cache_are_the_reference(topk):
     np.testing.assert_allclose(jnp.concatenate(got), want, atol=1e-4)
 
 
-def test_a_chunk_at_an_offset_is_one_prefill():
-    cfg = tiny()
-    _, sp = _served(cfg)
+def test_a_chunk_at_an_offset_is_one_prefill(cfg, sp):
     seq = np.random.RandomState(3).randint(0, 128, 48).astype(np.int32)
     whole, _ = _run(sp, cfg, fresh_cache(cfg), seq, 0, slots=SLOT)
     cache, parts = fresh_cache(cfg), []
@@ -108,28 +132,58 @@ def _engine(cfg, params, **kw):
     return InferenceEngine(cfg, {"params": params}, **args)
 
 
-def test_a_request_on_a_cached_document_answers_as_a_cold_one():
+@pytest.fixture(scope="module")
+def engines(cfg, params):
+    """``engines(**kw)``: the module's ONE engine of these keyword
+    arguments over ``cfg`` and ``params``, with no work left.  Other
+    tests have used it: read its outputs, and its counters as
+    differences (``_since``)."""
+    built = {}
+
+    def engine(**kw):
+        key = tuple(sorted(kw.items()))
+        if key not in built:
+            built[key] = _engine(cfg, params, **kw)
+        assert not built[key].has_work
+        return built[key]
+
+    return engine
+
+
+def _since(eng):
+    """``done()``: the ``EngineStats`` of what ``eng`` has done since this
+    call, every counter a difference, so that its ratios are those of
+    the caller's work alone."""
+    before = dataclasses.asdict(eng.stats)
+    return lambda: type(eng.stats)(**{
+        name: getattr(eng.stats, name) - was
+        for name, was in before.items()})
+
+
+def test_a_request_on_a_cached_document_answers_as_a_cold_one(cfg, engines):
     """The document's blocks are mapped, the tail's chunks start behind
     them, and tokens and books are those of an engine that never saw it."""
-    cfg = tiny()
-    params = SeededGlm5Params(cfg, 9)
     rng = np.random.RandomState(5)
     doc = rng.randint(0, 128, 48).astype(np.int32)
     tails = [rng.randint(0, 128, n).astype(np.int32) for n in (9, 21)]
-    warm = _engine(cfg, params)
+    warm = engines()
+    shared_before = warm.prefix_stats()["prefix_shared_tokens"]
+    done = _since(warm)
     warm.add_request(doc, 1)
     warm.run()
-    chunks_before = warm.stats.prefill_chunks
+    chunks_before = done().prefill_chunks
     rids = [warm.add_request(np.concatenate([doc, t]), 6) for t in tails]
     hot = warm.run()
     # 48 tokens shared: one chunk for the 9-token tail, two for the 21
-    assert warm.stats.prefill_chunks - chunks_before <= 3
-    assert warm.prefix_stats()["prefix_shared_tokens"] == 2 * 48
+    assert done().prefill_chunks - chunks_before <= 3
+    assert warm.prefix_stats()["prefix_shared_tokens"] - shared_before \
+        == 2 * 48
+    # one engine that shares nothing answers both, one behind the other
+    cold = engines(prefix_sharing=False)
     for tail, rid in zip(tails, rids):
-        cold = _engine(cfg, params, prefix_sharing=False)
         crid = cold.add_request(np.concatenate([doc, tail]), 6)
         assert cold.run()[crid].tolist() == hot[rid].tolist()
-    st = warm.stats
+    st = done()
     assert 0 < st.dsa_selected_ratio < 1 and st.attn_rows_selected > 0
     assert st.index_rows_scanned >= st.dsa_rows_live > st.attn_rows_selected
     assert 0 < st.moe_picks_held < st.moe_picks
@@ -137,7 +191,8 @@ def test_a_request_on_a_cached_document_answers_as_a_cold_one():
     assert warm._blockmgr.check_books()
 
 
-def test_the_decode_span_says_what_is_read_and_what_is_attended(monkeypatch):
+def test_the_decode_span_says_what_is_read_and_what_is_attended(
+        cfg, engines, monkeypatch):
     """A selection model's decode chunk streams every live row under the
     mask: its span carries ``kv_rows_streamed`` (the kernel's whole groups
     of pages up to each length, as a model without a selection books
@@ -154,16 +209,16 @@ def test_the_decode_span_says_what_is_read_and_what_is_attended(monkeypatch):
             spans.append(attrs)
         return inner(name, **attrs)
 
+    # (the host's side of the engine only: the programs trace no span)
     monkeypatch.setattr("dlrover_tpu.serving.engine.span", span)
-    cfg = tiny()
-    params = SeededGlm5Params(cfg, 9)
     rng = np.random.RandomState(6)
     prompts = [rng.randint(0, 128, n).astype(np.int32) for n in (19, 37)]
-    eng = _engine(cfg, params)
+    eng = engines()
+    done = _since(eng)
     for prompt in prompts:
         eng.add_request(prompt, 5)
     eng.run()
-    st = eng.stats
+    st = done()
     # one token from the prefill, then 4 forwards at lengths n + 1 .. n + 4
     lengths = np.array([[n + j for j in range(1, 5)] for n in (19, 37)])
     rows = mla_decode.PAGES_PER_BLOCK * 8
@@ -174,17 +229,17 @@ def test_the_decode_span_says_what_is_read_and_what_is_attended(monkeypatch):
     assert sum(a["attn_rows_selected"] for a in spans) \
         == cfg.index_topk * lengths.size < st.kv_rows_live
     assert all(a["index_rows_scanned"] >= a["kv_rows_live"] for a in spans)
-    oracle = _engine(cfg, params, attention_impl="xla")
+    oracle = engines(attention_impl="xla")
+    done = _since(oracle)
     for prompt in prompts:
         oracle.add_request(prompt, 5)
     oracle.run()
     assert (oracle.stats.kv_rows_live, oracle.stats.kv_rows_streamed) \
         == (0, 0)
-    assert oracle.stats.attn_rows_selected == st.attn_rows_selected
+    assert done().attn_rows_selected == st.attn_rows_selected
 
 
-def test_the_engine_refuses_a_latent_model_without_pools():
-    cfg = tiny()
+def test_the_engine_refuses_a_latent_model_without_pools(cfg):
     with pytest.raises(ValueError, match="paged=True"):
         InferenceEngine(cfg, {"params": SeededGlm5Params(cfg, 1)},
                         max_len=96)
@@ -362,12 +417,12 @@ def _as_mask(pos, width):
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_decode_under_the_threshold_is_the_parents_top_k_and_gather(impl):
+def test_decode_under_the_threshold_is_the_parents_top_k_and_gather(
+        impl, cfg):
     """No score ties the ``index_topk``-th: the threshold chooses the rows
     ``top_k`` chose, and streaming every live row under their mask
     attends them as the gathered copy was attended.  A slot with fewer
     rows than it may choose attends them all, a slot of length 0 none."""
-    cfg = tiny()
     args = _decode_operands(cfg, [45, 0, 97, 5, 120])
     got, chosen = latent._attend_decode(*args, cfg, impl, True)
     want, pos = _parents_decode(*args, cfg, impl, True)
@@ -379,12 +434,11 @@ def test_decode_under_the_threshold_is_the_parents_top_k_and_gather(impl):
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_a_tie_at_the_threshold_is_attended_whole_as_a_run_does(impl):
+def test_a_tie_at_the_threshold_is_attended_whole_as_a_run_does(impl, cfg):
     """Scores that tie the ``index_topk``-th are ALL chosen, as the
     reference says (``scores >= kth``) and as the same query chooses
     when it comes as a run of one (``_attend_run``, the one helper);
     ``top_k`` kept the lowest positions among them."""
-    cfg = tiny()
     lengths = [61, 110, 87]
     args = _decode_operands(cfg, lengths, whole_numbers=True)
     qq, q_i, w, lat, idx, table, _ = args
@@ -452,12 +506,11 @@ def test_a_mask_rows_positions_are_numpys(width, chosen, size):
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_the_witness_rows_are_the_watched_slots_mask(impl, monkeypatch):
+def test_the_witness_rows_are_the_watched_slots_mask(impl, cfg, sp,
+                                                     monkeypatch):
     """A decode forward over two slots hands back, a layer, the WATCHED
     slot's chosen positions and nothing of the other's: exactly its row
     of that layer's mask, ascending, -1 behind the last."""
-    cfg = tiny()
-    _, sp = _served(cfg)
     rng = np.random.RandomState(6)
     width = latent.latent_row_width(cfg)
     cache = {
@@ -519,13 +572,11 @@ def test_the_witness_rows_are_the_watched_slots_mask(impl, monkeypatch):
     assert np.asarray(rows)[0, :at.size].tolist() == at.tolist()
 
 
-def _watched(**engine):
-    """Questions on one cached document through an engine that is
-    watched, as ``perfbench/drivers/serve_sparse.py`` watches its window:
-    what the engine's own programs handed back, packed for the
-    reference."""
-    cfg = tiny()
-    params = SeededGlm5Params(cfg, 9)
+def _watched(cfg, params, **engine):
+    """Questions on one cached document through an engine of its own
+    that is watched, as ``perfbench/drivers/serve_sparse.py`` watches its
+    window: what the engine's own programs handed back, from its first,
+    packed for the reference."""
     rng = np.random.RandomState(11)
     doc = rng.randint(0, 128, 48).astype(np.int32)
     eng = _engine(cfg, params, **engine)
@@ -536,19 +587,24 @@ def _watched(**engine):
         eng.add_request(np.concatenate(
             [doc, rng.randint(0, 128, n).astype(np.int32)]), 14)
     eng.run()
-    seen = serve_sparse.Witnessed(doc, eng.witness_log, cfg.num_layers, 1)
-    return cfg, params, seen
+    return serve_sparse.Witnessed(doc, eng.witness_log, cfg.num_layers, 1)
 
 
-def test_a_watched_request_is_witnessed_by_a_step_that_waits_once():
+@pytest.fixture(scope="module")
+def watched(cfg, params):
+    return _watched(cfg, params)
+
+
+def test_a_watched_request_is_witnessed_by_a_step_that_waits_once(
+        cfg, params):
     """``step`` dispatches a latent model's prompt chunks (one a slot)
     and its decode chunk before it reads any of them: the watched
     request's witness is still appended with every dispatch that
     advances it, as device arrays the step never reads, and the experts'
-    picks are booked behind the step's reads."""
-    cfg = tiny()
+    picks are booked behind the step's reads.  An engine of its own:
+    the witness log and the books are read from their start."""
     rng = np.random.RandomState(4)
-    eng = _engine(cfg, SeededGlm5Params(cfg, 9))
+    eng = _engine(cfg, params)
     eng.watch(lambda req: req.prompt.size == 40)
     for n in (40, 37):
         eng.add_request(rng.randint(0, 128, n).astype(np.int32), 6)
@@ -588,7 +644,9 @@ def test_a_padded_last_chunk_answers_as_whole_prompts_do(impl, monkeypatch):
     are padding, which attends nothing.  Greedy, the tokens are those of
     an engine that prefills each prompt whole (the bucketed program,
     which has no padding to skip) and the reference's argmax; the books,
-    the span and the gauge say what the padding was."""
+    the span and the gauge say what the padding was.  Engines of its
+    own (a longer context than ``cfg``'s, and the books and the gauge are
+    read whole)."""
     from dlrover_tpu.serving.router.metrics import RouterMetrics
     from dlrover_tpu.serving.router.replica import InferenceEngineAdapter
 
@@ -649,18 +707,14 @@ def _verdicts(checked):
     return [checked[k] for k in controls_glm5.VERDICTS]
 
 
-_WATCHED = {}
-
-
 @pytest.mark.parametrize("fault", [None] + sorted(controls_glm5.FAULTS))
-def test_the_timed_programs_witness_holds_and_a_planted_fault_shows(fault):
+def test_the_timed_programs_witness_holds_and_a_planted_fault_shows(
+        fault, cfg, params, watched):
     """The comparison the benchmark's cell makes of the engine's OWN
     prefill-chunk and decode-chunk programs (selected rows, first sparse
     MLP, emitted tokens) holds against the reference, and fails, by the
     driver's limits, against a reference with any one fault planted."""
-    if not _WATCHED:
-        _WATCHED["it"] = _watched()
-    cfg, params, seen = _WATCHED["it"]
+    seen = watched
     assert len(seen.requests) == 2                   # one at a time
     assert seen.queries["run"].size == 13 + 9
     # (a request's last token is fed to no forward that counts)
@@ -681,12 +735,14 @@ def test_the_timed_programs_witness_holds_and_a_planted_fault_shows(fault):
 
 
 @pytest.mark.parametrize("program", ["decode", "run"])
-def test_a_fault_in_one_timed_program_shows_there(program, monkeypatch):
+def test_a_fault_in_one_timed_program_shows_there(program, cfg, params,
+                                                  monkeypatch):
     """The fault planted in the PROGRAM: the indexer's head weights lose
     their sign in the decode program's scan alone, or in the prefill
     chunk's alone; the witness of that program fails the selection, and
     the prefill chunk's, which ran before any decode, is untouched by the
-    decode program's."""
+    decode program's.  An engine of its own: the programs it traces are
+    patched."""
     if program == "decode":
         inner = paged_index.gather_index_scores
         monkeypatch.setattr(
@@ -697,7 +753,7 @@ def test_a_fault_in_one_timed_program_shows_there(program, monkeypatch):
         monkeypatch.setattr(
             paged_index, "index_scores",
             lambda q, w, keys: inner(q, jnp.abs(w), keys))
-    cfg, params, seen = _watched(attention_impl="xla")
+    seen = _watched(cfg, params, attention_impl="xla")
     got = serve_sparse.reference_check(cfg, params, config_of(cfg), seen)
     assert not got["selection_matches_reference"]
     assert not serve_sparse.selection_holds(got[f"selection_{program}"])
