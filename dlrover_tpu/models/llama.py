@@ -68,6 +68,12 @@ class LayerSpec:
     # what mixes tokens: "attn" (the model's attention block) | "kda"
     # (linear attention with a recurrent state: LlamaConfig.kda_heads)
     mixer: str = "attn"
+    # what differs between two kinds of LATENT layer in one model (0: the
+    # config's ``kv_lora_rank`` / ``qk_nope_head_dim``), and whether the
+    # config's indexer (``index_topk``) runs in this layer
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    indexer: bool = True
 
 
 def layer_pattern(specs: Tuple[LayerSpec, ...]) -> Tuple[int, int]:
@@ -170,6 +176,10 @@ class LlamaConfig:
     # a sigmoid gate on the attention output, one a query head, from the
     # layer's normed input
     attn_head_gate: bool = False
+    # latent attention: the normed query bottleneck times ``sqrt(hidden /
+    # q_lora_rank)``, the normed latent times ``sqrt(hidden / its rank)``
+    # (``apply_mla_qkv_lora_rescale``); the rotated key row is not scaled
+    mla_lora_rescale: bool = False
     # layers that are not all alike, one LayerSpec each (None = every
     # layer is the one the fields above describe)
     layers: Optional[Tuple[LayerSpec, ...]] = None
@@ -263,6 +273,14 @@ class LlamaConfig:
     def expert_width(self) -> int:
         return self.moe_intermediate_size or self.intermediate_size
 
+    def latent_dims(self, spec: LayerSpec) -> Tuple[int, int, bool]:
+        """``(latent rank, nope size, whether the indexer runs)`` of a
+        latent-attention layer: the layer's own where it says so, else the
+        config's."""
+        return (spec.kv_lora_rank or self.kv_lora_rank,
+                spec.qk_nope_head_dim or self.qk_nope_head_dim,
+                bool(self.index_topk and spec.indexer))
+
     def layer_params(self, spec: LayerSpec) -> int:
         """Parameters of one layer as this device holds it."""
         h, d = self.hidden_size, self.head_dim_
@@ -276,12 +294,14 @@ class LlamaConfig:
                  + self.kda_heads + h * self.kda_heads + self.kda_head_dim
                  + w * h + 2 * h)
         elif self.kv_lora_rank:
-            heads, q, c = spec.num_heads, self.q_lora_rank, self.kv_lora_rank
+            heads, q = spec.num_heads, self.q_lora_rank
+            c, nope, indexed = self.latent_dims(spec)
+            d = nope + self.qk_rope_head_dim
             n = ((h * q + q + q * heads * d if q else h * heads * d)
                  + h * (c + self.qk_rope_head_dim) + c
-                 + c * heads * (self.qk_nope_head_dim + self.v_head_dim)
+                 + c * heads * (nope + self.v_head_dim)
                  + heads * self.v_head_dim * h + 2 * h)
-            if self.index_topk:
+            if indexed:
                 i = self.index_head_dim
                 n += (q * self.index_n_heads * i + h * i + 2 * i
                       + h * self.index_n_heads)
@@ -527,7 +547,7 @@ class LlamaConfig:
         nope = RopeSpec(rotary_fraction=0.0)
         layers = tuple(
             LayerSpec(
-                num_heads=32, rope=nope,
+                num_heads=int(kw.get("num_heads", 32)), rope=nope,
                 mixer="attn" if (i + 1) % 4 == 0 or i == 26 else "kda",
                 mlp="sparse" if i else "dense")
             for i in range(num_layers))
@@ -570,6 +590,79 @@ class LlamaConfig:
         return cls(**base)
 
     @classmethod
+    def dots3_note(cls, **kw) -> "LlamaConfig":
+        """dots-studio/dots3-note-prev (``dots3_note``), its language model
+        as its config.json has it: 46 layers of latent attention in two
+        kinds, ``layer_types`` full, full, then (sliding, sliding, sliding,
+        full) x 11.  A FULL layer: 128 heads of 128 + 64 / 128 over a
+        latent of 512, theta 8e7, GLM-5's indexer (64 heads of 128) that
+        picks the 2048 keys a query attends to.  A SLIDING layer: 64 heads
+        of 192 + 64 / 128 over a latent of 1024, theta 5e4, a query seeing
+        the last 513 keys, itself counted, and no indexer.  Both: the
+        query through a bottleneck of 1024, the bottleneck and the latent
+        rescaled behind their norms (``mla_lora_rescale``), a sigmoid gate
+        a head on the attention's output.  Layer 0's MLP a SwiGLU of
+        13824, then 256 sigmoid-routed experts of 1536, 8 a token chosen
+        by score + bias, weights over their sum, beside one shared expert;
+        vocabulary 152064, untied.  Served, not trained.  ``num_layers``
+        cuts the pattern's depth; ``moe_experts_held`` and ``vocab_size``
+        give one chip its share.
+
+        Assumed, where the config names a mechanism and not its equation
+        (``perfbench/configs/dots3-note-serve.json``): the rescale as above
+        and the indexer's query reading the scaled bottleneck, the gate
+        from the layer's normed input, the window counting the query, the
+        indexer's details as GLM-5's, the selection bias."""
+        num_layers = int(kw.pop("num_layers", 46))
+        full, sliding = RopeSpec(theta=8e7), RopeSpec(theta=5e4)
+
+        def spec(i):
+            mlp = "sparse" if i else "dense"
+            if i >= 2 and (i - 2) % 4 != 3:
+                return LayerSpec(
+                    num_heads=64, window=513, rope=sliding, mlp=mlp,
+                    kv_lora_rank=1024, qk_nope_head_dim=192, indexer=False)
+            return LayerSpec(num_heads=128, rope=full, mlp=mlp)
+
+        base = dict(
+            vocab_size=152064,
+            hidden_size=5120,
+            intermediate_size=13824,
+            num_layers=num_layers,
+            num_heads=128,
+            num_kv_heads=128,
+            max_seq_len=524288,
+            rope_theta=8e7,
+            rms_norm_eps=1e-5,
+            q_lora_rank=1024,
+            kv_lora_rank=512,
+            qk_nope_head_dim=128,
+            qk_rope_head_dim=64,
+            v_head_dim=128,
+            index_n_heads=64,
+            index_head_dim=128,
+            index_topk=2048,
+            attn_head_gate=True,
+            mla_lora_rescale=True,
+            num_experts=256,
+            moe_top_k=8,
+            moe_norm_topk_prob=True,
+            moe_intermediate_size=1536,
+            moe_score_fn="sigmoid",
+            moe_routed_scale=1.0,
+            moe_shared_width=1536,
+            moe_select_bias=True,
+            moe_per_expert_init=True,
+            moe_aux_loss_coef=0.0,
+            moe_z_loss_coef=0.0,
+            scan_layers=False,
+            remat=False,
+            layers=tuple(spec(i) for i in range(num_layers)),
+        )
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
     def from_preset(
         cls, name: str, num_layers: int = 0, **kw
     ) -> "LlamaConfig":
@@ -602,7 +695,7 @@ class LlamaConfig:
 
 #: presets the entry points (examples/, the serving worker) can name
 PRESETS = ("tiny", "llama2_7b", "olmoe_1b_7b", "laguna_xs2", "glm5",
-           "sarvam_105b", "kimi_linear_48b")
+           "sarvam_105b", "kimi_linear_48b", "dots3_note")
 
 
 def resolve_remat_policy(name: str):
@@ -1110,6 +1203,8 @@ class LlamaModel(nn.Module):
             raise NotImplementedError(
                 "LlamaModel trains the grouped-query block: latent "
                 f"attention (kv_lora_rank={cfg.kv_lora_rank}), its indexer "
+                "(latent layers of two geometries and a window among them, "
+                "the rescale behind their norms), "
                 "and leading dense layers by count (moe_first_dense="
                 f"{cfg.moe_first_dense}) are served only "
                 "(serving/latent.py); a training layer for them is "

@@ -165,7 +165,8 @@ def _whole_bias(bias: jax.Array, width: int) -> jax.Array:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("c", "scale", "pages_per_block", "interpret"))
+    jax.jit, static_argnames=("c", "scale", "pages_per_block", "interpret",
+                              "name"))
 def mla_decode_attention(
     qq: jax.Array,       # [B, H, W]
     pool: jax.Array,     # [NB, bs, W]
@@ -177,6 +178,7 @@ def mla_decode_attention(
     scale: float,
     pages_per_block: int = PAGES_PER_BLOCK,
     interpret: bool = False,
+    name: str = "mla_decode_attn",
 ) -> jax.Array:
     b, heads, w = qq.shape
     bs = pool.shape[1]
@@ -209,9 +211,10 @@ def mla_decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((b, heads, c), jnp.float32),
         interpret=interpret,
-        # the kernel's instruction in a device trace:
-        # ``mla_decode_attn.<n>``
-        name="mla_decode_attn",
+        # the kernel's instruction in a device trace: ``mla_decode_attn.<n>``
+        # (a window layer's call has a name of its own, so that a reader
+        # tells the two apart: ``mla_window_decode_attn``)
+        name=name,
     )(table.astype(jnp.int32), lengths.astype(jnp.int32), qq, pool, *masks)
 
 

@@ -193,7 +193,7 @@ def query_tiles(n_real, klen: int):
 
 @functools.partial(
     jax.jit, static_argnames=("c", "scale", "pages", "queries_per_tile",
-                              "interpret"))
+                              "interpret", "name"))
 def mla_prefill_attention(
     qq: jax.Array,       # [K, H, W]
     bias: jax.Array,     # [K, MB * bs] f32
@@ -207,6 +207,7 @@ def mla_prefill_attention(
     pages: Optional[int] = None,         # default: ``block_pages``
     queries_per_tile: int = QUERIES_PER_TILE,
     interpret: bool = False,
+    name: str = "mla_prefill_attn",
 ) -> jax.Array:
     klen, heads, w = qq.shape
     bs = pool.shape[1]
@@ -248,8 +249,9 @@ def mla_prefill_attention(
             vmem_limit_bytes=96 * 1024 * 1024),
         interpret=interpret,
         # the kernel's instruction in a device trace:
-        # ``mla_prefill_attn.<n>``
-        name="mla_prefill_attn",
+        # ``mla_prefill_attn.<n>`` (a window layer's call:
+        # ``mla_window_prefill_attn``)
+        name=name,
     )(table.astype(jnp.int32), q_pos.astype(jnp.int32),
       jnp.full((1,), klen if n_real is None else n_real, jnp.int32),
       qq.reshape((klen + pad) * heads, w),

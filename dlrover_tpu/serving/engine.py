@@ -159,6 +159,23 @@ class EngineStats:
     #                               active ones; the jnp path walks all)
     state_resets_total: int = 0   # slots whose state a prompt's first
     #                               chunk started from zeros
+    # window layers of latent attention (LayerSpec.window), whose rows live
+    # in a ring a slot (serving/paged.py): host arithmetic at each decode
+    # dispatch, summed over its forwards and the window layers
+    window_rows_in_window: int = 0  # rows the decoding slots' queries
+    #                               could see: min(position + 1, window)
+    window_rows_streamed: int = 0  # rows their decode attention read: the
+    #                               blocks a window touches, whole
+    window_rows_resident: int = 0  # a GAUGE: rows the live slots' rings
+    #                               hold now, one window layer's
+    window_rows_resident_max: int = 0  # ... the most any ONE sequence has
+    #                               held: never more than ring x block
+    window_warm_starts: int = 0   # admissions that found their shared
+    #                               prefix's window rows kept, and began
+    #                               behind it
+    window_cold_fallbacks: int = 0  # ... that found cached blocks and no
+    #                               rows kept where their prefill would
+    #                               begin: no block shared, begun at 0
     kda_chunk_rows_real: int = 0  # prompt tokens their chunk kernel took
     kda_chunk_rows_padded: int = 0  # ... and the rows of the 64-token
     #                               chunks it computed for them
@@ -236,6 +253,14 @@ class EngineStats:
         for a model with no such layer)."""
         return self.state_bytes_streamed / self.state_bytes_live \
             if self.state_bytes_live else 0.0
+
+    @property
+    def window_stream_ratio(self) -> float:
+        """Rows the window layers' decode attention read per row inside
+        the windows: 1.0 = a decode reads the window and no more (0.0 for
+        a model with no window layer)."""
+        return self.window_rows_streamed / self.window_rows_in_window \
+            if self.window_rows_in_window else 0.0
 
     @property
     def kv_stream_ratio(self) -> float:
@@ -356,7 +381,14 @@ class InferenceEngine:
         chunk, held still while the slot is idle or prefilling
         (``cache_nbytes`` counts both kinds).  Such a model takes every
         prompt in chunks (``prefill_chunk`` > 0) and is refused prefix
-        sharing, drafts and a mesh by what each would need.
+        sharing, drafts and a mesh by what each would need.  Its WINDOW
+        layers (``LayerSpec.window``) keep no blocks of the pool either: a
+        ring a slot and layer, sized by ``slots x window`` whatever
+        ``cache_blocks`` is, and kept copies (half the slots' number, at
+        least 4) of a prompt prefix's last ``window - 1`` rows, from which
+        a request that shares the prefix starts warm (``serving/paged.py
+        WindowStore``).  Such a model too takes every prompt in chunks and
+        is refused drafts and a mesh.
 
         ``attention_impl`` selects the paged decode attention read:
         ``"xla"`` = fused gather (materializes the dequantized dense
@@ -453,6 +485,36 @@ class InferenceEngine:
                     "is kept whole on one device.  Missing: the recurrent "
                     "state and its kernels sharded over heads (ROADMAP "
                     "Reach A6)")
+        # window layers (serving/latent.py _window_layer): a ring a slot,
+        # not rows in blocks
+        windows = sorted({s.window for s in cfg.layer_specs
+                          if s.mixer == "attn" and s.window})
+        self._window_layers = sum(
+            bool(s.mixer == "attn" and s.window) for s in cfg.layer_specs)
+        if windows:
+            if len(windows) > 1:
+                raise ValueError(
+                    f"window layers of {windows} keys in one model: the "
+                    "rings of a slot have one geometry (serving/paged.py "
+                    "ring_geometry)")
+            if self.speculative_k:
+                raise ValueError(
+                    f"speculative_k={speculative_k!r} with window layers: "
+                    "a rejected draft's rows have already overwritten the "
+                    "ring's oldest.  Missing: a window under drafts "
+                    "(ROADMAP Reach A4); pass speculative_k=0")
+            if not prefill_chunk:
+                raise ValueError(
+                    "window layers take their prompts in chunks (a ring "
+                    "holds one chunk and the window behind it): pass "
+                    "prefill_chunk > 0")
+            if mesh is not None:
+                raise ValueError(
+                    "a mesh with window layers: a slot's ring is kept "
+                    "whole on one device.  Missing: the rings sharded "
+                    "with the latent pools (ROADMAP Reach A4)")
+        # every prompt goes through the chunked path: no bucketed prefill
+        self._chunked_only = bool(self._kda_layers or windows)
         self.params = serving_params_from_llama(
             variables, cfg, int8=int8, fuse=mesh is None)
         # speculative slack: a verify near the end of a sequence writes
@@ -536,9 +598,18 @@ class InferenceEngine:
                 n_blocks = int(int(cache_blocks) * self.kv_budget_x)
             else:
                 n_blocks = self.max_slots * self._max_blocks + 1
+            store = None
+            if windows:
+                from dlrover_tpu.serving.paged import (WindowStore,
+                                                       ring_geometry)
+
+                store = WindowStore(
+                    ring_geometry(windows[0], int(prefill_chunk),
+                                  self.block_size), self.max_slots,
+                    bool(prefix_sharing))
             self._blockmgr = BlockManager(
                 n_blocks, self.block_size,
-                sharing=bool(prefix_sharing))
+                sharing=bool(prefix_sharing), windows=store)
             self._slot_blocks: List[Optional[List[int]]] = (
                 [None] * self.max_slots
             )
@@ -556,13 +627,15 @@ class InferenceEngine:
                 from dlrover_tpu.serving.latent import latent_row_width
 
                 shape = (n_blocks, self.block_size)
+                paged_specs = [s for s in cfg.layer_specs
+                               if s.mixer == "attn" and not s.window]
                 self._cache = {
-                    # one pool an ATTENTION layer: a layer of linear
-                    # attention keeps no rows
+                    # one pool an ATTENTION layer that sees every key: a
+                    # layer of linear attention keeps no rows, a window
+                    # layer a ring a slot
                     "latent_pool": [
-                        jnp.zeros(shape + (latent_row_width(cfg),),
-                                  cfg.dtype)
-                        for _ in range(cfg.num_layers - self._kda_layers)],
+                        jnp.zeros(shape + (latent_row_width(cfg, s),),
+                                  cfg.dtype) for s in paged_specs],
                     "table": jnp.asarray(self._table_np),
                     # the slot whose forward the programs hand back
                     # (``watch``); -1: none
@@ -571,7 +644,17 @@ class InferenceEngine:
                 if cfg.index_topk:
                     self._cache["index_pool"] = [
                         jnp.zeros(shape + (cfg.index_head_dim,), cfg.dtype)
-                        for _ in range(cfg.num_layers)]
+                        for s in paged_specs if cfg.latent_dims(s)[2]]
+                if store is not None:
+                    g = store.geometry
+                    wide = [latent_row_width(cfg, s) for s in cfg.layer_specs
+                            if s.mixer == "attn" and s.window]
+                    self._cache["window_ring"] = [
+                        jnp.zeros((self.max_slots, g.ring, self.block_size,
+                                   w), cfg.dtype) for w in wide]
+                    self._cache["window_keep"] = [
+                        jnp.zeros((store.snapshots, g.keep, self.block_size,
+                                   w), cfg.dtype) for w in wide]
                 if cfg.num_experts:
                     # [picks, picks on held experts], wrapping: the host
                     # adds differences (_book_moe_picks)
@@ -932,6 +1015,33 @@ class InferenceEngine:
 
             self._prefill_chunk_fn = prefill_chunk_fn
 
+        self._window_keep_fn = self._window_restore_fn = None
+        if self._window_layers:
+            def window_copy(cache, slot, entry, blocks, restore):
+                """Every window layer's rows of ONE prefix boundary between
+                the ring of ``slot`` and the snapshot ``entry``: ``blocks``
+                [keep] names the ring's blocks in position order
+                (``WindowStore.ring_blocks``; behind the last, the ring's
+                size: read as any block, written nowhere).  ``restore``:
+                snapshot -> ring, else ring -> snapshot."""
+                rings, keeps = [], []
+                for ring, keep in zip(cache["window_ring"],
+                                      cache["window_keep"]):
+                    if restore:
+                        ring = ring.at[slot, blocks].set(
+                            jnp.take(keep, entry, axis=0), mode="drop")
+                    else:
+                        keep = keep.at[entry].set(jnp.take(
+                            jnp.take(ring, slot, axis=0),
+                            jnp.minimum(blocks, ring.shape[1] - 1), axis=0))
+                    rings.append(ring)
+                    keeps.append(keep)
+                return dict(cache, window_ring=rings, window_keep=keeps)
+
+            self._window_keep_fn, self._window_restore_fn = (
+                jax.jit(functools.partial(window_copy, restore=restore),
+                        donate_argnums=(0,)) for restore in (False, True))
+
         self._spec_fn = None
         if self.speculative_k > 1:
             from dlrover_tpu.serving.model import verify_step
@@ -1003,7 +1113,7 @@ class InferenceEngine:
         chunked = self._prefill_chunk_fn is not None
         buckets = [n for n in self.buckets
                    if (not chunked or n <= self.prefill_chunk)
-                   and not self._kda_layers]
+                   and not self._chunked_only]
         for g in range(1, b + 1):
             slots = jnp.arange(g, dtype=jnp.int32)
             if chunked and g <= self._prefill_group:
@@ -1019,6 +1129,16 @@ class InferenceEngine:
                     self.params, self._cache, zeros(g, bucket),
                     jnp.ones(g, jnp.int32), slots, zeros(g), rng,
                     zeros(b))
+                ran += 1
+        store = self._blockmgr.windows if self.paged else None
+        if store is not None and store.snapshots:
+            # (an idle engine: slot 0's ring and snapshot 0 are nobody's)
+            for label, fn in (("window_keep", self._window_keep_fn),
+                              ("window_restore", self._window_restore_fn)):
+                self._cache = run(
+                    label, fn, self._cache, jnp.asarray(0, jnp.int32),
+                    jnp.asarray(0, jnp.int32),
+                    jnp.asarray(store.ring_blocks(0)))
                 ran += 1
         jax.block_until_ready(self._cache)
         return ran
@@ -1081,9 +1201,10 @@ class InferenceEngine:
                 return
             bucket = _bucket(self._queue[0].prompt.size, self.buckets)
             if self._prefill_chunk_fn is not None and (
-                    bucket > self.prefill_chunk or self._kda_layers):
-                # (a state a slot is carried chunk by chunk: a model with
-                # linear-attention layers has no bucketed prefill)
+                    bucket > self.prefill_chunk or self._chunked_only):
+                # (a state or a ring a slot is carried chunk by chunk: a
+                # model with linear-attention or window layers has no
+                # bucketed prefill)
                 if not self._admit_chunked(free[0]):
                     return  # pool exhausted: keep queued, keep order
                 continue
@@ -1219,16 +1340,16 @@ class InferenceEngine:
             self._blockmgr.mark_pending(
                 blocks[shared // self.block_size:
                        req.prompt.size // self.block_size])
+            store = self._blockmgr.windows
             if shared:
-                c = self.prefill_chunk
+                from dlrover_tpu.serving.paged import warm_start
+
                 # warm start: shared blocks already hold the prefix's
                 # K/V, so the cursor begins at the last chunk boundary
-                # inside the shared region instead of 0 — the TTFT win.
-                # The clamp keeps the FINAL chunk live even when the
-                # whole prompt is shared: sampling the first token
-                # needs one real dispatch.
-                start = min((shared // c) * c,
-                            ((req.prompt.size - 1) // c) * c)
+                # inside the shared region instead of 0 — the TTFT win
+                # (the final chunk stays live: paged.warm_start)
+                start = warm_start(shared, req.prompt.size,
+                                   self.prefill_chunk)
                 # the chunk program WRITES positions [start, ...), so
                 # every shared block it overlaps must diverge first
                 # (COW) — unlike batched prefill there is no write
@@ -1253,6 +1374,20 @@ class InferenceEngine:
                         blocks[j] = new_bid
                 if src:
                     self._copy_blocks(src, dst)
+            if store is not None and start:
+                # the window layers share no block: the prefix's last
+                # ``window - 1`` rows, kept when a prefill passed this
+                # boundary, go into the slot's ring (held, or
+                # ``alloc_sequence`` had shared nothing: the request then
+                # starts COLD at 0 on blocks of its own, never wrong)
+                kept = store.lookup(blocks[start // self.block_size - 1])
+                self._cache = self._window_restore_fn(
+                    self._cache, jnp.asarray(s, jnp.int32),
+                    jnp.asarray(kept, jnp.int32),
+                    jnp.asarray(store.ring_blocks(start)))
+                self.stats.window_warm_starts += 1
+            if store is not None:
+                self.stats.window_cold_fallbacks = store.cold_starts
             self._bind_blocks(s, blocks)
             self._table_dirty = True
         self._queue.popleft()
@@ -1362,6 +1497,7 @@ class InferenceEngine:
         self.stats.prefill_calls += 1
         self.stats.prefill_chunks += 1
         self.stats.prefill_chunk_slots += g
+        self._keep_window_rows(slots, ends)
         ended = []
         for i, s in enumerate(slots):
             req = self._slot_req[s]
@@ -1390,6 +1526,32 @@ class InferenceEngine:
         self._unread.append(_Unread(
             "prefill_chunk", attrs, started, firsts,
             functools.partial(self._deliver_firsts, ended)))
+
+    def _keep_window_rows(self, slots, ends) -> None:
+        """Behind a prompt chunk's dispatch: where a slot's cursor now
+        stands at the last chunk boundary inside its prompt's whole
+        blocks, the point a later request that shares the prompt as a
+        prefix warm-starts from, copy the window layers' last ``window -
+        1`` rows out of the slot's ring (``WindowStore``), unless that
+        prefix's are kept already, the block that ends there stands for
+        no prefix (diverged, sharing off) or the store is full.  One small
+        program a prompt, queued behind the chunk that wrote the rows."""
+        store = self._blockmgr.windows if self.paged else None
+        if store is None or not store.snapshots:
+            return
+        c, bs = self.prefill_chunk, self.block_size
+        for s, end in zip(slots, ends):
+            prompt = self._slot_req[s].prompt
+            if not end or end != (prompt.size // bs * bs) // c * c:
+                continue
+            entry = self._blockmgr.window_entry(
+                self._slot_blocks[s][int(end) // bs - 1])
+            if entry is None:
+                continue
+            self._cache = self._window_keep_fn(
+                self._cache, jnp.asarray(s, jnp.int32),
+                jnp.asarray(entry, jnp.int32),
+                jnp.asarray(store.ring_blocks(int(end))))
 
     def _finish_if_done(self, s: int, last_token: int) -> bool:
         req = self._slot_req[s]
@@ -1559,7 +1721,8 @@ class InferenceEngine:
             1, self.chunk + 1)[None, :]
         rows = {"kv_rows_live": live, "kv_rows_streamed": streamed,
                 **self._book_selection(lengths - 1, lengths),
-                **self._book_state_bytes(active)}
+                **self._book_state_bytes(active),
+                **self._book_window_rows(lengths)}
         started = time.perf_counter()
         with self._dispatching("decode_chunk"):
             out, self._last_dev, _, self._cache, self._rng, seen = \
@@ -1751,6 +1914,38 @@ class InferenceEngine:
         self.stats.state_bytes_streamed += walked
         return {"state_bytes_live": live, "state_bytes_streamed": walked}
 
+    def _book_window_rows(self, lengths: np.ndarray) -> Dict[str, int]:
+        """Book what the window layers of one decode chunk read, for
+        slots whose queries see ``lengths`` [slots, forwards] keys with no
+        window: rows inside the windows, rows their attention streams
+        (the ``reach`` blocks a window can touch, whole, whichever path
+        decodes),
+        summed over the window layers; and the rows the live slots'
+        rings hold now, one layer's.  Returns them for the dispatch's
+        span ({} for a model with no window layer)."""
+        store = self._blockmgr.windows if self.paged else None
+        if store is None:
+            return {}
+        g = store.geometry
+        inside = np.minimum(lengths, g.window)
+        # (kernel and gather alike walk the whole of so small a table)
+        streamed = lengths.size * g.reach * g.block_size
+        held = store.resident_rows([
+            self._prefill_pos[s] if self._prefilling[s]
+            else self._positions[s] + self.chunk
+            for s, r in enumerate(self._slot_req) if r is not None])
+        book = {"window_rows_in_window":
+                int(inside.sum()) * self._window_layers,
+                "window_rows_streamed": streamed * self._window_layers,
+                "window_rows_resident": int(held.sum())}
+        st = self.stats
+        st.window_rows_in_window += book["window_rows_in_window"]
+        st.window_rows_streamed += book["window_rows_streamed"]
+        st.window_rows_resident = book["window_rows_resident"]
+        st.window_rows_resident_max = max(
+            st.window_rows_resident_max, int(held.max(initial=0)))
+        return book
+
     def _book_state_chunks(self, starts, ends) -> Dict[str, int]:
         """Book what the linear-attention layers' chunk kernel takes of
         prompt chunks whose real tokens stand at ``starts[i] .. ends[i] -
@@ -1780,9 +1975,21 @@ class InferenceEngine:
         """Bytes of everything this engine keeps a sequence in: the paged
         pools (or the dense K/V) and, a slot, the linear-attention
         layers' states and convolution rows."""
-        return int(sum(
-            x.nbytes for key, val in self._cache.items()
-            if isinstance(val, list) for x in val))
+        return sum(self.cache_nbytes_by_kind.values())
+
+    @property
+    def cache_nbytes_by_kind(self) -> Dict[str, int]:
+        """:attr:`cache_nbytes` by what grows it: ``paged`` (the pools of
+        blocks: ``cache_blocks``), ``window`` (the window layers' rings
+        and kept prefixes: slots x window) and ``state`` (a slot's
+        recurrent state)."""
+        kinds = {"paged": 0, "window": 0, "state": 0}
+        for key, val in self._cache.items():
+            if isinstance(val, list):
+                kind = "window" if key.startswith("window_") else (
+                    "state" if key.startswith("kda_") else "paged")
+                kinds[kind] += int(sum(x.nbytes for x in val))
+        return kinds
 
     def watch(self, wanted) -> None:
         """Keep what the engine's own programs do for ONE request at a

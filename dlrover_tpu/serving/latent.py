@@ -12,6 +12,18 @@ dispatches on each layer's description, the pools are indexed by the
 attention layers alone, and a ``RopeSpec`` whose ``rotary_fraction`` is 0
 (``LlamaConfig.kimi_linear_48b``) rotates nothing.
 
+A model's latent layers may be of MORE THAN ONE KIND
+(``LlamaConfig.dots3_note``): each reads its own geometry from its
+``LayerSpec`` (heads, latent rank, nope size, rotary embedding:
+``LlamaConfig.latent_dims``), a layer whose ``LayerSpec.window`` is w
+attends the last w keys only and keeps its rows in a RING a slot
+(``serving/paged.py``: no block of the pools, no table on the host;
+:func:`_window_layer`), and the indexer runs in the layers that have one.
+With ``mla_lora_rescale`` the normed bottleneck and the normed latent are
+scaled behind their norms; with ``attn_head_gate`` each head's attended
+value is gated ahead of ``W_o``.  All of it is chosen by what the model
+has, at trace time: a model with one kind of layer traces what it did.
+
 One layer, on its normed input ``h`` (positions ``t``, ``s``):
 
 - *latent attention*: ``c_q = RMSNorm(W_qa h)``, ``q = W_qb c_q`` (or,
@@ -78,11 +90,12 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from dlrover_tpu.models.llama import (LlamaConfig, RopeSpec,
+from dlrover_tpu.models.llama import (LayerSpec, LlamaConfig, RopeSpec,
                                       rope_inverse_frequencies)
 from dlrover_tpu.models.moe import grouped_matmul, route
 from dlrover_tpu.serving.model import _lm_head, _mm, _rmsnorm
-from dlrover_tpu.serving.paged import scatter_tokens
+from dlrover_tpu.serving.paged import (ring_table, scatter_ring,
+                                       scatter_tokens)
 from dlrover_tpu.utils.profiler import device_scope
 
 #: pages of the pools one key block of the query-run path holds
@@ -91,15 +104,18 @@ _NEG_INF = -jnp.inf
 _LANES = 128
 
 
-def latent_row_width(cfg: LlamaConfig) -> int:
-    """Values of one row of the latent pool: ``[c_kv | k_r]`` and zeros up
+def latent_row_width(cfg: LlamaConfig,
+                     spec: Optional[LayerSpec] = None) -> int:
+    """Values of one row of the latent pool (of the layer ``spec``, where
+    a model's latent layers differ): ``[c_kv | k_r]`` and zeros up
     to whole 128-lane tiles (GLM-5: 576 -> 640).  With a minor dimension
     that is no multiple of 128 the TPU keeps a ``[blocks, 128, 576]``
     array with the BLOCK's rows minor, and every program that gathers
     rows copies the pool into row-major order and back (compiled for a
     described v5e, PR 34: two copies of every layer's pool a dispatch).
     The absorbed query carries zeros there too, so no score moves."""
-    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // _LANES) * _LANES
+    c = cfg.latent_dims(spec)[0] if spec is not None else cfg.kv_lora_rank
+    return -(-(c + cfg.qk_rope_head_dim) // _LANES) * _LANES
 
 
 def _layernorm(x, scale, bias, eps=1e-6):
@@ -138,20 +154,28 @@ def rope_pairs(x: jax.Array, positions: jax.Array, spec: RopeSpec,
     return jnp.concatenate([rot, xf[..., rotary:]], axis=-1)
 
 
-def _projections(lp, h, cfg: LlamaConfig, pos, dtype):
+def _projections(lp, h, cfg: LlamaConfig, spec: LayerSpec, pos, dtype):
     """``h`` [B, K, E], ``pos`` [B, K] -> the absorbed queries ``qq``
     [B, K, H, W], the cache rows ``row`` [B, K, W] (``W`` =
     :func:`latent_row_width`: C + R and zeros), and the
     indexer's ``q_i`` [B, K, Hi, Di], ``k_i`` [B, K, Di], ``w`` [B, K, Hi]
-    (float32)."""
+    (float32), in the geometry of the layer ``spec``: its heads, its
+    latent rank and nope size (``LlamaConfig.latent_dims``), its rotary
+    embedding; the indexer's three where it runs in this layer."""
     b, k = h.shape[:2]
-    c, r = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-    nope, heads = cfg.qk_nope_head_dim, cfg.num_heads
-    rope = cfg.rope
-    with device_scope("mla_proj"):
+    r, heads, rope = cfg.qk_rope_head_dim, spec.num_heads, spec.rope
+    c, nope, indexed = cfg.latent_dims(spec)
+    # (``mla_lora_rescale``: behind their norms, ahead of every reader)
+    q_up, kv_up = ((cfg.hidden_size / cfg.q_lora_rank) ** 0.5,
+                   (cfg.hidden_size / c) ** 0.5) \
+        if cfg.mla_lora_rescale else (None, None)
+    with device_scope("swa_proj" if spec.window else "mla_proj"):
         if "wq_a" in lp:               # the query through its bottleneck
             c_q = _rmsnorm(_mm(h, lp["wq_a"], dtype), lp["q_a_norm"],
-                           cfg.rms_norm_eps).astype(dtype)
+                           cfg.rms_norm_eps)
+            if q_up:
+                c_q = c_q * q_up
+            c_q = c_q.astype(dtype)
             q = _mm(c_q, lp["wq_b"], dtype).reshape(b, k, heads, nope + r)
         else:
             # W_q^T, [H x (nope + rope), E]: the layout the chip's
@@ -166,8 +190,10 @@ def _projections(lp, h, cfg: LlamaConfig, pos, dtype):
         q_rope = rope_pairs(q[..., nope:], pos, rope, r)
         ckv = _mm(h, lp["wkv_a"], dtype)
         c_kv = _rmsnorm(ckv[..., :c], lp["kv_a_norm"], cfg.rms_norm_eps)
+        if kv_up:
+            c_kv = c_kv * kv_up
         k_r = rope_pairs(ckv[..., c:], pos, rope, r)
-        pad = latent_row_width(cfg) - c - r
+        pad = latent_row_width(cfg, spec) - c - r
         row = jnp.concatenate(
             [c_kv, k_r, jnp.zeros((b, k, pad), jnp.float32)],
             axis=-1).astype(dtype)
@@ -178,7 +204,7 @@ def _projections(lp, h, cfg: LlamaConfig, pos, dtype):
             [q_abs, q_rope, jnp.zeros((b, k, heads, pad), jnp.float32)],
             axis=-1).astype(dtype)
     q_i = k_i = w = None
-    if cfg.index_topk:
+    if indexed:
         hi, di = cfg.index_n_heads, cfg.index_head_dim
         with device_scope("dsa_index"):
             q_i = rope_pairs(
@@ -194,8 +220,17 @@ def _projections(lp, h, cfg: LlamaConfig, pos, dtype):
     return qq, row, q_i, k_i, w
 
 
-def _softmax_scale(cfg: LlamaConfig) -> float:
-    return float(cfg.head_dim_ ** -0.5 * cfg.attn_scale_mult)
+def _attn_spec(cfg: LlamaConfig, spec: Optional[LayerSpec]) -> LayerSpec:
+    """``spec``, or of a model whose attention layers are all alike the
+    one kind."""
+    return spec or next(s for s in cfg.layer_specs if s.mixer == "attn")
+
+
+def _softmax_scale(cfg: LlamaConfig,
+                   spec: Optional[LayerSpec] = None) -> float:
+    nope = cfg.latent_dims(_attn_spec(cfg, spec))[1]
+    return float((nope + cfg.qk_rope_head_dim) ** -0.5
+                 * cfg.attn_scale_mult)
 
 
 def _orderable(x: jax.Array) -> jax.Array:
@@ -232,7 +267,8 @@ def _chosen(keys: jax.Array, k: int, dead: jax.Array) -> jax.Array:
 
 def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
                 cfg: LlamaConfig, pages: int, impl: str = "xla",
-                interpret: bool = False, n_real=None):
+                interpret: bool = False, n_real=None,
+                spec: Optional[LayerSpec] = None):
     """A run of queries of ONE sequence against its cached rows, this
     run's own among them: ``qq`` [K, H, C + R], ``q_i`` [K, Hi, Di], ``w``
     [K, Hi], ``q_pos`` [K] ascending, ``table_row`` [MB] (a multiple of
@@ -253,14 +289,14 @@ def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
     below otherwise (the off-chip path and the kernel's oracle: every
     block's ``[K, H, keys]`` float32 scores pass through HBM)."""
     klen, heads, _ = qq.shape
-    c = cfg.kv_lora_rank
+    c = cfg.latent_dims(_attn_spec(cfg, spec))[0]
     bs = latent_pool.shape[1]
     kb = pages * bs                               # keys a block
     n_blocks = table_row.shape[0] // pages
     width = n_blocks * kb
     last = q_pos[-1] if n_real is None else q_pos[n_real - 1]
     n_live = jnp.minimum((last + kb) // kb, n_blocks)
-    scale = _softmax_scale(cfg)
+    scale = _softmax_scale(cfg, spec)
 
     def real_only(chosen):
         if n_real is None:
@@ -275,7 +311,7 @@ def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
         key_pos = j * kb + jnp.arange(kb)
         return key_pos[None, :] <= q_pos[:, None]            # [K, kb]
 
-    if cfg.index_topk and width > cfg.index_topk:
+    if q_i is not None and width > cfg.index_topk:
         from dlrover_tpu.ops.pallas.paged_index import index_scores
 
         with device_scope("dsa_index"):
@@ -303,7 +339,8 @@ def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
 
             o_lat = mla_prefill_attention(
                 qq, jnp.where(chosen, 0.0, _NEG_INF), q_pos, latent_pool,
-                table_row, n_real, c=c, scale=scale, interpret=interpret)
+                table_row, n_real, c=c, scale=scale, interpret=interpret,
+                queries_per_tile=_queries_per_tile(heads))
             return o_lat, chosen
 
         def attend_block(j, carry):
@@ -331,8 +368,18 @@ def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
         return acc / jnp.maximum(l, 1e-30)[..., None], chosen
 
 
+def _queries_per_tile(heads: int) -> int:
+    """Queries a program of the run kernel holds: the kernel's own for up
+    to 64 heads, fewer for more, so that ``queries x heads``, the rows of
+    its matmuls and of its float32 accumulator, stay 2048."""
+    from dlrover_tpu.ops.pallas.mla_prefill import QUERIES_PER_TILE
+
+    return max(8, min(QUERIES_PER_TILE, QUERIES_PER_TILE * 64 // heads))
+
+
 def _attend_decode(qq, q_i, w, latent_pool, index_pool, table, lengths,
-                   cfg: LlamaConfig, impl: str, interpret: bool):
+                   cfg: LlamaConfig, impl: str, interpret: bool,
+                   spec: Optional[LayerSpec] = None):
     """One query a slot: ``qq`` [B, H, C + R], ``q_i`` [B, Hi, Di], ``w``
     [B, Hi], ``lengths`` [B] the keys each slot sees (0: its output is
     not wanted).  Returns the attended latent [B, H, C] float32 and the
@@ -343,7 +390,9 @@ def _attend_decode(qq, q_i, w, latent_pool, index_pool, table, lengths,
 
     mb, bs = table.shape[1], latent_pool.shape[1]
     chosen = bias = None
-    if cfg.index_topk and mb * bs > cfg.index_topk:
+    c, scale = cfg.latent_dims(_attn_spec(cfg, spec))[0], \
+        _softmax_scale(cfg, spec)
+    if q_i is not None and mb * bs > cfg.index_topk:
         with device_scope("dsa_index"):
             if impl == "pallas":
                 scores = paged_index.paged_index_scores(
@@ -360,12 +409,10 @@ def _attend_decode(qq, q_i, w, latent_pool, index_pool, table, lengths,
         if impl == "pallas":
             o = mla_decode.mla_decode_attention(
                 qq, latent_pool, table, lengths, bias,
-                c=cfg.kv_lora_rank, scale=_softmax_scale(cfg),
-                interpret=interpret)
+                c=c, scale=scale, interpret=interpret)
         else:
             o = mla_decode.gather_latent_decode(
-                qq, latent_pool, table, lengths, bias,
-                c=cfg.kv_lora_rank, scale=_softmax_scale(cfg))
+                qq, latent_pool, table, lengths, bias, c=c, scale=scale)
     return o, chosen
 
 
@@ -400,13 +447,100 @@ def _rows_of(chosen, length, rows: int, size: int) -> jax.Array:
                      -1).astype(jnp.int32)
 
 
-def _attn_out(lp, o_lat, cfg: LlamaConfig, dtype):
-    """Attended latents [B, K, H, C] -> the block's output [B, K, E]."""
-    with device_scope("mla_attn"):
+def _attn_out(lp, o_lat, cfg: LlamaConfig, dtype, h=None,
+              scope: str = "mla_attn"):
+    """Attended latents [B, K, H, C] -> the block's output [B, K, E]; with
+    a head gate (``attn_head_gate``) each head's value times the sigmoid
+    of its gate, from the layer's normed input ``h``, ahead of ``W_o``."""
+    with device_scope(scope):
         o = jnp.einsum("bkhc,hcv->bkhv", o_lat.astype(dtype),
                        lp["wkv_b_v"].astype(dtype),
-                       preferred_element_type=jnp.float32).astype(dtype)
+                       preferred_element_type=jnp.float32)
+        if "head_gate" in lp:
+            gate = jnp.dot(h.astype(dtype), lp["head_gate"].astype(dtype),
+                           preferred_element_type=jnp.float32)
+            o = o * jax.nn.sigmoid(gate)[..., None]
+        o = o.astype(dtype)
         return _mm(o.reshape(*o.shape[:2], -1), lp["wo"], dtype)
+
+
+def _window_mask(q_pos, key_pos, window: int):
+    """[..., K, keys] bool: key ``s`` is one of the ``window`` a query at
+    ``t`` sees, itself counted: ``t - window < s <= t``."""
+    return (key_pos[..., None, :] <= q_pos[..., :, None]) & (
+        key_pos[..., None, :] > q_pos[..., :, None] - window)
+
+
+def _attend_window_decode(qq, pool, positions, active, cfg: LlamaConfig,
+                          spec: LayerSpec, ring: int, impl: str,
+                          interpret: bool):
+    """One query a slot of a WINDOW layer: ``qq`` [B, H, W] at
+    ``positions`` [B] against the slots' rings in ``pool``
+    [slots x ``ring``, block, W] (``serving/paged.py``), through a
+    position-ordered table of the blocks a window touches and under a
+    mask of the positions inside it.  The decode kernel again, by a name of its own;
+    [B, H, C] float32."""
+    from dlrover_tpu.ops.pallas import mla_decode
+
+    b, bs = qq.shape[0], pool.shape[1]
+    reach = -(-(spec.window - 1) // bs) + 1
+    table, base = ring_table(jnp.arange(b), positions, reach, spec.window,
+                             ring, bs)
+    lengths = positions.astype(jnp.int32) + 1 - base
+    if active is not None:
+        lengths = jnp.where(active, lengths, 0)
+    key_pos = base[:, None] + jnp.arange(reach * bs)
+    bias = jnp.where(key_pos > (positions[:, None] - spec.window), 0.0,
+                     _NEG_INF)
+    kw = dict(c=cfg.latent_dims(spec)[0], scale=_softmax_scale(cfg, spec))
+    with device_scope("swa_attn"):
+        if impl == "pallas":
+            return mla_decode.mla_decode_attention(
+                qq, pool, table, lengths, bias, interpret=interpret,
+                name="mla_window_decode_attn", **kw)
+        return mla_decode.gather_latent_decode(
+            qq, pool, table, lengths, bias, **kw)
+
+
+def _attend_window_run(qq, q_pos, pool, slot, n_real, cfg: LlamaConfig,
+                       spec: LayerSpec, ring: int, impl: str,
+                       interpret: bool):
+    """A run of queries of ONE sequence in a WINDOW layer: ``qq`` [K, H,
+    W] at ``q_pos`` [K] (ascending, the run's own rows already in the
+    ring of ``slot``) against the whole ring in position order, each query
+    under the mask of its window, a padded query (at or behind ``n_real``)
+    attending nothing.  ``impl == "pallas"``: the run kernel over the
+    ring's ``ring`` pages, by a name of its own; otherwise one dense
+    masked softmax over the gathered ring.  [K, H, C] float32."""
+    klen, bs = qq.shape[0], pool.shape[1]
+    c, scale = cfg.latent_dims(spec)[0], _softmax_scale(cfg, spec)
+    table, base = ring_table(slot[None], q_pos[:1], ring, spec.window,
+                             ring, bs)
+    key_pos = base[0] + jnp.arange(ring * bs)
+    keep = _window_mask(q_pos, key_pos, spec.window)
+    if n_real is not None:
+        keep = keep & (jnp.arange(klen) < n_real)[:, None]
+    with device_scope("swa_attn"):
+        if impl == "pallas":
+            from dlrover_tpu.ops.pallas.mla_prefill import (
+                mla_prefill_attention,
+            )
+
+            return mla_prefill_attention(
+                qq, jnp.where(keep, 0.0, _NEG_INF), q_pos - base[0], pool,
+                table[0], n_real, c=c, scale=scale, interpret=interpret,
+                queries_per_tile=_queries_per_tile(qq.shape[1]),
+                name="mla_window_prefill_attn")
+        rows = jnp.take(pool, table[0], axis=0).reshape(
+            ring * bs, pool.shape[-1]).astype(qq.dtype)
+        s = jnp.einsum("khc,sc->khs", qq, rows,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(keep[:, None, :], s, _NEG_INF)
+        m = s.max(axis=-1, keepdims=True)
+        p = jnp.exp(s - jnp.where(jnp.isfinite(m), m, 0.0))
+        o = jnp.einsum("khs,sc->khc", p.astype(qq.dtype), rows[:, :c],
+                       preferred_element_type=jnp.float32)
+        return o / jnp.maximum(p.sum(axis=-1), 1e-30)[..., None]
 
 
 def _swiglu(h, wgu, down, dtype):
@@ -504,6 +638,47 @@ def _kda_mixer(lp, h, state, conv, cfg: LlamaConfig, dtype, positions,
     return jnp.stack(ys), state, conv, jnp.stack(decays)
 
 
+def _window_layer(lp, h, held, cfg: LlamaConfig, spec: LayerSpec, dtype,
+                  positions, pos_k, slots, n_real, active, decode: bool,
+                  impl: str, interpret: bool):
+    """A WINDOW layer of latent attention on ``h`` [B, K, E]: ``(y,
+    held)``, the block's output and the layer's rings ``held`` [slots,
+    ring, block, W] with this forward's rows written (``serving/paged.py``:
+    no table, the row of position ``p`` in its slot's ring block ``(p //
+    block) % ring``).  One query a slot over every slot is a decode
+    forward (``active`` [B] or None: all; any other slot's row is written
+    nowhere); a run of queries of the slots ``slots`` is a prompt chunk, a
+    row at a time, its first ``n_real`` queries real."""
+    b, klen, _ = h.shape
+    if slots is None and not decode:
+        raise ValueError(
+            "a run of queries over every slot is a speculative verify, and "
+            "a window layer's ring keeps no row a rejected draft could "
+            "hand back.  Missing: a window under drafts (ROADMAP Reach A4)")
+    n_slots, ring, bs, width = held.shape
+    pool = held.reshape(n_slots * ring, bs, width)
+    qq, row, _, _, _ = _projections(lp, h, cfg, spec, pos_k, dtype)
+    if decode:
+        real = jnp.ones((b, 1), bool) if active is None else active[:, None]
+        pool = scatter_ring(pool, jnp.arange(b), row.astype(pool.dtype),
+                            positions, real, ring)
+        o_lat = _attend_window_decode(
+            qq[:, 0], pool, positions, active, cfg, spec, ring, impl,
+            interpret)[:, None]
+    else:
+        real = jnp.ones((b, klen), bool) if n_real is None else (
+            jnp.arange(klen)[None, :] < n_real[:, None])
+        pool = scatter_ring(pool, slots, row.astype(pool.dtype), positions,
+                            real, ring)
+        o_lat = jax.lax.map(
+            lambda a: _attend_window_run(
+                a[0], a[1], pool, a[2], a[3], cfg, spec, ring, impl,
+                interpret),
+            (qq, pos_k, slots, n_real))
+    y = _attn_out(lp, o_lat, cfg, dtype, h, "swa_attn")
+    return y, pool.reshape(held.shape)
+
+
 def _pad_table(table: jax.Array, pages: int) -> jax.Array:
     """The table padded with the trash block to whole key blocks."""
     pad = -table.shape[1] % pages
@@ -539,7 +714,11 @@ def verify_step(
     more for ties at the threshold; a run: ``chosen_bits`` [layers,
     K, table rows / 8] uint8, ``jnp.packbits`` of the mask), and the first
     sparse MLP's normed input and output (``sparse_in``, ``sparse_out``
-    [K, E]).  A slot that is not among the rows leaves junk there.  A model
+    [K, E]); of a model with window layers also ``full_out`` and
+    ``window_out`` [K, E], the first full and the first window layer's
+    attention output for the slot's row, ``window_in`` [K, E], that window
+    layer's normed input, and the slot's ``logits`` beside the rows.  A slot that is not among the rows
+    leaves junk there.  A model
     with NO selection has no rows to tell of (every query attends to every
     row behind it) and hands back, in their place, what the selection
     otherwise makes the only judge of: ``logits`` [V] float32, the slot's
@@ -576,7 +755,7 @@ def verify_step(
         watch = jnp.clip(watch, 0, b - 1) if slots is None \
             else jnp.argmax(slots == watch)
     latent_pools, index_pools, selections, seen = [], [], [], {}
-    states, convs = [], []
+    states, convs, rings = [], [], []
     for lp, spec in zip(params["layers"], cfg.layer_specs):
         h = _rmsnorm(x, lp["input_norm"], cfg.rms_norm_eps).astype(dtype)
         if spec.mixer == "kda":
@@ -595,31 +774,45 @@ def verify_step(
             states.append(state)
             convs.append(conv)
             x = x + y
+        elif spec.window:
+            y, held = _window_layer(
+                lp, h, cache["window_ring"][len(rings)], cfg, spec, dtype,
+                positions, pos_k, slots, None if decode else n_real,
+                active if decode else None, decode, attention_impl,
+                kernel_interpret)
+            if watch is not None and not rings:
+                # the first window layer's normed input and output for
+                # the watched row
+                seen.update(window_in=jnp.take(h, watch, axis=0),
+                            window_out=jnp.take(y, watch, axis=0))
+            rings.append(held)
+            x = x + y
         else:
             i = len(latent_pools)
-            qq, row, q_i, k_i, w = _projections(lp, h, cfg, pos_k, dtype)
+            qq, row, q_i, k_i, w = _projections(lp, h, cfg, spec, pos_k,
+                                                dtype)
             lat = cache["latent_pool"][i]
             lat = scatter_tokens(lat, table, row.astype(lat.dtype),
                                  positions)
             idx = None
-            if cfg.index_topk:
-                idx = cache["index_pool"][i]
+            if k_i is not None:
+                idx = cache["index_pool"][len(index_pools)]
                 idx = scatter_tokens(idx, table, k_i.astype(idx.dtype),
                                      positions)
             if decode:
                 o_lat, chosen = _attend_decode(
                     qq[:, 0], None if q_i is None else q_i[:, 0],
                     None if w is None else w[:, 0], lat, idx, table,
-                    lengths, cfg, attention_impl, kernel_interpret)
+                    lengths, cfg, attention_impl, kernel_interpret, spec)
                 o_lat = o_lat[:, None]
             else:
                 o_lat, chosen = jax.lax.map(
                     lambda a: _attend_run(a[0], a[1], a[2], a[3], lat, idx,
                                           a[4], cfg, KEY_BLOCK_PAGES,
                                           attention_impl, kernel_interpret,
-                                          a[5]),
+                                          a[5], spec),
                     (qq, q_i, w, pos_k, run_table, n_real))
-            if watch is not None and cfg.index_topk:
+            if watch is not None and k_i is not None:
                 seen_row = None if chosen is None \
                     else jnp.take(chosen, watch, axis=0)
                 if decode:
@@ -634,9 +827,15 @@ def verify_step(
                             seen_row, jnp.take(lengths, watch), rows,
                             min(cfg.index_topk + _LANES, rows))
                 selections.append(seen_row)
-            x = x + _attn_out(lp, o_lat, cfg, dtype)
+            y = _attn_out(lp, o_lat, cfg, dtype, h)
+            if watch is not None and "window_ring" in cache \
+                    and not latent_pools:
+                # the first full layer's output for the watched row
+                seen["full_out"] = jnp.take(y, watch, axis=0)
+            x = x + y
             latent_pools.append(lat)
-            index_pools.append(idx)
+            if idx is not None:
+                index_pools.append(idx)
         h = _rmsnorm(x, lp["post_norm"], cfg.rms_norm_eps).astype(dtype)
         y, n = _mlp(lp, h, cfg, dtype, counted)
         if n is not None and picks is not None:
@@ -663,10 +862,16 @@ def verify_step(
                  jnp.take(states[-1], at, axis=0)])
     if cfg.index_topk:
         out_cache["index_pool"] = index_pools
+    if rings:
+        out_cache["window_ring"] = rings
     if picks is not None:
         out_cache["moe_picks"] = picks
     if watch is not None and cfg.index_topk:
         chosen = jnp.stack(selections)
+        if rings:
+            # a model of window layers beside layers with a selection:
+            # the slot's logits too, as a model with no selection gives
+            seen["logits"] = jnp.take(logits[:, 0], watch, axis=0)
         out_cache["witness"] = dict(seen, **(
             {"rows": chosen} if decode
             else {"chosen_bits": jnp.packbits(chosen, axis=-1)}))
@@ -690,10 +895,11 @@ def prefill(params: Dict[str, Any], cfg: LlamaConfig, tokens: jax.Array,
     no pool behind it: nothing for the kernel to save).
     The experts' picks of this path are not counted."""
     dtype = cfg.dtype
-    if any(s.mixer != "attn" for s in cfg.layer_specs):
+    if any(s.mixer != "attn" or s.window for s in cfg.layer_specs):
         raise ValueError(
-            "a bucketed prefill hands back cache rows to scatter, and a "
-            "linear-attention layer keeps a state a slot: its prompts go "
+            "a bucketed prefill hands back cache rows to scatter into "
+            "blocks, and a linear-attention layer keeps a state a slot, a "
+            "window layer a ring a slot: their prompts go "
             "through the chunked path (InferenceEngine(prefill_chunk=...))")
     g, lp_len = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0)
@@ -701,16 +907,17 @@ def prefill(params: Dict[str, Any], cfg: LlamaConfig, tokens: jax.Array,
     one_page = jnp.zeros((g, 1), jnp.int32)
     counted = jnp.ones((g, lp_len), bool)
     rows, keys = [], []
-    for lp in params["layers"]:
+    for lp, spec in zip(params["layers"], cfg.layer_specs):
         h = _rmsnorm(x, lp["input_norm"], cfg.rms_norm_eps).astype(dtype)
-        qq, row, q_i, k_i, w = _projections(lp, h, cfg, pos, dtype)
+        qq, row, q_i, k_i, w = _projections(lp, h, cfg, spec, pos, dtype)
         # each prompt's own rows as a pool of one page
         o_lat, _ = jax.lax.map(
             lambda a: _attend_run(
                 a[0], a[1], a[2], a[3], a[4][None],
-                None if a[5] is None else a[5][None], a[6], cfg, 1),
+                None if a[5] is None else a[5][None], a[6], cfg, 1,
+                spec=spec),
             (qq, q_i, w, pos, row, k_i, one_page))
-        x = x + _attn_out(lp, o_lat, cfg, dtype)
+        x = x + _attn_out(lp, o_lat, cfg, dtype, h)
         h = _rmsnorm(x, lp["post_norm"], cfg.rms_norm_eps).astype(dtype)
         x = x + _mlp(lp, h, cfg, dtype, counted)[0]
         rows.append(row)

@@ -45,11 +45,32 @@ Generated tokens, speculative-verify slack and bucket-padding junk
 all land at positions >= the prompt's full-block prefix, which the
 allocator always backs with fresh blocks — so the only writers the
 COW machinery must police are the prefill paths above.
+
+A SECOND kind of cache under the same manager (``BlockManager.windows``,
+:class:`WindowStore`): a layer whose queries see the last ``w`` keys only
+(``LayerSpec.window``) keeps, a slot, a RING of ``ring`` blocks, the row
+of position ``p`` in ring block ``(p // block_size) % ring``.  No
+allocator, no table on the host: a ring is its slot's for as long as the
+slot lives, its bytes are ``slots x ring`` whatever ``num_blocks`` is,
+and the device computes a position-ordered table of the ring's blocks
+from the positions (:func:`ring_table`), under which the paged kernels
+stream them like any other pages, masked to the window.  What a ring
+holds of an earlier occupant, or of positions a wrap has passed, lies
+outside every window of the present one and is never attended.  Behind
+the rings the same pools hold SNAPSHOTS: the last ``w - 1`` rows of a
+prompt's shareable prefix (``keep`` blocks a window layer), copied out
+when a chunked prefill passes that boundary and copied into the ring of a
+later request that warm-starts there (``serving/engine.py
+_admit_chunked``): what the window layers need of a cached prefix where
+the full layers share its blocks.  A snapshot belongs to the committed
+block that ends at its boundary and goes when that block's registration
+goes; a sequence whose prefill would begin where no snapshot is held
+shares no block at all (``alloc_sequence``) and starts at 0.
 """
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -90,7 +111,11 @@ class BlockManager:
     equivalence suite."""
 
     def __init__(self, num_blocks: int, block_size: int,
-                 sharing: bool = True):
+                 sharing: bool = True,
+                 windows: Optional["WindowStore"] = None):
+        # the window layers' rings and snapshots (module docstring); None:
+        # the model has no window layer
+        self.windows = windows
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.sharing = bool(sharing)
@@ -126,7 +151,23 @@ class BlockManager:
             bid = self.index.evict_one()
         if bid is not None:
             self._pending.discard(bid)
+            self._drop_window(bid)
         return bid
+
+    def _drop_window(self, bid: int) -> None:
+        """``bid`` stands for no prefix any more: the window layers'
+        snapshot behind it, if any, goes with the registration."""
+        if self.windows is not None:
+            self.windows.drop(bid)
+
+    def window_entry(self, bid: int) -> Optional[int]:
+        """An entry for a NEW snapshot of the window layers' rows behind
+        block ``bid``; None where the block stands for no prefix (sharing
+        off, diverged), has its snapshot, or the store is full."""
+        if not self.index.is_committed(bid) \
+                or self.windows.lookup(bid) is not None:
+            return None
+        return self.windows.take(bid)
 
     def mark_pending(self, bids: List[int]) -> None:
         """Declare committed blocks whose KV write is IN FLIGHT (the
@@ -198,6 +239,15 @@ class BlockManager:
                 if bid is None:
                     break
                 shared.append((chain, bid))
+        cold = bool(shared) and self.windows is not None \
+            and not self.windows.warm([b for _, b in shared], prompt.size)
+        if cold:
+            # the window layers share no block: a sequence stands behind
+            # cached blocks only where a snapshot holds those layers'
+            # rows at the point its prefill would begin.  None there: it
+            # takes blocks of its own and starts at 0 (nothing is copied
+            # only to be overwritten; its blocks become the prefix's)
+            shared = []
         need = n_blocks - len(shared)
         # reviving a shared hit that currently lingers in the LRU also
         # consumes availability (it leaves the evictable set) — without
@@ -206,6 +256,8 @@ class BlockManager:
         revived = sum(1 for _, bid in shared if self._ref[bid] == 0)
         if need > self.available_blocks - revived:
             return None
+        if cold:
+            self.windows.cold_starts += 1
         blocks: List[int] = []
         for chain_h, bid in shared:
             if self._ref[bid] == 0:
@@ -244,6 +296,7 @@ class BlockManager:
                     # instead of letting a future hit map it
                     if self.index.is_committed(bid):
                         self.index.forget(bid)
+                        self._drop_window(bid)
                     self._pending.discard(bid)
                     self._free.append(bid)
 
@@ -271,6 +324,7 @@ class BlockManager:
         if self.index.is_committed(bid):
             self.index.forget(bid)
             self._pending.discard(bid)
+            self._drop_window(bid)
         return bid, False
 
     # ------------------------------------------------------------ books
@@ -315,6 +369,142 @@ class BlockManager:
             assert self.index.is_committed(bid), (
                 f"uncommitted block {bid} lingering in LRU")
         return True
+
+
+# ------------------------------------------------------- window rings
+class RingGeometry(NamedTuple):
+    """A window layer's cache a slot (module docstring), in blocks."""
+
+    window: int        # keys a query sees, itself counted
+    chunk: int         # queries of a prompt chunk
+    block_size: int
+    ring: int          # blocks a slot and window layer
+    reach: int         # blocks one query's window touches at most
+    keep: int          # blocks of a snapshot: the last ``window - 1`` rows
+
+    @property
+    def rows(self) -> int:
+        return self.ring * self.block_size
+
+
+def ring_geometry(window: int, chunk: int, block_size: int) -> RingGeometry:
+    """The ring of a layer of window ``window`` whose prompts arrive in
+    chunks of ``chunk`` queries that start at multiples of it: a chunk
+    needs the ``window - 1`` rows behind it and writes its own, so
+    ``ceil((window - 1) / block) + ceil(chunk / block)`` blocks, one more
+    where chunks do not start on a block's first row; never more than
+    ``ceil((window - 1 + chunk) / block) + 1``."""
+    back = -(-(window - 1) // block_size)
+    odd = 1 if chunk % block_size else 0
+    ring = min(back + -(-chunk // block_size) + odd,
+               -(-(window - 1 + chunk) // block_size) + 1)
+    return RingGeometry(window, chunk, block_size, ring, back + 1,
+                        back + odd)
+
+
+def warm_start(shared: int, prompt: int, chunk: int) -> int:
+    """Where the chunked prefill of a prompt of ``prompt`` tokens begins
+    behind ``shared`` cached positions: the last chunk boundary inside
+    them, the FINAL chunk kept live even where the whole prompt is shared
+    (sampling the first token needs one real dispatch)."""
+    return min(shared // chunk * chunk, (prompt - 1) // chunk * chunk)
+
+
+class WindowStore:
+    """Host-side books of the window layers' caches, a layer two arrays:
+    the rings ``[slots, ring, block_size, W]`` and the snapshots
+    ``[snapshots, keep, block_size, W]``: which ring blocks hold a
+    prefix's last rows, and which prefix each snapshot belongs to.
+
+    A snapshot belongs to the prefix index's BLOCK that ends where it was
+    taken (a committed block stands for the whole prefix behind it), and
+    lives as long as that block's registration: ``BlockManager`` drops it
+    with the block, so the index's LRU is the one policy.  A full store
+    keeps nothing new until the index lets a block go; half the slots'
+    number of snapshots (four at least) holds the prefixes that requests
+    in flight can stand behind."""
+
+    def __init__(self, geometry: RingGeometry, slots: int, sharing: bool):
+        self.geometry = geometry
+        self.slots = int(slots)
+        self.snapshots = max(4, self.slots // 2) if sharing else 0
+        self.cold_starts = 0     # sequences a missing snapshot kept from
+        #                          standing behind cached blocks
+        self._entry_of: Dict[int, int] = {}     # block id -> snapshot
+        self._free = list(range(self.snapshots))[::-1]
+
+    def ring_blocks(self, end: int) -> np.ndarray:
+        """Which blocks of a slot's ring hold the last ``window - 1``
+        positions before ``end``, in position order: ``keep`` indices,
+        ``ring`` (no block: a write there is dropped) behind the last."""
+        g = self.geometry
+        first = max(0, end - (g.window - 1)) // g.block_size
+        ids = [ab % g.ring for ab in range(first, -(-end // g.block_size))]
+        return np.asarray(ids + [g.ring] * (g.keep - len(ids)), np.int32)
+
+    def lookup(self, bid: int) -> Optional[int]:
+        """The snapshot of the prefix that block ``bid`` ends; None where
+        there is none."""
+        return self._entry_of.get(bid)
+
+    def warm(self, shared: List[int], prompt: int) -> bool:
+        """Are the window layers' rows held where a prompt of ``prompt``
+        tokens would begin its prefill behind the cached blocks
+        ``shared`` (:func:`warm_start`)?"""
+        g = self.geometry
+        start = warm_start(len(shared) * g.block_size, prompt, g.chunk)
+        return start > 0 and shared[start // g.block_size - 1] \
+            in self._entry_of
+
+    def take(self, bid: int) -> Optional[int]:
+        """An entry for a new snapshot behind block ``bid`` (None: the
+        store is full)."""
+        if not self._free:
+            return None
+        self._entry_of[bid] = self._free.pop()
+        return self._entry_of[bid]
+
+    def drop(self, bid: int) -> None:
+        """Block ``bid`` stands for no prefix any more."""
+        entry = self._entry_of.pop(bid, None)
+        if entry is not None:
+            self._free.append(entry)
+
+    def resident_rows(self, length) -> np.ndarray:
+        """Rows of sequences of ``length`` positions that their rings
+        hold, a window layer."""
+        return np.minimum(np.asarray(length), self.geometry.rows)
+
+
+def ring_table(slots: jax.Array, first_pos: jax.Array, blocks: int,
+               window: int, ring: int, block_size: int):
+    """``(table [B, blocks], base [B])``: for each row, blocks of the ring
+    of slot ``slots[b]`` (of a pool ``[slots x ring, block_size, W]``) in
+    POSITION order, from the block that holds the first key a query at
+    ``first_pos[b]`` sees, and the position of that table's first row.  A
+    block ahead of the newest position holds what a wrap left there: the
+    caller's mask is by position."""
+    fb = jnp.maximum(first_pos - (window - 1), 0) // block_size
+    ab = fb[:, None] + jnp.arange(blocks)[None, :]
+    table = slots[:, None] * ring + ab % ring
+    return table.astype(jnp.int32), (fb * block_size).astype(jnp.int32)
+
+
+@device_scoped("kv_write")
+def scatter_ring(pool: jax.Array, slots: jax.Array, rows: jax.Array,
+                 positions: jax.Array, real: jax.Array,
+                 ring: int) -> jax.Array:
+    """Write ``rows`` [B, K, W], the rows of positions ``positions[b] ..
+    + K - 1``, into the rings of ``slots`` [B] in ``pool`` [slots x ring,
+    block_size, W]; a row ``real`` [B, K] does not mark (a parked slot's,
+    what pads a chunk) is written nowhere."""
+    b, k = rows.shape[:2]
+    bs = pool.shape[1]
+    pos = positions[:, None] + jnp.arange(k)[None, :]
+    bid = slots[:, None] * ring + (pos // bs) % ring
+    bid = jnp.where(real, bid, pool.shape[0])
+    return pool.at[bid.reshape(-1), (pos % bs).reshape(-1)].set(
+        rows.reshape(b * k, rows.shape[-1]), mode="drop")
 
 
 # ---------------------------------------------------------------- device

@@ -134,19 +134,22 @@ def serving_params_from_llama(
             "and key in serving/model.py::_attn_proj (ROADMAP Reach A3)")
     attn = {dataclasses.replace(s, mlp="dense") for s in cfg.layer_specs
             if s.mixer == "attn"}
-    if cfg.attn_head_gate or len(attn) > 1 or any(
-            s.window for s in attn) or (
-            cfg.layers is not None and not cfg.kv_lora_rank):
+    if not cfg.kv_lora_rank and (
+            cfg.attn_head_gate or len(attn) > 1 or any(
+                s.window for s in attn) or cfg.layers is not None):
         raise ValueError(
-            "the serving engine's attention layers are ONE kind of layer: "
-            "full causal attention with one head count and one rotary "
-            f"embedding, no head gate (attn_head_gate={cfg.attn_head_gate}"
-            f"); this model describes {len(attn)} kinds of attention "
-            "layer.  Layers that differ are served as latent attention "
-            "beside linear attention only (LayerSpec.mixer, "
-            "serving/latent.py).  Missing: a window in the paged kernels "
-            "and the cache manager (ROADMAP A4), per-layer head counts, "
-            "partial rotary and YaRN in serving/model.py (A3)")
+            "the serving engine's grouped-query layers are ONE kind of "
+            "layer: full causal attention with one head count and one "
+            f"rotary embedding, no head gate (attn_head_gate="
+            f"{cfg.attn_head_gate}); this model describes {len(attn)} kinds "
+            "of attention layer.  Layers that differ (head counts, a "
+            "window, a head gate, their rotary embedding) are served as "
+            "LATENT attention only (LayerSpec, serving/latent.py), beside "
+            "linear attention (LayerSpec.mixer).  Missing behind the "
+            "grouped-query block: a lower bound on the keys in "
+            "ops/pallas/paged_attention.py and window rows in its cache "
+            "(ROADMAP A4), per-layer head counts, a head gate, partial "
+            "rotary and YaRN in serving/model.py (A3)")
     if cfg.num_experts and not cfg.kv_lora_rank:
         raise ValueError(
             f"sparse experts (num_experts={cfg.num_experts}) are served "
@@ -211,11 +214,13 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
     from a ``layer_{i}`` tree named as ``perfbench/reference_glm5.py``,
     ``perfbench/reference_sarvam.py`` and the tests make it: ``attn`` (the
     query through a bottleneck, ``q_a_proj``, ``q_a_norm``, ``q_b_proj``
-    [Q, H, nope + rope], or with ``q_lora_rank`` 0 straight from the
+    [Q, H, nope + rope] (a layer's own ``nope``, heads and latent rank where
+    its ``LayerSpec`` has them), or with ``q_lora_rank`` 0 straight from the
     hidden state, ``q_proj`` [E, H, nope + rope]; with ``qk_norm`` a
     ``q_norm`` scale [nope + rope] for every head; ``kv_a_proj`` [E, C +
     rope], ``kv_a_norm``, ``kv_b_proj`` [C, H, nope + V], ``o_proj`` [H,
-    V, E]), with ``index_topk`` an ``indexer``
+    V, E], with ``attn_head_gate`` a ``g_proj`` [E, H]), with
+    ``index_topk`` in a layer whose ``LayerSpec.indexer`` says so an ``indexer``
     (``wq_b`` [Q, Hi, Di], ``wk``, ``k_norm`` scale and bias,
     ``weights_proj``); in place of ``attn`` a layer whose
     ``LayerSpec.mixer`` is "kda" has ``kda`` (``serving/linear.py
@@ -233,7 +238,6 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
             "model has the one without the other")
     variables = nn.meta.unbox(variables)
     params = variables["params"] if "params" in variables else variables
-    nope = cfg.qk_nope_head_dim
 
     def mat(w):
         return jnp.asarray(w, dtype)
@@ -250,6 +254,7 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
             raise ValueError(f"no served mixer {spec.mixer!r}: a layer is "
                              "'attn' or 'kda' (LayerSpec.mixer)")
         a = p["attn"]
+        _, nope, indexed = cfg.latent_dims(spec)
         kv_b = mat(a["kv_b_proj"]["kernel"])             # [C, H, nope+V]
         out = {
             "wkv_a": mat(a["kv_a_proj"]["kernel"]),
@@ -267,7 +272,9 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
             out["wq_t"] = flat_out(a["q_proj"]["kernel"]).T
         if cfg.qk_norm:
             out["q_norm"] = a["q_norm"]["scale"]
-        if cfg.index_topk:
+        if cfg.attn_head_gate:
+            out["head_gate"] = mat(a["g_proj"]["kernel"])     # [E, H]
+        if indexed:
             ix = p["indexer"]
             out.update(
                 iwq=flat_out(ix["wq_b"]["kernel"]),

@@ -140,6 +140,10 @@ class RouterMetrics:
         self.moe_picks_held = 0.0
         self.prefill_query_tiles = 0.0
         self.prefill_query_tiles_live = 0.0
+        self.window_rows_in_window = 0.0
+        self.window_rows_streamed = 0.0
+        self.window_cache_bytes = 0.0
+        self.cache_bytes = 0.0
         self.dispatches = 0.0
         self.chained_dispatches = 0.0
         # prefix-cache fleet aggregates (engine-side COW ledger summed
@@ -306,7 +310,9 @@ class RouterMetrics:
         for name in ("dsa_rows_live", "attn_rows_selected", "moe_picks",
                      "moe_picks_held", "prefill_query_tiles",
                      "prefill_query_tiles_live", "dispatches",
-                     "chained_dispatches"):
+                     "chained_dispatches", "window_rows_in_window",
+                     "window_rows_streamed", "window_cache_bytes",
+                     "cache_bytes"):
             setattr(self, name, sum(d.get(name, 0.0) for d in dicts))
         for attr, key in (
             ("prefix_hits", "prefix_hits"),
@@ -425,6 +431,12 @@ class RouterMetrics:
             "serving_prefill_live_tile_share": (
                 self.prefill_query_tiles_live / self.prefill_query_tiles
                 if self.prefill_query_tiles else 0.0),
+            "serving_window_stream_ratio": (
+                self.window_rows_streamed / self.window_rows_in_window
+                if self.window_rows_in_window else 0.0),
+            "serving_window_cache_share": (
+                self.window_cache_bytes / self.cache_bytes
+                if self.cache_bytes else 0.0),
             "serving_sched_capacity_evals_total":
                 self.sched_capacity_evals,
             "serving_sched_rounds_skipped_total":

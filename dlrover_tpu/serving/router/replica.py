@@ -186,8 +186,15 @@ class InferenceEngineAdapter:
             for name in ("dsa_rows_live", "attn_rows_selected",
                          "moe_picks", "moe_picks_held",
                          "prefill_query_tiles",
-                         "prefill_query_tiles_live"):
+                         "prefill_query_tiles_live",
+                         "window_rows_in_window", "window_rows_streamed"):
                 out[name] = float(getattr(st, name, 0))
+            # window layers' rings beside the pools of blocks (0 for a
+            # model with no window layer)
+            kinds = getattr(eng, "cache_nbytes_by_kind", None)
+            if kinds is not None:
+                out["window_cache_bytes"] = float(kinds["window"])
+                out["cache_bytes"] = float(sum(kinds.values()))
             # prefix-cache ledger (all-float, so the dict still rides
             # STATS frames as-is); dense engines have no sharing
             prefix = getattr(eng, "prefix_stats", None)

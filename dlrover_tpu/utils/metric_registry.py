@@ -212,6 +212,20 @@ METRIC_HELP: Dict[str, str] = {
         "rest of a chunk still computes; 0 = no replica serves such a "
         "model"
     ),
+    "serving_window_stream_ratio": (
+        "latent rows the window layers' decode attention read over the "
+        "rows inside their queries' windows, fleet-wide (LayerSpec.window: "
+        "a ring of blocks a slot, streamed through a position-ordered "
+        "table): 1.0 = a decode reads the window and no more, whole "
+        "blocks read 1.25 at a window of 513 in blocks of 128; 0 = no "
+        "replica serves window layers"
+    ),
+    "serving_window_cache_share": (
+        "bytes of the window layers' rings and kept prefixes over all "
+        "cache bytes (pools of blocks, rings, recurrent state), "
+        "fleet-wide: the rings are sized by slots x window and do not "
+        "grow with cache_blocks; 0 = no replica serves window layers"
+    ),
     "serving_moe_held_share": (
         "router picks that fell on the experts a replica holds over all "
         "its picks, fleet-wide (a replica that is one share of an "

@@ -323,8 +323,11 @@ def test_the_blocks_refuse_a_bucketed_prefill_and_a_verify(cfg, params_of):
     mixed = dataclasses.replace(cfg, layers=tuple(
         dataclasses.replace(s, window=8 if i == 3 else 0)
         for i, s in enumerate(cfg.layer_specs)))
-    with pytest.raises(ValueError, match="ONE kind of layer"):
-        serving_params_from_llama({"params": params}, mixed)
+    # (a window in a LATENT layer is served since PR 47: a ring a slot,
+    # tests/test_dots3_serving.py; behind the grouped-query block it is
+    # still refused, tests/test_olmoe_reference.py)
+    assert len(serving_params_from_llama(
+        {"params": params}, mixed)["layers"]) == cfg.num_layers
     with pytest.raises(ValueError, match="no served mixer"):
         serving_params_from_llama(
             {"params": params}, dataclasses.replace(cfg, layers=tuple(
@@ -481,18 +484,63 @@ def sarvam_program_text(program):
     return re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
 
 
-@pytest.mark.parametrize("program", sorted(_SARVAM))
-def test_sarvam_traces_what_it_did(program, tmp_path):
-    """The decode forward, the prompt chunk and the bucketed prefill of a
-    latent model with NO linear-attention layer are, to the letter, the
-    programs the tree before the layer loop's dispatch traced
-    (``serve-longctx-decode`` compiles what it compiled): the kind of
-    mixer, the rotation that is off and the per-slot state are all chosen
-    by what the model has, at trace time.  A change that means to move
+# Kimi-Linear's own served programs as the PARENT of PR 47 traced them (the
+# tiny preset above in bf16): a layer's geometry, the rescale, the gate and
+# the window are all chosen by what the model has, at trace time.
+_KIMI = {
+    "decode": (4492, "61977bcde222d64d5b6427188fec20dea61ce1a001e5f668ed1a"
+                     "06a413741b18"),
+    "prefill_chunk": (20243, "869c8ce402d8ff9b3ceecb569745eac40e287fa8f7d0"
+                             "285d3cea4bc1c0a698ca"),
+}
+
+
+def kimi_program_text(program):
+    from dlrover_tpu.serving.linear import state_shapes
+
+    cfg = tiny(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    sp = jax.eval_shape(lambda: serving_params_from_llama(
+        {"params": SeededKimiLinearParams(cfg, 3)}, cfg))
+    S = jax.ShapeDtypeStruct
+    b, nb, bs, mb = 2, 9, 8, 4
+    kda = sum(s.mixer == "kda" for s in cfg.layer_specs)
+    state, conv = state_shapes(cfg, b)
+    cache = {
+        "latent_pool": [S((nb, bs, latent.latent_row_width(cfg)),
+                          jnp.bfloat16)] * (cfg.num_layers - kda),
+        "kda_state": [S(state, jnp.float32)] * kda,
+        "kda_conv": [S(conv, jnp.bfloat16)] * kda,
+        "table": S((b, mb), jnp.int32), "moe_picks": S((2,), jnp.uint32),
+        "watch_slot": S((), jnp.int32)}
+    ints = lambda *shape: S(shape, jnp.int32)  # noqa: E731
+    kernels = dict(attention_impl="pallas", kernel_interpret=True)
+    if program == "decode":
+        jaxpr = jax.make_jaxpr(
+            lambda p, c, t, pos, act: latent.verify_step(
+                p, cfg, c, t, pos, active=act, **kernels))(
+            sp, cache, ints(b, 1), ints(b), S((b,), jnp.bool_))
+    else:
+        jaxpr = jax.make_jaxpr(
+            lambda p, c, t, pos, sl, li: latent.verify_step(
+                p, cfg, c, t, pos, slots=sl, logits_index=li, **kernels))(
+            sp, cache, ints(1, 64), ints(1), ints(1), ints(1))
+    return re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+
+
+@pytest.mark.parametrize("model, program", [
+    ("kimi", p) for p in sorted(_KIMI)] + [
+    ("sarvam", p) for p in sorted(_SARVAM)])
+def test_the_older_models_trace_what_they_did(model, program, tmp_path):
+    """One pin for the served programs of the latent models this file can
+    build (GLM-5's are ``tests/test_sarvam_serving.py
+    test_glm5_traces_what_it_did``): to the letter what the parent of the
+    PR that last meant to move them traced.  A change that means to move
     them, or a JAX that prints them otherwise, re-pins: the text is left
     in a file to diff."""
-    text = sarvam_program_text(program)
-    (tmp_path / f"{program}.txt").write_text(text)
+    pins, text_of = {"kimi": (_KIMI, kimi_program_text),
+                     "sarvam": (_SARVAM, sarvam_program_text)}[model]
+    text = text_of(program)
+    (tmp_path / f"{model}.{program}.txt").write_text(text)
     got = (len(text.splitlines()), hashlib.sha256(text.encode()).hexdigest())
-    assert got == _SARVAM[program], \
-        f"jax {jax.__version__}; the trace: {tmp_path / program}.txt"
+    assert got == pins[program], \
+        f"jax {jax.__version__}; the trace: {tmp_path}/{model}.{program}.txt"

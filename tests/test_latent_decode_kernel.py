@@ -201,3 +201,62 @@ def test_the_unmasked_call_is_the_parents_and_an_all_chosen_masks_bits():
         got = mla_decode.mla_decode_attention(*args, **kw)
         assert (np.asarray(got) == np.asarray(
             mla_decode.mla_decode_attention(*args, everything, **kw))).all()
+
+
+# ------------------------------------------------- a window layer's rings
+def _rings(positions, window, ring, seed=0, slots=None):
+    """Rings ``[slots x ring, BS, W]`` in which the row of position ``p``
+    of slot ``s`` (every ``p`` up to ``positions[s]``) sits where
+    ``serving/paged.py`` puts it, a later position over an earlier one;
+    every other row LOUD.  Returns the pool and, a slot, its rows by
+    position."""
+    rng = np.random.RandomState(seed)
+    slots = slots or len(positions)
+    pool = np.full((slots * ring, BS, W), 64.0, np.float32)
+    rows = []
+    for s, last in enumerate(positions):
+        mine = rng.randn(last + 1, W).astype(np.float32)
+        mine[:, C + 8:] = 0.0
+        for p in range(last + 1):
+            pool[s * ring + (p // BS) % ring, p % BS] = mine[p]
+        rows.append(mine)
+    return pool, rows
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize("positions, window, ring", [
+    ((0, 5, 8, 16), 9, 2),          # inside the window, at it, past it
+    ((40, 3, 95, 64), 17, 4),       # rings that have wrapped many times
+    ((30, 31, 32, 33), 10, 4),      # a window that is no whole blocks
+])
+def test_a_window_decode_reads_its_window_of_the_ring(positions, window,
+                                                      ring, impl):
+    """``serving/latent.py _attend_window_decode``: the decode kernel
+    again (by its window's name) and its oracle, over a position-ordered
+    table of the ring's blocks under the window's mask, are the dense
+    softmax over the last ``window`` rows; an idle slot reads nothing."""
+    from dlrover_tpu.models.llama import LayerSpec, LlamaConfig
+    from dlrover_tpu.serving import latent
+
+    cfg = LlamaConfig(kv_lora_rank=C, qk_nope_head_dim=int(SCALE ** -2) - 8,
+                      qk_rope_head_dim=8, v_head_dim=8, num_heads=HEADS)
+    spec = LayerSpec(num_heads=HEADS, window=window)
+    scale = latent._softmax_scale(cfg, spec)
+    pool, rows = _rings(positions, window, ring)
+    rng = np.random.RandomState(7)
+    qq = rng.randn(len(positions), HEADS, W).astype(np.float32)
+    qq[..., C + 8:] = 0.0
+    active = np.array([True] * (len(positions) - 1) + [False])
+    got = np.asarray(latent._attend_window_decode(
+        jnp.asarray(qq), jnp.asarray(pool), jnp.asarray(positions),
+        jnp.asarray(active), cfg, spec, ring, impl, True))
+    for s, last in enumerate(positions):
+        if not active[s]:
+            np.testing.assert_array_equal(got[s], 0.0)
+            continue
+        keys = rows[s][max(0, last - window + 1):last + 1]
+        sc = qq[s] @ keys.T * scale
+        p = np.exp(sc - sc.max(-1, keepdims=True))
+        np.testing.assert_allclose(
+            got[s], (p / p.sum(-1, keepdims=True)) @ keys[:, :C],
+            atol=2e-5)

@@ -195,3 +195,49 @@ def test_a_chunk_through_the_kernel_is_the_chunk_through_the_loop():
         for a, b in zip(cache_p[name], cache_x[name]):
             np.testing.assert_allclose(a, b, atol=2e-5)
     np.testing.assert_array_equal(cache_p["moe_picks"], cache_x["moe_picks"])
+
+
+# ------------------------------------------------- a window layer's rings
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize("start, klen, n_real, window, ring", [
+    (0, 8, None, 9, 2),       # a prompt's first chunk
+    (24, 8, None, 9, 2),      # a later one: the ring has wrapped
+    (32, 16, 11, 17, 4),      # a last chunk, padded behind 11 queries
+    (64, 16, None, 10, 4),    # a window that is no whole blocks
+])
+def test_a_window_run_reads_each_querys_window_of_the_ring(
+        start, klen, n_real, window, ring, impl):
+    """``serving/latent.py _attend_window_run``: the run kernel (by its
+    window's name) over the ring's pages in position order, and the dense
+    path, are each query's softmax over the last ``window`` rows; a padded
+    query attends nothing; what the ring holds of positions a wrap has
+    passed, or ahead of the run, is LOUD and never read."""
+    from dlrover_tpu.models.llama import LayerSpec, LlamaConfig
+    from dlrover_tpu.serving import latent
+    from tests.test_latent_decode_kernel import BS, C, HEADS, W, _rings
+
+    cfg = LlamaConfig(kv_lora_rank=C, qk_nope_head_dim=8,
+                      qk_rope_head_dim=8, v_head_dim=8, num_heads=HEADS)
+    spec = LayerSpec(num_heads=HEADS, window=window)
+    scale = latent._softmax_scale(cfg, spec)
+    real = klen if n_real is None else n_real
+    slot = 2
+    pool, rows = _rings((0, 0, start + real - 1), window, ring, slots=3)
+    rng = np.random.RandomState(5)
+    qq = rng.randn(klen, HEADS, W).astype(np.float32)
+    qq[..., C + 8:] = 0.0
+    got = np.asarray(latent._attend_window_run(
+        jnp.asarray(qq), start + jnp.arange(klen), jnp.asarray(pool),
+        jnp.asarray(slot), None if n_real is None else jnp.asarray(n_real),
+        cfg, spec, ring, impl, True))
+    for k in range(klen):
+        if k >= real:
+            np.testing.assert_array_equal(got[k], 0.0)
+            continue
+        t = start + k
+        keys = rows[slot][max(0, t - window + 1):t + 1]
+        sc = qq[k] @ keys.T * scale
+        p = np.exp(sc - sc.max(-1, keepdims=True))
+        np.testing.assert_allclose(
+            got[k], (p / p.sum(-1, keepdims=True)) @ keys[:, :C],
+            atol=2e-5)

@@ -289,6 +289,69 @@ def _decode_kernel_operands(text: str) -> list:
         "operand_layout_constraints={")[1].split("}}")[0]) for line in calls]
 
 
+def test_window_layer_kernels_compile_at_dots3_widths(one_chip):
+    """A WINDOW layer of ``dots3-note-serve`` at the cell's geometry (64
+    heads, rows of 1 152 over a latent of 1 024, rings of 8 pages of 128
+    rows a slot, a window of 513): the decode forward's attention is the
+    decode kernel over the 5 pages a window touches, a prompt chunk's the
+    run kernel over the ring, each ONE custom call under ``swa_attn`` by a
+    name of its own; and a FULL layer's run kernel at 128 heads (16
+    queries a tile) fits the chip's fast memory."""
+    from dlrover_tpu.models.llama import LlamaConfig
+    from dlrover_tpu.serving import latent
+    from dlrover_tpu.utils.profiler import device_scope, parse_program
+
+    cfg = LlamaConfig.dots3_note(num_layers=6, dtype=jnp.bfloat16)
+    full, spec = cfg.layer_specs[1], cfg.layer_specs[2]
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def decode(qq, pool, pos, active):
+        with device_scope("decode_chunk"):
+            return latent._attend_window_decode(
+                qq, pool, pos, active, cfg, spec, 8, "pallas", False)
+
+    def run(qq, q_pos, pool, slot, n_real):
+        with device_scope("prefill_chunk"):
+            return latent._attend_window_run(
+                qq, q_pos, pool, slot, n_real, cfg, spec, 8, "pallas",
+                False)
+
+    def full_run(qq, q_i, w, q_pos, lat, idx, table, n_real):
+        with device_scope("prefill_chunk"):
+            return latent._attend_run(
+                qq, q_i, w, q_pos, lat, idx, table, cfg,
+                latent.KEY_BLOCK_PAGES, "pallas", n_real=n_real, spec=full)
+
+    rings = s((32 * 8, 128, 1152), jnp.bfloat16)
+    for fn, args, scopes, kernel, scope in (
+        (decode, (s((32, 64, 1152), jnp.bfloat16), rings,
+                  s((32,), jnp.int32), s((32,), jnp.bool_)),
+         {"decode_chunk", "swa_attn"}, "mla_window_decode_attn",
+         "swa_attn"),
+        (run, (s((512, 64, 1152), jnp.bfloat16), s((512,), jnp.int32),
+               rings, s((), jnp.int32), s((), jnp.int32)),
+         {"prefill_chunk", "swa_attn"}, "mla_window_prefill_attn",
+         "swa_attn"),
+        (full_run, (s((512, 128, 640), jnp.bfloat16),
+                    s((512, 64, 128), jnp.bfloat16),
+                    s((512, 64), jnp.float32), s((512,), jnp.int32),
+                    s((2760, 128, 640), jnp.bfloat16),
+                    s((2760, 128, 128), jnp.bfloat16), s((256,), jnp.int32),
+                    s((), jnp.int32)),
+         {"prefill_chunk", "dsa_index", "dsa_select", "mla_attn"},
+         "mla_prefill_attn", "mla_attn")):
+        lowered = jax.jit(fn).lower(*args)
+        table = parse_program("window", lowered.compile().as_text(), scopes,
+                              lowered.as_text(debug_info=True))
+        assert table.complete, table.missing
+        kernels = {n: sc for n, sc in table.scope_of.items()
+                   if n.split(".")[0] == kernel}
+        assert kernels and set(kernels.values()) == {scope}, (
+            kernel, table.scope_of)
+
+
 def test_glm5_decode_forward_streams_under_the_mask(one_chip, monkeypatch):
     """One decode forward of ``glm5-serve`` at the cell's widths (32 slots,
     tables of 258 pages of 128 rows, experts 0-15 of 256, an eighth of the
