@@ -15,7 +15,10 @@ batch that sequences enter and leave independently —
   one prompt chunk of what still prefills, the decode chunk) before it
   waits for any: each slot's last token passes from program to program
   on the device, and the host reads every result once, in dispatch
-  order, behind the last dispatch (``InferenceEngine.step``);
+  order, behind the last dispatch; the decode chunk it leaves UNREAD
+  for the next step, which reads it behind its own dispatches, so the
+  device has the next chunk queued while the host reads, delivers and
+  dispatches (``InferenceEngine.step``);
 - ``int8=True`` serves pre-quantized int8 weights through XLA's native
   int8 MXU dot (weights stream from HBM at half the bf16 bytes — decode
   is bandwidth-bound, so this is the serving speedup; measured against
@@ -105,9 +108,17 @@ class EngineStats:
     #                               prefill_seconds (the stall-bound
     #                               budget)
     dispatches: int = 0           # programs sent to the device
-    chained_dispatches: int = 0   # ... while an earlier one of the same
-    #                               step was still unread: its host
-    #                               preparation cost the device no gap
+    chained_dispatches: int = 0   # ... while an earlier one was still
+    #                               unread (of the same step, or the
+    #                               decode chunk the step before left in
+    #                               flight): its host preparation cost
+    #                               the device no gap
+    lookahead_steps: int = 0      # steps that returned with their decode
+    #                               chunk unread
+    wasted_lane_chunks: int = 0   # decode-chunk lanes whose request had
+    #                               ended (an end-of-sequence, read one
+    #                               chunk late) before the chunk was
+    #                               read: their tokens were dropped
     # a latent-attention model's prompt chunks (one layer's, as every
     # layer walks the same): host arithmetic from each chunk's REAL end
     # (what pads a prompt's last chunk to the program's shape attends
@@ -221,9 +232,9 @@ class EngineStats:
     @property
     def chained_dispatch_share(self) -> float:
         """Programs dispatched behind an unread one over all programs
-        dispatched: how often a step's queue on the device hid the
-        host's preparation of the next program (0.0 where every step is
-        one program)."""
+        dispatched: how often the device's queue hid the host's
+        preparation of the next program (near 1.0 while steps look
+        ahead: only a dispatch into an idle engine is not chained)."""
         return self.chained_dispatches / self.dispatches \
             if self.dispatches else 0.0
 
@@ -276,12 +287,25 @@ class EngineStats:
 class _Unread:
     """Programs dispatched and not yet read (one bucketed prefill, one
     step's prompt chunks, one decode chunk): what ``_read_results``
-    needs to time them and hand their tokens on."""
+    needs to time them and hand their tokens on.  A decode chunk
+    outlives the step that dispatched it, so whatever its delivery needs
+    travels here: the slots it advanced may hold other requests by
+    then."""
     name: str                     # span ``dlrover.engine.<name>``
     attrs: Dict[str, Any]         # ... and its attributes
     started: float                # host clock at the dispatch's start
     outputs: Any                  # device arrays the host has to read
-    deliver: Callable[[Any], None]  # the bookkeeping that needs them
+    # the bookkeeping that needs them, ``deliver(rows, values)``, and the
+    # lanes it is for: tuples of (slot, request, ...), which ``cancel``
+    # thins
+    deliver: Callable[[List[tuple], Any], None]
+    rows: List[tuple]
+    # ``witness_log`` entries of these programs (``watch``): in the log
+    # once the programs are read, never while they run
+    witness: List[Dict[str, Any]] = dataclasses.field(default_factory=list)
+    # the experts' pick counters as these programs left them (a copy
+    # beside the donated cache's; None for a model that counts none)
+    picks: Any = None
 
 
 # the ``EngineStats`` clocks a program's time adds to, by ``_Unread.name``
@@ -734,16 +758,22 @@ class InferenceEngine:
         self._ctx_len = np.zeros(self.max_slots, np.int32)
         self._positions = np.zeros(self.max_slots, np.int32)
         self._tokens = np.zeros(self.max_slots, np.int32)
-        # ``_tokens`` as the step's programs hand it on to each other on
-        # the device: a CACHE of the host's copy, which is whole again
-        # after every ``_read_results``.  None = not valid (whatever
-        # writes ``_tokens`` outside that chain drops it): the next
-        # dispatch uploads the host's
+        # ``_tokens`` as the programs hand it on to each other on the
+        # device: a CACHE of the host's copy, which is whole again once
+        # everything in flight is read.  None = not valid (whatever
+        # writes ``_tokens`` outside that chain reads everything first,
+        # then drops it): the next dispatch uploads the host's
         self._last_dev: Optional[jax.Array] = None
-        # what the current step has dispatched and not read, in dispatch
-        # order, and how many programs that is
+        # what has been dispatched and not read, in dispatch order (between
+        # steps: the last step's decode chunk), and how many programs
+        # that is
         self._unread: List[_Unread] = []
         self._in_flight = 0
+        # whether the oldest program in flight was dispatched with nothing
+        # ahead of it (every other one is chained), and the host clock at
+        # which the last result read reached the host
+        self._head_alone = False
+        self._reached = 0.0
         self._remaining = np.zeros(self.max_slots, np.int32)
         self._queue: deque[Request] = deque()
         self._finished: List[Request] = []
@@ -897,8 +927,12 @@ class InferenceEngine:
                         length=n_steps,
                     )
             # ``witness``: the watched slot's forwards, one a step (None
-            # for a model that keeps none: ``watch``)
-            return out.T, tokens, positions, cache, rng, witness
+            # for a model that keeps none: ``watch``).  Last, the experts'
+            # pick counters a second time, outside the donated cache (None
+            # for a model that counts none): the host reads them with this
+            # chunk's tokens, while the cache's own is the next program's
+            return (out.T, tokens, positions, cache, rng, witness,
+                    cache.get("moe_picks"))
 
         paged = self.paged
         pool_names = self._pool_names
@@ -1011,7 +1045,8 @@ class InferenceEngine:
                         logits[:, 0, :], sub, temperature, top_k, top_p)
                 last = last.at[slots].set(
                     jnp.where(final, first.astype(last.dtype), 0))
-                return cache, first, rng, witness, last
+                return (cache, first, rng, witness, last,
+                        cache.get("moe_picks"))
 
             self._prefill_chunk_fn = prefill_chunk_fn
 
@@ -1085,8 +1120,7 @@ class InferenceEngine:
         ``verify``, ``prefill_chunk.g<G>``, ``prefill.g<G>.b<bucket>``),
         so a trace of this engine can be read by the program's own
         device scopes.  Returns the number of programs run."""
-        assert not self._queue and all(
-            r is None for r in self._slot_req), "warmup needs an idle engine"
+        assert not self.has_work, "warmup needs an idle engine"
         rng, b = self._rng, self.max_slots
 
         def zeros(*shape):
@@ -1099,7 +1133,7 @@ class InferenceEngine:
             register_program(label, lambda: program_texts(fn, *shapes))
             return fn(*args)
 
-        _, _, _, self._cache, _, _ = run(
+        _, _, _, self._cache, _, _, _ = run(
             "decode_chunk", self._chunk_fn,
             self.params, self._cache, zeros(b), zeros(b),
             jnp.zeros(b, bool), rng)
@@ -1117,7 +1151,7 @@ class InferenceEngine:
         for g in range(1, b + 1):
             slots = jnp.arange(g, dtype=jnp.int32)
             if chunked and g <= self._prefill_group:
-                self._cache, _, _, _, _ = run(
+                self._cache, _, _, _, _, _ = run(
                     f"prefill_chunk.g{g}", self._prefill_chunk_fn,
                     self.params, self._cache,
                     zeros(g, self.prefill_chunk), zeros(g), slots,
@@ -1171,8 +1205,9 @@ class InferenceEngine:
         return rid
 
     def _admit(self) -> None:
-        """Admit waiting requests and read their first tokens: for a
-        caller outside ``step``, which dispatches more before it reads."""
+        """Admit waiting requests and read their first tokens (and
+        whatever else is in flight): for a caller outside ``step``, which
+        dispatches more before it reads."""
         self._dispatch_admissions()
         self._read_results()
 
@@ -1191,7 +1226,9 @@ class InferenceEngine:
         ``last`` vector for the programs behind, in an ``_Unread`` for
         ``_read_results``).  A slot whose first token ends its request
         is therefore held until the step's reads, not refilled within
-        this call."""
+        this call; one that the step before's decode chunk finished by
+        count is free already, its last tokens still on the device
+        (``_dispatch_decode``)."""
         while self._queue:
             free = [
                 s for s in range(self.max_slots)
@@ -1269,17 +1306,18 @@ class InferenceEngine:
                 self._remaining[s] = req.max_new_tokens - 1
             self._unread.append(_Unread(
                 "prefill", {"bucket": bucket, "n": len(group)}, started,
-                [firsts], functools.partial(
-                    self._deliver_firsts,
-                    [(s, g) for g, s in enumerate(slots)])))
+                [firsts], self._deliver_firsts,
+                [(s, req, g) for g, (s, req) in enumerate(
+                    zip(slots, group))]))
 
-    def _deliver_firsts(self, rows: List[Tuple[int, int]],
+    def _deliver_firsts(self, rows: List[Tuple[int, Request, int]],
                         firsts: List[np.ndarray]) -> None:
         """Hand prefill programs' first tokens (one array a program) to
-        their requests: ``rows`` pairs a slot with its index in them."""
+        their requests: ``rows`` names a slot, the request (which the
+        step's decode dispatch may have taken off the slot already: a
+        budget that its chunk spends) and its index in the tokens."""
         firsts = np.concatenate(firsts)
-        for s, g in rows:
-            req = self._slot_req[s]
+        for s, req, g in rows:
             first = int(firsts[g])
             req.output.append(first)
             p = req.prompt.size
@@ -1287,7 +1325,9 @@ class InferenceEngine:
             self._ctx_buf[s, p] = first
             self._ctx_len[s] = p + 1
             self._tokens[s] = first
-            self._finish_if_done(s, first)
+            if first == self.eos_token or (
+                    self._slot_req[s] is req and self._remaining[s] <= 0):
+                self._finish(s, req)
 
     def _alloc_lifetime(self, req: Request, bucket: int):
         """ONE capacity formula for every admission path (batched AND
@@ -1478,11 +1518,11 @@ class InferenceEngine:
             # one dispatch for all rows, or one a row where the model
             # asks for that (``_prefill_group``); the cache and the last
             # tokens thread through them
-            firsts = []
+            firsts, witness, picks = [], [], None
             for i in range(0, g, self._prefill_group):
                 rows = slice(i, i + self._prefill_group)
-                self._cache, first, self._rng, seen, self._last_dev = \
-                    self._launch(
+                (self._cache, first, self._rng, seen, self._last_dev,
+                 picks) = self._launch(
                         self._prefill_chunk_fn,
                         self.params, self._cache, jnp.asarray(chunk[rows]),
                         jnp.asarray(starts[rows]),
@@ -1492,7 +1532,8 @@ class InferenceEngine:
                         jnp.asarray(final[rows]),
                     )
                 if self._watch_slot in slots[rows]:
-                    self._witnessed("run", self._prefill_pos, seen)
+                    witness.append(self._witness(
+                        "run", self._prefill_pos, seen))
                 firsts.append(first)
         self.stats.prefill_calls += 1
         self.stats.prefill_chunks += 1
@@ -1519,13 +1560,13 @@ class InferenceEngine:
             self._prefilling[s] = False
             self._positions[s] = req.prompt.size
             self._remaining[s] = req.max_new_tokens - 1
-            ended.append((s, i))
+            ended.append((s, req, i))
         # read even where no row ends its prompt: the wait is the clock
         # of this dispatch, and without it the decode chunk's would
         # count this program's time as its own
         self._unread.append(_Unread(
             "prefill_chunk", attrs, started, firsts,
-            functools.partial(self._deliver_firsts, ended)))
+            self._deliver_firsts, ended, witness, picks))
 
     def _keep_window_rows(self, slots, ends) -> None:
         """Behind a prompt chunk's dispatch: where a slot's cursor now
@@ -1554,16 +1595,26 @@ class InferenceEngine:
                 jnp.asarray(store.ring_blocks(int(end))))
 
     def _finish_if_done(self, s: int, last_token: int) -> bool:
+        """End the request in slot ``s`` if ``last_token`` is the
+        end-of-sequence or its budget is spent (the paths that read
+        before they dispatch again: ``_spec_step``, ``_drain_fixed``)."""
         req = self._slot_req[s]
         assert req is not None
         if (self.eos_token is not None and last_token == self.eos_token) \
                 or self._remaining[s] <= 0:
-            req.done = True
-            self._finished.append(req)
-            self.stats.finished_requests += 1
-            self._release_slot(s)
+            self._finish(s, req)
             return True
         return False
+
+    def _finish(self, s: int, req: Request) -> None:
+        """``req``, admitted into slot ``s``, has its whole output: hand
+        it to the step's return, and free the slot unless a decode
+        dispatch has (a finish by count: ``_dispatch_decode``)."""
+        req.done = True
+        self._finished.append(req)
+        self.stats.finished_requests += 1
+        if self._slot_req[s] is req:
+            self._release_slot(s)
 
     def _release_slot(self, s: int) -> None:
         """Return slot ``s`` to the free set — completion AND
@@ -1590,9 +1641,10 @@ class InferenceEngine:
 
     def cancel(self, rid: int) -> bool:
         """Withdraw a request wherever it lives — the engine queue, a
-        live decode slot, or a slot still MID-CHUNKED-PREFILL (the
-        cursor state is discarded and the lifetime block allocation
-        freed) — reclaiming slot + paged KV immediately.  Always True:
+        live decode slot, a decode chunk still in flight, or a slot still
+        MID-CHUNKED-PREFILL (the cursor state is discarded and the
+        lifetime block allocation freed) — reclaiming slot + paged KV
+        immediately, without waiting for the device.  Always True:
         local delivery cannot fail, and an already-finished rid is a
         successfully-delivered no-op (the router-side cancel
         contract)."""
@@ -1600,11 +1652,16 @@ class InferenceEngine:
             if req.rid == rid:
                 del self._queue[i]
                 return True
+        for sent in self._unread:
+            # a lane of the chunk in flight: its tokens go nowhere (also
+            # where the chunk's dispatch took the request off its slot)
+            sent.rows[:] = [r for r in sent.rows if r[1].rid != rid]
         for s, req in enumerate(self._slot_req):
             if req is not None and req.rid == rid:
+                # (the device's vector of last tokens stays: a free
+                # slot's entry is read by nothing before an admission
+                # writes it)
                 self._release_slot(s)
-                # slot state changed outside a step's chain of programs
-                self._last_dev = None
                 return True
         return True
 
@@ -1659,30 +1716,56 @@ class InferenceEngine:
     # ----------------------------------------------------------- step
     @property
     def has_work(self) -> bool:
+        # (an unread chunk holds requests' last tokens: their finishes
+        # are a step's to return; one whose every lane was cancelled
+        # holds nobody's, and is read when there is work again)
         return bool(self._queue) or any(
-            r is not None for r in self._slot_req)
+            r is not None for r in self._slot_req) or any(
+            sent.rows for sent in self._unread)
 
     @spanned("dlrover.engine.step")
     def step(self) -> List[Request]:
         """Admit waiting requests, advance at most ONE bounded prefill
         chunk a prefilling slot, run one decode chunk (or speculative
-        verify), return requests finished during this step.  The
+        verify), return requests whose whole output this step read.  The
         ordering IS the stall bound: a max-length prompt costs every
         other slot one chunk per decode round, never a whole prefill
         (one dispatch for all prefilling slots, or one a slot for a
         latent-attention model: ``_advance_prefill``).
 
-        The step dispatches ALL of that before it waits for any of it.
-        Which slots are free, prefill or decode, every position and
-        every block are host facts; the one thing a program needs of the
-        one before is each slot's last token, and that passes between
-        them on the device (``_last_tokens``).  So while the host
-        prepares the next program the device runs the last, and the
-        results are read once, in dispatch order, behind the last
-        dispatch (``_read_results``).  What a sampled token alone can
-        say, that it ends its request, is learnt there: a slot whose
-        FIRST token is the end-of-sequence sits in this step's decode
-        chunk as a wasted lane, as one that ends mid-chunk does."""
+        The step dispatches ALL of that before it waits for any of it,
+        and it LOOKS AHEAD over its own boundary: its decode chunk it
+        leaves unread, for the next step to read behind that step's own
+        dispatches.  On the host the order is: dispatch step N
+        (admissions, prompt chunks, decode chunk N) -> read chunk N - 1,
+        then what step N dispatched ahead of its chunk, in dispatch order
+        -> deliver, finish, return.  On the device the order of programs
+        is what it always was, and chunk N is queued behind chunk N - 1
+        while the host waits for that one, delivers it, returns to its
+        caller, takes new requests and dispatches again.
+
+        That needs nothing a token could say.  Which slots are free,
+        prefill or decode, every position, every block and every budget
+        are host facts, booked when a program is DISPATCHED
+        (``_dispatch_decode`` takes a chunk's tokens off each budget and
+        frees a slot whose budget the chunk spends, so the next step
+        admits into it, one step after the dispatch); the one thing a
+        program needs of the one before is each slot's last token, and
+        that passes between them on the device (``_last_tokens``).  A
+        request is returned by the step that READS its last tokens: one
+        step after the dispatch that finished it, with its whole output
+        (``has_work`` stays true until then; a step with nothing to
+        dispatch reads what is left).  What a sampled token alone can
+        say, that it ends its request, is learnt at the read, one chunk
+        late: a request that ends inside chunk N already has a lane in
+        chunk N + 1, whose tokens are dropped
+        (``EngineStats.wasted_lane_chunks``), and its slot and blocks are
+        freed at chunk N's read.  ``EngineStats.lookahead_steps`` counts
+        the steps that returned with a chunk unread.
+
+        With speculation on there is no look-ahead: drafts come from the
+        host's context, so that step reads everything before it
+        verifies."""
         before = len(self._finished)
         self._dispatch_admissions()
         if self.prefill_chunk:
@@ -1694,10 +1777,11 @@ class InferenceEngine:
                 self._spec_step()
             return self._finished[before:]
         active = self._decoding()
-        if active.any():
+        decoding = bool(active.any())
+        if decoding:
             self._dispatch_decode(active)
-        self._read_results()
-        if active.any() and self._spec_fn is not None:
+        self._read_results(ahead=decoding)
+        if decoding and self._spec_fn is not None:
             self._after_chunk_round()
         return self._finished[before:]
 
@@ -1713,7 +1797,14 @@ class InferenceEngine:
         ])
 
     def _dispatch_decode(self, active: np.ndarray) -> None:
-        """One decode chunk for the slots ``active``, not waited for."""
+        """One decode chunk for the slots ``active``, not waited for.
+        What it does to each slot is booked here, by arithmetic: the
+        position, and the budget.  A slot whose budget the chunk spends
+        is taken off its request now (``_release_slot``: the slot, its
+        blocks and its table row are the next step's admissions' to
+        have, whose programs the device runs behind this chunk); the
+        request travels with the chunk and ends when its tokens are
+        read (``_deliver_chunk``)."""
         if self.paged and self._table_dirty:
             self._push_table()
         live, streamed = self._book_kv_rows(active)
@@ -1725,7 +1816,7 @@ class InferenceEngine:
                 **self._book_window_rows(lengths)}
         started = time.perf_counter()
         with self._dispatching("decode_chunk"):
-            out, self._last_dev, _, self._cache, self._rng, seen = \
+            out, self._last_dev, _, self._cache, self._rng, seen, picks = \
                 self._launch(
                     self._chunk_fn,
                     self.params, self._cache, self._last_tokens(),
@@ -1734,14 +1825,23 @@ class InferenceEngine:
                     jnp.asarray(self._positions.copy()),
                     jnp.asarray(active), self._rng,
                 )
+        witness = []
         if self._watch_slot >= 0 and active[self._watch_slot]:
-            self._witnessed("decode", self._positions, seen)
+            witness.append(self._witness("decode", self._positions, seen))
         # what the program does to them: no round trip
         self._positions[active] += self.chunk
         self.stats.decode_forwards += self.chunk
+        lanes = []
+        for s in np.flatnonzero(active):
+            take = min(self.chunk, int(self._remaining[s]))
+            self._remaining[s] -= take
+            last = bool(self._remaining[s] <= 0)
+            lanes.append((int(s), self._slot_req[s], take, last))
+            if last:
+                self._release_slot(s)
         self._unread.append(_Unread(
-            "decode_chunk", rows, started, out,
-            functools.partial(self._deliver_chunk, active)))
+            "decode_chunk", rows, started, out, self._deliver_chunk, lanes,
+            witness, picks))
 
     # ------------------------------------- dispatch now, read afterwards
     def _last_tokens(self) -> jax.Array:
@@ -1755,9 +1855,10 @@ class InferenceEngine:
 
     def _launch(self, program, *args):
         """Dispatch one program, counted, and as chained where an
-        earlier one of this step is still unread."""
+        earlier one is still unread."""
         self.stats.dispatches += 1
         self.stats.chained_dispatches += self._in_flight > 0
+        self._head_alone |= not self._in_flight
         self._in_flight += 1
         return program(*args)
 
@@ -1771,19 +1872,25 @@ class InferenceEngine:
             return contextlib.nullcontext()
         return span("dlrover.engine." + name)
 
-    def _read_results(self) -> None:
-        """Read what the step dispatched, in dispatch order, and do the
-        bookkeeping that needed it: first tokens into their requests,
-        then the decode chunk's, each followed by its finishes, so
-        requests finish (and free their slots) in the order of the
-        dispatches.  Each wait is a span under its program's name and
-        adds to that program's ``*_seconds`` (``EngineStats``)."""
-        if not self._unread:
+    def _read_results(self, ahead: bool = False) -> None:
+        """Read what is in flight, in dispatch order, and do the
+        bookkeeping that needed it: the decode chunk the step before
+        left, then this step's first tokens, each followed by its
+        finishes, so requests finish in the order of the dispatches.
+        ``ahead``: all but the newest, this step's own decode chunk,
+        which the next call reads.  Each wait is a span under its
+        program's name and adds to that program's ``*_seconds``
+        (``EngineStats``), from the later of its dispatch and the result
+        before it reaching the host, be that a step ago."""
+        split = len(self._unread) - ahead
+        unread, self._unread = self._unread[:split], self._unread[split:]
+        kept = len(self._unread)          # one program: a decode chunk
+        self.stats.lookahead_steps += kept
+        if not unread:
             return
-        unread, self._unread = self._unread, []
-        with span("dlrover.engine.reads", dispatches=self._in_flight,
-                  chained=self._in_flight - 1):
-            reached = 0.0         # the last result reached the host at
+        reading = self._in_flight - kept
+        with span("dlrover.engine.reads", dispatches=reading,
+                  chained=reading - self._head_alone):
             for sent in unread:
                 with span("dlrover.engine." + sent.name, **sent.attrs):
                     values = jax.tree_util.tree_map(
@@ -1791,12 +1898,13 @@ class InferenceEngine:
                 now = time.perf_counter()
                 for clock in _CLOCKS[sent.name]:
                     setattr(self.stats, clock, getattr(self.stats, clock)
-                            + now - max(sent.started, reached))
-                reached = now
-                sent.deliver(values)
-            self._in_flight = 0
-            # behind the syncs above
-            self._book_moe_picks()
+                            + now - max(sent.started, self._reached))
+                self._reached = now
+                self.witness_log.extend(sent.witness)
+                self._book_moe_picks(sent.picks)
+                sent.deliver(sent.rows, values)
+            self._in_flight = kept
+            self._head_alone = False
 
     def _book_kv_rows(self, active: np.ndarray,
                       chunks: int = 1) -> Tuple[int, int]:
@@ -2023,18 +2131,19 @@ class InferenceEngine:
         self._cache = dict(self._cache,
                            watch_slot=jnp.asarray(s, jnp.int32))
 
-    def _witnessed(self, kind: str, positions, seen) -> None:
+    def _witness(self, kind: str, positions, seen) -> Dict[str, Any]:
+        """The ``witness_log`` entry of a program just dispatched for the
+        watched slot; it joins the log when the program is read."""
         s = self._watch_slot
-        self.witness_log.append({
-            "request": self._slot_req[s], "kind": kind,
-            "start": int(positions[s]), "seen": seen})
+        return {"request": self._slot_req[s], "kind": kind,
+                "start": int(positions[s]), "seen": seen}
 
-    def _book_moe_picks(self) -> None:
+    def _book_moe_picks(self, counted: Optional[jax.Array]) -> None:
         """Add to ``stats.moe_picks`` / ``moe_picks_held`` what the
-        programs dispatched since the last call counted on the device
-        (two wrapping uint32 carried in the cache; read behind a sync
-        the caller has already paid)."""
-        counted = self._cache.get("moe_picks")
+        programs up to the one just read counted on the device since the
+        last call (``counted``: two wrapping uint32 that it handed back
+        beside the cache that carries them, None for a model that counts
+        none; the two counters lag by what is in flight)."""
         if counted is None:
             return
         now = np.asarray(counted, np.uint32)
@@ -2044,31 +2153,36 @@ class InferenceEngine:
         self.stats.moe_picks_held += int(delta[1])
 
     @spanned("dlrover.engine.deliver")
-    def _deliver_chunk(self, active: np.ndarray, out: np.ndarray) -> None:
-        """Hand a decode chunk's tokens ([B, chunk]) to the requests of
-        the slots ``active`` in it.  A slot whose request the reads
-        before this one have ended (on its first token) keeps nothing of
-        its lane."""
-        self._tokens[active] = out[active, -1]
-        for s in np.flatnonzero(active):
-            req = self._slot_req[s]
-            if req is None:
+    def _deliver_chunk(self, lanes: List[Tuple[int, Request, int, bool]],
+                       out: np.ndarray) -> None:
+        """Hand a decode chunk's tokens ([B, chunk]) on: ``lanes`` names
+        each slot it advanced, the request that held the slot at its
+        dispatch, how many of the lane's tokens that request's budget
+        had room for, and whether those are its last.  A request that an
+        earlier read has ended (an end-of-sequence: the chunk before's,
+        or its first token) keeps nothing of its lane; one whose budget
+        the chunk spent, or that ends inside it, ends here."""
+        for s, req, take, last in lanes:
+            if self._slot_req[s] is req or self._slot_req[s] is None:
+                # (a later occupant's token is its own program's)
+                self._tokens[s] = out[s, -1]
+            if req.done:
+                self.stats.wasted_lane_chunks += 1
                 continue
-            take = min(self.chunk, int(self._remaining[s]))
             toks = out[s, :take].tolist()
             if self.eos_token is not None and self.eos_token in toks:
                 toks = toks[: toks.index(self.eos_token) + 1]
             req.output.extend(toks)
-            if self._spec_fn is not None and toks:
+            if self._spec_fn is not None and self._slot_req[s] is req:
                 # keep the draft-lookup context fresh so a later
                 # switch back to speculation sees these tokens
                 n = int(self._ctx_len[s])
                 end = min(n + len(toks), self._ctx_buf.shape[1])
                 self._ctx_buf[s, n:end] = toks[: end - n]
                 self._ctx_len[s] = end
-            self._remaining[s] -= len(toks)
             self.stats.generated_tokens += len(toks)
-            self._finish_if_done(s, toks[-1] if toks else -1)
+            if last or toks[-1] == self.eos_token:
+                self._finish(s, req)
 
     def _after_chunk_round(self) -> None:
         """Speculation governor, chunk-decode side: count down a
@@ -2197,7 +2311,8 @@ class InferenceEngine:
         number of decode chunks is known, so dispatch them all
         back-to-back and sync the host ONCE — per-chunk host round
         trips would otherwise dominate decode latency (multi-step
-        scheduling taken to its fixed-budget limit)."""
+        scheduling taken to its fixed-budget limit).  Reads first
+        whatever a step left in flight (``_admit``)."""
         self._admit()
         active = np.array([r is not None for r in self._slot_req])
         if not active.any():
@@ -2213,7 +2328,7 @@ class InferenceEngine:
         positions = jnp.asarray(self._positions)
         active_j = jnp.asarray(active)
         for _ in range(n_chunks):
-            out, tokens, positions, self._cache, self._rng, _ = \
+            out, tokens, positions, self._cache, self._rng, _, picks = \
                 self._launch(
                     self._chunk_fn,
                     self.params, self._cache, tokens, positions,
@@ -2222,6 +2337,7 @@ class InferenceEngine:
             outs.append(out)
         out = np.concatenate([np.asarray(o) for o in outs], axis=1)
         self._in_flight = 0
+        self._book_moe_picks(picks)
         self._tokens = np.array(tokens)
         self._positions = np.array(positions)
         self._last_dev = None

@@ -146,6 +146,8 @@ class RouterMetrics:
         self.cache_bytes = 0.0
         self.dispatches = 0.0
         self.chained_dispatches = 0.0
+        self.lookahead_steps = 0.0
+        self.wasted_lane_chunks = 0.0
         # prefix-cache fleet aggregates (engine-side COW ledger summed
         # over reporting replicas, same sweep as the raw-speed keys)
         self.prefix_hits = 0.0
@@ -310,7 +312,8 @@ class RouterMetrics:
         for name in ("dsa_rows_live", "attn_rows_selected", "moe_picks",
                      "moe_picks_held", "prefill_query_tiles",
                      "prefill_query_tiles_live", "dispatches",
-                     "chained_dispatches", "window_rows_in_window",
+                     "chained_dispatches", "lookahead_steps",
+                     "wasted_lane_chunks", "window_rows_in_window",
                      "window_rows_streamed", "window_cache_bytes",
                      "cache_bytes"):
             setattr(self, name, sum(d.get(name, 0.0) for d in dicts))
@@ -422,6 +425,9 @@ class RouterMetrics:
             "serving_engine_chained_dispatch_share": (
                 self.chained_dispatches / self.dispatches
                 if self.dispatches else 0.0),
+            "serving_engine_lookahead_steps_total": self.lookahead_steps,
+            "serving_engine_wasted_lane_chunks_total":
+                self.wasted_lane_chunks,
             "serving_dsa_selected_ratio": (
                 self.attn_rows_selected / self.dsa_rows_live
                 if self.dsa_rows_live else 0.0),

@@ -107,9 +107,12 @@ class InferenceEngineAdapter:
         return finished
 
     def inflight_outputs(self) -> Dict[int, List[int]]:
-        """Live output snapshot per RUNNING request (finished ones are
-        covered by ``step()``'s return) — the streaming introspection
-        surface the remote worker and the local pump both diff against."""
+        """Live output snapshot per request that HOLDS A SLOT (finished
+        ones are covered by ``step()``'s return, and so is one whose
+        last decode chunk is dispatched and unread: the engine has
+        taken it off its slot, and the step that reads the chunk
+        returns it whole) — the streaming introspection surface the
+        remote worker and the local pump both diff against."""
         return {
             req.rid: req.output
             for req in self.engine._slot_req if req is not None
@@ -158,10 +161,14 @@ class InferenceEngineAdapter:
             "prefill_calls": float(st.prefill_calls),
             "prefill_admissions": float(st.prefill_admissions),
             # programs sent to the device, and those sent while an
-            # earlier one of the same step was unread (the sums: a
-            # fleet's share weighs by work)
+            # earlier one was unread (the sums: a fleet's share weighs
+            # by work); steps that returned with their decode chunk in
+            # flight, and chunk lanes whose request had ended before
+            # the chunk was read
             "dispatches": float(st.dispatches),
             "chained_dispatches": float(st.chained_dispatches),
+            "lookahead_steps": float(st.lookahead_steps),
+            "wasted_lane_chunks": float(st.wasted_lane_chunks),
         }
         if getattr(eng, "paged", False):
             # resolved paged-attention impl (0=xla gather, 1=fused

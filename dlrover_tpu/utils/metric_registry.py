@@ -190,11 +190,23 @@ METRIC_HELP: Dict[str, str] = {
     ),
     "serving_engine_chained_dispatch_share": (
         "engine programs (prefills, prompt chunks, decode chunks) "
-        "dispatched while an earlier program of the same engine step "
-        "was still unread, over all programs dispatched, fleet-wide: "
-        "the share of dispatches whose host preparation the device's "
-        "queue hid; 0 = every step is one program (or no replica "
-        "reports)"
+        "dispatched while an earlier program was still unread (of the "
+        "same engine step, or the decode chunk the step before left in "
+        "flight), over all programs dispatched, fleet-wide: the share "
+        "of dispatches whose host preparation the device's queue hid; "
+        "near 1 while steps look ahead, 0 = no replica reports"
+    ),
+    "serving_engine_lookahead_steps_total": (
+        "engine steps that returned with their decode chunk dispatched "
+        "and unread (the next step reads it behind its own dispatches), "
+        "fleet-wide; standing still beside rising decode traffic says "
+        "steps read their own chunk (speculation on)"
+    ),
+    "serving_engine_wasted_lane_chunks_total": (
+        "decode-chunk lanes whose request had already ended when the "
+        "chunk was read (an end-of-sequence is learnt one chunk late), "
+        "fleet-wide: each is one slot's chunk of forwards computed and "
+        "dropped"
     ),
     "serving_dsa_selected_ratio": (
         "key rows attended per key row live on replicas whose model "

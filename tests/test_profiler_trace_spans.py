@@ -65,9 +65,11 @@ SERVE_SPANS = {
     "dlrover.router.pump": ("dlrover.router.phase.pump", "main"),
     "dlrover.engine.step": ("dlrover.router.pump", "main"),
     "dlrover.engine.admit": ("dlrover.engine.step", "main"),
-    # a program's name opens around its wait, under the step's reads,
-    # and around its dispatch too where nothing was in flight (a
-    # bucketed prefill's: under the step's admit)
+    # a program's name opens around its wait, under a step's reads (a
+    # decode chunk's: the NEXT step's, which reads it behind its own
+    # dispatches), and around its dispatch too where nothing was in
+    # flight (a bucketed prefill's into an idle engine: under the
+    # step's admit)
     "dlrover.engine.prefill": ("dlrover.engine.step", "main"),
     "dlrover.engine.reads": ("dlrover.engine.step", "main"),
     "dlrover.engine.prefill_chunk": ("dlrover.engine.step", "main"),
@@ -479,17 +481,35 @@ def test_engine_spans_time_what_the_engine_counters_time(serve_run):
     from its dispatch (or the result before it) to its result: they hold
     the program's spans (its wait, and its dispatch where nothing was in
     flight) and the host's dispatching of the programs chained behind
-    it, and lie inside the steps.  A program that is alone in its step,
-    as a verify is, reads as its one span (both engines were made for
-    this trace and ran inside it only)."""
-    spans = ps.totals(serve_run["parsed"])
+    it.  A decode chunk is read by the step AFTER the one that
+    dispatched it (the step looks ahead), so its time runs across the
+    step's boundary: the clocks lie inside the stretch from the first
+    step's start to the last step's end, not inside the steps, and the
+    chunk's wait is the first span under the next step's reads.  A
+    program that is alone in its step, as a verify is, reads as its one
+    span (both engines were made for this trace and ran inside it
+    only)."""
+    parsed = serve_run["parsed"]
+    spans = ps.totals(parsed)
     chunked, speculating = serve_run["stats"]
     counted = sum(s.decode_seconds + s.prefill_seconds
                   for s in serve_run["stats"])
     traced = sum(spans[n]["seconds"] for n in (
         "dlrover.engine.decode_chunk", "dlrover.engine.verify",
         "dlrover.engine.prefill", "dlrover.engine.prefill_chunk"))
-    assert 0 < traced <= counted <= spans["dlrover.engine.step"]["seconds"]
+    steps = ps.named(parsed, "dlrover.engine.step")
+    stretch = max(t + d for _, t, d, _ in steps) \
+        - min(t for _, t, _, _ in steps)
+    assert 0 < traced <= counted <= stretch * 1e-9
+    assert chunked.lookahead_steps > 0 == speculating.lookahead_steps
+    waits = sorted(
+        (t, name) for name in ("dlrover.engine.decode_chunk",
+                               "dlrover.engine.prefill",
+                               "dlrover.engine.prefill_chunk")
+        for _, t, _, a in ps.named(parsed, name) if a)
+    for _, start, dur, _ in ps.named(parsed, "dlrover.engine.reads"):
+        inside = [n for t, n in waits if start <= t < start + dur]
+        assert "dlrover.engine.decode_chunk" not in inside[1:], inside
     assert 0 < spans["dlrover.engine.prefill_chunk"]["seconds"] \
         <= chunked.prefill_chunk_seconds <= chunked.prefill_seconds
     assert spans["dlrover.engine.verify"]["seconds"] \

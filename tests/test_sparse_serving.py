@@ -595,24 +595,38 @@ def watched(cfg, params):
     return _watched(cfg, params)
 
 
-def test_a_watched_request_is_witnessed_by_a_step_that_waits_once(
+def test_a_watched_request_is_witnessed_by_programs_that_have_been_read(
         cfg, params):
     """``step`` dispatches a latent model's prompt chunks (one a slot)
-    and its decode chunk before it reads any of them: the watched
-    request's witness is still appended with every dispatch that
-    advances it, as device arrays the step never reads, and the experts'
-    picks are booked behind the step's reads.  An engine of its own:
-    the witness log and the books are read from their start."""
+    and its decode chunk before it reads any of them, and leaves the
+    decode chunk in flight: a witness entry joins the log when its
+    program is READ, so bringing the log to the host after every step,
+    as the benchmark's drivers do, waits for nothing (every leaf is
+    ready, none is the in-flight chunk's); the log is complete after the
+    drain, and the experts' picks, taken from what each program read
+    handed back, lag by the chunk in flight and end at the parent's
+    numbers.  An engine of its own: the witness log and the books are
+    read from their start."""
     rng = np.random.RandomState(4)
     eng = _engine(cfg, params)
     eng.watch(lambda req: req.prompt.size == 40)
     for n in (40, 37):
         eng.add_request(rng.randint(0, 128, n).astype(np.int32), 6)
-    picks = []
+    picks, lagged = [], 0
     while eng.has_work:
         eng.step()
-        assert not eng._unread
+        assert [u.name for u in eng._unread] in ([], ["decode_chunk"])
+        in_flight = [id(leaf) for u in eng._unread for w in u.witness
+                     for leaf in jax.tree_util.tree_leaves(w["seen"])]
+        lagged += bool(in_flight)
+        for w in eng.witness_log:
+            leaves = jax.tree_util.tree_leaves(w["seen"])
+            assert all(leaf.is_ready() and id(leaf) not in in_flight
+                       for leaf in leaves)
+            with jax.transfer_guard_device_to_host("allow"):
+                jax.tree_util.tree_map(np.asarray, w["seen"])
         picks.append(eng.stats.moe_picks)
+    assert lagged == 2 and not eng._unread     # both decode chunks
     watched = eng.witness_log
     assert {w["request"].rid for w in watched} == {0}
     assert [(w["kind"], w["start"]) for w in watched] == [
@@ -622,10 +636,12 @@ def test_a_watched_request_is_witnessed_by_a_step_that_waits_once(
                for leaf in jax.tree_util.tree_leaves(w["seen"]))
     st = eng.stats
     # three steps of two chunk programs, the second behind the first; a
-    # decode chunk behind the third's, and one alone
-    assert (st.dispatches, st.chained_dispatches) == (2 * 3 + 2, 3 + 1)
+    # decode chunk behind the third's, and one behind that, unread
+    assert (st.dispatches, st.chained_dispatches) == (2 * 3 + 2, 3 + 2)
+    assert (st.lookahead_steps, st.wasted_lane_chunks) == (2, 0)
     assert picks == sorted(picks) and picks[0] > 0
-    assert st.moe_picks == picks[-1] and 0 < st.moe_picks_held < st.moe_picks
+    # (the parent's numbers for these two requests)
+    assert (st.moe_picks, st.moe_picks_held) == (picks[-1], 155) == (372, 155)
     # a prompt's three chunks end at 16, 32 and its last token, 40 or 37
     # (behind it the third program is padding, which walks nothing):
     # 1 + 1 + 2 of the kernel's blocks of 4 pages x 8 rows, where a

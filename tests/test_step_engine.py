@@ -834,10 +834,11 @@ def test_router_open_loop_soak_60s():
 
 
 # ----------------------------------------------------------------------
-# The ENGINE's step (ISSUE 35): a step dispatches all its programs before
-# it waits for any.  Each slot's last token passes from program to
-# program on the device; the host reads once, in dispatch order, behind
-# the last dispatch.
+# The ENGINE's step (ISSUE 35, ISSUE 48): a step dispatches all its
+# programs before it waits for any, and leaves its decode chunk unread for
+# the next step.  Each slot's last token passes from program to program
+# on the device; the host reads in dispatch order, behind the step's last
+# dispatch, the chunk of the step before first.
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
@@ -879,7 +880,8 @@ def _engine(tiny_model, **kw):
 def solo_tokens(tiny_model):
     """Every request of the queue alone, through ``generate`` on an idle
     engine of one slot with no end-of-sequence: greedy decoding's tokens,
-    which no batching, layout or admission path may change."""
+    which no batching, layout, admission path or order of reads may
+    change."""
     _, _, prompts = tiny_model
     eng = _engine(tiny_model, max_slots=1)
     solo = []
@@ -893,12 +895,21 @@ def _until_eos(tokens, eos):
     return tokens[: tokens.index(eos) + 1] if eos in tokens else tokens
 
 
-def _drive(eng, prompts, reupload=False):
+def _in_flight_is_one_chunk(eng):
+    """Between steps: nothing, or the last step's decode chunk."""
+    assert [u.name for u in eng._unread] in ([], ["decode_chunk"])
+    assert eng._in_flight == len(eng._unread)
+
+
+def _drive(eng, prompts, parents_order=False):
     """The whole queue through ``step``; returns the tokens by request,
     the order of finishes, and the finishes of each step as (where the
-    finishing token came from, slot, request)."""
+    finishing token came from, slot, request).  ``parents_order``: every
+    step reads its own chunk before it returns, as the parent's did, and
+    the next uploads the host's last tokens, which is where the parent's
+    programs took them from."""
     phases = []
-    finish = eng._finish_if_done
+    finish = eng._finish
 
     def during(phase, fn):
         def inner(*args):
@@ -909,29 +920,34 @@ def _drive(eng, prompts, reupload=False):
                 phases.pop()
         return inner
 
-    def noted_finish(s, token):
-        req = eng._slot_req[s]
-        if finish(s, token):
-            steps[-1].append((phases[-1], s, req.rid))
-            return True
-        return False
+    def noted_finish(s, req):
+        steps[-1].append((phases[-1], s, req.rid))
+        order.append(req.rid)
+        return finish(s, req)
 
-    eng._finish_if_done = noted_finish
+    eng._finish = noted_finish
     eng._deliver_firsts = during("first", eng._deliver_firsts)
     eng._deliver_chunk = during("chunk", eng._deliver_chunk)
     for prompt, (_, new) in zip(prompts, _QUEUE):
         eng.add_request(prompt, new)
-    steps, order = [], []
+    steps, order, returned = [], [], []
     while eng.has_work:
         assert len(steps) < 200
-        if reupload:
-            eng._last_dev = None
         steps.append([])
-        order += [r.rid for r in eng.step()]
-        assert not eng._unread and eng._in_flight == 0
-        # the device's vector is the host's, slot for slot
-        assert np.array_equal(np.asarray(eng._last_tokens()), eng._tokens)
-        assert order == [rid for step in steps for _, _, rid in step]
+        returned += [r.rid for r in eng.step()]
+        _in_flight_is_one_chunk(eng)
+        if parents_order:
+            eng._read_results()
+            eng._last_dev = None
+        else:
+            # a step returns what it finished, whole
+            assert returned == order
+        if not eng._unread:
+            # the device's vector is the host's, slot for slot
+            assert np.array_equal(np.asarray(eng._last_tokens()),
+                                  eng._tokens)
+    assert all(r.done and len(r.output) <= r.max_new_tokens
+               for r in eng._finished)
     return ({r.rid: list(r.output) for r in eng._finished}, order, steps)
 
 
@@ -941,43 +957,48 @@ def _drive(eng, prompts, reupload=False):
 def test_a_mixed_queue_yields_the_parents_tokens(
         tiny_model, solo_tokens, layout, admission, sampling):
     """Prompts across the buckets, long ones, a budget of one token and a
-    first token that is the end-of-sequence, more requests than slots:
-    greedy, every request's tokens are its solo run's (the parent's,
-    token for token); sampled from a seed, they are those of an engine
-    that uploads the host's last tokens before every step, which is
-    where the parent's programs took them from.  Requests finish in the
-    order the parent's code ran its finishes: first tokens in dispatch
-    order, then the decode chunk's by slot."""
+    first token that is the end-of-sequence, more requests than slots,
+    every step looking ahead: greedy, every request's tokens are its solo
+    run's through ``generate`` (the parent's, token for token); sampled
+    from a seed, they are those of the same engine driven in the parent's
+    order (each step reads its own chunk before it returns, and the host's
+    last tokens are uploaded again).  Requests finish in the order of the
+    dispatches: the chunk of the step before by slot, then this step's
+    first tokens."""
     _, _, prompts = tiny_model
     kw = dict(paged=layout == "paged", block_size=8,
               prefill_chunk=16 if admission == "chunked" else 0)
     if sampling == "greedy":
         eos = solo_tokens[_EOS_FIRST][0]
         want = {i: _until_eos(t, eos) for i, t in enumerate(solo_tokens)}
-        got, order, steps = _drive(_engine(tiny_model, eos_token=eos, **kw),
-                                   prompts)
+        eng = _engine(tiny_model, eos_token=eos, **kw)
+        got, order, steps = _drive(eng, prompts)
         assert got == want
     else:
         kw.update(temperature=0.8, top_k=20, seed=5)
         free, _, _ = _drive(_engine(tiny_model, **kw), prompts)
         eos = free[_EOS_FIRST][0]
-        got, order, steps = _drive(_engine(tiny_model, eos_token=eos, **kw),
-                                   prompts)
+        eng = _engine(tiny_model, eos_token=eos, **kw)
+        got, order, steps = _drive(eng, prompts)
         want, want_order, _ = _drive(
-            _engine(tiny_model, eos_token=eos, **kw), prompts, reupload=True)
+            _engine(tiny_model, eos_token=eos, **kw), prompts,
+            parents_order=True)
         assert got == want and order == want_order
         assert got != {i: _until_eos(t, eos)
                        for i, t in enumerate(solo_tokens)}   # it sampled
     assert got[_EOS_FIRST] == [eos] and len(got[3]) == 1
     assert sorted(order) == list(range(len(_QUEUE)))
+    assert eng.stats.lookahead_steps > 0
     for step in steps:
-        firsts = [f for f in step if f[0] == "first"]
         chunk = [f for f in step if f[0] == "chunk"]
-        assert step == firsts + chunk
+        firsts = [f for f in step if f[0] == "first"]
+        assert step == chunk + firsts
         assert [s for _, s, _ in chunk] == sorted(s for _, s, _ in chunk)
-    # a first token that ends its request waits for the step's reads
+    # a first token that ends its request is learnt at the step's reads;
+    # its lane in that step's chunk is dropped when the chunk is read
     assert any(f[2] == _EOS_FIRST and f[0] == "first"
                for step in steps for f in step)
+    assert eng.stats.wasted_lane_chunks >= 1
 
 
 class _Read:
@@ -1012,16 +1033,26 @@ def _spied(eng, events):
 
 def test_a_step_reads_nothing_between_its_dispatches(tiny_model):
     """Two admissions (two buckets: two prefill programs), a prompt
-    chunk and a decode chunk in one step: four dispatches, then their
-    four reads, in the order of the dispatches."""
+    chunk and a decode chunk in one step, behind the chunk the step
+    before left in flight: four dispatches with no device-to-host read
+    among them, then four reads in the order of the dispatches, the OLD
+    chunk's first; the new chunk stays unread."""
     _, _, prompts = tiny_model
     eng = _engine(tiny_model, max_slots=4, paged=True, block_size=8,
                   prefill_chunk=16)
+    events = []
+    _spied(eng, events)
     eng.add_request(prompts[0], 12)
     eng.add_request(prompts[5], 6)          # 30 tokens: two chunks
     eng.step()
-    events = []
-    _spied(eng, events)
+    # the first step's chunk is in flight: dispatched, not read
+    assert events == [
+        ("dispatch", "prefill"), ("dispatch", "prefill_chunk"),
+        ("dispatch", "decode_chunk"), ("read", "prefill"),
+        ("read", "prefill_chunk")]
+    assert [u.name for u in eng._unread] == ["decode_chunk"]
+    assert len(eng._slot_req[0].output) == 1
+    del events[:]
     eng.add_request(prompts[6], 8)          # bucket 8
     eng.add_request(prompts[1], 8)          # bucket 16
     before = dataclasses.replace(eng.stats)
@@ -1031,89 +1062,229 @@ def test_a_step_reads_nothing_between_its_dispatches(tiny_model):
         eng._dispatch_decode(eng._decoding())
     assert events == [("dispatch", n) for n in (
         "prefill", "prefill", "prefill_chunk", "decode_chunk")]
-    assert all(len(r.output) == 0 for r in eng._slot_req[1:])
-    eng._read_results()
+    assert [len(r.output) for r in eng._slot_req] == [1, 0, 0, 0]
+    eng._read_results(ahead=True)
+    # chunk N is read after chunk N + 1's dispatch, and chunk N + 1 is not
     assert events[4:] == [("read", n) for n in (
-        "prefill", "prefill", "prefill_chunk", "decode_chunk")]
+        "decode_chunk", "prefill", "prefill", "prefill_chunk")]
+    assert [u.name for u in eng._unread] == ["decode_chunk"]
     assert eng.stats.dispatches - before.dispatches == 4
-    assert eng.stats.chained_dispatches - before.chained_dispatches == 3
-    # a first token and, for all four slots, that step's chunk of four
-    assert [len(r.output) for r in eng._slot_req] == [1 + 4 + 4, 5, 5, 5]
-    # the whole of it again through ``step``: the same shape
+    assert eng.stats.chained_dispatches - before.chained_dispatches == 4
+    assert eng.stats.lookahead_steps - before.lookahead_steps == 1
+    # a first token each, and for slot 0 the OLD chunk's four
+    assert [len(r.output) for r in eng._slot_req] == [1 + 4, 1, 1, 1]
+    # the whole of it again through ``step``: the same shape, and every
+    # decode chunk is read exactly one step after its dispatch
     del events[:]
     eng.add_request(prompts[4], 3)
+    unread_chunks = 1
     while eng.has_work:
         eng.step()
         kinds = [k for k, _ in events]
         assert kinds == sorted(kinds), events   # dispatches, then reads
+        sent = events.count(("dispatch", "decode_chunk"))
+        assert events.count(("read", "decode_chunk")) == unread_chunks
+        if unread_chunks:
+            assert events[kinds.index("read")] == ("read", "decode_chunk")
+        unread_chunks = sent
         del events[:]
+    assert unread_chunks == 0 and not eng._unread
 
 
-def test_chained_dispatches_are_counted_and_exported(tiny_model):
-    """A scripted sequence of steps: a program is chained when an
-    earlier one of ITS step is unread, never across steps."""
+def test_a_slot_and_its_blocks_are_refilled_under_the_unread_chunk(
+        tiny_model, solo_tokens):
+    """One slot, a pool that holds one request: the chunk that spends a
+    request's budget frees its slot and its blocks at the DISPATCH, the
+    next request is admitted into both while that chunk is unread, and
+    both get their right tokens.  The finished one is returned by the
+    step that reads it, with its whole output; ``has_work`` stays true
+    until then."""
+    _, _, prompts = tiny_model
+    eng = _engine(tiny_model, max_slots=1, paged=True, block_size=8,
+                  cache_blocks=5, prefix_sharing=False)
+    first = eng.add_request(prompts[0], 9)      # 5 + 9: two blocks of 4
+    returned = eng.step() + eng.step()
+    # its last chunk is dispatched: the slot and the blocks are free, the
+    # request has not been returned and lacks that chunk's tokens
+    assert returned == [] and eng._slot_blocks[0] is None
+    assert eng._blockmgr.available_blocks == 4
+    (chunk,) = eng._unread
+    (_, req, take, last), = chunk.rows
+    assert req.rid == first and last and not req.done
+    assert len(req.output) == 9 - take
+    assert eng.has_work and not eng._queue
+    second = eng.add_request(prompts[2], 10)    # 20 + 10: all the blocks
+    returned = eng.step()
+    assert eng._slot_req[0].rid == second
+    assert set(eng._slot_blocks[0]) == {1, 2, 3, 4}
+    assert [r.rid for r in returned] == [first] and returned[0].done
+    assert returned[0].output == solo_tokens[0]
+    done = eng.run()
+    assert done[second].tolist() == solo_tokens[2]
+    assert not eng.has_work and not eng._unread
+
+
+def test_an_end_of_sequence_is_learnt_one_chunk_late(tiny_model,
+                                                     solo_tokens):
+    """Greedy, with the token a request emits INSIDE its second decode
+    chunk made the end-of-sequence: the output is cut there, the request
+    has a lane in the third chunk already, whose tokens are dropped (one
+    ``wasted_lane_chunks``), and its blocks are released when the second
+    chunk is read, not before."""
+    _, _, prompts = tiny_model
+    tokens = solo_tokens[2]                 # 10 tokens: 1 + 2 + 2 + 2 + ..
+    eos = tokens[3]                         # ... the second chunk's first
+    assert eos not in tokens[:3]
+    eng = _engine(tiny_model, max_slots=2, chunk=2, paged=True,
+                  block_size=8, eos_token=eos)
+    events = []
+    _spied(eng, events)
+    release = eng._release_slot
+    eng._release_slot = lambda s: (events.append(("release", s)),
+                                   release(s))[1]
+    eng.add_request(prompts[2], 10)
+    returned = []
+    while eng.has_work:
+        returned += eng.step()
+    assert [r.output for r in returned] == [tokens[:4]]
+    assert eng.stats.wasted_lane_chunks == 1
+    assert eng.stats.generated_tokens == 3      # (first tokens not counted)
+    chunks = [e for e in events if e[1] != "prefill"]
+    assert chunks == [
+        ("dispatch", "decode_chunk"),           # 1: tokens 1-2
+        ("dispatch", "decode_chunk"),           # 2: the end inside it
+        ("read", "decode_chunk"),               # 1
+        ("dispatch", "decode_chunk"),           # 3: the wasted lane
+        ("read", "decode_chunk"),               # 2: the end is learnt
+        ("release", 0),
+        ("read", "decode_chunk"),               # 3: dropped
+    ]
+    assert eng._blockmgr.available_blocks == eng._blockmgr.num_blocks - 1
+
+
+def test_lookahead_counters_are_counted_and_exported(tiny_model):
+    """A scripted sequence of steps: a program is chained when an earlier
+    one is unread, be it the step's own or the chunk the step before left;
+    a step that returns with its chunk unread is a look-ahead step."""
     _, _, prompts = tiny_model
     eng = _engine(tiny_model, max_slots=4, paged=True, block_size=8)
     st = eng.stats
-    assert (st.dispatches, st.chained_dispatches) == (0, 0)
+    assert (st.dispatches, st.chained_dispatches, st.lookahead_steps,
+            st.wasted_lane_chunks) == (0, 0, 0, 0)
     assert st.chained_dispatch_share == 0.0
     eng.add_request(prompts[0], 20)
     eng.step()                  # a prefill, and the chunk behind it
     assert (st.dispatches, st.chained_dispatches) == (2, 1)
-    eng.step()                  # the chunk alone
-    assert (st.dispatches, st.chained_dispatches) == (3, 1)
+    eng.step()                  # the chunk, behind the unread one
+    assert (st.dispatches, st.chained_dispatches) == (3, 2)
+    assert st.lookahead_steps == 2
     eng.add_request(prompts[6], 2)      # bucket 8
     eng.add_request(prompts[4], 2)      # bucket 16
     eng.add_request(prompts[1], 2)      # bucket 16: one group with it
     eng.step()                  # two prefills and the chunk
-    assert (st.dispatches, st.chained_dispatches) == (6, 3)
+    assert (st.dispatches, st.chained_dispatches) == (6, 5)
     assert st.prefill_calls == 3 and st.prefill_admissions == 4
-    assert st.chained_dispatch_share == 0.5
+    assert st.chained_dispatch_share == 5 / 6
     assert 0 < st.prefill_seconds and 0 < st.decode_seconds
+    while eng.has_work:
+        eng.step()
+    # only the dispatch into an idle engine was not chained; the step
+    # that found nothing to dispatch read what was left and looked no
+    # further
+    assert st.chained_dispatches == st.dispatches - 1
+    assert st.lookahead_steps == st.decode_forwards // eng.chunk
+    assert st.wasted_lane_chunks == 0
     metrics = RouterMetrics()
     sent = InferenceEngineAdapter(eng).engine_metrics()
-    assert (sent["dispatches"], sent["chained_dispatches"]) == (6.0, 3.0)
-    metrics.observe_engine_metrics([sent, {"dispatches": 2.0}, {}])
-    assert metrics.metrics()["serving_engine_chained_dispatch_share"] \
-        == 3.0 / 8.0
+    assert (sent["dispatches"], sent["chained_dispatches"]) == (
+        float(st.dispatches), float(st.dispatches - 1))
+    assert sent["lookahead_steps"] == float(st.lookahead_steps)
+    assert sent["wasted_lane_chunks"] == 0.0
+    metrics.observe_engine_metrics(
+        [sent, {"dispatches": 2.0, "lookahead_steps": 1.0,
+                "wasted_lane_chunks": 3.0}, {}])
+    got = metrics.metrics()
+    assert got["serving_engine_chained_dispatch_share"] \
+        == (st.dispatches - 1) / (st.dispatches + 2.0)
+    assert got["serving_engine_lookahead_steps_total"] \
+        == st.lookahead_steps + 1.0
+    assert got["serving_engine_wasted_lane_chunks_total"] == 3.0
     metrics.observe_engine_metrics([])
     assert metrics.metrics()["serving_engine_chained_dispatch_share"] == 0.0
+    from dlrover_tpu.utils.metric_registry import METRIC_HELP
+
+    for name in ("serving_engine_lookahead_steps_total",
+                 "serving_engine_wasted_lane_chunks_total"):
+        assert name in METRIC_HELP
 
 
-@pytest.mark.parametrize("outside", ["cancel", "spec_step", "drain_fixed"])
-def test_the_device_vector_is_uploaded_again_after(tiny_model, solo_tokens,
-                                                   outside):
-    """What writes the slots' state outside a step's chain of programs
-    drops the device's copy of the last tokens, and the next dispatch
-    uploads the host's: the tokens stay the solo runs'."""
+@pytest.mark.parametrize("outside", ["cancel", "spec_step", "drain_fixed",
+                                     "run", "generate"])
+def test_what_is_in_flight_is_read_or_dropped_first_by(tiny_model,
+                                                       solo_tokens, outside):
+    """What works on the slots outside a step's chain of programs first
+    deals with the chunk a step left in flight.  ``cancel`` drops the
+    request's lane and waits for nothing (the device's vector stays: the
+    chain is whole); a speculating step, ``_drain_fixed``, ``run`` and
+    ``generate`` read it, and where they write the host's last tokens
+    the next dispatch uploads those.  The tokens stay the solo runs'."""
     _, _, prompts = tiny_model
     kw = dict(paged=True, block_size=8)
     if outside == "spec_step":
         kw["speculative_k"] = 3
     eng = _engine(tiny_model, **kw)
     rids = [eng.add_request(prompts[i], _QUEUE[i][1]) for i in (0, 1, 2)]
-    if outside == "drain_fixed":
-        eng._drain_fixed()
-        assert eng.stats.chained_dispatches > 0
-    else:
-        eng.step()
-        assert eng._last_dev is None if outside == "spec_step" \
-            else eng._last_dev is not None
-    if outside == "cancel":
-        assert eng.cancel(rids[1])
-    assert eng._last_dev is None and not eng._unread
+    if outside == "spec_step":
+        # chunk decode behind a verify leaves its chunk in flight ...
+        eng._spec_state = "backoff"
+        eng._spec_cooldown = 100
+    eng.step()
+    assert [u.name for u in eng._unread] == ["decode_chunk"]
+    assert eng._last_dev is not None
+    assert [len(r.output) for r in eng._slot_req] == [1, 1, 1]
     uploads = []
     upload = eng._last_tokens
     eng._last_tokens = lambda: (
         uploads.append(eng._last_dev is None), upload())[1]
-    rids.append(eng.add_request(prompts[4], _QUEUE[4][1]))
-    if outside == "spec_step":
-        eng._spec_state = "backoff"     # chunk decode behind a verify
-        eng._spec_cooldown = 100
-    eng.step()
-    assert uploads[0] and not any(uploads[1:])
-    assert np.array_equal(np.asarray(eng._last_dev), eng._tokens)
+    if outside == "cancel":
+        assert eng.cancel(rids[1])
+        assert eng._slot_req[1] is None and eng._slot_blocks[1] is None
+        (chunk,) = eng._unread
+        assert [row[0] for row in chunk.rows] == [0, 2]
+        assert eng._last_dev is not None
+        rids.append(eng.add_request(prompts[4], _QUEUE[4][1]))
+        eng.step()
+        assert not any(uploads)
+    elif outside == "spec_step":
+        # ... which the verify's step reads before it drafts
+        eng._spec_state = "on"
+        rids.append(eng.add_request(prompts[4], _QUEUE[4][1]))
+        eng.step()
+        assert eng.stats.spec_calls == 1
+        assert eng._last_dev is None and not eng._unread
+        assert all(len(r.output) >= 1 + 4 + 1 for r in eng._slot_req)
+        eng._spec_state = "backoff"
+        del uploads[:]
+        eng.step()
+        assert uploads[0] and not any(uploads[1:])
+    elif outside == "drain_fixed":
+        rids.append(eng.add_request(prompts[4], _QUEUE[4][1]))
+        eng._drain_fixed()
+        assert eng._last_dev is None and not eng._unread
+        assert eng._in_flight == 0
+        del uploads[:]
+        eng.step()
+        assert uploads[0] and not any(uploads[1:])
+    else:
+        rids.append(eng.add_request(prompts[4], _QUEUE[4][1]))
+    if outside == "generate":
+        tokens, _ = eng.generate(prompts[6][None], _QUEUE[6][1])
+        assert tokens[0, prompts[6].size:].tolist() == solo_tokens[6]
+        assert not eng.has_work and eng._last_dev is None
+        return
     done = eng.run()
+    assert not eng.has_work and not eng._unread and eng._in_flight == 0
+    assert np.array_equal(np.asarray(eng._last_tokens()), eng._tokens)
     for i, rid in zip((0, 1, 2, 4), rids):
         if outside == "cancel" and i == 1:
             assert rid not in done
