@@ -107,9 +107,13 @@ def default_loss_fn(
     ``aux["weight"]`` is the number of tokens the mean was taken over
     (used to weight microbatches during gradient accumulation).
 
-    With ``loss_chunk_size`` the lm-head projection is fused into a
-    chunked cross entropy (:func:`fused_lm_head_loss`) — full logits are
-    never materialized.
+    Without ``loss_chunk_size`` the loss reads the model's logits as the
+    head's matmul wrote them, once forward and once backward
+    (``ops/losses.py``: the LABELS are shifted, the cross entropy has its
+    own backward rule).  With ``loss_chunk_size`` the lm-head projection
+    is fused into a chunked cross entropy (:func:`fused_lm_head_loss`) —
+    full logits are never materialized, and the head's matmul runs once
+    more in the backward: bounded memory, not speed.
 
     ``forward_fn(params, batch, return_hidden) -> (out, var_updates)``
     replaces the plain ``model.apply`` (used by pipeline parallelism to
@@ -138,6 +142,28 @@ def default_loss_fn(
                 mutable=["moe_losses"],
             )
 
+    def _targets(batch):
+        """``(labels, mask)`` in the full-length layout of the logits.
+        Without ``labels`` position t predicts token t+1: the LABELS are
+        shifted, never the logits (a slice of them is a copy of the whole
+        array to ``seq - 1`` rows, off the tile, and a pad back in the
+        backward; the chunked path needs ``seq`` chunkable), and the last
+        position has weight 0."""
+        labels, mask = batch.get("labels"), batch.get("loss_mask")
+        if labels is not None:
+            return labels, mask
+        ids = batch["input_ids"]
+        labels = jnp.concatenate(
+            [ids[:, 1:], jnp.zeros_like(ids[:, :1])], axis=1
+        )
+        valid = jnp.ones(ids.shape, jnp.float32).at[:, -1].set(0.0)
+        if mask is not None:
+            # weight of position t is the validity of its TARGET token t+1
+            valid = valid * jnp.concatenate(
+                [mask[:, 1:], jnp.zeros_like(mask[:, :1])], axis=1
+            )
+        return labels, valid
+
     def chunked_loss_fn(params, batch):
         hidden, var_updates = forward_fn(params, batch, return_hidden=True)
         if "lm_head" in params:
@@ -151,24 +177,7 @@ def default_loss_fn(
                 "cannot locate the LM head: expected 'lm_head', "
                 "'embed_tokens', or 'wte' in params"
             )
-        labels = batch.get("labels")
-        mask = batch.get("loss_mask")
-        if labels is None:
-            # shift inside the full-length layout so seq stays chunkable:
-            # position t predicts token t+1; the last position is masked.
-            ids = batch["input_ids"]
-            labels = jnp.concatenate(
-                [ids[:, 1:], jnp.zeros_like(ids[:, :1])], axis=1
-            )
-            valid = jnp.ones(ids.shape, jnp.float32).at[:, -1].set(0.0)
-            if mask is not None:
-                # weight of position t is the validity of its TARGET token
-                # t+1 (same shift the plain path applies as mask[:, 1:])
-                mask = valid * jnp.concatenate(
-                    [mask[:, 1:], jnp.zeros_like(mask[:, :1])], axis=1
-                )
-            else:
-                mask = valid
+        labels, mask = _targets(batch)
         with device_scope("head"):
             loss, weight = fused_lm_head_loss(
                 hidden, kernel, labels, mask, chunk_size=loss_chunk_size,
@@ -178,14 +187,7 @@ def default_loss_fn(
 
     def loss_fn(params, batch):
         logits, var_updates = forward_fn(params, batch, return_hidden=False)
-        labels = batch.get("labels")
-        if labels is None:
-            labels = batch["input_ids"][:, 1:]
-            logits = logits[:, :-1]
-            mask = batch.get("loss_mask")
-            mask = mask[:, 1:] if mask is not None else None
-        else:
-            mask = batch.get("loss_mask")
+        labels, mask = _targets(batch)
         with device_scope("head"):
             loss, weight = masked_language_model_loss(
                 logits, labels, mask, return_weight=True

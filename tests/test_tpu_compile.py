@@ -717,6 +717,13 @@ def test_sparse_cells_step_reads_expert_weights_in_the_stack(
         "gmm": 9 * sparse_layers_a_loop, "tgmm": 3 * sparse_layers_a_loop}
     # all layers' groups in one row: the operand the kernels index into
     assert f"bf16[{stack[0] * stack[1]},{stack[2]},{stack[3]}]" in text
+    # PR 49: nothing of the head is computed twice.  The hybrid cell's step
+    # is rematerialised by the COMPILER to fit; with the loss's ``d_logits``
+    # pinned to memory it ran the head's d-hidden matmul a second time
+    # (``fusion.2177.remat``, 4.4 ms a step on the chip; PERF.md section 6)
+    assert not re.findall(
+        r'^\s+%([\w.\-]*remat[\w.\-]*) = .*op_name="[^"]*[/(]head[/)]',
+        text, re.M)
     if cfg.layers is not None:
         half_a_head = re.compile(
             rf"(?:bf16|f32)\[{rows},{seq},\d+,(?:{cfg.head_dim_ // 2}|"
