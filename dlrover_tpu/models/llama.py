@@ -66,7 +66,8 @@ class LayerSpec:
     rope: RopeSpec = RopeSpec()
     mlp: str = "dense"            # "dense" | "sparse"
     # what mixes tokens: "attn" (the model's attention block) | "kda"
-    # (linear attention with a recurrent state: LlamaConfig.kda_heads)
+    # (linear attention with a recurrent state: LlamaConfig.kda_heads) |
+    # "ssm" (a Mamba-2 state-space layer: LlamaConfig.ssm_heads)
     mixer: str = "attn"
     # what differs between two kinds of LATENT layer in one model (0: the
     # config's ``kv_lora_rank`` / ``qk_nope_head_dim``), and whether the
@@ -173,6 +174,25 @@ class LlamaConfig:
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_rank: int = 0
+    # Mamba-2 (a ``LayerSpec.mixer`` of "ssm"): ``ssm_heads`` heads of
+    # ``ssm_head_dim`` channels, each with a float32 state of ``ssm_head_dim
+    # x ssm_state`` a sequence that decays by ONE scalar a head and token;
+    # ``B`` and ``C`` (``ssm_state`` values each) shared by all heads (one
+    # group); a causal depthwise convolution with bias over the last
+    # ``ssm_conv`` positions ahead of x, B and C; the gate inside the output
+    # norm.  Served only (serving/linear.py)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    # the embedding times this; every residual branch (mixer and MLP) times
+    # this before it is added (Granite's ``embedding_multiplier`` and
+    # ``residual_multiplier``).  Served by the loop of layer kinds only
+    embedding_mult: float = 1.0
+    residual_mult: float = 1.0
+    # the grouped-query block's softmax scale where the model states one
+    # (Granite's ``attention_multiplier``); None: ``head_dim_ ** -0.5``
+    attn_scale: Optional[float] = None
     # a sigmoid gate on the attention output, one a query head, from the
     # layer's normed input
     attn_head_gate: bool = False
@@ -260,6 +280,20 @@ class LlamaConfig:
             self.num_layers - lead)
 
     @property
+    def layer_kinds(self) -> bool:
+        """Whether the served forward is the loop that dispatches on each
+        layer's description (``serving/latent.py verify_step``): latent
+        attention, or a grouped-query model with sparse experts, with a
+        layer that keeps a recurrent state, or with a multiplier or a
+        softmax scale of its own.  Any other dense grouped-query model
+        keeps ``serving/model.py``'s own loop."""
+        return bool(
+            self.kv_lora_rank or self.num_experts
+            or any(s.mixer != "attn" for s in self.layer_specs)
+            or self.embedding_mult != 1.0 or self.residual_mult != 1.0
+            or self.attn_scale is not None)
+
+    @property
     def rope(self) -> RopeSpec:
         """The rotary embedding of a model whose layers are all alike."""
         return self.rope_scaling or RopeSpec(theta=self.rope_theta)
@@ -293,6 +327,14 @@ class LlamaConfig:
                  + 2 * (h * self.kda_rank + self.kda_rank * w) + w
                  + self.kda_heads + h * self.kda_heads + self.kda_head_dim
                  + w * h + 2 * h)
+        elif spec.mixer == "ssm":
+            # W_in to [z | x B C | dt]; the convolution's taps and bias over
+            # x, B and C; a head's dt bias, rate and skip; the gated norm;
+            # W_out; the block's two norms
+            w = self.ssm_heads * self.ssm_head_dim
+            xbc = w + 2 * self.ssm_state
+            n = (h * (w + xbc + self.ssm_heads) + (self.ssm_conv + 1) * xbc
+                 + 3 * self.ssm_heads + w + w * h + 2 * h)
         elif self.kv_lora_rank:
             heads, q = spec.num_heads, self.q_lora_rank
             c, nope, indexed = self.latent_dims(spec)
@@ -663,6 +705,69 @@ class LlamaConfig:
         return cls(**base)
 
     @classmethod
+    def granite_4_h_small(cls, **kw) -> "LlamaConfig":
+        """ibm-granite/granite-4.0-h-small (``granitemoehybrid``) as its
+        config.json has it: 40 layers, ``layer_types`` attention at 5, 15,
+        25 and 35 (0-based) and Mamba-2 everywhere else, a period of ten.
+        A MAMBA layer: 128 heads of 64 channels over a state of 128, one
+        group (B and C shared by every head), a causal depthwise
+        convolution of 4 with bias, one scalar decay a head and token
+        (``ops/pallas/ssm.py``).  An ATTENTION layer: 32 query / 8 KV heads
+        of 128, NO positional encoding, softmax scale 1/128
+        (``attention_multiplier``).  Every layer's MLP: 72 softmax-routed
+        experts of 768, 10 a token, weights over the picks' sum, beside one
+        shared expert of 1536.  The embedding x 12, every residual branch
+        x 0.22, logits / 16; vocabulary 100352, TIED.  Served, not
+        trained.  ``num_layers`` cuts the pattern's depth;
+        ``moe_experts_held`` and ``vocab_size`` give one chip its share.
+
+        Assumed, where the config names a mechanism and not its equation
+        (``perfbench/configs/granite-4.0-h-small-serve.json``):
+        ``intermediate_size`` is one expert's width, the split orders ``[z
+        | xBC | dt]`` and ``[x | B | C]``, the step unclamped, the gate
+        inside the norm and the norm over all 8192 channels, a float32
+        state."""
+        num_layers = int(kw.pop("num_layers", 40))
+        nope = RopeSpec(rotary_fraction=0.0)
+        layers = tuple(
+            LayerSpec(num_heads=int(kw.get("num_heads", 32)), rope=nope,
+                      mixer="attn" if i % 10 == 5 else "ssm", mlp="sparse")
+            for i in range(num_layers))
+        base = dict(
+            vocab_size=100352,
+            hidden_size=4096,
+            intermediate_size=768,
+            num_layers=num_layers,
+            num_heads=32,
+            num_kv_heads=8,
+            head_dim=128,
+            max_seq_len=131072,
+            rms_norm_eps=1e-5,
+            tie_embeddings=True,
+            ssm_heads=128,
+            ssm_head_dim=64,
+            ssm_state=128,
+            ssm_conv=4,
+            embedding_mult=12.0,
+            residual_mult=0.22,
+            attn_scale=0.0078125,
+            logit_scale=1.0 / 16.0,
+            num_experts=72,
+            moe_top_k=10,
+            moe_norm_topk_prob=True,
+            moe_score_fn="softmax",
+            moe_shared_width=1536,
+            moe_per_expert_init=True,
+            moe_aux_loss_coef=0.0,
+            moe_z_loss_coef=0.0,
+            scan_layers=False,
+            remat=False,
+            layers=layers,
+        )
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
     def from_preset(
         cls, name: str, num_layers: int = 0, **kw
     ) -> "LlamaConfig":
@@ -695,7 +800,8 @@ class LlamaConfig:
 
 #: presets the entry points (examples/, the serving worker) can name
 PRESETS = ("tiny", "llama2_7b", "olmoe_1b_7b", "laguna_xs2", "glm5",
-           "sarvam_105b", "kimi_linear_48b", "dots3_note")
+           "sarvam_105b", "kimi_linear_48b", "dots3_note",
+           "granite_4_h_small")
 
 
 def resolve_remat_policy(name: str):
@@ -1195,10 +1301,19 @@ class LlamaModel(nn.Module):
         if any(s.mixer != "attn" for s in cfg.layer_specs):
             raise NotImplementedError(
                 "LlamaModel trains attention layers: a layer whose mixer "
-                "is linear attention (LayerSpec.mixer='kda') is served only "
+                "is linear attention (LayerSpec.mixer='kda') or a "
+                "state-space scan ('ssm') is served only "
                 "(serving/linear.py).  Missing: the chunk kernel's backward "
-                "(ops/pallas/kda.py) and a training layer (ROADMAP Reach "
-                "A6)")
+                "(ops/pallas/kda.py, ops/pallas/ssm.py) and a training "
+                "layer (ROADMAP Reach A6)")
+        if cfg.embedding_mult != 1.0 or cfg.residual_mult != 1.0 \
+                or cfg.attn_scale is not None:
+            raise NotImplementedError(
+                "LlamaModel has no embedding or residual multiplier and no "
+                f"stated softmax scale (embedding_mult={cfg.embedding_mult}, "
+                f"residual_mult={cfg.residual_mult}, attn_scale="
+                f"{cfg.attn_scale}): they are served only "
+                "(serving/latent.py's loop of layer kinds)")
         if cfg.kv_lora_rank or cfg.moe_first_dense:
             raise NotImplementedError(
                 "LlamaModel trains the grouped-query block: latent "

@@ -128,7 +128,7 @@ def _decode_kernel(
     *args,
     block_size: int, pages: int, num_groups: int,
     kv_heads: int, group: int, head_dim: int,
-    quant: bool, packed: bool,
+    quant: bool, packed: bool, scale: Optional[float] = None,
 ):
     if quant:
         (q_ref, k_hbm, v_hbm, ks_ref, vs_ref, o_ref,
@@ -219,7 +219,10 @@ def _decode_kernel(
                 for kvi in range(kv_heads)
             ],
             axis=0,
-        ) / (head_dim ** 0.5)
+        )
+        # the softmax scale a model states for itself, else the head's
+        scores = scores / (head_dim ** 0.5) if scale is None \
+            else scores * scale
         key_pos = g * rows + jax.lax.broadcasted_iota(
             jnp.int32, scores.shape, 1)
         visible = key_pos < lengths_ref[b]
@@ -276,7 +279,7 @@ def streamed_rows(lengths, block_size: int, table_width: int,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("pages_per_block", "interpret"))
+    jax.jit, static_argnames=("pages_per_block", "interpret", "scale"))
 def paged_decode_attention(
     q: jax.Array,        # [B, H, D]
     k_pool: jax.Array,   # [NB, bs, KV, Dc]
@@ -288,6 +291,7 @@ def paged_decode_attention(
     v_scale: Optional[jax.Array] = None,
     pages_per_block: int = 8,
     interpret: bool = False,
+    scale: Optional[float] = None,     # None: head_dim ** -0.5
 ) -> jax.Array:
     b, h, d = q.shape
     nb, bs, kv, dc = k_pool.shape
@@ -317,7 +321,7 @@ def paged_decode_attention(
     kernel = functools.partial(
         _decode_kernel, block_size=bs, pages=p_n,
         num_groups=num_groups, kv_heads=kv, group=g, head_dim=d,
-        quant=quant, packed=packed,
+        quant=quant, packed=packed, scale=scale,
     )
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [pl.BlockSpec((1, kv, g, d), q_map), any_spec, any_spec]

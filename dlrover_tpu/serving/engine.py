@@ -160,14 +160,15 @@ class EngineStats:
     # here (a device reduction carried in the cache beside the pools)
     moe_picks: int = 0
     moe_picks_held: int = 0
-    # linear-attention layers (LayerSpec.mixer "kda"), whose state is one
-    # float32 matrix a head and slot: host arithmetic, summed over decode
-    # forwards and layers
+    # layers that keep a state a slot (LayerSpec.mixer "kda" or "ssm"),
+    # one float32 matrix a head and slot: host arithmetic, summed over
+    # decode forwards and layers
     state_bytes_live: int = 0     # read + written for the slots that
     #                               decoded: 2 x a slot's state each
     state_bytes_streamed: int = 0  # ... for the slots the decode kernel's
-    #                               grid walked (kda_decode_step: the
-    #                               active ones; the jnp path walks all)
+    #                               grid walked (kda_decode_step,
+    #                               ssm_decode_step: the active ones;
+    #                               the jnp path walks all)
     state_resets_total: int = 0   # slots whose state a prompt's first
     #                               chunk started from zeros
     # window layers of latent attention (LayerSpec.window), whose rows live
@@ -190,6 +191,10 @@ class EngineStats:
     kda_chunk_rows_real: int = 0  # prompt tokens their chunk kernel took
     kda_chunk_rows_padded: int = 0  # ... and the rows of the 64-token
     #                               chunks it computed for them
+    # state-space layers (LayerSpec.mixer "ssm"): the ``state_*`` counters
+    # above count their states too; their chunk kernel's rows
+    ssm_chunk_rows_real: int = 0
+    ssm_chunk_rows_padded: int = 0  # ... in chunks of 128 tokens
 
     @property
     def decode_tokens_per_sec(self) -> float:
@@ -396,10 +401,14 @@ class InferenceEngine:
         a row costs 1 536 bytes: ``serving/latent.py``).
         Such a model needs ``paged=True``, keeps its rows in the model's
         dtype (``kv_dtype`` None, ``kv_budget_x`` 1) and runs on one
-        device with unquantized weights.  Its layers of LINEAR attention
-        (``LayerSpec.mixer`` "kda") keep no rows: the pools are an
+        device with unquantized weights.  A model's layers that keep a
+        recurrent STATE (``LayerSpec.mixer`` "kda", linear attention, beside
+        latent attention; "ssm", a Mamba-2 scan, beside grouped-query
+        attention) keep no rows: the pools are an
         attention layer each, and beside them a float32 state a SLOT and
-        KDA layer (``kda_state`` [slots, H, d, d], ``kda_conv``), the same
+        such layer under the kind's name (``kda_state`` [slots, H, d, d] and
+        ``kda_conv``, or ``ssm_state`` [slots, H, P, N] and ``ssm_conv``:
+        ``serving/linear.py state_shapes``), ONE mechanism for both, the same
         size at token 1 and at token 1 000 000, zeroed inside the program
         that takes a slot's first prompt chunk, carried from chunk to
         chunk, held still while the slot is idle or prefilling
@@ -475,18 +484,24 @@ class InferenceEngine:
         # the unfused projection layout (fused [q|k|v] columns would
         # shard head-incorrectly).
         self.mesh = mesh
-        # linear-attention layers (serving/linear.py): a state a SLOT, not
-        # rows in blocks.  What cannot be right yet is refused by what is
-        # missing, not served wrongly
-        self._kda_layers = sum(s.mixer == "kda" for s in cfg.layer_specs)
-        if self._kda_layers:
-            if not cfg.kv_lora_rank:
+        # layers that keep a recurrent state (serving/linear.py: linear
+        # attention, "kda", or a state-space scan, "ssm"): a state a SLOT,
+        # not rows in blocks, of ONE kind a model.  What cannot be right yet
+        # is refused by what is missing, not served wrongly
+        kinds = sorted({s.mixer for s in cfg.layer_specs
+                        if s.mixer != "attn"})
+        self._state_kind = kinds[0] if kinds else None
+        self._state_layers = sum(s.mixer != "attn" for s in cfg.layer_specs)
+        if kinds:
+            name = {"kda": "linear-attention", "ssm": "state-space"}.get(
+                kinds[0], kinds[0])
+            if len(kinds) > 1:
                 raise ValueError(
-                    "linear-attention layers are served beside latent "
-                    "attention only (serving/latent.py's layer loop)")
+                    f"layers of {kinds} in one model: a slot's recurrent "
+                    "state is of one kind (serving/linear.py state_shapes)")
             if prefix_sharing:
                 raise ValueError(
-                    "prefix_sharing=True with linear-attention layers: a "
+                    f"prefix_sharing=True with {name} layers: a "
                     "warm start behind a shared prefix needs the recurrent "
                     "state at the prefix's end, and nothing keeps it.  "
                     "Missing: a snapshot of recurrent state at a shared "
@@ -494,18 +509,18 @@ class InferenceEngine:
                     "A6); pass prefix_sharing=False")
             if self.speculative_k:
                 raise ValueError(
-                    f"speculative_k={speculative_k!r} with linear-attention "
+                    f"speculative_k={speculative_k!r} with {name} "
                     "layers: a rejected draft has already advanced the "
                     "state.  Missing: roll-back of recurrent state under "
                     "drafts (ROADMAP Reach A6); pass speculative_k=0")
             if not prefill_chunk:
                 raise ValueError(
-                    "linear-attention layers take their prompts in chunks "
+                    f"{name} layers take their prompts in chunks "
                     "(the state is carried from one to the next): pass "
                     "prefill_chunk > 0")
             if mesh is not None:
                 raise ValueError(
-                    "a mesh with linear-attention layers: a slot's state "
+                    f"a mesh with {name} layers: a slot's state "
                     "is kept whole on one device.  Missing: the recurrent "
                     "state and its kernels sharded over heads (ROADMAP "
                     "Reach A6)")
@@ -538,7 +553,16 @@ class InferenceEngine:
                     "whole on one device.  Missing: the rings sharded "
                     "with the latent pools (ROADMAP Reach A4)")
         # every prompt goes through the chunked path: no bucketed prefill
-        self._chunked_only = bool(self._kda_layers or windows)
+        # (nor has the loop of layer kinds one behind its grouped-query
+        # block: serving/latent.py prefill)
+        self._chunked_only = bool(
+            self._state_layers or windows
+            or (cfg.layer_kinds and not cfg.kv_lora_rank))
+        if self._chunked_only and not prefill_chunk:
+            raise ValueError(
+                "a grouped-query model with sparse experts or multipliers "
+                "is served by the loop of layer kinds (serving/latent.py), "
+                "which takes its prompts in chunks: pass prefill_chunk > 0")
         self.params = serving_params_from_llama(
             variables, cfg, int8=int8, fuse=mesh is None)
         # speculative slack: a verify near the end of a sequence writes
@@ -588,11 +612,18 @@ class InferenceEngine:
         # in a latent pool and, of a model with an indexer, an index-key
         # pool
         self._latent = bool(cfg.kv_lora_rank)
-        if self._latent and not (
+        # the loop of layer kinds (``LlamaConfig.layer_kinds``): latent
+        # attention, or grouped-query layers beside a state a slot or
+        # sparse MLPs; its programs count the experts' picks and keep a
+        # witness
+        self._kinds = bool(cfg.layer_kinds)
+        if self._kinds and not (
                 self.paged and self.kv_dtype is None and mesh is None
                 and not int8):
             raise ValueError(
-                "a latent-attention model is served from paged pools in "
+                "a latent-attention model, and any model of layer kinds "
+                "(a recurrent state a slot, sparse experts), is served "
+                "from paged pools in "
                 "the model's dtype on one device: pass paged=True, no "
                 "kv_dtype, no mesh, int8=False")
         if self.paged:
@@ -647,19 +678,23 @@ class InferenceEngine:
                         else cfg.head_dim_)
             kvd = (n_blocks, self.block_size,
                    cfg.num_kv_heads, code_dim)
-            if self._latent:
+            if self._kinds:
                 from dlrover_tpu.serving.latent import latent_row_width
 
                 shape = (n_blocks, self.block_size)
                 paged_specs = [s for s in cfg.layer_specs
                                if s.mixer == "attn" and not s.window]
+                # one pool (K and V: two) an ATTENTION layer that sees
+                # every key: a layer that keeps a state a slot keeps no
+                # rows, a window layer a ring a slot
+                pools = {"latent_pool": [
+                    jnp.zeros(shape + (latent_row_width(cfg, s),),
+                              cfg.dtype) for s in paged_specs]} \
+                    if self._latent else {
+                        name: [jnp.zeros(kvd, cfg.dtype) for _ in paged_specs]
+                        for name in ("k_pool", "v_pool")}
                 self._cache = {
-                    # one pool an ATTENTION layer that sees every key: a
-                    # layer of linear attention keeps no rows, a window
-                    # layer a ring a slot
-                    "latent_pool": [
-                        jnp.zeros(shape + (latent_row_width(cfg, s),),
-                                  cfg.dtype) for s in paged_specs],
+                    **pools,
                     "table": jnp.asarray(self._table_np),
                     # the slot whose forward the programs hand back
                     # (``watch``); -1: none
@@ -683,19 +718,20 @@ class InferenceEngine:
                     # [picks, picks on held experts], wrapping: the host
                     # adds differences (_book_moe_picks)
                     self._cache["moe_picks"] = jnp.zeros(2, jnp.uint32)
-                if self._kda_layers:
+                if self._state_layers:
                     from dlrover_tpu.serving.linear import state_shapes
 
-                    state, conv = state_shapes(cfg, self.max_slots)
-                    # indexed by SLOT, donated through every program like
-                    # the pools; zeroed inside the program that takes a
-                    # slot's first prompt chunk
-                    self._cache["kda_state"] = [
+                    state, conv = state_shapes(cfg, self.max_slots,
+                                               self._state_kind)
+                    # "<kind>_state", "<kind>_conv": indexed by SLOT,
+                    # donated through every program like the pools; zeroed
+                    # inside the program that takes a slot's first chunk
+                    self._cache[self._state_kind + "_state"] = [
                         jnp.zeros(state, jnp.float32)
-                        for _ in range(self._kda_layers)]
-                    self._cache["kda_conv"] = [
+                        for _ in range(self._state_layers)]
+                    self._cache[self._state_kind + "_conv"] = [
                         jnp.zeros(conv, cfg.dtype)
-                        for _ in range(self._kda_layers)]
+                        for _ in range(self._state_layers)]
             elif self.kv_dtype in ("int8", "int4"):
                 from dlrover_tpu.models.quantize import KV_SCALE_DTYPE
 
@@ -784,10 +820,10 @@ class InferenceEngine:
         self._watch_slot = -1
         self.witness_log: List[Dict[str, Any]] = []
         # slots one prefill-chunk dispatch advances: all that prefill,
-        # or of a latent model one (its attention walks a row's live key
-        # blocks one row after another anyway, and one group size is one
-        # program to compile, not max_slots)
-        self._prefill_group = 1 if self._latent else self.max_slots
+        # or of a model of layer kinds one (its attention walks a row's
+        # live key blocks one row after another anyway, and one group size
+        # is one program to compile, not max_slots)
+        self._prefill_group = 1 if self._kinds else self.max_slots
         # names of the two per-layer pool lists a bucketed prefill's
         # results are scattered into
         self._pool_names = ("k_pool", "v_pool") if not self._latent else (
@@ -836,7 +872,7 @@ class InferenceEngine:
         if req in ("xla", "pallas"):
             self.attention_impl_why = "requested"
             return req, None
-        if self._latent and not self._kernel_interpret:
+        if self._kinds and not self._kernel_interpret:
             self.attention_impl_why = (
                 "auto: the decode kernels read live pages only, the gather "
                 "the whole table; not measured")
@@ -2004,17 +2040,19 @@ class InferenceEngine:
                 "query_tiles_live": live}
 
     def _book_state_bytes(self, active: np.ndarray) -> Dict[str, int]:
-        """Book what the linear-attention layers of one decode chunk move
+        """Book what the layers that keep a state a slot (linear attention
+        or a state-space scan) move in one decode chunk
         of their slots' states: read + written for the slots ``active``
         (live), and for the slots the path walks (the decode kernel's
         grid: the active ones; the ``jnp`` step: every slot).  Returns the
         two for the dispatch's span ({} for a model with no such layer)."""
-        if not self._kda_layers:
+        if not self._state_layers:
             return {}
         from dlrover_tpu.ops.pallas.kda import decode_states_walked
 
-        one = 2 * int(np.prod(self._cache["kda_state"][0].shape[1:])) * 4
-        each = one * self._kda_layers * self.chunk
+        held = self._cache[self._state_kind + "_state"][0]
+        one = 2 * int(np.prod(held.shape[1:])) * 4
+        each = one * self._state_layers * self.chunk
         live = decode_states_walked(active) * each
         walked = live if self.attention_impl == "pallas" \
             else self.max_slots * each
@@ -2055,34 +2093,41 @@ class InferenceEngine:
         return book
 
     def _book_state_chunks(self, starts, ends) -> Dict[str, int]:
-        """Book what the linear-attention layers' chunk kernel takes of
+        """Book what the chunk kernel of the layers that keep a state a
+        slot takes of
         prompt chunks whose real tokens stand at ``starts[i] .. ends[i] -
-        1``: the real tokens, the rows of the 64-token chunks it computes
+        1``: the real tokens, the rows of the chunks (KDA's 64 tokens, the
+        state-space scan's 128) it computes
         for them (one layer's, as every layer walks the same), and the
         slots whose state starts from zeros.  Returns them for the
-        dispatch's span ({} for a model with no such layer)."""
-        if not self._kda_layers:
+        dispatch's span, the first two under the kind's name ({} for a
+        model with no such layer)."""
+        if not self._state_layers:
             return {}
-        from dlrover_tpu.ops.pallas.kda import CHUNK, chunk_rows
+        from dlrover_tpu.ops.pallas import kda, ssm
 
+        kind = self._state_kind
+        chunk = {"kda": kda, "ssm": ssm}[kind].CHUNK     # its kernel's
         # (the ``jnp`` recurrence walks a program's every row)
         kernel = self.attention_impl == "pallas" \
-            and self.prefill_chunk % CHUNK == 0
-        real, padded = chunk_rows(
+            and self.prefill_chunk % chunk == 0
+        real, padded = kda.chunk_rows(
             np.asarray(ends) - np.asarray(starts), self.prefill_chunk,
-            CHUNK if kernel else self.prefill_chunk)
+            chunk if kernel else self.prefill_chunk)
         resets = int(np.count_nonzero(np.asarray(starts) == 0))
-        self.stats.kda_chunk_rows_real += real
-        self.stats.kda_chunk_rows_padded += padded
+        book = {f"{kind}_chunk_rows_real": real,
+                f"{kind}_chunk_rows_padded": padded}
+        for name, n in book.items():
+            setattr(self.stats, name, getattr(self.stats, name) + n)
         self.stats.state_resets_total += resets
-        return {"kda_chunk_rows_real": real,
-                "kda_chunk_rows_padded": padded, "state_resets": resets}
+        return dict(book, state_resets=resets)
 
     @property
     def cache_nbytes(self) -> int:
         """Bytes of everything this engine keeps a sequence in: the paged
-        pools (or the dense K/V) and, a slot, the linear-attention
-        layers' states and convolution rows."""
+        pools (or the dense K/V) and, a slot, the states and convolution
+        rows of the layers that keep one (linear attention, a state-space
+        scan)."""
         return sum(self.cache_nbytes_by_kind.values())
 
     @property
@@ -2095,7 +2140,8 @@ class InferenceEngine:
         for key, val in self._cache.items():
             if isinstance(val, list):
                 kind = "window" if key.startswith("window_") else (
-                    "state" if key.startswith("kda_") else "paged")
+                    "state" if key.endswith(("_state", "_conv"))
+                    else "paged")
                 kinds[kind] += int(sum(x.nbytes for x in val))
         return kinds
 
@@ -2115,11 +2161,13 @@ class InferenceEngine:
         selection of keys and a share of the experts otherwise leave to
         show only in the logits; of a model with linear-attention layers
         also the slot's recurrent state behind each forward, of the first
-        and the last such layer (``kda_state``).  ``None`` stops.  A
-        latent-attention model's engine only: no other program keeps a
+        and the last such layer (``kda_state``; of state-space layers
+        ``ssm_state`` and ``ssm_conv``).  ``None`` stops.  The engine of a
+        model of layer kinds only (latent attention, a state a slot,
+        sparse experts): no other program keeps a
         witness."""
-        if not self._latent:
-            raise ValueError("only a latent-attention model's programs "
+        if not self._kinds:
+            raise ValueError("only the programs of the loop of layer kinds "
                              "keep a witness (serving/latent.py)")
         self._watch = wanted
 

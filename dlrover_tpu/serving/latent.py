@@ -5,12 +5,24 @@ whole context (``LlamaConfig.sarvam_105b``): the blocks
 ``serving/model.py``'s ``verify_step`` and ``prefill`` run in place of the
 grouped-query ones.
 
-A model may mix such layers with layers of LINEAR attention
-(``LayerSpec.mixer`` "kda": ``serving/linear.py``, a recurrent state a
-slot in place of rows in the pools): the layer loop of :func:`verify_step`
-dispatches on each layer's description, the pools are indexed by the
-attention layers alone, and a ``RopeSpec`` whose ``rotary_fraction`` is 0
-(``LlamaConfig.kimi_linear_48b``) rotates nothing.
+A model may mix such layers with layers that keep a recurrent STATE A
+SLOT in place of rows in the pools (``LayerSpec.mixer`` "kda", linear
+attention, or "ssm", a Mamba-2 scan: ``serving/linear.py``): the layer
+loop of :func:`verify_step` dispatches on each layer's description, the
+pools are indexed by the attention layers alone, and a ``RopeSpec`` whose
+``rotary_fraction`` is 0 (``LlamaConfig.kimi_linear_48b``) rotates
+nothing.
+
+This loop is THE loop of layer kinds (``LlamaConfig.layer_kinds``): the
+attention block it calls is latent or GROUPED-QUERY by what the config
+says.  A model with no ``kv_lora_rank`` whose layers are sparse or keep a
+state (``LlamaConfig.granite_4_h_small``) runs :func:`_gqa_layer` where a
+latent model runs its projections: ``serving/model.py``'s fused ``W_qkv``
+and ``W_o``, K/V rows in ``k_pool`` / ``v_pool`` under the one block
+table, ``paged_decode_attention`` at decode and a walk of the live key
+blocks for a run, the softmax scale the config's (``attn_scale``).  The
+embedding and every residual branch are scaled where the config says so
+(``embedding_mult``, ``residual_mult``).
 
 A model's latent layers may be of MORE THAN ONE KIND
 (``LlamaConfig.dots3_note``): each reads its own geometry from its
@@ -91,6 +103,7 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.models.llama import (LayerSpec, LlamaConfig, RopeSpec,
+                                      apply_rope, rope_frequencies,
                                       rope_inverse_frequencies)
 from dlrover_tpu.models.moe import grouped_matmul, route
 from dlrover_tpu.serving.model import _lm_head, _mm, _rmsnorm
@@ -599,17 +612,21 @@ def _mlp(lp, h, cfg: LlamaConfig, dtype, counted):
         return _swiglu(h, lp["wgu"], lp["down"], dtype), None
 
 
-def _kda_mixer(lp, h, state, conv, cfg: LlamaConfig, dtype, positions,
-               slots, n_real, active, impl: str, interpret: bool):
-    """A linear-attention layer (``serving/linear.py``) on ``h`` [B, K, E]:
-    ``(y, state, conv, decay)``, the layer's per-slot state advanced and
+def _state_mixer(kind: str, lp, h, state, conv, cfg: LlamaConfig, dtype,
+                 positions, slots, n_real, active, impl: str,
+                 interpret: bool):
+    """A layer that keeps a state a slot (``kind`` "kda" | "ssm":
+    ``serving/linear.py BLOCKS``) on ``h`` [B, K, E]: ``(y, state, conv,
+    decay)``, the layer's per-slot state advanced and, of a KDA layer,
     what its decay was computed from and to ([B, K, 2, H, d]:
-    ``kda_decode``).  One query a slot over every slot is a decode
-    forward (``active`` [B] or None: all); a run of queries of the slots ``slots`` is a prompt chunk, a
-    row at a time, from zeros where the run starts at position 0, to its
-    ``n_real``-th token (None: all of it)."""
-    from dlrover_tpu.serving.linear import kda_decode, kda_run
+    ``kda_decode``; None of a state-space layer).  One query a slot over
+    every slot is a decode forward (``active`` [B] or None: all); a run of
+    queries of the slots ``slots`` is a prompt chunk, a row at a time,
+    from zeros where the run starts at position 0, to its ``n_real``-th
+    token (None: all of it)."""
+    from dlrover_tpu.serving.linear import BLOCKS
 
+    decode_fn, run_fn = BLOCKS[kind]
     b, klen, _ = h.shape
     if slots is None:
         if klen != 1:
@@ -620,13 +637,14 @@ def _kda_mixer(lp, h, state, conv, cfg: LlamaConfig, dtype, positions,
                 "state under drafts (ROADMAP Reach A6)")
         if active is None:
             active = jnp.ones((b,), bool)
-        y, state, conv, decay = kda_decode(
+        y, state, conv, decay = decode_fn(
             lp, h[:, 0], state, conv, active, cfg, dtype, impl, interpret)
-        return y[:, None], state, conv, decay[:, None]
+        return y[:, None], state, conv, \
+            None if decay is None else decay[:, None]
     ys, decays = [], []
     for r in range(b):
         slot = slots[r]
-        y, s_new, c_new, decay = kda_run(
+        y, s_new, c_new, decay = run_fn(
             lp, h[r], jnp.take(state, slot, axis=0),
             jnp.take(conv, slot, axis=1), positions[r] == 0,
             jnp.asarray(klen, jnp.int32) if n_real is None else n_real[r],
@@ -635,7 +653,105 @@ def _kda_mixer(lp, h, state, conv, cfg: LlamaConfig, dtype, positions,
         conv = conv.at[:, slot].set(c_new)
         ys.append(y)
         decays.append(decay)
-    return jnp.stack(ys), state, conv, jnp.stack(decays)
+    return jnp.stack(ys), state, conv, \
+        None if decays[0] is None else jnp.stack(decays)
+
+
+def _gqa_attend_run(q, q_pos, k_pool, v_pool, table_row, n_real,
+                    scale: float, pages: int):
+    """A run of queries of ONE sequence against its cached K/V rows, this
+    run's own among them: ``q`` [K, H, D], ``q_pos`` [K] ascending,
+    ``table_row`` [MB] (a multiple of ``pages``).  Causal softmax attention
+    with a running maximum over the key blocks up to the last REAL query's
+    position (``n_real``: :func:`_attend_run`), never the whole table; a
+    query group a KV head, the cache not expanded.  [K, H, D] float32."""
+    klen, heads, d = q.shape
+    bs, kv = k_pool.shape[1:3]
+    kb = pages * bs
+    n_blocks = table_row.shape[0] // pages
+    last = q_pos[-1] if n_real is None else q_pos[n_real - 1]
+    n_live = jnp.minimum((last + kb) // kb, n_blocks)
+    qg = q.reshape(klen, kv, heads // kv, d)
+
+    def block(pool, j):
+        ids = jax.lax.dynamic_slice_in_dim(table_row, j * pages, pages)
+        return jnp.take(pool, ids, axis=0).reshape(kb, kv, d)
+
+    def attend_block(j, carry):
+        m, l, acc = carry
+        s = jnp.einsum("qkgd,skd->qkgs", qg, block(k_pool, j).astype(q.dtype),
+                       preferred_element_type=jnp.float32) * scale
+        keep = (j * kb + jnp.arange(kb))[None, :] <= q_pos[:, None]
+        s = jnp.where(keep[:, None, None, :], s, _NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        alpha = jnp.exp(jnp.where(jnp.isfinite(m), m, safe) - safe)
+        p = jnp.exp(s - safe[..., None])
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "qkgs,skd->qkgd", p.astype(q.dtype),
+            block(v_pool, j).astype(q.dtype),
+            preferred_element_type=jnp.float32)
+        return m_new, alpha * l + p.sum(axis=-1), acc
+
+    shape = qg.shape[:3]
+    m, l, acc = jax.lax.fori_loop(
+        0, n_live, attend_block,
+        (jnp.full(shape, _NEG_INF, jnp.float32),
+         jnp.zeros(shape, jnp.float32), jnp.zeros(qg.shape, jnp.float32)))
+    return (acc / jnp.maximum(l, 1e-30)[..., None]).reshape(klen, heads, d)
+
+
+def _gqa_layer(lp, h, k_pool, v_pool, table, run_table, cfg: LlamaConfig,
+               spec: LayerSpec, dtype, positions, pos_k, lengths, n_real,
+               decode: bool, impl: str, interpret: bool):
+    """A GROUPED-QUERY attention layer on ``h`` [B, K, E]: ``(y, k_pool,
+    v_pool)``, the block's output and the layer's pools with this
+    forward's rows written.  One query a slot over every slot with
+    ``impl == "pallas"`` is ``paged_decode_attention`` over each slot's
+    live pages; every other shape walks a row's live key blocks
+    (:func:`_gqa_attend_run`).  Rotated where the layer's ``RopeSpec`` says
+    so, scaled by the config's ``attn_scale`` where it states one."""
+    from dlrover_tpu.serving.model import _qkv
+
+    b, klen, _ = h.shape
+    d = cfg.head_dim_
+    with device_scope("attn_proj"):
+        q, k, v = _qkv(lp, h, cfg, dtype)
+        if spec.rope.rotary_fraction:
+            angles = rope_frequencies(d, cfg.max_seq_len,
+                                      spec.rope.theta)[pos_k]
+            q, k = apply_rope(q, angles), apply_rope(k, angles)
+    k_pool = scatter_tokens(k_pool, table, k.astype(k_pool.dtype), positions)
+    v_pool = scatter_tokens(v_pool, table, v.astype(v_pool.dtype), positions)
+    scale = float(d ** -0.5 if cfg.attn_scale is None else cfg.attn_scale)
+    with device_scope("paged_attn"):
+        if decode and impl == "pallas":
+            from dlrover_tpu.ops.pallas.paged_attention import (
+                paged_decode_attention,
+            )
+
+            o = paged_decode_attention(
+                q[:, 0], k_pool, v_pool, table, lengths, scale=scale,
+                interpret=interpret)[:, None]
+        else:
+            if decode:
+                run_table = _pad_table(table, KEY_BLOCK_PAGES)
+            o = jax.lax.map(
+                lambda a: _gqa_attend_run(a[0], a[1], k_pool, v_pool, a[2],
+                                          a[3], scale, KEY_BLOCK_PAGES),
+                (q, pos_k, run_table, n_real))
+    o = o.astype(dtype).reshape(b, klen, -1)
+    with device_scope("attn_proj"):
+        return _mm(o, lp["wo"], dtype), k_pool, v_pool
+
+
+def _residual(x, y, cfg: LlamaConfig):
+    """The residual stream with a branch's output added, times the
+    config's ``residual_mult`` where it has one."""
+    if cfg.residual_mult == 1.0:
+        return x + y
+    return (x.astype(jnp.float32) + cfg.residual_mult
+            * y.astype(jnp.float32)).astype(x.dtype)
 
 
 def _window_layer(lp, h, held, cfg: LlamaConfig, spec: LayerSpec, dtype,
@@ -691,9 +807,10 @@ def _pad_table(table: jax.Array, pages: int) -> jax.Array:
 def verify_step(
     params: Dict[str, Any],
     cfg: LlamaConfig,
-    cache: Dict[str, Any],   # {"latent_pool", "index_pool": lists, an
-    tokens: jax.Array,       #   ATTENTION layer each; "kda_state",
-                             #   "kda_conv": a KDA layer each, by slot;
+    cache: Dict[str, Any],   # {"latent_pool", "index_pool" (or "k_pool",
+    tokens: jax.Array,       #   "v_pool"): lists, an ATTENTION layer
+                             #   each; "<kind>_state", "<kind>_conv": a
+                             #   layer of that kind each, by slot;
                              #   "table"; "moe_picks"}
     positions: jax.Array,
     slots: Optional[jax.Array] = None,
@@ -727,15 +844,21 @@ def verify_step(
     the slot's recurrent state behind this forward of the first and the
     last such layer, and ``kda_decay`` [2, H, d], the first such layer's
     ``(f, g)`` at the slot's query (a run: its last real one): the
-    log-decay ``g`` beside the float32 sums ``f`` it is a function of."""
+    log-decay ``g`` beside the float32 sums ``f`` it is a function of; of
+    a model with state-space layers ``ssm_state`` [2, H, P, N] and
+    ``ssm_conv`` [2, taps - 1, H P + 2 N], the slot's state and convolution
+    rows behind this forward of the first and the last such layer."""
     dtype = cfg.dtype
     b, klen = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0)            # [B, K, E]
+    if cfg.embedding_mult != 1.0:
+        x = (x.astype(jnp.float32) * cfg.embedding_mult).astype(x.dtype)
     pos_k = positions[:, None] + jnp.arange(klen)[None, :]   # [B, K]
     table = cache["table"]
     if slots is not None:
         table = jnp.take(table, slots, axis=0)
     decode = klen == 1 and slots is None and logits_index is None
+    run_table = n_real = None
     if decode:
         lengths = positions.astype(jnp.int32) + 1
         counted = jnp.ones((b, 1), bool)
@@ -743,6 +866,7 @@ def verify_step(
             lengths = jnp.where(active, lengths, 0)
             counted = active[:, None]
     else:
+        lengths = None
         run_table = _pad_table(table, KEY_BLOCK_PAGES)
         counted = jnp.ones((b, klen), bool) if logits_index is None else (
             jnp.arange(klen)[None, :] <= logits_index[:, None])
@@ -755,16 +879,19 @@ def verify_step(
         watch = jnp.clip(watch, 0, b - 1) if slots is None \
             else jnp.argmax(slots == watch)
     latent_pools, index_pools, selections, seen = [], [], [], {}
+    k_pools, v_pools = [], []
     states, convs, rings = [], [], []
+    kind = next((s.mixer for s in cfg.layer_specs if s.mixer != "attn"),
+                None)                  # the one kind of state a slot
     for lp, spec in zip(params["layers"], cfg.layer_specs):
         h = _rmsnorm(x, lp["input_norm"], cfg.rms_norm_eps).astype(dtype)
-        if spec.mixer == "kda":
-            y, state, conv, decay = _kda_mixer(
-                lp, h, cache["kda_state"][len(states)],
-                cache["kda_conv"][len(convs)], cfg, dtype, positions,
+        if spec.mixer != "attn":
+            y, state, conv, decay = _state_mixer(
+                spec.mixer, lp, h, cache[kind + "_state"][len(states)],
+                cache[kind + "_conv"][len(convs)], cfg, dtype, positions,
                 slots, None if decode else n_real, active if decode
                 else None, attention_impl, kernel_interpret)
-            if watch is not None and not states:
+            if watch is not None and not states and decay is not None:
                 # the first such layer's decay at the watched row's query
                 # (a run: its last real one), beside what it came from
                 at = jnp.zeros((), jnp.int32) if logits_index is None \
@@ -773,7 +900,16 @@ def verify_step(
                     jnp.take(decay, watch, axis=0), at, axis=0)
             states.append(state)
             convs.append(conv)
-            x = x + y
+            x = _residual(x, y, cfg)
+        elif not cfg.kv_lora_rank:
+            y, k_new, v_new = _gqa_layer(
+                lp, h, cache["k_pool"][len(k_pools)],
+                cache["v_pool"][len(v_pools)], table, run_table, cfg, spec,
+                dtype, positions, pos_k, lengths, n_real, decode,
+                attention_impl, kernel_interpret)
+            k_pools.append(k_new)
+            v_pools.append(v_new)
+            x = _residual(x, y, cfg)
         elif spec.window:
             y, held = _window_layer(
                 lp, h, cache["window_ring"][len(rings)], cfg, spec, dtype,
@@ -786,7 +922,7 @@ def verify_step(
                 seen.update(window_in=jnp.take(h, watch, axis=0),
                             window_out=jnp.take(y, watch, axis=0))
             rings.append(held)
-            x = x + y
+            x = _residual(x, y, cfg)
         else:
             i = len(latent_pools)
             qq, row, q_i, k_i, w = _projections(lp, h, cfg, spec, pos_k,
@@ -832,7 +968,7 @@ def verify_step(
                     and not latent_pools:
                 # the first full layer's output for the watched row
                 seen["full_out"] = jnp.take(y, watch, axis=0)
-            x = x + y
+            x = _residual(x, y, cfg)
             latent_pools.append(lat)
             if idx is not None:
                 index_pools.append(idx)
@@ -843,23 +979,35 @@ def verify_step(
         if n is not None and watch is not None and "sparse_in" not in seen:
             seen.update(sparse_in=jnp.take(h, watch, axis=0),
                         sparse_out=jnp.take(y, watch, axis=0))
-        x = x + y
+        x = _residual(x, y, cfg)
 
     x = _rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
     if logits_index is not None:
         x = jnp.take_along_axis(
             x, logits_index.astype(jnp.int32)[:, None, None], axis=1)
     logits = _lm_head(params, x.astype(dtype), cfg)
-    out_cache = dict(cache, latent_pool=latent_pools)
+    out_cache = dict(cache, latent_pool=latent_pools) if cfg.kv_lora_rank \
+        else dict(cache, k_pool=k_pools, v_pool=v_pools)
     if states:
-        out_cache.update(kda_state=states, kda_conv=convs)
+        out_cache.update({kind + "_state": states, kind + "_conv": convs})
         if watch is not None:
             # the watched slot's state behind this forward, of the first
             # and the last layer that keeps one
             at = watch if slots is None else jnp.take(slots, watch)
-            seen["kda_state"] = jnp.stack(
+            seen[kind + "_state"] = jnp.stack(
                 [jnp.take(states[0], at, axis=0),
                  jnp.take(states[-1], at, axis=0)])
+            if kind == "ssm":
+                # (a head's [P, N], not the kernels' kept layout) ... and
+                # its convolution rows (KDA's witness has the decay's two
+                # sides in their place)
+                from dlrover_tpu.ops.pallas.ssm import unpack_state
+
+                seen["ssm_state"] = unpack_state(seen["ssm_state"],
+                                                 cfg.ssm_head_dim)
+                seen["ssm_conv"] = jnp.stack(
+                    [jnp.take(convs[0], at, axis=1),
+                     jnp.take(convs[-1], at, axis=1)])
     if cfg.index_topk:
         out_cache["index_pool"] = index_pools
     if rings:
@@ -895,12 +1043,15 @@ def prefill(params: Dict[str, Any], cfg: LlamaConfig, tokens: jax.Array,
     no pool behind it: nothing for the kernel to save).
     The experts' picks of this path are not counted."""
     dtype = cfg.dtype
-    if any(s.mixer != "attn" or s.window for s in cfg.layer_specs):
+    if any(s.mixer != "attn" or s.window for s in cfg.layer_specs) \
+            or not cfg.kv_lora_rank:
         raise ValueError(
             "a bucketed prefill hands back cache rows to scatter into "
-            "blocks, and a linear-attention layer keeps a state a slot, a "
-            "window layer a ring a slot: their prompts go "
-            "through the chunked path (InferenceEngine(prefill_chunk=...))")
+            "blocks, and a linear-attention or state-space layer keeps a "
+            "state a slot, a window layer a ring a slot; the grouped-query "
+            "block of this loop has no bucketed path either: their prompts "
+            "go through the chunked path "
+            "(InferenceEngine(prefill_chunk=...))")
     g, lp_len = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0)
     pos = jnp.broadcast_to(jnp.arange(lp_len), (g, lp_len))

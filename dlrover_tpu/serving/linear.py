@@ -1,6 +1,11 @@
-"""The served Kimi Delta Attention block (``LayerSpec.mixer`` "kda"): what
-``serving/latent.py``'s layer loop runs in place of latent attention for
-a layer that keeps no rows.
+"""The served blocks that keep a recurrent STATE A SLOT and no rows: Kimi
+Delta Attention (``LayerSpec.mixer`` "kda") and the Mamba-2 state-space
+layer ("ssm", at the end of this file), what ``serving/latent.py``'s layer
+loop runs in place of attention for such a layer.  :func:`state_shapes`
+gives the engine either kind's state; :data:`BLOCKS` the kind's two
+functions, one token a slot and a run of one slot's tokens.
+
+Kimi Delta Attention.
 
 On its normed input ``x`` (one token a slot at decode, a run of one
 slot's tokens in a prompt chunk):
@@ -31,6 +36,26 @@ between steps), to the state after its last real token (``n_real``).
 Device scopes: ``kda_proj`` (projections, convolution, norms of q and k,
 decay and beta), ``kda_scan`` (the delta rule), ``kda_out`` (gated norm
 and ``W_o``).
+
+Mamba-2 (:func:`ssm_decode`, :func:`ssm_run`), on its normed input ``u``:
+
+- ``[z | xBC | dt] = W_in u``: ONE matmul, float32 sums (``dt`` and the
+  gate ``z`` are not rounded on the way; ``xBC`` is, to the model's dtype,
+  the convolution rows' own); ``xBC <- SiLU(conv(xBC) + bias)`` over the
+  last ``ssm_conv`` positions a channel; ``[x | B | C] = xBC``, B and C
+  shared by all heads;
+- ``Delta = softplus(dt + dt_bias)`` and the log-decay ``-exp(A_log)
+  Delta``, ONE scalar a head and token, float32;
+- the scan over the slot's ``state`` (one float32 ``[P, N]`` a head):
+  ``ops/pallas/ssm.py``, the kernels with ``impl == "pallas"``, the
+  ``jnp`` recurrence otherwise; ``y = S C + D x``;
+- ``W_out RMSNorm(y x SiLU(z))``: the gate INSIDE the norm, one learned
+  scale over all ``H x P`` channels.
+
+``state`` [slots, H / pack, N, pack x P] float32 (the kernels' kept layout:
+``ops/pallas/ssm.py packed_shape``) and ``conv`` [ssm_conv - 1, slots, H P
++ 2 N], with the life KDA's have.  Device scopes: ``ssm_proj``,
+``ssm_scan`` (the kernel alone), ``ssm_out``.
 """
 
 from __future__ import annotations
@@ -41,7 +66,7 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.models.llama import LlamaConfig
-from dlrover_tpu.ops.pallas import kda
+from dlrover_tpu.ops.pallas import kda, ssm
 from dlrover_tpu.serving.model import _mm, _rmsnorm
 from dlrover_tpu.utils.profiler import device_scope
 
@@ -84,8 +109,14 @@ def kda_params(p: Dict[str, Any], cfg: LlamaConfig, dtype
     }
 
 
-def state_shapes(cfg: LlamaConfig, slots: int):
-    """``(state, conv)`` shapes of one KDA layer for ``slots`` slots."""
+def state_shapes(cfg: LlamaConfig, slots: int, kind: str = "kda"):
+    """``(state, conv)`` shapes of one layer of ``kind`` ("kda" | "ssm")
+    for ``slots`` slots: the float32 state and the convolution's last
+    inputs, taps ahead of slots."""
+    if kind == "ssm":
+        h, p, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+        return (slots,) + ssm.packed_shape(h, p, n), (
+            cfg.ssm_conv - 1, slots, h * p + 2 * n)
     h, d = cfg.kda_heads, cfg.kda_head_dim
     return (slots, h, d, d), (cfg.kda_conv - 1, slots, 3 * h * d)
 
@@ -190,3 +221,118 @@ def kda_run(lp, x, state, conv, fresh, n_real, cfg: LlamaConfig, dtype,
         else:
             o, state = kda.kda_recurrence(state, q, k, v, g, beta, n_real)
     return _output(lp, x, o, cfg, dtype), state, conv, decay
+
+
+# ------------------------------------------------------------- Mamba-2
+def ssm_params(p: Dict[str, Any], cfg: LlamaConfig, dtype
+               ) -> Dict[str, Any]:
+    """A layer's ``ssm`` subtree, named as ``perfbench/reference_granite.py``
+    and the tests make it (``in_proj`` [E, 2 H P + 2 N + H] to ``[z | xBC |
+    dt]``; ``conv`` kernel [taps, H P + 2 N] and bias; ``dt_bias``,
+    ``A_log``, ``D`` [H]; ``norm`` [H P]; ``out_proj`` [H P, E]), as the
+    serving tree: the scan's constants float32."""
+    f32 = jnp.float32
+    return {
+        "ssm_win": jnp.asarray(p["in_proj"]["kernel"], dtype),
+        "ssm_conv": jnp.asarray(p["conv"]["kernel"], f32),
+        "ssm_conv_bias": jnp.asarray(p["conv"]["bias"], f32),
+        "ssm_dt_bias": jnp.asarray(p["dt_bias"], f32),
+        "ssm_rate": jnp.exp(jnp.asarray(p["A_log"], f32)),
+        "ssm_skip": jnp.asarray(p["D"], f32),
+        "ssm_norm": p["norm"]["scale"],
+        "ssm_wout": jnp.asarray(p["out_proj"]["kernel"], dtype),
+    }
+
+
+def _ssm_project(lp, u, cfg: LlamaConfig, dtype):
+    """``u`` [T, E] -> the gate ``z`` [T, H P] and ``dt`` [T, H] as the
+    float32 sums gave them, ``xBC`` [T, H P + 2 N] in ``dtype``."""
+    w = cfg.ssm_heads * cfg.ssm_head_dim
+    full = _mm32(u, lp["ssm_win"], dtype)
+    return full[:, :w], full[:, w:-cfg.ssm_heads].astype(dtype), \
+        full[:, -cfg.ssm_heads:]
+
+
+def _ssm_inputs(lp, mixed, dt, cfg: LlamaConfig):
+    """``mixed`` [T, H P + 2 N] float32 (behind the convolution, bias not
+    yet added) and ``dt`` [T, H] -> ``x`` [T, H, P], ``b c`` [T, N], the
+    step and the log-decay [T, H], float32."""
+    t = mixed.shape[0]
+    h, p, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    act = jax.nn.silu(mixed + lp["ssm_conv_bias"])
+    step = jax.nn.softplus(dt + lp["ssm_dt_bias"])
+    return (act[:, :h * p].reshape(t, h, p), act[:, h * p:h * p + n],
+            act[:, h * p + n:], step, -lp["ssm_rate"] * step)
+
+
+def _ssm_output(lp, y, x, z, cfg: LlamaConfig, dtype):
+    """``y`` [T, H, P] (the scan's ``S C``), the scan's input ``x`` and the
+    gate ``z`` [T, H P] -> the block's output [T, E]."""
+    with device_scope("ssm_out"):
+        y = (y + lp["ssm_skip"][:, None] * x).reshape(z.shape)
+        o = _rmsnorm(y * jax.nn.silu(z), lp["ssm_norm"], cfg.rms_norm_eps)
+        return _mm(o.astype(dtype), lp["ssm_wout"], dtype)
+
+
+def ssm_decode(lp, u, state, conv, active, cfg: LlamaConfig, dtype,
+               impl: str, interpret: bool):
+    """One token a slot: ``u`` [B, E], ``state`` [B, H / pack, N, pack x
+    P], ``conv`` [taps - 1, B, H P + 2 N], ``active`` [B] bool.  Returns ``(y [B, E],
+    state, conv, None)``; a slot that is not active keeps state and conv
+    as they were."""
+    with device_scope("ssm_proj"):
+        z, new, dt = _ssm_project(lp, u, cfg, dtype)
+        window = jnp.concatenate([conv, new[None]], axis=0)
+        mixed = jnp.sum(window.astype(jnp.float32)
+                        * lp["ssm_conv"][:, None, :], axis=0)
+        x, b, c, dt, la = _ssm_inputs(lp, mixed, dt, cfg)
+        conv = jnp.where(active[None, :, None], window[1:], conv)
+    with device_scope("ssm_scan"):
+        if impl == "pallas":
+            y, state = ssm.ssm_decode_step(state, x, dt, la, b, c, active,
+                                           interpret=interpret)
+        else:
+            y, new_state = ssm.ssm_step(
+                ssm.unpack_state(state, cfg.ssm_head_dim), x, dt, la, b, c)
+            state = jnp.where(active[:, None, None, None],
+                              ssm.pack_state(new_state), state)
+    return _ssm_output(lp, y, x, z, cfg, dtype), state, conv, None
+
+
+def ssm_run(lp, u, state, conv, fresh, n_real, cfg: LlamaConfig, dtype,
+            impl: str, interpret: bool):
+    """A run of one slot's tokens: ``u`` [K, E], ``state`` [H / pack, N,
+    pack x P] and ``conv`` [taps - 1, H P + 2 N] the slot's own, ``fresh`` and ``n_real``
+    as :func:`kda_run`'s.  Returns ``(y [K, E], state, conv, None)``,
+    state and conv after the last real token."""
+    taps = cfg.ssm_conv
+    with device_scope("ssm_proj"):
+        state = jnp.where(fresh, 0.0, state)
+        conv = jnp.where(fresh, jnp.zeros((), conv.dtype), conv)
+        z, new, dt = _ssm_project(lp, u, cfg, dtype)
+        window = jnp.concatenate([conv, new], axis=0)
+        wf = window.astype(jnp.float32)
+        mixed = sum(wf[j:j + u.shape[0]] * lp["ssm_conv"][j]
+                    for j in range(taps))
+        x, b, c, dt, la = _ssm_inputs(lp, mixed, dt, cfg)
+        # the last taps - 1 inputs up to the last real token
+        conv = jax.lax.dynamic_slice_in_dim(window, n_real, taps - 1)
+        kernel = impl == "pallas" and u.shape[0] % ssm.CHUNK == 0
+        if kernel:
+            # every consumer of the projection's output under this scope,
+            # the kernel alone under the next
+            ops = ssm.chunk_operands(x, dt, la, b, c, n_real)
+    with device_scope("ssm_scan"):
+        if kernel:
+            y, state = ssm.ssm_chunk_call(state, *ops, n_real,
+                                          interpret=interpret)
+        else:
+            y, state = ssm.ssm_recurrence(
+                ssm.unpack_state(state, cfg.ssm_head_dim), x, dt, la, b, c,
+                n_real)
+            state = ssm.pack_state(state)
+    return _ssm_output(lp, y, x, z, cfg, dtype), state, conv, None
+
+
+#: a kind's ``(one token a slot, a run of one slot's tokens)``
+BLOCKS = {"kda": (kda_decode, kda_run), "ssm": (ssm_decode, ssm_run)}

@@ -261,9 +261,11 @@ def verify_step(
     mask and reaches nothing but that kernel.
 
     A latent-attention model (``cfg.kv_lora_rank``) has its own blocks
-    and cache rows: ``serving/latent.py verify_step``, same arguments.
+    and cache rows, and so has any model whose layers are of more than
+    one kind, sparse, or scaled (``cfg.layer_kinds``):
+    ``serving/latent.py verify_step``, same arguments.
     """
-    if cfg.kv_lora_rank:
+    if cfg.layer_kinds:
         from dlrover_tpu.serving import latent
 
         return latent.verify_step(
@@ -469,7 +471,7 @@ def prefill(
     ``real_len`` is harmless: decode overwrites/masks it (module
     docstring).  Of a latent-attention model (``cfg.kv_lora_rank``) the
     two lists are its cache rows and index keys (``serving/latent.py``)."""
-    if cfg.kv_lora_rank:
+    if cfg.layer_kinds:
         from dlrover_tpu.serving import latent
 
         return latent.prefill(params, cfg, tokens, real_len)

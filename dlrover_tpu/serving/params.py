@@ -128,39 +128,36 @@ def serving_params_from_llama(
 
     if cfg.qk_norm and not cfg.kv_lora_rank:
         raise ValueError(
-            "the serving engine's attention blocks have no QK-norm "
+            "the serving engine's grouped-query blocks have no QK-norm "
             f"(qk_norm={cfg.qk_norm}): the model would be served as a "
             "different one.  Missing: the norm over the projected query "
-            "and key in serving/model.py::_attn_proj (ROADMAP Reach A3)")
+            "and key in serving/model.py::_attn_proj and "
+            "serving/latent.py::_gqa_layer (ROADMAP Reach A3)")
     attn = {dataclasses.replace(s, mlp="dense") for s in cfg.layer_specs
             if s.mixer == "attn"}
     if not cfg.kv_lora_rank and (
             cfg.attn_head_gate or len(attn) > 1 or any(
-                s.window for s in attn) or cfg.layers is not None):
+                s.window or s.rope.rotary_fraction not in (0.0, 1.0)
+                or s.rope.yarn_factor for s in attn)
+            or (cfg.layers is not None and not cfg.layer_kinds)):
         raise ValueError(
             "the serving engine's grouped-query layers are ONE kind of "
-            "layer: full causal attention with one head count and one "
-            f"rotary embedding, no head gate (attn_head_gate="
+            "layer: full causal attention with one head count, "
+            "rotated whole or not at all, no head gate (attn_head_gate="
             f"{cfg.attn_head_gate}); this model describes {len(attn)} kinds "
-            "of attention layer.  Layers that differ (head counts, a "
-            "window, a head gate, their rotary embedding) are served as "
-            "LATENT attention only (LayerSpec, serving/latent.py), beside "
-            "linear attention (LayerSpec.mixer).  Missing behind the "
-            "grouped-query block: a lower bound on the keys in "
+            "of attention layer.  Beside them a model may have layers that "
+            "keep a recurrent state (LayerSpec.mixer) and sparse MLPs "
+            "(serving/latent.py's loop).  Missing behind the grouped-query "
+            "block: a lower bound on the keys in "
             "ops/pallas/paged_attention.py and window rows in its cache "
             "(ROADMAP A4), per-layer head counts, a head gate, partial "
-            "rotary and YaRN in serving/model.py (A3)")
-    if cfg.num_experts and not cfg.kv_lora_rank:
-        raise ValueError(
-            f"sparse experts (num_experts={cfg.num_experts}) are served "
-            "behind latent attention only (serving/latent.py sparse_mlp); "
-            "the grouped-query block's MLP in serving/model.py::_mlp is "
-            "dense (ROADMAP B1, serving half)")
-    if cfg.kv_lora_rank:
+            "rotary and YaRN in serving/latent.py::_gqa_layer (A3)")
+    if cfg.layer_kinds:
         if int8 or not fuse:
             raise ValueError(
-                "a latent-attention model is served in its own dtype on "
-                "one device: no int8 weights, no tensor-parallel mesh")
+                "a model of layer kinds (latent attention, a recurrent "
+                "state a slot, sparse experts) is served in its own dtype "
+                "on one device: no int8 weights, no tensor-parallel mesh")
         return _latent_params(variables, cfg, dtype or cfg.dtype)
     if dtype is None:
         dtype = cfg.dtype
@@ -210,7 +207,10 @@ def serving_params_from_llama(
 
 def _latent_params(variables: Any, cfg: LlamaConfig, dtype
                    ) -> Dict[str, Any]:
-    """The serving tree of a latent-attention model (serving/latent.py)
+    """The serving tree of a model of layer kinds (serving/latent.py): a
+    grouped-query model's attention layers as :func:`_layer_tree` fuses
+    them (``wqkv``, ``wo``), beside ``ssm`` layers (``serving/linear.py
+    ssm_params``) and the MLPs below; or a latent-attention model's,
     from a ``layer_{i}`` tree named as ``perfbench/reference_glm5.py``,
     ``perfbench/reference_sarvam.py`` and the tests make it: ``attn`` (the
     query through a bottleneck, ``q_a_proj``, ``q_a_norm``, ``q_b_proj``
@@ -250,10 +250,21 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
             from dlrover_tpu.serving.linear import kda_params
 
             return kda_params(p["kda"], cfg, dtype)
+        if spec.mixer == "ssm":
+            from dlrover_tpu.serving.linear import ssm_params
+
+            return ssm_params(p["ssm"], cfg, dtype)
         if spec.mixer != "attn":
             raise ValueError(f"no served mixer {spec.mixer!r}: a layer is "
-                             "'attn' or 'kda' (LayerSpec.mixer)")
+                             "'attn', 'kda' or 'ssm' (LayerSpec.mixer)")
         a = p["attn"]
+        if not cfg.kv_lora_rank:         # the grouped-query block
+            return {
+                "wqkv": jnp.concatenate(
+                    [flat_out(a[n]["kernel"])
+                     for n in ("q_proj", "k_proj", "v_proj")], axis=-1),
+                "wo": mat(a["o_proj"]["kernel"]).reshape(
+                    -1, cfg.hidden_size)}
         _, nope, indexed = cfg.latent_dims(spec)
         kv_b = mat(a["kv_b_proj"]["kernel"])             # [C, H, nope+V]
         out = {

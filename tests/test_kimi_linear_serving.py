@@ -331,7 +331,7 @@ def test_the_blocks_refuse_a_bucketed_prefill_and_a_verify(cfg, params_of):
     with pytest.raises(ValueError, match="no served mixer"):
         serving_params_from_llama(
             {"params": params}, dataclasses.replace(cfg, layers=tuple(
-                LayerSpec(num_heads=2, rope=cfg.rope, mixer="ssm",
+                LayerSpec(num_heads=2, rope=cfg.rope, mixer="rwkv",
                           mlp=s.mlp) for s in cfg.layer_specs)))
 
 

@@ -297,13 +297,14 @@ def test_zipf_batches_replay():
     ("qk_norm", "QK-norm"),
     ("window", "ONE kind of layer"),
     ("head_gate", "no head gate"),
-    ("experts_behind_gqa", "B1"),
+    ("experts_int8", "no int8 weights"),
 ])
 def test_serving_refuses_a_model_by_what_it_lacks(lacks, match):
     """Sparse experts are served (behind latent attention:
-    tests/test_sparse_serving.py); what the serving blocks still lack is
-    refused by name: QK-norm, a window, a head gate, and experts behind
-    the grouped-query block."""
+    tests/test_sparse_serving.py; behind the grouped-query block since PR
+    50: tests/test_granite_serving.py); what the serving blocks still lack
+    is refused by name: QK-norm, a window, a head gate, and int8 weights
+    for a model of layer kinds."""
     from dlrover_tpu.models.llama import LayerSpec
     from dlrover_tpu.serving.params import serving_params_from_llama
 
@@ -317,7 +318,8 @@ def test_serving_refuses_a_model_by_what_it_lacks(lacks, match):
     elif lacks == "head_gate":
         cfg = dataclasses.replace(cfg, qk_norm=False, num_experts=0,
                                   attn_head_gate=True)
-    elif lacks == "experts_behind_gqa":
+    elif lacks == "experts_int8":
         cfg = dataclasses.replace(cfg, qk_norm=False)
     with pytest.raises(ValueError, match=match):
-        serving_params_from_llama({"params": params}, cfg)
+        serving_params_from_llama({"params": params}, cfg,
+                                  int8=lacks == "experts_int8")

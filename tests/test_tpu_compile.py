@@ -536,6 +536,85 @@ def test_kimi_linear_programs_keep_their_state_in_place(one_chip,
     assert memory.temp_size_in_bytes < one_state
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+def test_granite_programs_keep_their_state_in_place(one_chip, monkeypatch,
+                                                    program):
+    """One period of granite-4.0-h-small-serve (Mamba-2 x 5, attention,
+    Mamba-2 x 4) at its published widths and the cell's 128 slots: the
+    decode forward holds ``ssm_decode_step`` and the prompt chunk
+    ``ssm_chunk_fwd``, each under ``ssm_scan``, the attention layer's decode
+    kernel under ``paged_attn``; the donated states come back aliased, and
+    nothing in the program is a copy of a layer's 537 MB of state."""
+    from dlrover_tpu.models import moe
+    from dlrover_tpu.models.llama import LlamaConfig
+    from dlrover_tpu.serving import latent, linear
+    from dlrover_tpu.serving.model import decode_step
+    from dlrover_tpu.serving.params import serving_params_from_llama
+    from dlrover_tpu.utils.profiler import device_scope, parse_program
+    from perfbench.weights_granite import SeededGraniteParams
+
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    cfg = LlamaConfig.granite_4_h_small(
+        num_layers=10, moe_experts_held=(0, 18), vocab_size=25088,
+        max_seq_len=5248, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    slots, mb, bs, nb = 128, 41, 128, 3072
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    sp = on_chip(jax.eval_shape(lambda: serving_params_from_llama(
+        {"params": SeededGraniteParams(cfg, 3)}, cfg)))
+    S = jax.ShapeDtypeStruct
+    state, conv = linear.state_shapes(cfg, slots, "ssm")
+    cache = on_chip({
+        "k_pool": [S((nb, bs, 8, 128), jnp.bfloat16)],
+        "v_pool": [S((nb, bs, 8, 128), jnp.bfloat16)],
+        "ssm_state": [S(state, jnp.float32)] * 9,
+        "ssm_conv": [S(conv, jnp.bfloat16)] * 9,
+        "table": S((slots, mb), jnp.int32),
+        "moe_picks": S((2,), jnp.uint32),
+        "watch_slot": S((), jnp.int32)})
+    if program == "decode":
+        def forward(p, c, t, pos, act):
+            with device_scope("decode_chunk"):
+                return decode_step(p, cfg, c, t, pos,
+                                   attention_impl="pallas", active=act)
+
+        args = on_chip((S((slots,), jnp.int32), S((slots,), jnp.int32),
+                        S((slots,), jnp.bool_)))
+        kernels = ["ssm_decode_step"] * 9 + ["paged_decode_attention"]
+    else:
+        def forward(p, c, t, pos, sl, li):
+            with device_scope("prefill_chunk"):
+                return latent.verify_step(
+                    p, cfg, c, t, pos, slots=sl, logits_index=li,
+                    attention_impl="pallas")
+
+        args = on_chip((S((1, 512), jnp.int32),) + (S((1,), jnp.int32),) * 3)
+        kernels = ["ssm_chunk_fwd"] * 9
+    lowered = jax.jit(forward, donate_argnums=(1,)).lower(sp, cache, *args)
+    compiled = lowered.compile()
+    table = parse_program(
+        program, compiled.as_text(),
+        {program if program != "decode" else "decode_chunk", "ssm_proj",
+         "ssm_scan", "ssm_out", "attn_proj", "kv_write", "paged_attn",
+         "moe_route", "moe_experts", "moe_shared", "head"},
+        lowered.as_text(debug_info=True))
+    assert table.complete, table.missing
+    scopes = {n: scope for n, scope in table.scope_of.items()
+              if n.startswith(("ssm_decode_step", "ssm_chunk_fwd",
+                               "paged_decode_attention"))}
+    assert sorted(scopes.values()) == sorted(
+        "ssm_scan" if k.startswith("ssm") else "paged_attn"
+        for k in kernels), scopes
+    memory = compiled.memory_analysis()
+    one_state = 128 * 128 * 64 * 128 * 4
+    assert memory.alias_size_in_bytes >= 9 * one_state
+    assert memory.temp_size_in_bytes < one_state
+
+
 def test_paged_decode_int4_is_refused_loudly(one_chip):
     """Packed int4 pools do not compile on a TPU (minor dimension 64);
     until the pool is re-laid the kernel refuses in the repo's own
