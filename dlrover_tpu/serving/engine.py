@@ -160,6 +160,11 @@ class EngineStats:
     # here (a device reduction carried in the cache beside the pools)
     moe_picks: int = 0
     moe_picks_held: int = 0
+    # ... and how the sparse layers' sorted buffer met them
+    # (serving/latent.py sparse_mlp): the walks taken over the held picks
+    # and the layer-forwards that took them
+    moe_buffer_walks: int = 0
+    moe_layer_forwards: int = 0
     # layers that keep a state a slot (LayerSpec.mixer "kda" or "ssm"),
     # one float32 matrix a head and slot: host arithmetic, summed over
     # decode forwards and layers
@@ -233,6 +238,15 @@ class EngineStats:
         a dense model)."""
         return self.moe_picks_held / self.moe_picks \
             if self.moe_picks else 0.0
+
+    @property
+    def moe_walks_per_layer(self) -> float:
+        """Walks of the sorted buffer a sparse layer-forward: 1.0 while
+        every layer's held picks fit its buffer, more where a hot expert
+        overflowed it, less where layers held no pick (0.0 for a dense
+        model)."""
+        return self.moe_buffer_walks / self.moe_layer_forwards \
+            if self.moe_layer_forwards else 0.0
 
     @property
     def chained_dispatch_share(self) -> float:
@@ -715,9 +729,10 @@ class InferenceEngine:
                         jnp.zeros((store.snapshots, g.keep, self.block_size,
                                    w), cfg.dtype) for w in wide]
                 if cfg.num_experts:
-                    # [picks, picks on held experts], wrapping: the host
-                    # adds differences (_book_moe_picks)
-                    self._cache["moe_picks"] = jnp.zeros(2, jnp.uint32)
+                    # [picks, picks on held experts, buffer walks, sparse
+                    # layer-forwards], wrapping: the host adds differences
+                    # (_book_moe_picks)
+                    self._cache["moe_picks"] = jnp.zeros(4, jnp.uint32)
                 if self._state_layers:
                     from dlrover_tpu.serving.linear import state_shapes
 
@@ -815,7 +830,7 @@ class InferenceEngine:
         self._finished: List[Request] = []
         self._next_rid = 0
         self.stats = EngineStats()
-        self._moe_picks_seen = np.zeros(2, np.uint32)
+        self._moe_picks_seen = np.zeros(4, np.uint32)
         self._watch = None                 # ``watch``'s predicate
         self._watch_slot = -1
         self.witness_log: List[Dict[str, Any]] = []
@@ -2187,18 +2202,21 @@ class InferenceEngine:
                 "start": int(positions[s]), "seen": seen}
 
     def _book_moe_picks(self, counted: Optional[jax.Array]) -> None:
-        """Add to ``stats.moe_picks`` / ``moe_picks_held`` what the
-        programs up to the one just read counted on the device since the
-        last call (``counted``: two wrapping uint32 that it handed back
-        beside the cache that carries them, None for a model that counts
-        none; the two counters lag by what is in flight)."""
+        """Add to ``stats.moe_picks`` / ``moe_picks_held`` /
+        ``moe_buffer_walks`` / ``moe_layer_forwards`` what the programs up
+        to the one just read counted on the device since the last call
+        (``counted``: four wrapping uint32 that it handed back beside the
+        cache that carries them, None for a model that counts none; the
+        counters lag by what is in flight)."""
         if counted is None:
             return
         now = np.asarray(counted, np.uint32)
         delta = (now - self._moe_picks_seen).astype(np.uint32)
         self._moe_picks_seen = now
-        self.stats.moe_picks += int(delta[0])
-        self.stats.moe_picks_held += int(delta[1])
+        for name, more in zip(("moe_picks", "moe_picks_held",
+                               "moe_buffer_walks", "moe_layer_forwards"),
+                              delta):
+            setattr(self.stats, name, getattr(self.stats, name) + int(more))
 
     @spanned("dlrover.engine.deliver")
     def _deliver_chunk(self, lanes: List[Tuple[int, Request, int, bool]],

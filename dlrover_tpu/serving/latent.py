@@ -105,7 +105,7 @@ import jax.numpy as jnp
 from dlrover_tpu.models.llama import (LayerSpec, LlamaConfig, RopeSpec,
                                       apply_rope, rope_frequencies,
                                       rope_inverse_frequencies)
-from dlrover_tpu.models.moe import grouped_matmul, route
+from dlrover_tpu.models.moe import GMM_TILING, grouped_matmul, route
 from dlrover_tpu.serving.model import _lm_head, _mm, _rmsnorm
 from dlrover_tpu.serving.paged import (ring_table, scatter_ring,
                                        scatter_tokens)
@@ -562,13 +562,80 @@ def _swiglu(h, wgu, down, dtype):
     return _mm(jax.nn.silu(gu[..., :f]) * gu[..., f:], down, dtype)
 
 
+# Rows of :func:`sparse_mlp`'s sorted buffer over the picks an even routing
+# sends this chip, as (numerator, denominator).  On the v5e at granite's
+# prompt chunk (512 tokens x top 10, 18 of 72 experts held, hidden 4096,
+# width 768; my chip runs, PR 51): sixteen seeded routings sent 1 245-1 328
+# picks where even is 1 280, and what is around the grouped matmuls costs by
+# the row (placing 125 us and bringing back 221 us at 2 048 rows) while the
+# matmuls themselves do not (768 / 771 / 808 us at 1 792 / 2 048 / 5 120
+# rows: they walk the groups, not the buffer).  5 / 4 would save ~40 us a
+# layer and walk twice at a load 9 % over even; 3 / 2 walks once up to 50 %.
+BUFFER_HEADROOM = (3, 2)
+# ... and the picks from which the buffer is cut at all: a forward of fewer
+# (every cell's decode forward: 1 280 picks of granite's 128 slots) keeps a
+# buffer of every pick, in line, with no ``cond`` behind it.  With granite's
+# decode buffer cut to 512 rows ``serve-rag-ssm`` read 2 137-2 168 tokens/s
+# in seven runs and 1 734 and 1 965 in two more, their decode forwards at
+# 44 and 38 ms for 34.5 (slots that decode alike pick alike, and the walks
+# behind the ``cond`` are slow when taken); uncut, 2 140-2 150 in three and
+# nothing to overflow (my chip runs, PR 51; the parent 1 974-2 000).
+WALKED_FROM = 2048
+
+
+def _buffer_rows(picks: int, held: int, num_experts: int) -> int:
+    """Rows of :func:`sparse_mlp`'s sorted buffer for ``picks`` (tokens x
+    ``top_k``) of which an even routing sends ``held / num_experts`` here:
+    the smallest multiple of the grouped matmul's row tile not under
+    ``BUFFER_HEADROOM`` times that, and never more than ``picks`` (all of
+    them where every expert is held, and in a forward of fewer than
+    ``WALKED_FROM`` picks)."""
+    num, den = BUFFER_HEADROOM
+    tile = GMM_TILING[0]
+    if picks < WALKED_FROM:
+        return picks
+    rows = -(-num * picks * held // (den * num_experts))
+    return min(picks, -(-rows // tile) * tile)
+
+
 def sparse_mlp(lp, h, cfg: LlamaConfig, dtype, counted):
     """The sparse MLP of one layer on ``h`` [B, K, E]: ``(y, [picks,
-    picks on held experts])``, the two counted over the rows ``counted``
-    [B, K] marks (a parked slot's junk row routes too).  The picks are
-    sorted by expert with this device's ahead of all others, as
-    ``models/moe.py MoEMLP`` sorts them; the grouped matmuls get the held
-    groups' sizes and multiply nothing behind them."""
+    picks on held experts, walks, 1])``, the two counts over the rows
+    ``counted`` [B, K] marks (a parked slot's junk row routes too).
+
+    The picks on the experts held here, in expert order, are ``n_held``
+    rows; the sorted buffer that feeds the grouped matmuls has
+    :func:`_buffer_rows` rows, ``C``, and is WALKED over them ``C`` at a
+    time, ``walks = ceil(n_held / C)`` times, a count read from the routing
+    on the device: one walk while the routing is near even, more where a
+    hot expert overloads this share.  Every pass around the matmuls is
+    over ``C`` rows: a pick on an absent expert is never placed,
+    multiplied, zeroed or brought back, and no pick is dropped under any
+    routing.  The first walk is in line and the others behind a ``cond``
+    that near-even routing never takes: in ``serve-rag-ssm`` a ``while``
+    from the first walk read 2 067 tokens/s, one from the second 2 097,
+    the ``cond`` 2 144 where the parent read 1 977 (my chip runs, PR 51: a
+    loop in a layer, taken or not, costs the programs around it more than
+    the rows it spares).
+
+    A pick's row needs no sort: its expert's first row plus the tokens
+    ahead of its own that picked the same expert (a token picks an expert
+    once).  Rows are placed by a 0 / 1 matrix ``[T, C]`` against ``x`` (an
+    exact copy) and come back as ``[T, C]`` carrying ``top_p`` in float32
+    times the result at ``HIGHEST``.  Timed alone at granite's chunk /
+    its decode forward (128 tokens, 512 rows) / glm5's decode forward (32
+    tokens x top 8, hidden 6144, 256 rows), us (my chip runs, PR 51; the
+    decode forwards' buffers have since been left uncut, ``WALKED_FROM``).
+    The rows: this 30 / 37 / 33, a prefix sum over tokens 46 / 40 / 34,
+    one over picks 74 / 67 / 39, the parent's ``argsort`` 48 / 42 / 40.
+    Placing: this 124 / 53 / 43, a gather of ``C`` rows 155 / 62 / 55 (128
+    / 60 / 52 with its index given), the parent's sort and gather of
+    ``T x top_k`` 252 / 97 / 58.  Bringing back: this 224 / 55 / 47, the
+    weights split by hand into three bfloat16 parts 222 / 69 / 64 (one
+    bfloat16 pass, not exact, is 125 / 52 / 43), a scatter-add of ``C``
+    rows 379 / 118 / 83, a gather of ``top_k`` rows a token 522 / 119 /
+    69, the parent's un-sort of ``T x top_k`` 566 / 141 / -.  The three
+    grouped matmuls are 771 / 734 / 1 147 of the layer."""
     b, klen, e = h.shape
     t, k = b * klen, cfg.moe_top_k
     first, held = cfg.moe_experts_held or (0, cfg.num_experts)
@@ -579,25 +646,58 @@ def sparse_mlp(lp, h, cfg: LlamaConfig, dtype, counted):
         top_p, top_e, _ = route(
             logits, k, cfg.moe_score_fn, cfg.moe_norm_topk_prob,
             cfg.moe_routed_scale, lp.get("select_bias"))
-        local = top_e.reshape(t * k) - first
-        is_held = jnp.logical_and(local >= 0, local < held)
-        group = jnp.where(is_held, local, held)
-        sizes = jnp.sum(jax.nn.one_hot(group, held, dtype=jnp.int32), axis=0)
-        rows = jnp.repeat(counted.reshape(t), k)
-        picks = jnp.stack([jnp.sum(rows), jnp.sum(rows & is_held)]
+        # [T, k, held]: a pick on an absent expert has no column
+        hot = (top_e - first)[:, :, None] == jnp.arange(held)
+        is_held = hot.any(axis=-1)
+        sizes = jnp.sum(hot, axis=(0, 1), dtype=jnp.int32)
+        rows = counted.reshape(t, 1)
+        picks = jnp.stack([k * jnp.sum(rows), jnp.sum(rows & is_held)]
                           ).astype(jnp.uint32)
     with device_scope("moe_experts"):
-        order = jnp.argsort(group, stable=True)
-        xs = x.astype(dtype)[order // k]
-        gate = grouped_matmul(xs, lp["w_gate"].astype(dtype), sizes)
-        up = grouped_matmul(xs, lp["w_up"].astype(dtype), sizes)
-        out = grouped_matmul(jax.nn.silu(gate) * up,
-                             lp["w_down"].astype(dtype), sizes)
-        # rows behind the held groups are no expert's: never written
-        live = (jnp.arange(t * k) < jnp.sum(sizes))[:, None]
-        out = jnp.where(live, out, jnp.zeros((), out.dtype))
-        out = out[jnp.argsort(order)].reshape(t, k, e)
-        y = jnp.sum(out.astype(jnp.float32) * top_p[..., None], axis=1)
+        c = _buffer_rows(t * k, held, cfg.num_experts)
+        exact = jax.lax.Precision.HIGHEST
+        xs, weights = x.astype(dtype), [
+            lp[name].astype(dtype) for name in ("w_gate", "w_up", "w_down")]
+        ends = jnp.cumsum(sizes)
+        n_held = ends[-1]
+        walks = (n_held + c - 1) // c
+        ahead = jnp.dot(jnp.tri(t, k=-1, dtype=jnp.bfloat16),
+                        hot.any(axis=1).astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32)   # [T, held]
+        row = (ends - sizes) + ahead.astype(jnp.int32)
+        row = jnp.sum(jnp.where(hot, row[:, None, :], 0), axis=-1)
+        row = jnp.where(is_held, row, -1).T                    # [k, T]
+        weight = top_p.T
+
+        def walk(w, y):
+            lo = w * c
+            here = jnp.clip(ends, lo, lo + c) - jnp.clip(ends - sizes, lo,
+                                                         lo + c)
+            at = (row - lo)[:, :, None] == jnp.arange(c)       # [k, T, C]
+            # one bfloat16 pass copies bfloat16 rows exactly
+            buf = jnp.einsum(
+                "tc,te->ce", at.any(axis=0).astype(dtype), xs,
+                preferred_element_type=dtype,
+                precision=None if dtype == jnp.bfloat16 else exact)
+            gate = grouped_matmul(buf, weights[0], here)
+            up = grouped_matmul(buf, weights[1], here)
+            out = grouped_matmul(jax.nn.silu(gate) * up, weights[2], here)
+            # rows behind the window's groups are no expert's: never
+            # written
+            live = (jnp.arange(c) < n_held - lo)[:, None]
+            out = jnp.where(live, out, jnp.zeros((), out.dtype))
+            back = jnp.sum(jnp.where(at, weight[:, :, None], 0.0), axis=0)
+            return y + jnp.dot(back, out.astype(jnp.float32),
+                               precision=exact)
+
+        y = walk(0, jnp.zeros((t, e), jnp.float32))
+        if c < t * k:               # a buffer of every pick has no others
+            y = jax.lax.cond(
+                walks > 1,
+                lambda y: jax.lax.fori_loop(1, walks, walk, y),
+                lambda y: y, y)
+        picks = jnp.concatenate([picks, jnp.stack(
+            [walks, jnp.ones_like(walks)]).astype(jnp.uint32)])
     if "shared_wgu" in lp:
         with device_scope("moe_shared"):
             y = y + _swiglu(x, lp["shared_wgu"], lp["shared_down"],

@@ -138,6 +138,8 @@ class RouterMetrics:
         self.attn_rows_selected = 0.0
         self.moe_picks = 0.0
         self.moe_picks_held = 0.0
+        self.moe_buffer_walks = 0.0
+        self.moe_layer_forwards = 0.0
         self.prefill_query_tiles = 0.0
         self.prefill_query_tiles_live = 0.0
         self.window_rows_in_window = 0.0
@@ -310,7 +312,8 @@ class RouterMetrics:
         self.kv_rows_streamed = sum(
             d.get("kv_rows_streamed", 0.0) for d in dicts)
         for name in ("dsa_rows_live", "attn_rows_selected", "moe_picks",
-                     "moe_picks_held", "prefill_query_tiles",
+                     "moe_picks_held", "moe_buffer_walks",
+                     "moe_layer_forwards", "prefill_query_tiles",
                      "prefill_query_tiles_live", "dispatches",
                      "chained_dispatches", "lookahead_steps",
                      "wasted_lane_chunks", "window_rows_in_window",
@@ -425,6 +428,9 @@ class RouterMetrics:
             "serving_engine_chained_dispatch_share": (
                 self.chained_dispatches / self.dispatches
                 if self.dispatches else 0.0),
+            "serving_moe_walks_per_layer": (
+                self.moe_buffer_walks / self.moe_layer_forwards
+                if self.moe_layer_forwards else 0.0),
             "serving_engine_lookahead_steps_total": self.lookahead_steps,
             "serving_engine_wasted_lane_chunks_total":
                 self.wasted_lane_chunks,

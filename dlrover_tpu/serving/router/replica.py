@@ -192,6 +192,7 @@ class InferenceEngineAdapter:
             # for a model with neither)
             for name in ("dsa_rows_live", "attn_rows_selected",
                          "moe_picks", "moe_picks_held",
+                         "moe_buffer_walks", "moe_layer_forwards",
                          "prefill_query_tiles",
                          "prefill_query_tiles_live",
                          "window_rows_in_window", "window_rows_streamed"):

@@ -196,6 +196,14 @@ METRIC_HELP: Dict[str, str] = {
         "of dispatches whose host preparation the device's queue hid; "
         "near 1 while steps look ahead, 0 = no replica reports"
     ),
+    "serving_moe_walks_per_layer": (
+        "walks of the served sparse MLP's sorted buffer over the picks a "
+        "replica's experts hold, a sparse layer-forward, fleet-wide "
+        "(serving/latent.py sparse_mlp): 1 while every layer's held "
+        "picks fit the buffer, above 1 where a hot expert overflowed it, "
+        "below 1 where layers held no pick; 0 = no replica serves "
+        "sparse experts"
+    ),
     "serving_engine_lookahead_steps_total": (
         "engine steps that returned with their decode chunk dispatched "
         "and unread (the next step reads it behind its own dispatches), "

@@ -70,7 +70,7 @@ def fresh_cache(cfg, blocks=16, bs=8, slots=1):
         "index_pool": [jnp.zeros((blocks, bs, cfg.index_head_dim))
                        for _ in range(cfg.num_layers)],
         "table": jnp.asarray(table),
-        "moe_picks": jnp.zeros(2, jnp.uint32)}
+        "moe_picks": jnp.zeros(4, jnp.uint32)}
 
 
 def reference_logits(cfg, params, seq, **kw):

@@ -423,12 +423,13 @@ def test_a_trained_sparse_model_is_served_behind_the_grouped_query_block():
              for n in ("k_pool", "v_pool")}
     cache.update(table=jnp.asarray(
         np.arange(1, 1 + b * mb).reshape(b, mb), jnp.int32),
-        moe_picks=jnp.zeros(2, jnp.uint32))
+        moe_picks=jnp.zeros(4, jnp.uint32))
     got, cache = dense.verify_step(sp, cfg, cache, toks,
                                    jnp.zeros(b, jnp.int32),
                                    slots=jnp.arange(b))
     np.testing.assert_allclose(got, want, atol=2e-5)
-    assert cache["moe_picks"].tolist() == [2 * 19 * 2 * cfg.num_layers] * 2
+    assert cache["moe_picks"].tolist() == [2 * 19 * 2 * cfg.num_layers] * 2 + [
+        cfg.num_layers] * 2      # every expert held: one walk a layer
     with pytest.raises(ValueError, match="prompts in chunks"):
         InferenceEngine(cfg, variables, paged=True, max_len=64)
 

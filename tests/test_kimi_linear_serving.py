@@ -440,14 +440,16 @@ def test_every_planted_fault_moves_the_reference(fault, cfg, params_of,
 # tiny preset of tests/test_sarvam_serving.py in bf16, paged pools, the
 # kernels' options as the engine hands them): (lines, sha256 of the
 # jaxpr's text).  GLM-5's are pinned by
-# tests/test_sarvam_serving.py::test_glm5_traces_what_it_did.
+# tests/test_sarvam_serving.py::test_glm5_traces_what_it_did.  Re-pinned in
+# PR 51, as ``_KIMI`` below was: the experts' sorted buffer is
+# ``sparse_mlp``'s compact one and ``moe_picks`` carries four counts.
 _SARVAM = {
-    "decode": (4635, "2c1afa1c20ca35341c264da056e180b3f182202a84ba98a859"
-                     "442a4aaa93cbef"),
-    "prefill_chunk": (5406, "4782a00f9540dc5dc2acaae4dfb757198e4ada071263"
-                            "87139e0e81533a1979d7"),
-    "prefill": (4083, "10cbaa8d28dff73f2a9fc52f0fe4f2020914e1fada9db8b730"
-                      "563dfa644bcae7"),
+    "decode": (4696, "03bdd4066bc9a46840f3f4ecc5486befdba66f9e000a118381"
+                     "48b3f32c9b934e"),
+    "prefill_chunk": (5457, "25819b5b4d713342210f6e1ec3678a64ec4aee610439"
+                            "d218ee3db547d01b8dff"),
+    "prefill": (4099, "40fa95bff008e47b9fb40c4dfe3fd40f9b60d1fcbfeca68cec"
+                      "2fa2a911e4c46b"),
 }
 
 
@@ -463,7 +465,7 @@ def sarvam_program_text(program):
     cache = {
         "latent_pool": [S((nb, bs, latent.latent_row_width(cfg)),
                           jnp.bfloat16)] * cfg.num_layers,
-        "table": S((b, mb), jnp.int32), "moe_picks": S((2,), jnp.uint32),
+        "table": S((b, mb), jnp.int32), "moe_picks": S((4,), jnp.uint32),
         "watch_slot": S((), jnp.int32)}
     ints = lambda *shape: S(shape, jnp.int32)  # noqa: E731
     kernels = dict(attention_impl="pallas", kernel_interpret=True)
@@ -488,10 +490,10 @@ def sarvam_program_text(program):
 # tiny preset above in bf16): a layer's geometry, the rescale, the gate and
 # the window are all chosen by what the model has, at trace time.
 _KIMI = {
-    "decode": (4492, "61977bcde222d64d5b6427188fec20dea61ce1a001e5f668ed1a"
-                     "06a413741b18"),
-    "prefill_chunk": (20243, "869c8ce402d8ff9b3ceecb569745eac40e287fa8f7d0"
-                             "285d3cea4bc1c0a698ca"),
+    "decode": (4830, "54930e0dcb2f24089a6c71b4f46fd3753e39dc13d2d945ad68bf"
+                     "c15432f644c4"),
+    "prefill_chunk": (20334, "82484019bd0b4bf2fcc45a5d0bfd2623d6fc38cd6003"
+                             "ccf1f0b381fcf2497ff7"),
 }
 
 
@@ -510,7 +512,7 @@ def kimi_program_text(program):
                           jnp.bfloat16)] * (cfg.num_layers - kda),
         "kda_state": [S(state, jnp.float32)] * kda,
         "kda_conv": [S(conv, jnp.bfloat16)] * kda,
-        "table": S((b, mb), jnp.int32), "moe_picks": S((2,), jnp.uint32),
+        "table": S((b, mb), jnp.int32), "moe_picks": S((4,), jnp.uint32),
         "watch_slot": S((), jnp.int32)}
     ints = lambda *shape: S(shape, jnp.int32)  # noqa: E731
     kernels = dict(attention_impl="pallas", kernel_interpret=True)

@@ -86,7 +86,7 @@ def fresh_cache(cfg, blocks=16, bs=8, slots=1):
         "latent_pool": [jnp.zeros((blocks, bs, width))
                         for _ in range(cfg.num_layers)],
         "table": jnp.asarray(table),
-        "moe_picks": jnp.zeros(2, jnp.uint32)}
+        "moe_picks": jnp.zeros(4, jnp.uint32)}
 
 
 def reference_logits(cfg, params, seq):
@@ -440,14 +440,17 @@ def serve_latent_watched(cfg, params):
 # 44's: its selection is a threshold and a mask over the decode kernel
 # (``_kth_largest``, a float32 bias as ``mla_decode_attention``'s fifth
 # operand, the watched slot's mask row as positions) where it was
-# ``top_k``, a gather and two einsums; ``prefill`` is the text it was
+# ``top_k``, a gather and two einsums.  ALL THREE are PR 51's: the
+# experts' sorted buffer is ``sparse_mlp``'s compact one, placed by
+# counting and walked (no ``argsort``, no gather of ``T x top_k`` rows),
+# and ``moe_picks`` carries four counts; nothing else in them moved.
 _GLM5 = {
-    "decode": (6290, "93faba0c1818cd30fdf58c8588c48d6665d448c3e2b4d75b96e8"
-                     "3086deeec6d9"),
-    "prefill_chunk": (6531, "2191d9240025eb16efd8a5070ced717311d6444dfc91"
-                            "4fb62cd234abfc053e0b"),
-    "prefill": (5010, "3c37b0a227d855b7cf0046847617ad5712bfd23acd6424ded8"
-                      "17c9fc93bd7aee"),
+    "decode": (6346, "e67517283fd887fe4c76a2e213eb0dfc5f2c1e99a0ebb57e1565"
+                     "dad87d3ae69d"),
+    "prefill_chunk": (6582, "f2e975da3158689a8342e5e2355a75d8bde4bcee1a07"
+                            "4b0a4b241cb6745ee158"),
+    "prefill": (5056, "233fa462d5089b3d8b93c23260104424cd65a4470a8b539002"
+                      "8bbaeaaf948227"),
 }
 
 
@@ -476,7 +479,7 @@ def test_glm5_traces_what_it_did(program, tmp_path):
                           jnp.bfloat16)] * cfg.num_layers,
         "index_pool": [S((nb, bs, cfg.index_head_dim),
                          jnp.bfloat16)] * cfg.num_layers,
-        "table": S((b, mb), jnp.int32), "moe_picks": S((2,), jnp.uint32),
+        "table": S((b, mb), jnp.int32), "moe_picks": S((4,), jnp.uint32),
         "watch_slot": S((), jnp.int32)}
     ints = lambda *shape: S(shape, jnp.int32)  # noqa: E731
     kernels = dict(attention_impl="pallas", kernel_interpret=True)

@@ -270,6 +270,7 @@ def test_the_books_are_inert_for_a_dense_model():
 _GAUGES = {
     "serving_dsa_selected_ratio": 0.08,
     "serving_moe_held_share": 0.0625,
+    "serving_moe_walks_per_layer": 1.2,
     "serving_prefill_live_tile_share": 0.7,
 }
 
@@ -285,9 +286,11 @@ def test_the_gauges_reach_the_scrape(gauge):
     m.observe_engine_metrics([
         {"dsa_rows_live": 100.0, "attn_rows_selected": 8.0,
          "moe_picks": 64.0, "moe_picks_held": 4.0,
+         "moe_buffer_walks": 10.0, "moe_layer_forwards": 10.0,
          "prefill_query_tiles": 16.0, "prefill_query_tiles_live": 16.0},
         {"dsa_rows_live": 300.0, "attn_rows_selected": 24.0,
          "moe_picks": 64.0, "moe_picks_held": 4.0,
+         "moe_buffer_walks": 14.0, "moe_layer_forwards": 10.0,
          "prefill_query_tiles": 64.0, "prefill_query_tiles_live": 40.0},
         {}])
     assert m.metrics()[gauge] == pytest.approx(_GAUGES[gauge])
@@ -518,7 +521,7 @@ def test_the_witness_rows_are_the_watched_slots_mask(impl, cfg, sp,
         "index_pool": [jnp.zeros((33, 8, cfg.index_head_dim))]
         * cfg.num_layers,
         "table": jnp.asarray(np.arange(1, 33).reshape(2, 16), jnp.int32),
-        "moe_picks": jnp.zeros(2, jnp.uint32)}
+        "moe_picks": jnp.zeros(4, jnp.uint32)}
     kw = dict(attention_impl=impl, kernel_interpret=True)
     chunk = jax.jit(lambda p, c, t, at, slot: latent.verify_step(
         p, cfg, c, t, at, slots=slot, **kw))

@@ -1213,9 +1213,11 @@ def test_lookahead_counters_are_counted_and_exported(tiny_model):
     assert metrics.metrics()["serving_engine_chained_dispatch_share"] == 0.0
     from dlrover_tpu.utils.metric_registry import METRIC_HELP
 
-    for name in ("serving_engine_lookahead_steps_total",
+    for name in ("serving_engine_chained_dispatch_share",
+                 "serving_moe_walks_per_layer",
+                 "serving_engine_lookahead_steps_total",
                  "serving_engine_wasted_lane_chunks_total"):
-        assert name in METRIC_HELP
+        assert name in METRIC_HELP and name in got
 
 
 @pytest.mark.parametrize("outside", ["cancel", "spec_step", "drain_fixed",

@@ -270,7 +270,7 @@ def _decode_forward(one_chip, cfg, seeded, cache: dict, slots: int):
                                active=act)
 
     lowered = jax.jit(forward, donate_argnums=(1,)).lower(*on_chip((
-        sp, dict(cache, moe_picks=S((2,), jnp.uint32),
+        sp, dict(cache, moe_picks=S((4,), jnp.uint32),
                  watch_slot=S((), jnp.int32)),
         S((slots,), jnp.int32), S((slots,), jnp.int32),
         S((slots,), jnp.bool_))))
@@ -359,7 +359,8 @@ def test_glm5_decode_forward_streams_under_the_mask(one_chip, monkeypatch):
     selection is a threshold and a mask.  A layer is ONE
     ``paged_index_scores`` under ``dsa_index`` and ONE ``mla_decode_attn``
     under ``mla_attn`` whose fifth operand is the float32 mask, a row a
-    slot; the program sorts nothing but the experts' picks, and holds no
+    slot; the program sorts nothing but the router's scores (the experts'
+    picks are placed by counting, ``sparse_mlp``), and holds no
     copy of ``slots x index_topk`` latent rows nor of every row a table
     could hold."""
     import re
@@ -401,7 +402,7 @@ def test_glm5_decode_forward_streams_under_the_mask(one_chip, monkeypatch):
          f"bf16[{nb},{bs},640]", mask]] * 2
     sorts = {table.scope_of.get(m) for m in re.findall(
         r"%(sort[.\d]*) = \S+ sort\(", text)}
-    assert sorts <= {"moe_route", "moe_experts"}, sorts
+    assert sorts <= {"moe_route"}, sorts
     for rows in (cfg.index_topk, mb * bs):        # latent rows, copied
         assert not re.search(rf"\[{slots},{rows},(640|512)\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2**20
@@ -494,7 +495,7 @@ def test_kimi_linear_programs_keep_their_state_in_place(one_chip,
         "kda_state": [S(state, jnp.float32)] * 3,
         "kda_conv": [S(conv, jnp.bfloat16)] * 3,
         "table": S((slots, mb), jnp.int32),
-        "moe_picks": S((2,), jnp.uint32),
+        "moe_picks": S((4,), jnp.uint32),
         "watch_slot": S((), jnp.int32)})
     if program == "decode":
         def forward(p, c, t, pos, act):
@@ -544,7 +545,9 @@ def test_granite_programs_keep_their_state_in_place(one_chip, monkeypatch,
     decode forward holds ``ssm_decode_step`` and the prompt chunk
     ``ssm_chunk_fwd``, each under ``ssm_scan``, the attention layer's decode
     kernel under ``paged_attn``; the donated states come back aliased, and
-    nothing in the program is a copy of a layer's 537 MB of state."""
+    nothing in the program is a copy of a layer's 537 MB of state.  The
+    chunk's sparse layers hold no array of all 5 120 picks' rows: their
+    sorted buffer is 2 048 (``sparse_mlp``)."""
     from dlrover_tpu.models import moe
     from dlrover_tpu.models.llama import LlamaConfig
     from dlrover_tpu.serving import latent, linear
@@ -574,7 +577,7 @@ def test_granite_programs_keep_their_state_in_place(one_chip, monkeypatch,
         "ssm_state": [S(state, jnp.float32)] * 9,
         "ssm_conv": [S(conv, jnp.bfloat16)] * 9,
         "table": S((slots, mb), jnp.int32),
-        "moe_picks": S((2,), jnp.uint32),
+        "moe_picks": S((4,), jnp.uint32),
         "watch_slot": S((), jnp.int32)})
     if program == "decode":
         def forward(p, c, t, pos, act):
@@ -609,6 +612,12 @@ def test_granite_programs_keep_their_state_in_place(one_chip, monkeypatch,
     assert sorted(scopes.values()) == sorted(
         "ssm_scan" if k.startswith("ssm") else "paged_attn"
         for k in kernels), scopes
+    if program == "prefill_chunk":
+        import re
+
+        text = compiled.as_text()
+        assert "[2048,4096]" in text and not re.search(
+            r"\[5120,(4096|768)\]", text)
     memory = compiled.memory_analysis()
     one_state = 128 * 128 * 64 * 128 * 4
     assert memory.alias_size_in_bytes >= 9 * one_state
