@@ -50,6 +50,7 @@ from dlrover_tpu.serving.params import serving_params_from_llama
 from dlrover_tpu.utils.profiler import (
     abstract,
     device_scope,
+    event,
     program_texts,
     register_program,
     span,
@@ -80,6 +81,24 @@ class Request:
     max_new_tokens: int
     output: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
+    # the request's own clock: ``time.monotonic()``, the router's, which
+    # takes these stamps as they are (the programs' ``*_seconds`` keep
+    # ``perf_counter``), each read where the host books the fact
+    queued_at: float = 0.0        # ``add_request``
+    admitted_at: Optional[float] = None  # its slot and blocks booked
+    # the READ of the program that sampled its first and its newest
+    # tokens: one stamp a program read, the same for every lane of it
+    first_token_at: Optional[float] = None
+    last_token_at: Optional[float] = None
+    prompt_chunks: int = 0        # programs that ran its prompt, one an
+    #                               engine step (a bucketed prefill: 1)
+    deliveries: int = 0           # reads that handed it tokens
+    cached_tokens: int = 0        # where in its prompt its prefill
+    #                               began: a chunked warm start's first
+    #                               position, the shared region whose
+    #                               writes a bucketed prefill masks (it
+    #                               computes them all the same), 0 cold
+    gap_max: float = 0.0          # its longest wait between deliveries
 
 
 @dataclasses.dataclass
@@ -200,6 +219,22 @@ class EngineStats:
     # above count their states too; their chunk kernel's rows
     ssm_chunk_rows_real: int = 0
     ssm_chunk_rows_padded: int = 0  # ... in chunks of 128 tokens
+    # the requests' own clocks (``Request``), summed over the engine's
+    # whole life: sums and counts an operator divides, no percentile here
+    slot_wait_seconds: float = 0.0  # ``add_request`` to a slot and its
+    #                               blocks, over admissions
+    prefill_wall_seconds: float = 0.0  # admission to the read of the
+    first_tokens: int = 0         # ... first token, and how many
+    token_gap_seconds: float = 0.0  # a delivery's read less the same
+    token_gaps: int = 0           # ... request's last one: a first
+    #                               delivery has none
+    prompt_tokens: int = 0        # of admitted prompts, and those its
+    prompt_tokens_cached: int = 0  # ... prefill began behind
+    # ... by name (no field: how ``engine_metrics`` hands them on)
+    REQUEST_CLOCK = (
+        "slot_wait_seconds", "prefill_wall_seconds", "first_tokens",
+        "token_gap_seconds", "token_gaps", "prompt_tokens",
+        "prompt_tokens_cached")
 
     @property
     def decode_tokens_per_sec(self) -> float:
@@ -314,10 +349,10 @@ class _Unread:
     attrs: Dict[str, Any]         # ... and its attributes
     started: float                # host clock at the dispatch's start
     outputs: Any                  # device arrays the host has to read
-    # the bookkeeping that needs them, ``deliver(rows, values)``, and the
-    # lanes it is for: tuples of (slot, request, ...), which ``cancel``
-    # thins
-    deliver: Callable[[List[tuple], Any], None]
+    # the bookkeeping that needs them, ``deliver(rows, values, read at,
+    # name)``, and the lanes it is for: tuples of (slot, request, ...),
+    # which ``cancel`` thins
+    deliver: Callable[[List[tuple], Any, float, str], None]
     rows: List[tuple]
     # ``witness_log`` entries of these programs (``watch``): in the log
     # once the programs are read, never while they run
@@ -331,6 +366,16 @@ class _Unread:
 _CLOCKS = {"prefill": ("prefill_seconds",),
            "prefill_chunk": ("prefill_seconds", "prefill_chunk_seconds"),
            "decode_chunk": ("decode_seconds",)}
+
+
+# a program that hands on first tokens only: no delivery before them
+_NO_GAPS = (0, 0.0, 0.0)
+
+
+def _erids(requests) -> str:
+    """Whose prompt a program ran, for its span (joined with spaces: the
+    profiler cuts a string at a comma)."""
+    return " ".join(str(r.rid) for r in requests)
 
 
 def _bucket(n: int, buckets: Tuple[int, ...]) -> int:
@@ -1252,7 +1297,8 @@ class InferenceEngine:
                     "(cache_blocks too small for this request)")
         rid = self._next_rid
         self._next_rid += 1
-        self._queue.append(Request(rid, prompt, int(max_new_tokens)))
+        self._queue.append(Request(rid, prompt, int(max_new_tokens),
+                                   queued_at=time.monotonic()))
         return rid
 
     def _admit(self) -> None:
@@ -1329,6 +1375,7 @@ class InferenceEngine:
                 for g, s in enumerate(slots):
                     self._bind_blocks(s, allocs[g][0])
                 self._push_table()
+            admitted = time.monotonic()
             padded = np.zeros((len(group), bucket), np.int32)
             lens = np.empty(len(group), np.int32)
             for g, req in enumerate(group):
@@ -1351,26 +1398,57 @@ class InferenceEngine:
                     )
             self.stats.prefill_calls += 1
             self.stats.prefill_admissions += len(group)
-            for s, req in zip(slots, group):
+            for s, req, skip in zip(slots, group, skips):
                 self._slot_req[s] = req
                 self._positions[s] = req.prompt.size
                 self._remaining[s] = req.max_new_tokens - 1
+                req.prompt_chunks = 1
+                self._book_admission(s, req, admitted, int(skip), 1)
             self._unread.append(_Unread(
-                "prefill", {"bucket": bucket, "n": len(group)}, started,
+                "prefill", {"bucket": bucket, "n": len(group),
+                            "erids": _erids(group)}, started,
                 [firsts], self._deliver_firsts,
                 [(s, req, g) for g, (s, req) in enumerate(
                     zip(slots, group))]))
 
+    def _book_admission(self, s: int, req: Request, now: float,
+                        cached: int, chunks: int) -> None:
+        """``req`` has slot ``s`` and its blocks: its wait for them ended
+        at ``now``, its prefill begins ``cached`` tokens into its prompt
+        and takes ``chunks`` programs."""
+        req.admitted_at = now
+        req.cached_tokens = cached
+        waited = now - req.queued_at
+        st = self.stats
+        st.slot_wait_seconds += waited
+        st.prompt_tokens += req.prompt.size
+        st.prompt_tokens_cached += cached
+        event("dlrover.request.admitted", erid=req.rid, slot=s,
+              slot_wait_ms=waited * 1e3,
+              prompt_tokens=int(req.prompt.size), cached_tokens=cached,
+              chunks=chunks)
+
     def _deliver_firsts(self, rows: List[Tuple[int, Request, int]],
-                        firsts: List[np.ndarray]) -> None:
-        """Hand prefill programs' first tokens (one array a program) to
-        their requests: ``rows`` names a slot, the request (which the
-        step's decode dispatch may have taken off the slot already: a
-        budget that its chunk spends) and its index in the tokens."""
+                        firsts: List[np.ndarray], now: float,
+                        program: str) -> None:
+        """Hand prefill programs' first tokens (one array a program),
+        read at ``now``, to their requests: ``rows`` names a slot, the
+        request (which the step's decode dispatch may have taken off the
+        slot already: a budget that its chunk spends) and its index in
+        the tokens."""
         firsts = np.concatenate(firsts)
         for s, req, g in rows:
             first = int(firsts[g])
             req.output.append(first)
+            req.first_token_at = req.last_token_at = now
+            req.deliveries = 1
+            wall = now - req.admitted_at
+            self.stats.first_tokens += 1
+            self.stats.prefill_wall_seconds += wall
+            event("dlrover.request.first_token", erid=req.rid,
+                  prefill_ms=wall * 1e3,
+                  since_queued_ms=(now - req.queued_at) * 1e3,
+                  steps=req.prompt_chunks)
             p = req.prompt.size
             self._ctx_buf[s, :p] = req.prompt
             self._ctx_buf[s, p] = first
@@ -1379,6 +1457,7 @@ class InferenceEngine:
             if first == self.eos_token or (
                     self._slot_req[s] is req and self._remaining[s] <= 0):
                 self._finish(s, req)
+        self._say_delivered(program, len(rows), len(rows), _NO_GAPS)
 
     def _alloc_lifetime(self, req: Request, bucket: int):
         """ONE capacity formula for every admission path (batched AND
@@ -1490,6 +1569,9 @@ class InferenceEngine:
         self._positions[s] = self._park_pos
         self._remaining[s] = req.max_new_tokens
         self.stats.prefill_admissions += 1
+        self._book_admission(
+            s, req, time.monotonic(), start,
+            -(-(req.prompt.size - start) // self.prefill_chunk))
         return True
 
     def _copy_blocks(self, src: List[int], dst: List[int]) -> None:
@@ -1549,6 +1631,7 @@ class InferenceEngine:
         for i, s in enumerate(slots):
             req = self._slot_req[s]
             assert req is not None
+            req.prompt_chunks += 1
             start = int(self._prefill_pos[s])
             end = min(start + c, req.prompt.size)
             chunk[i, : end - start] = req.prompt[start:end]
@@ -1562,7 +1645,9 @@ class InferenceEngine:
         if self.paged and self._table_dirty:
             self._push_table()
         started = time.perf_counter()
-        attrs = {"n": g, **self._book_selection(starts, ends),
+        attrs = {"n": g,
+                 "erids": _erids(self._slot_req[s] for s in slots),
+                 **self._book_selection(starts, ends),
                  **self._book_key_blocks(starts, ends),
                  **self._book_state_chunks(starts, ends)}
         with self._dispatching("prefill_chunk"):
@@ -1664,6 +1749,10 @@ class InferenceEngine:
         req.done = True
         self._finished.append(req)
         self.stats.finished_requests += 1
+        event("dlrover.request.finished", erid=req.rid,
+              tokens=len(req.output),
+              decode_ms=(req.last_token_at - req.first_token_at) * 1e3,
+              deliveries=req.deliveries, gap_ms_max=req.gap_max * 1e3)
         if self._slot_req[s] is req:
             self._release_slot(s)
 
@@ -1947,13 +2036,14 @@ class InferenceEngine:
                     values = jax.tree_util.tree_map(
                         np.asarray, sent.outputs)
                 now = time.perf_counter()
+                read_at = time.monotonic()   # the requests' clock
                 for clock in _CLOCKS[sent.name]:
                     setattr(self.stats, clock, getattr(self.stats, clock)
                             + now - max(sent.started, self._reached))
                 self._reached = now
                 self.witness_log.extend(sent.witness)
                 self._book_moe_picks(sent.picks)
-                sent.deliver(sent.rows, values)
+                sent.deliver(sent.rows, values, read_at, sent.name)
             self._in_flight = kept
             self._head_alone = False
 
@@ -2218,16 +2308,41 @@ class InferenceEngine:
                               delta):
             setattr(self.stats, name, getattr(self.stats, name) + int(more))
 
-    @spanned("dlrover.engine.deliver")
+    def _handed(self, req: Request, now: float, gaps: List[float]) -> None:
+        """``req`` is handed tokens that were read at ``now``: its gap
+        since its last delivery joins the engine's sums, its own longest
+        and ``gaps`` (count, seconds, longest: one delivery's)."""
+        gap = now - req.last_token_at
+        req.last_token_at = now
+        req.deliveries += 1
+        req.gap_max = max(req.gap_max, gap)
+        self.stats.token_gaps += 1
+        self.stats.token_gap_seconds += gap
+        gaps[0] += 1
+        gaps[1] += gap
+        gaps[2] = max(gaps[2], gap)
+
+    def _say_delivered(self, program: str, lanes: int, tokens: int,
+                       gaps: List[float]) -> None:
+        """``dlrover.engine.deliver``: what one program's read handed on,
+        said once it is handed (the sums are what ``_handed`` booked)."""
+        if lanes:
+            event("dlrover.engine.deliver", program=program, lanes=lanes,
+                  tokens=tokens, gaps=int(gaps[0]),
+                  gap_ms_sum=gaps[1] * 1e3, gap_ms_max=gaps[2] * 1e3)
+
     def _deliver_chunk(self, lanes: List[Tuple[int, Request, int, bool]],
-                       out: np.ndarray) -> None:
-        """Hand a decode chunk's tokens ([B, chunk]) on: ``lanes`` names
-        each slot it advanced, the request that held the slot at its
-        dispatch, how many of the lane's tokens that request's budget
-        had room for, and whether those are its last.  A request that an
-        earlier read has ended (an end-of-sequence: the chunk before's,
-        or its first token) keeps nothing of its lane; one whose budget
-        the chunk spent, or that ends inside it, ends here."""
+                       out: np.ndarray, now: float, program: str) -> None:
+        """Hand a decode chunk's tokens ([B, chunk]), read at ``now``,
+        on: ``lanes`` names each slot it advanced, the request that held
+        the slot at its dispatch, how many of the lane's tokens that
+        request's budget had room for, and whether those are its last.
+        A request that an earlier read has ended (an end-of-sequence:
+        the chunk before's, or its first token) keeps nothing of its
+        lane; one whose budget the chunk spent, or that ends inside it,
+        ends here."""
+        gaps = [0, 0.0, 0.0]
+        before = self.stats.generated_tokens
         for s, req, take, last in lanes:
             if self._slot_req[s] is req or self._slot_req[s] is None:
                 # (a later occupant's token is its own program's)
@@ -2247,8 +2362,11 @@ class InferenceEngine:
                 self._ctx_buf[s, n:end] = toks[: end - n]
                 self._ctx_len[s] = end
             self.stats.generated_tokens += len(toks)
+            self._handed(req, now, gaps)
             if last or toks[-1] == self.eos_token:
                 self._finish(s, req)
+        self._say_delivered(program, len(lanes),
+                            self.stats.generated_tokens - before, gaps)
 
     def _after_chunk_round(self) -> None:
         """Speculation governor, chunk-decode side: count down a
@@ -2311,6 +2429,7 @@ class InferenceEngine:
             )
             out = np.asarray(out)
             n_commit = np.asarray(n_commit)
+        now = time.monotonic()          # the requests' clock
         self._in_flight = 0
         # the commits below write the host's ``_tokens`` only
         self._last_dev = None
@@ -2319,32 +2438,38 @@ class InferenceEngine:
         self.stats.decode_forwards += 1
         round_proposed = 0
         round_accepted = 0
-        with span("dlrover.engine.deliver"):
-            for s in range(self.max_slots):
-                req = self._slot_req[s]
-                if req is None or self._prefilling[s]:
-                    continue
-                accepted = int(n_commit[s]) - 1
-                round_proposed += int(draft_lens[s])
-                round_accepted += accepted
-                self.stats.spec_proposed += int(draft_lens[s])
-                self.stats.spec_accepted += accepted
-                toks = out[s, : accepted + 1].tolist()
-                take = min(len(toks), int(self._remaining[s]))
-                toks = toks[:take]
-                if self.eos_token is not None and self.eos_token in toks:
-                    toks = toks[: toks.index(self.eos_token) + 1]
-                if not toks:
-                    continue
-                req.output.extend(toks)
-                n = int(self._ctx_len[s])
-                self._ctx_buf[s, n:n + len(toks)] = toks
-                self._ctx_len[s] = n + len(toks)
-                self._remaining[s] -= len(toks)
-                self.stats.generated_tokens += len(toks)
-                self._tokens[s] = toks[-1]
-                self._positions[s] += len(toks)
-                self._finish_if_done(s, toks[-1])
+        gaps = [0, 0.0, 0.0]
+        lanes = 0
+        before = self.stats.generated_tokens
+        for s in range(self.max_slots):
+            req = self._slot_req[s]
+            if req is None or self._prefilling[s]:
+                continue
+            accepted = int(n_commit[s]) - 1
+            round_proposed += int(draft_lens[s])
+            round_accepted += accepted
+            self.stats.spec_proposed += int(draft_lens[s])
+            self.stats.spec_accepted += accepted
+            toks = out[s, : accepted + 1].tolist()
+            take = min(len(toks), int(self._remaining[s]))
+            toks = toks[:take]
+            if self.eos_token is not None and self.eos_token in toks:
+                toks = toks[: toks.index(self.eos_token) + 1]
+            if not toks:
+                continue
+            req.output.extend(toks)
+            lanes += 1
+            self._handed(req, now, gaps)
+            n = int(self._ctx_len[s])
+            self._ctx_buf[s, n:n + len(toks)] = toks
+            self._ctx_len[s] = n + len(toks)
+            self._remaining[s] -= len(toks)
+            self.stats.generated_tokens += len(toks)
+            self._tokens[s] = toks[-1]
+            self._positions[s] += len(toks)
+            self._finish_if_done(s, toks[-1])
+        self._say_delivered("verify", lanes,
+                            self.stats.generated_tokens - before, gaps)
         # governor: measured low acceptance -> back off to chunk decode
         # (a missing draft costs one wasted verify's worth of drafts
         # every round; backing off makes the miss genuinely free)
@@ -2408,6 +2533,8 @@ class InferenceEngine:
         self._positions = np.array(positions)
         self._last_dev = None
         self.stats.decode_seconds += time.perf_counter() - t0
+        now, gaps = time.monotonic(), [0, 0.0, 0.0]
+        before = self.stats.generated_tokens
         for s in range(self.max_slots):
             req = self._slot_req[s]
             if req is None:
@@ -2415,9 +2542,13 @@ class InferenceEngine:
             take = min(out.shape[1], int(self._remaining[s]))
             toks = out[s, :take].tolist()
             req.output.extend(toks)
+            if toks:
+                self._handed(req, now, gaps)
             self._remaining[s] -= len(toks)
             self.stats.generated_tokens += len(toks)
             self._finish_if_done(s, toks[-1] if toks else -1)
+        self._say_delivered("decode_chunk", int(gaps[0]),
+                            self.stats.generated_tokens - before, gaps)
 
     # ----------------------------------------- batch-generate (RL API)
     def generate(

@@ -50,14 +50,6 @@ _UNHANDLED_FRAME_KINDS = (FrameKind.HEARTBEAT,)
 class RemoteReplicaHandle:
     """Engine-protocol proxy over one worker's frame connection."""
 
-    # decode-step attribution contract: in-process engines time their
-    # own step() into last_step_seconds, but this proxy's step() is a
-    # frame DRAIN (microseconds) — timing it would report network
-    # bookkeeping as decode time.  Pinned to None so ReplicaHandle.pump
-    # always takes the worker-reported path (the worker.decode span's
-    # engine_seconds/steps riding the DONE frame) for remote replicas.
-    last_step_seconds = None
-
     def __init__(
         self,
         addr: str,

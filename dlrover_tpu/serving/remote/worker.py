@@ -71,9 +71,6 @@ class FakeEngine:  # dlint: disable=DL011 stands in for the remote worker PROCES
         # engine's committed-prefix hot-head ranking, so fabric/router
         # tests exercise prefix-routing advertisements without jax
         self._head_hits: Dict[str, int] = {}
-        # wall seconds of the most recent step() — decode-step
-        # histogram attribution when this engine runs in-process
-        self.last_step_seconds: Optional[float] = None
 
     def add_request(self, prompt, max_new_tokens: int) -> int:
         prompt = np.asarray(prompt, np.int32).reshape(-1)
@@ -100,7 +97,6 @@ class FakeEngine:  # dlint: disable=DL011 stands in for the remote worker PROCES
         return rid
 
     def step(self) -> List:
-        t0 = time.perf_counter()
         if self.step_delay:
             time.sleep(self.step_delay)
         finished = []
@@ -120,7 +116,6 @@ class FakeEngine:  # dlint: disable=DL011 stands in for the remote worker PROCES
                 finished.append(
                     SimpleNamespace(rid=rid, output=st["output"]))
                 del self.active[rid]
-        self.last_step_seconds = time.perf_counter() - t0
         return finished
 
     @property

@@ -44,6 +44,7 @@ from dlrover_tpu.serving.tenancy import (
     WfqBandQueue,
     plan_shed,
 )
+from dlrover_tpu.utils.profiler import event
 from dlrover_tpu.utils.tracing import RequestTrace, Tracer
 
 PRIORITY_HIGH = 0
@@ -152,9 +153,9 @@ class ServingRequest:
     # ones are aborted and a CANCEL is sent to the owning replica
     cancel_requested: bool = False
     # when the first token of the current attempt was seen: the TOKEN
-    # frame's receive time for a remote replica; for an in-process one
-    # the ``now`` its router step BEGAN with, so up to one engine step
-    # EARLY (``last_delivery_at`` is the clock read at the hand-over)
+    # frame's receive time for a remote replica, the engine's read of
+    # the program that sampled it for an in-process one
+    # (``last_delivery_at`` is the clock read at the hand-over here)
     first_token_at: Optional[float] = None
     ttft_recorded: bool = False            # metrics bookkeeping
     finished_at: Optional[float] = None
@@ -181,11 +182,6 @@ class ServingRequest:
     stream_owner: Optional[tuple] = dataclasses.field(
         default=None, repr=False, compare=False
     )
-    # per-decode-step seconds of the attempt that finished this request
-    # (worker-reported over the DONE frame's worker.decode span for
-    # remote replicas, engine-timed for in-process ones); feeds the
-    # serving_decode_step_seconds histogram with this trace's exemplar
-    decode_step_seconds: Optional[float] = None
     _done: threading.Event = dataclasses.field(
         default_factory=threading.Event, repr=False, compare=False
     )
@@ -227,6 +223,13 @@ class ServingRequest:
     _on_terminal: Optional[object] = dataclasses.field(
         default=None, repr=False, compare=False
     )
+    # token-gap hook, stamped by the router that admitted the request:
+    # every delivery but an attempt's first calls it with the seconds
+    # since the delivery before and the trace id (the
+    # serving_token_gap_seconds histogram and its exemplar)
+    _on_gap: Optional[object] = dataclasses.field(
+        default=None, repr=False, compare=False
+    )
 
     @property
     def total_len(self) -> int:
@@ -248,18 +251,32 @@ class ServingRequest:
         if not tokens:
             return
         if self.first_token_at is None:
-            self.first_token_at = now
-            if self.trace is not None:
-                self.trace.first_token(now)
+            self.mark_first_token(now)
         self.last_token_at = now
         self._delivered()
         self.output.extend(tokens)
         self._streamed += len(tokens)
         self._events.put(("tokens", list(tokens)))
 
+    def mark_first_token(self, now: float) -> None:
+        """The current attempt's first token was seen at ``now``."""
+        self.first_token_at = now
+        if self.trace is not None:
+            self.trace.first_token(now)
+        event("dlrover.request.first_delivery", rid=self.rid,
+              ttft_ms=(now - self.submitted_at) * 1e3)
+
     def _delivered(self) -> None:
+        """Tokens change hands: a local engine's hand-over and a remote
+        worker's TOKEN frame both come through here, so the gap between
+        two deliveries reads alike for both kinds of replica."""
+        now = time.monotonic()
+        if self._on_gap is not None and self.last_delivery_at is not None:
+            self._on_gap(now - self.last_delivery_at,
+                         None if self.trace is None
+                         else self.trace.trace_id)
         self.deliveries += 1
-        self.last_delivery_at = time.monotonic()
+        self.last_delivery_at = now
 
     def finish(self, output: List[int], now: float) -> None:
         if self.state in SERVING_REQUEST_TERMINAL_STATES:
@@ -276,9 +293,7 @@ class ServingRequest:
             self._delivered()
             self._events.put(("tokens", output[self._streamed:]))
         if self.first_token_at is None:
-            self.first_token_at = now
-            if self.trace is not None:
-                self.trace.first_token(now)
+            self.mark_first_token(now)
         self.output = output
         self.state = ServingRequestState.DONE
         # clamp: the router stamps a whole pump round with its entry

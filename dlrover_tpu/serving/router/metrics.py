@@ -47,7 +47,7 @@ Gauge/counter names (stable API, documented in README + PERF.md):
   second attempts dispatched, races the hedge won, loser CANCELs,
   budget denials, primaries-died-hedge-took-over promotions, and the
   currently-racing count
-- ``serving_{ttft_hist,queue_wait,e2e_latency,decode_step}_seconds``
+- ``serving_{ttft_hist,queue_wait,e2e_latency,token_gap}_seconds``
   — OpenMetrics latency histograms (``_bucket``/``_count``/``_sum``,
   log-spaced buckets) with ``trace_id`` exemplars on the buckets, so
   "p99 TTFT spiked" drills down to the exact trace via ``/traces``
@@ -208,9 +208,7 @@ class RouterMetrics:
         self.ttft_hist = _hist("serving_ttft_hist_seconds")
         self.queue_wait_hist = _hist("serving_queue_wait_seconds")
         self.e2e_hist = _hist("serving_e2e_latency_seconds")
-        self.decode_step_hist = _hist(
-            "serving_decode_step_seconds",
-            buckets=log_buckets(1e-4, 2.0))
+        self.token_gap_hist = _hist("serving_token_gap_seconds")
         # step-loop instrumentation (measure FIRST, then attack what
         # the histograms name): per-critical-section lock hold time +
         # per-phase wall time of each router step round.  µs-floor
@@ -269,11 +267,11 @@ class RouterMetrics:
         """Admission-to-completion latency of a finished request."""
         self.e2e_hist.observe(seconds, trace_id=trace_id)
 
-    def observe_decode_step(self, seconds: float,
-                            trace_id: Optional[str] = None) -> None:
-        """One engine decode step (whole-batch attribution; remote
-        replicas report theirs via the worker.decode span)."""
-        self.decode_step_hist.observe(seconds, trace_id=trace_id)
+    def observe_token_gap(self, seconds: float,
+                          trace_id: Optional[str] = None) -> None:
+        """Between two deliveries of tokens to one request
+        (``ServingRequest._delivered``; an attempt's first has none)."""
+        self.token_gap_hist.observe(seconds, trace_id=trace_id)
 
     def observe_step_lock(self, seconds: float) -> None:
         """One step-lock critical section's hold time."""
@@ -483,7 +481,7 @@ class RouterMetrics:
         ``exporter.attach_router(router)``)."""
         parts = [h.render() for h in (
             self.ttft_hist, self.queue_wait_hist,
-            self.e2e_hist, self.decode_step_hist,
+            self.e2e_hist, self.token_gap_hist,
             self.step_lock_hist,
         )]
         # the phase histograms are ONE family fanned out by label: emit

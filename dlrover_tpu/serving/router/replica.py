@@ -82,11 +82,6 @@ class InferenceEngineAdapter:
     def __init__(self, engine):
         self.engine = engine
         self._stream_pos: Dict[int, int] = {}  # rid -> tokens streamed
-        # wall seconds of the most recent step() — feeds the
-        # serving_decode_step_seconds histogram (whole-batch
-        # attribution, same convention as the remote worker's
-        # worker.decode span)
-        self.last_step_seconds: Optional[float] = None
 
     @property
     def block_size(self) -> int:
@@ -101,10 +96,7 @@ class InferenceEngineAdapter:
         return self.engine.add_request(prompt, max_new_tokens)
 
     def step(self) -> List:
-        t0 = time.perf_counter()
-        finished = self.engine.step()
-        self.last_step_seconds = time.perf_counter() - t0
-        return finished
+        return self.engine.step()
 
     def inflight_outputs(self) -> Dict[int, List[int]]:
         """Live output snapshot per request that HOLDS A SLOT (finished
@@ -120,11 +112,16 @@ class InferenceEngineAdapter:
 
     def drain_token_events(self, now: float) -> List:
         """Tokens emitted since the last drain as ``(rid, tokens, t)``
-        events.  The in-process engine emits inside ``step()``, so the
-        pump's ``now`` IS the emission time (remote proxies override the
-        timestamp with the TOKEN frame's receive time instead)."""
+        events, ``t`` the engine's own stamp of each request's newest
+        tokens: the read of the program that sampled them
+        (``Request.last_token_at``, on the router's clock), which lies
+        INSIDE the step the pump's ``now`` began before (so ``now`` is
+        not used here; a remote proxy's ``t`` is the TOKEN frame's
+        receive time)."""
+        read_at = {req.rid: req.last_token_at
+                   for req in self.engine._slot_req if req is not None}
         return [
-            (rid, toks, now)
+            (rid, toks, read_at[rid])
             for rid, toks in stream_deltas(
                 self.inflight_outputs(), self._stream_pos)
         ]
@@ -169,6 +166,9 @@ class InferenceEngineAdapter:
             "chained_dispatches": float(st.chained_dispatches),
             "lookahead_steps": float(st.lookahead_steps),
             "wasted_lane_chunks": float(st.wasted_lane_chunks),
+            # the requests' own clocks, summed (serving/engine.py)
+            **{name: float(getattr(st, name))
+               for name in st.REQUEST_CLOCK},
         }
         if getattr(eng, "paged", False):
             # resolved paged-attention impl (0=xla gather, 1=fused
@@ -257,24 +257,6 @@ class InferenceEngineAdapter:
             _bucket(prompt_len, eng.buckets),
         )
         return float(-(-total // eng.block_size))
-
-
-def _worker_decode_step_seconds(spans) -> Optional[float]:
-    """Per-step decode seconds from a DONE frame's ``worker.decode``
-    span attrs (``engine_seconds`` / ``steps``), or ``None`` when the
-    worker shipped no spans (unsampled trace, legacy worker)."""
-    for raw in spans or ():
-        try:
-            if raw.get("name") != "worker.decode":
-                continue
-            attrs = raw.get("attrs") or {}
-            steps = int(attrs["steps"])
-            engine_s = float(attrs["engine_seconds"])
-        except (AttributeError, KeyError, TypeError, ValueError):
-            continue
-        if steps > 0 and engine_s >= 0:
-            return engine_s / steps
-    return None
 
 
 class ReplicaHandle:
@@ -507,9 +489,10 @@ class ReplicaHandle:
                 f"replica {self.name} engine failed: {e}") from e
         self.last_heartbeat = now
         # streaming engines: forward newly-emitted tokens into each
-        # request's stream; the event timestamp (TOKEN-frame receive
-        # time for remote workers) stamps first_token_at — TTFT is
-        # measured from true first-token emission
+        # request's stream; the event timestamp (the engine's read of
+        # the tokens in process, the TOKEN frame's receive time for a
+        # remote worker) stamps first_token_at — TTFT is measured from
+        # true first-token emission
         drain = getattr(self.engine, "drain_token_events", None)
         if drain is not None:
             for erid, toks, t in drain(now):
@@ -529,10 +512,6 @@ class ReplicaHandle:
                 if first and req.first_token_at is not None:
                     self.ttft_pending.append(req)
         done: List[ServingRequest] = []
-        # whole-batch decode-step attribution for engines that time
-        # their own step (the in-process adapter / FakeEngine); remote
-        # proxies report theirs per request via the worker.decode span
-        local_step_s = getattr(self.engine, "last_step_seconds", None)
         for ereq in finished:
             req = self.inflight.pop(ereq.rid, None)
             if req is None:
@@ -546,16 +525,10 @@ class ReplicaHandle:
                 # contract extended to hedging)
                 continue
             self.generated_tokens += len(ereq.output)
+            # (a sampled-out request's worker shipped no spans: its
+            # completion pays no span grafting, the cost the sampling
+            # knob exists to shed)
             spans = getattr(ereq, "trace_spans", None)
-            if spans:
-                worker_step = _worker_decode_step_seconds(spans)
-            else:
-                # sampled-out request: the worker shipped no spans, so
-                # the completion path pays zero span parsing/grafting
-                # — the cost the sampling knob exists to shed
-                worker_step = None
-            req.decode_step_seconds = (
-                worker_step if worker_step is not None else local_step_s)
             if req.trace is not None and spans:
                 # remote workers ship their own spans (decode steps,
                 # engine time) back on the DONE frame, already shifted
@@ -563,7 +536,12 @@ class ReplicaHandle:
                 # under the attempt that served this request BEFORE
                 # finish() closes the trace into the ring
                 req.trace.graft_worker_spans(spans)
-            req.finish(list(ereq.output), now)
+            # finished when its last tokens were handed over: an engine
+            # that stamps its requests says when (inside this step); one
+            # that does not (FakeEngine, a remote proxy) ends at ``now``
+            read_at = getattr(ereq, "last_token_at", None)
+            req.finish(list(ereq.output),
+                       now if read_at is None else read_at)
             done.append(req)
         if drain is None:
             # legacy engines surface no token stream: the first pump
@@ -572,10 +550,8 @@ class ReplicaHandle:
             # the best available TTFT estimate
             for req in self.inflight.values():
                 if req.first_token_at is None:
-                    req.first_token_at = now
+                    req.mark_first_token(now)
                     self.ttft_pending.append(req)
-                    if req.trace is not None:
-                        req.trace.first_token(now)
             for req in done:
                 if req.first_token_at is None:
                     req.first_token_at = now

@@ -75,7 +75,7 @@ from dlrover_tpu.serving.router.replica import (
 )
 from dlrover_tpu.serving.router.scheduler import ContinuousBatchScheduler
 from dlrover_tpu.serving.tenancy.registry import TENANT_CLASSES
-from dlrover_tpu.utils.profiler import PhaseSpans, span
+from dlrover_tpu.utils.profiler import PhaseSpans, event, span
 
 
 def _tid(req: ServingRequest) -> Optional[str]:
@@ -358,6 +358,7 @@ class ServingRouter:
             self.metrics.rejected = self.gateway.rejected
             raise
         self.metrics.submitted = self.gateway.submitted
+        req._on_gap = self.metrics.observe_token_gap
         return req
 
     # ----------------------------------------------------------- pump
@@ -525,9 +526,16 @@ class ServingRouter:
         for handle, req in placements:
             try:
                 handle.submit(req)
+                waited = max(0.0, now - req.enqueued_at)
                 self.metrics.observe_queue_wait(
-                    max(0.0, now - req.enqueued_at),
-                    trace_id=_tid(req))
+                    waited, trace_id=_tid(req))
+                # both identifiers: the request is one key from this
+                # queue to its last chunk in the profiler's trace
+                event("dlrover.request.placed", rid=req.rid,
+                      erid=req.engine_rid, replica=handle.name,
+                      queue_wait_ms=waited * 1e3,
+                      prompt_tokens=int(req.prompt.size),
+                      requeues=req.requeues)
                 if not handle.ever_placed:
                     # the autoscale trace's final milestone: the
                     # new replica is not just joined but SERVING
@@ -644,10 +652,6 @@ class ServingRouter:
                                 req.priority, ttft, e2e, now,
                                 tenant_class=self.gateway
                                 .tenant_class(req.tenant))
-                    if req.decode_step_seconds is not None:
-                        self.metrics.observe_decode_step(
-                            req.decode_step_seconds,
-                            trace_id=_tid(req))
                     if self.hedge is not None:
                         self._feed_hedge_policy(req)
                     if self._hedges:

@@ -165,7 +165,7 @@ METRIC_HELP: Dict[str, str] = {
         "cumulative wall seconds spent in bounded chunked-prefill "
         "dispatches across the fleet — the budget that keeps one "
         "long prompt from stalling every slot's token cadence "
-        "(compare with serving_decode_step_seconds to verify the "
+        "(compare with serving_token_gap_seconds to verify the "
         "stall bound)"
     ),
     "serving_attention_impl": (
@@ -398,10 +398,11 @@ METRIC_HELP: Dict[str, str] = {
         "admission-to-completion latency distribution "
         "(exemplars carry trace_ids)"
     ),
-    "serving_decode_step_seconds": (
-        "engine decode-step time distribution — whole-batch "
-        "attribution, worker-reported for remote replicas "
-        "(exemplars carry trace_ids)"
+    "serving_token_gap_seconds": (
+        "seconds between two deliveries of tokens to one request, one "
+        "sample a delivery but an attempt's first — a local engine's "
+        "hand-over and a remote worker's TOKEN frame alike, as a "
+        "streaming client sees them (exemplars carry trace_ids)"
     ),
     # -- router step-loop instrumentation (RouterMetrics, fed by -------
     # -- ServingRouter.step; the measure-first half of the data-plane

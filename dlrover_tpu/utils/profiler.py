@@ -17,6 +17,8 @@ equivalents are:
 - :func:`span` — the program's own host spans (trainer step, checkpoint
   stage and commit, engine step, router step) written into that same
   trace, on the device's clock, whoever opened the profiler;
+  :func:`event` — one that says a fact and times nothing (a served
+  request's ``dlrover.request.*``, joined by ``rid`` / ``erid``);
 - :func:`device_scope` — the device-side sibling of :func:`span`: a
   ``jax.named_scope`` the process remembers, so that
   :func:`program_scopes` can say which scope each instruction of a
@@ -323,6 +325,15 @@ def span(name: str, **attrs):
     from jax.profiler import TraceAnnotation
 
     return TraceAnnotation(name, **attrs)
+
+
+def event(name: str, **attrs) -> None:
+    """A fact in the profiler's trace: a :func:`span` opened and closed
+    at once, ``attrs`` what it says (a request was placed, admitted,
+    given its first token).  The profiler cuts a string at a comma:
+    join lists with spaces."""
+    with span(name, **attrs):
+        pass
 
 
 def spanned(name: str):

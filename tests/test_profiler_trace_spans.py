@@ -514,3 +514,181 @@ def test_engine_spans_time_what_the_engine_counters_time(serve_run):
         <= chunked.prefill_chunk_seconds <= chunked.prefill_seconds
     assert spans["dlrover.engine.verify"]["seconds"] \
         == pytest.approx(speculating.decode_seconds, rel=0.02)
+
+
+# -- a request's own clock (ISSUE 52) -----------------------------------------
+
+REQUEST_EVENTS = ("placed", "admitted", "first_token", "first_delivery",
+                  "finished")
+# (prompt tokens, what it shares): more requests than the two slots, so
+# some wait in the router's queue; the last stands behind the first 32
+# tokens (two blocks) of a prompt served before it
+REQUEST_PROMPTS = [(40, None), (8, None), (24, None), (9, None), (30, None)]
+WARM_PROMPT = (48, 32)
+
+
+def _serve_requests():
+    """One tiny paged engine of two slots that prefills in chunks of 16
+    and shares prefixes, behind a router: ``REQUEST_PROMPTS`` at once,
+    then the warm one.  Returns the router's requests, the engine and
+    the engine's own requests by ``erid``."""
+    from dlrover_tpu.serving.engine import InferenceEngine
+
+    cfg = LlamaConfig.tiny(max_seq_len=96, dtype=jnp.float32)
+    variables = LlamaModel(cfg).init(jax.random.PRNGKey(0),
+                                     jnp.zeros((1, 8), jnp.int32))
+    engine = InferenceEngine(cfg, variables, max_slots=2, chunk=4,
+                             paged=True, block_size=16, prefill_chunk=16,
+                             temperature=0.0, max_len=96)
+    router = ServingRouter(
+        scheduler=ContinuousBatchScheduler(block_size=16))
+    router.join_replica("one", InferenceEngineAdapter(engine))
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(1, cfg.vocab_size, n).astype(np.int32)
+               for n, _ in REQUEST_PROMPTS]
+    reqs = [router.submit(p, 9) for p in prompts]
+    router.run_until_idle(max_steps=500)
+    size, shared = WARM_PROMPT
+    warm = np.concatenate([prompts[0][:shared], rng.randint(
+        1, cfg.vocab_size, size - shared).astype(np.int32)])
+    reqs.append(router.submit(warm, 9))
+    router.run_until_idle(max_steps=500)
+    assert all(r.state == "Done" and len(r.output) == 9 for r in reqs)
+    return reqs, engine, {r.rid: r for r in engine._finished}
+
+
+@pytest.fixture(scope="module")
+def request_run(tmp_path_factory):
+    tmp = tmp_path_factory.mktemp("requests")
+    with _traced(tmp / "trace"):
+        reqs, engine, ereqs = _serve_requests()
+    return {"parsed": _parse(tmp / "trace"), "reqs": reqs,
+            "engine": engine, "ereqs": ereqs}
+
+
+def _request_events(parsed, what, key):
+    found = {}
+    for _, _, _, a in ps.named(parsed, "dlrover.request." + what):
+        assert a[key] not in found, (what, a)      # exactly one each
+        found[a[key]] = a
+    return found
+
+
+def test_a_request_is_one_key_from_the_queue_to_its_last_chunk(request_run):
+    parsed, reqs = request_run["parsed"], request_run["reqs"]
+    placed = _request_events(parsed, "placed", "rid")
+    first_delivery = _request_events(parsed, "first_delivery", "rid")
+    admitted, first_token, finished = (
+        _request_events(parsed, what, "erid")
+        for what in ("admitted", "first_token", "finished"))
+    rids = {r.rid for r in reqs}
+    assert set(placed) == set(first_delivery) == rids
+    erids = {placed[rid]["erid"] for rid in rids}
+    assert set(admitted) == set(first_token) == set(finished) == erids \
+        == {r.engine_rid for r in reqs}
+    step_ms = max(d for _, _, d, _ in
+                  ps.named(parsed, "dlrover.router.step")) / 1e6
+    waited = 0
+    for req in reqs:
+        rid, erid = req.rid, req.engine_rid
+        assert placed[rid]["replica"] == "one"
+        assert placed[rid]["prompt_tokens"] == req.prompt.size \
+            == admitted[erid]["prompt_tokens"]
+        # the router's clock to the engine's read of the first token is
+        # the three waits; the engine takes the request a little into the
+        # router step whose start ended the first
+        parts = placed[rid]["queue_wait_ms"] \
+            + admitted[erid]["slot_wait_ms"] \
+            + first_token[erid]["prefill_ms"]
+        assert 0 <= first_delivery[rid]["ttft_ms"] - parts <= step_ms
+        assert first_token[erid]["since_queued_ms"] == pytest.approx(
+            admitted[erid]["slot_wait_ms"]
+            + first_token[erid]["prefill_ms"])
+        waited += placed[rid]["queue_wait_ms"] > step_ms
+        assert finished[erid]["tokens"] == 9
+        # the first token, then a delivery a decode chunk of four; the
+        # router hands the two chunks on together (the last chunk's
+        # dispatch took the request off its slot, and a pump streams
+        # what holds a slot: PERF.md section 7, the look-ahead (c))
+        assert finished[erid]["deliveries"] == 3 == req.deliveries + 1
+        assert 0 < finished[erid]["gap_ms_max"] \
+            <= finished[erid]["decode_ms"]
+        # its prompt's programs are found among the device's by its erid
+        ran = [a for name in ("prefill", "prefill_chunk")
+               for _, _, _, a in ps.named(parsed, "dlrover.engine." + name)
+               if str(erid) in str(a.get("erids", "")).split()]
+        assert len(ran) == admitted[erid]["chunks"] \
+            == first_token[erid]["steps"]
+    assert waited >= 2          # two slots: the others queued in the router
+
+
+def test_deliveries_in_the_trace_add_up_to_the_engines_counters(request_run):
+    """The engine was made inside the session: what its ``.deliver`` and
+    request events say is what ``EngineStats`` summed."""
+    parsed, stats = request_run["parsed"], request_run["engine"].stats
+    delivers = [a for _, _, _, a in
+                ps.named(parsed, "dlrover.engine.deliver")]
+    assert {a["program"] for a in delivers} \
+        == {"prefill_chunk", "decode_chunk"}
+    assert sum(a["gaps"] for a in delivers) == stats.token_gaps == 12
+    assert sum(a["gap_ms_sum"] for a in delivers) == pytest.approx(
+        stats.token_gap_seconds * 1e3)
+    assert max(a["gap_ms_max"] for a in delivers) <= max(
+        r.gap_max for r in request_run["ereqs"].values()) * 1e3 + 1e-6
+    assert sum(a["tokens"] for a in delivers) \
+        == stats.generated_tokens + stats.first_tokens == 6 * 9
+    firsts = _request_events(parsed, "first_token", "erid").values()
+    admitted = _request_events(parsed, "admitted", "erid").values()
+    assert len(firsts) == stats.first_tokens == 6
+    assert sum(a["prefill_ms"] for a in firsts) == pytest.approx(
+        stats.prefill_wall_seconds * 1e3)
+    assert sum(a["slot_wait_ms"] for a in admitted) == pytest.approx(
+        stats.slot_wait_seconds * 1e3)
+    assert sum(a["prompt_tokens"] for a in admitted) \
+        == stats.prompt_tokens == sum(n for n, _ in REQUEST_PROMPTS) + 48
+    # ... and they ride the replica's dict as they are
+    handed = InferenceEngineAdapter(request_run["engine"]).engine_metrics()
+    assert {n: handed[n] for n in stats.REQUEST_CLOCK} \
+        == {n: float(getattr(stats, n)) for n in stats.REQUEST_CLOCK}
+
+
+def test_a_warm_start_books_where_its_prefill_began(request_run):
+    from dlrover_tpu.serving.paged import warm_start
+
+    size, shared = WARM_PROMPT
+    began = warm_start(shared, size, 16)
+    assert began == 32
+    admitted = _request_events(request_run["parsed"], "admitted", "erid")
+    *cold, warm = request_run["reqs"]
+    ereqs = request_run["ereqs"]
+    assert ereqs[warm.engine_rid].cached_tokens == began \
+        == admitted[warm.engine_rid]["cached_tokens"]
+    # one program ran the 16 tokens behind them
+    assert ereqs[warm.engine_rid].prompt_chunks == 1 \
+        == admitted[warm.engine_rid]["chunks"]
+    for req in cold:
+        assert ereqs[req.engine_rid].cached_tokens == 0 \
+            == admitted[req.engine_rid]["cached_tokens"]
+        assert ereqs[req.engine_rid].prompt_chunks \
+            == -(-req.prompt.size // 16)
+    assert request_run["engine"].stats.prompt_tokens_cached == began
+
+
+def test_with_no_session_the_events_leave_nothing(request_run, tmp_path):
+    """The same requests with no profiler open: the engine steps to the
+    same tokens and books the same counts, and a session opened
+    afterwards holds no event of theirs."""
+    reqs, engine, ereqs = _serve_requests()
+    assert [r.output for r in reqs] \
+        == [r.output for r in request_run["reqs"]]
+    traced = request_run["engine"].stats
+    for name in ("first_tokens", "token_gaps", "prompt_tokens",
+                 "prompt_tokens_cached", "generated_tokens", "dispatches"):
+        assert getattr(engine.stats, name) == getattr(traced, name), name
+    assert all(r.first_token_at <= r.last_token_at and r.deliveries == 3
+               for r in ereqs.values())
+    with _traced(tmp_path):
+        jax.block_until_ready(jnp.zeros(8) + 1)
+    assert not any(n.startswith("dlrover.request.")
+                   or n == "dlrover.engine.deliver"
+                   for n in ps.totals(_parse(tmp_path)))

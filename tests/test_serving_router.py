@@ -720,6 +720,71 @@ def test_router_over_real_paged_engines():
         "serving_requests_completed_total"] == 6
 
 
+def test_first_token_is_stamped_at_the_engines_read_not_the_steps_start():
+    """The repair (ISSUE 52): an in-process engine's tokens are stamped
+    with the engine's own read of the program that sampled them, not
+    with the ``now`` their router step began with.  The step is made
+    slow in front of its dispatches: the old stamp lay that much before
+    any token existed."""
+    import jax
+    import jax.numpy as jnp
+
+    from dlrover_tpu.models.llama import LlamaConfig, LlamaModel
+    from dlrover_tpu.serving.engine import InferenceEngine
+    from dlrover_tpu.serving.router import InferenceEngineAdapter
+
+    cfg = LlamaConfig.tiny(max_seq_len=64, dtype=jnp.float32)
+    variables = LlamaModel(cfg).init(jax.random.PRNGKey(0),
+                                     jnp.zeros((1, 8), jnp.int32))
+    eng = InferenceEngine(cfg, variables, max_slots=2, chunk=4,
+                          paged=True, block_size=16)
+    slow, admit = 0.05, eng._dispatch_admissions
+
+    def slow_admissions():
+        time.sleep(slow)
+        admit()
+
+    eng._dispatch_admissions = slow_admissions
+    router = ServingRouter(
+        scheduler=ContinuousBatchScheduler(block_size=16))
+    router.join_replica("slow", InferenceEngineAdapter(eng))
+    rng = np.random.RandomState(0)
+    reqs = [router.submit(rng.randint(1, cfg.vocab_size, 8)
+                          .astype(np.int32), n) for n in (10, 1)]
+    began = []
+    while router.has_work:
+        began.append(time.monotonic())
+        router.step(now=began[-1])
+        assert len(began) < 50
+    ereqs = {r.rid: r for r in eng._finished}
+    for req in reqs:
+        ereq = ereqs[req.engine_rid]
+        assert req.state == ServingRequestState.DONE
+        # the engine's stamps, as they are: both requests were placed
+        # by the first step, whose start is ``slow`` before any read
+        assert req.first_token_at == ereq.first_token_at \
+            >= began[0] + slow
+        assert req.finished_at == ereq.last_token_at
+        assert req.submitted_at < ereq.queued_at <= ereq.admitted_at \
+            < ereq.first_token_at <= ereq.last_token_at
+    streamed, one_token = reqs
+    # a budget of one token ends at its first: never on a slot between
+    # two steps, so its one delivery is the flush at its finish
+    assert (one_token.deliveries, ereqs[one_token.engine_rid].deliveries) \
+        == (1, 1)
+    assert one_token.finished_at == one_token.first_token_at
+    # the histogram takes one sample a delivery but a request's first,
+    # and each gap holds at least one slow step
+    gaps = router.metrics.token_gap_hist.snapshot()
+    assert gaps["count"] == sum(r.deliveries - 1 for r in reqs) \
+        == streamed.deliveries - 1 >= 1
+    assert gaps["sum"] >= slow * gaps["count"]
+    text = router.metrics.render_histograms()
+    assert "# TYPE serving_token_gap_seconds histogram" in text
+    assert "serving_decode_step_seconds" not in text
+    assert streamed.trace.trace_id in text
+
+
 # -- ISSUE 7: DL009 terminal-state guards + out-of-lock placement ----------
 
 

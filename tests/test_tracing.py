@@ -513,10 +513,13 @@ def test_histograms_on_metrics_scrape_resolve_to_traces():
             timeout=5).read().decode()
         for family in ("serving_ttft_hist_seconds",
                        "serving_queue_wait_seconds",
-                       "serving_e2e_latency_seconds",
-                       "serving_decode_step_seconds"):
+                       "serving_e2e_latency_seconds"):
             assert f"# TYPE {family} histogram" in body, family
             assert f"{family}_count 3" in body, family
+        # this engine streams nothing in process: each answer is one
+        # delivery, and a gap needs two
+        assert "# TYPE serving_token_gap_seconds histogram" in body
+        assert "serving_token_gap_seconds_count 0" in body
         exemplar_ids = set(re.findall(r'# \{trace_id="([0-9a-f]{32})"\}',
                                       body))
         assert exemplar_ids
