@@ -24,38 +24,55 @@ engine selects this kernel through ``attention_impl`` —
   ``interpret=True`` mode on CPU in tier-1, so a numerics regression
   cannot hide behind missing hardware).
 
-Design — the two fixes the old STATUS header prescribed, plus the new
-leverage:
+Design:
 
-1. **Multi-page compute blocks with double-buffered manual DMA.**  The
-   old kernel's grid was ``(B, MB)`` — one 16-row page per grid step,
-   so per-grid-step latency dominated (472 us vs the gather's 86 us)
-   and the per-kv-head dots under-filled the MXU.  Now the grid is
-   ``(B,)`` and each program streams its slot's pages in GROUPS of
-   ``pages_per_block`` (default 8 -> 128 key rows per compute block at
-   the engine's 16-row pages): the pools stay in HBM
-   (``memory_space=ANY``) and the kernel issues per-page async copies
-   into a 2-slot VMEM scratch, starting group ``g+1``'s DMAs before
-   computing group ``g`` — the double-buffer pattern, with the page
-   list coming from the scalar-prefetched block table.
-2. **Dequantization folded inside.**  Quantized codes are widened in
-   VMEM right after the copy lands (int4 codes unpack split-half: byte
-   ``j`` holds code ``j`` low-nibble and ``j + D/2`` high-nibble, so
-   unpack is a concatenate, not an interleave).  HBM traffic for the
-   codes is code-width; the XLA gather path cannot avoid materializing
-   the dequantized rows.  The per-(token, head) SCALES do not stream in
-   place: their pool's minor dimension is the KV-head count, which the
-   TPU pads to 128 lanes in HBM and refuses as a DMA slice, so the
-   wrapper gathers this batch's scales (2/D of the code bytes) into a
-   lane-dense ``[B, groups, KV, rows]`` view, and the kernel applies
-   them to the ``[G, rows]`` score and probability tiles — a scale is
-   constant along the head dim, so it factors out of both dots.
+1. **One stream of page groups, continuous across slots.**  The pools
+   stay in HBM (``memory_space=ANY``); the grid is ``(B,)``, one slot a
+   program, run in order, and a program streams its slot's pages in
+   GROUPS by per-page async copies into a 2-buffer VMEM scratch, the
+   page list coming from the scalar-prefetched block table.  While a
+   group is multiplied the next one is already in flight into the other
+   buffer: the slot's own next group, or, behind the slot's LAST live
+   group, the FIRST group of the next slot that holds a key.  The
+   buffers, their semaphores and one SMEM word (which buffer a slot
+   starts in) carry those copies from one program to the next, so the
+   DMA queue never drains at a slot's edge: only the call's first group
+   is waited for with nothing to compute.  A slot of length 0 (idle, or
+   parked while its prompt prefills) starts nothing, waits for nothing
+   and is stepped over; the call's last live group starts nothing
+   behind itself.  A DMA that starts is waited, on every path.
+2. **Groups are sized in ROWS.**  Where the caller names no
+   ``pages_per_block`` a group is ``GROUP_ROWS`` (256) key rows
+   whatever a page is: 2 pages of 128 rows, 16 of 16 (one rule, a
+   function of the page size alone; a narrower table is one group).
+   Contexts of 0.2-5 k are a few groups each, so the dead tail of a
+   slot's last group is an eighth of what it reads and not a half.
+3. **Tiles go to the MXU as stored.**  A group lands as ``[rows x KV,
+   D]``: a row's KV heads side by side.  ONE dot of all H query heads
+   against it gives ``[H, rows x KV]`` scores, of which a head owns the
+   columns of its own KV head; the others are masked with the rows
+   behind the length.  No tile is sliced, relaid or copied per head,
+   and the weight tiles the MXU loads are the ones the per-head dots
+   would load.  For a float pool the K and V tiles and the query are
+   the dots' operands in the POOL's dtype with a float32 result; ``p``
+   is rounded to V's dtype once before ``p x V``, as ``mla_decode.py``
+   and the flash kernels do.  Scores, the running maximum and sum and
+   the accumulator are float32 values carried through the group loop.
+4. **Dequantization folded inside.**  Quantized codes are widened to
+   float32 in VMEM right after the copy lands (int4 codes unpack
+   split-half: byte ``j`` holds code ``j`` low-nibble and ``j + D/2``
+   high-nibble, so unpack is a concatenate, not an interleave), and
+   their dots are float32.  HBM traffic for the codes is code-width;
+   the XLA gather path cannot avoid materializing the dequantized rows.
+   The per-(token, head) SCALES do not stream in place: their pool's
+   minor dimension is the KV-head count, which the TPU pads to 128
+   lanes in HBM and refuses as a DMA slice, so the wrapper gathers this
+   batch's scales (2/D of the code bytes) into a lane-dense view in the
+   order of a group's score columns, and the kernel applies them to the
+   score and probability tiles — a scale is constant along the head
+   dim, so it factors out of both dots.
    **Packed int4 pools do not compile on a TPU** (``INT4_REFUSAL``):
    that variant runs in interpret mode only, as the parity harness.
-3. Online softmax (flash-style m/l/acc carry in VMEM scratch) over
-   ``[KV*G, pages*bs]`` score tiles per group; GQA queries regroup to
-   ``[KV, G, D]`` and each kv head's scores come from one dot against
-   its slice of the group.
 
 Scope: single-query decode (the serving engine's K=1 step — its hot
 path; speculative verify and prefill keep the gather path).
@@ -72,19 +89,25 @@ Layout contract (matches serving/paged.py):
 Returns [B, H, D] fp32.
 
 Only a slot's LIVE pages stream: each program's group loop runs
-``min(ceil(length / rows), groups)`` times (``rows`` = one group's
-``pages_per_block * bs`` key rows), a trip count read from ``lengths``
-at run time, so the kernel's traffic follows the context a slot holds
-and not the width its table was sized for.  Length 0 reads nothing
-and returns zeros — the engine passes it for a slot that is idle or
-parked while its prompt prefills, whose logits nobody reads.  The LAST
-live group streams whole: its rows past the length are masked to -inf,
-and its pages past the allocation are table zeros, the trash block,
-whose junk the mask discards (the table is padded with them to a
-multiple of ``pages_per_block``).  A length past the table's capacity
-reads the whole table and no further.  For every length >= 1 the
-output is bit-identical to running every group: a wholly dead group
-only ever multiplied the carry by ``alpha = 1`` and added ``p = 0``.
+``min(ceil(length / rows), groups)`` times (``rows`` = one group's key
+rows), a trip count read from ``lengths`` at run time
+(:func:`streamed_rows` is the same arithmetic on the host), so the
+kernel's traffic follows the context a slot holds and not the width its
+table was sized for.  Length 0 reads nothing and returns zeros — the
+engine passes it for a slot that is idle or parked while its prompt
+prefills, whose logits nobody reads.  The LAST live group streams
+whole: its rows past the length are masked to -inf, and its pages past
+the allocation are table zeros, the trash block, whose junk the mask
+discards (the table is padded with them to whole groups).  A length
+past the table's capacity reads the whole table and no further.  For
+every length >= 1 the output is bit-identical to running every group: a
+wholly dead group only ever multiplied the carry by ``alpha = 1`` and
+added ``p = 0``.
+
+On a v5e (PR 53, the kernel alone, bf16 pools): 128 slots of 0.5-5 k
+rows in 128-row pages 1.00 ms a call where the float32 per-head dots of
+8-page groups took 2.69 (83 % of the HBM peak for the live bytes); 8
+slots of ~0.2 k rows in 16-row pages 16 us where they took 34.
 """
 
 from __future__ import annotations
@@ -100,6 +123,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+_LANES = 128
+
+#: key rows a compute group holds where the caller names no
+#: ``pages_per_block``: 2 pages of 128 rows, 16 of 16 (measured on a
+#: v5e at both page sizes, PR 53: 512 rows read 9 % slower at 128 slots
+#: of 0.5-5 k and 1.7-1.8 x slower at 8 slots of ~0.2 k, a longer dead
+#: tail and a short slot's whole stream in one group; 128 rows read
+#: 1 % and 6-11 % faster: PERF.md section 7 (d))
+GROUP_ROWS = 256
 
 #: Why the packed-int4 variant is refused on a TPU (compiled for a
 #: described v5e, PR 21).  The pool's minor dimension is D//2 = 64, and
@@ -126,135 +158,141 @@ def _unpack4_f32(x: jax.Array) -> jax.Array:
 def _decode_kernel(
     table_ref, lengths_ref,          # scalar-prefetched (SMEM)
     *args,
-    block_size: int, pages: int, num_groups: int,
+    block_size: int, pages: int, num_groups: int, capacity: int,
     kv_heads: int, group: int, head_dim: int,
-    quant: bool, packed: bool, scale: Optional[float] = None,
+    quant: bool, packed: bool, scale: float,
 ):
     if quant:
         (q_ref, k_hbm, v_hbm, ks_ref, vs_ref, o_ref,
-         kb, vb, m_scr, l_scr, acc_scr, sem) = args
+         kb, vb, sem, buf_ref) = args
     else:
-        (q_ref, k_hbm, v_hbm, o_ref,
-         kb, vb, m_scr, l_scr, acc_scr, sem) = args
+        q_ref, k_hbm, v_hbm, o_ref, kb, vb, sem, buf_ref = args
         ks_ref = vs_ref = None
 
     b = pl.program_id(0)
-    bs, p_n = block_size, pages
-    rows = p_n * bs                   # key rows per compute group
+    slots = pl.num_programs(0)
+    rows = pages * block_size         # key rows per compute group
+    heads = kv_heads * group
+    cols = rows * kv_heads            # a group's (row, KV head) pairs
 
-    def _group_copies(g, slot):
-        """The per-page DMA descriptors for group ``g`` into buffer
-        ``slot`` — built identically at start() and wait() time (the
-        canonical Pallas double-buffer idiom)."""
-        copies = []
-        for j in range(p_n):          # static unroll: p_n DMAs in flight
-            page = table_ref[b, g * p_n + j]
-            copies.append(pltpu.make_async_copy(
-                k_hbm.at[page], kb.at[slot, j], sem.at[slot, j, 0]))
-            copies.append(pltpu.make_async_copy(
-                v_hbm.at[page], vb.at[slot, j], sem.at[slot, j, 1]))
-        return copies
+    def start_group(slot, g, buf):
+        """Slot ``slot``'s group ``g`` into buffer ``buf``: one copy a
+        page, K and V (static unroll: all of them in flight)."""
+        for j in range(pages):
+            page = table_ref[slot, g * pages + j]
+            pltpu.make_async_copy(
+                k_hbm.at[page], kb.at[buf, j], sem.at[buf, j, 0]).start()
+            pltpu.make_async_copy(
+                v_hbm.at[page], vb.at[buf, j], sem.at[buf, j, 1]).start()
 
-    def start_group(g, slot):
-        for c in _group_copies(g, slot):
-            c.start()
+    def wait_group(pool_hbm, bufs, buf, which):
+        # a wait reads its semaphore and the destination's size: the
+        # page a copy came from is not its business
+        for j in range(pages):
+            pltpu.make_async_copy(
+                pool_hbm.at[0], bufs.at[buf, j], sem.at[buf, j, which]).wait()
 
-    def wait_group(g, slot):
-        for c in _group_copies(g, slot):
-            c.wait()
+    def next_live(after):
+        """The first slot behind ``after`` that holds a key, ``slots``
+        where none does: a slot of length 0 is stepped over."""
+        return jax.lax.while_loop(
+            lambda i: jnp.logical_and(
+                i < slots, lengths_ref[jnp.minimum(i, slots - 1)] <= 0),
+            lambda i: i + 1, after + 1)
+
+    # the call's first copies; every later slot finds its first group in
+    # flight, started under the last group of the live slot before it
+    @pl.when(b == 0)
+    def _():
+        buf_ref[0] = 0
+        first = next_live(-1)
+
+        @pl.when(first < slots)       # a DMA that starts is waited:
+        def _():                      # a call of empty slots starts none
+            start_group(first, 0, 0)
 
     # the slot's LIVE groups: the loop below ends at the slot's length,
     # not at the table's width, so a wholly dead group is never copied
-    # (a skipped group contributed alpha = 1, p = 0: nothing changes)
-    n_live = jnp.minimum(pl.cdiv(lengths_ref[b], rows), num_groups)
+    length = jnp.minimum(lengths_ref[b], capacity)
+    n_live = jnp.minimum(pl.cdiv(length, rows), num_groups)
+    behind = next_live(b)
+    buf0 = buf_ref[0]                 # where this slot's first group is
 
-    m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
+    # dots take the tiles as the pool stores them (float32 accumulator);
+    # quantized codes are widened in VMEM, after the copy, so HBM only
+    # ever saw code-width bytes
+    operand = jnp.float32 if quant else kb.dtype
+    q = q_ref[0].reshape(heads, head_dim).astype(operand)
 
-    @pl.when(n_live > 0)              # a DMA that starts is waited:
-    def _():                          # length 0 starts none
-        start_group(0, 0)             # warm-up: first group in flight
+    def tile(bufs, buf):
+        raw = bufs[buf]               # [P, bs, KV, Dc]
+        if packed:
+            raw = _unpack4_f32(raw)
+        return raw.reshape(cols, head_dim).astype(operand)
 
-    qf = q_ref[0].astype(jnp.float32)            # [KV, G, D]
+    def scaled(x, s_ref, g):
+        """``x`` [heads, cols] times the group's per-(row, KV head)
+        scales, which lie 128 columns a sublane row (the wrapper's
+        layout): a scale is constant along the head dim, so it factors
+        out of both dots onto the score / probability tile."""
+        if not quant:
+            return x
+        s = s_ref[0, g]               # [ceil(cols / 128), 128]
+        return jnp.concatenate(
+            [x[:, t:t + _LANES] * s[t // _LANES:t // _LANES + 1,
+                                    :min(_LANES, cols - t)]
+             for t in range(0, cols, _LANES)], axis=1)
 
-    def _codes(raw):
-        # raw [P, bs, KV, Dc] -> f32 [P, bs, KV, D]; the whole point:
-        # this runs on VMEM-resident codes AFTER the copy, so HBM only
-        # ever saw code-width bytes.  The per-(token, head) scales are
-        # NOT applied here: a scale is constant along the head dim, so
-        # it factors out of both dots and multiplies the [G, rows]
-        # score / probability tiles instead (rows on lanes — see the
-        # wrapper's scale layout)
-        return _unpack4_f32(raw) if packed else raw.astype(jnp.float32)
+    # A group's tile is [rows x KV, D] as it lands: row r's KV heads lie
+    # side by side.  One dot of all the heads against it gives
+    # [heads, rows x KV] scores of which a head owns the columns of ITS
+    # KV head; the others are masked like rows behind the length, so no
+    # tile is sliced or relaid per head.  ``key_row``: the key's row in
+    # the group where the column is the head's own, else behind any
+    # length.
+    col = jax.lax.broadcasted_iota(jnp.int32, (heads, cols), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (heads, cols), 0)
+    key_row = jnp.where(col % kv_heads == head // group,
+                        col // kv_heads, capacity)
 
-    def body(g, _):
-        slot = jax.lax.rem(g, 2)
+    def body(g, carry):
+        m, l, acc = carry
+        buf = jax.lax.rem(buf0 + g, 2)
+        last = g + 1 == n_live
+        # the queue never drains: this slot's next group, or behind its
+        # last group the next live slot's first, goes out before this
+        # group is waited for (the call's last group starts nothing)
+        nxt_slot = jnp.where(last, jnp.minimum(behind, slots - 1), b)
 
-        @pl.when(g + 1 < n_live)
-        def _():                      # overlap: next group's DMA first
-            start_group(g + 1, jax.lax.rem(g + 1, 2))
+        @pl.when(jnp.logical_or(jnp.logical_not(last), behind < slots))
+        def _():
+            start_group(nxt_slot, jnp.where(last, 0, g + 1), 1 - buf)
 
-        wait_group(g, slot)
-        kf = _codes(kb[slot]).reshape(rows, kv_heads, head_dim)
-        vf = _codes(vb[slot]).reshape(rows, kv_heads, head_dim)
-        if quant:
-            ks = ks_ref[0, g]         # [KV, rows] f32, this group's
-            vs = vs_ref[0, g]
+        wait_group(k_hbm, kb, buf, 0)
+        s = jax.lax.dot_general(q, tile(kb, buf), (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = scaled(s, ks_ref, g) * scale
+        # a live group's first row is visible to every head, so the
+        # running maximum is finite from the first group on and a masked
+        # column's exp is exactly 0
+        s = jnp.where(key_row < length - g * rows, s, _NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l = alpha * l + p.sum(axis=-1, keepdims=True)
+        wait_group(v_hbm, vb, buf, 1)
+        # p is rounded to V's dtype once, as the flash kernels do
+        pv = jax.lax.dot_general(
+            scaled(p, vs_ref, g).astype(operand), tile(vb, buf),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        return m_new, l, acc * alpha + pv
 
-        def k_scaled(x, kvi):         # [G, rows] * [1, rows]
-            return x * ks[kvi:kvi + 1, :] if quant else x
-
-        def v_scaled(x, kvi):
-            return x * vs[kvi:kvi + 1, :] if quant else x
-
-        # per-kv-head scores: [KV*G, rows] via KV dots (static loop) —
-        # at rows = pages*bs the dot's N dim is 128+ and fills the MXU
-        scores = jnp.concatenate(
-            [
-                k_scaled(jax.lax.dot_general(
-                    qf[kvi], kf[:, kvi], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ), kvi)
-                for kvi in range(kv_heads)
-            ],
-            axis=0,
-        )
-        # the softmax scale a model states for itself, else the head's
-        scores = scores / (head_dim ** 0.5) if scale is None \
-            else scores * scale
-        key_pos = g * rows + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 1)
-        visible = key_pos < lengths_ref[b]
-        scores = jnp.where(visible, scores, _NEG_INF)
-
-        m_prev = m_scr[...]                      # [KV*G]
-        m_new = jnp.maximum(m_prev, scores.max(axis=-1))
-        # guard the all-masked group: exp(-inf - -inf) must not NaN
-        alpha = jnp.where(m_new == _NEG_INF, 0.0,
-                          jnp.exp(m_prev - m_new))
-        p = jnp.exp(scores - m_new[:, None])
-        p = jnp.where(visible, p, 0.0)
-        l_scr[...] = alpha * l_scr[...] + p.sum(axis=-1)
-        pv = jnp.concatenate(
-            [
-                jax.lax.dot_general(
-                    v_scaled(p[kvi * group:(kvi + 1) * group], kvi),
-                    vf[:, kvi],
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                for kvi in range(kv_heads)
-            ],
-            axis=0,
-        )
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
-        m_scr[...] = m_new
-        return 0
-
-    jax.lax.fori_loop(0, n_live, body, 0)
-    denom = jnp.maximum(l_scr[...], 1e-30)
-    o_ref[0] = (acc_scr[...] / denom[:, None]).reshape(
+    _, l, acc = jax.lax.fori_loop(0, n_live, body, (
+        jnp.full((heads, 1), _NEG_INF, jnp.float32),
+        jnp.zeros((heads, 1), jnp.float32),
+        jnp.zeros((heads, head_dim), jnp.float32)))
+    buf_ref[0] = jax.lax.rem(buf0 + n_live, 2)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).reshape(
         kv_heads, group, head_dim).astype(o_ref.dtype)
 
 
@@ -266,13 +304,22 @@ def _page_groups(table_width: int, pages_per_block: int):
     return p_n, -(-table_width // p_n)
 
 
+def _group_pages(block_size: int, pages_per_block: Optional[int]) -> int:
+    """Pages a group of THIS kernel holds where the caller names none:
+    ``GROUP_ROWS`` key rows, whatever a page is (at least one page)."""
+    if pages_per_block is not None:
+        return pages_per_block
+    return max(1, GROUP_ROWS // block_size)
+
+
 def streamed_rows(lengths, block_size: int, table_width: int,
-                  pages_per_block: int = 8) -> int:
+                  pages_per_block: Optional[int] = None) -> int:
     """Key rows the kernel copies (K and V each) for slots of these
     ``lengths``: whole groups up to each length, none for length 0,
     never more than the table — the kernel's trip count as host
     arithmetic, for a caller that books what it streams."""
-    p_n, num_groups = _page_groups(table_width, pages_per_block)
+    p_n, num_groups = _page_groups(
+        table_width, _group_pages(block_size, pages_per_block))
     rows = p_n * block_size
     groups = np.clip(-(-np.asarray(lengths) // rows), 0, num_groups)
     return int(groups.sum()) * rows
@@ -289,7 +336,7 @@ def paged_decode_attention(
     *,
     k_scale: Optional[jax.Array] = None,   # [NB, bs, KV] (quant pools)
     v_scale: Optional[jax.Array] = None,
-    pages_per_block: int = 8,
+    pages_per_block: Optional[int] = None,  # None: GROUP_ROWS of rows
     interpret: bool = False,
     scale: Optional[float] = None,     # None: head_dim ** -0.5
 ) -> jax.Array:
@@ -307,8 +354,8 @@ def paged_decode_attention(
     g = h // kv
     mb = table.shape[1]
     # pad the table to a multiple of the page-group size with zeros —
-    # the trash block, whose junk the length mask discards
-    p_n, num_groups = _page_groups(mb, pages_per_block)
+    # the trash block, which no length reaches
+    p_n, num_groups = _page_groups(mb, _group_pages(bs, pages_per_block))
     pad = num_groups * p_n - mb
     if pad:
         table = jnp.concatenate(
@@ -319,39 +366,35 @@ def paged_decode_attention(
         return (bi, 0, 0, 0)
 
     kernel = functools.partial(
-        _decode_kernel, block_size=bs, pages=p_n,
-        num_groups=num_groups, kv_heads=kv, group=g, head_dim=d,
-        quant=quant, packed=packed, scale=scale,
+        _decode_kernel, block_size=bs, pages=p_n, num_groups=num_groups,
+        capacity=mb * bs, kv_heads=kv, group=g, head_dim=d,
+        quant=quant, packed=packed,
+        scale=float(d ** -0.5 if scale is None else scale),
     )
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [pl.BlockSpec((1, kv, g, d), q_map), any_spec, any_spec]
     operands = [qg, k_pool, v_pool]
-    scratch = [
-        pltpu.VMEM((2, p_n, bs, kv, dc), k_pool.dtype),
-        pltpu.VMEM((2, p_n, bs, kv, dc), v_pool.dtype),
-    ]
     if quant:
         # The scale pools' minor dimension is the KV-head count, which
         # no TPU tiling accepts as a DMA slice (and which HBM pads to
         # 128 lanes), so the kernel never touches them in place.  The
         # wrapper gathers this batch's scales — 2/D of the code bytes —
-        # into a lane-dense [B, groups, KV, rows] f32 view whose rows
-        # line up with the score tile's key axis; the codes, which are
-        # the traffic that matters, still stream in place.
-        def rows_on_lanes(scale_pool):
-            s = jnp.take(scale_pool, table, axis=0)   # [B, MBp, bs, KV]
-            s = s.reshape(b, num_groups, p_n * bs, kv)
-            return s.transpose(0, 1, 3, 2).astype(jnp.float32)
+        # into a lane-dense f32 view: a group's scales in the order of
+        # its score tile's columns (row, then KV head), 128 a sublane
+        # row; the codes, which are the traffic that matters, still
+        # stream in place.
+        cols = p_n * bs * kv
+        tiles = -(-cols // _LANES)
 
-        s_spec = pl.BlockSpec((1, num_groups, kv, p_n * bs), q_map)
+        def on_lanes(scale_pool):
+            s = jnp.take(scale_pool, table, axis=0)   # [B, MBp, bs, KV]
+            s = s.reshape(b, num_groups, cols).astype(jnp.float32)
+            s = jnp.pad(s, ((0, 0), (0, 0), (0, tiles * _LANES - cols)))
+            return s.reshape(b, num_groups, tiles, _LANES)
+
+        s_spec = pl.BlockSpec((1, num_groups, tiles, _LANES), q_map)
         in_specs += [s_spec, s_spec]
-        operands += [rows_on_lanes(k_scale), rows_on_lanes(v_scale)]
-    scratch += [
-        pltpu.VMEM((kv * g,), jnp.float32),
-        pltpu.VMEM((kv * g,), jnp.float32),
-        pltpu.VMEM((kv * g, d), jnp.float32),
-        pltpu.SemaphoreType.DMA((2, p_n, 2)),
-    ]
+        operands += [on_lanes(k_scale), on_lanes(v_scale)]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -359,9 +402,18 @@ def paged_decode_attention(
             grid=(b,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, kv, g, d), q_map),
-            scratch_shapes=scratch,
+            scratch_shapes=[
+                pltpu.VMEM((2, p_n, bs, kv, dc), k_pool.dtype),
+                pltpu.VMEM((2, p_n, bs, kv, dc), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, p_n, 2)),
+                pltpu.SMEM((1,), jnp.int32),   # the buffer a slot starts in
+            ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, d), jnp.float32),
+        # one slot after another: a slot's first copies are started by
+        # the slot before it, and the buffers carry them across
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         # the name of the kernel's instruction in a device trace
         # (``paged_decode_attention.<n>``), whatever wraps the call

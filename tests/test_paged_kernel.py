@@ -48,7 +48,9 @@ from dlrover_tpu.models.quantize import (
     quantize_kv_int8,
     unpack_int4,
 )
+from dlrover_tpu.ops.pallas import mla_decode, paged_index
 from dlrover_tpu.ops.pallas.paged_attention import (
+    GROUP_ROWS,
     INT4_REFUSAL,
     gather_reference,
     kernel_parity,
@@ -100,12 +102,12 @@ def _pool_setup(B=3, H=8, KV=2, D=32, bs=8, MB=5, seed=0):
 
 
 def test_kernel_parity_bf16_pools():
-    """Multi-page double-buffered groups (MB=5 does NOT divide the
-    8-page default group — the trash-padded tail must mask clean)
-    against the gather reference, odd lengths included."""
+    """Multi-page double-buffered groups (MB=5 does NOT divide an
+    8-page group — the trash-padded tail must mask clean) against the
+    gather reference, odd lengths included."""
     q, kf, vf, table, lengths = _pool_setup()
     out = paged_decode_attention(q, kf, vf, table, lengths,
-                                 interpret=True)
+                                 pages_per_block=8, interpret=True)
     ref = gather_reference(q, kf, vf, table, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=3e-5)
@@ -255,10 +257,150 @@ def test_streamed_rows_is_the_kernels_trip_count():
         want = sum(min(-(-n // _ROWS), groups) * _ROWS for n in lengths)
         assert streamed_rows(lengths, _BS, mb, _PAGES) == want
     assert streamed_rows([0, 0], _BS, 6, _PAGES) == 0
-    # the wrapper's default group: 8 pages, or the whole of a narrower
-    # table
-    assert streamed_rows([1, 129], 16, 145) == 128 + 256
+    # the wrapper's default group: GROUP_ROWS key rows whatever a page
+    # is (16 pages of 16, 2 of 128), or the whole of a narrower table
+    assert GROUP_ROWS == 256
+    for bs, mb in ((16, 145), (128, 41)):
+        assert streamed_rows([1, 256, 257, 0], bs, mb) == 256 + 256 + 512
+        assert streamed_rows([bs * mb + 9], bs, mb) \
+            == -(-bs * mb // 256) * 256
     assert streamed_rows([1, 200], 16, 5) == 80 + 80
+    assert streamed_rows([1, 300], 512, 4) == 512 + 512
+
+
+def test_sibling_kernels_book_what_they_booked():
+    """``mla_decode.streamed_rows`` and ``paged_index.scanned_rows`` take
+    ``_page_groups`` from the GQA kernel's file with their OWN pages a
+    group: the GQA kernel's rule did not move them (figures of the tree
+    before it changed, PR 53)."""
+    lengths = [0, 1, 1024, 1025, 5000, 40000]
+    assert [mla_decode.streamed_rows(lengths, bs, mb)
+            for bs, mb in ((128, 259), (16, 145), (128, 5))] \
+        == [43008, 7168, 3200]
+    assert [paged_index.scanned_rows(lengths, bs, mb)
+            for bs, mb in ((128, 259), (16, 145), (128, 5))] \
+        == [41472, 6912, 4608]
+
+
+# -- the slot's edge, at both cells' geometries cut small ---------------------
+
+# 32 query heads over 8 KV heads of 128 and tables of 640 rows: 16-row
+# pages (mistral-7b-serve: the head's own scale) and 128-row pages
+# (granite-4.0-h-small-serve: the scale its config states), under the
+# wrapper's own group rule (256 rows: 3 groups a table, the last one
+# padded with the trash block)
+_CELLS = {"pages16": dict(bs=16, mb=40, scale=None),
+          "pages128": dict(bs=128, mb=5, scale=0.0078125)}
+_EDGES = {
+    "zero_between_two_live": [300, 0, 0, 77],
+    "zero_first_and_last": [0, 257, 640, 0],
+    "every_slot_zero": [0, 0, 0, 0],
+    "one_group_slots_only": [1, 256, 100, 255],
+    "a_slot_fills_its_table": [640, 5, 640, 641],
+    "one_past_a_groups_end": [257, 513, 1, 512],
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _cell_pools(cell: str, pool: str):
+    """Queries, (quantized) pools and a shuffled table at a cell's
+    geometry cut small, and the blocks by slot."""
+    geo = _CELLS[cell]
+    q, kf, vf, table, _ = _pool_setup(B=4, H=32, KV=8, D=128, bs=geo["bs"],
+                                      MB=geo["mb"], seed=7)
+    rng = np.random.RandomState(11)
+    table = jnp.asarray(rng.permutation(4 * geo["mb"]).astype(np.int32)
+                        .reshape(4, geo["mb"]) + 1)
+    k, v, ks, vs = kf, vf, None, None
+    if _QUANTIZERS[pool] is not None:
+        k, ks = _QUANTIZERS[pool](kf)
+        v, vs = _QUANTIZERS[pool](vf)
+    return q, k, v, ks, vs, table
+
+
+@pytest.mark.parametrize("edge", sorted(_EDGES))
+@pytest.mark.parametrize("pool", sorted(_QUANTIZERS))
+@pytest.mark.parametrize("cell", sorted(_CELLS))
+def test_stream_crosses_the_slots_edge(cell, pool, edge):
+    """The kernel's stream runs on from one slot into the next (a
+    slot's first group is started under the last group of the live slot
+    before it; a slot of length 0 is stepped over and starts nothing):
+    whatever lies between, before or behind the live slots, each slot's
+    output is the gather's for its length and zeros for length 0, and
+    no block outside a live group is copied (they hold NaN here, and a
+    copied NaN reaches the output through ``0 x NaN``)."""
+    geo = _CELLS[cell]
+    bs, mb = geo["bs"], geo["mb"]
+    q, k, v, ks, vs, table = _cell_pools(cell, pool)
+    lengths = np.array(_EDGES[edge], np.int32)
+    pages = GROUP_ROWS // bs
+    live = np.minimum(-(-lengths // GROUP_ROWS), -(-mb // pages)) * pages
+    read = {0} if (live > mb).any() else set()      # the padding's block
+    for b, n in enumerate(live):
+        read |= set(np.asarray(table)[b, :n].tolist())
+    unread = np.array(sorted(set(range(k.shape[0])) - read))
+
+    def poisoned(x):
+        if x is None:
+            return None
+        if jnp.issubdtype(x.dtype, jnp.floating):
+            return x.at[unread].set(jnp.nan)
+        return x.at[unread].set(127)     # codes have no NaN: scales do
+
+    out = np.asarray(paged_decode_attention(
+        q, poisoned(k), poisoned(v), table, jnp.asarray(lengths),
+        k_scale=poisoned(ks), v_scale=poisoned(vs), scale=geo["scale"],
+        interpret=True))
+    want = q if geo["scale"] is None else q * (geo["scale"] * 128 ** 0.5)
+    ref = np.asarray(gather_reference(
+        want, k, v, table, jnp.asarray(lengths), ks, vs))
+    assert np.isfinite(out).all()
+    for b, n in enumerate(lengths):
+        if n == 0:
+            np.testing.assert_array_equal(out[b], 0.0)
+        else:
+            np.testing.assert_allclose(out[b], ref[b], atol=3e-5)
+
+
+def _dots(jaxpr):
+    """Every ``dot_general`` under ``jaxpr``, kernels' bodies included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _dots(sub)
+
+
+@pytest.mark.parametrize("cell", sorted(_CELLS))
+def test_bf16_tiles_go_to_the_dots_as_stored(cell):
+    """A bf16 pool's K and V tiles and the query reach both dots in
+    bf16 with a float32 result (no float32 copy of a tile is made; p is
+    rounded to V's dtype once, which is what the wider bound here
+    allows: 2^-9 of a probability), across a slot's edge."""
+    geo = _CELLS[cell]
+    q, k, v, _, _, table = _cell_pools(cell, "bf16")
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    lengths = jnp.asarray(_EDGES["zero_between_two_live"], jnp.int32)
+
+    def run(q, k, v):
+        return paged_decode_attention(q, k, v, table, lengths,
+                                      scale=geo["scale"], interpret=True)
+
+    dots = list(_dots(jax.make_jaxpr(run)(q, k, v).jaxpr))
+    assert len(dots) == 2
+    for eqn in dots:
+        assert [x.aval.dtype for x in eqn.invars] == [jnp.bfloat16] * 2
+        assert eqn.outvars[0].aval.dtype == jnp.float32
+    want = q.astype(jnp.float32)
+    if geo["scale"] is not None:
+        want = want * (geo["scale"] * 128 ** 0.5)
+    ref = gather_reference(want, k, v, table, lengths)
+    np.testing.assert_allclose(
+        np.asarray(run(q, k, v))[[0, 3]], np.asarray(ref)[[0, 3]],
+        atol=1e-3)
 
 
 def test_engine_parked_and_idle_slots_read_nothing(setup):

@@ -781,10 +781,12 @@ def test_a_fault_in_one_timed_program_shows_there(program, cfg, params,
 
 
 # the dense decoder's serving programs, as the parent of PR 34 traced them
-# (bf16 tiny preset, paged pools): (lines, sha256 of the jaxpr's text)
+# (bf16 tiny preset, paged pools): (lines, sha256 of the jaxpr's text);
+# ``decode_step`` holds the paged GQA decode kernel's body and was pinned
+# again when PR 53 changed that kernel (1669 lines before it)
 _DENSE = {
-    "decode_step": (1669, "b20c0b127f8b214e76bfa62980fbf4337e0867d3dd36102b"
-                          "47be7a48c9030341"),
+    "decode_step": (1747, "7c60d0fb4c3fb61acdc0de9de26b136c73d5acc94678f345"
+                          "92b10925d84cb4af"),
     "prefill": (619, "cd182c54b1a09da445322202802dcb7738bcba847ee412cb0066"
                      "3b52aa569325"),
     "prefill_chunk": (935, "0e0d6374ccc52a0fc590c0cf18c138096343708d5a04d1"

@@ -137,7 +137,7 @@ def test_flash_under_fsdp_mesh_is_shard_mapped(topo):
 # the bench geometry (h2048, GQA 16:4), the smoke's (llama2_7b, 32 MHA
 # heads) and the ``serve-batch-closed`` cell's own (mistral-7b-serve:
 # GQA 32:8, 8 slots of 2305 rows = 145 pages, which the kernel pads to
-# 19 groups of 8, in a pool of 1161 blocks); head_dim 128, 16-row pages
+# 10 groups of 16, in a pool of 1161 blocks); head_dim 128, 16-row pages
 _GEOMETRIES = {
     "bench16x4": dict(heads=16, kv_heads=4),
     "llama2_7b": dict(heads=32, kv_heads=32),
