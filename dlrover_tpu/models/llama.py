@@ -65,9 +65,11 @@ class LayerSpec:
     window: int = 0
     rope: RopeSpec = RopeSpec()
     mlp: str = "dense"            # "dense" | "sparse"
-    # what mixes tokens: "attn" (the model's attention block) | "kda"
-    # (linear attention with a recurrent state: LlamaConfig.kda_heads) |
-    # "ssm" (a Mamba-2 state-space layer: LlamaConfig.ssm_heads)
+    # what mixes tokens: "attn" (the model's attention block) | "conv" (a
+    # gated short convolution, ``ShortConv``: LlamaConfig.conv_taps;
+    # trained, not served) | "kda" (linear attention with a recurrent
+    # state: LlamaConfig.kda_heads) | "ssm" (a Mamba-2 state-space layer:
+    # LlamaConfig.ssm_heads); the last two are served, not trained
     mixer: str = "attn"
     # what differs between two kinds of LATENT layer in one model (0: the
     # config's ``kv_lora_rank`` / ``qk_nope_head_dim``), and whether the
@@ -140,8 +142,11 @@ class LlamaConfig:
     # initialise each expert as a matrix of its own (MoEMLP.per_expert_init)
     moe_per_expert_init: bool = False
     # a learned bias added to the scores for the CHOICE of the top_k only
-    # (``noaux_tc``); the weights stay the scores' own
+    # (``noaux_tc``); the weights stay the scores' own.  Zeros at
+    # initialisation, or N(0, ``moe_select_bias_std``) where a seeded run
+    # wants a bias that changes picks
     moe_select_bias: bool = False
+    moe_select_bias_std: float = 0.0
     # of a model whose layers are otherwise alike: this many leading
     # layers keep the dense MLP, the rest are sparse
     moe_first_dense: int = 0
@@ -185,6 +190,12 @@ class LlamaConfig:
     ssm_head_dim: int = 0
     ssm_state: int = 0
     ssm_conv: int = 4
+    # a gated short convolution (a ``LayerSpec.mixer`` of "conv", LFM2):
+    # ``[B | C | u] = W_in h``, three widths of ``hidden_size``; a causal
+    # DEPTHWISE convolution of ``conv_taps`` taps over ``B * u``, no bias,
+    # no activation, no recurrent state; ``W_out (C * that)``.  Trained
+    # only (``ShortConv``)
+    conv_taps: int = 3
     # the embedding times this; every residual branch (mixer and MLP) times
     # this before it is added (Granite's ``embedding_multiplier`` and
     # ``residual_multiplier``).  Served by the loop of layer kinds only
@@ -203,12 +214,17 @@ class LlamaConfig:
     # layers that are not all alike, one LayerSpec each (None = every
     # layer is the one the fields above describe)
     layers: Optional[Tuple[LayerSpec, ...]] = None
-    # RMSNorm with a learned scale over the WHOLE projected query and the
-    # whole projected key, before the split into heads and before RoPE
-    # (OLMoE, OLMo-2).  Of a latent-attention model: over each query
-    # head's ``qk_nope_head_dim + qk_rope_head_dim`` values, one scale for
-    # all heads, before rotation; the key's norm is the latent's RMSNorm
+    # RMSNorm with a learned scale on the projected query and the
+    # projected key, before RoPE, of the kind ``qk_norm_kind`` names:
+    # "projection", over the WHOLE projection before the split into heads,
+    # one scale of heads x head_dim (OLMoE, OLMo-2), or "head", over each
+    # head's ``head_dim`` values, ONE scale of ``head_dim`` for all query
+    # heads and one for all key heads (LFM2).  Of a latent-attention model
+    # (the kind is not read): over each query head's ``qk_nope_head_dim +
+    # qk_rope_head_dim`` values, one scale for all heads, before rotation;
+    # the key's norm is the latent's RMSNorm
     qk_norm: bool = False
+    qk_norm_kind: str = "projection"
     # the rotary embedding of a latent-attention model where it is not
     # plain RoPE at ``rope_theta`` (YaRN: ``rope_inverse_frequencies``)
     rope_scaling: Optional[RopeSpec] = None
@@ -257,6 +273,10 @@ class LlamaConfig:
             raise ValueError(
                 f"{len(self.layers)} layer descriptions for num_layers="
                 f"{self.num_layers}")
+        if self.qk_norm_kind not in ("projection", "head"):
+            raise ValueError(
+                f"unknown qk_norm_kind {self.qk_norm_kind!r}: the QK-norm is "
+                "over the whole 'projection' or over each 'head'")
         if (self.rope_scaling or self.attn_scale_mult != 1.0) \
                 and not self.kv_lora_rank:
             raise ValueError(
@@ -335,6 +355,10 @@ class LlamaConfig:
             xbc = w + 2 * self.ssm_state
             n = (h * (w + xbc + self.ssm_heads) + (self.ssm_conv + 1) * xbc
                  + 3 * self.ssm_heads + w + w * h + 2 * h)
+        elif spec.mixer == "conv":
+            # W_in to [B | C | u]; a channel's taps; W_out; the block's
+            # two norms
+            n = 3 * h * h + self.conv_taps * h + h * h + 2 * h
         elif self.kv_lora_rank:
             heads, q = spec.num_heads, self.q_lora_rank
             c, nope, indexed = self.latent_dims(spec)
@@ -352,8 +376,12 @@ class LlamaConfig:
         if self.attn_head_gate and spec.mixer == "attn":
             n += h * spec.num_heads
         if self.qk_norm and spec.mixer == "attn":
-            n += d if self.kv_lora_rank else d * (
-                spec.num_heads + self.num_kv_heads)
+            if self.kv_lora_rank:
+                n += d
+            elif self.qk_norm_kind == "head":
+                n += 2 * d
+            else:
+                n += d * (spec.num_heads + self.num_kv_heads)
         if spec.mlp == "sparse":
             held = (self.moe_experts_held or (0, self.num_experts))[1]
             n += 3 * h * self.expert_width * held + h * self.num_experts
@@ -768,6 +796,72 @@ class LlamaConfig:
         return cls(**base)
 
     @classmethod
+    def lfm2_8b_a1b(cls, **kw) -> "LlamaConfig":
+        """LiquidAI/LFM2-8B-A1B (``lfm2_moe``) as its config.json has it: 24
+        layers, ``layer_types`` full attention at 2, 6, 10, 14, 18 and 21
+        (0-based) and a gated short convolution everywhere else (three to
+        one; ``layer_pattern`` of the whole is (18, 3)).  A CONV layer:
+        ``[B | C | u] = W_in h``, a causal depthwise convolution of 3 taps
+        over ``B * u`` with no bias and no activation, ``W_out (C * that)``
+        (``ShortConv``).  An ATTENTION layer: 32 query / 8 KV heads of 64,
+        an RMSNorm over each head of q and k with one scale for all heads,
+        plain RoPE at theta 1e6.  Layers 0 and 1 keep a dense SwiGLU of
+        7168, the rest 32 sigmoid-routed experts of 1792, 4 a token chosen
+        by score + bias, weights over their sum, NO shared expert;
+        vocabulary 65536, TIED.  Trained, not served.  ``num_layers`` cuts
+        the pattern's depth; ``moe_experts_held`` and ``vocab_size`` give one
+        chip its share.  (The benchmark's cut is a run of layers from the
+        MIDDLE, published layers 1-13: one dense conv layer, then three
+        periods of (attention, conv, conv, conv), ``layer_pattern`` (1, 4);
+        ``perfbench/drivers/train_conv.py conv_config`` makes it of the
+        configuration file's published keys.)
+
+        Assumed, where the config names a mechanism and not its equation
+        (``perfbench/configs/lfm2-8b-a1b-train.json``): tied embeddings,
+        the split order and the taps' order (oldest first), the QK-norm's
+        one scale for all heads, the bias a buffer no optimizer rule moves
+        (seeded N(0, ``moe_select_bias_std``))."""
+        kinds = ["conv"] * 24
+        for i in (2, 6, 10, 14, 18, 21):
+            kinds[i] = "attn"
+        num_layers = int(kw.pop("num_layers", 24))
+        rope = RopeSpec(theta=1000000.0)
+        layers = tuple(
+            LayerSpec(num_heads=int(kw.get("num_heads", 32)), rope=rope,
+                      mixer=kinds[i], mlp="sparse" if i >= 2 else "dense")
+            for i in range(num_layers))
+        base = dict(
+            vocab_size=65536,
+            hidden_size=2048,
+            intermediate_size=7168,
+            num_layers=num_layers,
+            num_heads=32,
+            num_kv_heads=8,
+            head_dim=64,
+            max_seq_len=128000,
+            rope_theta=1000000.0,
+            rms_norm_eps=1e-5,
+            tie_embeddings=True,
+            qk_norm=True,
+            qk_norm_kind="head",
+            conv_taps=3,
+            num_experts=32,
+            moe_top_k=4,
+            moe_norm_topk_prob=True,
+            moe_intermediate_size=1792,
+            moe_score_fn="sigmoid",
+            moe_routed_scale=1.0,
+            moe_shared_width=0,
+            moe_select_bias=True,
+            moe_per_expert_init=True,
+            moe_aux_loss_coef=0.0,
+            moe_z_loss_coef=0.0,
+            layers=layers,
+        )
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
     def from_preset(
         cls, name: str, num_layers: int = 0, **kw
     ) -> "LlamaConfig":
@@ -801,7 +895,7 @@ class LlamaConfig:
 #: presets the entry points (examples/, the serving worker) can name
 PRESETS = ("tiny", "llama2_7b", "olmoe_1b_7b", "laguna_xs2", "glm5",
            "sarvam_105b", "kimi_linear_48b", "dots3_note",
-           "granite_4_h_small")
+           "granite_4_h_small", "lfm2_8b_a1b")
 
 
 def resolve_remat_policy(name: str):
@@ -1048,14 +1142,16 @@ class Attention(nn.Module):
             k = k_proj(x)
             v = v_proj(x)
             if cfg.qk_norm:
-                def whole(name, y):
-                    flat = y.reshape(
-                        *y.shape[:-2], y.shape[-2] * y.shape[-1])
+                def normed(name, y):
+                    # "head": over a head's values, one scale for all heads
+                    shape = y.shape
+                    if cfg.qk_norm_kind == "projection":
+                        y = y.reshape(*shape[:-2], shape[-2] * shape[-1])
                     return RMSNorm(cfg.rms_norm_eps, cfg.dtype,
                                    cfg.param_dtype,
-                                   name=name)(flat).reshape(y.shape)
+                                   name=name)(y).reshape(shape)
 
-                q, k = whole("q_norm", q), whole("k_norm", k)
+                q, k = normed("q_norm", q), normed("k_norm", k)
             q = with_logical_constraint(
                 q, ("batch", "seq", "heads", "head_dim"))
             k = with_logical_constraint(
@@ -1142,6 +1238,79 @@ class Attention(nn.Module):
             return o_proj(out)
 
 
+def causal_depthwise_conv(v: jax.Array, taps: jax.Array,
+                          segment_ids: Optional[jax.Array] = None
+                          ) -> jax.Array:
+    """``out[t] = sum_j taps[j] * v[t - (K - 1 - j)]``: v [b, s, c], taps
+    [K, c] with the OLDEST position's tap first and the current one's
+    last, one weight a channel and tap; float32 sums whatever comes in.
+    Zeros stand ahead of a sequence's first token and, where
+    ``segment_ids`` [b, s] are given, ahead of a SEGMENT's: a packed row
+    does not leak across documents.  Shifted multiply-adds, so the
+    backward is a convolution again (the shifts the other way)."""
+    k, s = taps.shape[0], v.shape[1]
+    v, taps = v.astype(jnp.float32), taps.astype(jnp.float32)
+    out = v * taps[k - 1]
+    for back in range(1, min(k, s)):
+        shifted = jnp.pad(v, ((0, 0), (back, 0), (0, 0)))[:, :s]
+        if segment_ids is not None:
+            before = jnp.pad(segment_ids, ((0, 0), (back, 0)),
+                             constant_values=-1)[:, :s]
+            shifted = jnp.where((before == segment_ids)[..., None],
+                                shifted, 0.0)
+        out = out + shifted * taps[k - 1 - back]
+    return out
+
+
+def _taps_init(key, shape, dtype):
+    """N(0, 1/3) (LeCun-normal by a channel's fan-in of three taps), with 1
+    added to the current position's tap: at initialisation a channel
+    passes its own token on and sees the ones before it."""
+    taps = jax.random.normal(key, shape, jnp.float32) * shape[0] ** -0.5
+    return taps.at[-1].add(1.0).astype(dtype)
+
+
+class ShortConv(nn.Module):
+    """A gated short convolution as the token mixer (LFM2):
+    ``[B | C | u] = W_in h``; ``W_out (C * conv(B * u))``, the convolution
+    causal and depthwise over ``cfg.conv_taps`` positions, no bias, no
+    activation, no state beyond those positions.  ``conv_proj`` is the two
+    projections, ``conv_mix`` the gates and the taps between them."""
+
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array,
+                 segment_ids: Optional[jax.Array] = None) -> jax.Array:
+        cfg = self.config
+        h = cfg.hidden_size
+        init = nn.initializers.lecun_normal()
+        with device_scope("conv_proj"):
+            bcu = nn.DenseGeneral(
+                (3, h), use_bias=False, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, dot_general=cfg.dot_general,
+                kernel_init=nn.with_logical_partitioning(
+                    init, ("embed", None, "mlp")),
+                name="in_proj")(x)
+            bcu = with_logical_constraint(
+                bcu, ("batch", "seq", None, "mlp"))
+        taps = self.param(
+            "taps", nn.with_logical_partitioning(_taps_init, (None, "mlp")),
+            (cfg.conv_taps, h), cfg.param_dtype)
+        with device_scope("conv_mix"):
+            b, c, u = (bcu[..., i, :].astype(jnp.float32) for i in range(3))
+            y = (c * causal_depthwise_conv(b * u, taps, segment_ids)
+                 ).astype(cfg.dtype)
+        with device_scope("conv_proj"):
+            y = with_logical_constraint(y, ("batch", "seq", "mlp"))
+            return nn.DenseGeneral(
+                h, use_bias=False, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, dot_general=cfg.dot_general,
+                kernel_init=nn.with_logical_partitioning(
+                    init, ("mlp", "embed")),
+                name="out_proj")(y)
+
+
 class MLP(nn.Module):
     """SwiGLU feed-forward."""
 
@@ -1190,15 +1359,21 @@ class DecoderLayer(nn.Module):
         """``stacked``: a sparse layer's expert weights as the scan over
         layers stacks them, and the layer's index (``MoEMLP``)."""
         cfg, spec = self.config, self.spec
-        # the layer's two norms count with the projections (``attn_proj``)
-        with device_scope("attn_proj"):
+        conv = spec is not None and spec.mixer == "conv"
+        # the layer's two norms count with the mixer's projections
+        # (``attn_proj``, ``conv_proj``)
+        proj_scope = "conv_proj" if conv else "attn_proj"
+        with device_scope(proj_scope):
             h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
                         name="input_norm")(x)
-        x = x + Attention(cfg, spec, name="attn")(
-            h, positions, segment_ids, decode=decode, cache_len=cache_len,
-            rope=rope)
+        if conv:
+            x = x + ShortConv(cfg, name="conv")(h, segment_ids)
+        else:
+            x = x + Attention(cfg, spec, name="attn")(
+                h, positions, segment_ids, decode=decode,
+                cache_len=cache_len, rope=rope)
         x = with_logical_constraint(x, ("batch", "seq", "act_embed"))
-        with device_scope("attn_proj"):
+        with device_scope(proj_scope):
             h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
                         name="post_norm")(x)
         if cfg.num_experts and (spec is None or spec.mlp == "sparse"):
@@ -1218,6 +1393,7 @@ class DecoderLayer(nn.Module):
                 experts_held=cfg.moe_experts_held,
                 per_expert_init=cfg.moe_per_expert_init,
                 select_bias=cfg.moe_select_bias,
+                select_bias_std=cfg.moe_select_bias_std,
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
                 fp8=cfg.fp8,
@@ -1298,14 +1474,15 @@ class LlamaModel(nn.Module):
         :func:`dlrover_tpu.ops.losses.fused_lm_head_loss` so the full
         logits are never materialized."""
         cfg = self.config
-        if any(s.mixer != "attn" for s in cfg.layer_specs):
+        if any(s.mixer not in ("attn", "conv") for s in cfg.layer_specs):
             raise NotImplementedError(
-                "LlamaModel trains attention layers: a layer whose mixer "
-                "is linear attention (LayerSpec.mixer='kda') or a "
-                "state-space scan ('ssm') is served only "
-                "(serving/linear.py).  Missing: the chunk kernel's backward "
-                "(ops/pallas/kda.py, ops/pallas/ssm.py) and a training "
-                "layer (ROADMAP Reach A6)")
+                "LlamaModel trains attention layers and gated short "
+                "convolutions (LayerSpec.mixer='attn', 'conv'): a layer "
+                "whose mixer is linear attention ('kda') or a state-space "
+                "scan ('ssm') is served only (serving/linear.py).  Missing: "
+                "the chunk kernel's backward (ops/pallas/kda.py, "
+                "ops/pallas/ssm.py) and a training layer around it "
+                "(ROADMAP Reach A6)")
         if cfg.embedding_mult != 1.0 or cfg.residual_mult != 1.0 \
                 or cfg.attn_scale is not None:
             raise NotImplementedError(
@@ -1316,12 +1493,14 @@ class LlamaModel(nn.Module):
                 "(serving/latent.py's loop of layer kinds)")
         if cfg.kv_lora_rank or cfg.moe_first_dense:
             raise NotImplementedError(
-                "LlamaModel trains the grouped-query block: latent "
+                "LlamaModel trains the grouped-query block and the gated "
+                "short convolution: latent "
                 f"attention (kv_lora_rank={cfg.kv_lora_rank}), its indexer "
                 "(latent layers of two geometries and a window among them, "
                 "the rescale behind their norms), "
                 "and leading dense layers by count (moe_first_dense="
-                f"{cfg.moe_first_dense}) are served only "
+                f"{cfg.moe_first_dense}; a model trained here describes "
+                "its layers one by one, LlamaConfig.layers) are served only "
                 "(serving/latent.py); a training layer for them is "
                 "ROADMAP Reach A5")
         if positions is None:

@@ -369,8 +369,10 @@ class MoEMLP(nn.Module):
     shared_width: int = 0
     # (first, count) of the experts this device holds (None = all)
     experts_held: Optional[Tuple[int, int]] = None
-    # a bias on the scores for the CHOICE of the top_k only (route)
+    # a bias on the scores for the CHOICE of the top_k only (route):
+    # zeros at initialisation, or N(0, select_bias_std)
     select_bias: bool = False
+    select_bias_std: float = 0.0
     # LeCun fan-in of ONE expert's matrix.  Off, the fan-in is the whole
     # stack's (the expert axis counts as receptive field), which makes an
     # expert's output (experts^-1/2)^3 of a dense layer's at
@@ -428,7 +430,9 @@ class MoEMLP(nn.Module):
                 # ``noaux_tc``: moved by the load, never by a gradient
                 bias = jax.lax.stop_gradient(self.param(
                     "select_bias", nn.with_logical_partitioning(
-                        nn.initializers.zeros_init(), ("expert",)),
+                        nn.initializers.normal(self.select_bias_std)
+                        if self.select_bias_std
+                        else nn.initializers.zeros_init(), ("expert",)),
                     (e,), jnp.float32))
             top_p, top_e, probs = route(
                 logits, k, self.score_fn, self.norm_topk_prob,

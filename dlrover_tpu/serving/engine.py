@@ -551,6 +551,13 @@ class InferenceEngine:
                         if s.mixer != "attn"})
         self._state_kind = kinds[0] if kinds else None
         self._state_layers = sum(s.mixer != "attn" for s in cfg.layer_specs)
+        if "conv" in kinds:
+            raise ValueError(
+                "layers whose mixer is a gated short convolution "
+                "(LayerSpec.mixer='conv') are trained, not served.  "
+                "Missing: a convolution state a slot with no recurrent "
+                "state beside it (serving/linear.py state_shapes) and its "
+                "steps in serving/latent.py::_state_mixer (ROADMAP Reach A4)")
         if kinds:
             name = {"kda": "linear-attention", "ssm": "state-space"}.get(
                 kinds[0], kinds[0])

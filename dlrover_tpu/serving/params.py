@@ -126,12 +126,22 @@ def serving_params_from_llama(
     the Pallas kernel layout at load time."""
     import flax.linen as nn
 
+    if any(s.mixer == "conv" for s in cfg.layer_specs):
+        raise ValueError(
+            "a gated short convolution (LayerSpec.mixer='conv') is trained, "
+            "not served.  Missing: a convolution state a slot (the last "
+            f"conv_taps - 1 = {cfg.conv_taps - 1} gated rows of a layer) "
+            "with NO recurrent state beside it in serving/linear.py "
+            "state_shapes, and its decode and prefill-chunk steps in "
+            "serving/latent.py::_state_mixer (ROADMAP Reach A4)")
     if cfg.qk_norm and not cfg.kv_lora_rank:
         raise ValueError(
             "the serving engine's grouped-query blocks have no QK-norm "
-            f"(qk_norm={cfg.qk_norm}): the model would be served as a "
-            "different one.  Missing: the norm over the projected query "
-            "and key in serving/model.py::_attn_proj and "
+            f"(qk_norm={cfg.qk_norm}, qk_norm_kind={cfg.qk_norm_kind!r}): "
+            "the model would be served as a different one.  Missing: the "
+            "norm over the projected query and key (the whole projection, "
+            "or each head under one scale) in "
+            "serving/model.py::_attn_proj and "
             "serving/latent.py::_gqa_layer (ROADMAP Reach A3)")
     attn = {dataclasses.replace(s, mlp="dense") for s in cfg.layer_specs
             if s.mixer == "attn"}
@@ -255,8 +265,9 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
 
             return ssm_params(p["ssm"], cfg, dtype)
         if spec.mixer != "attn":
-            raise ValueError(f"no served mixer {spec.mixer!r}: a layer is "
-                             "'attn', 'kda' or 'ssm' (LayerSpec.mixer)")
+            raise ValueError(f"no served mixer {spec.mixer!r}: a served "
+                             "layer is 'attn', 'kda' or 'ssm' "
+                             "(LayerSpec.mixer; 'conv' is trained only)")
         a = p["attn"]
         if not cfg.kv_lora_rank:         # the grouped-query block
             return {

@@ -91,6 +91,21 @@ def test_flash_fwd_bwd_cells_heads_one_chip(one_chip, heads, kv_heads):
     assert text.count("tpu_custom_call") >= 3  # forward, dq, dkv
 
 
+def test_flash_fwd_bwd_at_a_head_of_64_one_chip(one_chip):
+    """The ``train-conv-moe-8k`` cell's attention (LFM2-8B-A1B: 32 query /
+    8 KV heads of 64) at seq 8192, 2 sequences: a head fills half a vreg
+    row and half the MXU's contraction, and the forward, ``dq`` and
+    ``dkv`` kernels still lower at the tile defaults a head of 128 reads."""
+    q = jax.ShapeDtypeStruct((2, 8192, 32, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8192, 8, 64), jnp.bfloat16,
+                              sharding=one_chip)
+    grad = _attn_loss(lambda q, k, v, s: flash_attention(
+        q, k, v, causal=True, segment_ids=s))
+    text = jax.jit(grad).lower(q, kv, kv, None).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3  # forward, dq, dkv
+
+
 @pytest.mark.parametrize("heads", [64, 48])
 def test_window_flash_fwd_bwd_hybrid_cell_one_chip(one_chip, heads):
     """The ``train-hybrid-8k`` cell's window layers (Laguna-XS.2: 64 query
@@ -743,12 +758,31 @@ def _hybrid_cell():
         dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, remat=True), 2, 8192
 
 
+def _conv_cell():
+    import json
+    import os
+
+    from perfbench.drivers.train_conv import conv_config
+
+    # the cell's configuration file as its driver reads it: published
+    # layers 1-13, one leading conv layer and three periods of (attention,
+    # conv, conv, conv), heads of 64
+    with open(os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "perfbench/configs/lfm2-8b-a1b-train.json")) as f:
+        config = json.load(f)
+    dep = config["deployment"]
+    return (conv_config(config, max_seq_len=dep["seq_len"]),
+            dep["sequences_per_chip_per_step"], dep["seq_len"])
+
+
 @pytest.mark.parametrize("cell,stack,sparse_layers_a_loop,flash_calls", [
     (_olmoe_cell, (3, 64, 2048, 1024), 1, {"attn": 4}),
     (_hybrid_cell, (2, 32, 2048, 512), 4, {
         "attn_full": 8, "flash_window_fwd": 6, "flash_window_dq": 3,
         "flash_window_dkv": 3, "rope_rotate": 30}),
-], ids=["train-moe-dropless", "train-hybrid-8k"])
+    (_conv_cell, (3, 8, 2048, 1792), 4, {"attn_full": 4}),
+], ids=["train-moe-dropless", "train-hybrid-8k", "train-conv-moe-8k"])
 def test_sparse_cells_step_reads_expert_weights_in_the_stack(
         topo, monkeypatch, cell, stack, sparse_layers_a_loop, flash_calls):
     """The whole train step of the two sparse cells (``ElasticTrainer``'s
@@ -787,6 +821,12 @@ def test_sparse_cells_step_reads_expert_weights_in_the_stack(
     state = nn.unbox(res.abstract_state)
     scanned = state.params["layers"]["layer"] if cfg.layers is None \
         else state.params["periods"]["layer_0"]
+    convs = sum(s.mixer == "conv" for s in cfg.layer_specs)
+    if convs:
+        # a period's layers have different trees: attention, then convs
+        assert "attn" in scanned and "conv" not in scanned
+        assert state.params["periods"]["layer_1"]["conv"]["taps"].shape \
+            == (3, 3, 2048)
     assert scanned["mlp"]["w_gate"].shape == stack
     batch = {"input_ids": jax.ShapeDtypeStruct((rows, seq), jnp.int32)}
     with logical_rules_context(res.config.logical_rules), res.mesh:
@@ -809,10 +849,16 @@ def test_sparse_cells_step_reads_expert_weights_in_the_stack(
     # is rematerialised by the COMPILER to fit; with the loss's ``d_logits``
     # pinned to memory it ran the head's d-hidden matmul a second time
     # (``fusion.2177.remat``, 4.4 ms a step on the chip; PERF.md section 6)
-    assert not re.findall(
-        r'^\s+%([\w.\-]*remat[\w.\-]*) = .*op_name="[^"]*[/(]head[/)]',
-        text, re.M)
-    if cfg.layers is not None:
+    # (the TIED head of ``train-conv-moe-8k`` is not held to it: there the
+    # compiler recomputes the logits' matmul, ``embed_tokens.attend``, to
+    # fit; PERF.md section 7)
+    if not cfg.tie_embeddings:
+        assert not re.findall(
+            r'^\s+%([\w.\-]*remat[\w.\-]*) = .*op_name="[^"]*[/(]head[/)]',
+            text, re.M)
+    for scope in ("conv_proj", "conv_mix") if convs else ():
+        assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', text), scope
+    if cfg.layers is not None and cfg.head_dim_ == 128:
         half_a_head = re.compile(
             rf"(?:bf16|f32)\[{rows},{seq},\d+,(?:{cfg.head_dim_ // 2}|"
             rf"{cfg.head_dim_ // 4})\]")
