@@ -512,9 +512,11 @@ def accelerate(
                 micro_step, (zero, zero_grads, zero), batch
             )
             # one value a microbatch: the worst load, the mean loss, the
-            # step's picks on held experts
+            # step's picks on held experts and its layers that overflowed
+            # their sorted buffer
             worst = {"moe_load_max": jnp.max, "moe_load_min": jnp.min,
-                     "moe_picks_held": jnp.sum}
+                     "moe_picks_held": jnp.sum,
+                     "moe_overflow_layers": jnp.sum}
             moe_stats = {k: worst.get(k, jnp.mean)(v)
                          for k, v in moe_stats.items()}
             with device_scope("grad_accum"):

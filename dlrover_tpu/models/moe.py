@@ -31,13 +31,38 @@ Variants (all off by default, and then the traced layer is as it was):
 dense SwiGLU expert on every token (scope ``moe_shared``);
 ``experts_held=(first, count)`` makes the layer ONE SHARE of an
 expert-parallel group: the router keeps all ``num_experts`` outputs and
-picks ``top_k`` of them, the layer holds ``count`` experts' weights,
-the picks on held experts are sorted by expert AHEAD of all others, the
-grouped matmuls get the held groups' sizes and multiply nothing behind
-them, and a pick on an absent expert adds nothing, forward or backward.
-Nothing stands in for the absent devices.  The sorted buffer keeps its
-static bound of ``T x top_k`` rows though ``count / num_experts`` of
-them are live on average: the exchange of a deployment would fill it.
+picks ``top_k`` of them, the layer holds ``count`` experts' weights, and a
+pick on an absent expert adds nothing, forward or backward.  Nothing
+stands in for the absent devices.
+
+The sorted buffer of a share holds the picks on HELD experts only:
+:func:`buffer_rows` rows, ``C``, 3 / 2 of what an even routing sends the
+share (24 576 of ``train-conv-moe-8k``'s 65 536 picks and of
+``train-hybrid-8k``'s 131 072).  Every pass around the three grouped
+matmuls (placing the tokens' rows, the selects behind the held groups,
+``silu(gate) * up``, the weights, the sum back to tokens, and the
+transposes of all of these) is over ``C`` rows and not ``T x top_k``.  Rows
+go in by ONE gather of ``C`` rows and come back by a gather of ``[top_k,
+T]`` rows summed over the choices in float32 (:func:`_sum_of_rows`; each is
+the other's transpose, so the walk holds no scatter).  The layer's
+derivative is written out (:func:`_walked`): JAX's own, through a ``cond``,
+makes every branch write what any branch keeps and asked 17.0 GB of the
+chip's 15.75 (PR 56).
+
+No pick is dropped under any routing.  Where the share gets more picks
+than ``C``, the rows of expert order behind the buffer are walked behind
+ONE ``cond`` a layer and direction (:class:`_HeldWalk` ``further`` /
+``further_back``), a SEGMENT at a time: up to ``SEGMENT_ROWS`` rows of one
+expert, gathered, through three plain matmuls against that expert's
+weights, added to their tokens in float32.  That path is rare (never in
+``train-conv-moe-8k``; one layer of eight in most steps of
+``train-hybrid-8k``), every sparse layer's program holds it forward and
+backward, and the kernels' roofline is read from exactly 12 ``gmm`` /
+``tgmm`` calls a layer: so it calls no kernel, keeps nothing for its
+backward (which runs its forward again), has no temporary larger than a
+segment, and is written to be SHORT, not fast.  A layer that holds every
+expert, or whose buffer would be every pick, keeps the buffer of every
+pick and traces what it did.
 
 What the layer sows into the ``"moe_losses"`` collection, once a layer:
 ``aux_loss`` (the coefficients times the two terms below: what
@@ -46,15 +71,17 @@ What the layer sows into the ``"moe_losses"`` collection, once a layer:
 ``T x top_k`` picks that chose e, ``P_e`` the mean router probability),
 ``z_loss`` (``mean_t logsumexp(logits_t)^2``), ``expert_counts``
 (the picks of each of ALL ``num_experts``) and, of a share,
-``held_counts`` (its groups' sizes).  :func:`routing_stats` reduces them
-for the step's metrics.
+``held_counts`` (its groups' sizes) and ``overflowed`` (1 where they
+exceed the compact buffer).  :func:`routing_stats` reduces them for the
+step's metrics.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
-from typing import Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -76,6 +103,47 @@ from dlrover_tpu.utils.profiler import device_scope
 # 23.5 ms.  A side of 2048 beside one of 1024, and (1024, 1024, 1024), do
 # not fit the chip's fast memory.  The time does not move with the skew.
 GMM_TILING = (256, 1024, 1024)
+
+# Rows of the sorted buffer of a layer that holds a share of its experts,
+# over the picks an even routing sends it, as (numerator, denominator).  ONE
+# rule (:func:`buffer_rows`) for the trained layer below and the served one
+# (serving/latent.py ``sparse_mlp``), which share it and nothing else:
+# serving places its rows by 0 / 1 matmuls and walks every window with the
+# kernels, which PR 51 measured for decode-sized forwards.  Chosen there, on
+# the v5e at granite's prompt chunk (512 tokens x top 10, 18 of 72 experts
+# held, hidden 4096, width 768; my chip runs, PR 51): sixteen seeded routings
+# sent 1 245-1 328 picks where even is 1 280, and what is around the grouped
+# matmuls costs by the row (placing 125 us and bringing back 221 us at 2 048
+# rows) while the matmuls themselves do not (768 / 771 / 808 us at 1 792 /
+# 2 048 / 5 120 rows: they walk the groups, not the buffer).  5 / 4 would
+# save ~40 us a layer and walk twice at a load 9 % over even; 3 / 2 walks
+# once up to 50 %.  In training (builder's chip runs, PR 56, which the driver
+# never measured): ``train-conv-moe-8k`` overflowed in no layer of 119 steps,
+# ``train-hybrid-8k`` (Zipf ids at random router weights, a load of 14.6-18.8
+# x the mean) in ONE of its eight sparse layers in most steps of three seeds
+# in four; a floor fitted to that cell, 49 152 rows, was slower (978 ms a
+# step against 858) and was struck in review.
+BUFFER_HEADROOM = (3, 2)
+# Rows of one segment of the walk behind an overflowing buffer: rows of ONE
+# expert, so that its matmuls are plain ones.  The MXU's side, because the
+# walk is in every sparse layer's program and has to be short: compiled for
+# a described v5e at ``train-hybrid-8k``'s widths, XLA's matmul of 128 or 256
+# rows is 0.07 MB of executable and of 512 rows 0.14-0.18, its gather of
+# float32 rows 0.18 / 0.41 / 0.41 (PR 57).  A segment's time is its expert's
+# weights read and their gradient's float32 sum carried, not its rows.
+SEGMENT_ROWS = 128
+
+
+def buffer_rows(picks: int, held: int, num_experts: int) -> int:
+    """Rows of the sorted buffer for ``picks`` (tokens x ``top_k``) of
+    which an even routing sends ``held / num_experts`` here: the smallest
+    multiple of the grouped matmul's row tile not under ``BUFFER_HEADROOM``
+    times that, and never more than ``picks`` (all of them where every
+    expert is held)."""
+    num, den = BUFFER_HEADROOM
+    tile = GMM_TILING[0]
+    rows = -(-num * picks * held // (den * num_experts))
+    return min(picks, -(-rows // tile) * tile)
 
 
 # the expert weights of a ``MoEMLP``, as its parameters name them
@@ -247,6 +315,296 @@ def _to_token_order_bwd(order, g):
 _to_token_order.defvjp(_to_token_order_fwd, _to_token_order_bwd)
 
 
+@jax.jit
+def _sum_of_rows(rows, slot, weights=None):
+    """Buffer rows back to tokens: out[i] = the sum over token i's
+    ``top_k`` picks j of ``rows[slot[j, i]]`` (times ``weights[j, i]``) in
+    float32, [T, m].  ``rows`` [C, m]; ``slot`` [top_k, T] holds a pick's
+    row in the buffer, or C for a pick the buffer does not hold, which
+    adds nothing.  A gather of T rows a CHOICE, which the compiler fuses
+    into the one pass that writes the sum: ONE gather of ``[top_k, T]``
+    rows is written out before it is summed (537 MB a pass in
+    ``train-hybrid-8k``'s compiled step, PR 57), and rows gathered
+    ``[T, top_k]`` are, at top 4, a relayout of half-filled tiles (2.66
+    ms against 1.68 alone on the chip; builder, PR 56).  Jitted for its
+    trace, as ``_traced_once`` below is."""
+    c = rows.shape[0]
+    total = jnp.zeros((slot.shape[1], rows.shape[-1]), jnp.float32)
+    for j, choice in enumerate(slot):
+        picked = jnp.where((choice < c)[:, None],
+                           rows[jnp.minimum(choice, c - 1)],
+                           jnp.zeros((), rows.dtype)).astype(jnp.float32)
+        if weights is not None:
+            picked = picked * weights[j][:, None]
+        total = total + picked
+    return total
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _rows_to_tokens(y, top_p, source, slot, live, top_k):
+    """The routed sum [T, m] in float32 from the compact buffer's rows
+    ``y`` [C, m]: :func:`_sum_of_rows` with the router's weights ``top_p``
+    [T, top_k].  ``source`` [C] is the pick a row holds, ``slot`` [top_k,
+    T] the row that holds a pick, ``live`` [C, 1] the rows that hold one
+    (a row behind them is never read).  Backward, a row's gradient is its
+    token's times its pick's weight, and a pick's weight gets its row's
+    product with its token's gradient, placed by ``slot``: gathers both,
+    and no pass over ``T x top_k`` rows."""
+    return _sum_of_rows(y, slot, top_p.T)
+
+
+def _rows_to_tokens_fwd(y, top_p, source, slot, live, top_k):
+    return (_rows_to_tokens(y, top_p, source, slot, live, top_k),
+            (y, top_p, source, slot, live))
+
+
+def _rows_to_tokens_bwd(top_k, residuals, g):
+    y, top_p, source, slot, live = residuals
+    g_rows = jnp.where(live, g[source // top_k], 0.0)      # float32 [C, m]
+    y = jnp.where(live, y, jnp.zeros((), y.dtype))
+    d_weight = jnp.sum(y.astype(jnp.float32) * g_rows, axis=-1)
+    d_top_p = jnp.concatenate([d_weight, jnp.zeros((1,), jnp.float32)])[slot]
+    return ((g_rows * top_p.reshape(-1, 1)[source]).astype(y.dtype),
+            d_top_p.T.astype(top_p.dtype), None, None, None)
+
+
+_rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
+
+
+# A method of ``_HeldWalk`` jitted INSIDE the step's own jit, the walk (a
+# hashable value) static: its trace is then made once a model and not once a
+# sparse layer and pass (forward, recomputed, backward), which was 1.4 s of
+# ``train-conv-moe-8k``'s warm set-up and 3 of ``train-hybrid-8k``'s (PR 57);
+# the compiler inlines the calls.
+_traced_once = functools.partial(jax.jit, static_argnums=0)
+
+
+@dataclasses.dataclass(frozen=True)
+class _HeldWalk:
+    """The arithmetic of a share's compact buffer (``MoEMLP`` with
+    ``experts_held``; the module's docstring), for :func:`_walked`.
+
+    ``operands`` are ``(x [T, m], top_p [T, top_k], w_gate, w_up,
+    w_down)``, what the layer is differentiated in; ``aux`` is a dict of
+    what it is not: ``stacked`` (``MoEMLP.__call__``), ``order`` [T x
+    top_k] (the picks in expert order, held ones ahead), ``ends`` [held]
+    (where each held group ends in it), ``source`` [bound] / ``live``
+    [bound, 1] / ``sizes`` [held] (the buffer's picks, which of its rows
+    hold one, its groups) and ``slot`` [top_k, T] (the row that holds a
+    pick; ``bound``: none)."""
+
+    top_k: int
+    bound: int
+    dtype: Any
+    fp8: bool
+
+    def _quant(self):
+        if self.fp8:
+            from dlrover_tpu.ops.fp8 import fake_quant_fp8, grad_quant_fp8
+
+            return fake_quant_fp8, grad_quant_fp8
+        return (lambda v: v), (lambda v: v)
+
+    def rows(self, x, aux):
+        """The buffer's rows of ``x``, zeros behind the held picks."""
+        with device_scope("moe_dispatch"):
+            return jnp.where(aux["live"], x[aux["source"] // self.top_k],
+                             jnp.zeros((), x.dtype))
+
+    @_traced_once
+    def first(self, xs, top_p, weights, aux):
+        """The routed sum [T, m] in float32 of the buffer's rows ``xs``:
+        the kernels, over the buffer's groups."""
+        fake_quant, grad_quant = self._quant()
+        stacked, sizes, live = aux["stacked"], aux["sizes"], aux["live"]
+
+        def lies_in(name):
+            return stacked and (stacked[0][name], stacked[1])
+
+        with device_scope("moe_experts"):
+            wg, wu, wd = (fake_quant(w.astype(self.dtype)) for w in weights)
+            xq = fake_quant(xs)
+            gate = grad_quant(
+                grouped_matmul(xq, wg, sizes, lies_in("w_gate")))
+            up = grad_quant(grouped_matmul(xq, wu, sizes, lies_in("w_up")))
+            if self.fp8:
+                # fp8 scales by the largest entry, of ``act`` forward and
+                # of these two's gradients backward (the selects'
+                # transposes): not one of rows that no matmul wrote
+                gate, up = (jnp.where(live, v, jnp.zeros((), v.dtype))
+                            for v in (gate, up))
+            out = grad_quant(grouped_matmul(
+                fake_quant(nn.silu(gate) * up), wd, sizes,
+                lies_in("w_down")))
+        with device_scope("moe_combine"):
+            return _rows_to_tokens(out, top_p, aux["source"], aux["slot"],
+                                   live, self.top_k)
+
+    # ... and of the rows of expert order BEHIND the buffer, a segment at
+    # a time: rows ``p`` to ``stop`` of ONE expert, so plain matmuls do
+
+    def _segment_at(self, p, aux):
+        """``(expert, stop, picks [SEGMENT_ROWS], valid)`` of the segment
+        that starts at row ``p`` of expert order: it ends with its
+        expert's group or ``SEGMENT_ROWS`` on."""
+        ends = aux["ends"]
+        expert = jnp.sum(ends <= p).astype(jnp.int32)
+        stop = jnp.minimum(ends[expert], p + SEGMENT_ROWS)
+        picks = jax.lax.dynamic_slice(
+            jnp.pad(aux["order"], (0, SEGMENT_ROWS)), (p,), (SEGMENT_ROWS,))
+        return expert, stop, picks, jnp.arange(SEGMENT_ROWS) < stop - p
+
+    def _segment_weights(self, weights, aux):
+        """``expert -> (wg, wu, wd)`` of one expert as the matmuls take
+        them: read in the scan's stack where there is one (a layer's
+        weights as an operand of the ``cond`` would be a copy of them,
+        201 MB in ``train-hybrid-8k``, made whether it is taken or not);
+        fp8 scales by a whole tensor's largest entry."""
+        if self.fp8:
+            fake_quant, _ = self._quant()
+            weights = [fake_quant(w.astype(self.dtype)) for w in weights]
+        elif aux["stacked"]:
+            stack, layer = aux["stacked"]
+            return lambda expert: [stack[name][layer, expert]
+                                   for name in _EXPERT_WEIGHTS]
+        return lambda expert: [w[expert].astype(self.dtype) for w in weights]
+
+    def _segment(self, rows, wg, wu, wd, weight):
+        """A segment's weighted results [SEGMENT_ROWS, m] in float32: the
+        arithmetic of :meth:`first` for one expert (a row of zeros gives
+        zeros, so nothing is selected)."""
+        fake_quant, grad_quant = self._quant()
+
+        def matmul(lhs, w):
+            return grad_quant(jnp.dot(
+                fake_quant(lhs), w,
+                preferred_element_type=jnp.float32).astype(lhs.dtype))
+
+        with device_scope("moe_experts"):
+            out = matmul(nn.silu(matmul(rows, wg)) * matmul(rows, wu), wd)
+        with device_scope("moe_combine"):
+            return out.astype(jnp.float32) * weight[:, None]
+
+    def _segment_operands(self, x, top_p, picks, valid):
+        with device_scope("moe_dispatch"):
+            return (jnp.where(valid[:, None], x[picks // self.top_k],
+                              jnp.zeros((), x.dtype)),
+                    jnp.where(valid, top_p.reshape(-1)[picks], 0.0))
+
+    @_traced_once
+    def further(self, y, operands, aux):
+        """``y`` plus the results of the held picks behind the buffer."""
+        x, top_p, *weights = operands
+        of = self._segment_weights(weights, aux)
+
+        def segment(carry):
+            p, y = carry
+            expert, stop, picks, valid = self._segment_at(p, aux)
+            rows, weight = self._segment_operands(x, top_p, picks, valid)
+            out = self._segment(rows, *of(expert), weight)
+            with device_scope("moe_combine"):
+                # a row that is no pick's adds zeros to some token
+                return stop, y.at[picks // self.top_k].add(out)
+
+        return jax.lax.while_loop(
+            lambda carry: carry[0] < aux["ends"][-1], segment,
+            (jnp.int32(self.bound), y))[1]
+
+    @_traced_once
+    def further_back(self, g, grads, operands, aux):
+        """``grads`` (of ``operands``, the first being ``x``'s in FLOAT32)
+        plus those of :meth:`further` under ``y``'s gradient ``g``.
+        Nothing was kept: a segment's forward runs again.  A token's row
+        gradient is summed in float32, and so is an expert's weights'
+        over its segments (``summed``: the buffer's share of it, then each
+        segment's), rounded where the expert's last segment writes it."""
+        x, top_p, *weights = operands
+        of = self._segment_weights(weights, aux)
+
+        def segment(carry):
+            p, last, summed, d_x, d_top_p, d_weights = carry
+            expert, stop, picks, valid = self._segment_at(p, aux)
+            rows, weight = self._segment_operands(x, top_p, picks, valid)
+            with device_scope("moe_combine"):
+                g_rows = jnp.where(valid[:, None], g[picks // self.top_k],
+                                   0.0)
+            d_rows, *d_expert, d_weight = jax.vjp(
+                self._segment, rows, *of(expert), weight)[1](g_rows)
+            with device_scope("moe_experts"):
+                summed = [jnp.where(expert == last, so_far,
+                                  whole[expert].astype(jnp.float32))
+                        + d.astype(jnp.float32)
+                        for so_far, whole, d in zip(summed, d_weights,
+                                                    d_expert)]
+                d_weights = [whole.at[expert].set(c.astype(whole.dtype))
+                             for whole, c in zip(d_weights, summed)]
+            with device_scope("moe_dispatch"):
+                d_x = d_x.at[picks // self.top_k].add(
+                    d_rows.astype(jnp.float32))
+            with device_scope("moe_combine"):
+                d_top_p = d_top_p.reshape(-1).at[picks].add(
+                    d_weight.astype(d_top_p.dtype)).reshape(d_top_p.shape)
+            return stop, expert, summed, d_x, d_top_p, d_weights
+
+        d_x, d_top_p, *d_weights = grads
+        summed = [jnp.zeros(w.shape[1:], jnp.float32) for w in d_weights]
+        *_, d_x, d_top_p, d_weights = jax.lax.while_loop(
+            lambda carry: carry[0] < aux["ends"][-1], segment,
+            (jnp.int32(self.bound), jnp.int32(-1), summed, d_x, d_top_p,
+             d_weights))
+        return (d_x, d_top_p, *d_weights)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _walked(walk: _HeldWalk, operands, aux):
+    """The routed sum [T, m] in float32 of a share's layer: ``walk.first``
+    through the compact buffer, in line, and ``walk.further`` behind ONE
+    ``cond`` where the held picks overflow it.  Differentiable in
+    ``operands``.
+
+    The derivative is written out because JAX's own, through a ``cond``,
+    makes every branch write whatever any branch keeps for its backward
+    (zeros where it keeps none), and keeps copies of the operands it only
+    passes on, the scan's stacked expert weights among them: 17.0 GB of
+    the chip's 15.75 in ``train-conv-moe-8k`` (PR 56).  Here the first walk
+    keeps what its backward needs, as JAX would, and the further one
+    nothing.  ``x``'s gradient is summed over a token's picks in float32,
+    the buffer's and the overflow's, and rounded once."""
+    x, top_p, *weights = operands
+    return _further(walk, walk.first(walk.rows(x, aux), top_p, weights, aux),
+                    operands, aux)
+
+
+def _further(walk, y, operands, aux):
+    return jax.lax.cond(aux["ends"][-1] > walk.bound,
+                        lambda y: walk.further(y, operands, aux),
+                        lambda y: y, y)
+
+
+def _walked_fwd(walk, operands, aux):
+    x, top_p, *weights = operands
+    y, back = jax.vjp(
+        lambda xs, top_p, weights: walk.first(xs, top_p, weights, aux),
+        walk.rows(x, aux), top_p, weights)
+    return _further(walk, y, operands, aux), (operands, aux, back)
+
+
+def _walked_bwd(walk, residuals, g):
+    operands, aux, back = residuals
+    d_rows, d_top_p, d_weights = back(g)
+    with device_scope("moe_dispatch"):
+        # the transpose of ``walk.rows``: a gather too
+        d_x = _sum_of_rows(d_rows, aux["slot"])
+    d_x, *rest = jax.lax.cond(
+        aux["ends"][-1] > walk.bound,
+        lambda grads: walk.further_back(g, grads, operands, aux),
+        lambda grads: grads, (d_x, d_top_p, *d_weights))
+    return (d_x.astype(operands[0].dtype), *rest), None
+
+
+_walked.defvjp(_walked_fwd, _walked_bwd)
+
+
 def _leaves_named(tree, name: str):
     """The leaves of a ``moe_losses`` tree sown under ``name``, each
     flattened: one entry a layer under ``nn.scan``'s stacking or not."""
@@ -279,7 +637,9 @@ def routing_stats(moe_losses) -> Dict[str, jax.Array]:
     losses are means over layers, without their coefficients.  Of a model
     whose layers hold a share of their experts (``experts_held``), the
     load is over the HELD groups, ``moe_picks_held`` is the picks on them
-    summed over layers and ``moe_held_share`` that over all picks."""
+    summed over layers, ``moe_held_share`` that over all picks and
+    ``moe_overflow_layers`` the layers whose held picks exceeded the
+    compact buffer (``MoEMLP``: the rare walk behind its ``cond`` ran)."""
     def stacked(name):
         return jnp.concatenate(
             [c.reshape(-1, c.shape[-1]).astype(jnp.float32)
@@ -297,7 +657,11 @@ def routing_stats(moe_losses) -> Dict[str, jax.Array]:
         picks, counts = jnp.sum(counts), stacked("held_counts")
         mean = jnp.maximum(jnp.mean(counts, axis=-1, keepdims=True), 1.0)
         held = {"moe_picks_held": jnp.sum(counts),
-                "moe_held_share": jnp.sum(counts) / picks}
+                "moe_held_share": jnp.sum(counts) / picks,
+                "moe_overflow_layers": sum(
+                    (jnp.sum(c.astype(jnp.float32))
+                     for c in _leaves_named(moe_losses, "overflowed")),
+                    jnp.zeros((), jnp.float32))}
     load = counts / mean
     return {
         "moe_load_max": jnp.max(load),
@@ -463,49 +827,74 @@ class MoEMLP(nn.Module):
             self.sow("moe_losses", "held_counts", counts,
                      reduce_fn=lambda _, new: new, init_fn=lambda: None)
 
-        with device_scope("moe_dispatch"):
-            order = jnp.argsort(picks, stable=True)  # expert-major
-            inverse = jnp.argsort(order)
-            xs = _to_expert_order(
-                x.reshape(t, m).astype(self.dtype), order, inverse, k)
-            if self.experts_held is not None:
-                # rows behind the held groups are no expert's: the grouped
-                # matmuls leave them unwritten, forward (``out``) and
-                # backward (the rows' gradient, which this select's
-                # transpose zeroes)
-                live = (jnp.arange(t * k) < jnp.sum(counts))[:, None]
-                xs = jnp.where(live, xs, jnp.zeros((), xs.dtype))
-
-        if self.fp8:
-            from dlrover_tpu.ops.fp8 import fake_quant_fp8, grad_quant_fp8
+        bound = buffer_rows(t * k, held, e)
+        if bound < t * k:
+            # a share's compact buffer: the picks it HOLDS, ``bound`` rows
+            with device_scope("moe_dispatch"):
+                order = jnp.argsort(picks, stable=True)  # held ones ahead
+                inverse = jnp.argsort(order)
+                ends = jnp.cumsum(counts)
+                aux = {
+                    "stacked": stacked, "order": order, "ends": ends,
+                    "source": order[:bound],
+                    "live": (jnp.arange(bound) < ends[-1])[:, None],
+                    "sizes": jnp.minimum(ends, bound)
+                    - jnp.minimum(ends - counts, bound),
+                    "slot": jnp.where(
+                        inverse < jnp.minimum(ends[-1], bound), inverse,
+                        bound).reshape(t, k).T,
+                }
+            self.sow("moe_losses", "overflowed",
+                     (ends[-1] > bound).astype(jnp.int32),
+                     reduce_fn=lambda _, new: new, init_fn=lambda: None)
+            y = _walked(
+                _HeldWalk(k, bound, self.dtype, self.fp8),
+                (x.reshape(t, m).astype(self.dtype), top_p, w_gate, w_up,
+                 w_down), aux)
         else:
-            fake_quant_fp8 = grad_quant_fp8 = lambda x: x  # noqa: E731
+            with device_scope("moe_dispatch"):
+                order = jnp.argsort(picks, stable=True)  # expert-major
+                inverse = jnp.argsort(order)
+                xs = _to_expert_order(
+                    x.reshape(t, m).astype(self.dtype), order, inverse, k)
+                if self.experts_held is not None:
+                    # rows behind the held groups are no expert's: the
+                    # grouped matmuls leave them unwritten, forward
+                    # (``out``) and backward (the rows' gradient, which
+                    # this select's transpose zeroes)
+                    live = (jnp.arange(t * k) < jnp.sum(counts))[:, None]
+                    xs = jnp.where(live, xs, jnp.zeros((), xs.dtype))
 
-        def lies_in(name):
-            return stacked and (stacked[0][name], stacked[1])
+            if self.fp8:
+                from dlrover_tpu.ops.fp8 import fake_quant_fp8, grad_quant_fp8
+            else:
+                fake_quant_fp8 = grad_quant_fp8 = lambda x: x  # noqa: E731
 
-        with device_scope("moe_experts"):
-            wg = fake_quant_fp8(w_gate.astype(self.dtype))
-            wu = fake_quant_fp8(w_up.astype(self.dtype))
-            wd = fake_quant_fp8(w_down.astype(self.dtype))
-            xq = fake_quant_fp8(xs)
-            gate = grad_quant_fp8(
-                grouped_matmul(xq, wg, counts, lies_in("w_gate")))
-            up = grad_quant_fp8(
-                grouped_matmul(xq, wu, counts, lies_in("w_up")))
-            act = nn.silu(gate) * up
-            if self.fp8 and self.experts_held is not None:
-                # fp8 scales by the largest entry: not one of rows that
-                # no matmul wrote
-                act = jnp.where(live, act, jnp.zeros((), act.dtype))
-            out = grad_quant_fp8(grouped_matmul(
-                fake_quant_fp8(act), wd, counts, lies_in("w_down")))
+            def lies_in(name):
+                return stacked and (stacked[0][name], stacked[1])
 
-        with device_scope("moe_combine"):
-            if self.experts_held is not None:
-                out = jnp.where(live, out, jnp.zeros((), out.dtype))
-            out = _to_token_order(out, order, inverse).reshape(t, k, m)
-            y = jnp.sum(out.astype(jnp.float32) * top_p[..., None], axis=1)
+            with device_scope("moe_experts"):
+                wg = fake_quant_fp8(w_gate.astype(self.dtype))
+                wu = fake_quant_fp8(w_up.astype(self.dtype))
+                wd = fake_quant_fp8(w_down.astype(self.dtype))
+                xq = fake_quant_fp8(xs)
+                gate = grad_quant_fp8(
+                    grouped_matmul(xq, wg, counts, lies_in("w_gate")))
+                up = grad_quant_fp8(
+                    grouped_matmul(xq, wu, counts, lies_in("w_up")))
+                act = nn.silu(gate) * up
+                if self.fp8 and self.experts_held is not None:
+                    # fp8 scales by the largest entry: not one of rows that
+                    # no matmul wrote
+                    act = jnp.where(live, act, jnp.zeros((), act.dtype))
+                out = grad_quant_fp8(grouped_matmul(
+                    fake_quant_fp8(act), wd, counts, lies_in("w_down")))
+
+            with device_scope("moe_combine"):
+                if self.experts_held is not None:
+                    out = jnp.where(live, out, jnp.zeros((), out.dtype))
+                out = _to_token_order(out, order, inverse).reshape(t, k, m)
+                y = jnp.sum(out.astype(jnp.float32) * top_p[..., None], axis=1)
         if self.shared_width:
             with device_scope("moe_shared"):
                 y = y + self._shared_expert(x).reshape(t, m)

@@ -105,7 +105,7 @@ import jax.numpy as jnp
 from dlrover_tpu.models.llama import (LayerSpec, LlamaConfig, RopeSpec,
                                       apply_rope, rope_frequencies,
                                       rope_inverse_frequencies)
-from dlrover_tpu.models.moe import GMM_TILING, grouped_matmul, route
+from dlrover_tpu.models.moe import buffer_rows, grouped_matmul, route
 from dlrover_tpu.serving.model import _lm_head, _mm, _rmsnorm
 from dlrover_tpu.serving.paged import (ring_table, scatter_ring,
                                        scatter_tokens)
@@ -562,18 +562,10 @@ def _swiglu(h, wgu, down, dtype):
     return _mm(jax.nn.silu(gu[..., :f]) * gu[..., f:], down, dtype)
 
 
-# Rows of :func:`sparse_mlp`'s sorted buffer over the picks an even routing
-# sends this chip, as (numerator, denominator).  On the v5e at granite's
-# prompt chunk (512 tokens x top 10, 18 of 72 experts held, hidden 4096,
-# width 768; my chip runs, PR 51): sixteen seeded routings sent 1 245-1 328
-# picks where even is 1 280, and what is around the grouped matmuls costs by
-# the row (placing 125 us and bringing back 221 us at 2 048 rows) while the
-# matmuls themselves do not (768 / 771 / 808 us at 1 792 / 2 048 / 5 120
-# rows: they walk the groups, not the buffer).  5 / 4 would save ~40 us a
-# layer and walk twice at a load 9 % over even; 3 / 2 walks once up to 50 %.
-BUFFER_HEADROOM = (3, 2)
-# ... and the picks from which the buffer is cut at all: a forward of fewer
-# (every cell's decode forward: 1 280 picks of granite's 128 slots) keeps a
+# The picks from which :func:`sparse_mlp`'s buffer is cut at all (its rows
+# are models/moe.py ``buffer_rows``, the rule the trained layer shares, with
+# the readings that chose 3 / 2): a forward of fewer (every cell's decode
+# forward: 1 280 picks of granite's 128 slots) keeps a
 # buffer of every pick, in line, with no ``cond`` behind it.  With granite's
 # decode buffer cut to 512 rows ``serve-rag-ssm`` read 2 137-2 168 tokens/s
 # in seven runs and 1 734 and 1 965 in two more, their decode forwards at
@@ -585,17 +577,11 @@ WALKED_FROM = 2048
 
 def _buffer_rows(picks: int, held: int, num_experts: int) -> int:
     """Rows of :func:`sparse_mlp`'s sorted buffer for ``picks`` (tokens x
-    ``top_k``) of which an even routing sends ``held / num_experts`` here:
-    the smallest multiple of the grouped matmul's row tile not under
-    ``BUFFER_HEADROOM`` times that, and never more than ``picks`` (all of
-    them where every expert is held, and in a forward of fewer than
-    ``WALKED_FROM`` picks)."""
-    num, den = BUFFER_HEADROOM
-    tile = GMM_TILING[0]
+    ``top_k``): models/moe.py ``buffer_rows``, and every pick in a forward
+    of fewer than ``WALKED_FROM``."""
     if picks < WALKED_FROM:
         return picks
-    rows = -(-num * picks * held // (den * num_experts))
-    return min(picks, -(-rows // tile) * tile)
+    return buffer_rows(picks, held, num_experts)
 
 
 def sparse_mlp(lp, h, cfg: LlamaConfig, dtype, counted):

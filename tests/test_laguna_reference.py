@@ -282,6 +282,38 @@ def test_accelerate_exports_the_held_picks_of_every_microbatch():
         float(stats["moe_load_max"]))
 
 
+def test_accelerate_sums_the_overflowed_layers_of_every_microbatch(
+        monkeypatch):
+    """The same model with a sorted buffer of 8 rows (PR 57: a share's
+    compact buffer, here far under what the routing sends it): every
+    sparse layer walks the rest behind its ``cond``, under the scan and
+    the remat, and the step's ``moe_overflow_layers`` is the SUM over the
+    microbatches of the layers that did."""
+    monkeypatch.setattr(moe, "buffer_rows",
+                        lambda picks, held, experts: min(picks, 8))
+    conf = _tiny_conf(num_hidden_layers=3)
+    cfg = _system(conf)
+    model = LlamaModel(cfg)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0,
+                             cfg.vocab_size).astype(jnp.int32)
+    res = accelerate(
+        model, config=AccelerateConfig(
+            mesh_spec=MeshSpec.for_device_count(1), grad_accum_steps=2),
+        batch_shape=(2, 32), devices=jax.devices()[:1])
+    state = res.init_fn(jax.random.PRNGKey(0))
+    _, sown = model.apply({"params": state.params}, ids,
+                          mutable=["moe_losses"])
+    stats = moe.routing_stats(sown["moe_losses"])
+    sparse = sum(s.mlp == "sparse" for s in cfg.layer_specs)
+    assert float(stats["moe_overflow_layers"]) == sparse > 0
+    _, metrics = res.train_step(state, {"input_ids": jnp.stack([ids, ids])})
+    assert float(metrics["moe_overflow_layers"]) == 2 * sparse
+    assert float(metrics["moe_picks_held"]) == 2 * float(
+        stats["moe_picks_held"])
+    assert np.isfinite(float(metrics["loss"]))
+    assert np.isfinite(float(metrics["grad_norm"]))
+
+
 def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
     """4 shares of 16 experts: the routed parts of all shares, and the
     shared expert counted ONCE, are what the uncut reference gives for

@@ -312,6 +312,12 @@ _TREES = {   # leaves, and the digest of the listing below, as of PR 31
 _DENSE_JAXPR = "192dff4570b869795eca753f2a33f77e6851ad454cf74e49e881807417c572a6"
 
 
+# ... and a tiny sparse one's whose layers hold EVERY expert, with each
+# equation's scopes (``name_stack``): taken on the parent of PR 57
+# (97b77cf), to the letter (``train-moe-dropless`` runs this layer)
+_ALL_HELD_JAXPR = "5fc92c02ef859b47cb161e79ad5e952b44bbcfc32b57a8c90974a1cb380500e3"
+
+
 def _digest(text):
     return hashlib.sha256(text.encode()).hexdigest()
 
@@ -360,6 +366,31 @@ def test_dense_model_traces_what_it_did(tmp_path):
     (tmp_path / "dense_jaxpr.txt").write_text(text)
     assert _digest(text) == _DENSE_JAXPR, \
         f"jax {jax.__version__}; the trace: {tmp_path / 'dense_jaxpr.txt'}"
+
+
+def test_a_layer_that_holds_every_expert_traces_what_it_did(tmp_path):
+    """PR 57 gave a layer that holds a SHARE of its experts a compact
+    sorted buffer; one that holds them all keeps the buffer of every pick
+    and no ``cond``: the traced gradient of a tiny sparse scanned model,
+    the kernels' own jaxprs and every equation's scopes in it (the
+    benchmark reads device time by them), is the one of the tree before.
+    Re-pinned like ``_DENSE_JAXPR``, by a change that means to move it."""
+    from dlrover_tpu.accel.accelerate import default_loss_fn
+
+    cfg = LlamaConfig.tiny(scan_layers=True, remat=True, num_layers=3,
+                           num_experts=4, moe_top_k=2, dtype=jnp.bfloat16,
+                           param_dtype=jnp.bfloat16)
+    model = LlamaModel(cfg)
+    ids = jnp.zeros((2, 32), jnp.int32)
+    params = jax.eval_shape(
+        lambda: nn.unbox(model.init(jax.random.PRNGKey(0), ids))["params"])
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: default_loss_fn(model)(
+        p, {"input_ids": ids})[0]))(params)
+    text = re.sub(r"0x[0-9a-f]+", "0x", jaxpr.pretty_print(name_stack=True))
+    assert "pallas_call" in text and "moe_dispatch" in text
+    (tmp_path / "all_held_jaxpr.txt").write_text(text)
+    assert _digest(text) == _ALL_HELD_JAXPR, \
+        f"jax {jax.__version__}; the trace: {tmp_path / 'all_held_jaxpr.txt'}"
 
 
 def test_flash_checkpoint_of_the_sliced_model_restores(tmp_path,
