@@ -165,7 +165,9 @@ class LlamaConfig:
     v_head_dim: int = 0
     # a learned selection of keys (``index_topk`` 0 = none): an indexer of
     # ``index_n_heads`` heads of ``index_head_dim`` scores every key
-    # behind a query, and the query attends to the ``index_topk`` largest
+    # behind a query, and the query attends to the ``index_topk`` largest.
+    # Its queries come from the query's bottleneck in a latent layer and
+    # from the layer's normed input in a grouped-query one.  Served only
     index_n_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
@@ -330,10 +332,18 @@ class LlamaConfig:
     def latent_dims(self, spec: LayerSpec) -> Tuple[int, int, bool]:
         """``(latent rank, nope size, whether the indexer runs)`` of a
         latent-attention layer: the layer's own where it says so, else the
-        config's."""
+        config's.  The third is a grouped-query layer's too."""
         return (spec.kv_lora_rank or self.kv_lora_rank,
                 spec.qk_nope_head_dim or self.qk_nope_head_dim,
                 bool(self.index_topk and spec.indexer))
+
+    def _indexer_params(self, query_from: int) -> int:
+        """An indexer whose queries are projected from ``query_from``
+        values (the query bottleneck's rank, or the hidden size): ``W_iq``,
+        ``W_ik``, the key norm's scale and bias, the heads' weights."""
+        h, i = self.hidden_size, self.index_head_dim
+        return (query_from * self.index_n_heads * i + h * i + 2 * i
+                + h * self.index_n_heads)
 
     def layer_params(self, spec: LayerSpec) -> int:
         """Parameters of one layer as this device holds it."""
@@ -368,11 +378,11 @@ class LlamaConfig:
                  + c * heads * (nope + self.v_head_dim)
                  + heads * self.v_head_dim * h + 2 * h)
             if indexed:
-                i = self.index_head_dim
-                n += (q * self.index_n_heads * i + h * i + 2 * i
-                      + h * self.index_n_heads)
+                n += self._indexer_params(q)
         else:
             n = h * d * (spec.num_heads * 2 + self.num_kv_heads * 2) + 2 * h
+            if self.latent_dims(spec)[2]:
+                n += self._indexer_params(h)
         if self.attn_head_gate and spec.mixer == "attn":
             n += h * spec.num_heads
         if self.qk_norm and spec.mixer == "attn":
@@ -862,6 +872,60 @@ class LlamaConfig:
         return cls(**base)
 
     @classmethod
+    def keye_vl2_30b_a3b(cls, **kw) -> "LlamaConfig":
+        """Kwai-Keye/Keye-VL-2.0-30B-A3B (``KeyeVL2``), its LANGUAGE MODEL as
+        its config.json has it: 48 identical layers, 32 query / 4 KV heads
+        of 128 with an RMSNorm over each head of q and k (one scale for all
+        query heads, one for all key heads), RoPE at theta 1e7 over the
+        whole head (M-RoPE: a text token's three position streams are
+        equal, which is plain RoPE), and inside every layer an indexer
+        (``sa_config``: 16 heads of 64, ONE index key a token) that picks
+        the 2048 keys a query attends to; every MLP 128 softmax-routed
+        experts of 768, 8 a token, weights over the picks' sum, no shared
+        expert; vocabulary 151936, untied.  Served, not trained (a
+        selection has no training loss here).  ``num_layers`` cuts the
+        depth; ``moe_experts_held`` and ``vocab_size`` give one chip its
+        share.
+
+        Assumed, where the config names a mechanism and not its equation
+        (``perfbench/configs/keye-vl2-30b-a3b-serve.json``): the QK-norm,
+        the indexer's projections from the layer's normed input, its key
+        norm a LayerNorm with scale and bias, all 64 of its dimensions
+        rotated (halves paired, as the heads), its head weights scaled
+        ``(16 x 64)^-0.5``; the vision tower and the image tokens' two
+        further position streams are left out."""
+        base = dict(
+            vocab_size=151936,
+            hidden_size=2048,
+            intermediate_size=6144,
+            num_layers=48,
+            num_heads=32,
+            num_kv_heads=4,
+            head_dim=128,
+            max_seq_len=262144,
+            rope_theta=10000000.0,
+            rms_norm_eps=1e-6,
+            qk_norm=True,
+            qk_norm_kind="head",
+            index_n_heads=16,
+            index_head_dim=64,
+            index_topk=2048,
+            num_experts=128,
+            moe_top_k=8,
+            moe_norm_topk_prob=True,
+            moe_intermediate_size=768,
+            moe_score_fn="softmax",
+            moe_shared_width=0,
+            moe_per_expert_init=True,
+            moe_aux_loss_coef=0.0,
+            moe_z_loss_coef=0.0,
+            scan_layers=False,
+            remat=False,
+        )
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
     def from_preset(
         cls, name: str, num_layers: int = 0, **kw
     ) -> "LlamaConfig":
@@ -895,7 +959,7 @@ class LlamaConfig:
 #: presets the entry points (examples/, the serving worker) can name
 PRESETS = ("tiny", "llama2_7b", "olmoe_1b_7b", "laguna_xs2", "glm5",
            "sarvam_105b", "kimi_linear_48b", "dots3_note",
-           "granite_4_h_small", "lfm2_8b_a1b")
+           "granite_4_h_small", "lfm2_8b_a1b", "keye_vl2_30b_a3b")
 
 
 def resolve_remat_policy(name: str):
@@ -1491,6 +1555,13 @@ class LlamaModel(nn.Module):
                 f"residual_mult={cfg.residual_mult}, attn_scale="
                 f"{cfg.attn_scale}): they are served only "
                 "(serving/latent.py's loop of layer kinds)")
+        if cfg.index_topk and not cfg.kv_lora_rank:
+            raise NotImplementedError(
+                "LlamaModel's grouped-query block has no indexer "
+                f"(index_topk={cfg.index_topk}): a learned selection of keys "
+                "is served only (serving/latent.py _gqa_layer).  Missing: the "
+                "selection's own training loss, which aligns the index "
+                "scores with the attention they stand for (ROADMAP Reach A5)")
         if cfg.kv_lora_rank or cfg.moe_first_dense:
             raise NotImplementedError(
                 "LlamaModel trains the grouped-query block and the gated "

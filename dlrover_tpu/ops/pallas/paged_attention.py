@@ -86,7 +86,24 @@ Layout contract (matches serving/paged.py):
   table    [B, MB] int32    per-slot block lists (0 = trash block)
   lengths  [B]    int32     visible keys per slot (= position + 1;
                             0 = the slot's output is not wanted)
+  bias     [B, n] f32       optional: 0 where the slot attends key row s,
+                            -inf elsewhere (a learned selection of keys,
+                            ``serving/latent.py _gqa_layer``; rows behind
+                            ``n`` are nobody's).  None: every row behind
+                            the length, and the call has no such operand
+                            at all: the kernel every model without a
+                            selection compiles is the one it compiled
 Returns [B, H, D] fp32.
+
+Under a ``bias`` the kernel is MASKED-DENSE: it streams every live page as
+it does without one and the rows nobody chose leave the running softmax as
+it was (a selection of 2 048 of 24 k rows wants 8 % of the bytes it reads;
+gathering the chosen rows alone is ROADMAP Reach A12).  The wrapper lays
+the slot's row of the bias out as the score tile's columns lie (a key
+row's KV heads side by side), a group a sublane row, and the slot's whole
+block rides the pipeline beside its query.  Such a call is named apart
+(``SELECTED_ATTENTION``), so that a device trace tells it from the
+unmasked one.
 
 Only a slot's LIVE pages stream: each program's group loop runs
 ``min(ceil(length / rows), groups)`` times (``rows`` = one group's key
@@ -133,6 +150,12 @@ _LANES = 128
 #: 1 % and 6-11 % faster: PERF.md section 7 (d))
 GROUP_ROWS = 256
 
+#: the kernel's instruction in a device trace (``<name>.<n>``): the call
+#: under a selection's ``bias`` has a name no reader of the unmasked one
+#: matches
+DECODE_ATTENTION = "paged_decode_attention"
+SELECTED_ATTENTION = "paged_selected_attention"
+
 #: Why the packed-int4 variant is refused on a TPU (compiled for a
 #: described v5e, PR 21).  The pool's minor dimension is D//2 = 64, and
 #: Mosaic accepts a DMA slice only of a 128-aligned minor dimension.
@@ -160,14 +183,15 @@ def _decode_kernel(
     *args,
     block_size: int, pages: int, num_groups: int, capacity: int,
     kv_heads: int, group: int, head_dim: int,
-    quant: bool, packed: bool, scale: float,
+    quant: bool, packed: bool, scale: float, masked: bool = False,
 ):
     if quant:
-        (q_ref, k_hbm, v_hbm, ks_ref, vs_ref, o_ref,
-         kb, vb, sem, buf_ref) = args
+        q_ref, k_hbm, v_hbm, ks_ref, vs_ref, *rest = args
     else:
-        q_ref, k_hbm, v_hbm, o_ref, kb, vb, sem, buf_ref = args
+        q_ref, k_hbm, v_hbm, *rest = args
         ks_ref = vs_ref = None
+    bias_ref = rest[0] if masked else None
+    o_ref, kb, vb, sem, buf_ref = rest[masked:]
 
     b = pl.program_id(0)
     slots = pl.num_programs(0)
@@ -272,9 +296,18 @@ def _decode_kernel(
         s = jax.lax.dot_general(q, tile(kb, buf), (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = scaled(s, ks_ref, g) * scale
+        if masked:
+            # the group's row of the slot's bias, over every head: minus
+            # infinity where nobody chose the key, whose exp is exactly 0
+            # against the finite running maximum
+            s = s + bias_ref[0, pl.ds(g, 1), :]
         # a live group's first row is visible to every head, so the
         # running maximum is finite from the first group on and a masked
-        # column's exp is exactly 0
+        # column's exp is exactly 0.  (Under a bias a group may hold no
+        # chosen row: the maximum then stays at its floor, the columns
+        # behind the length count as 1 each, and the first chosen row's
+        # ``alpha`` of exactly 0 takes them out again: every slot with a
+        # key has a chosen row.)
         s = jnp.where(key_row < length - g * rows, s, _NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
@@ -339,6 +372,7 @@ def paged_decode_attention(
     pages_per_block: Optional[int] = None,  # None: GROUP_ROWS of rows
     interpret: bool = False,
     scale: Optional[float] = None,     # None: head_dim ** -0.5
+    bias: Optional[jax.Array] = None,  # [B, n] f32: 0 chosen, -inf not
 ) -> jax.Array:
     b, h, d = q.shape
     nb, bs, kv, dc = k_pool.shape
@@ -370,6 +404,7 @@ def paged_decode_attention(
         capacity=mb * bs, kv_heads=kv, group=g, head_dim=d,
         quant=quant, packed=packed,
         scale=float(d ** -0.5 if scale is None else scale),
+        masked=bias is not None,
     )
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [pl.BlockSpec((1, kv, g, d), q_map), any_spec, any_spec]
@@ -395,6 +430,20 @@ def paged_decode_attention(
         s_spec = pl.BlockSpec((1, num_groups, tiles, _LANES), q_map)
         in_specs += [s_spec, s_spec]
         operands += [on_lanes(k_scale), on_lanes(v_scale)]
+    if bias is not None:
+        # the slot's row of the bias as a group's score columns lie (row,
+        # then KV head), a group a sublane row; rows behind the bias's own
+        # width (the table's padding to whole groups) are nobody's
+        rows = p_n * bs
+        width = num_groups * rows
+        bias = bias.astype(jnp.float32)[:, :width]
+        bias = jnp.pad(bias, ((0, 0), (0, width - bias.shape[1])),
+                       constant_values=-jnp.inf)
+        in_specs.append(pl.BlockSpec(
+            (1, num_groups, rows * kv),
+            lambda bi, table_ref, lengths_ref: (bi, 0, 0)))
+        operands.append(jnp.repeat(bias, kv, axis=1).reshape(
+            b, num_groups, rows * kv))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -416,8 +465,9 @@ def paged_decode_attention(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         # the name of the kernel's instruction in a device trace
-        # (``paged_decode_attention.<n>``), whatever wraps the call
-        name="paged_decode_attention",
+        # (``paged_decode_attention.<n>``), whatever wraps the call; a
+        # call under a selection's bias has a name of its own
+        name=DECODE_ATTENTION if bias is None else SELECTED_ATTENTION,
     )(table.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
     return out.reshape(b, h, d)
 
@@ -432,6 +482,7 @@ def gather_reference(
     lengths: jax.Array,  # [B]
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
+    bias: Optional[jax.Array] = None,   # [B, n] f32 (0 chosen, -inf not)
 ) -> jax.Array:
     """The fused-gather path the engine's ``attention_impl="xla"``
     runs, as a standalone function: materialize the dense (dequantized)
@@ -464,6 +515,9 @@ def gather_reference(
     ) / jnp.sqrt(float(d))
     key_pos = jnp.arange(ck.shape[1])
     mask = key_pos[None, :] < lengths[:, None]          # [B, L]
+    if bias is not None:        # the rows a selection chose, of the live
+        n = min(bias.shape[1], mask.shape[1])
+        mask &= jnp.zeros_like(mask).at[:, :n].set(bias[:, :n] == 0)
     scores = jnp.where(mask[:, None, None, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum(

@@ -165,7 +165,10 @@ class EngineStats:
     #                               latent-attention model, with a
     #                               learned selection or none: one
     #                               layer's latent rows
-    #                               (mla_decode_attention)
+    #                               (mla_decode_attention); of a
+    #                               grouped-query model under a
+    #                               selection: every live K/V row, the
+    #                               mask's rows among them
     # a model with a learned selection of keys (LlamaConfig.index_topk),
     # summed over queries (decode forwards and prefill chunks alike) and
     # over nothing else: one layer's rows, as every layer reads the same
@@ -767,8 +770,13 @@ class InferenceEngine:
                     "watch_slot": jnp.asarray(-1, jnp.int32),
                 }
                 if cfg.index_topk:
+                    # an index key a token beside the layer's rows, of a
+                    # latent layer or a grouped-query one: under the one
+                    # block table, shared and copied with its blocks
+                    from dlrover_tpu.serving.latent import index_row_width
+
                     self._cache["index_pool"] = [
-                        jnp.zeros(shape + (cfg.index_head_dim,), cfg.dtype)
+                        jnp.zeros(shape + (index_row_width(cfg),), cfg.dtype)
                         for s in paged_specs if cfg.latent_dims(s)[2]]
                 if store is not None:
                     g = store.geometry
@@ -891,11 +899,12 @@ class InferenceEngine:
         # live key blocks one row after another anyway, and one group size
         # is one program to compile, not max_slots)
         self._prefill_group = 1 if self._kinds else self.max_slots
-        # names of the two per-layer pool lists a bucketed prefill's
-        # results are scattered into
-        self._pool_names = ("k_pool", "v_pool") if not self._latent else (
-            ("latent_pool", "index_pool") if cfg.index_topk
-            else ("latent_pool",))
+        # names of the per-layer pool lists a bucketed prefill's results
+        # are scattered into (a latent model's) and a diverging sequence's
+        # blocks are copied in (every model's)
+        self._pool_names = (("latent_pool",) if self._latent
+                            else ("k_pool", "v_pool")) + (
+            ("index_pool",) if self._kinds and cfg.index_topk else ())
         # paged decode attention: gather (xla) vs fused kernel
         # (pallas), resolved ONCE at build — "auto" measures both on
         # this engine's real pool geometry and picks the faster
@@ -2097,7 +2106,7 @@ class InferenceEngine:
         layer's rows.  Returns the three for the dispatch's span; {} for
         a model without a selection, whose spans carry what they did."""
         cfg = self.cfg
-        if not (self._latent and cfg.index_topk):
+        if not (self._kinds and cfg.index_topk):
             return {}
         from dlrover_tpu.ops.pallas.paged_index import scanned_rows
         from dlrover_tpu.serving.latent import KEY_BLOCK_PAGES

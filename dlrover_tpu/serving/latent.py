@@ -24,6 +24,31 @@ blocks for a run, the softmax scale the config's (``attn_scale``).  The
 embedding and every residual branch are scaled where the config says so
 (``embedding_mult``, ``residual_mult``).
 
+A grouped-query layer may carry the learned selection too
+(``LlamaConfig.keye_vl2_30b_a3b``: ``index_topk`` with no
+``kv_lora_rank``), and a QK-norm a head (``qk_norm_kind="head"``: each
+head of q and of k RMS-normed before rotation, one scale for all query
+heads and one for all key heads).  The INDEXER HAS TWO SOURCES OF QUERIES:
+a latent layer projects them from the query's bottleneck, ``q_i = W_iq
+c_q`` (:func:`_projections`), a grouped-query layer, which has no
+bottleneck, from the layer's normed input, ``q_i = W_iq h``
+(:func:`_gqa_index`); keys, head weights, scores and the choice are one
+(:func:`_select_decode`, :func:`_select_run`, :func:`_chosen`).  Such a
+layer keeps its index keys in an ``index_pool`` of its own beside
+``k_pool`` / ``v_pool`` under the SAME block table (rows of
+:func:`index_row_width` values: whole lanes), shared and copied with its
+K/V blocks, and attends UNDER the selection, masked-dense: decode streams
+every live K/V page through ``paged_decode_attention`` with the chosen
+rows as a float32 ``bias`` (the kernel names that call
+``paged_attention.SELECTED_ATTENTION`` in a device trace; a table
+of no more rows than a query may choose runs the unmasked kernel, and a
+model without an indexer traces no bias at all), a run walks its live key
+blocks under the mask (:func:`_gqa_attend_run`).  Of each K/V row streamed
+at 24 k under a selection of 2 048, 8 % is wanted: attending the chosen
+rows alone is ROADMAP Reach A12.  The WITNESS of such a layer is a latent
+layer's: the rows each query attended to, a layer (decode: positions; a
+run: the packed mask), beside the first sparse MLP's input and output.
+
 A model's latent layers may be of MORE THAN ONE KIND
 (``LlamaConfig.dots3_note``): each reads its own geometry from its
 ``LayerSpec`` (heads, latent rank, nope size, rotary embedding:
@@ -51,9 +76,11 @@ One layer, on its normed input ``h`` (positions ``t``, ``s``):
   value projection ``W_kvb,h[V]`` is applied to the attended latent, once
   a query.
 - *the indexer* (``index_topk`` > 0): ``q_i = W_iq c_q`` in
-  ``index_n_heads`` heads, ``k_i = LayerNorm(W_ik h)`` one row a token
+  ``index_n_heads`` heads (of a grouped-query layer ``W_iq h``), ``k_i =
+  LayerNorm(W_ik h)`` one row a token
   (cached beside the latent row), the
-  first ``rope`` dimensions of both rotated, ``w = W_iw h / sqrt(heads x
+  first ``rope`` dimensions of both rotated (of a grouped-query layer all
+  of them, halves paired), ``w = W_iw h / sqrt(heads x
   size)`` in float32; ``I[t, s] = sum_h w[t, h] relu(q_i[t, h] . k_i[s])``
   for ``s <= t``, and query ``t`` attends to the ``index_topk`` largest
   only (to every key behind it while they are no more than that).
@@ -103,8 +130,7 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.models.llama import (LayerSpec, LlamaConfig, RopeSpec,
-                                      apply_rope, rope_frequencies,
-                                      rope_inverse_frequencies)
+                                      apply_rope, rope_inverse_frequencies)
 from dlrover_tpu.models.moe import buffer_rows, grouped_matmul, route
 from dlrover_tpu.serving.model import _lm_head, _mm, _rmsnorm
 from dlrover_tpu.serving.paged import (ring_table, scatter_ring,
@@ -129,6 +155,21 @@ def latent_row_width(cfg: LlamaConfig,
     The absorbed query carries zeros there too, so no score moves."""
     c = cfg.latent_dims(spec)[0] if spec is not None else cfg.kv_lora_rank
     return -(-(c + cfg.qk_rope_head_dim) // _LANES) * _LANES
+
+
+def index_row_width(cfg: LlamaConfig) -> int:
+    """Values of one row of the index-key pool.  Of a GROUPED-QUERY
+    model: ``index_head_dim`` and zeros up to whole 128-lane tiles
+    (Keye-VL-2.0: 64 -> 128).  A minor dimension of 64 is no DMA slice the
+    chip's compiler takes (``ops/pallas/paged_attention.py
+    INT4_REFUSAL``), and the TPU keeps a ``[blocks, 128, 64]`` bfloat16
+    array in tiles of 128 lanes anyway: the padded pool holds what the
+    unpadded one would.  The index queries carry zeros there too, so no
+    score moves.  Of a latent model: ``index_head_dim`` as it is (128 in
+    every served one; :func:`_projections` writes unpadded rows)."""
+    if cfg.kv_lora_rank:
+        return cfg.index_head_dim
+    return -(-cfg.index_head_dim // _LANES) * _LANES
 
 
 def _layernorm(x, scale, bias, eps=1e-6):
@@ -278,6 +319,63 @@ def _chosen(keys: jax.Array, k: int, dead: jax.Array) -> jax.Array:
     return (keys >= _kth_largest(keys, k)[:, None]) & (keys > dead)
 
 
+def _live_blocks(q_pos, n_real, table_pages: int, bs: int, pages: int):
+    """Key blocks (``pages`` pages of ``bs`` rows) a run walks: up to its
+    last REAL query's position, never more than the table holds."""
+    kb = pages * bs
+    last = q_pos[-1] if n_real is None else q_pos[n_real - 1]
+    return jnp.minimum((last + kb) // kb, table_pages // pages)
+
+
+def _select_run(q_i, w, q_pos, index_pool, table_row, bs: int,
+                cfg: LlamaConfig, pages: int, n_real, n_live):
+    """The keys each query of a run of ONE sequence attends to, [K, MB x
+    bs] bool (``table_row`` [MB], a multiple of ``pages``; ``bs`` rows a
+    page): with an indexer (``q_i`` [K, Hi, Di], ``w`` [K, Hi]) and a
+    table of more rows than a query may choose, the index scores of every
+    live key block (``index_pool`` [NB, bs, Di]) and of each query its
+    ``index_topk`` largest (:func:`_chosen`); otherwise every key behind
+    the query.  A query at or behind ``n_real`` (None: all are real)
+    chooses nothing, and only the first ``n_live`` key blocks, those up to
+    the last real query (:func:`_live_blocks`), are scored.  The one
+    selection of a latent layer's run and a grouped-query layer's."""
+    klen = q_pos.shape[0]
+    kb = pages * bs                               # keys a block
+    width = table_row.shape[0] // pages * kb
+
+    def real_only(chosen):
+        if n_real is None:
+            return chosen
+        return chosen & (jnp.arange(klen) < n_real)[:, None]
+
+    def causal(j):
+        key_pos = j * kb + jnp.arange(kb)
+        return key_pos[None, :] <= q_pos[:, None]            # [K, kb]
+
+    if q_i is None or width <= cfg.index_topk:
+        # no more keys than a query may choose: plain causal attention
+        return real_only(jnp.arange(width)[None, :] <= q_pos[:, None])
+
+    from dlrover_tpu.ops.pallas.paged_index import index_scores
+
+    with device_scope("dsa_index"):
+        def score_block(j, keys):
+            ids = jax.lax.dynamic_slice_in_dim(table_row, j * pages, pages)
+            block = jnp.take(index_pool, ids, axis=0).reshape(
+                kb, index_pool.shape[-1])
+            s = index_scores(q_i, w, block)
+            s = _orderable(jnp.where(causal(j), s, _NEG_INF))
+            return jax.lax.dynamic_update_slice_in_dim(
+                keys, s, j * kb, axis=1)
+
+        dead = _orderable(jnp.full((), _NEG_INF, jnp.float32))
+        keys = jax.lax.fori_loop(
+            0, n_live, score_block,
+            jnp.full((klen, width), dead, jnp.uint32))
+    with device_scope("dsa_select"):
+        return real_only(_chosen(keys, cfg.index_topk, dead))
+
+
 def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
                 cfg: LlamaConfig, pages: int, impl: str = "xla",
                 interpret: bool = False, n_real=None,
@@ -305,44 +403,11 @@ def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
     c = cfg.latent_dims(_attn_spec(cfg, spec))[0]
     bs = latent_pool.shape[1]
     kb = pages * bs                               # keys a block
-    n_blocks = table_row.shape[0] // pages
-    width = n_blocks * kb
-    last = q_pos[-1] if n_real is None else q_pos[n_real - 1]
-    n_live = jnp.minimum((last + kb) // kb, n_blocks)
+    n_live = _live_blocks(q_pos, n_real, table_row.shape[0], bs, pages)
     scale = _softmax_scale(cfg, spec)
 
-    def real_only(chosen):
-        if n_real is None:
-            return chosen
-        return chosen & (jnp.arange(klen) < n_real)[:, None]
-
-    def block(pool, j):
-        ids = jax.lax.dynamic_slice_in_dim(table_row, j * pages, pages)
-        return jnp.take(pool, ids, axis=0).reshape(kb, pool.shape[-1])
-
-    def causal(j):
-        key_pos = j * kb + jnp.arange(kb)
-        return key_pos[None, :] <= q_pos[:, None]            # [K, kb]
-
-    if q_i is not None and width > cfg.index_topk:
-        from dlrover_tpu.ops.pallas.paged_index import index_scores
-
-        with device_scope("dsa_index"):
-            def score_block(j, keys):
-                s = index_scores(q_i, w, block(index_pool, j))
-                s = _orderable(jnp.where(causal(j), s, _NEG_INF))
-                return jax.lax.dynamic_update_slice_in_dim(
-                    keys, s, j * kb, axis=1)
-
-            dead = _orderable(jnp.full((), _NEG_INF, jnp.float32))
-            keys = jax.lax.fori_loop(
-                0, n_live, score_block,
-                jnp.full((klen, width), dead, jnp.uint32))
-        with device_scope("dsa_select"):
-            chosen = real_only(_chosen(keys, cfg.index_topk, dead))
-    else:
-        # no more keys than a query may choose: plain causal attention
-        chosen = real_only(jnp.arange(width)[None, :] <= q_pos[:, None])
+    chosen = _select_run(q_i, w, q_pos, index_pool, table_row, bs, cfg,
+                         pages, n_real, n_live)
 
     with device_scope("mla_attn"):
         if impl == "pallas":
@@ -358,7 +423,9 @@ def _attend_run(qq, q_i, w, q_pos, latent_pool, index_pool, table_row,
 
         def attend_block(j, carry):
             m, l, acc = carry
-            lat = block(latent_pool, j)                       # [kb, C+R]
+            ids = jax.lax.dynamic_slice_in_dim(table_row, j * pages, pages)
+            lat = jnp.take(latent_pool, ids, axis=0).reshape(
+                kb, latent_pool.shape[-1])                    # [kb, C+R]
             s = jnp.einsum("khc,sc->khs", qq, lat.astype(qq.dtype),
                            preferred_element_type=jnp.float32) * scale
             keep = jax.lax.dynamic_slice_in_dim(chosen, j * kb, kb, axis=1)
@@ -390,6 +457,33 @@ def _queries_per_tile(heads: int) -> int:
     return max(8, min(QUERIES_PER_TILE, QUERIES_PER_TILE * 64 // heads))
 
 
+def _select_decode(q_i, w, index_pool, table, lengths, cfg: LlamaConfig,
+                   impl: str, interpret: bool):
+    """The keys one query a slot attends to, [B, rows] bool (``q_i`` [B,
+    Hi, Di], ``w`` [B, Hi], ``lengths`` [B] the keys each slot sees): the
+    index scores of every slot's LIVE pages (the ``paged_index_scores``
+    kernel with ``impl == "pallas"``, its gather otherwise) and of each
+    slot its ``index_topk`` largest (:func:`_chosen`).  None where a query
+    attends to every row behind it: no indexer, or a table of no more rows
+    than a query may choose.  The one selection of a latent layer's decode
+    forward and a grouped-query layer's."""
+    from dlrover_tpu.ops.pallas import paged_index
+
+    if q_i is None or table.shape[1] * index_pool.shape[1] <= cfg.index_topk:
+        return None
+    with device_scope("dsa_index"):
+        if impl == "pallas":
+            scores = paged_index.paged_index_scores(
+                q_i, w, index_pool, table, lengths, interpret=interpret)
+        else:
+            scores = paged_index.gather_index_scores(
+                q_i, w, index_pool, table, lengths)
+    with device_scope("dsa_select"):
+        return _chosen(
+            _orderable(scores), cfg.index_topk,
+            _orderable(jnp.full((), _NEG_INF, jnp.float32)))
+
+
 def _attend_decode(qq, q_i, w, latent_pool, index_pool, table, lengths,
                    cfg: LlamaConfig, impl: str, interpret: bool,
                    spec: Optional[LayerSpec] = None):
@@ -399,25 +493,13 @@ def _attend_decode(qq, q_i, w, latent_pool, index_pool, table, lengths,
     selection, [B, rows] bool (None where a query attends to every row
     behind it: a model with no selection, or a table of no more rows
     than a query may choose)."""
-    from dlrover_tpu.ops.pallas import mla_decode, paged_index
+    from dlrover_tpu.ops.pallas import mla_decode
 
-    mb, bs = table.shape[1], latent_pool.shape[1]
-    chosen = bias = None
     c, scale = cfg.latent_dims(_attn_spec(cfg, spec))[0], \
         _softmax_scale(cfg, spec)
-    if q_i is not None and mb * bs > cfg.index_topk:
-        with device_scope("dsa_index"):
-            if impl == "pallas":
-                scores = paged_index.paged_index_scores(
-                    q_i, w, index_pool, table, lengths, interpret=interpret)
-            else:
-                scores = paged_index.gather_index_scores(
-                    q_i, w, index_pool, table, lengths)
-        with device_scope("dsa_select"):
-            chosen = _chosen(
-                _orderable(scores), cfg.index_topk,
-                _orderable(jnp.full((), _NEG_INF, jnp.float32)))
-            bias = jnp.where(chosen, 0.0, _NEG_INF)
+    chosen = _select_decode(q_i, w, index_pool, table, lengths, cfg, impl,
+                            interpret)
+    bias = None if chosen is None else jnp.where(chosen, 0.0, _NEG_INF)
     with device_scope("mla_attn"):
         if impl == "pallas":
             o = mla_decode.mla_decode_attention(
@@ -744,19 +826,21 @@ def _state_mixer(kind: str, lp, h, state, conv, cfg: LlamaConfig, dtype,
 
 
 def _gqa_attend_run(q, q_pos, k_pool, v_pool, table_row, n_real,
-                    scale: float, pages: int):
+                    scale: float, pages: int, chosen=None):
     """A run of queries of ONE sequence against its cached K/V rows, this
     run's own among them: ``q`` [K, H, D], ``q_pos`` [K] ascending,
     ``table_row`` [MB] (a multiple of ``pages``).  Causal softmax attention
     with a running maximum over the key blocks up to the last REAL query's
     position (``n_real``: :func:`_attend_run`), never the whole table; a
-    query group a KV head, the cache not expanded.  [K, H, D] float32."""
+    query group a KV head, the cache not expanded.  Under a selection
+    (``chosen`` [K, MB x bs] bool, :func:`_select_run`: causal already) a
+    query attends to its chosen keys only, MASKED-DENSE: every live block
+    is scored and the rows nobody chose leave the softmax as it was.
+    [K, H, D] float32."""
     klen, heads, d = q.shape
     bs, kv = k_pool.shape[1:3]
     kb = pages * bs
-    n_blocks = table_row.shape[0] // pages
-    last = q_pos[-1] if n_real is None else q_pos[n_real - 1]
-    n_live = jnp.minimum((last + kb) // kb, n_blocks)
+    n_live = _live_blocks(q_pos, n_real, table_row.shape[0], bs, pages)
     qg = q.reshape(klen, kv, heads // kv, d)
 
     def block(pool, j):
@@ -767,7 +851,10 @@ def _gqa_attend_run(q, q_pos, k_pool, v_pool, table_row, n_real,
         m, l, acc = carry
         s = jnp.einsum("qkgd,skd->qkgs", qg, block(k_pool, j).astype(q.dtype),
                        preferred_element_type=jnp.float32) * scale
-        keep = (j * kb + jnp.arange(kb))[None, :] <= q_pos[:, None]
+        if chosen is None:
+            keep = (j * kb + jnp.arange(kb))[None, :] <= q_pos[:, None]
+        else:
+            keep = jax.lax.dynamic_slice_in_dim(chosen, j * kb, kb, axis=1)
         s = jnp.where(keep[:, None, None, :], s, _NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1))
         safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
@@ -787,48 +874,153 @@ def _gqa_attend_run(q, q_pos, k_pool, v_pool, table_row, n_real,
     return (acc / jnp.maximum(l, 1e-30)[..., None]).reshape(klen, heads, d)
 
 
-def _gqa_layer(lp, h, k_pool, v_pool, table, run_table, cfg: LlamaConfig,
-               spec: LayerSpec, dtype, positions, pos_k, lengths, n_real,
-               decode: bool, impl: str, interpret: bool):
+def _gqa_index(lp, h, cfg: LlamaConfig, spec: LayerSpec, pos, dtype):
+    """The indexer of a GROUPED-QUERY layer on its normed input ``h`` [B,
+    K, E] at ``pos`` [B, K]: ``q_i = W_iq h`` [B, K, Hi, W], ``k_i =
+    LayerNorm(W_ik h)`` [B, K, W] (``W`` = :func:`index_row_width`: Di and
+    zeros), both rotated over ALL ``Di`` dimensions, halves paired, at
+    the layer's theta (``h`` where a latent layer's indexer has the
+    query bottleneck ``c_q``), and ``w = W_iw h x (Hi x Di)^-0.5`` [B, K,
+    Hi] float32."""
+    b, k = h.shape[:2]
+    hi, di = cfg.index_n_heads, cfg.index_head_dim
+    q_i = _mm(h, lp["iwq"], dtype).reshape(b, k, hi, di)
+    k_i = _layernorm(_mm(h, lp["iwk"], dtype), lp["ik_norm_scale"],
+                     lp["ik_norm_bias"])
+    if spec.rope.rotary_fraction:
+        angles = pos.astype(jnp.float32)[..., None] \
+            * rope_inverse_frequencies(spec.rope, di)
+        q_i = apply_rope(q_i, angles)
+        k_i = apply_rope(k_i[:, :, None], angles)[:, :, 0]
+    pad = index_row_width(cfg) - di
+    q_i = jnp.pad(q_i.astype(dtype), ((0, 0), (0, 0), (0, 0), (0, pad)))
+    k_i = jnp.pad(k_i.astype(dtype), ((0, 0), (0, 0), (0, pad)))
+    w = jnp.dot(h.astype(dtype), lp["iw"].astype(dtype),
+                preferred_element_type=jnp.float32) * float((hi * di) ** -0.5)
+    return q_i, k_i, w
+
+
+def _gqa_layer(lp, h, k_pool, v_pool, idx_pool, table, run_table,
+               cfg: LlamaConfig, spec: LayerSpec, dtype, positions, pos_k,
+               lengths, n_real, decode: bool, impl: str, interpret: bool):
     """A GROUPED-QUERY attention layer on ``h`` [B, K, E]: ``(y, k_pool,
-    v_pool)``, the block's output and the layer's pools with this
-    forward's rows written.  One query a slot over every slot with
-    ``impl == "pallas"`` is ``paged_decode_attention`` over each slot's
-    live pages; every other shape walks a row's live key blocks
-    (:func:`_gqa_attend_run`).  Rotated where the layer's ``RopeSpec`` says
-    so, scaled by the config's ``attn_scale`` where it states one."""
+    v_pool, idx_pool, chosen)``, the block's output, the layer's pools with
+    this forward's rows written and the keys each query attended to (None:
+    every key behind it).  One query a slot over every slot with ``impl ==
+    "pallas"`` is ``paged_decode_attention`` over each slot's live pages;
+    every other shape walks a row's live key blocks
+    (:func:`_gqa_attend_run`).  Each head of q and k RMS-normed under one
+    scale where the layer has them (``q_norm``, ``k_norm``), rotated where
+    the layer's ``RopeSpec`` says so, scaled by the config's ``attn_scale``
+    where it states one.
+
+    With an INDEXER (``idx_pool`` [NB, bs, :func:`index_row_width`], the
+    layer's index keys under the same block table; None: the layer has
+    none) a query attends to its ``index_topk`` chosen keys only, the
+    selection a latent layer makes (:func:`_select_decode`,
+    :func:`_select_run`) from index queries projected from ``h``.  Decode
+    on the chip streams every live K/V page under the mask of the chosen
+    rows (``paged_decode_attention`` with a ``bias``); a run walks its
+    live key blocks under it."""
     from dlrover_tpu.serving.model import _qkv
 
     b, klen, _ = h.shape
     d = cfg.head_dim_
     with device_scope("attn_proj"):
         q, k, v = _qkv(lp, h, cfg, dtype)
+        if "q_norm" in lp:             # a head at a time, before rotation
+            q = _rmsnorm(q, lp["q_norm"], cfg.rms_norm_eps)
+            k = _rmsnorm(k, lp["k_norm"], cfg.rms_norm_eps)
         if spec.rope.rotary_fraction:
-            angles = rope_frequencies(d, cfg.max_seq_len,
-                                      spec.rope.theta)[pos_k]
+            angles = pos_k.astype(jnp.float32)[..., None] \
+                * rope_inverse_frequencies(spec.rope, d)
             q, k = apply_rope(q, angles), apply_rope(k, angles)
+        q = q.astype(dtype)
     k_pool = scatter_tokens(k_pool, table, k.astype(k_pool.dtype), positions)
     v_pool = scatter_tokens(v_pool, table, v.astype(v_pool.dtype), positions)
     scale = float(d ** -0.5 if cfg.attn_scale is None else cfg.attn_scale)
-    with device_scope("paged_attn"):
-        if decode and impl == "pallas":
-            from dlrover_tpu.ops.pallas.paged_attention import (
-                paged_decode_attention,
-            )
+    chosen = None
+    if idx_pool is not None:
+        with device_scope("dsa_index"):
+            q_i, k_i, w = _gqa_index(lp, h, cfg, spec, pos_k, dtype)
+        idx_pool = scatter_tokens(idx_pool, table,
+                                  k_i.astype(idx_pool.dtype), positions)
+        o, chosen = _gqa_attend_selected(
+            q, q_i, w, k_pool, v_pool, idx_pool, table, run_table, cfg,
+            pos_k, lengths, n_real, scale, decode, impl, interpret)
+    else:
+        with device_scope("paged_attn"):
+            if decode and impl == "pallas":
+                from dlrover_tpu.ops.pallas.paged_attention import (
+                    paged_decode_attention,
+                )
 
-            o = paged_decode_attention(
-                q[:, 0], k_pool, v_pool, table, lengths, scale=scale,
-                interpret=interpret)[:, None]
-        else:
-            if decode:
-                run_table = _pad_table(table, KEY_BLOCK_PAGES)
-            o = jax.lax.map(
-                lambda a: _gqa_attend_run(a[0], a[1], k_pool, v_pool, a[2],
-                                          a[3], scale, KEY_BLOCK_PAGES),
-                (q, pos_k, run_table, n_real))
+                o = paged_decode_attention(
+                    q[:, 0], k_pool, v_pool, table, lengths, scale=scale,
+                    interpret=interpret)[:, None]
+            else:
+                if decode:
+                    run_table = _pad_table(table, KEY_BLOCK_PAGES)
+                o = jax.lax.map(
+                    lambda a: _gqa_attend_run(a[0], a[1], k_pool, v_pool,
+                                              a[2], a[3], scale,
+                                              KEY_BLOCK_PAGES),
+                    (q, pos_k, run_table, n_real))
     o = o.astype(dtype).reshape(b, klen, -1)
     with device_scope("attn_proj"):
-        return _mm(o, lp["wo"], dtype), k_pool, v_pool
+        return _mm(o, lp["wo"], dtype), k_pool, v_pool, idx_pool, chosen
+
+
+def _gqa_attend_selected(q, q_i, w, k_pool, v_pool, idx_pool, table,
+                         run_table, cfg: LlamaConfig, pos_k, lengths, n_real,
+                         scale: float, decode: bool, impl: str,
+                         interpret: bool):
+    """The attention of a grouped-query layer UNDER ITS SELECTION: ``(o
+    [B, K, H, D] float32, chosen)``.  Decode with ``impl == "pallas"``:
+    ``chosen`` [B, rows] from :func:`_select_decode` (None while the table
+    holds no more rows than a query may choose), then the decode kernel
+    over every slot's live pages under the mask.  Every other shape, a row
+    at a time: ``chosen`` [B, K, rows] from :func:`_select_run`, then the
+    walk of the row's live key blocks under it."""
+    bs = k_pool.shape[1]
+    if decode and impl == "pallas":
+        from dlrover_tpu.ops.pallas.paged_attention import (
+            paged_decode_attention,
+        )
+
+        chosen = _select_decode(q_i[:, 0], w[:, 0], idx_pool, table, lengths,
+                                cfg, impl, interpret)
+        with device_scope("paged_attn"):
+            bias = None if chosen is None else jnp.where(chosen, 0.0,
+                                                         _NEG_INF)
+            o = paged_decode_attention(
+                q[:, 0], k_pool, v_pool, table, lengths, scale=scale,
+                interpret=interpret, bias=bias)[:, None]
+        return o, chosen
+    if decode:
+        run_table = _pad_table(table, KEY_BLOCK_PAGES)
+
+    def row(a):
+        q_r, qi_r, w_r, pos_r, table_r, n_r = a
+        chosen = _select_run(qi_r, w_r, pos_r, idx_pool, table_r, bs, cfg,
+                             KEY_BLOCK_PAGES, n_r, _live_blocks(
+                                 pos_r, n_r, table_r.shape[0], bs,
+                                 KEY_BLOCK_PAGES))
+        with device_scope("paged_attn"):
+            return _gqa_attend_run(q_r, pos_r, k_pool, v_pool, table_r, n_r,
+                                   scale, KEY_BLOCK_PAGES, chosen), chosen
+
+    rows = (q, q_i, w, pos_k, run_table, n_real)
+    if q.shape[0] == 1:
+        # the engine's prompt chunks are one row a dispatch: no loop, and
+        # no copy of the row's [K, table rows] selection into a stack
+        o, chosen = jax.tree_util.tree_map(
+            lambda x: x[None],
+            row(jax.tree_util.tree_map(lambda x: x[0], rows)))
+    else:
+        with device_scope("paged_attn"):      # the loop's own copies
+            o, chosen = jax.lax.map(row, rows)
+    return o, (chosen[:, 0] if decode else chosen)
 
 
 def _residual(x, y, cfg: LlamaConfig):
@@ -879,6 +1071,30 @@ def _window_layer(lp, h, held, cfg: LlamaConfig, spec: LayerSpec, dtype,
             (qq, pos_k, slots, n_real))
     y = _attn_out(lp, o_lat, cfg, dtype, h, "swa_attn")
     return y, pool.reshape(held.shape)
+
+
+def _seen_selection(chosen, watch, decode: bool, rows: int, lengths,
+                    cfg: LlamaConfig, packed: bool = False):
+    """One layer's selection for the WITNESS: the watched row of
+    ``chosen`` (decode: [B, rows] or None, every row behind the length; a
+    run: [B, K, rows]).  Decode hands it back as positions
+    (:func:`_rows_of`), the watched slot's row alone, with room for the
+    rows that tie the ``index_topk``-th, which are chosen and attended
+    with it (a tie at the threshold is one query in a few thousand at 33 k
+    float32 scores: every window has some).  ``packed``: a run's mask as
+    ``jnp.packbits`` of it, a layer at a time (a grouped-query model's
+    layers: stacking eight layers' [512, 33 792] masks unpacked was 1.2 ms
+    of a 29 ms chunk under no scope; my chip run, PR 58)."""
+    seen_row = None if chosen is None else jnp.take(chosen, watch, axis=0)
+    if decode:
+        with device_scope("dsa_select"):
+            seen_row = _rows_of(
+                seen_row, jnp.take(lengths, watch), rows,
+                min(cfg.index_topk + _LANES, rows))
+    elif packed:
+        with device_scope("dsa_select"):
+            seen_row = jnp.packbits(seen_row, axis=-1)
+    return seen_row
 
 
 def _pad_table(table: jax.Array, pages: int) -> jax.Array:
@@ -988,13 +1204,21 @@ def verify_step(
             convs.append(conv)
             x = _residual(x, y, cfg)
         elif not cfg.kv_lora_rank:
-            y, k_new, v_new = _gqa_layer(
+            indexed = cfg.latent_dims(spec)[2]
+            y, k_new, v_new, idx, chosen = _gqa_layer(
                 lp, h, cache["k_pool"][len(k_pools)],
-                cache["v_pool"][len(v_pools)], table, run_table, cfg, spec,
-                dtype, positions, pos_k, lengths, n_real, decode,
-                attention_impl, kernel_interpret)
+                cache["v_pool"][len(v_pools)],
+                cache["index_pool"][len(index_pools)] if indexed else None,
+                table, run_table, cfg, spec, dtype, positions, pos_k,
+                lengths, n_real, decode, attention_impl, kernel_interpret)
+            if watch is not None and indexed:
+                selections.append(_seen_selection(
+                    chosen, watch, decode, table.shape[1] * k_new.shape[1],
+                    lengths, cfg, packed=True))
             k_pools.append(k_new)
             v_pools.append(v_new)
+            if idx is not None:
+                index_pools.append(idx)
             x = _residual(x, y, cfg)
         elif spec.window:
             y, held = _window_layer(
@@ -1035,20 +1259,9 @@ def verify_step(
                                           a[5], spec),
                     (qq, q_i, w, pos_k, run_table, n_real))
             if watch is not None and k_i is not None:
-                seen_row = None if chosen is None \
-                    else jnp.take(chosen, watch, axis=0)
-                if decode:
-                    # the watched slot's row alone, a layer; with room
-                    # for the rows that tie the ``index_topk``-th, which
-                    # are chosen and attended with it (a tie at the
-                    # threshold is one query in a few thousand at 33 k
-                    # float32 scores: every window has some)
-                    rows = table.shape[1] * lat.shape[1]
-                    with device_scope("dsa_select"):
-                        seen_row = _rows_of(
-                            seen_row, jnp.take(lengths, watch), rows,
-                            min(cfg.index_topk + _LANES, rows))
-                selections.append(seen_row)
+                selections.append(_seen_selection(
+                    chosen, watch, decode, table.shape[1] * lat.shape[1],
+                    lengths, cfg))
             y = _attn_out(lp, o_lat, cfg, dtype, h)
             if watch is not None and "window_ring" in cache \
                     and not latent_pools:
@@ -1108,7 +1321,9 @@ def verify_step(
             seen["logits"] = jnp.take(logits[:, 0], watch, axis=0)
         out_cache["witness"] = dict(seen, **(
             {"rows": chosen} if decode
-            else {"chosen_bits": jnp.packbits(chosen, axis=-1)}))
+            # (a grouped-query model's layers packed theirs one by one)
+            else {"chosen_bits": jnp.packbits(chosen, axis=-1)
+                  if cfg.kv_lora_rank else chosen}))
     elif watch is not None:
         out_cache["witness"] = dict(
             seen, logits=jnp.take(logits[:, 0], watch, axis=0))

@@ -134,15 +134,18 @@ def serving_params_from_llama(
             "with NO recurrent state beside it in serving/linear.py "
             "state_shapes, and its decode and prefill-chunk steps in "
             "serving/latent.py::_state_mixer (ROADMAP Reach A4)")
-    if cfg.qk_norm and not cfg.kv_lora_rank:
+    if cfg.qk_norm and not cfg.kv_lora_rank and (
+            cfg.qk_norm_kind != "head" or not cfg.layer_kinds):
         raise ValueError(
-            "the serving engine's grouped-query blocks have no QK-norm "
-            f"(qk_norm={cfg.qk_norm}, qk_norm_kind={cfg.qk_norm_kind!r}): "
-            "the model would be served as a different one.  Missing: the "
-            "norm over the projected query and key (the whole projection, "
-            "or each head under one scale) in "
-            "serving/model.py::_attn_proj and "
-            "serving/latent.py::_gqa_layer (ROADMAP Reach A3)")
+            "the serving engine's grouped-query blocks have no QK-norm of "
+            f"this kind (qk_norm={cfg.qk_norm}, qk_norm_kind="
+            f"{cfg.qk_norm_kind!r}, layer_kinds={cfg.layer_kinds}): the "
+            "model would be served as a different one.  Served: each head "
+            "under one scale ('head') behind the loop of layer kinds "
+            "(serving/latent.py::_gqa_layer).  Missing: the norm over the "
+            "whole projection ('projection') there, and either kind in the "
+            "dense decoder's own loop, serving/model.py::_attn_proj "
+            "(ROADMAP Reach A3)")
     attn = {dataclasses.replace(s, mlp="dense") for s in cfg.layer_specs
             if s.mixer == "attn"}
     if not cfg.kv_lora_rank and (
@@ -154,14 +157,24 @@ def serving_params_from_llama(
             "the serving engine's grouped-query layers are ONE kind of "
             "layer: full causal attention with one head count, "
             "rotated whole or not at all, no head gate (attn_head_gate="
-            f"{cfg.attn_head_gate}); this model describes {len(attn)} kinds "
+            f"{cfg.attn_head_gate}), with or without a QK-norm a head and "
+            f"an indexer; this model describes {len(attn)} kinds "
             "of attention layer.  Beside them a model may have layers that "
             "keep a recurrent state (LayerSpec.mixer) and sparse MLPs "
             "(serving/latent.py's loop).  Missing behind the grouped-query "
             "block: a lower bound on the keys in "
             "ops/pallas/paged_attention.py and window rows in its cache "
-            "(ROADMAP A4), per-layer head counts, a head gate, partial "
-            "rotary and YaRN in serving/latent.py::_gqa_layer (A3)")
+            "(ROADMAP A4), per-layer head counts, an indexer in some layers "
+            "only, a head gate, partial rotary and YaRN in "
+            "serving/latent.py::_gqa_layer (A3)")
+    if cfg.index_topk and not cfg.kv_lora_rank and not cfg.layer_kinds:
+        raise ValueError(
+            "a learned selection of keys behind the grouped-query block "
+            f"(index_topk={cfg.index_topk}) is served by the loop of layer "
+            "kinds (serving/latent.py::_gqa_layer), and this dense model is "
+            "served by serving/model.py's own loop, which has no indexer, "
+            "no index-key pool and no mask on its attention (ROADMAP Reach "
+            "A12)")
     if cfg.layer_kinds:
         if int8 or not fuse:
             raise ValueError(
@@ -219,7 +232,10 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
                    ) -> Dict[str, Any]:
     """The serving tree of a model of layer kinds (serving/latent.py): a
     grouped-query model's attention layers as :func:`_layer_tree` fuses
-    them (``wqkv``, ``wo``), beside ``ssm`` layers (``serving/linear.py
+    them (``wqkv``, ``wo``; with a QK-norm a head the two scales ``q_norm``
+    and ``k_norm`` [D]; with ``index_topk`` an ``indexer`` named as below
+    but for its query projection ``wq`` [E, Hi, Di], from the layer's
+    input), beside ``ssm`` layers (``serving/linear.py
     ssm_params``) and the MLPs below; or a latent-attention model's,
     from a ``layer_{i}`` tree named as ``perfbench/reference_glm5.py``,
     ``perfbench/reference_sarvam.py`` and the tests make it: ``attn`` (the
@@ -241,11 +257,12 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
     and its bias stay float32."""
     import flax.linen as nn
 
-    if cfg.index_topk and not cfg.q_lora_rank:
+    if cfg.index_topk and cfg.kv_lora_rank and not cfg.q_lora_rank:
         raise ValueError(
-            "the indexer's queries come from the query's bottleneck "
-            f"(index_topk={cfg.index_topk}, q_lora_rank=0): no published "
-            "model has the one without the other")
+            "a latent layer's indexer takes its queries from the query's "
+            f"bottleneck (index_topk={cfg.index_topk}, q_lora_rank=0): no "
+            "published latent model has the one without the other (a "
+            "grouped-query layer's takes them from the layer's input)")
     variables = nn.meta.unbox(variables)
     params = variables["params"] if "params" in variables else variables
 
@@ -269,14 +286,30 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
                              "layer is 'attn', 'kda' or 'ssm' "
                              "(LayerSpec.mixer; 'conv' is trained only)")
         a = p["attn"]
+        _, nope, indexed = cfg.latent_dims(spec)
+
+        def indexer(query_proj):
+            ix = p["indexer"]
+            return dict(
+                iwq=flat_out(ix[query_proj]["kernel"]),
+                iwk=mat(ix["wk"]["kernel"]),
+                ik_norm_scale=ix["k_norm"]["scale"],
+                ik_norm_bias=ix["k_norm"]["bias"],
+                iw=mat(ix["weights_proj"]["kernel"]))
+
         if not cfg.kv_lora_rank:         # the grouped-query block
-            return {
+            out = {
                 "wqkv": jnp.concatenate(
                     [flat_out(a[n]["kernel"])
                      for n in ("q_proj", "k_proj", "v_proj")], axis=-1),
                 "wo": mat(a["o_proj"]["kernel"]).reshape(
                     -1, cfg.hidden_size)}
-        _, nope, indexed = cfg.latent_dims(spec)
+            if cfg.qk_norm:              # a head at a time, one scale each
+                out.update(q_norm=a["q_norm"]["scale"],
+                           k_norm=a["k_norm"]["scale"])
+            if indexed:                  # its queries from the layer's input
+                out.update(indexer("wq"))
+            return out
         kv_b = mat(a["kv_b_proj"]["kernel"])             # [C, H, nope+V]
         out = {
             "wkv_a": mat(a["kv_a_proj"]["kernel"]),
@@ -297,13 +330,7 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
         if cfg.attn_head_gate:
             out["head_gate"] = mat(a["g_proj"]["kernel"])     # [E, H]
         if indexed:
-            ix = p["indexer"]
-            out.update(
-                iwq=flat_out(ix["wq_b"]["kernel"]),
-                iwk=mat(ix["wk"]["kernel"]),
-                ik_norm_scale=ix["k_norm"]["scale"],
-                ik_norm_bias=ix["k_norm"]["bias"],
-                iw=mat(ix["weights_proj"]["kernel"]))
+            out.update(indexer("wq_b"))
         return out
 
     def layer(p, spec):
