@@ -2077,7 +2077,14 @@ class InferenceEngine:
         each forward; every other slot gets length 0 and reads
         nothing), and return the two sums.  Host integer arithmetic
         on a [slots, forwards] array; (0, 0) where the gather path
-        decodes."""
+        decodes.  ``live`` is a slot's rows each, whoever shares them.
+        ``streamed`` is what the kernel COPIES: where grouped-query
+        layers attend under a selection (``paged_decode_attention`` with
+        a ``bias``) a run of leading page groups that several active
+        slots' rows of ``_table_np`` hold in common is copied, and booked,
+        once a forward for all of them, so streamed / live falls under 1
+        as far as pages are shared; every other kernel copies, and books,
+        each slot's groups."""
         if self.attention_impl != "pallas":
             return 0, 0
         if self._latent:
@@ -2090,8 +2097,11 @@ class InferenceEngine:
             + np.arange(1, chunks * self.chunk + 1)[None, :]
         live = int(np.minimum(
             lengths, self._max_blocks * self.block_size).sum())
+        selected = "index_pool" in self._pool_names and not self._latent \
+            and self._max_blocks * self.block_size > self.cfg.index_topk
         streamed = streamed_rows(
-            lengths, self.block_size, self._max_blocks)
+            lengths, self.block_size, self._max_blocks,
+            table=self._table_np[active] if selected else None)
         self.stats.kv_rows_live += live
         self.stats.kv_rows_streamed += streamed
         return live, streamed

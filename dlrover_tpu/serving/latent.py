@@ -38,8 +38,10 @@ layer keeps its index keys in an ``index_pool`` of its own beside
 ``k_pool`` / ``v_pool`` under the SAME block table (rows of
 :func:`index_row_width` values: whole lanes), shared and copied with its
 K/V blocks, and attends UNDER the selection, masked-dense: decode streams
-every live K/V page through ``paged_decode_attention`` with the chosen
-rows as a float32 ``bias`` (the kernel names that call
+the live K/V pages through ``paged_decode_attention`` with the chosen
+rows as a float32 ``bias``, a run of leading pages that several slots'
+table rows hold in common (a cached document) ONCE a layer and forward
+for all of them (``paged_attention.shared_runs``; the kernel names that call
 ``paged_attention.SELECTED_ATTENTION`` in a device trace; a table
 of no more rows than a query may choose runs the unmasked kernel, and a
 model without an indexer traces no bias at all), a run walks its live key
@@ -902,7 +904,8 @@ def _gqa_index(lp, h, cfg: LlamaConfig, spec: LayerSpec, pos, dtype):
 
 def _gqa_layer(lp, h, k_pool, v_pool, idx_pool, table, run_table,
                cfg: LlamaConfig, spec: LayerSpec, dtype, positions, pos_k,
-               lengths, n_real, decode: bool, impl: str, interpret: bool):
+               lengths, n_real, decode: bool, impl: str, interpret: bool,
+               runs=None):
     """A GROUPED-QUERY attention layer on ``h`` [B, K, E]: ``(y, k_pool,
     v_pool, idx_pool, chosen)``, the block's output, the layer's pools with
     this forward's rows written and the keys each query attended to (None:
@@ -919,9 +922,12 @@ def _gqa_layer(lp, h, k_pool, v_pool, idx_pool, table, run_table,
     none) a query attends to its ``index_topk`` chosen keys only, the
     selection a latent layer makes (:func:`_select_decode`,
     :func:`_select_run`) from index queries projected from ``h``.  Decode
-    on the chip streams every live K/V page under the mask of the chosen
-    rows (``paged_decode_attention`` with a ``bias``); a run walks its
-    live key blocks under it."""
+    on the chip streams the live K/V pages under the mask of the chosen
+    rows (``paged_decode_attention`` with a ``bias``), a run of leading
+    pages that several slots' table rows hold in common once for all of
+    them (``runs``: ``paged_attention.shared_runs``, which the forward
+    derives once for its layers); a run of queries walks its live key
+    blocks under the mask."""
     from dlrover_tpu.serving.model import _qkv
 
     b, klen, _ = h.shape
@@ -947,7 +953,7 @@ def _gqa_layer(lp, h, k_pool, v_pool, idx_pool, table, run_table,
                                   k_i.astype(idx_pool.dtype), positions)
         o, chosen = _gqa_attend_selected(
             q, q_i, w, k_pool, v_pool, idx_pool, table, run_table, cfg,
-            pos_k, lengths, n_real, scale, decode, impl, interpret)
+            pos_k, lengths, n_real, scale, decode, impl, interpret, runs)
     else:
         with device_scope("paged_attn"):
             if decode and impl == "pallas":
@@ -974,12 +980,13 @@ def _gqa_layer(lp, h, k_pool, v_pool, idx_pool, table, run_table,
 def _gqa_attend_selected(q, q_i, w, k_pool, v_pool, idx_pool, table,
                          run_table, cfg: LlamaConfig, pos_k, lengths, n_real,
                          scale: float, decode: bool, impl: str,
-                         interpret: bool):
+                         interpret: bool, runs=None):
     """The attention of a grouped-query layer UNDER ITS SELECTION: ``(o
     [B, K, H, D] float32, chosen)``.  Decode with ``impl == "pallas"``:
     ``chosen`` [B, rows] from :func:`_select_decode` (None while the table
     holds no more rows than a query may choose), then the decode kernel
-    over every slot's live pages under the mask.  Every other shape, a row
+    over the slots' live pages under the mask, the pages of a shared run
+    once (``runs``: the forward's ``shared_runs``).  Every other shape, a row
     at a time: ``chosen`` [B, K, rows] from :func:`_select_run`, then the
     walk of the row's live key blocks under it."""
     bs = k_pool.shape[1]
@@ -995,7 +1002,7 @@ def _gqa_attend_selected(q, q_i, w, k_pool, v_pool, idx_pool, table,
                                                          _NEG_INF)
             o = paged_decode_attention(
                 q[:, 0], k_pool, v_pool, table, lengths, scale=scale,
-                interpret=interpret, bias=bias)[:, None]
+                interpret=interpret, bias=bias, runs=runs)[:, None]
         return o, chosen
     if decode:
         run_table = _pad_table(table, KEY_BLOCK_PAGES)
@@ -1175,6 +1182,15 @@ def verify_step(
         # a row's real queries: behind ``logits_index`` a run is padding
         n_real = None if logits_index is None \
             else logits_index.astype(jnp.int32) + 1
+    runs = None
+    if decode and attention_impl == "pallas" and "index_pool" in cache \
+            and not cfg.kv_lora_rank:
+        # the slots whose table rows begin alike, once for every
+        # grouped-query layer's kernel under its selection
+        from dlrover_tpu.ops.pallas.paged_attention import shared_runs
+
+        with device_scope("paged_attn"):
+            runs = shared_runs(table, lengths, cache["k_pool"][0].shape[1])
     picks = cache.get("moe_picks")
     watch = cache.get("watch_slot")
     if watch is not None:
@@ -1210,7 +1226,8 @@ def verify_step(
                 cache["v_pool"][len(v_pools)],
                 cache["index_pool"][len(index_pools)] if indexed else None,
                 table, run_table, cfg, spec, dtype, positions, pos_k,
-                lengths, n_real, decode, attention_impl, kernel_interpret)
+                lengths, n_real, decode, attention_impl, kernel_interpret,
+                runs)
             if watch is not None and indexed:
                 selections.append(_seen_selection(
                     chosen, watch, decode, table.shape[1] * k_new.shape[1],

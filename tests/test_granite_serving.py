@@ -403,6 +403,22 @@ def test_the_loops_grouped_query_block_is_the_dense_models():
         np.testing.assert_allclose(got, want, atol=2e-5)
 
 
+def test_the_decode_forward_traces_the_unmasked_kernel_alone(cfg, params_of):
+    """The one grouped-query layer of ``serve-rag-ssm``'s decode program
+    calls ``paged_decode_attention`` with no bias: the kernel under a
+    selection (``paged_selected_attention``, which streams a shared run of
+    pages once) is no part of it."""
+    from dlrover_tpu.ops.pallas.paged_attention import (DECODE_ATTENTION,
+                                                        SELECTED_ATTENTION)
+
+    engine = _engine(cfg, params_of(3), "pallas")
+    text = str(jax.make_jaxpr(lambda c: latent.verify_step(
+        engine.params, cfg, c, jnp.zeros((3, 1), jnp.int32),
+        jnp.asarray([19, 12, 5], jnp.int32), attention_impl="pallas",
+        kernel_interpret=True))(engine._cache))
+    assert DECODE_ATTENTION in text and SELECTED_ATTENTION not in text
+
+
 def test_a_trained_sparse_model_is_served_behind_the_grouped_query_block():
     """What ROADMAP A1 asked for: a model ``LlamaModel`` TRAINS (rotated
     grouped-query attention, sparse experts, all of them held) goes

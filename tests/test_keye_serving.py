@@ -15,6 +15,7 @@ import ast
 import contextlib
 import dataclasses
 import difflib
+import hashlib
 import inspect
 import json
 import os
@@ -29,7 +30,9 @@ from dlrover_tpu.models.llama import (PRESETS, LlamaConfig, LlamaModel,
 from dlrover_tpu.ops.pallas import paged_index
 from dlrover_tpu.ops.pallas.paged_attention import (SELECTED_ATTENTION,
                                                     gather_reference,
-                                                    paged_decode_attention)
+                                                    paged_decode_attention,
+                                                    shared_runs,
+                                                    streamed_rows)
 from dlrover_tpu.serving import latent
 from dlrover_tpu.serving.engine import InferenceEngine
 from dlrover_tpu.serving.params import serving_params_from_llama
@@ -267,6 +270,97 @@ def test_the_masked_decode_kernel_is_its_oracle(pages):
             q, k, v, table, lengths, bias=b_, interpret=True,
             pages_per_block=pages))(given))
         assert (SELECTED_ATTENTION in text) == (given is not None)
+    # ... and without one the traced program is commit 8914f83's, the
+    # parent of the shared-run stream (PR 59), to the letter: the sha256
+    # of that tree's text at these shapes
+    text = str(jax.make_jaxpr(lambda *a: paged_decode_attention(
+        *a, interpret=True, pages_per_block=pages))(q, k, v, table, lengths))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == {
+        None: "d4483c0a4fe05f5c", 2: "cda2193b21eb4974",
+        3: "988b47066ffc7140"}[pages]
+
+
+# slots of (document or None, leading pages of it in the table row, length)
+# over pages of 16 rows in a table of 48: what the shared-run stream of the
+# kernel under a selection must get right
+_SHARED_RUNS = {
+    # three runs of 3 / 2 / 1 slots, not side by side, of 34 and 20 pages
+    "three_runs": [("A", 34, 700), ("B", 20, 400), ("A", 34, 768),
+                   (None, 0, 300), ("B", 20, 321), ("A", 34, 545)],
+    # 5 common pages: no whole number of groups of 2 or 3 pages
+    "ragged_run": [("A", 5, 200), ("A", 5, 90), (None, 0, 17)],
+    # a member of length 0, and two whose length ends on the run's last row
+    "edges": [("A", 6, 0), ("A", 6, 96), ("A", 6, 130), ("A", 6, 96)],
+    # every slot in one run, more of them than the largest pass holds
+    # (RUN_TILES: one pass of 16 and one of 4)
+    "all_slots": [("A", 36, 600 + 7 * i) for i in range(18)],
+    # nothing shared: the parent's stream, and the parent's booking
+    "none_shared": [(None, 0, 5), (None, 0, 300), (None, 0, 768)],
+    # rows alike at entry 0 and apart at entry 1
+    "one_page": [("A", 1, 100), ("A", 1, 200)],
+    # a member that chose no row of the run's groups behind the first
+    "chose_none": [("A", 34, 700), ("A", 34, 600), ("A", 34, 580)],
+}
+
+
+@pytest.mark.parametrize("pages", [None, 2, 3])
+@pytest.mark.parametrize("case", sorted(_SHARED_RUNS))
+def test_a_shared_run_is_streamed_once_and_attended_a_slot_at_a_time(
+        case, pages):
+    """``paged_decode_attention`` under a bias over slots whose table rows
+    begin alike (interpret mode): the gather's result a slot, each under
+    its own selection, and the rows the host books as streamed are the
+    kernel's plan: a run's shared groups once."""
+    bs, mb, h, kv, d = 16, 48, 4, 2, 64
+    slots = _SHARED_RUNS[case]
+    b, table, free, docs = len(slots), [], 1, {}
+    for doc, n, _ in slots:
+        if doc is not None and doc not in docs:
+            docs[doc], free = np.arange(free, free + mb), free + mb
+        table.append(np.concatenate(
+            [docs[doc][:n] if n else [], np.arange(free, free + mb - n)]))
+        free += mb - n
+    table = jnp.asarray(np.stack(table), jnp.int32)
+    lengths = jnp.asarray([s[2] for s in slots], jnp.int32)
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(ks[0], (b, h, d), jnp.float32)
+    k = jax.random.normal(ks[1], (free, bs, kv, d), jnp.float32)
+    v = jax.random.normal(ks[2], (free, bs, kv, d), jnp.float32)
+    chosen = (jax.random.uniform(ks[3], (b, mb * bs)) < 0.1
+              ).at[:, 0].set(True)
+    if case == "chose_none":
+        chosen = chosen.at[1, 32:544].set(False)
+    bias = jnp.where(chosen, 0.0, -jnp.inf)
+    got = paged_decode_attention(q, k, v, table, lengths, bias=bias,
+                                 interpret=True, pages_per_block=pages)
+    want = gather_reference(q, k, v, table, lengths, bias=bias)
+    live = np.asarray(lengths) > 0      # (a slot of no key: zeros, unread)
+    np.testing.assert_allclose(got[live], want[live], atol=3e-6)
+    assert not np.asarray(got)[~live].any()
+    # the plan the kernel walks and the host's booking are one arithmetic
+    order, plan = shared_runs(table, lengths, bs, pages)
+    start, n_live, shared, members = np.asarray(plan)[:4]
+    rows = bs * (pages or 16)
+    booked = streamed_rows(lengths, bs, mb, pages, table=table)
+    assert booked == int((n_live - start).sum()) * rows
+    each = streamed_rows(lengths, bs, mb, pages)
+    runs = {"three_runs": [3, 2, 1], "all_slots": [18],
+            "chose_none": [3]}.get(case)
+    if case in ("none_shared", "one_page") or (
+            pages is None and case in ("ragged_run", "edges")):
+        assert booked == each and not shared.any()
+        np.testing.assert_array_equal(order, np.arange(b))
+    elif runs:
+        assert sorted(members[members > 0], reverse=True) == runs
+        assert booked == each - sum(
+            (n - 1) * g * rows for n, g in zip(
+                members[members > 0], shared[members > 0]))
+        assert booked < each
+    elif case == "ragged_run":           # 5 pages: 2 groups of 2, 1 of 3
+        assert shared.tolist() == [5 // pages, 5 // pages, 0]
+    else:                                # "edges": length 0 is in no run
+        assert members.tolist() == [1, 3, 0, 0]
+        assert shared.tolist() == [0] + [96 // rows] * 3
 
 
 def test_the_index_kernel_scores_keys_of_half_a_row_of_lanes():
@@ -351,6 +445,11 @@ def test_a_request_on_a_cached_document_answers_as_the_reference(
     st = warm.stats
     assert 0 < st.dsa_selected_ratio < 1 and st.attn_rows_selected > 0
     assert st.index_rows_scanned >= st.dsa_rows_live > st.attn_rows_selected
+    # a table of 13 pages of 8 rows is ONE group of the kernel: every
+    # forward copies it whole for each slot, shared document or not (the
+    # pages two slots share are a whole group or more in
+    # test_two_questions_on_one_document_stream_its_pages_once)
+    assert st.kv_rows_streamed % (warm._max_blocks * 8) == 0
     assert st.kv_rows_streamed >= st.kv_rows_live > 0
     assert 0 < st.moe_picks_held < st.moe_picks
     assert warm._blockmgr.check_books()
@@ -366,6 +465,97 @@ def test_a_request_on_a_cached_document_answers_as_the_reference(
             assert np.asarray(seen["chosen_bits"]).shape[:2] == (
                 cfg.num_layers, 16)
     warm.witness_log.clear()
+
+
+def test_two_questions_on_one_document_stream_its_pages_once(
+        cfg, params, monkeypatch):
+    """A cached document of 65 pages of 8 rows, two groups of the decode
+    kernel and a page: two questions that decode side by side give the
+    tokens each gives alone, and the engine books the document's two
+    groups ONCE a forward for both (``kv_rows_streamed`` under
+    ``kv_rows_live``), by the arithmetic the kernel's plan is made of,
+    in the stats and in the ``decode_chunk`` spans alike."""
+    from dlrover_tpu.utils import profiler
+
+    spans, books = [], []
+    inner = profiler.span
+
+    def span(name, **attrs):
+        if name == "dlrover.engine.decode_chunk" and attrs:
+            spans.append(attrs)
+        return inner(name, **attrs)
+
+    monkeypatch.setattr("dlrover_tpu.serving.engine.span", span)
+    eng = _engine(dataclasses.replace(cfg, max_seq_len=600), params,
+                  max_len=600, prefill_buckets=(600,), prefill_chunk=64)
+    booking = eng._book_kv_rows
+
+    def book(active, chunks=1):
+        books.append((eng._positions[active].copy(),
+                      eng._table_np[active].copy()))
+        return booking(active, chunks)
+
+    monkeypatch.setattr(eng, "_book_kv_rows", book)
+    rng = np.random.RandomState(7)
+    doc = rng.randint(0, 128, 523).astype(np.int32)
+    prompts = [np.concatenate([doc, rng.randint(0, 128, n).astype(np.int32)])
+               for n in (9, 21)]
+    eng.add_request(doc, 1)
+    eng.run()
+    alone = []
+    for prompt in prompts:
+        rid = eng.add_request(prompt, 9)
+        alone.append(eng.run()[rid].tolist())
+    before = dataclasses.asdict(eng.stats)
+    del spans[:], books[:]
+    rids = [eng.add_request(prompt, 9) for prompt in prompts]
+    both = eng.run()
+    assert [both[rid].tolist() for rid in rids] == alone
+    live = eng.stats.kv_rows_live - before["kv_rows_live"]
+    streamed = eng.stats.kv_rows_streamed - before["kv_rows_streamed"]
+    # the host's arithmetic, a dispatch and a forward at a time: groups of
+    # 32 pages (256 rows) up to each length, less the whole groups of the
+    # rows' common pages for the second slot of two
+    want_live = want_streamed = shared_forwards = 0
+    for positions, table in books:
+        common = 0
+        if len(table) == 2:
+            differ = np.flatnonzero(table[0] != table[1])
+            common = (differ[0] if differ.size else table.shape[1]) // 32
+        for forward in range(1, eng.chunk + 1):
+            lengths = positions + forward
+            want_live += int(lengths.sum())
+            want_streamed += 256 * (int((-(-lengths // 256)).sum()) - common)
+            shared_forwards += common > 0
+    assert shared_forwards >= eng.chunk and common == 2
+    assert (live, streamed) == (want_live, want_streamed)
+    assert streamed < live
+    assert sum(a["kv_rows_streamed"] for a in spans) == streamed
+    assert eng.stats.kv_stream_ratio == pytest.approx(
+        eng.stats.kv_rows_streamed / eng.stats.kv_rows_live)
+    assert eng._blockmgr.check_books()
+
+
+def test_a_model_without_a_selection_traces_no_selected_attention():
+    """``serving/model.py``'s decode forward under the kernel: the
+    unmasked call and no other (``serve-batch-closed``'s program)."""
+    from dlrover_tpu.ops.pallas.paged_attention import DECODE_ATTENTION
+    from dlrover_tpu.serving import model as dense
+
+    plain = LlamaConfig.tiny(dtype=jnp.float32, param_dtype=jnp.float32)
+    toks = jnp.zeros((2, 8), jnp.int32)
+    variables = LlamaModel(plain).init(jax.random.PRNGKey(0), toks)
+    sp = serving_params_from_llama(variables, plain)
+    b, nb, bs, mb = 2, 12, 8, 5
+    cache = {n: [jnp.zeros((nb, bs, plain.num_kv_heads, plain.head_dim_))
+                 for _ in range(plain.num_layers)]
+             for n in ("k_pool", "v_pool")}
+    cache["table"] = jnp.asarray(
+        np.arange(1, 1 + b * mb).reshape(b, mb), jnp.int32)
+    text = str(jax.make_jaxpr(lambda c: dense.verify_step(
+        sp, plain, c, toks[:, :1], jnp.asarray([19, 12], jnp.int32),
+        attention_impl="pallas", kernel_interpret=True))(cache))
+    assert DECODE_ATTENTION in text and SELECTED_ATTENTION not in text
 
 
 # -------------------------------------------------- the file, the refusals

@@ -208,6 +208,29 @@ def test_index_score_kernel_compiles_at_glm5_widths(one_chip):
     assert "paged_index_scores" in text and "tpu_custom_call" in text
 
 
+def test_selected_attention_kernel_compiles_at_keye_widths(one_chip):
+    """``paged_decode_attention`` under a selection's bias at
+    ``serve-docqa-sparse-gqa``'s geometry (32 slots, 32 query heads on 4
+    KV heads of 128, pages of 128 rows, a table of 258): the kernel that
+    streams a shared run of pages once, every slot's bias in VMEM beside
+    the runs' carries (its own ``vmem_limit_bytes``), the runs derived in
+    the same program."""
+    from dlrover_tpu.ops.pallas.paged_attention import SELECTED_ATTENTION
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def run(q, k, v, table, lengths, bias):
+        return paged_decode_attention(q, k, v, table, lengths, bias=bias)
+
+    pool = s((2200, 128, 4, 128), jnp.bfloat16)
+    text = jax.jit(run).lower(
+        s((32, 32, 128), jnp.bfloat16), pool, pool, s((32, 258), jnp.int32),
+        s((32,), jnp.int32), s((32, 258 * 128), jnp.float32)
+    ).compile().as_text()
+    assert SELECTED_ATTENTION in text and "tpu_custom_call" in text
+
+
 def test_latent_prefill_kernel_compiles_under_its_scope(one_chip):
     """A prefill chunk's query run at the served cell's geometry (512
     queries of which ``n_real`` are the prompt's, 64 heads, rows of 640, a
