@@ -23,7 +23,7 @@ layer's own work is not partitioned yet: the picks are sorted over ALL
 tokens and a Pallas call has no partitioning rule, so on a mesh the
 router's probabilities and the grouped matmul's operands are replicated
 first (:func:`_replicated`) and every device computes the whole layer.
-The result is the same; the all-to-all by hand is ROADMAP B1(c).
+The result is the same; the all-to-all by hand is ROADMAP A2.
 
 Variants (all off by default, and then the traced layer is as it was):
 ``score_fn="sigmoid"`` scores each expert by the sigmoid of its logit;

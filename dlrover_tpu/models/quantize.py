@@ -122,64 +122,6 @@ def dequantize_kv_int8(q: jax.Array, scale: jax.Array,
     ).astype(dtype)
 
 
-# ---------------------------------------------------------- int4 KV
-# Packing layout (SPLIT-HALF, not interleaved): byte ``j`` of a packed
-# ``[..., D//2]`` vector holds code ``j`` in its LOW nibble and code
-# ``j + D//2`` in its HIGH nibble.  Unpacking is then a plain
-# concatenate along the last axis — no interleave reshape — which the
-# Pallas kernel's in-VMEM dequant and XLA both lower cleanly (an
-# interleave would force a [.., D//2, 2] -> [.., D] relayout on every
-# attention read).  Codes are symmetric in [-7, 7] (-8 excluded so the
-# scale grid is symmetric, matching the int8 path's [-127, 127]).
-
-def pack_int4(codes: jax.Array) -> jax.Array:
-    """``codes int [..., D] -> packed int8 [..., D//2]`` (split-half
-    nibble layout above).  D must be even."""
-    d = codes.shape[-1]
-    assert d % 2 == 0, f"int4 packing needs an even last dim, got {d}"
-    c = codes.astype(jnp.int32)
-    lo = c[..., : d // 2]
-    hi = c[..., d // 2:]
-    return ((hi << 4) | (lo & 0xF)).astype(jnp.int8)
-
-
-def unpack_int4(packed: jax.Array) -> jax.Array:
-    """Inverse of :func:`pack_int4`: ``int8 [..., D//2] -> int32 codes
-    [..., D]`` (sign-extended nibbles, split-half concatenation)."""
-    p = packed.astype(jnp.int32)
-    lo = (p << 28) >> 28   # arithmetic shifts sign-extend the nibble
-    hi = (p << 24) >> 28
-    return jnp.concatenate([lo, hi], axis=-1)
-
-
-def quantize_kv_int4(kv: jax.Array):
-    """Symmetric per-vector int4 quantization over the head dim:
-    ``kv [..., D] -> (packed int8 [..., D//2], scale [...])``.  Same
-    per-(token, head) scale granularity as :func:`quantize_kv_int8`
-    (appends never requantize a block), amax/7 scale, codes clipped to
-    [-7, 7].  Half the code bytes of int8 — the ~3.7x KV-budget
-    multiplier at D=64/128 — at the cost of ~16x coarser rounding,
-    which the drift tests bound."""
-    x = kv.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(x), axis=-1)
-    scale = jnp.maximum(amax, 1e-8) / 7.0
-    q = jnp.clip(
-        jnp.round(x / scale[..., None]), -7, 7
-    ).astype(jnp.int8)
-    return pack_int4(q), scale.astype(KV_SCALE_DTYPE)
-
-
-def dequantize_kv_int4(packed: jax.Array, scale: jax.Array,
-                       dtype=jnp.bfloat16) -> jax.Array:
-    """Inverse of :func:`quantize_kv_int4`; call INSIDE jit so unpack +
-    convert fuse into the consuming attention reads and the pool
-    streams from HBM at half a byte per element."""
-    return (
-        unpack_int4(packed).astype(jnp.float32)
-        * scale.astype(jnp.float32)[..., None]
-    ).astype(dtype)
-
-
 def quantized_nbytes(qvariables: Any) -> int:
     total = 0
     for leaf in jax.tree_util.tree_leaves(qvariables):

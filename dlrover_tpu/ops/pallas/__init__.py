@@ -3,7 +3,7 @@
 - ``flash_attention``: training's causal / segmented / windowed attention,
   forward and backward.
 - ``paged_attention``: a dense decoder's decode attention over paged K/V
-  pools (bf16, int8; int4 refused), ending at each slot's length.
+  pools (bf16, int8), ending at each slot's length.
 - ``paged_index``: a latent model's index scores over a slot's live pages
   of index keys (decode).
 - ``mla_prefill``: a latent model's masked, absorbed attention of a run

@@ -6,8 +6,8 @@ over the cache bytes, and for quantized pools a pass at FULL bf16
 width (the gather dequantizes first, so XLA pays code-width bytes once
 to read and bf16 width again to re-stream the materialized view).
 This kernel reads K/V blocks IN PLACE from the pools and folds the
-dequant INSIDE, so an int8 pool streams at 1 byte/element and a packed
-int4 pool at 0.5 — the dense bf16 view never exists.
+dequant INSIDE, so an int8 pool streams at 1 byte/element — the dense
+bf16 view never exists.
 
 CONTRACT (supersedes the old EXPERIMENTAL/STATUS header): the serving
 engine selects this kernel through ``attention_impl`` —
@@ -20,9 +20,9 @@ engine selects this kernel through ``attention_impl`` —
   because the interpret-mode kernel is a correctness tool, not a perf
   candidate);
 - numerically the kernel matches the gather path to float tolerance
-  for bf16, int8 and packed int4 pools (parity tests run in
-  ``interpret=True`` mode on CPU in tier-1, so a numerics regression
-  cannot hide behind missing hardware).
+  for bf16 and int8 pools (parity tests run in ``interpret=True`` mode
+  on CPU in tier-1, so a numerics regression cannot hide behind missing
+  hardware).
 
 Design:
 
@@ -59,10 +59,8 @@ Design:
    and the flash kernels do.  Scores, the running maximum and sum and
    the accumulator are float32 values carried through the group loop.
 4. **Dequantization folded inside.**  Quantized codes are widened to
-   float32 in VMEM right after the copy lands (int4 codes unpack
-   split-half: byte ``j`` holds code ``j`` low-nibble and ``j + D/2``
-   high-nibble, so unpack is a concatenate, not an interleave), and
-   their dots are float32.  HBM traffic for the codes is code-width;
+   float32 in VMEM right after the copy lands, and their dots are
+   float32.  HBM traffic for the codes is code-width;
    the XLA gather path cannot avoid materializing the dequantized rows.
    The per-(token, head) SCALES do not stream in place: their pool's
    minor dimension is the KV-head count, which the TPU pads to 128
@@ -71,16 +69,14 @@ Design:
    order of a group's score columns, and the kernel applies them to the
    score and probability tiles — a scale is constant along the head
    dim, so it factors out of both dots.
-   **Packed int4 pools do not compile on a TPU** (``INT4_REFUSAL``):
-   that variant runs in interpret mode only, as the parity harness.
 
 Scope: single-query decode (the serving engine's K=1 step — its hot
 path; speculative verify and prefill keep the gather path).
 
 Layout contract (matches serving/paged.py):
   q        [B, H, D]        current-token queries
-  k_pool   [NB, bs, KV, Dc] Dc = D (bf16/int8) or D//2 (packed int4)
-  v_pool   [NB, bs, KV, Dc]
+  k_pool   [NB, bs, KV, D]  float (bf16) or int8 codes
+  v_pool   [NB, bs, KV, D]
   k_scale  [NB, bs, KV]     per-(token, head) scales (quantized pools)
   v_scale  [NB, bs, KV]
   table    [B, MB] int32    per-slot block lists (0 = trash block)
@@ -192,28 +188,6 @@ GROUP_ROWS = 256
 DECODE_ATTENTION = "paged_decode_attention"
 SELECTED_ATTENTION = "paged_selected_attention"
 
-#: Why the packed-int4 variant is refused on a TPU (compiled for a
-#: described v5e, PR 21).  The pool's minor dimension is D//2 = 64, and
-#: Mosaic accepts a DMA slice only of a 128-aligned minor dimension.
-#: Until the pool is re-laid lane-dense, int4 pools run the gather.
-INT4_REFUSAL = (
-    "the packed int4 pool does not compile on TPU v5e — MosaicError: "
-    "'Slice shape along dimension 3 must be aligned to tiling (128), "
-    "but is 64' (the packed minor dimension D//2); use "
-    "attention_impl='xla' (the gather) or kv_dtype='int8'"
-)
-
-
-def _unpack4_f32(x: jax.Array) -> jax.Array:
-    """Packed int4 ``[..., Dc] -> f32 codes [..., 2*Dc]`` (split-half
-    layout; the int32 shifts sign-extend each nibble).  Kept local so
-    the kernel has no cross-module imports to trace."""
-    p = x.astype(jnp.int32)
-    lo = (p << 28) >> 28
-    hi = (p << 24) >> 28
-    return jnp.concatenate([lo, hi], axis=-1).astype(jnp.float32)
-
-
 def _group_copies(table_ref, k_hbm, v_hbm, kb, vb, sem, pages: int):
     """``(start_group, wait_group)`` of a decode kernel's stream: a page
     group of a slot's table row into one of the two VMEM buffers, one
@@ -241,7 +215,7 @@ def _decode_kernel(
     *args,
     block_size: int, pages: int, num_groups: int, capacity: int,
     kv_heads: int, group: int, head_dim: int,
-    quant: bool, packed: bool, scale: float,
+    quant: bool, scale: float,
 ):
     if quant:
         q_ref, k_hbm, v_hbm, ks_ref, vs_ref, *rest = args
@@ -292,9 +266,7 @@ def _decode_kernel(
     q = q_ref[0].reshape(heads, head_dim).astype(operand)
 
     def tile(bufs, buf):
-        raw = bufs[buf]               # [P, bs, KV, Dc]
-        if packed:
-            raw = _unpack4_f32(raw)
+        raw = bufs[buf]               # [P, bs, KV, D]
         return raw.reshape(cols, head_dim).astype(operand)
 
     def scaled(x, s_ref, g):
@@ -694,7 +666,7 @@ def shared_runs(table: jax.Array, lengths: jax.Array, block_size: int,
     jax.jit, static_argnames=("pages_per_block", "interpret", "scale"))
 def paged_decode_attention(
     q: jax.Array,        # [B, H, D]
-    k_pool: jax.Array,   # [NB, bs, KV, Dc]
+    k_pool: jax.Array,   # [NB, bs, KV, D]
     v_pool: jax.Array,
     table: jax.Array,    # [B, MB] int32
     lengths: jax.Array,  # [B] int32
@@ -708,20 +680,14 @@ def paged_decode_attention(
     runs=None,           # shared_runs(table, lengths, ...): under a bias
 ) -> jax.Array:
     b, h, d = q.shape
-    nb, bs, kv, dc = k_pool.shape
+    nb, bs, kv, _ = k_pool.shape
     quant = k_scale is not None
     if bias is not None:
         assert not quant, "a selection's bias over a quantized pool"
         return _selected_attention(
             q, k_pool, v_pool, table, lengths, bias, runs,
             pages_per_block, interpret, scale)
-    packed = quant and dc != d
-    if packed:
-        assert dc * 2 == d, (q.shape, k_pool.shape)
-        if not interpret:
-            raise NotImplementedError(INT4_REFUSAL)
-    else:
-        assert dc == d, (q.shape, k_pool.shape)
+    assert k_pool.shape[-1] == d, (q.shape, k_pool.shape)
     assert h % kv == 0, (h, kv)
     g = h // kv
     mb = table.shape[1]
@@ -740,7 +706,7 @@ def paged_decode_attention(
     kernel = functools.partial(
         _decode_kernel, block_size=bs, pages=p_n, num_groups=num_groups,
         capacity=mb * bs, kv_heads=kv, group=g, head_dim=d,
-        quant=quant, packed=packed,
+        quant=quant,
         scale=float(d ** -0.5 if scale is None else scale),
     )
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
@@ -775,8 +741,8 @@ def paged_decode_attention(
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, kv, g, d), q_map),
             scratch_shapes=[
-                pltpu.VMEM((2, p_n, bs, kv, dc), k_pool.dtype),
-                pltpu.VMEM((2, p_n, bs, kv, dc), v_pool.dtype),
+                pltpu.VMEM((2, p_n, bs, kv, d), k_pool.dtype),
+                pltpu.VMEM((2, p_n, bs, kv, d), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, p_n, 2)),
                 pltpu.SMEM((1,), jnp.int32),   # the buffer a slot starts in
             ],
@@ -882,7 +848,7 @@ def _selected_attention(q, k_pool, v_pool, table, lengths, bias, runs,
 @functools.partial(jax.jit, static_argnames=())
 def gather_reference(
     q: jax.Array,        # [B, H, D]
-    k_pool: jax.Array,   # [NB, bs, KV, Dc]
+    k_pool: jax.Array,   # [NB, bs, KV, D]
     v_pool: jax.Array,
     table: jax.Array,    # [B, MB]
     lengths: jax.Array,  # [B]
@@ -895,12 +861,8 @@ def gather_reference(
     per-slot view, then masked GQA attention — both the parity oracle
     for the kernel and the ``"xla"`` side of the auto-pick
     measurement.  Mirrors ``serving/model.py`` exactly: ``gather_blocks
-    [_q|_q4]`` then the unexpanded-cache einsum pair."""
-    from dlrover_tpu.serving.paged import (
-        gather_blocks,
-        gather_blocks_q,
-        gather_blocks_q4,
-    )
+    [_q]`` then the unexpanded-cache einsum pair."""
+    from dlrover_tpu.serving.paged import gather_blocks, gather_blocks_q
 
     b, h, d = q.shape
     kv = k_pool.shape[2]
@@ -908,9 +870,6 @@ def gather_reference(
     if k_scale is None:
         ck = gather_blocks(k_pool, table).astype(jnp.float32)
         cv = gather_blocks(v_pool, table).astype(jnp.float32)
-    elif k_pool.shape[-1] != d:
-        ck = gather_blocks_q4(k_pool, k_scale, table, jnp.float32)
-        cv = gather_blocks_q4(v_pool, v_scale, table, jnp.float32)
     else:
         ck = gather_blocks_q(k_pool, k_scale, table, jnp.float32)
         cv = gather_blocks_q(v_pool, v_scale, table, jnp.float32)
@@ -947,13 +906,8 @@ def kernel_parity(
     before it announces.  The reference's dots run at full f32
     precision so the comparison measures the kernel, not the backend's
     default matmul truncation."""
-    from dlrover_tpu.models.quantize import (
-        quantize_kv_int4,
-        quantize_kv_int8,
-    )
+    from dlrover_tpu.models.quantize import quantize_kv_int8
 
-    if kv_dtype == "int4" and not interpret:
-        return {"kv_dtype": "int4", "refused": INT4_REFUSAL}
     b, mb = slots, max_blocks
     nb = b * mb + 1
     kq, kk, kv_ = jax.random.split(jax.random.PRNGKey(seed), 3)
@@ -962,10 +916,9 @@ def kernel_parity(
     k = (0.3 * jax.random.normal(kk, shape)).astype(dtype)
     v = (0.3 * jax.random.normal(kv_, shape)).astype(dtype)
     scales = {}
-    if kv_dtype in ("int8", "int4"):
-        quant = quantize_kv_int4 if kv_dtype == "int4" else quantize_kv_int8
-        k, scales["k_scale"] = quant(k)
-        v, scales["v_scale"] = quant(v)
+    if kv_dtype == "int8":
+        k, scales["k_scale"] = quantize_kv_int8(k)
+        v, scales["v_scale"] = quantize_kv_int8(v)
     table = jax.random.randint(
         jax.random.fold_in(kq, 1), (b, mb), 1, nb, jnp.int32)
     # one key, odd mid lengths, ..., every column live

@@ -448,10 +448,7 @@ class InferenceEngine:
         HBM-denominated ``cache_blocks`` budget is multiplied by
         ``kv_budget_x`` (~2x for bf16 models), which is what doubles
         the continuous batch the placement ledger can admit at fixed
-        HBM.  ``kv_dtype="int4"`` packs two codes per byte (split-half
-        nibbles, even head_dim required) for ``kv_budget_x`` ~3.7x —
-        coarser rounding, bounded by the drift tests of
-        ``tests/test_paged_kernel.py``.
+        HBM.
 
         ``cache_blocks`` counts blocks of ``block_size`` ROWS, a row
         being what one token keeps in one layer: K and V of every KV head
@@ -661,21 +658,17 @@ class InferenceEngine:
         self.paged = bool(paged)
         if kv_dtype in (None, "bf16"):
             self.kv_dtype = None
-        elif kv_dtype in ("int8", "int4"):
+        elif kv_dtype == "int8":
             if not self.paged:
                 raise ValueError(
                     f"kv_dtype={kv_dtype!r} is a paged-pool feature "
                     "(per-block-scale quantized K/V pools); pass "
                     "paged=True")
-            if kv_dtype == "int4" and cfg.head_dim_ % 2:
-                raise ValueError(
-                    "kv_dtype='int4' packs two codes per byte and "
-                    f"needs an even head_dim (got {cfg.head_dim_})")
             self.kv_dtype = kv_dtype
         else:
             raise ValueError(
                 f"kv_dtype={kv_dtype!r} not supported: use None/'bf16' "
-                "(native), 'int8' or 'int4'")
+                "(native) or 'int8'")
         self.kv_budget_x = 1.0
         # latent attention (serving/latent.py): rows without a head axis
         # in a latent pool and, of a model with an indexer, an index-key
@@ -741,12 +734,8 @@ class InferenceEngine:
             self._table_np = np.zeros(
                 (self.max_slots, self._max_blocks), np.int32
             )
-            # packed int4 pools halve the code dim (two codes/byte,
-            # split-half nibble layout — models/quantize.pack_int4)
-            code_dim = (cfg.head_dim_ // 2 if self.kv_dtype == "int4"
-                        else cfg.head_dim_)
             kvd = (n_blocks, self.block_size,
-                   cfg.num_kv_heads, code_dim)
+                   cfg.num_kv_heads, cfg.head_dim_)
             if self._kinds:
                 from dlrover_tpu.serving.latent import latent_row_width
 
@@ -807,7 +796,7 @@ class InferenceEngine:
                     self._cache[self._state_kind + "_conv"] = [
                         jnp.zeros(conv, cfg.dtype)
                         for _ in range(self._state_layers)]
-            elif self.kv_dtype in ("int8", "int4"):
+            elif self.kv_dtype == "int8":
                 from dlrover_tpu.models.quantize import KV_SCALE_DTYPE
 
                 self._cache = {
@@ -935,16 +924,6 @@ class InferenceEngine:
                     "attention_impl='pallas' reads paged block pools "
                     "in place; pass paged=True")
             return "xla", None
-        refused = ""
-        if self.kv_dtype == "int4" and not self._kernel_interpret:
-            from dlrover_tpu.ops.pallas.paged_attention import (
-                INT4_REFUSAL,
-            )
-
-            refused = INT4_REFUSAL
-        if req == "pallas" and refused:
-            raise ValueError(
-                f"attention_impl='pallas' with kv_dtype='int4': {refused}")
         if req in ("xla", "pallas"):
             self.attention_impl_why = "requested"
             return req, None
@@ -958,9 +937,6 @@ class InferenceEngine:
             # not a perf candidate — auto must not "measure" it
             self.attention_impl_why = (
                 "auto off-chip: the interpret-mode kernel is not timed")
-            return "xla", None
-        if refused:
-            self.attention_impl_why = f"auto: kernel not tried — {refused}"
             return "xla", None
         timings = self._measure_attention()
         self.attention_impl_why = "auto: measured, faster impl kept"
@@ -997,7 +973,7 @@ class InferenceEngine:
             (self.max_slots,), min(self._cache_len, mb * self.block_size),
             jnp.int32)
         kw = {}
-        if self.kv_dtype in ("int8", "int4"):
+        if self.kv_dtype == "int8":
             kw = dict(k_scale=self._cache["k_scale"][0],
                       v_scale=self._cache["v_scale"][0])
         return measure_paged_attention(
@@ -1048,8 +1024,7 @@ class InferenceEngine:
 
         paged = self.paged
         pool_names = self._pool_names
-        kv_quant = self.kv_dtype in ("int8", "int4")
-        kv_packed4 = self.kv_dtype == "int4"
+        kv_quant = self.kv_dtype == "int8"
 
         @functools.partial(jax.jit, donate_argnums=(1,))
         def insert_fn(params, cache, tokens, real_len, slots, skip, rng,
@@ -1073,24 +1048,21 @@ class InferenceEngine:
             with device_scope("prefill"):
                 logits, ks, vs = prefill(params, cfg, tokens, real_len)
             if paged and kv_quant:
-                from dlrover_tpu.serving.paged import (
-                    scatter_tokens_q,
-                    scatter_tokens_q4,
-                )
+                from dlrover_tpu.serving.paged import scatter_tokens_q
 
-                scatter_q = (scatter_tokens_q4 if kv_packed4
-                             else scatter_tokens_q)
                 rows = jnp.take(cache["table"], slots, axis=0)  # [G, MB]
                 zero = jnp.zeros(slots.shape, jnp.int32)
                 kp, ksc, vp, vsc = [], [], [], []
                 for p, sp, k in zip(cache["k_pool"], cache["k_scale"],
                                     ks):
-                    np_, ns_ = scatter_q(p, sp, rows, k, zero, skip)
+                    np_, ns_ = scatter_tokens_q(
+                        p, sp, rows, k, zero, skip)
                     kp.append(np_)
                     ksc.append(ns_)
                 for p, sp, v in zip(cache["v_pool"], cache["v_scale"],
                                     vs):
-                    np_, ns_ = scatter_q(p, sp, rows, v, zero, skip)
+                    np_, ns_ = scatter_tokens_q(
+                        p, sp, rows, v, zero, skip)
                     vp.append(np_)
                     vsc.append(ns_)
                 new_cache = dict(cache, k_pool=kp, k_scale=ksc,
@@ -1836,20 +1808,9 @@ class InferenceEngine:
 
     @property
     def kv_quant_blocks(self) -> int:
-        """Blocks in a quantized (int8 OR int4) KV pool (0 when the
-        pool is native-dtype) — the ``serving_kv_quant_blocks``
-        gauge."""
-        if self.paged and self.kv_dtype in ("int8", "int4"):
-            return self._blockmgr.num_blocks
-        return 0
-
-    @property
-    def kv4_blocks(self) -> int:
-        """Blocks in a packed-int4 KV pool specifically — the
-        ``serving_kv_int4_blocks`` gauge (int4's ~3.7x budget is a
-        different capacity planning regime than int8's ~2x, so the
-        dashboard needs them apart)."""
-        if self.paged and self.kv_dtype == "int4":
+        """Blocks in a quantized (int8) KV pool (0 when the pool is
+        native-dtype) — the ``serving_kv_quant_blocks`` gauge."""
+        if self.paged and self.kv_dtype == "int8":
             return self._blockmgr.num_blocks
         return 0
 

@@ -163,11 +163,11 @@ def index_row_width(cfg: LlamaConfig) -> int:
     """Values of one row of the index-key pool.  Of a GROUPED-QUERY
     model: ``index_head_dim`` and zeros up to whole 128-lane tiles
     (Keye-VL-2.0: 64 -> 128).  A minor dimension of 64 is no DMA slice the
-    chip's compiler takes (``ops/pallas/paged_attention.py
-    INT4_REFUSAL``), and the TPU keeps a ``[blocks, 128, 64]`` bfloat16
-    array in tiles of 128 lanes anyway: the padded pool holds what the
-    unpadded one would.  The index queries carry zeros there too, so no
-    score moves.  Of a latent model: ``index_head_dim`` as it is (128 in
+    chip's compiler takes (Mosaic: "Slice shape along dimension 3 must be
+    aligned to tiling (128), but is 64"), and the TPU keeps a
+    ``[blocks, 128, 64]`` bfloat16 array in tiles of 128 lanes anyway:
+    the padded pool holds what the unpadded one would.  The index queries
+    carry zeros there too, so no score moves.  Of a latent model: ``index_head_dim`` as it is (128 in
     every served one; :func:`_projections` writes unpadded rows)."""
     if cfg.kv_lora_rank:
         return cfg.index_head_dim

@@ -282,14 +282,10 @@ def verify_step(
         pos_k]                                               # [B, K, d/2]
 
     # paged cache ({"k_pool","v_pool","table"}, quantized pools add
-    # {"k_scale","v_scale"}; packed int4 pools are recognized by their
-    # half-width code dim) vs dense ({"k","v"}): same transformer
+    # {"k_scale","v_scale"}) vs dense ({"k","v"}): same transformer
     # loop, different cache plumbing (serving/paged.py)
     paged = "table" in cache
     quant = "k_scale" in cache
-    packed4 = (
-        quant and cache["k_pool"][0].shape[-1] != d
-    )
     use_kernel = (
         paged and attention_impl == "pallas" and klen == 1
         and slots is None and logits_index is None
@@ -298,14 +294,10 @@ def verify_step(
         from dlrover_tpu.serving.paged import (
             gather_blocks,
             gather_blocks_q,
-            gather_blocks_q4,
             scatter_tokens,
             scatter_tokens_q,
-            scatter_tokens_q4,
         )
 
-        scatter_q = scatter_tokens_q4 if packed4 else scatter_tokens_q
-        gather_q = gather_blocks_q4 if packed4 else gather_blocks_q
         table = cache["table"]
         if slots is not None:
             table = jnp.take(table, slots, axis=0)           # [G, MB]
@@ -326,10 +318,10 @@ def verify_step(
         q, k, v = _attn_proj(lp, h, cfg, dtype, angles)
         ck = cv = None
         if paged and quant:
-            kp, ksc = scatter_q(
+            kp, ksc = scatter_tokens_q(
                 cache["k_pool"][i], cache["k_scale"][i], table,
                 k, positions)
-            vp, vsc = scatter_q(
+            vp, vsc = scatter_tokens_q(
                 cache["v_pool"][i], cache["v_scale"][i], table,
                 v, positions)
             if use_kernel:
@@ -339,8 +331,8 @@ def verify_step(
                         k_scale=ksc, v_scale=vsc,
                         interpret=kernel_interpret)[:, None]
             else:
-                ck = gather_q(kp, ksc, table, dtype)
-                cv = gather_q(vp, vsc, table, dtype)
+                ck = gather_blocks_q(kp, ksc, table, dtype)
+                cv = gather_blocks_q(vp, vsc, table, dtype)
             new_k.append(kp)
             new_v.append(vp)
             new_ks.append(ksc)

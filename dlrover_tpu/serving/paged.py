@@ -570,12 +570,11 @@ def kv_budget_multiplier(ref_dtype, head_dim: int,
                          kv_dtype: str = "int8") -> float:
     """THE single source of KV-budget math: how many ``kv_dtype``
     blocks fit in the HBM of one ``ref_dtype`` block.  A quantized
-    (token, head) vector costs its code bytes (``D`` for int8, ``D/2``
-    for packed int4) plus one ``KV_SCALE_DTYPE`` scale —
+    (token, head) vector costs its code bytes (``D`` for int8) plus one
+    ``KV_SCALE_DTYPE`` scale —
     ``D * itemsize(ref) / (code_bytes + itemsize(scale))``.
 
-    bf16 references: int8 -> 1.94x @ D=64 / 1.97x @ D=128; int4 ->
-    3.76x @ D=64 / 3.88x @ D=128 (the >= 3.5x acceptance bar).
+    bf16 references: int8 -> 1.94x @ D=64 / 1.97x @ D=128.
 
     Everything downstream derives from THIS function — the engine
     multiplies its HBM-denominated ``cache_blocks`` budget by it
@@ -590,13 +589,11 @@ def kv_budget_multiplier(ref_dtype, head_dim: int,
 
     if kv_dtype in (None, "bf16"):
         return 1.0
-    code_bytes = {"int8": float(head_dim),
-                  "int4": head_dim / 2.0}.get(kv_dtype)
-    if code_bytes is None:
+    if kv_dtype != "int8":
         raise ValueError(f"kv_budget_multiplier: unknown kv_dtype "
                          f"{kv_dtype!r}")
     ref = int(head_dim) * jnp.dtype(ref_dtype).itemsize
-    return ref / (code_bytes + jnp.dtype(KV_SCALE_DTYPE).itemsize)
+    return ref / (float(head_dim) + jnp.dtype(KV_SCALE_DTYPE).itemsize)
 
 
 @device_scoped("kv_write")
@@ -642,57 +639,6 @@ def gather_blocks_q(
     g = jnp.take(pool, table, axis=0)          # [B, MB, bs, KV, D]
     s = jnp.take(scale_pool, table, axis=0)    # [B, MB, bs, KV]
     return dequantize_kv_int8(
-        g.reshape(b, mb * pool.shape[1], *pool.shape[2:]),
-        s.reshape(b, mb * pool.shape[1], *s.shape[3:]),
-        dtype,
-    )
-
-
-@device_scoped("kv_write")
-def scatter_tokens_q4(
-    pool: jax.Array,        # [NB, bs, KV, D//2] packed int4 codes
-    scale_pool: jax.Array,  # [NB, bs, KV] per-vector scales
-    table: jax.Array,       # [B, MB]
-    kv: jax.Array,          # [B, K, KV, D] new fp entries
-    positions: jax.Array,   # [B]
-    skip_upto: Optional[jax.Array] = None,  # [B] COW write mask
-):
-    """int4 twin of :func:`scatter_tokens_q`: quantize-pack-and-write K
-    consecutive tokens per slot (codes at half a byte per element,
-    per-(token, head) scales in the block-shaped scale pool — same
-    index math, so a write is always self-consistent)."""
-    from dlrover_tpu.models.quantize import quantize_kv_int4
-
-    bs = pool.shape[1]
-    b, k = kv.shape[:2]
-    q, scale = quantize_kv_int4(kv)
-    bidx, off = _block_offsets(table, positions, k, bs, skip_upto)
-    flat_b, flat_o = bidx.reshape(-1), off.reshape(-1)
-    return (
-        pool.at[flat_b, flat_o].set(q.reshape(b * k, *q.shape[2:])),
-        scale_pool.at[flat_b, flat_o].set(
-            scale.reshape(b * k, *scale.shape[2:])),
-    )
-
-
-@device_scoped("paged_attn")
-def gather_blocks_q4(
-    pool: jax.Array,        # [NB, bs, KV, D//2] packed int4 codes
-    scale_pool: jax.Array,  # [NB, bs, KV]
-    table: jax.Array,       # [B, MB]
-    dtype,
-) -> jax.Array:
-    """Dense ``[B, MB*bs, KV, D]`` dequantized view of packed int4
-    pools — unpack + dequant fuse into the consuming attention reads,
-    so the pool streams from HBM at 0.5 bytes/element (the fused
-    Pallas kernel goes further and never materializes this view at
-    all; this is the XLA fallback path)."""
-    from dlrover_tpu.models.quantize import dequantize_kv_int4
-
-    b, mb = table.shape
-    g = jnp.take(pool, table, axis=0)          # [B, MB, bs, KV, D//2]
-    s = jnp.take(scale_pool, table, axis=0)    # [B, MB, bs, KV]
-    return dequantize_kv_int4(
         g.reshape(b, mb * pool.shape[1], *pool.shape[2:]),
         s.reshape(b, mb * pool.shape[1], *s.shape[3:]),
         dtype,

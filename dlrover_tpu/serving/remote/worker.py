@@ -674,14 +674,11 @@ def main(argv: Optional[List[str]] = None) -> int:
                         "(device, depth, pool, attention pick, kernel "
                         "parity) to this JSON file before announcing")
     p.add_argument("--max-len", type=int, default=4096)
-    p.add_argument("--kv-dtype", choices=("bf16", "int8", "int4"),
+    p.add_argument("--kv-dtype", choices=("bf16", "int8"),
                    default="bf16",
                    help="llama engine KV pool storage: int8 = "
                         "per-(token, head)-scale quantized pools "
-                        "(~2x the block budget at the same HBM), "
-                        "int4 = packed two-codes-per-byte pools "
-                        "(~3.7x budget; coarser rounding, bounded "
-                        "by the drift gates)")
+                        "(~2x the block budget at the same HBM)")
     p.add_argument("--attention-impl",
                    choices=("auto", "xla", "pallas"), default="auto",
                    help="llama engine paged decode attention: "

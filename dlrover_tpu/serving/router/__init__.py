@@ -26,9 +26,7 @@ Layers (one module each):
   placement -> streamed tokens -> DONE), both driven by tier-1 tests;
 - :mod:`metrics`   — Prometheus gauges/counters for all of the above;
 - :mod:`router`    — the orchestrating pump, behind the step-engine
-  seam (``step_engine="event" | "sweep"``);
-- :mod:`stepengine` — the sharded router front: N independent step
-  loops, requests partitioned by rid hash, shared brown-out view.
+  seam (``step_engine="event" | "sweep"``).
 
 Tenancy (who is asking, as opposed to how urgent) lives one package up
 in :mod:`dlrover_tpu.serving.tenancy` — policy + accounting with no
@@ -70,9 +68,6 @@ from dlrover_tpu.serving.router.autoscale import (  # noqa: F401
 from dlrover_tpu.serving.router.slo import (  # noqa: F401
     SloEngine,
     SloObjective,
-)
-from dlrover_tpu.serving.router.stepengine import (  # noqa: F401
-    ShardedRouterFront,
 )
 from dlrover_tpu.serving.tenancy import (  # noqa: F401
     TenantRegistry,

@@ -422,9 +422,8 @@ class RequestGateway:
         # tenant identity + QoS contracts; the default registry is the
         # trivial single-tenant fleet (everything resolves to one
         # unmetered weight-1.0 tenant — WFQ degenerates to exact FIFO
-        # and nothing below behaves differently from pre-tenancy).  A
-        # sharded front passes ONE registry shared across its shard
-        # gateways so quotas meter fleet traffic, not per-shard slices.
+        # and nothing below behaves differently from pre-tenancy).
+        # Gateways handed ONE registry meter their traffic together.
         self.tenants = tenants if tenants is not None else TenantRegistry()
         # tracing is on by default: stdlib-only dict/deque bookkeeping
         # whose memory is capped by the tracer's bounded rings, so

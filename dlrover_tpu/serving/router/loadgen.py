@@ -40,8 +40,8 @@ and drives it at the in-process serving stack:
 
 Everything here is driver-side; the router under test is the real
 one, unmodified — any object with ``submit``/``step``/``has_work``
-(a :class:`~dlrover_tpu.serving.router.router.ServingRouter` or the
-sharded front) drives identically.
+(a :class:`~dlrover_tpu.serving.router.router.ServingRouter`) drives
+identically.
 """
 
 from __future__ import annotations
@@ -459,9 +459,8 @@ def run_router_rig(
 
     - every admitted request object is KEPT and audited at the end —
       zero-lost means zero requests outside a terminal state, and the
-      books identity is computed from the requests themselves, so the
-      rig works unchanged against a single router or the sharded
-      front (whose counters live in N shards);
+      books identity is computed from the requests themselves, not
+      from the router's counters;
     - the headline number is sustained END-TO-END QPS: completed
       requests over the whole wall (offer + drain) — the step loop
       cannot hide behind a fast front door;
@@ -471,9 +470,7 @@ def run_router_rig(
       later (seeded by admission order, replayable): the mid-flight
       cancel mix the nightly soak runs.
 
-    ``step_every`` bounds admissions between router rounds; a threaded
-    sharded front self-drives and its ``step()`` briefly yields
-    instead, which keeps this driver loop correct for both."""
+    ``step_every`` bounds admissions between router rounds."""
     cfg = config or LoadgenConfig()
     gen = OpenLoopGenerator(cfg)
     content = cfg.workload != "independent"

@@ -129,7 +129,6 @@ class RouterMetrics:
         # the introspection dict — local adapters and llama workers)
         self.spec_accept_ratio = 0.0
         self.kv_quant_blocks = 0.0
-        self.kv4_blocks = 0.0
         self.prefill_chunk_seconds = 0.0
         self.paged_kernel_step_seconds = 0.0
         self.kv_rows_live = 0.0
@@ -299,8 +298,6 @@ class RouterMetrics:
             sum(ratios) / len(ratios) if ratios else 0.0)
         self.kv_quant_blocks = sum(
             d.get("kv_quant_blocks", 0.0) for d in dicts)
-        self.kv4_blocks = sum(
-            d.get("kv4_blocks", 0.0) for d in dicts)
         self.prefill_chunk_seconds = sum(
             d.get("prefill_chunk_seconds", 0.0) for d in dicts)
         self.paged_kernel_step_seconds = sum(
@@ -416,7 +413,6 @@ class RouterMetrics:
             "serving_capacity_debt": self.capacity_debt,
             "serving_spec_accept_ratio": self.spec_accept_ratio,
             "serving_kv_quant_blocks": self.kv_quant_blocks,
-            "serving_kv_int4_blocks": self.kv4_blocks,
             "serving_prefill_chunk_seconds": self.prefill_chunk_seconds,
             "serving_paged_kernel_step_seconds":
                 self.paged_kernel_step_seconds,

@@ -153,7 +153,6 @@ class InferenceEngineAdapter:
             "tokens_per_forward": st.tokens_per_forward,
             "kv_quant_blocks": float(
                 getattr(eng, "kv_quant_blocks", 0)),
-            "kv4_blocks": float(getattr(eng, "kv4_blocks", 0)),
             "prefill_chunk_seconds": st.prefill_chunk_seconds,
             "prefill_calls": float(st.prefill_calls),
             "prefill_admissions": float(st.prefill_admissions),

@@ -37,9 +37,7 @@ Step engines (the data-plane raw-speed seam, ``step_engine=``):
 Both engines observe the same step-phase histograms
 (``serving_step_phase_seconds{phase=...}``) and step-lock hold-time
 histogram (``serving_step_lock_hold_seconds``) — instrument first,
-then attack what the histograms name.  A sharded front over N
-independent routers lives in
-:mod:`dlrover_tpu.serving.router.stepengine`.
+then attack what the histograms name.
 """
 
 from __future__ import annotations
@@ -146,11 +144,6 @@ class ServingRouter:
         self.brownout = brownout
         if brownout is not None:
             self.gateway.brownout = brownout
-        # sharded-front hook: when True the brown-out POLICY object is
-        # updated by an external owner (the front, with fleet-global
-        # depth/capacity) and this router only APPLIES the stage's
-        # shedding to its own shard (stepengine.ShardedRouterFront)
-        self.brownout_external = False
         self.scheduler = scheduler or ContinuousBatchScheduler()
         self.manager = manager or ReplicaManager()
         self.metrics = metrics or RouterMetrics()
@@ -924,21 +917,7 @@ class ServingRouter:
         the watermark, record stage transitions, and at stage 2+
         expiry-cancel queued and in-flight BATCH through the cancel
         machinery — decisions here, deliveries after lock release via
-        ``cancels`` (a remote CANCEL is a frame send; DL003/DL007).
-
-        With ``brownout_external`` set (the sharded front), the policy
-        object is updated by its owner with FLEET-GLOBAL depth and
-        capacity; this router only applies the already-decided stage's
-        shedding to its own shard."""
-        if self.brownout_external:
-            stage = self.brownout.stage
-            self.metrics.brownout_stage = float(stage)
-            if not self.brownout.cancels_batch:
-                return
-            self._brownout_cancel_batch(
-                now, cancels, dumps,
-                keep_total=self._brownout_keep_total(now))
-            return
+        ``cancels`` (a remote CANCEL is a frame send; DL003/DL007)."""
         capacity = self._capacity(now)
         prev = self.brownout.stage
         stage = self.brownout.update(now, self.gateway.depth(), capacity)
@@ -973,15 +952,6 @@ class ServingRouter:
             except Exception:
                 continue  # a dying replica's ledger is not capacity
         return capacity
-
-    def _brownout_keep_total(self, now: float) -> Optional[int]:
-        """Multi-tenant survivor budget for a brown-out BATCH shed:
-        the queued depth at which the ladder would START de-escalating
-        (local capacity x the exit watermark).  Trivial registry →
-        None, the legacy whole-band clear."""
-        if self.gateway.tenants.trivial:
-            return None
-        return int(self._capacity(now) * self.brownout.exit_pressure)
 
     def _brownout_cancel_batch(self, now: float, cancels: List[tuple],
                                dumps: List[tuple],

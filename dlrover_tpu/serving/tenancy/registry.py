@@ -133,11 +133,11 @@ class _TokenBucket:
 class TenantRegistry:
     """Registered tenants + resolution + quota state + accounting.
 
-    Thread-safe where it must be: the gateway consults it under its
-    own admission lock, but a :class:`~dlrover_tpu.serving.router.
-    stepengine.ShardedRouterFront` shares ONE registry across N
-    shard gateways (a per-shard registry would multiply every quota
-    by N), so bucket consumption takes the registry's own lock."""
+    Thread-safe where it must be: a gateway consults it under its own
+    admission lock, but ONE registry may sit behind several gateways
+    that are called from several threads (a registry a gateway would
+    multiply every quota by their number), so bucket consumption
+    takes the registry's own lock."""
 
     def __init__(self, specs: Iterable[TenantSpec] = (),
                  default_tenant: str = "default"):

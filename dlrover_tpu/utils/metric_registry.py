@@ -253,12 +253,6 @@ METRIC_HELP: Dict[str, str] = {
         "num_experts when routing is even; 0 = no replica serves "
         "sparse experts"
     ),
-    "serving_kv_int4_blocks": (
-        "KV cache blocks held in packed-int4 pools across the fleet "
-        "(a subset of serving_kv_quant_blocks) — int4's ~3.7x budget "
-        "multiplier is a different capacity-planning regime than "
-        "int8's ~2x, so the dashboard needs the split"
-    ),
     "serving_rpc_retries_total": (
         "control-plane RPC retries under the typed backoff policy "
         "(common/retry) — a rising value under a steady fleet says "

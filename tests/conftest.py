@@ -5,6 +5,7 @@ virtual 8-device CPU mesh: the env vars below MUST be set before the first
 `import jax` anywhere in the test process.
 """
 
+import collections
 import os
 import shutil
 import tempfile
@@ -44,11 +45,54 @@ jax.config.update("jax_platforms", "cpu")
 import pytest  # noqa: E402
 
 
+# The files a run hands out FIRST, in this order, one to a worker.  Each
+# holds one test that is a multi-process world of 45-220 s (most of it
+# sleeps by design), and xdist's ``--dist loadfile`` hands files out by
+# their number of tests, most first: a file of one test goes LAST and
+# runs alone while the other workers have shut down (PR 59's run: the
+# last 150-200 s of 1 153 were these, ROADMAP D12).  The rule: a test
+# file of fewer than three tests that takes more than 60 s is added
+# here, not left to be the run's tail.
+WORLDS_FIRST = (
+    "test_goodput_e2e.py",
+    "test_multislice_elastic_e2e.py",
+    "test_elastic_spmd_e2e.py",
+    "test_elastic_spmd_grow_e2e.py",
+)
+
+
+def file_order(nodeids):
+    """Rank of every file among ``nodeids``: ``WORLDS_FIRST`` in its
+    order, then the files by their number of tests, most first, ties by
+    name (what xdist did for them).  Reads nothing but the node ids, so
+    every worker of a run collects the same order."""
+    counts = collections.Counter(n.split("::", 1)[0] for n in nodeids)
+
+    def key(path):
+        name = path.rsplit("/", 1)[-1]
+        if name in WORLDS_FIRST:
+            return (0, WORLDS_FIRST.index(name), path)
+        return (1, -counts[path], path)
+
+    return {path: rank for rank, path in enumerate(sorted(counts, key=key))}
+
+
 def pytest_configure(config):
     # the controller of an xdist run: its workers see this name as
     # PYTEST_XDIST_TESTRUNUID, so all of them share the directory above
     if getattr(config.option, "testrunuid", "") is None:
         config.option.testrunuid = _RUN
+    # ... and it hands files out in the order they were collected
+    # (``pytest_collection_modifyitems`` below), not by count alone
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
+@pytest.hookimpl(trylast=True)  # behind ``-m``: count what will run
+def pytest_collection_modifyitems(items):
+    # stable and keyed by file only: a file's tests stay in its own order
+    rank = file_order([item.nodeid for item in items])
+    items.sort(key=lambda item: rank[item.nodeid.split("::", 1)[0]])
 
 
 @pytest.hookimpl(trylast=True)

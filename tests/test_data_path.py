@@ -182,8 +182,16 @@ def test_index_sharding_client_recovers_after_failure(local_master):
     got = [sc.fetch_sample_index(timeout=10) for _ in range(4)]
     assert got == [0, 1, 2, 3]
     sc.report_batch_done(2)  # only the first shard's samples were trained
-    # worker 0 "dies": in-flight (fetched, unacked) shards recovered
-    time.sleep(0.3)  # let prefetch pull ahead
+    # worker 0 "dies": in-flight (fetched, unacked) shards recovered.
+    # First the prefetcher comes to rest, on what it shows and not on a
+    # clock: it holds three unacked shards ((2,3) dequeued, (4,5) filling
+    # the index queue, (6,7) waiting for room) and asks the master for
+    # nothing more until a sample is taken.  A task it were handed AFTER
+    # the failure report would be a dead worker's, and lost.
+    deadline = time.monotonic() + 30.0
+    while not (sc._task_fifo.qsize() == 3 and sc._index_queue.full()):
+        assert time.monotonic() < deadline, "the prefetcher never rested"
+        time.sleep(0.01)
     c0.report_failure("killed", level="node", node_rank=0)
     sc.close()
 

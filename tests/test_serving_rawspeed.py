@@ -329,10 +329,13 @@ def test_kv_int8_budget_multiplier_feeds_pool_and_ledger(setup):
 def test_kv_dtype_validation(setup):
     with pytest.raises(ValueError, match="paged=True"):
         _engine(setup, kv_dtype="int8")
-    with pytest.raises(ValueError, match="paged=True"):
-        _engine(setup, kv_dtype="int4")
     with pytest.raises(ValueError, match="not supported"):
         _engine(setup, paged=True, kv_dtype="fp8")
+    # the packed pool left the tree (PR 60): refused by name like any
+    # unknown value, paged or not
+    for paged in (False, True):
+        with pytest.raises(ValueError, match="'int4' not supported"):
+            _engine(setup, paged=paged, kv_dtype="int4")
 
 
 # -- speculative accept into paged KV --------------------------------------

@@ -1,7 +1,6 @@
 """Step-engine suite (ISSUE 15): the router data-plane rebuild.
 
-Covers the seam itself (event loop vs historical sweep vs the sharded
-front), the incremental placement index's no-rescan guarantee (the
+Covers the seam itself (event loop vs historical sweep), the incremental placement index's no-rescan guarantee (the
 scheduling-decision-count regression pin the acceptance criteria
 name), the event-driven cancel/expiry sweeps, batched frame drains,
 the step-phase/step-lock histograms on /metrics, the full-pipeline
@@ -11,7 +10,7 @@ sampled traceparent fast path).
 The equivalence test is the safety net under the whole refactor: the
 same seeded workload — mixed priorities, cancels, an expiry, a replica
 failure — must reach the SAME terminal state and output per submitted
-request under the old sweep, the event loop, and the sharded front.
+request under the old sweep and the event loop.
 """
 
 import dataclasses
@@ -39,19 +38,15 @@ from dlrover_tpu.serving.router import (  # noqa: E402
     PRIORITY_BATCH,
     PRIORITY_HIGH,
     PRIORITY_NORMAL,
-    BrownoutPolicy,
-    BrownoutShedError,
     ContinuousBatchScheduler,
     RequestGateway,
     RouterMetrics,
     ServingRouter,
-    ShardedRouterFront,
 )
 from dlrover_tpu.serving.router.loadgen import (  # noqa: E402
     LoadgenConfig,
     run_router_rig,
 )
-from dlrover_tpu.serving.router.stepengine import shard_of  # noqa: E402
 
 
 def _prompt(i, n=8):
@@ -77,22 +72,6 @@ def test_step_engine_validation():
     assert r.step_engine == "event"
     assert r.gateway.incremental is True
     assert r.scheduler.incremental is True
-
-
-def test_sharded_front_partitions_by_rid_hash():
-    front = ShardedRouterFront(num_shards=3)
-    for i in range(3):
-        front.shards[i].join_replica(
-            f"r{i}", FakeEngine(slots=8, tokens_per_step=8))
-    reqs = [front.submit(_prompt(i), 4) for i in range(30)]
-    per_shard = [s.gateway.submitted for s in front.shards]
-    assert sum(per_shard) == 30
-    assert all(n > 0 for n in per_shard), per_shard
-    front.run_until_idle()
-    for r in reqs:
-        assert r.state == ServingRequestState.DONE
-    # the partition function itself is deterministic and total
-    assert {shard_of(rid, 3) for rid in range(100)} == {0, 1, 2}
 
 
 # -- placement fast path: the scheduling-decision-count pin ------------------
@@ -385,31 +364,20 @@ def _replay_workload(router):
             reqs[40].cancel()
         if step == 4:
             # kill one replica: its in-flight requests fail over
-            target = (router.shard_of_replica("r1")
-                      if isinstance(router, ShardedRouterFront)
-                      else router)
-            if target is not None:
-                target.fail_replica("r1")
+            router.fail_replica("r1")
         if not router.has_work:
             break
     return [(r.state, len(r.output)) for r in reqs]
 
 
-@pytest.mark.parametrize("candidate", ["sweep", "sharded"])
+@pytest.mark.parametrize("candidate", ["sweep"])
 def test_step_engine_equivalence_terminal_states(candidate):
     """Same seeded workload -> same terminal state and output per
     submitted request under the event loop (the shipped default) and
-    each other candidate.  Placement DISTRIBUTION may differ (the
-    index breaks capacity ties by name, shards partition replicas);
-    request OUTCOME may not."""
+    the reference.  Placement DISTRIBUTION may differ (the index breaks
+    capacity ties by name); request OUTCOME may not."""
     baseline = _replay_workload(_router("event"))
-    if candidate == "sweep":
-        other = _replay_workload(_router("sweep"))
-    else:
-        front = ShardedRouterFront(
-            num_shards=2, threaded=False,
-            router_factory=lambda i: _router("event"))
-        other = _replay_workload(front)
+    other = _replay_workload(_router(candidate))
     assert len(baseline) == len(other)
     for i, (a, b) in enumerate(zip(baseline, other)):
         assert a == b, f"submission {i}: event={a} {candidate}={b}"
@@ -422,14 +390,8 @@ def test_step_engine_equivalence_terminal_states(candidate):
 def test_failover_equivalence_zero_lost():
     """A replica failure mid-run balances the books under every
     engine: every request terminal, requeues observed, zero poisoned."""
-    for make in (
-        lambda: _router("event"),
-        lambda: _router("sweep"),
-        lambda: ShardedRouterFront(
-            num_shards=2, threaded=False,
-            router_factory=lambda i: _router("event")),
-    ):
-        router = make()
+    for engine in ("event", "sweep"):
+        router = _router(engine)
         t = 7000.0
         for i in range(4):
             router.join_replica(
@@ -441,79 +403,15 @@ def test_failover_equivalence_zero_lost():
             t += 0.05
             router.step(now=t)
             if step == 3:
-                if isinstance(router, ShardedRouterFront):
-                    victim = router.replica_names[0]
-                    router.shard_of_replica(victim).fail_replica(
-                        victim)
-                else:
-                    router.fail_replica("r0")
+                router.fail_replica("r0")
             if not router.has_work:
                 break
         for r in reqs:
             assert r.state == ServingRequestState.DONE, (
                 r.rid, r.state)
-        if isinstance(router, ShardedRouterFront):
-            counters = router.counters()
-            assert counters["serving_requests_requeued_total"] >= 1
-            assert counters["serving_requests_poisoned_total"] == 0
-        else:
-            m = router.metrics.metrics()
-            assert m["serving_requests_requeued_total"] >= 1
-            assert m["serving_requests_poisoned_total"] == 0
-
-
-# -- sharded front: threads, shared brown-out, remote chaos ------------------
-
-
-def test_sharded_front_threaded_books_balance():
-    front = ShardedRouterFront(num_shards=2, threaded=True)
-    for i in range(4):
-        front.join_replica(
-            f"r{i}", FakeEngine(slots=8, tokens_per_step=8))
-    front.start()
-    try:
-        reqs = [front.submit(_prompt(i), 8) for i in range(200)]
-        deadline = time.monotonic() + 30.0
-        while front.has_work and time.monotonic() < deadline:
-            time.sleep(0.005)
-        for r in reqs:
-            assert r.state == ServingRequestState.DONE, (
-                r.rid, r.state)
-        counters = front.counters()
-        assert counters["serving_requests_submitted_total"] == 200
-        assert counters["serving_requests_completed_total"] == 200
-    finally:
-        front.stop()
-
-
-def test_sharded_front_shared_brownout_sheds_every_shard():
-    """The shared brown-out view: the FRONT updates one policy with
-    fleet-global pressure; once the ladder enters shed_batch, EVERY
-    shard's gateway refuses BATCH — a shard with a locally-empty queue
-    must shed too (per-shard watermarks would not)."""
-    bo = BrownoutPolicy(enter_pressure=2.0, exit_pressure=0.5,
-                        dwell_seconds=0.5)
-    front = ShardedRouterFront(
-        num_shards=2, threaded=False, brownout=bo,
-        router_factory=lambda i: ServingRouter(
-            scheduler=ContinuousBatchScheduler(block_size=4)))
-    # capacity exists on shard 0 only; demand floods both queues
-    front.shards[0].join_replica(
-        "r0", FakeEngine(slots=1, tokens_per_step=1, max_len=4096))
-    t = 9000.0
-    front.step(now=t)
-    for i in range(40):
-        front.submit(_prompt(i), 500, priority=PRIORITY_NORMAL, now=t)
-    front.step(now=t)
-    front.step(now=t + 0.6)   # dwell earned -> stage 1
-    assert bo.stage == 1
-    for shard in front.shards:
-        with pytest.raises(BrownoutShedError):
-            shard.submit(_prompt(99), 4, priority=PRIORITY_BATCH,
-                         now=t + 0.7)
-    # both shards applied the externally-decided stage to metrics
-    for shard in front.shards:
-        assert shard.metrics.brownout_stage == 1.0
+        m = router.metrics.metrics()
+        assert m["serving_requests_requeued_total"] >= 1
+        assert m["serving_requests_poisoned_total"] == 0
 
 
 class _ThreadedWorker:
@@ -526,51 +424,6 @@ class _ThreadedWorker:
 
     def stop(self):
         self.server.crash()
-
-
-def test_sharded_front_remote_chaos_zero_lost():
-    """The sharded twin of the chaos acceptance: remote workers behind
-    the front's independent (threaded) step loops, one killed abruptly
-    mid-stream — zero lost requests, books balance fleet-wide."""
-    from dlrover_tpu.serving.remote.proxy import RemoteReplicaHandle
-
-    workers = [_ThreadedWorker(slots=4, tokens_per_step=2,
-                               step_delay=0.002) for _ in range(4)]
-    front = ShardedRouterFront(num_shards=2, threaded=True)
-    try:
-        for i, w in enumerate(workers):
-            front.join_replica(
-                f"w{i}", RemoteReplicaHandle(
-                    w.server.addr, name=f"w{i}", frame_timeout=1.0))
-        front.start()
-        reqs = [front.submit(_prompt(i), 8) for i in range(120)]
-        # kill one worker once it holds in-flight requests
-        victim = None
-        deadline = time.monotonic() + 20.0
-        while victim is None and time.monotonic() < deadline:
-            for i, w in enumerate(workers):
-                shard = front.shard_of_replica(f"w{i}")
-                handle = shard.manager.get(f"w{i}") if shard else None
-                if handle is not None and handle.inflight:
-                    victim = i
-                    break
-            time.sleep(0.005)
-        assert victim is not None
-        workers[victim].stop()
-        deadline = time.monotonic() + 45.0
-        while front.has_work and time.monotonic() < deadline:
-            time.sleep(0.01)
-        lost = [r for r in reqs
-                if r.state != ServingRequestState.DONE]
-        assert not lost, [(r.rid, r.state) for r in lost]
-        counters = front.counters()
-        assert counters["serving_requests_completed_total"] == 120
-        assert counters["serving_requests_requeued_total"] >= 1
-        assert counters["serving_requests_poisoned_total"] == 0
-    finally:
-        front.stop()
-        for w in workers:
-            w.stop()
 
 
 # -- instrumentation on /metrics ---------------------------------------------

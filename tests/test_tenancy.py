@@ -11,6 +11,7 @@ depth pressure produced it.
 """
 
 import textwrap
+import threading
 from types import SimpleNamespace
 
 import numpy as np
@@ -27,7 +28,6 @@ from dlrover_tpu.serving.router import (
     RequestGateway,
     RouterMetrics,
     ServingRouter,
-    ShardedRouterFront,
     TenantQuotaError,
 )
 from dlrover_tpu.serving.router.brownout import (
@@ -492,26 +492,41 @@ def test_slo_class_burn_tracks_premium_separately():
     assert "class:premium" in summary
 
 
-# ------------------------------------------------ sharded front share
+# ------------------------------------- one registry, several gateways
 
 
-def test_sharded_front_shares_one_registry():
-    reg = TenantRegistry([TenantSpec("t", quota_qps=2.0, burst=2.0)])
-    front = ShardedRouterFront(num_shards=2, tenants=reg)
-    try:
-        gws = [s.gateway for s in front.shards]
-        assert all(gw.tenants is reg for gw in gws)
-        # ONE bucket fleet-wide: 2 tokens total, not 2 per shard
-        admitted, refused = 0, 0
-        for i in range(6):
+def test_two_gateways_on_two_threads_consume_one_quota():
+    """ONE registry behind two plain gateways, each called from its own
+    thread: the tenant's bucket is consumed once, not once a gateway
+    (why the registry takes its own lock: neither gateway's admission
+    lock covers the other's call)."""
+    burst = 50
+    reg = TenantRegistry([TenantSpec("t", quota_qps=1.0, burst=burst)])
+    gateways = [RequestGateway(max_pending=4096, tenants=reg)
+                for _ in range(2)]
+    admitted, refused = [0, 0], [0, 0]
+    barrier = threading.Barrier(2)
+
+    def flood(i):
+        barrier.wait()
+        for n in range(200):
             try:
-                front.submit(_prompt(i), 2, tenant="t", now=77.0)
-                admitted += 1
+                gateways[i].submit(_prompt(n), 2, tenant="t", now=77.0)
+                admitted[i] += 1
             except TenantQuotaError:
-                refused += 1
-        assert admitted == 2 and refused == 4
-    finally:
-        front.stop()
+                refused[i] += 1
+
+    threads = [threading.Thread(target=flood, args=(i,)) for i in (0, 1)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(30.0)
+    assert not any(t.is_alive() for t in threads)
+    # the clock stands still, so the bucket holds its burst and no more
+    assert sum(admitted) == burst and sum(refused) == 400 - burst
+    assert reg.admitted["t"] == burst
+    assert reg.quota_rejected["t"] == 400 - burst
+    assert [gw.depth() for gw in gateways] == admitted
 
 
 # ------------------------------------------------- noisy neighbor gate

@@ -22,10 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from dlrover_tpu.ops.attention import dot_product_attention
 from dlrover_tpu.ops.pallas.flash_attention import flash_attention
-from dlrover_tpu.ops.pallas.paged_attention import (
-    INT4_REFUSAL,
-    paged_decode_attention,
-)
+from dlrover_tpu.ops.pallas.paged_attention import paged_decode_attention
 from dlrover_tpu.ops.pallas.quant_matmul import int8_matmul
 
 
@@ -165,9 +162,8 @@ def _paged_args(one_chip, heads, kv_heads, kv_dtype, d=128, bs=16,
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    code = {"bf16": (d, jnp.bfloat16), "int8": (d, jnp.int8),
-            "int4": (d // 2, jnp.int8)}[kv_dtype]
-    pool = s((nb, bs, kv_heads, code[0]), code[1])
+    code = {"bf16": jnp.bfloat16, "int8": jnp.int8}[kv_dtype]
+    pool = s((nb, bs, kv_heads, d), code)
     scale = None if kv_dtype == "bf16" else \
         s((nb, bs, kv_heads), jnp.bfloat16)
     return (s((slots, heads, d), jnp.bfloat16), pool, pool,
@@ -660,17 +656,6 @@ def test_granite_programs_keep_their_state_in_place(one_chip, monkeypatch,
     one_state = 128 * 128 * 64 * 128 * 4
     assert memory.alias_size_in_bytes >= 9 * one_state
     assert memory.temp_size_in_bytes < one_state
-
-
-def test_paged_decode_int4_is_refused_loudly(one_chip):
-    """Packed int4 pools do not compile on a TPU (minor dimension 64);
-    until the pool is re-laid the kernel refuses in the repo's own
-    words, at trace time — it never runs the gather under its name."""
-    args = _paged_args(one_chip, kv_dtype="int4",
-                       **_GEOMETRIES["bench16x4"])
-    with pytest.raises(NotImplementedError) as err:
-        jax.jit(_paged).lower(*args)
-    assert str(err.value) == INT4_REFUSAL
 
 
 @pytest.mark.parametrize("rows", [8, 4096])
