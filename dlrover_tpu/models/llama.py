@@ -1302,36 +1302,32 @@ class Attention(nn.Module):
             return o_proj(out)
 
 
-def causal_depthwise_conv(v: jax.Array, taps: jax.Array,
-                          segment_ids: Optional[jax.Array] = None
-                          ) -> jax.Array:
-    """``out[t] = sum_j taps[j] * v[t - (K - 1 - j)]``: v [b, s, c], taps
-    [K, c] with the OLDEST position's tap first and the current one's
-    last, one weight a channel and tap; float32 sums whatever comes in.
-    Zeros stand ahead of a sequence's first token and, where
-    ``segment_ids`` [b, s] are given, ahead of a SEGMENT's: a packed row
-    does not leak across documents.  Shifted multiply-adds, so the
-    backward is a convolution again (the shifts the other way)."""
-    k, s = taps.shape[0], v.shape[1]
-    v, taps = v.astype(jnp.float32), taps.astype(jnp.float32)
-    out = v * taps[k - 1]
-    for back in range(1, min(k, s)):
-        shifted = jnp.pad(v, ((0, 0), (back, 0), (0, 0)))[:, :s]
-        if segment_ids is not None:
-            before = jnp.pad(segment_ids, ((0, 0), (back, 0)),
-                             constant_values=-1)[:, :s]
-            shifted = jnp.where((before == segment_ids)[..., None],
-                                shifted, 0.0)
-        out = out + shifted * taps[k - 1 - back]
-    return out
-
-
 def _taps_init(key, shape, dtype):
     """N(0, 1/3) (LeCun-normal by a channel's fan-in of three taps), with 1
     added to the current position's tap: at initialisation a channel
     passes its own token on and sees the ones before it."""
     taps = jax.random.normal(key, shape, jnp.float32) * shape[0] ** -0.5
     return taps.at[-1].add(1.0).astype(dtype)
+
+
+def _conv_mix(bcu, taps, segment_ids):
+    """``C * conv(B * u)`` of ``in_proj``'s output ``bcu`` [b, s, 3, h]:
+    one kernel pass each way over its three planes where one device holds
+    whole tiles of an unpacked sequence on a TPU
+    (``ops/pallas/short_conv.py``), the ``jnp`` oracle elsewhere.  A packed
+    row (``segment_ids``) takes the ``jnp`` form: the kernels put zeros
+    ahead of a ROW, not of a segment."""
+    from dlrover_tpu.ops.pallas import short_conv
+
+    # [b, 3, s, h] is how the projection lies in memory: no pass
+    bcu = bcu.transpose(0, 2, 1, 3)
+    mesh = ambient_mesh()
+    # a Mosaic kernel cannot be partitioned by GSPMD: one device's work
+    if (segment_ids is None and jax.default_backend() == "tpu"
+            and short_conv.kernel_takes(bcu, taps)
+            and (mesh is None or mesh.size == 1)):
+        return short_conv.gated_short_conv(bcu, taps)
+    return short_conv.gated_conv_reference(bcu, taps, segment_ids)
 
 
 class ShortConv(nn.Module):
@@ -1362,9 +1358,7 @@ class ShortConv(nn.Module):
             "taps", nn.with_logical_partitioning(_taps_init, (None, "mlp")),
             (cfg.conv_taps, h), cfg.param_dtype)
         with device_scope("conv_mix"):
-            b, c, u = (bcu[..., i, :].astype(jnp.float32) for i in range(3))
-            y = (c * causal_depthwise_conv(b * u, taps, segment_ids)
-                 ).astype(cfg.dtype)
+            y = _conv_mix(bcu, taps, segment_ids)
         with device_scope("conv_proj"):
             y = with_logical_constraint(y, ("batch", "seq", "mlp"))
             return nn.DenseGeneral(

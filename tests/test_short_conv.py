@@ -1,8 +1,12 @@
 """The gated short convolution (``models/llama.py ShortConv`` and
-``causal_depthwise_conv``), the first mixer ``LlamaModel`` trains that is
-not attention, against a token-by-token loop: forward and gradient in
-float32 to 1e-6, causality, the zeros ahead of a sequence and of a
-segment, the taps' order, bf16 in and float32 sums."""
+``ops/pallas/short_conv.py``: ``causal_depthwise_conv``, the ``jnp`` form,
+and the kernel pair in Pallas's interpreter), the first mixer
+``LlamaModel`` trains that is not attention, against a token-by-token
+loop: forward and gradient in float32 to 1e-6, causality, the zeros ahead
+of a sequence and of a segment, the taps' order, bf16 in and float32 sums,
+and which form ``ShortConv`` takes."""
+
+import functools
 
 import flax.linen as nn
 import jax
@@ -10,8 +14,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dlrover_tpu.models.llama import (LlamaConfig, ShortConv,
-                                      causal_depthwise_conv)
+from dlrover_tpu.models.llama import LlamaConfig, ShortConv
+from dlrover_tpu.ops.pallas import short_conv
+from dlrover_tpu.ops.pallas.short_conv import causal_depthwise_conv
 
 TOL = 1e-6
 B, S, C, K = 2, 12, 8, 3
@@ -188,3 +193,141 @@ def test_the_taps_start_as_a_pass_through_of_the_current_token(block):
     # N(0, 1/3), with 1 added to the current position's tap (the last)
     assert abs(wide[:2].mean()) < 0.05 and abs(wide[2].mean() - 1) < 0.08
     assert abs(wide.var(axis=1).mean() - 1 / 3) < 0.05
+
+
+# --- the kernel pair (``gated_conv_fwd`` / ``gated_conv_bwd``) in the
+# interpreter: 192 rows are three tiles of 64, each two inner steps of 32,
+# so the taps reach across a tile's edge and an inner step's, both ways
+
+
+def _planes(k, s, h, dtype, b=2):
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    bcu = jax.random.normal(keys[0], (b, 3, s, h), jnp.float32)
+    taps = jax.random.normal(keys[1], (k, h), jnp.float32) * k ** -0.5
+    g = jax.random.normal(keys[2], (b, s, h), jnp.float32)
+    return bcu.astype(dtype), taps.astype(dtype), g.astype(dtype)
+
+
+def _loop_gated(bcu, taps, g):
+    """``y = C * conv(B * u)`` and the gradients of ``sum(y * g)``, a
+    token and tap at a time in float64."""
+    bcu, taps, g = (np.asarray(a.astype(jnp.float32), np.float64)
+                    for a in (bcu, taps, g))
+    (b, c, u), k, s = (bcu[:, i] for i in range(3)), taps.shape[0], g.shape[1]
+    v = b * u
+    conv = _loop_conv(v, taps)
+    dconv, dv, dtaps = g * c, np.zeros_like(v), np.zeros_like(taps)
+    for t in range(s):
+        for j in range(k):
+            src = t - (k - 1 - j)
+            if src >= 0:
+                dv[:, src] += taps[j] * dconv[:, t]
+                dtaps[j] += (v[:, src] * dconv[:, t]).sum(0)
+    return c * conv, np.stack([dv * u, g * conv, dv * b], 1), dtaps
+
+
+def _forms(bcu, taps, g):
+    """(y, d bcu, d taps) of the kernel pair and of the ``jnp`` form."""
+    def of(fn):
+        y, vjp = jax.vjp(fn, bcu, taps)
+        return (y,) + vjp(g)
+
+    return (of(lambda x, w: short_conv.gated_short_conv(x, w, True)),
+            of(short_conv.gated_conv_reference))
+
+
+@pytest.mark.parametrize("k,h,dtype", [
+    (3, 256, jnp.float32), (4, 256, jnp.float32), (3, 384, jnp.float32),
+    (3, 256, jnp.bfloat16)],
+    ids=["three-taps", "four-taps", "three-channel-blocks", "bf16"])
+def test_kernel_pair_matches_the_loop_and_the_jnp_form(k, h, dtype):
+    """Forward and the gradients of ``B``, ``C``, ``u`` and the taps.
+    bf16 comes in and goes out (the taps' gradient in the taps' dtype), the
+    sums are float32: one rounding away from the float64 loop."""
+    bcu, taps, g = _planes(k, 192, h, dtype)
+    assert short_conv.kernel_takes(bcu, taps)
+    assert short_conv._blocks(192, h, 7, bcu.dtype.itemsize) == (
+        64, {256: 256, 384: 128}[h])
+    kernel, form = _forms(bcu, taps, g)
+    want = _loop_gated(bcu, taps, g)
+    one_rounding = 2.0 ** -8 if dtype == jnp.bfloat16 else 4e-6
+    for got, ref, loop in zip(kernel, form, want):
+        assert got.dtype == ref.dtype == dtype and got.shape == loop.shape
+        scale = np.abs(loop).max()
+        np.testing.assert_allclose(
+            np.asarray(got, np.float64), loop, atol=one_rounding * scale)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float64), np.asarray(ref, np.float64),
+            atol=one_rounding * scale)
+
+
+def test_kernel_a_token_changes_no_output_before_it_and_zeros_lead():
+    bcu, taps, _ = _planes(K, 192, 128, jnp.float32, b=1)
+    fwd = functools.partial(short_conv.gated_conv_fwd, interpret=True)
+    a = fwd(bcu, taps)
+    # the first row of the second tile: its reach crosses the tile's edge
+    t = 64
+    b = fwd(bcu.at[:, 2, t].add(1.0), taps)
+    assert np.array_equal(a[:, :t], b[:, :t])
+    changed = np.abs(np.asarray(a - b)).max(axis=(0, 2)) > 0
+    assert changed.tolist() == [t <= i < t + K for i in range(192)]
+    # zeros ahead of row 0: the first token sees itself only
+    v = bcu[:, 0] * bcu[:, 2]
+    np.testing.assert_allclose(
+        a[:, 0], bcu[:, 1, 0] * (taps[K - 1] * v[:, 0]), rtol=1e-6)
+    np.testing.assert_allclose(
+        a[:, 1], bcu[:, 1, 1] * (taps[K - 1] * v[:, 1]
+                                 + taps[K - 2] * v[:, 0]), rtol=1e-5)
+
+
+@pytest.mark.parametrize("bcu,taps", [
+    ((2, 3, 64, 200), (3, 200)), ((2, 3, 100, 128), (3, 128)),
+    ((2, 3, 64, 128), (10, 128)), ((2, 64, 384), (3, 128))],
+    ids=["odd-channels", "ragged-sequence", "taps-beyond-a-halo",
+         "rows-not-planes"])
+def test_kernel_takes_refuses(bcu, taps):
+    bcu, taps = jnp.zeros(bcu), jnp.zeros(taps)
+    assert not short_conv.kernel_takes(bcu, taps)
+    with pytest.raises(ValueError, match="gated convolution kernels take"):
+        short_conv.gated_conv_fwd(bcu, taps)
+
+
+@pytest.mark.parametrize("case", ["plain", "packed", "mesh", "odd-shapes"])
+def test_block_takes_the_kernel_where_it_may(monkeypatch, case):
+    """On a TPU ``ShortConv`` hands whole tiles of an unpacked sequence
+    on one device to the kernel pair; a packed row, a mesh of several
+    devices and shapes the kernels refuse take the ``jnp`` form, with the
+    same numbers."""
+    s, h = (12, C) if case == "odd-shapes" else (64, 128)
+    module = ShortConv(LlamaConfig.tiny(
+        hidden_size=h, conv_taps=K, dtype=jnp.float32))
+    x = jax.random.normal(jax.random.PRNGKey(2), (B, s, h), jnp.float32)
+    params = nn.meta.unbox(module.init(jax.random.PRNGKey(3), x))["params"]
+    segments = jnp.asarray([[0] * 20 + [1] * 44] * B) \
+        if case == "packed" else None
+
+    def grads():
+        return jax.value_and_grad(lambda p: jnp.sum(
+            module.apply({"params": p}, x, segments) ** 2))(params)
+
+    want = grads()
+    calls = []
+
+    def kernel(bcu, taps):
+        calls.append(bcu.shape)
+        return real(bcu, taps, True)
+
+    real = short_conv.gated_short_conv
+    monkeypatch.setattr(short_conv, "gated_short_conv", kernel)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if case == "mesh":
+        from dlrover_tpu.accel.parallel.mesh import MeshSpec
+
+        with MeshSpec(dp=2).build_mesh(jax.devices()[:2]):
+            got = grads()
+    else:
+        got = grads()
+    assert calls == ([(B, 3, s, h)] if case == "plain" else [])
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, atol=4e-6 * np.abs(b).max())

@@ -750,6 +750,26 @@ def test_rope_rotate_compiles_at_the_hybrid_cells_shapes(
         assert " transpose(" not in text and " copy(" not in text
 
 
+def test_gated_conv_pair_compiles_at_the_conv_cells_shape(one_chip):
+    """``train-conv-moe-8k``'s gates and taps (2 x 8192 tokens, three
+    planes of 2048 channels, 3 taps, bf16): the forward and the backward
+    are one kernel call each; beside the backward's only the taps'
+    gradient summed over the two sequences."""
+    from dlrover_tpu.ops.pallas import short_conv
+
+    def s(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    bcu, taps, dy = s((2, 3, 8192, 2048)), s((3, 2048)), s((2, 8192, 2048))
+    assert short_conv.kernel_takes(bcu, taps)
+    for fn, args in ((short_conv.gated_conv_fwd, (bcu, taps)),
+                     (short_conv.gated_conv_bwd, (dy, bcu, taps))):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert text.count("tpu_custom_call") == 1
+        assert f'%{fn.__name__}' in text
+        assert " transpose(" not in text and " copy(" not in text
+
+
 def _olmoe_cell():
     from dlrover_tpu.models.llama import LlamaConfig
 
@@ -803,7 +823,8 @@ _PARENT_STEP_MB = {"train-hybrid-8k": (138.02, 1.07),
     (_hybrid_cell, (2, 32, 2048, 512), 4, {
         "attn_full": 8, "flash_window_fwd": 6, "flash_window_dq": 3,
         "flash_window_dkv": 3, "rope_rotate": 30}),
-    (_conv_cell, (3, 8, 2048, 1792), 4, {"attn_full": 4}),
+    (_conv_cell, (3, 8, 2048, 1792), 4, {
+        "attn_full": 4, "gated_conv_fwd": 8, "gated_conv_bwd": 4}),
 ], ids=["train-moe-dropless", "train-hybrid-8k", "train-conv-moe-8k"])
 def test_sparse_cells_step_reads_expert_weights_in_the_stack(
         topo, monkeypatch, request, cell, stack, sparse_layers_a_loop,
@@ -828,7 +849,13 @@ def test_sparse_cells_step_reads_expert_weights_in_the_stack(
     weights read in the stack), no array has ``T x top_k`` rows by the
     hidden size or the experts' width, the step fits the chip (the compile
     fails where it does not) and its executable stays inside its budget
-    (``_PARENT_STEP_MB``)."""
+    (``_PARENT_STEP_MB``).  Since PR 61 a convolution layer's gates and
+    taps are ``gated_conv_fwd`` (forward and recomputed) and
+    ``gated_conv_bwd``, handed ``in_proj``'s output as the compiler lays
+    it out: under ``conv_mix`` no float32 array of tokens x channels and
+    no ``copy`` / ``transpose`` / ``concatenate`` of the projection's
+    (364 such float32 instructions before; ``[b, s, 3 h]`` rows cost two
+    copies of 201 MB a layer: PERF.md section 6, PR 61)."""
     import collections
     import re
 
@@ -913,6 +940,18 @@ def test_sparse_cells_step_reads_expert_weights_in_the_stack(
             text, re.M)
     for scope in ("conv_proj", "conv_mix") if convs else ():
         assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', text), scope
+    if convs:
+        mixed = [line for line in text.splitlines() if re.search(
+            r'op_name="[^"]*[/(]conv_mix[/)]', line)]
+        assert sum(" custom-call(" in line for line in mixed) == 12
+        h = cfg.hidden_size
+        tokens = rf"(?:{rows},{seq}|{rows * seq})"
+        wide = re.compile(rf"= \(?f32\[{tokens},(?:\d+,)?{h}\]")
+        moved = re.compile(
+            rf"= bf16\[(?:{tokens},(?:3,{h}|{3 * h})|{rows},3,{seq},{h})\]"
+            r"\S* (?:copy|transpose|concatenate)\(")
+        assert not [line for line in mixed
+                    if wide.search(line) or moved.search(line)]
     if cfg.layers is not None and cfg.head_dim_ == 128:
         half_a_head = re.compile(
             rf"(?:bf16|f32)\[{rows},{seq},\d+,(?:{cfg.head_dim_ // 2}|"
