@@ -69,7 +69,9 @@ class LayerSpec:
     # gated short convolution, ``ShortConv``: LlamaConfig.conv_taps;
     # trained, not served) | "kda" (linear attention with a recurrent
     # state: LlamaConfig.kda_heads) | "ssm" (a Mamba-2 state-space layer:
-    # LlamaConfig.ssm_heads); the last two are served, not trained
+    # LlamaConfig.ssm_heads) | "retention" (power retention of degree 2
+    # over the model's own query and key heads: ops/pallas/retention.py);
+    # the last three are served, not trained
     mixer: str = "attn"
     # what differs between two kinds of LATENT layer in one model (0: the
     # config's ``kv_lora_rank`` / ``qk_nope_head_dim``), and whether the
@@ -369,6 +371,12 @@ class LlamaConfig:
             # W_in to [B | C | u]; a channel's taps; W_out; the block's
             # two norms
             n = 3 * h * h + self.conv_taps * h + h * h + 2 * h
+        elif spec.mixer == "retention":
+            # q, k, v and o as the grouped-query block's; the gate, one
+            # scalar a key head with its bias; the two head norms; the
+            # block's two norms
+            n = (h * d * (spec.num_heads * 2 + self.num_kv_heads * 2)
+                 + (h + 1) * self.num_kv_heads + 2 * d + 2 * h)
         elif self.kv_lora_rank:
             heads, q = spec.num_heads, self.q_lora_rank
             c, nope, indexed = self.latent_dims(spec)
@@ -926,6 +934,52 @@ class LlamaConfig:
         return cls(**base)
 
     @classmethod
+    def brumby_14b(cls, **kw) -> "LlamaConfig":
+        """manifestai/Brumby-14B-Base (``brumby``) as its config.json has
+        it: 40 identical layers, 40 query / 8 key heads of 128, a dense
+        SwiGLU of 17408, vocabulary 151936, untied, theta 1e6, no window
+        anywhere (``max_window_layers`` is the depth and
+        ``use_sliding_window`` false).  Every layer's mixer is POWER
+        RETENTION (``LayerSpec.mixer`` "retention",
+        ``ops/pallas/retention.py``): attention whose weight is ``(q .
+        k)^2`` under a gate, served as a recurrence over the symmetric
+        square of the key, a float32 state of 8 256 x 128 and a sum of
+        keys a key head and sequence, NO rows and no cache that grows.
+        Served, not trained.  ``num_layers`` cuts the depth.
+
+        Assumed, where the config names a mechanism and not its equation
+        (``perfbench/configs/brumby-14b-serve.json``): degree 2; the gate
+        ``logsigmoid(W_gate h + b)``, one scalar a KEY head and token,
+        float32; the normaliser ``z`` and ``eps`` 1e-6 in the denominator;
+        a QK-norm a head (one scale for all query heads, one for all key
+        heads) and RoPE over the whole head, halves paired, ahead of the
+        power, as the Qwen3 dense family whose widths these are."""
+        num_layers = int(kw.pop("num_layers", 40))
+        num_heads = int(kw.get("num_heads", 40))
+        rope = RopeSpec(theta=float(kw.get("rope_theta", 1000000.0)))
+        base = dict(
+            vocab_size=151936,
+            hidden_size=5120,
+            intermediate_size=17408,
+            num_layers=num_layers,
+            num_heads=num_heads,
+            num_kv_heads=8,
+            head_dim=128,
+            max_seq_len=32768,
+            rope_theta=1000000.0,
+            rms_norm_eps=1e-6,
+            qk_norm=True,
+            qk_norm_kind="head",
+            scan_layers=False,
+            remat=False,
+            layers=tuple(
+                LayerSpec(num_heads=num_heads, rope=rope, mixer="retention")
+                for _ in range(num_layers)),
+        )
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
     def from_preset(
         cls, name: str, num_layers: int = 0, **kw
     ) -> "LlamaConfig":
@@ -959,7 +1013,8 @@ class LlamaConfig:
 #: presets the entry points (examples/, the serving worker) can name
 PRESETS = ("tiny", "llama2_7b", "olmoe_1b_7b", "laguna_xs2", "glm5",
            "sarvam_105b", "kimi_linear_48b", "dots3_note",
-           "granite_4_h_small", "lfm2_8b_a1b", "keye_vl2_30b_a3b")
+           "granite_4_h_small", "lfm2_8b_a1b", "keye_vl2_30b_a3b",
+           "brumby_14b")
 
 
 def resolve_remat_policy(name: str):
@@ -1536,11 +1591,12 @@ class LlamaModel(nn.Module):
             raise NotImplementedError(
                 "LlamaModel trains attention layers and gated short "
                 "convolutions (LayerSpec.mixer='attn', 'conv'): a layer "
-                "whose mixer is linear attention ('kda') or a state-space "
-                "scan ('ssm') is served only (serving/linear.py).  Missing: "
+                "whose mixer is linear attention ('kda'), a state-space "
+                "scan ('ssm') or power retention ('retention') is served "
+                "only (serving/linear.py).  Missing: "
                 "the chunk kernel's backward (ops/pallas/kda.py, "
-                "ops/pallas/ssm.py) and a training layer around it "
-                "(ROADMAP Reach A6)")
+                "ops/pallas/ssm.py, ops/pallas/retention.py) and a "
+                "training layer around it (ROADMAP Reach A6)")
         if cfg.embedding_mult != 1.0 or cfg.residual_mult != 1.0 \
                 or cfg.attn_scale is not None:
             raise NotImplementedError(

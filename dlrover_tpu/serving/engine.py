@@ -187,15 +187,15 @@ class EngineStats:
     # and the layer-forwards that took them
     moe_buffer_walks: int = 0
     moe_layer_forwards: int = 0
-    # layers that keep a state a slot (LayerSpec.mixer "kda" or "ssm"),
-    # one float32 matrix a head and slot: host arithmetic, summed over
+    # layers that keep a state a slot (LayerSpec.mixer "kda", "ssm" or
+    # "retention"), float32 a head and slot: host arithmetic, summed over
     # decode forwards and layers
     state_bytes_live: int = 0     # read + written for the slots that
     #                               decoded: 2 x a slot's state each
     state_bytes_streamed: int = 0  # ... for the slots the decode kernel's
     #                               grid walked (kda_decode_step,
-    #                               ssm_decode_step: the active ones;
-    #                               the jnp path walks all)
+    #                               ssm_decode_step, retention_decode_step:
+    #                               the active ones; the jnp path walks all)
     state_resets_total: int = 0   # slots whose state a prompt's first
     #                               chunk started from zeros
     # window layers of latent attention (LayerSpec.window), whose rows live
@@ -222,6 +222,10 @@ class EngineStats:
     # above count their states too; their chunk kernel's rows
     ssm_chunk_rows_real: int = 0
     ssm_chunk_rows_padded: int = 0  # ... in chunks of 128 tokens
+    # power retention (LayerSpec.mixer "retention"): the ``state_*``
+    # counters count its state AND its sum of keys; its chunk kernel's rows
+    retention_chunk_rows_real: int = 0
+    retention_chunk_rows_padded: int = 0  # ... in chunks of 128 tokens
     # the requests' own clocks (``Request``), summed over the engine's
     # whole life: sums and counts an operator divides, no percentile here
     slot_wait_seconds: float = 0.0  # ``add_request`` to a slot and its
@@ -463,17 +467,24 @@ class InferenceEngine:
         device with unquantized weights.  A model's layers that keep a
         recurrent STATE (``LayerSpec.mixer`` "kda", linear attention, beside
         latent attention; "ssm", a Mamba-2 scan, beside grouped-query
-        attention) keep no rows: the pools are an
+        attention; "retention", power retention, in EVERY layer) keep no
+        rows: the pools are an
         attention layer each, and beside them a float32 state a SLOT and
         such layer under the kind's name (``kda_state`` [slots, H, d, d] and
-        ``kda_conv``, or ``ssm_state`` [slots, H, P, N] and ``ssm_conv``:
-        ``serving/linear.py state_shapes``), ONE mechanism for both, the same
+        ``kda_conv``, ``ssm_state`` [slots, H, P, N] and ``ssm_conv``, or
+        ``retention_state`` [slots, Hk, tiles, d, d] and
+        ``retention_keysum``: ``serving/linear.py state_shapes``), ONE
+        mechanism for all, the same
         size at token 1 and at token 1 000 000, zeroed inside the program
         that takes a slot's first prompt chunk, carried from chunk to
         chunk, held still while the slot is idle or prefilling
         (``cache_nbytes`` counts both kinds).  Such a model takes every
         prompt in chunks (``prefill_chunk`` > 0) and is refused prefix
-        sharing, drafts and a mesh by what each would need.  Its WINDOW
+        sharing, drafts and a mesh by what each would need.  A model with
+        NO layer that caches rows has no pool and no block table at all:
+        ``paged`` reads False whatever was passed, ``cache_blocks`` and
+        ``block_size`` size nothing, and admission (the engine's and a
+        router's) is by SLOTS.  Its WINDOW
         layers (``LayerSpec.window``) keep no blocks of the pool either: a
         ring a slot and layer, sized by ``slots x window`` whatever
         ``cache_blocks`` is, and kept copies (half the slots' number, at
@@ -544,9 +555,10 @@ class InferenceEngine:
         # shard head-incorrectly).
         self.mesh = mesh
         # layers that keep a recurrent state (serving/linear.py: linear
-        # attention, "kda", or a state-space scan, "ssm"): a state a SLOT,
-        # not rows in blocks, of ONE kind a model.  What cannot be right yet
-        # is refused by what is missing, not served wrongly
+        # attention, "kda", a state-space scan, "ssm", or power retention,
+        # "retention"): a state a SLOT, not rows in blocks, of ONE kind a
+        # model.  What cannot be right yet is refused by what is missing,
+        # not served wrongly
         kinds = sorted({s.mixer for s in cfg.layer_specs
                         if s.mixer != "attn"})
         self._state_kind = kinds[0] if kinds else None
@@ -555,12 +567,13 @@ class InferenceEngine:
             raise ValueError(
                 "layers whose mixer is a gated short convolution "
                 "(LayerSpec.mixer='conv') are trained, not served.  "
-                "Missing: a convolution state a slot with no recurrent "
-                "state beside it (serving/linear.py state_shapes) and its "
+                "Missing: a kind in serving/linear.py state_shapes (the one "
+                "place that says which arrays a kind keeps a slot) that "
+                "keeps a convolution's rows and NO float32 state, and its "
                 "steps in serving/latent.py::_state_mixer (ROADMAP Reach A4)")
         if kinds:
-            name = {"kda": "linear-attention", "ssm": "state-space"}.get(
-                kinds[0], kinds[0])
+            name = {"kda": "linear-attention", "ssm": "state-space",
+                    "retention": "power-retention"}.get(kinds[0], kinds[0])
             if len(kinds) > 1:
                 raise ValueError(
                     f"layers of {kinds} in one model: a slot's recurrent "
@@ -679,16 +692,29 @@ class InferenceEngine:
         # sparse MLPs; its programs count the experts' picks and keep a
         # witness
         self._kinds = bool(cfg.layer_kinds)
+        # ... and of those a model with NO layer that caches rows (every
+        # layer keeps a state a slot): no pool, no block table in any
+        # program, nothing for ``cache_blocks`` to size or for a placement
+        # ledger to charge.  Admission is by slots
+        self.rowless = self._kinds and not any(
+            s.mixer == "attn" for s in cfg.layer_specs)
+        if self.rowless:
+            self.paged = False
         if self._kinds and not (
-                self.paged and self.kv_dtype is None and mesh is None
-                and not int8):
+                (self.paged or self.rowless) and self.kv_dtype is None
+                and mesh is None and not int8):
             raise ValueError(
                 "a latent-attention model, and any model of layer kinds "
                 "(a recurrent state a slot, sparse experts), is served "
                 "from paged pools in "
                 "the model's dtype on one device: pass paged=True, no "
                 "kv_dtype, no mesh, int8=False")
-        if self.paged:
+        if self.rowless:
+            self._cache = {"watch_slot": jnp.asarray(-1, jnp.int32)}
+            if cfg.num_experts:
+                self._cache["moe_picks"] = jnp.zeros(4, jnp.uint32)
+            self._cache.update(self._slot_states())
+        elif self.paged:
             # block-pool cache (serving/paged.py): per-sequence memory
             # scales with ACTUAL lengths, concurrency is bounded by the
             # pool (HBM budget) instead of slots x max_len reservations,
@@ -782,20 +808,7 @@ class InferenceEngine:
                     # layer-forwards], wrapping: the host adds differences
                     # (_book_moe_picks)
                     self._cache["moe_picks"] = jnp.zeros(4, jnp.uint32)
-                if self._state_layers:
-                    from dlrover_tpu.serving.linear import state_shapes
-
-                    state, conv = state_shapes(cfg, self.max_slots,
-                                               self._state_kind)
-                    # "<kind>_state", "<kind>_conv": indexed by SLOT,
-                    # donated through every program like the pools; zeroed
-                    # inside the program that takes a slot's first chunk
-                    self._cache[self._state_kind + "_state"] = [
-                        jnp.zeros(state, jnp.float32)
-                        for _ in range(self._state_layers)]
-                    self._cache[self._state_kind + "_conv"] = [
-                        jnp.zeros(conv, cfg.dtype)
-                        for _ in range(self._state_layers)]
+                self._cache.update(self._slot_states())
             elif self.kv_dtype == "int8":
                 from dlrover_tpu.models.quantize import KV_SCALE_DTYPE
 
@@ -907,6 +920,29 @@ class InferenceEngine:
             self._resolve_attention()
         self._build_programs()
 
+    def _slot_states(self) -> Dict[str, List[jax.Array]]:
+        """``<kind>_<name>``: what the layers that keep a state a slot
+        hold, a list over those layers of each array ``serving/linear.py
+        state_shapes`` names: indexed by SLOT, donated through every
+        program like the pools, zeroed inside the program that takes a
+        slot's first chunk ({} for a model with no such layer)."""
+        if not self._state_layers:
+            return {}
+        from dlrover_tpu.serving.linear import state_shapes
+
+        kept = state_shapes(self.cfg, self.max_slots, self._state_kind)
+        # a slot's recurrent arrays of one layer at the bytes they are kept
+        # in (power retention's sum of keys beside its state; a
+        # convolution's rows are not counted): ``_book_state_bytes``
+        self._state_slot_bytes = sum(
+            int(np.prod(held.shape)) * 4 // self.max_slots
+            for name, held in kept.items() if name != "conv")
+        return {
+            self._state_kind + "_" + name: [
+                jnp.zeros(held.shape, held.dtype)
+                for _ in range(self._state_layers)]
+            for name, held in kept.items()}
+
     # ----------------------------------------------- attention impl
     def _resolve_attention(self):
         from dlrover_tpu.ops.pallas.paged_attention import (
@@ -918,7 +954,7 @@ class InferenceEngine:
             raise ValueError(
                 f"attention_impl={req!r} not supported: use 'auto', "
                 "'xla' or 'pallas'")
-        if not self.paged:
+        if not self.paged and not self.rowless:
             if req == "pallas":
                 raise ValueError(
                     "attention_impl='pallas' reads paged block pools "
@@ -2046,7 +2082,7 @@ class InferenceEngine:
         once a forward for all of them, so streamed / live falls under 1
         as far as pages are shared; every other kernel copies, and books,
         each slot's groups."""
-        if self.attention_impl != "pallas":
+        if self.attention_impl != "pallas" or self.rowless:
             return 0, 0
         if self._latent:
             from dlrover_tpu.ops.pallas.mla_decode import streamed_rows
@@ -2142,9 +2178,7 @@ class InferenceEngine:
             return {}
         from dlrover_tpu.ops.pallas.kda import decode_states_walked
 
-        held = self._cache[self._state_kind + "_state"][0]
-        one = 2 * int(np.prod(held.shape[1:])) * 4
-        each = one * self._state_layers * self.chunk
+        each = 2 * self._state_slot_bytes * self._state_layers * self.chunk
         live = decode_states_walked(active) * each
         walked = live if self.attention_impl == "pallas" \
             else self.max_slots * each
@@ -2196,12 +2230,14 @@ class InferenceEngine:
         model with no such layer)."""
         if not self._state_layers:
             return {}
-        from dlrover_tpu.ops.pallas import kda, ssm
+        from dlrover_tpu.ops.pallas import kda, retention, ssm
 
         kind = self._state_kind
-        chunk = {"kda": kda, "ssm": ssm}[kind].CHUNK     # its kernel's
+        chunk = retention.chunk_of(self.prefill_chunk) \
+            if kind == "retention" \
+            else {"kda": kda, "ssm": ssm}[kind].CHUNK    # its kernel's
         # (the ``jnp`` recurrence walks a program's every row)
-        kernel = self.attention_impl == "pallas" \
+        kernel = self.attention_impl == "pallas" and chunk \
             and self.prefill_chunk % chunk == 0
         real, padded = kda.chunk_rows(
             np.asarray(ends) - np.asarray(starts), self.prefill_chunk,
@@ -2232,7 +2268,7 @@ class InferenceEngine:
         for key, val in self._cache.items():
             if isinstance(val, list):
                 kind = "window" if key.startswith("window_") else (
-                    "state" if key.endswith(("_state", "_conv"))
+                    "state" if key.startswith(f"{self._state_kind}_")
                     else "paged")
                 kinds[kind] += int(sum(x.nbytes for x in val))
         return kinds

@@ -7,11 +7,14 @@ grouped-query ones.
 
 A model may mix such layers with layers that keep a recurrent STATE A
 SLOT in place of rows in the pools (``LayerSpec.mixer`` "kda", linear
-attention, or "ssm", a Mamba-2 scan: ``serving/linear.py``): the layer
+attention, "ssm", a Mamba-2 scan, or "retention", power retention:
+``serving/linear.py``): the layer
 loop of :func:`verify_step` dispatches on each layer's description, the
 pools are indexed by the attention layers alone, and a ``RopeSpec`` whose
 ``rotary_fraction`` is 0 (``LlamaConfig.kimi_linear_48b``) rotates
-nothing.
+nothing.  A model whose EVERY layer keeps a state
+(``LlamaConfig.brumby_14b``) has no pool and no table in its cache at all,
+and a dense MLP behind every layer.
 
 This loop is THE loop of layer kinds (``LlamaConfig.layer_kinds``): the
 attention block it calls is latent or GROUPED-QUERY by what the config
@@ -782,18 +785,23 @@ def _mlp(lp, h, cfg: LlamaConfig, dtype, counted):
         return _swiglu(h, lp["wgu"], lp["down"], dtype), None
 
 
-def _state_mixer(kind: str, lp, h, state, conv, cfg: LlamaConfig, dtype,
-                 positions, slots, n_real, active, impl: str,
-                 interpret: bool):
-    """A layer that keeps a state a slot (``kind`` "kda" | "ssm":
-    ``serving/linear.py BLOCKS``) on ``h`` [B, K, E]: ``(y, state, conv,
-    decay)``, the layer's per-slot state advanced and, of a KDA layer,
-    what its decay was computed from and to ([B, K, 2, H, d]:
-    ``kda_decode``; None of a state-space layer).  One query a slot over
-    every slot is a decode forward (``active`` [B] or None: all); a run of
-    queries of the slots ``slots`` is a prompt chunk, a row at a time,
-    from zeros where the run starts at position 0, to its ``n_real``-th
-    token (None: all of it)."""
+def _state_mixer(kind: str, lp, h, state, second, second_axis: int,
+                 cfg: LlamaConfig, dtype, positions, slots, n_real, active,
+                 impl: str, interpret: bool):
+    """A layer that keeps a state a slot (``kind`` "kda" | "ssm" |
+    "retention": ``serving/linear.py BLOCKS``) on ``h`` [B, K, E]: ``(y,
+    state, second, decay)``, the layer's two per-slot arrays advanced (its
+    float32 state and what ``serving/linear.py state_shapes`` names beside
+    it: a convolution's last inputs, or power retention's sum of keys,
+    whose slots lie along ``second_axis``) and, of a KDA or retention
+    layer, what its decay was computed from and to ([B, K, 2, ...]:
+    ``kda_decode``, ``retention_decode``; None of a state-space layer).
+    One query a slot over every slot is a decode forward (``active`` [B] or
+    None: all); a run of queries of the slots ``slots`` is a prompt chunk,
+    a row at a time, from zeros where the run starts at position 0, to its
+    ``n_real``-th token (None: all of it).  ``positions`` [B], each row's
+    first, reach both branches: power retention rotates its queries and
+    keys."""
     from dlrover_tpu.serving.linear import BLOCKS
 
     decode_fn, run_fn = BLOCKS[kind]
@@ -807,23 +815,25 @@ def _state_mixer(kind: str, lp, h, state, conv, cfg: LlamaConfig, dtype,
                 "state under drafts (ROADMAP Reach A6)")
         if active is None:
             active = jnp.ones((b,), bool)
-        y, state, conv, decay = decode_fn(
-            lp, h[:, 0], state, conv, active, cfg, dtype, impl, interpret)
-        return y[:, None], state, conv, \
+        y, state, second, decay = decode_fn(
+            lp, h[:, 0], state, second, active, cfg, dtype, impl, interpret,
+            positions)
+        return y[:, None], state, second, \
             None if decay is None else decay[:, None]
     ys, decays = [], []
     for r in range(b):
         slot = slots[r]
         y, s_new, c_new, decay = run_fn(
             lp, h[r], jnp.take(state, slot, axis=0),
-            jnp.take(conv, slot, axis=1), positions[r] == 0,
+            jnp.take(second, slot, axis=second_axis),
+            (start := positions[r]) == 0,
             jnp.asarray(klen, jnp.int32) if n_real is None else n_real[r],
-            cfg, dtype, impl, interpret)
+            cfg, dtype, impl, interpret, start)
         state = state.at[slot].set(s_new)
-        conv = conv.at[:, slot].set(c_new)
+        second = second.at[(slice(None),) * second_axis + (slot,)].set(c_new)
         ys.append(y)
         decays.append(decay)
-    return jnp.stack(ys), state, conv, \
+    return jnp.stack(ys), state, second, \
         None if decays[0] is None else jnp.stack(decays)
 
 
@@ -1118,9 +1128,9 @@ def verify_step(
     cfg: LlamaConfig,
     cache: Dict[str, Any],   # {"latent_pool", "index_pool" (or "k_pool",
     tokens: jax.Array,       #   "v_pool"): lists, an ATTENTION layer
-                             #   each; "<kind>_state", "<kind>_conv": a
-                             #   layer of that kind each, by slot;
-                             #   "table"; "moe_picks"}
+                             #   each; "<kind>_state", "<kind>_conv" (or
+                             #   "_keysum"): a layer of that kind each, by
+                             #   slot; "table" (with a pool); "moe_picks"}
     positions: jax.Array,
     slots: Optional[jax.Array] = None,
     logits_index: Optional[jax.Array] = None,
@@ -1154,6 +1164,9 @@ def verify_step(
     last such layer, and ``kda_decay`` [2, H, d], the first such layer's
     ``(f, g)`` at the slot's query (a run: its last real one): the
     log-decay ``g`` beside the float32 sums ``f`` it is a function of; of
+    a model of power-retention layers ``retention_state`` [2, Hk, tiles, d,
+    d], ``retention_keysum`` [2, Hk, tiles, d] and ``retention_decay`` [2,
+    Hk] (the gate's sums and its logarithm) alike; of
     a model with state-space layers ``ssm_state`` [2, H, P, N] and
     ``ssm_conv`` [2, taps - 1, H P + 2 N], the slot's state and convolution
     rows behind this forward of the first and the last such layer."""
@@ -1163,8 +1176,10 @@ def verify_step(
     if cfg.embedding_mult != 1.0:
         x = (x.astype(jnp.float32) * cfg.embedding_mult).astype(x.dtype)
     pos_k = positions[:, None] + jnp.arange(klen)[None, :]   # [B, K]
-    table = cache["table"]
-    if slots is not None:
+    # (None: a model whose every layer keeps a state a slot has no rows,
+    # no pools and no table)
+    table = cache.get("table")
+    if slots is not None and table is not None:
         table = jnp.take(table, slots, axis=0)
     decode = klen == 1 and slots is None and logits_index is None
     run_table = n_real = None
@@ -1176,7 +1191,8 @@ def verify_step(
             counted = active[:, None]
     else:
         lengths = None
-        run_table = _pad_table(table, KEY_BLOCK_PAGES)
+        if table is not None:
+            run_table = _pad_table(table, KEY_BLOCK_PAGES)
         counted = jnp.ones((b, klen), bool) if logits_index is None else (
             jnp.arange(klen)[None, :] <= logits_index[:, None])
         # a row's real queries: behind ``logits_index`` a run is padding
@@ -1201,20 +1217,28 @@ def verify_step(
     states, convs, rings = [], [], []
     kind = next((s.mixer for s in cfg.layer_specs if s.mixer != "attn"),
                 None)                  # the one kind of state a slot
+    if kind is not None:
+        from dlrover_tpu.serving.linear import state_shapes
+
+        # the array a layer of this kind keeps beside its state, and the
+        # axis its slots lie along
+        name, held = list(state_shapes(cfg, b, kind).items())[1]
+        second, second_axis = kind + "_" + name, held.slot_axis
     for lp, spec in zip(params["layers"], cfg.layer_specs):
         h = _rmsnorm(x, lp["input_norm"], cfg.rms_norm_eps).astype(dtype)
         if spec.mixer != "attn":
             y, state, conv, decay = _state_mixer(
                 spec.mixer, lp, h, cache[kind + "_state"][len(states)],
-                cache[kind + "_conv"][len(convs)], cfg, dtype, positions,
-                slots, None if decode else n_real, active if decode
-                else None, attention_impl, kernel_interpret)
+                cache[second][len(convs)], second_axis, cfg, dtype,
+                positions, slots, None if decode else n_real,
+                active if decode else None, attention_impl,
+                kernel_interpret)
             if watch is not None and not states and decay is not None:
                 # the first such layer's decay at the watched row's query
                 # (a run: its last real one), beside what it came from
                 at = jnp.zeros((), jnp.int32) if logits_index is None \
                     else jnp.take(logits_index, watch).astype(jnp.int32)
-                seen["kda_decay"] = jnp.take(
+                seen[kind + "_decay"] = jnp.take(
                     jnp.take(decay, watch, axis=0), at, axis=0)
             states.append(state)
             convs.append(conv)
@@ -1302,10 +1326,14 @@ def verify_step(
         x = jnp.take_along_axis(
             x, logits_index.astype(jnp.int32)[:, None, None], axis=1)
     logits = _lm_head(params, x.astype(dtype), cfg)
-    out_cache = dict(cache, latent_pool=latent_pools) if cfg.kv_lora_rank \
-        else dict(cache, k_pool=k_pools, v_pool=v_pools)
+    if cfg.kv_lora_rank:
+        out_cache = dict(cache, latent_pool=latent_pools)
+    elif k_pools:
+        out_cache = dict(cache, k_pool=k_pools, v_pool=v_pools)
+    else:                              # no layer that caches rows
+        out_cache = dict(cache)
     if states:
-        out_cache.update({kind + "_state": states, kind + "_conv": convs})
+        out_cache.update({kind + "_state": states, second: convs})
         if watch is not None:
             # the watched slot's state behind this forward, of the first
             # and the last layer that keeps one
@@ -1314,16 +1342,18 @@ def verify_step(
                 [jnp.take(states[0], at, axis=0),
                  jnp.take(states[-1], at, axis=0)])
             if kind == "ssm":
-                # (a head's [P, N], not the kernels' kept layout) ... and
-                # its convolution rows (KDA's witness has the decay's two
-                # sides in their place)
+                # (a head's [P, N], not the kernels' kept layout)
                 from dlrover_tpu.ops.pallas.ssm import unpack_state
 
                 seen["ssm_state"] = unpack_state(seen["ssm_state"],
                                                  cfg.ssm_head_dim)
-                seen["ssm_conv"] = jnp.stack(
-                    [jnp.take(convs[0], at, axis=1),
-                     jnp.take(convs[-1], at, axis=1)])
+            if kind != "kda":
+                # ... and what the layer keeps beside it, its convolution
+                # rows or its sum of keys (KDA's witness has the decay's
+                # two sides in their place)
+                seen[second] = jnp.stack(
+                    [jnp.take(convs[0], at, axis=second_axis),
+                     jnp.take(convs[-1], at, axis=second_axis)])
     if cfg.index_topk:
         out_cache["index_pool"] = index_pools
     if rings:

@@ -1,9 +1,12 @@
 """The served blocks that keep a recurrent STATE A SLOT and no rows: Kimi
-Delta Attention (``LayerSpec.mixer`` "kda") and the Mamba-2 state-space
-layer ("ssm", at the end of this file), what ``serving/latent.py``'s layer
-loop runs in place of attention for such a layer.  :func:`state_shapes`
-gives the engine either kind's state; :data:`BLOCKS` the kind's two
-functions, one token a slot and a run of one slot's tokens.
+Delta Attention (``LayerSpec.mixer`` "kda"), the Mamba-2 state-space
+layer ("ssm") and power retention ("retention", at the end of this file),
+what ``serving/latent.py``'s layer loop runs in place of attention for
+such a layer.  :func:`state_shapes` is the ONE place that says which
+arrays a kind keeps a slot (a float32 state each; beside it the first two
+keep a convolution's last inputs, the third a float32 sum of keys and NO
+convolution); :data:`BLOCKS` gives the kind's two functions, one token a
+slot and a run of one slot's tokens.
 
 Kimi Delta Attention.
 
@@ -56,17 +59,39 @@ Mamba-2 (:func:`ssm_decode`, :func:`ssm_run`), on its normed input ``u``:
 ``ops/pallas/ssm.py packed_shape``) and ``conv`` [ssm_conv - 1, slots, H P
 + 2 N], with the life KDA's have.  Device scopes: ``ssm_proj``,
 ``ssm_scan`` (the kernel alone), ``ssm_out``.
+
+Power retention (:func:`retention_decode`, :func:`retention_run`), on its
+normed input ``x``, over the model's OWN heads (``num_heads`` queries on
+``num_kv_heads`` keys of ``head_dim``):
+
+- ``[q | k | v] = W_qkv x``: ONE matmul; each head of q and of k
+  RMS-normed (one learned scale for all query heads, one for all key
+  heads) and rotated over the whole head, halves paired, at the layer's
+  theta: the first state mixer that reads POSITIONS;
+- the gate ``log g = logsigmoid(W_gate x + b)``, ONE scalar a KEY head and
+  token, float32 sums;
+- the recurrence over the slot's ``state`` and ``keysum`` (``S`` and
+  ``z`` of ``ops/pallas/retention.py``: a float32 ``[tiles, d, d]`` and
+  ``[tiles, d]`` a key head, the symmetric square's unordered pairs once
+  each, shared by the ``num_heads / num_kv_heads`` query heads of the key
+  head): the kernels with ``impl == "pallas"``, the ``jnp`` recurrence
+  otherwise; the division is the kernels';
+- ``W_o y``.
+
+Device scopes: ``ret_proj`` (projections, head norms, rotation, gate),
+``ret_scan`` (the kernel alone), ``ret_out``.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from dlrover_tpu.models.llama import LlamaConfig
-from dlrover_tpu.ops.pallas import kda, ssm
+from dlrover_tpu.models.llama import (LlamaConfig, apply_rope,
+                                      rope_inverse_frequencies)
+from dlrover_tpu.ops.pallas import kda, retention, ssm
 from dlrover_tpu.serving.model import _mm, _rmsnorm
 from dlrover_tpu.utils.profiler import device_scope
 
@@ -109,16 +134,40 @@ def kda_params(p: Dict[str, Any], cfg: LlamaConfig, dtype
     }
 
 
-def state_shapes(cfg: LlamaConfig, slots: int, kind: str = "kda"):
-    """``(state, conv)`` shapes of one layer of ``kind`` ("kda" | "ssm")
-    for ``slots`` slots: the float32 state and the convolution's last
-    inputs, taps ahead of slots."""
+class Held(NamedTuple):
+    """One array a layer keeps for every slot: its shape over ``slots``
+    slots, its dtype, and which axis counts the slots."""
+
+    shape: Tuple[int, ...]
+    dtype: Any
+    slot_axis: int
+
+
+def state_shapes(cfg: LlamaConfig, slots: int,
+                 kind: str = "kda") -> Dict[str, Held]:
+    """What one layer of ``kind`` ("kda" | "ssm" | "retention") keeps for
+    ``slots`` slots, by name, in the order :data:`BLOCKS`' functions take
+    and return them (the engine's cache holds ``<kind>_<name>``, a list
+    over the layers of that kind).  Every kind keeps a float32 ``state``.
+    Beside it "kda" and "ssm" keep ``conv``, the convolution's last
+    inputs in the model's dtype, taps ahead of slots; "retention" keeps
+    ``keysum``, the float32 sum of keys that normalises its read-out, and
+    NO convolution."""
+    f32 = jnp.float32
+    if kind == "retention":
+        hk, d = cfg.num_kv_heads, cfg.head_dim_
+        tiles = retention.kept_tiles(d)
+        return {"state": Held((slots, hk, tiles, d, d), f32, 0),
+                "keysum": Held((slots, hk, tiles, d), f32, 0)}
     if kind == "ssm":
         h, p, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
-        return (slots,) + ssm.packed_shape(h, p, n), (
-            cfg.ssm_conv - 1, slots, h * p + 2 * n)
+        return {"state": Held((slots,) + ssm.packed_shape(h, p, n), f32, 0),
+                "conv": Held((cfg.ssm_conv - 1, slots, h * p + 2 * n),
+                             cfg.dtype, 1)}
     h, d = cfg.kda_heads, cfg.kda_head_dim
-    return (slots, h, d, d), (cfg.kda_conv - 1, slots, 3 * h * d)
+    return {"state": Held((slots, h, d, d), f32, 0),
+            "conv": Held((cfg.kda_conv - 1, slots, 3 * h * d), cfg.dtype,
+                         1)}
 
 
 def _mm32(x, w, dtype):
@@ -165,9 +214,10 @@ def _output(lp, x, o, cfg: LlamaConfig, dtype):
 
 
 def kda_decode(lp, x, state, conv, active, cfg: LlamaConfig, dtype,
-               impl: str, interpret: bool):
+               impl: str, interpret: bool, pos=None):
     """One token a slot: ``x`` [B, E], ``state`` [B, H, d, d], ``conv``
-    [taps - 1, B, 3 H d], ``active`` [B] bool.  Returns ``(y [B, E],
+    [taps - 1, B, 3 H d], ``active`` [B] bool (``pos``, the tokens'
+    positions, is not read: nothing here is rotated).  Returns ``(y [B, E],
     state, conv, decay)``; a slot that is not active keeps state and conv
     as they were; ``decay`` [B, 2, H, d] is ``(f, g)`` of :func:`_inputs`."""
     with device_scope("kda_proj"):
@@ -189,7 +239,7 @@ def kda_decode(lp, x, state, conv, active, cfg: LlamaConfig, dtype,
 
 
 def kda_run(lp, x, state, conv, fresh, n_real, cfg: LlamaConfig, dtype,
-            impl: str, interpret: bool):
+            impl: str, interpret: bool, pos=None):
     """A run of one slot's tokens: ``x`` [K, E], ``state`` [H, d, d] and
     ``conv`` [taps - 1, 3 H d] the slot's own, ``fresh`` (bool scalar:
     the prompt's first chunk starts from zeros), ``n_real`` (int32
@@ -275,7 +325,7 @@ def _ssm_output(lp, y, x, z, cfg: LlamaConfig, dtype):
 
 
 def ssm_decode(lp, u, state, conv, active, cfg: LlamaConfig, dtype,
-               impl: str, interpret: bool):
+               impl: str, interpret: bool, pos=None):
     """One token a slot: ``u`` [B, E], ``state`` [B, H / pack, N, pack x
     P], ``conv`` [taps - 1, B, H P + 2 N], ``active`` [B] bool.  Returns ``(y [B, E],
     state, conv, None)``; a slot that is not active keeps state and conv
@@ -300,7 +350,7 @@ def ssm_decode(lp, u, state, conv, active, cfg: LlamaConfig, dtype,
 
 
 def ssm_run(lp, u, state, conv, fresh, n_real, cfg: LlamaConfig, dtype,
-            impl: str, interpret: bool):
+            impl: str, interpret: bool, pos=None):
     """A run of one slot's tokens: ``u`` [K, E], ``state`` [H / pack, N,
     pack x P] and ``conv`` [taps - 1, H P + 2 N] the slot's own, ``fresh`` and ``n_real``
     as :func:`kda_run`'s.  Returns ``(y [K, E], state, conv, None)``,
@@ -334,5 +384,115 @@ def ssm_run(lp, u, state, conv, fresh, n_real, cfg: LlamaConfig, dtype,
     return _ssm_output(lp, y, x, z, cfg, dtype), state, conv, None
 
 
+# ------------------------------------------------------ power retention
+def retention_params(p: Dict[str, Any], cfg: LlamaConfig, dtype
+                     ) -> Dict[str, Any]:
+    """A layer's ``retention`` subtree, named as
+    ``perfbench/reference_brumby.py`` and the tests make it (``q_proj`` [E,
+    Hq, d], ``k_proj`` / ``v_proj`` [E, Hk, d]; ``q_norm`` / ``k_norm``
+    scale [d]; ``gate_proj`` kernel [E, Hk] and bias [Hk]; ``o_proj`` [Hq,
+    d, E]), as the serving tree: q, k and v fused into one matrix, the
+    gate's bias float32."""
+    def flat(name):
+        w = jnp.asarray(p[name]["kernel"], dtype)
+        return w.reshape(w.shape[0], -1)
+
+    return {
+        "ret_wqkv": jnp.concatenate(
+            [flat("q_proj"), flat("k_proj"), flat("v_proj")], axis=-1),
+        "ret_q_norm": p["q_norm"]["scale"],
+        "ret_k_norm": p["k_norm"]["scale"],
+        "ret_wgate": jnp.asarray(p["gate_proj"]["kernel"], dtype),
+        "ret_bgate": jnp.asarray(p["gate_proj"]["bias"], jnp.float32),
+        "ret_wo": jnp.asarray(p["o_proj"]["kernel"], dtype).reshape(
+            -1, cfg.hidden_size),
+    }
+
+
+def _retention_inputs(lp, x, pos, cfg: LlamaConfig, dtype):
+    """``x`` [T, E] (the block's normed input) at positions ``pos`` [T] ->
+    ``q`` [T, Hq, d] and ``k`` [T, Hk, d] (normed a head, rotated), ``v``
+    [T, Hk, d], the gate's logarithm [T, Hk] and the float32 sums ``f`` it
+    is the ``logsigmoid`` of, all float32."""
+    t = x.shape[0]
+    hq, hk, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    qkv = _mm(x, lp["ret_wqkv"], dtype)
+    q = _rmsnorm(qkv[:, :hq * d].reshape(t, hq, d), lp["ret_q_norm"],
+                 cfg.rms_norm_eps)
+    k = _rmsnorm(qkv[:, hq * d:(hq + hk) * d].reshape(t, hk, d),
+                 lp["ret_k_norm"], cfg.rms_norm_eps)
+    v = qkv[:, (hq + hk) * d:].reshape(t, hk, d).astype(jnp.float32)
+    rope = next(s.rope for s in cfg.layer_specs if s.mixer == "retention")
+    if rope.rotary_fraction:
+        angles = pos.astype(jnp.float32)[:, None] \
+            * rope_inverse_frequencies(rope, d)
+        q, k = apply_rope(q[None], angles)[0], apply_rope(k[None], angles)[0]
+    f = _mm32(x, lp["ret_wgate"], dtype) + lp["ret_bgate"]
+    return q, k, v, jax.nn.log_sigmoid(f), f
+
+
+def _retention_output(lp, y, cfg: LlamaConfig, dtype):
+    """``y`` [T, Hq, d] float32 -> the block's output [T, E]."""
+    with device_scope("ret_out"):
+        return _mm(y.reshape(y.shape[0], -1).astype(dtype), lp["ret_wo"],
+                   dtype)
+
+
+def retention_decode(lp, x, state, keysum, active, cfg: LlamaConfig, dtype,
+                     impl: str, interpret: bool, pos=None):
+    """One token a slot: ``x`` [B, E] at positions ``pos`` [B], ``state``
+    [B, Hk, tiles, d, d], ``keysum`` [B, Hk, tiles, d], ``active`` [B]
+    bool.  Returns ``(y [B, E], state, keysum, gate)``; a slot that is not
+    active keeps both as they were; ``gate`` [B, 2, Hk] is ``(f, log g)``
+    of :func:`_retention_inputs`."""
+    with device_scope("ret_proj"):
+        q, k, v, lg, f = _retention_inputs(lp, x, pos, cfg, dtype)
+    with device_scope("ret_scan"):
+        if impl == "pallas":
+            y, state, keysum = retention.retention_decode_step(
+                state, keysum, q, k, v, lg, active, interpret=interpret)
+        else:
+            y, s_new, z_new = retention.retention_step(
+                state, keysum, q, k, v, lg)
+            state = jnp.where(active[:, None, None, None, None], s_new,
+                              state)
+            keysum = jnp.where(active[:, None, None, None], z_new, keysum)
+    return _retention_output(lp, y, cfg, dtype), state, keysum, jnp.stack(
+        [f, lg], axis=1)
+
+
+def retention_run(lp, x, state, keysum, fresh, n_real, cfg: LlamaConfig,
+                  dtype, impl: str, interpret: bool, pos=None):
+    """A run of one slot's tokens: ``x`` [K, E] at positions ``pos``
+    onwards (a scalar: the first's), ``state`` [Hk, tiles, d, d] and
+    ``keysum`` [Hk, tiles, d] the slot's own, ``fresh`` and ``n_real`` as :func:`kda_run`'s.  Returns ``(y [K,
+    E], state, keysum, gate)``, both after the last real token, ``gate``
+    [K, 2, Hk] as :func:`retention_decode`'s."""
+    with device_scope("ret_proj"):
+        state = jnp.where(fresh, 0.0, state)
+        keysum = jnp.where(fresh, 0.0, keysum)
+        q, k, v, lg, f = _retention_inputs(
+            lp, x, pos + jnp.arange(x.shape[0]), cfg, dtype)
+        chunk = retention.chunk_of(x.shape[0]) if impl == "pallas" else 0
+        if chunk:
+            # every consumer of the projection's output under this scope,
+            # the kernel alone under the next
+            ops = retention.chunk_operands(q, k, v, lg, n_real, chunk=chunk)
+    with device_scope("ret_scan"):
+        if chunk:
+            # the two large products' operands in the model's dtype, as
+            # every other matmul's; the sums float32 either way
+            y, state, keysum = retention.retention_chunk_call(
+                state, keysum, *ops, n_real, chunk=chunk,
+                operands=jnp.dtype(dtype).name,
+                interpret=interpret)
+        else:
+            y, state, keysum = retention.retention_recurrence(
+                state, keysum, q, k, v, lg, n_real)
+    return _retention_output(lp, y, cfg, dtype), state, keysum, jnp.stack(
+        [f, lg], axis=1)
+
+
 #: a kind's ``(one token a slot, a run of one slot's tokens)``
-BLOCKS = {"kda": (kda_decode, kda_run), "ssm": (ssm_decode, ssm_run)}
+BLOCKS = {"kda": (kda_decode, kda_run), "ssm": (ssm_decode, ssm_run),
+          "retention": (retention_decode, retention_run)}

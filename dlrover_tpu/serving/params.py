@@ -250,7 +250,9 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
     (``wq_b`` [Q, Hi, Di], ``wk``, ``k_norm`` scale and bias,
     ``weights_proj``); in place of ``attn`` a layer whose
     ``LayerSpec.mixer`` is "kda" has ``kda`` (``serving/linear.py
-    kda_params``); and ``mlp`` as ``LlamaModel`` names a dense one or
+    kda_params``), one whose mixer is "retention" has ``retention``
+    (``retention_params``: its QK-norm a head and its rotation are the
+    block's own); and ``mlp`` as ``LlamaModel`` names a dense one or
     ``MoEMLP`` a sparse one (``select_bias`` beside the router).  The
     latent's up-projection is split into the key part, laid out for the
     absorbed query [H, nope, C], and the value part [H, C, V]; the router
@@ -281,9 +283,13 @@ def _latent_params(variables: Any, cfg: LlamaConfig, dtype
             from dlrover_tpu.serving.linear import ssm_params
 
             return ssm_params(p["ssm"], cfg, dtype)
+        if spec.mixer == "retention":
+            from dlrover_tpu.serving.linear import retention_params
+
+            return retention_params(p["retention"], cfg, dtype)
         if spec.mixer != "attn":
             raise ValueError(f"no served mixer {spec.mixer!r}: a served "
-                             "layer is 'attn', 'kda' or 'ssm' "
+                             "layer is 'attn', 'kda', 'ssm' or 'retention' "
                              "(LayerSpec.mixer; 'conv' is trained only)")
         a = p["attn"]
         _, nope, indexed = cfg.latent_dims(spec)

@@ -186,6 +186,9 @@ class InferenceEngineAdapter:
             # their slots could see (both 0 on the gather path)
             out["kv_rows_live"] = float(st.kv_rows_live)
             out["kv_rows_streamed"] = float(st.kv_rows_streamed)
+        if getattr(eng, "paged", False) or getattr(eng, "rowless", False):
+            # (a model with no layer that caches rows has no pool to page
+            # and no paged attention, and counts what follows all the same)
             # a learned selection of keys and a share of the experts:
             # the sums, so that a fleet's ratio weighs by work (all 0
             # for a model with neither)

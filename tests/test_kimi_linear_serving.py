@@ -506,7 +506,7 @@ def kimi_program_text(program):
     S = jax.ShapeDtypeStruct
     b, nb, bs, mb = 2, 9, 8, 4
     kda = sum(s.mixer == "kda" for s in cfg.layer_specs)
-    state, conv = state_shapes(cfg, b)
+    state, conv = (a.shape for a in state_shapes(cfg, b).values())
     cache = {
         "latent_pool": [S((nb, bs, latent.latent_row_width(cfg)),
                           jnp.bfloat16)] * (cfg.num_layers - kda),

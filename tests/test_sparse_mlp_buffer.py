@@ -196,7 +196,7 @@ def test_a_chunk_program_holds_no_row_of_a_dead_pick():
     sp = jax.eval_shape(lambda: serving_params_from_llama(
         {"params": SeededGraniteParams(cfg, 3)}, cfg))
     S = jax.ShapeDtypeStruct
-    state, conv = state_shapes(cfg, 3, "ssm")
+    state, conv = (a.shape for a in state_shapes(cfg, 3, "ssm").values())
     layers = sum(s.mixer == "ssm" for s in cfg.layer_specs)
     cache = {
         "k_pool": [S((20, 8, 2, 16), jnp.float32)] * (2 - layers),

@@ -522,7 +522,8 @@ def test_kimi_linear_programs_keep_their_state_in_place(one_chip,
     sp = on_chip(jax.eval_shape(lambda: serving_params_from_llama(
         {"params": SeededKimiLinearParams(cfg, 3)}, cfg)))
     S = jax.ShapeDtypeStruct
-    state, conv = linear.state_shapes(cfg, slots)
+    state, conv = (a.shape for a in linear.state_shapes(
+        cfg, slots).values())
     cache = on_chip({
         "latent_pool": [S((nb, bs, latent.latent_row_width(cfg)),
                           jnp.bfloat16)],
@@ -604,7 +605,8 @@ def test_granite_programs_keep_their_state_in_place(one_chip, monkeypatch,
     sp = on_chip(jax.eval_shape(lambda: serving_params_from_llama(
         {"params": SeededGraniteParams(cfg, 3)}, cfg)))
     S = jax.ShapeDtypeStruct
-    state, conv = linear.state_shapes(cfg, slots, "ssm")
+    state, conv = (a.shape for a in linear.state_shapes(
+        cfg, slots, "ssm").values())
     cache = on_chip({
         "k_pool": [S((nb, bs, 8, 128), jnp.bfloat16)],
         "v_pool": [S((nb, bs, 8, 128), jnp.bfloat16)],
@@ -655,6 +657,74 @@ def test_granite_programs_keep_their_state_in_place(one_chip, monkeypatch,
     memory = compiled.memory_analysis()
     one_state = 128 * 128 * 64 * 128 * 4
     assert memory.alias_size_in_bytes >= 9 * one_state
+    assert memory.temp_size_in_bytes < one_state
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+def test_brumby_programs_keep_their_state_in_place(one_chip, program):
+    """Two layers of brumby-14b-serve at its published widths and the
+    cell's 24 slots, with NO pool and no table in the cache: the decode
+    forward holds ``retention_decode_step`` and the prompt chunk
+    ``retention_chunk_fwd``, each under ``ret_scan``; the donated states
+    and sums of keys come back aliased, and nothing in the program is a
+    copy of a layer's 824 MB of state."""
+    from dlrover_tpu.models.llama import LlamaConfig
+    from dlrover_tpu.serving import latent, linear
+    from dlrover_tpu.serving.model import decode_step
+    from dlrover_tpu.serving.params import serving_params_from_llama
+    from dlrover_tpu.utils.profiler import device_scope, parse_program
+    from perfbench.weights_brumby import SeededBrumbyParams
+
+    cfg = LlamaConfig.brumby_14b(
+        num_layers=2, max_seq_len=5248, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)
+    slots = 24
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    sp = on_chip(jax.eval_shape(lambda: serving_params_from_llama(
+        {"params": SeededBrumbyParams(cfg, 3)}, cfg)))
+    S = jax.ShapeDtypeStruct
+    cache = on_chip({
+        **{"retention_" + name: [S(held.shape, held.dtype)] * 2
+           for name, held in linear.state_shapes(
+               cfg, slots, "retention").items()},
+        "watch_slot": S((), jnp.int32)})
+    if program == "decode":
+        def forward(p, c, t, pos, act):
+            with device_scope("decode_chunk"):
+                return decode_step(p, cfg, c, t, pos,
+                                   attention_impl="pallas", active=act)
+
+        args = on_chip((S((slots,), jnp.int32), S((slots,), jnp.int32),
+                        S((slots,), jnp.bool_)))
+        kernel = "retention_decode_step"
+    else:
+        def forward(p, c, t, pos, sl, li):
+            with device_scope("prefill_chunk"):
+                return latent.verify_step(
+                    p, cfg, c, t, pos, slots=sl, logits_index=li,
+                    attention_impl="pallas")
+
+        args = on_chip((S((1, 512), jnp.int32),) + (S((1,), jnp.int32),) * 3)
+        kernel = "retention_chunk_fwd"
+    lowered = jax.jit(forward, donate_argnums=(1,)).lower(sp, cache, *args)
+    compiled = lowered.compile()
+    table = parse_program(
+        program, compiled.as_text(),
+        {program if program != "decode" else "decode_chunk", "ret_proj",
+         "ret_scan", "ret_out", "mlp", "head"},
+        lowered.as_text(debug_info=True))
+    assert table.complete, table.missing
+    scopes = {n: scope for n, scope in table.scope_of.items()
+              if n.startswith(kernel)}
+    assert sorted(scopes.values()) == ["ret_scan"] * 2, scopes
+    memory = compiled.memory_analysis()
+    one_state = slots * 8 * 65 * 128 * 129 * 4
+    assert memory.alias_size_in_bytes >= 2 * one_state
     assert memory.temp_size_in_bytes < one_state
 
 
